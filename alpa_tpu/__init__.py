@@ -6,9 +6,6 @@ of stock jax/XLA — GSPMD over ICI for intra-mesh collectives, jax-runtime
 DCN transfers for cross-mesh resharding, no forked jaxlib, no Ray.
 See SURVEY.md for the design blueprint.
 """
-from alpa_tpu import jax_compat
-jax_compat.install()
-
 from alpa_tpu.api import (clear_executable_cache, init, shutdown,
                           parallelize, grad, value_and_grad)
 from alpa_tpu.device_mesh import (DeviceCluster, DistributedArray,

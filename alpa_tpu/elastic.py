@@ -35,9 +35,8 @@ failure *lifecycle* owned end to end by :class:`ElasticSupervisor`:
    clock are checked against ``elastic_step_budget`` /
    ``elastic_time_budget_s``.
 
-The wedge-recovery runbook (``scripts/chip_recovery_runbook.sh``) is
-code here: :class:`WedgeDetector` runs the probe-between-legs
-discipline — a bounded-timeout trivial device program per mesh,
+Wedge recovery: :class:`WedgeDetector` runs a probe between steps —
+a bounded-timeout trivial device program per mesh,
 classified ``ok`` / ``wedged`` (no answer, not even an error) /
 ``dead`` (probe raised), short-circuiting at the first wedge sign —
 and a wedge episode re-solves on the same devices (reset) and resumes
@@ -119,16 +118,14 @@ class PreemptionNotice(RuntimeError):
 
 
 class WedgeDetector:
-    """The chip-recovery runbook's probe discipline as code.
+    """Per-mesh liveness probe with a three-valued verdict.
 
-    ``scripts/chip_recovery_runbook.sh`` runs ``timeout 120 python
-    bench.py --probe`` between every leg and stops at the first sign of
-    a wedge; the taxonomy it encodes is exactly three-valued and this
-    class reproduces it per mesh:
+    A trivial device program runs on each mesh under a timeout, and the
+    sweep stops at the first sign of a wedge:
 
     * ``"ok"``     — the probe program completed inside the timeout.
-    * ``"wedged"`` — the probe neither answered nor errored (the
-      runbook's hung-``timeout`` case): the device is alive enough to
+    * ``"wedged"`` — the probe neither answered nor errored (it hung
+      past the timeout): the device is alive enough to
       accept work but will never finish it.  Killing/retrying on it
       wedges harder; reset and restore instead.
     * ``"dead"``   — the probe raised or returned falsy: the device (or
@@ -195,7 +192,7 @@ class WedgeDetector:
             if statuses[i] != "ok":
                 tripped = True
                 logger.warning("wedge detector: mesh %d is %s — "
-                               "stopping the sweep (runbook discipline: "
+                               "stopping the sweep (probe discipline: "
                                "never probe past a wedge)", i,
                                statuses[i])
         return statuses

@@ -331,10 +331,9 @@ class GlobalConfig:
         # this interval, so keep it <= elastic_step_budget).
         self.elastic_snapshot_interval = int(os.environ.get(
             "ALPA_TPU_ELASTIC_SNAPSHOT_INTERVAL", "1"))
-        # WedgeDetector probe timeout (seconds) — the runbook's
-        # ``timeout 120`` leg discipline (scripts/chip_recovery_runbook
-        # .sh): a probe that neither answers nor errors inside this
-        # window classifies the device as wedged, not dead.
+        # WedgeDetector probe timeout (seconds): a probe that neither
+        # answers nor errors inside this window classifies the device
+        # as wedged, not dead.
         self.wedge_probe_timeout_s = float(os.environ.get(
             "ALPA_TPU_WEDGE_PROBE_TIMEOUT", "120"))
 

@@ -37,7 +37,9 @@ NEG_INF = -1e9
 def _online_softmax_update(q, k_blk, v_blk, m_prev, l_prev, acc, *,
                            causal: bool, q_start, k_start):
     """One flash-attention block update, shared by both kernels:
-    (m, l, acc) -> (m', l', acc') after attending q to one k/v block."""
+    (m, l, acc) -> (m', l', acc') after attending q to one k/v block.
+    m and l are (block_q, 1) columns: the TPU lowering has no 1-D
+    vector layout, and a column broadcasts against the scores as is."""
     block_q = q.shape[0]
     block_k = k_blk.shape[0]
     s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
@@ -48,12 +50,12 @@ def _online_softmax_update(q, k_blk, v_blk, m_prev, l_prev, acc, *,
         k_pos = k_start + lax.broadcasted_iota(jnp.int32,
                                                (block_q, block_k), 1)
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    m_cur = jnp.max(s, axis=1)
+    m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_new = acc * alpha + jax.lax.dot_general(
         p, v_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return m_new, l_new, acc_new
@@ -65,7 +67,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     """One (batch*head, q-block) program instance.
 
     q_ref: (block_q, d); k_ref/v_ref: (s_k, d); o_ref: (block_q, d);
-    lse_ref: (block_q,) — per-row logsumexp of the scaled scores, the
+    lse_ref: (block_q, 1) — per-row logsumexp of the scaled scores, the
     residual the backward kernels reconstruct P from.
     """
     block_q, d = q_ref.shape
@@ -75,8 +77,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     q_blk = pl.program_id(1)
     q_start = q_blk * block_q + q_offset
 
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, d), jnp.float32)
 
     num_k_blocks = pl.cdiv(s_k, block_k)
@@ -98,13 +100,24 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         n_iter = num_k_blocks
     m, l, acc = lax.fori_loop(0, n_iter, body, (m0, l0, acc0))
     l = jnp.maximum(l, 1e-20)
-    o_ref[:] = (acc / l[:, None]).astype(o_ref.dtype)
+    o_ref[:] = (acc / l).astype(o_ref.dtype)
     lse_ref[:] = m + jnp.log(l)
 
 
 # above this many k/v bytes per (batch, head), stream blocks from HBM
-# instead of keeping k/v VMEM-resident (VMEM is ~16MB/core)
+# instead of keeping k/v VMEM-resident.  The compiler gives one kernel
+# 16 MiB of VMEM on a v5e and double-buffers every blocked operand, so
+# 4 MiB of resident pairs is what fits beside the q/o blocks and the
+# f32 block intermediates (compiled for v5e in tests/ops/test_tpu_compile.py)
 VMEM_RESIDENT_LIMIT = 4 * 1024 * 1024
+
+
+def _resident_bytes(seq: int, d: int, dtype) -> int:
+    """VMEM bytes of one resident (seq, d) pair (k+v, or q+do): the
+    minor dim is laid out in 128 lanes, so head dim 64 costs what 128
+    does."""
+    lanes = -(-d // 128) * 128
+    return 2 * seq * lanes * jnp.dtype(dtype).itemsize
 
 
 def _flash_streaming_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
@@ -144,7 +157,7 @@ def _flash_streaming_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     @pl.when(kb == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:], 1e-20)
-        o_ref[:] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
         lse_ref[:] = m_ref[:] + jnp.log(l)
 
 
@@ -157,9 +170,20 @@ def _pick_block(size: int, target: int) -> int:
     return b
 
 
+def _pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` that compiles the kernel when the program is
+    lowered for a TPU and interprets it on any other platform.  The
+    choice is made at lowering time from the platform compiled for
+    (``lax.platform_dependent``), not from ``jax.default_backend()``:
+    on a TPU the kernel compiles or raises, it never interprets."""
+    compiled = pl.pallas_call(kernel, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    return lambda *args: lax.platform_dependent(
+        *args, tpu=compiled, default=interpreted)
+
+
 def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
-                   block_q: int = 256, block_k: int = 256,
-                   interpret: bool = None):
+                   block_q: int = 256, block_k: int = 256):
     """q: (B, Sq, H, D); k/v: (B, Sk, H, D) -> (out (B, Sq, H, D),
     lse (B*H, Sq))."""
     b, sq, h, d = q.shape
@@ -167,16 +191,18 @@ def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
     sm_scale = 1.0 / np.sqrt(d)
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
-    if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
 
     # (B, Sq, H, D) -> (B*H, Sq, D)
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    # lse leaves the kernel as a (Sq, 1) column per (batch, head): the TPU
+    # lowering wants the last two block dims to be multiples of (8, 128)
+    # or the whole array dim, which (block_q, 1) is and (block_q,) is not
+    out_shape = (jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+                 jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32))
 
-    kv_bytes = 2 * sk * d * k.dtype.itemsize
-    if kv_bytes > VMEM_RESIDENT_LIMIT:
+    if _resident_bytes(sk, d, k.dtype) > VMEM_RESIDENT_LIMIT:
         # long-sequence path: stream k/v blocks, carry softmax state in
         # scratch across the innermost (sequential) grid dim
         nk = sk // block_k
@@ -193,12 +219,11 @@ def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
         else:
             def kv_index(i, j, kb):
                 return (i, kb, 0)
-        out, lse = pl.pallas_call(
+        out, lse = _pallas_call(
             partial(_flash_streaming_kernel, causal=causal,
                     sm_scale=sm_scale, q_offset=q_offset, nk=nk,
                     block_q=block_q, block_k=block_k),
-            out_shape=(jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-                       jax.ShapeDtypeStruct((b * h, sq), jnp.float32)),
+            out_shape=out_shape,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((None, block_q, d),
@@ -209,23 +234,23 @@ def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
             out_specs=(
                 pl.BlockSpec((None, block_q, d),
                              lambda i, j, kb: (i, j, 0)),
-                pl.BlockSpec((None, block_q), lambda i, j, kb: (i, j)),
+                pl.BlockSpec((None, block_q, 1),
+                             lambda i, j, kb: (i, j, 0)),
             ),
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, d), jnp.float32),
             ],
-            interpret=interpret,
         )(qt, kt, vt)
-        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+        return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
+                lse.reshape(b * h, sq))
 
     grid = (b * h, pl.cdiv(sq, block_q))
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
                 sm_scale=sm_scale, q_offset=q_offset),
-        out_shape=(jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, sq), jnp.float32)),
+        out_shape=out_shape,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
@@ -234,11 +259,11 @@ def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
         ],
         out_specs=(
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q), lambda i, j: (i, j)),
+            pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
         ),
-        interpret=interpret,
     )(qt, kt, vt)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+    return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
+            lse.reshape(b * h, sq))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -246,7 +271,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          sm_scale: float, q_offset: int):
     """dq for one (batch*head, q-block): loop over k/v blocks up to the
     diagonal.  P is rebuilt from the saved logsumexp; delta is the
-    precomputed rowsum(dO * O)."""
+    precomputed rowsum(dO * O).  lse_ref/delta_ref: (block_q, 1)."""
     block_q, d = q_ref.shape
     s_k = k_ref.shape[0]
     q = q_ref[:].astype(jnp.float32)
@@ -268,10 +293,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_pos = k_start + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         return dq_acc + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -291,7 +316,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, causal: bool,
                           sm_scale: float, q_offset: int):
     """dk/dv for one (batch*head, k-block): loop over q blocks from the
-    diagonal down."""
+    diagonal down.  Works on the transposed scores S^T (block_k, block_q),
+    so lse/delta come in as rows — lse_ref/delta_ref: (num q blocks,
+    block_q), one row per q block — and every product is a plain matmul."""
     block_k, d = k_ref.shape
     s_q = q_ref.shape[0]
     k_blk = k_ref[:].astype(jnp.float32)
@@ -304,26 +331,26 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q_start = q_start_local + q_offset
         q = q_ref[pl.ds(q_start_local, block_q), :].astype(jnp.float32)
         do = do_ref[pl.ds(q_start_local, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(q_start_local, block_q)]
-        delta = delta_ref[pl.ds(q_start_local, block_q)]
-        s = sm_scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
+        lse = lse_ref[pl.ds(qb, 1), :]
+        delta = delta_ref[pl.ds(qb, 1), :]
+        s_t = sm_scale * jax.lax.dot_general(
+            k_blk, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if causal:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+                jnp.int32, (block_k, block_q), 0)
+            q_pos = q_start + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            s_t = jnp.where(q_pos >= k_pos, s_t, NEG_INF)
+        p_t = jnp.exp(s_t - lse)
         dv_acc = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p_t, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        dp_t = jax.lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta)
         dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds_t, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk_acc, dv_acc
 
@@ -341,15 +368,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_backward_kernels(q, k, v, out, lse, do, *, causal: bool,
                             q_offset: int, block_q: int = 256,
-                            block_k: int = 256, interpret: bool = None):
+                            block_k: int = 256):
     """Two-pass flash backward (dq; dk/dv), VMEM-resident regime."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     sm_scale = 1.0 / np.sqrt(d)
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
-    if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+    nq = sq // block_q
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -359,30 +385,32 @@ def _flash_backward_kernels(q, k, v, out, lse, do, *, causal: bool,
     # delta = rowsum(dO * O): cheap elementwise reduce, XLA-fused
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
                     axis=-1)
+    # the per-row vectors in the two layouts the TPU lowering accepts
+    # (see _flash_forward): (Sq, 1) columns for the dq kernel, and one
+    # (block_q,) row per q block for the dkv kernel
+    as_col = lambda x: x.reshape(b * h, sq, 1)        # noqa: E731
+    as_rows = lambda x: x.reshape(b * h, nq, block_q)  # noqa: E731
 
     full = lambda i, j: (i, 0, 0)  # noqa: E731
-    full1 = lambda i, j: (i, 0)    # noqa: E731
     blk = lambda i, j: (i, j, 0)   # noqa: E731
-    blk1 = lambda i, j: (i, j)     # noqa: E731
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         partial(_flash_bwd_dq_kernel, block_k=block_k, causal=causal,
                 sm_scale=sm_scale, q_offset=q_offset),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        grid=(b * h, sq // block_q),
+        grid=(b * h, nq),
         in_specs=[
             pl.BlockSpec((None, block_q, d), blk),       # q
             pl.BlockSpec((None, sk, d), full),           # k
             pl.BlockSpec((None, sk, d), full),           # v
             pl.BlockSpec((None, block_q, d), blk),       # do
-            pl.BlockSpec((None, block_q), blk1),         # lse
-            pl.BlockSpec((None, block_q), blk1),         # delta
+            pl.BlockSpec((None, block_q, 1), blk),       # lse
+            pl.BlockSpec((None, block_q, 1), blk),       # delta
         ],
         out_specs=pl.BlockSpec((None, block_q, d), blk),
-        interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )(qt, kt, vt, dot, as_col(lse), as_col(delta))
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         partial(_flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
                 sm_scale=sm_scale, q_offset=q_offset),
         out_shape=(jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
@@ -393,13 +421,12 @@ def _flash_backward_kernels(q, k, v, out, lse, do, *, causal: bool,
             pl.BlockSpec((None, block_k, d), blk),       # k
             pl.BlockSpec((None, block_k, d), blk),       # v
             pl.BlockSpec((None, sq, d), full),           # do
-            pl.BlockSpec((None, sq), full1),             # lse
-            pl.BlockSpec((None, sq), full1),             # delta
+            pl.BlockSpec((None, nq, block_q), full),     # lse
+            pl.BlockSpec((None, nq, block_q), full),     # delta
         ],
         out_specs=(pl.BlockSpec((None, block_k, d), blk),
                    pl.BlockSpec((None, block_k, d), blk)),
-        interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+    )(qt, kt, vt, dot, as_rows(lse), as_rows(delta))
 
     unt = lambda x, s: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
     return unt(dq, sq), unt(dk, sk), unt(dv, sk)
@@ -409,10 +436,9 @@ def _bwd_kernels_feasible(q, k) -> bool:
     """Static predicate: the dq kernel keeps k+v (and the dkv kernel
     q+do) resident per (batch, head) — beyond the VMEM budget the
     backward recomputes instead."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    itemsize = jnp.dtype(q.dtype).itemsize
-    return max(2 * sk * d, 2 * sq * d) * itemsize <= VMEM_RESIDENT_LIMIT
+    d = q.shape[-1]
+    return _resident_bytes(max(q.shape[1], k.shape[1]), d,
+                           q.dtype) <= VMEM_RESIDENT_LIMIT
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -449,7 +475,9 @@ def _chunked_reference_attention(q, k, v, *, causal: bool, offset: int,
         return reference_attention(q_i, k, v, causal=causal,
                                    offset=offset + i * chunk)
 
-    outs = jax.lax.map(one_chunk, (jnp.arange(n), qc))
+    # checkpointed: the vjp of the map keeps each chunk's inputs, not its
+    # (chunk, S) scores stacked over all chunks (S x S again)
+    outs = jax.lax.map(jax.checkpoint(one_chunk), (jnp.arange(n), qc))
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
 
 

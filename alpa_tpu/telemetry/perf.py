@@ -564,7 +564,12 @@ def _stage_mfu(ops: Sequence[TimedOp], stage_execs,
                ) -> Dict[str, StageMfu]:
     if not stage_execs:
         return {}
-    peak = peak_tflops if peak_tflops else device_peak_tflops()
+    try:
+        peak = peak_tflops if peak_tflops else device_peak_tflops()
+    except ValueError:
+        # not a TPU in TPU_GENERATION_SPECS and no device_peak_tflops
+        # knob: there is no peak, so no MFU is reported
+        return {}
     out: Dict[str, StageMfu] = {}
     for ex in stage_execs:
         name = getattr(ex, "name", None)

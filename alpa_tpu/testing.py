@@ -18,34 +18,6 @@ import alpa_tpu
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
 
 
-def jax_version_tuple() -> tuple:
-    """(major, minor, patch) of the installed jax, non-numeric tails
-    dropped (``0.4.37.dev20241201`` -> (0, 4, 37))."""
-    parts = []
-    for p in jax.__version__.split("."):
-        if not p.isdigit():
-            break
-        parts.append(int(p))
-    return tuple(parts[:3])
-
-
-#: True on the pinned old-jax toolchain (< 0.5).  A handful of tier-1
-#: tests exercise behavior this jax/jaxlib cannot deliver (partial-auto
-#: shard_map sharding rank propagation, cross-jit donation aliasing,
-#: disjoint-mesh collectives in multi-controller mode, HLO text
-#: spellings); they skip with a reason instead of failing, and run again
-#: once the toolchain moves to a modern jax.
-OLD_JAX = jax_version_tuple() < (0, 5, 0)
-
-
-def skip_if_old_jax(reason: str):
-    """``pytest.mark.skipif`` gated on the old-jax toolchain, tagged with
-    the concrete jax limitation the test trips over."""
-    import pytest
-    return pytest.mark.skipif(
-        OLD_JAX, reason=f"known jax {jax.__version__} limitation: {reason}")
-
-
 def assert_allclose(x: Any, y: Any, rtol=1e-4, atol=1e-4):
     """Recursive pytree comparison (ref testing.py:28)."""
     if isinstance(x, dict):

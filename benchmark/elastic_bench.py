@@ -11,7 +11,7 @@ committed 2-stage pipeshard fixture, one scenario per failure class:
   grace window; scored on whether the synchronous snapshot landed
   inside the window (hit rate must be 1.0) plus recovery wall clock.
 * ``wedge``   — a mid-step instruction failure whose WedgeDetector
-  probe hangs (the BENCH_r03–r05 failure mode): torn state is never
+  probe hangs: torn state is never
   snapshotted; the supervisor resets and replays from the last
   verified checkpoint, bitwise.
 

@@ -70,4 +70,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from alpa_tpu.platform import enable_compilation_cache
+    enable_compilation_cache()
     main()

@@ -69,4 +69,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from alpa_tpu.platform import enable_compilation_cache
+    enable_compilation_cache()
     main()

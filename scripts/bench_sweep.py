@@ -1,7 +1,7 @@
 """On-chip config sweep for the bench model: attention impl x remat x loss.
 
-Prints one JSON line per config.  Stays inside the safe envelope
-(batch 8, seq 1024 — the relay wedges above that)."""
+Prints one JSON line per config (batch 8, seq 1024 unless a case says
+otherwise).  One process."""
 import json
 import time
 
@@ -67,8 +67,7 @@ def run_one(attention_impl, remat, chunked, batch_size=8,
 
 # (attn, remat, chunked, hidden, layers)
 SWEEPS = {
-    # impl sweep result (2026-07-29, v5e chip): reference/XLA attention, no
-    # remat, dense CE wins at GPT-125M bs8: 66.7 TF vs flash 47.7 / remat 53.9
+    # attention impl x remat x loss at GPT-125M bs8
     "impl": [
         ("reference", False, False, 768, 12),
         ("reference", False, True, 768, 12),
@@ -76,10 +75,7 @@ SWEEPS = {
         ("reference", True, True, 768, 12),
         ("flash", True, True, 768, 12),
     ],
-    # model-size sweep: bigger models amortize overhead -> higher MFU;
-    # batch stays at 8 (the relay wedges above that).  Result: monotone
-    # rise 60.1 (h1024 l24) -> 70.9 (h1536 l24) -> 75.2 (h2048 l16),
-    # all with remat; the h1024 no-remat variant failed remote compile.
+    # model-size sweep at batch 8
     "size": [
         ("reference", False, False, 1024, 24),
         ("reference", True, False, 1024, 24),
@@ -92,11 +88,9 @@ SWEEPS = {
         ("reference", True, True, 2048, 24),
         ("reference", True, True, 2560, 16),
     ],
-    # remat-policy rung (2026-07-29): "dots" saves matmul outputs.
-    # RESULT: h2048 l16 bs8 with "dots" WEDGED the relay (est 14.4 GB:
-    # 4.8 GB saved dots + 9.6 GB params/adam > safe envelope) — no case
-    # completed.  Keep "dots" for smaller models / bs<=4 only; the bench
-    # default stays full-block remat.
+    # remat-policy rung: "dots" saves matmul outputs (est 14.4 GB at
+    # h2048 l16 bs8: 4.8 GB saved dots + 9.6 GB params/adam), so these
+    # cases run at bs 4
     "policy": [
         dict(attention_impl="reference", remat=True, chunked=False,
              hidden=2048, layers=16, remat_policy="dots", batch_size=4),

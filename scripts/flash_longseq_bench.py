@@ -3,10 +3,9 @@
 The regime where blocked attention should win: XLA's reference path
 materializes the (B, H, S, S) score tensor in HBM (fp32), so its HBM
 traffic grows as S^2 while flash stays O(S * D).  Each case is memory-
-estimated first and SKIPPED above the safety gate (the relay wedges on
-near-OOM programs — never attempt).  Run under an external timeout:
+estimated first and skipped above ``SAFE_HBM_GB``.  One process:
 
-    timeout 600 python scripts/flash_longseq_bench.py
+    python scripts/flash_longseq_bench.py
 
 Prints one JSON line per (impl, seq, blocks) case.
 """

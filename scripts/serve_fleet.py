@@ -7,6 +7,10 @@ wired over HTTP.  Workers share identical params (same PRNG seed), so
 the fleet serves one logical model and the disaggregated handoff is
 bit-exact across processes.
 
+Each worker process claims the device jax finds for it, and a chip
+belongs to one process: a fleet of more than one worker runs with
+``JAX_PLATFORMS=cpu`` set, and is refused otherwise.
+
 Usage::
 
     # monolithic 2-replica fleet
@@ -76,11 +80,9 @@ def _spawn_worker(args, phase: str):
            "--phase", phase, "--host", args.host,
            "--seq-len", str(args.seq_len),
            "--prefill-chunk", str(args.prefill_chunk)]
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
-    proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
-                            env=env)
-    return proc
+    # the worker runs on whatever platform the environment names (or jax
+    # finds): the platform is never chosen here
+    return subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True)
 
 
 def _await_worker(proc, timeout: float):
@@ -147,6 +149,15 @@ def run_fleet(args) -> int:
             [("any", i) for i in range(args.replicas)])
     if not plan:
         plan = [("any", 0), ("any", 1)]
+    if len(plan) > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # a chip belongs to one process: every worker would claim the
+        # accelerator jax finds, and all but the first would fail or hang
+        print(f"serve_fleet: refusing to start {len(plan)} worker "
+              f"processes on the accelerator (JAX_PLATFORMS="
+              f"{os.environ.get('JAX_PLATFORMS')!r}): a chip belongs to "
+              f"one process.  Set JAX_PLATFORMS=cpu for a CPU fleet, or "
+              f"start one worker.", file=sys.stderr, flush=True)
+        return 2
     procs = []
     try:
         procs = [(phase, i, _spawn_worker(args, phase))

@@ -8,7 +8,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from alpa_tpu.parallel.spmd_pipeline import (spmd_pipeline,
                                              spmd_pipeline_1f1b,
                                              stack_pytrees)
-from alpa_tpu.testing import skip_if_old_jax
 
 
 def _mesh(shape, names):
@@ -18,9 +17,6 @@ def _mesh(shape, names):
 
 class TestSpmdPipeline:
 
-    @skip_if_old_jax("partial-automatic shard_map miscompiles (XLA "
-                     "PartitionId aborts), so jax_compat refuses it with "
-                     "NotImplementedError")
     def test_forward_matches_serial(self):
         mesh = _mesh((2, 4), ("dp", "pp"))
         S = 4
@@ -156,9 +152,6 @@ class TestSpmdPipeline1F1B:
 
 class TestGraftEntry:
 
-    @skip_if_old_jax("partial-automatic shard_map miscompiles (XLA "
-                     "PartitionId aborts), so jax_compat refuses it with "
-                     "NotImplementedError")
     def test_dryrun_multichip(self):
         import importlib.util
         import os

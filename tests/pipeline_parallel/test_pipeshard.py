@@ -15,7 +15,7 @@ from alpa_tpu.pipeline_parallel.layer_construction import (AutoLayerOption,
 from alpa_tpu.pipeline_parallel.stage_construction import (ManualStageOption,
                                                            UniformStageOption)
 from alpa_tpu.testing import (assert_allclose, create_mlp_train_state_and_batch,
-                              get_mlp_train_step, skip_if_old_jax)
+                              get_mlp_train_step)
 
 
 def _compare_pipeshard(method, n_steps=2, rtol=2e-3, num_layers=4,
@@ -60,9 +60,6 @@ class TestPipeshard:
                               stage_option=UniformStageOption(num_stages=2),
                               pipeline_schedule="1f1b_overlap_friendly"))
 
-    @skip_if_old_jax("XLA INTERNAL error compiling auto-layer stages: "
-                     "donated-input aliasing pairs sub-shapes of different "
-                     "sizes under microbatched accumulation")
     def test_auto_layers(self):
         _compare_pipeshard(
             PipeshardParallel(num_micro_batches=2,
@@ -76,9 +73,6 @@ class TestPipeshard:
                               layer_option=ManualLayerOption(),
                               stage_option=UniformStageOption(num_stages=2)))
 
-    @skip_if_old_jax("XLA INTERNAL error compiling auto-layer stages: "
-                     "donated-input aliasing pairs sub-shapes of different "
-                     "sizes under microbatched accumulation")
     def test_four_stages(self):
         _compare_pipeshard(
             PipeshardParallel(num_micro_batches=2,

@@ -105,7 +105,7 @@ def run_supervised(sup, batch, until, max_calls=50):
 
 
 class TestWedgeDetector:
-    """The runbook's probe-timeout taxonomy, as unit checks (no mesh
+    """The probe-timeout taxonomy, as unit checks (no mesh
     needed: the probe is injectable)."""
 
     def test_ok_wedged_dead(self):
@@ -137,7 +137,7 @@ class TestWedgeDetector:
         statuses = det.check()
         assert statuses == {0: "ok", 1: "wedged", 2: "skipped",
                             3: "skipped"}
-        # the runbook discipline: never probe past a wedge
+        # never probe past a wedge
         assert probed == ["m0", "m1"]
         assert not det.healthy()
 

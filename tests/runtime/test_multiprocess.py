@@ -56,14 +56,6 @@ def _run_workers(nproc, mode=None, timeout=540):
     return outs
 
 
-from alpa_tpu.testing import skip_if_old_jax  # noqa: E402
-
-_MULTIPROC_REASON = ("multi-controller jit over disjoint per-process mesh "
-                     "slices fails inside the worker processes (device_put "
-                     "to non-addressable shardings)")
-
-
-@skip_if_old_jax(_MULTIPROC_REASON)
 def test_two_process_runtime():
     outs = _run_workers(2)
     for _, out, _ in outs:
@@ -71,7 +63,7 @@ def test_two_process_runtime():
         assert "pipeshard ok" in out
 
 
-@skip_if_old_jax(_MULTIPROC_REASON)
+@pytest.mark.slow
 def test_four_process_auto_stage_runtime():
     """4 processes x 2 devices: AUTO stage construction, planned
     (packed-tile) cross-process resharding, and a measured per-instruction

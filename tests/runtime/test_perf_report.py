@@ -216,16 +216,17 @@ class TestMfu:
             global_config.device_peak_tflops = prev
 
     def test_default_peak_comes_from_generation_specs(self):
-        from alpa_tpu.mesh_profiling import (TPU_GENERATION_SPECS,
-                                             detect_tpu_generation)
+        from alpa_tpu.mesh_profiling import TPU_GENERATION_SPECS
         prev = global_config.device_peak_tflops
         try:
             global_config.device_peak_tflops = 0.0
-            info = perf.peak_flops_info()
-            gen = detect_tpu_generation()
-            assert info["generation"] == gen
+            info = perf.peak_flops_info("v5e")
+            assert info["generation"] == "v5e"
             assert info["peak_bf16_tflops"] == \
-                TPU_GENERATION_SPECS[gen]["peak_bf16_tflops"]
+                TPU_GENERATION_SPECS["v5e"]["peak_bf16_tflops"]
+            # no knob, no TPU: there is no peak to assume on the CPU mesh
+            with pytest.raises(ValueError):
+                perf.peak_flops_info()
         finally:
             global_config.device_peak_tflops = prev
 

@@ -11,7 +11,7 @@ import pytest
 import alpa_tpu
 from alpa_tpu import AutoShardingOption, ShardParallel
 from alpa_tpu.testing import (assert_allclose, create_mlp_train_state_and_batch,
-                              get_mlp_train_step, skip_if_old_jax)
+                              get_mlp_train_step)
 from alpa_tpu.util import count_communication_primitives
 
 
@@ -228,9 +228,6 @@ class TestConstraintEmission:
         np.testing.assert_allclose(np.asarray(want), np.asarray(got),
                                    rtol=1e-5, atol=1e-5)
 
-    @skip_if_old_jax("compiled HLO spells the planned TP collectives "
-                     "differently, so count_communication_primitives "
-                     "finds none of the expected all-reduces")
     def test_ilp_choice_realized_in_hlo_gpt(self):
         """Fidelity: the all-reduces in compiled HLO equal the comm-bearing
         strategies the ILP chose (planner choice == HLO reality)."""
