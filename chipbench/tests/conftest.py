@@ -1,0 +1,8 @@
+"""The tests of the benchmark's own arithmetic run on the CPU:
+``python -m pytest chipbench/tests`` from the root of the repo."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
