@@ -629,6 +629,7 @@ class PipeshardDriverExecutable:
         self._launch_gate = threading.Event()
         self._launch_gate.set()
         self._inflight_launches = 0
+        self._n_launches = 0    # the ``step`` of each pipeshard.step span
         self._quiesce_cv = threading.Condition()
 
     # ------------------------------------------------------------------
@@ -641,7 +642,10 @@ class PipeshardDriverExecutable:
         with self._quiesce_cv:
             self._inflight_launches += 1
         t0 = time.perf_counter()
-        step_span = _ttrace.begin("pipeshard.step", "runtime")
+        step_span = _ttrace.begin(
+            "pipeshard.step", "runtime",
+            {"step": self._n_launches} if _ttrace.enabled() else None)
+        self._n_launches += 1
         try:
             return self._launch(*flat_args)
         except BaseException:
@@ -1133,59 +1137,63 @@ class PipeshardDriverExecutable:
         regs: List[Any] = [None] * prog.num_slots
         n_mb = self.num_micro_batches
 
-        # place global inputs in one batched device_put
-        put_vals, put_shs, put_slots = [], [], []
-        for arg_idx, is_batch, entries in self._reg_input_loads:
-            arg = flat_args[arg_idx]
-            if is_batch:
-                if n_mb == 1:
-                    mbs = [arg]
-                elif isinstance(arg, jax.Array):
-                    mbs = jnp.split(arg, n_mb, axis=0)
+        # inputs, constants and zeroed accumulators into their slots (this
+        # and the replay: the step's two phases inside ``pipeshard.step``)
+        with _ttrace.span("pipeshard.place-inputs", "runtime"):
+            # place global inputs in one batched device_put
+            put_vals, put_shs, put_slots = [], [], []
+            for arg_idx, is_batch, entries in self._reg_input_loads:
+                arg = flat_args[arg_idx]
+                if is_batch:
+                    if n_mb == 1:
+                        mbs = [arg]
+                    elif isinstance(arg, jax.Array):
+                        mbs = jnp.split(arg, n_mb, axis=0)
+                    else:
+                        mbs = np.split(np.asarray(arg), n_mb, axis=0)
+                    for s, sh, mb in entries:
+                        put_vals.append(mbs[mb])
+                        put_shs.append(sh)
+                        put_slots.append(s)
                 else:
-                    mbs = np.split(np.asarray(arg), n_mb, axis=0)
-                for s, sh, mb in entries:
-                    put_vals.append(mbs[mb])
-                    put_shs.append(sh)
-                    put_slots.append(s)
-            else:
-                for s, sh, _mb in entries:
-                    put_vals.append(arg)
-                    put_shs.append(sh)
-                    put_slots.append(s)
-        if put_vals:
-            placed = jax.device_put(put_vals, put_shs)
-            for s, o in zip(put_slots, placed):
-                regs[s] = o
+                    for s, sh, _mb in entries:
+                        put_vals.append(arg)
+                        put_shs.append(sh)
+                        put_slots.append(s)
+            if put_vals:
+                placed = jax.device_put(put_vals, put_shs)
+                for s, o in zip(put_slots, placed):
+                    regs[s] = o
 
-        # consts (placed once, re-slotted per launch)
-        if self._reg_const_loads is None:
-            slot_of = prog.slot_of
-            loads = []
-            for v, places in self.const_place.items():
-                val = self.consts_map[v]
-                for mesh_id, sh in places:
-                    loads.append((slot_of[(v, -1, mesh_id)],
-                                  jax.device_put(val, sh)))
-            self._reg_const_loads = loads
-        for s, a in self._reg_const_loads:
-            regs[s] = a
+            # consts (placed once, re-slotted per launch)
+            if self._reg_const_loads is None:
+                slot_of = prog.slot_of
+                loads = []
+                for v, places in self.const_place.items():
+                    val = self.consts_map[v]
+                    for mesh_id, sh in places:
+                        loads.append((slot_of[(v, -1, mesh_id)],
+                                      jax.device_put(val, sh)))
+                self._reg_const_loads = loads
+            for s, a in self._reg_const_loads:
+                regs[s] = a
 
-        # zero accumulators (compiled once; slots resolved once)
-        self._ensure_zero_execs()
-        if self._reg_acc_slots is None:
-            slot_of = prog.slot_of
-            self._reg_acc_slots = [
-                (compiled, [slot_of[(v, -1, mesh_id)] for v in vs])
-                for mesh_id, vs, compiled in self._zero_exec_cache
-            ]
-        for compiled, slots in self._reg_acc_slots:
-            for s, buf in zip(slots, compiled()):
-                regs[s] = buf
+            # zero accumulators (compiled once; slots resolved once)
+            self._ensure_zero_execs()
+            if self._reg_acc_slots is None:
+                slot_of = prog.slot_of
+                self._reg_acc_slots = [
+                    (compiled, [slot_of[(v, -1, mesh_id)] for v in vs])
+                    for mesh_id, vs, compiled in self._zero_exec_cache
+                ]
+            for compiled, slots in self._reg_acc_slots:
+                for s, buf in zip(slots, compiled()):
+                    regs[s] = buf
 
         # replay
         loop_tic = time.perf_counter()
-        prog.execute(regs)
+        with _ttrace.span("pipeshard.replay", "runtime"):
+            prog.execute(regs)
         loop_s = time.perf_counter() - loop_tic
         n_inst = max(1, prog.n_instructions)
         self.last_dispatch_stats = {

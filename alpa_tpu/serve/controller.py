@@ -749,6 +749,9 @@ class _Handler(BaseHTTPRequestHandler):
         # a pool or router still exposes the series
         import alpa_tpu.serve.kv_cache  # noqa: F401  pylint: disable=unused-import
         import alpa_tpu.serve.router  # noqa: F401  pylint: disable=unused-import
+        # ... and the overlap runtime's (alpa_overlap_*), which a process
+        # that has run no pipeshard step has not imported yet
+        import alpa_tpu.pipeline_parallel.runtime_emitter  # noqa: F401  pylint: disable=unused-import
         self._send_text(200, _tmetrics.get_registry().to_prometheus_text())
 
     def _healthz(self):
@@ -860,8 +863,20 @@ class _Handler(BaseHTTPRequestHandler):
         failure is reported as a final ``data: {"error": ...}`` event —
         never a second status line into the open SSE body.
         """
-        it = self.controller.completions_stream(request)  # validates
-        self._stream_body(it)
+        # ``serve.request`` for a streamed completion: from the request's
+        # validation to its last event, with the engine's ``rid`` so the
+        # span joins the engine's ``engine.queue-wait`` / ``engine.prefill``
+        args = {"model": str(request.get("model")), "stream": True} \
+            if _ttrace.enabled() else None
+        token = _ttrace.begin("serve.request", "serving", args,
+                              "serve-driver")
+        try:
+            it = self.controller.completions_stream(request)  # validates
+            if args is not None:
+                args["rid"] = it.rid
+            self._stream_body(it)
+        finally:
+            _ttrace.end(token)
 
     def _disagg(self):
         """Disaggregation endpoints (serve.disagg / serve.router):

@@ -11,6 +11,7 @@ The per-row KV-cache indices introduced in ``model.gpt_model`` are what
 make this possible: every row decodes at its own position.
 """
 import dataclasses
+import itertools
 import logging
 import threading
 import time
@@ -43,6 +44,30 @@ _ACTIVE_ROWS = _REG.gauge(
 _TTFT = _REG.histogram(
     "alpa_serving_ttft_seconds",
     "Time from submit to first generated token")
+_QUEUE_WAIT = _REG.histogram(
+    "alpa_serving_queue_wait_seconds",
+    "Time from submit to the request taking a KV-cache row")
+_PREFILL_PROMPT = _REG.counter(
+    "alpa_serving_prefill_prompt_tokens_total",
+    "Prompt tokens the engine's prefills were asked to compute")
+_PREFILL_PADDED = _REG.counter(
+    "alpa_serving_prefill_padded_tokens_total",
+    "Token positions the engine's prefill programs ran over")
+
+# every engine span: category "serving", on this track (the queue waits,
+# which overlap each other, on their own)
+_TRACK = "serve-engine"
+_QUEUE_TRACK = "serve-queue"
+
+
+def _phase(rec, name, args=None):
+    """A span of the engine loop — or the shared no-op when ``rec`` is
+    None: the loop looks at ``trace.enabled()`` once a tick and hands
+    the recorder (or None) down, so a tick with tracing off pays one
+    flag check."""
+    if rec is None:
+        return _ttrace.NULL_SPAN
+    return rec.span(name, "serving", args, _TRACK)
 
 _STREAM_END = object()
 
@@ -57,6 +82,8 @@ class _TokenStream:
     def __init__(self, item, q):
         self._item = item
         self._q = q
+        #: the engine's identifier of this request (``rid`` of its spans)
+        self.rid = item["rid"]
 
     def __iter__(self):
         return self
@@ -212,6 +239,7 @@ class ContinuousBatchingEngine:
         self._queue = scheduler
         self._cv = threading.Condition()
         self._rng = jax.random.PRNGKey(0)
+        self._rids = itertools.count()
         self.admissions = 0
         self.decode_steps = 0
         self.step_failures = 0
@@ -354,9 +382,7 @@ class ContinuousBatchingEngine:
         if self._prefix is not None:
             # admission prefills in fixed chunks FROM the prefix offset:
             # reject synchronously what chunk padding cannot fit
-            c = self.gen.prefill_chunk
-            padded = max(1, -(-len(prompt) // c)) * c if len(prompt) \
-                else 0
+            padded = self._chunk_padded(len(prompt))
             if plen + padded > seq_len:
                 raise ValueError(
                     f"prompt {len(prompt)} pads to {padded} chunks past "
@@ -366,7 +392,11 @@ class ContinuousBatchingEngine:
                 "done": _DoneEvent(on_done), "error": None,
                 "on_token": on_token, "cancelled": False,
                 "queue": queue or "default", "prefilled": prefilled,
-                "t_submit": time.monotonic()}
+                "rid": next(self._rids),
+                "t_submit": time.monotonic(),
+                # the queue-wait span's start, stamped only while tracing
+                "t_submit_us": _ttrace.now_us() if _ttrace.enabled()
+                else None}
 
     def shutdown(self):
         with self._cv:
@@ -375,14 +405,37 @@ class ContinuousBatchingEngine:
 
     # ---- engine loop ----
 
-    def _admit_locked(self):
+    def _row_taken(self, rec, item):
+        """A queued request gets its row: its wait is over."""
+        _QUEUE_WAIT.observe(time.monotonic() - item["t_submit"])
+        if rec is not None and item["t_submit_us"] is not None:
+            ts = item["t_submit_us"]
+            rec.complete("engine.queue-wait", "serving", ts,
+                         _ttrace.now_us() - ts,
+                         {"rid": item["rid"],
+                          "prompt_len": len(item["prompt"])},
+                         _QUEUE_TRACK)
+
+    def _chunk_padded(self, n: int) -> int:
+        """Positions the chunked prefill runs over for ``n`` tokens."""
+        c = self.gen.prefill_chunk
+        return -(-n // c) * c
+
+    def _admit_locked(self, rec=None):
         """Fill free rows from the queue: one packed prefill when several
         prompts wait (and packing is on), else per-row prefills.
 
         Admission failures (trace/compile/device errors) fail ONLY the
         requests being admitted — the engine loop and resident rows
         survive (a dead loop thread would deadlock every submitter).
+
+        ``rec`` is the trace recorder while tracing is on (see
+        ``_phase``): a call that admits is then one ``engine.admit`` span
+        with an ``engine.prefill`` child for each prefill it ran.
         """
+        t_admit = _ttrace.now_us() if rec is not None else 0.0
+        admitted_before = self.admissions
+
         def next_live():
             """Policy-head item, retiring requests cancelled while still
             queued (client disconnected before admission: prefilling and
@@ -407,8 +460,18 @@ class ContinuousBatchingEngine:
                 total += len(item["prompt"])
             if len(take) >= 2:
                 try:
-                    last, row_caches = self._packed(
-                        [it["prompt"] for it in take])
+                    for item in take:
+                        self._row_taken(rec, item)
+                    with _phase(rec, "engine.prefill",
+                                {"rid": [it["rid"] for it in take],
+                                 "prompt_len": total,
+                                 "padded_len": self._packed.total_bucket,
+                                 "path": "packed"}
+                                if rec is not None else None):
+                        last, row_caches = self._packed(
+                            [it["prompt"] for it in take])
+                    _PREFILL_PROMPT.inc(total)
+                    _PREFILL_PADDED.inc(self._packed.total_bucket)
                     rowmap = np.zeros((self.B,), np.int32)
                     mask = np.zeros((self.B,), bool)
                     for slot, item in enumerate(take):
@@ -469,45 +532,64 @@ class ContinuousBatchingEngine:
                         item["done"].set()
                     break
             item = self._queue.popleft()
+            self._row_taken(rec, item)
             try:
                 p = item["prompt"]
-                if item.get("prefilled") is not None:
-                    # disaggregated handoff: the prefill ran on another
-                    # replica; its dense row state lands here unchanged
-                    # (bit-identical to what this engine's own prefill
-                    # would produce — serve.disagg pins this)
-                    logits1, caches1 = item["prefilled"]
-                    item["prefilled"] = None  # drop the reference
-                elif seq is not None and seq.matched_tokens:
-                    # prefix-reuse hit: gather the cached blocks into a
-                    # dense row and prefill ONLY the suffix from the
-                    # match offset (gather moves bits unchanged; the
-                    # chunk step masks exactly, so this stays bit-exact)
-                    m = seq.matched_tokens
-                    total = jnp.asarray([len(p)], jnp.int32)
-                    gathered = self._pool.gather_dense(seq)
-                    logits1, caches1 = self.gen._run_chunked_prefill(
-                        [p[m:]], total, 1, caches=gathered, start=m)
-                elif self._prefix is not None:
-                    # suffix-only prefill OVER the shared prefix K/V.
-                    # The handle's arrays are shared read-only: the
-                    # chunk step is functional and non-donating, so the
-                    # handle survives every admission unchanged.
-                    h = self._prefix
-                    total = jnp.asarray([h.length + len(p)], jnp.int32)
-                    logits1, caches1 = self.gen._run_chunked_prefill(
-                        [p], total, 1, caches=h.caches, start=h.length,
-                        init_last=h.last_logits)
-                else:
-                    ids = np.zeros((1, self.bucket), np.int32)
-                    ids[0, :len(p)] = p
-                    caches1 = init_kv_caches(self.gen.config, 1)
-                    logits1, caches1 = self.gen._prefill(
-                        self.gen.params, jnp.asarray(ids), caches1,
-                        jnp.asarray([len(p)], jnp.int32))
-                self._caches, self._logits = self._scatter_row(
-                    self._caches, caches1, self._logits,
-                    logits1.astype(jnp.float32), r)
+                # asked: the tokens this prefill has to compute; padded:
+                # the positions its program runs over
+                with _phase(rec, "engine.prefill") as prefill_span:
+                    if item.get("prefilled") is not None:
+                        # disaggregated handoff: the prefill ran on
+                        # another replica; its dense row state lands here
+                        # unchanged (bit-identical to what this engine's
+                        # own prefill would produce — serve.disagg pins
+                        # this)
+                        path, asked, padded = "handoff", 0, 0
+                        logits1, caches1 = item["prefilled"]
+                        item["prefilled"] = None  # drop the reference
+                    elif seq is not None and seq.matched_tokens:
+                        # prefix-reuse hit: gather the cached blocks into
+                        # a dense row and prefill ONLY the suffix from the
+                        # match offset (gather moves bits unchanged; the
+                        # chunk step masks exactly, so this stays
+                        # bit-exact)
+                        m = seq.matched_tokens
+                        path, asked = "prefix", len(p) - m
+                        padded = self._chunk_padded(asked)
+                        total = jnp.asarray([len(p)], jnp.int32)
+                        gathered = self._pool.gather_dense(seq)
+                        logits1, caches1 = self.gen._run_chunked_prefill(
+                            [p[m:]], total, 1, caches=gathered, start=m)
+                    elif self._prefix is not None:
+                        # suffix-only prefill OVER the shared prefix K/V.
+                        # The handle's arrays are shared read-only: the
+                        # chunk step is functional and non-donating, so
+                        # the handle survives every admission unchanged.
+                        h = self._prefix
+                        path, asked = "prefix", len(p)
+                        padded = self._chunk_padded(asked)
+                        total = jnp.asarray([h.length + len(p)],
+                                            jnp.int32)
+                        logits1, caches1 = self.gen._run_chunked_prefill(
+                            [p], total, 1, caches=h.caches,
+                            start=h.length, init_last=h.last_logits)
+                    else:
+                        path, asked, padded = "dense", len(p), self.bucket
+                        ids = np.zeros((1, self.bucket), np.int32)
+                        ids[0, :len(p)] = p
+                        caches1 = init_kv_caches(self.gen.config, 1)
+                        logits1, caches1 = self.gen._prefill(
+                            self.gen.params, jnp.asarray(ids), caches1,
+                            jnp.asarray([len(p)], jnp.int32))
+                    self._caches, self._logits = self._scatter_row(
+                        self._caches, caches1, self._logits,
+                        logits1.astype(jnp.float32), r)
+                    if rec is not None:
+                        prefill_span.args = {
+                            "rid": item["rid"], "prompt_len": len(p),
+                            "padded_len": padded, "path": path}
+                _PREFILL_PROMPT.inc(asked)
+                _PREFILL_PADDED.inc(padded)
                 if seq is not None:
                     # publish the prompt's full blocks while the row is
                     # still live, so concurrent shared-prefix requests
@@ -526,6 +608,10 @@ class ContinuousBatchingEngine:
                     self._pool.release(seq, register=False)
                 item["error"] = e
                 item["done"].set()
+        n = self.admissions - admitted_before
+        if rec is not None and n:
+            rec.complete("engine.admit", "serving", t_admit,
+                         _ttrace.now_us() - t_admit, {"n": n}, _TRACK)
 
     def _release_table(self, r: int, item: Optional[dict]):
         """Return row ``r``'s blocks to the pool.  A cleanly finished
@@ -551,9 +637,16 @@ class ContinuousBatchingEngine:
     def _run(self):
         while True:
             with self._cv:
-                while not self._stop and (len(self._queue) == 0 and
-                                          not self._active.any()):
-                    self._cv.wait()
+                if not self._stop and len(self._queue) == 0 and \
+                        not self._active.any():
+                    # nothing to do: named, so that a device idle for
+                    # want of requests is not read as the engine's fault
+                    idle = _ttrace.begin("engine.idle", "serving", None,
+                                         _TRACK)
+                    while not self._stop and (len(self._queue) == 0 and
+                                              not self._active.any()):
+                        self._cv.wait()
+                    _ttrace.end(idle)
                 if self._stop:
                     # fail pending work so no submitter deadlocks
                     err = RuntimeError("engine shut down")
@@ -568,16 +661,15 @@ class ContinuousBatchingEngine:
                             self._active[r] = False
                             self._rows[r] = None
                     return
-                self._admit_locked()
+                # the one look at the flag for this turn of the loop
+                rec = _ttrace.get_recorder() if _ttrace.enabled() \
+                    else None
+                self._admit_locked(rec)
             try:
-                if _ttrace.enabled():
-                    with _ttrace.get_recorder().span(
-                            "engine.decode-tick", "serving",
-                            {"active": int(self._active.sum())},
-                            "serve-engine"):
-                        self._step()
-                else:
-                    self._step()
+                with _phase(rec, "engine.decode-tick",
+                            {"active": int(self._active.sum())}
+                            if rec is not None else None):
+                    self._step(rec)
             except Exception as e:  # pylint: disable=broad-except
                 logger.exception("engine step failed")
                 self.step_failures += 1
@@ -591,8 +683,9 @@ class ContinuousBatchingEngine:
                             self._active[r] = False
                             self._rows[r] = None
 
-    def _step(self):
-        """One decode tick for every active row."""
+    def _step(self, rec=None):
+        """One decode tick for every active row.  ``rec``: see ``_phase``;
+        the tick's phases are child spans of ``engine.decode-tick``."""
         fault.fire("scheduler_tick", step=self.decode_steps,
                    active=int(self._active.sum()))
         self._rng, sub = jax.random.split(self._rng)
@@ -603,20 +696,29 @@ class ContinuousBatchingEngine:
                 for r in range(self.B)]
         base = next((c for c in cfgs if c is not None),
                     GenerationConfig())
-        nxt = np.asarray(_sample_logits(self._logits, sub, base)
-                         ).astype(np.int32)
-        for r, c in enumerate(cfgs):
-            if c is not None and dataclasses.astuple(c) != \
-                    dataclasses.astuple(base):
-                self._rng, sub_r = jax.random.split(self._rng)
-                nxt[r] = int(np.asarray(_sample_logits(
-                    self._logits[r:r + 1], sub_r, c))[0])
+        sampled = _sample_logits(self._logits, sub, base)
+        with _phase(rec, "engine.wait"):
+            # the first read-back: the host waits here for the previous
+            # tick's decode (and any prefill behind it) to finish
+            nxt = np.asarray(sampled).astype(np.int32)
+        with _phase(rec, "engine.resample") as resample_span:
+            resampled = 0
+            for r, c in enumerate(cfgs):
+                if c is not None and dataclasses.astuple(c) != \
+                        dataclasses.astuple(base):
+                    self._rng, sub_r = jax.random.split(self._rng)
+                    nxt[r] = int(np.asarray(_sample_logits(
+                        self._logits[r:r + 1], sub_r, c))[0])
+                    resampled += 1
+            if rec is not None:
+                resample_span.args = {"rows": resampled}
 
-        index = self._caches[0][2]          # per-row positions
-        tok = jnp.asarray(nxt[:, None])
-        logits, self._caches = self.gen._decode(
-            self.gen.params, tok, index, self._caches)
-        self._logits = logits.astype(jnp.float32)
+        with _phase(rec, "engine.dispatch"):
+            index = self._caches[0][2]          # per-row positions
+            tok = jnp.asarray(nxt[:, None])
+            logits, self._caches = self.gen._decode(
+                self.gen.params, tok, index, self._caches)
+            self._logits = logits.astype(jnp.float32)
         self.decode_steps += 1
         _DECODE_STEPS.inc()
         if self._pool is not None:
@@ -627,29 +729,34 @@ class ContinuousBatchingEngine:
                                     np.asarray(index))
 
         with self._cv:
-            for r in range(self.B):
-                if not self._active[r]:
-                    continue
-                item = self._rows[r]
-                cfg = item["cfg"]
-                t = int(nxt[r])
-                item["tokens"].append(t)
-                _TOKENS.inc()
-                if len(item["tokens"]) == 1 and "t_submit" in item:
-                    _TTFT.observe(time.monotonic() - item["t_submit"])
-                if item.get("on_token") is not None:
-                    try:
-                        item["on_token"](t)
-                    except Exception:  # pylint: disable=broad-except
-                        logger.exception("on_token callback failed")
-                hit_eos = (cfg.eos_token_id is not None and
-                           t == cfg.eos_token_id)
-                if (hit_eos or item.get("cancelled") or
-                        len(item["tokens"]) >= cfg.max_new_tokens):
-                    self._release_table(r, item)
-                    item["done"].set()
-                    self._active[r] = False
-                    self._rows[r] = None
+            with _phase(rec, "engine.deliver") as deliver_span:
+                delivered = 0
+                for r in range(self.B):
+                    if not self._active[r]:
+                        continue
+                    item = self._rows[r]
+                    cfg = item["cfg"]
+                    t = int(nxt[r])
+                    item["tokens"].append(t)
+                    delivered += 1
+                    _TOKENS.inc()
+                    if len(item["tokens"]) == 1 and "t_submit" in item:
+                        _TTFT.observe(time.monotonic() - item["t_submit"])
+                    if item.get("on_token") is not None:
+                        try:
+                            item["on_token"](t)
+                        except Exception:  # pylint: disable=broad-except
+                            logger.exception("on_token callback failed")
+                    hit_eos = (cfg.eos_token_id is not None and
+                               t == cfg.eos_token_id)
+                    if (hit_eos or item.get("cancelled") or
+                            len(item["tokens"]) >= cfg.max_new_tokens):
+                        self._release_table(r, item)
+                        item["done"].set()
+                        self._active[r] = False
+                        self._rows[r] = None
+                if rec is not None:
+                    deliver_span.args = {"tokens": delivered}
             # refill freed rows before the next tick
-            self._admit_locked()
+            self._admit_locked(rec)
             _ACTIVE_ROWS.set(int(self._active.sum()))

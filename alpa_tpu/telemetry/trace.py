@@ -16,13 +16,22 @@ thread-name metadata, ``i`` instants and ``C`` counters — loadable
 directly in Perfetto / chrome://tracing.  ``merge_chrome_traces``
 combines per-mesh / per-process files onto distinct pids.
 
+``start_capture`` / ``stop_capture`` switch the spans on together with
+``jax.profiler`` in a running process, any number of times, and give the
+offset between this recorder's clock and the profiler's (read from one
+marker span written to both), so that every span, also one written by
+``complete()`` from a pool thread, can be laid over the device events.
+
 Zero-cost-when-off: the module-level ``_ENABLED`` flag (seeded from
 ``ALPA_TPU_TRACE`` via ``global_config.telemetry_enabled``) is checked
 before *any* allocation — ``span()`` returns a shared no-op singleton
 when tracing is off, and the register-file replay checks the flag once
 per step, not per instruction (guarded by a <2% overhead test).
 """
+import dataclasses
+import glob
 import json
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -32,7 +41,8 @@ from alpa_tpu.global_env import global_config
 __all__ = [
     "TraceRecorder", "get_recorder", "set_recorder", "enabled",
     "set_enabled", "span", "instant", "counter", "begin", "end",
-    "now_us", "merge_chrome_traces", "CATEGORIES",
+    "now_us", "merge_chrome_traces", "CATEGORIES", "NULL_SPAN",
+    "Capture", "start_capture", "stop_capture", "CAPTURE_MARKER",
 ]
 
 # category taxonomy (docs/observability.md) — free-form strings are
@@ -70,6 +80,9 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+# for call sites that look at ``enabled()`` once for many spans (the
+# serving engine: once a tick) and hand out the no-op themselves
+NULL_SPAN = _NULL_SPAN
 
 
 class _Span:
@@ -343,3 +356,100 @@ def instant(name: str, category: str = "runtime",
 def counter(name: str, value: float, track: Optional[str] = None):
     if _ENABLED:
         _RECORDER.counter(name, value, track)
+
+
+# ---- capture: spans and the device profiler, on and off together ------
+
+# the one span written both to the recorder and to the profiler's trace
+CAPTURE_MARKER = "alpa.capture"
+_HOST_PLANE = "/host:CPU"
+
+
+@dataclasses.dataclass
+class Capture:
+    """What :func:`stop_capture` returns: the recorder's spans of the
+    capture (dicts as :meth:`TraceRecorder.spans` gives them, the marker
+    among them), where the profiler wrote its trace, and the marker's
+    start on the recorder's clock."""
+    log_dir: str
+    spans: List[Dict[str, Any]]
+    marker_ts_us: float
+    _offset_us: Optional[float] = None
+
+    def xplane_path(self) -> str:
+        found = sorted(glob.glob(os.path.join(
+            self.log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        if not found:
+            raise FileNotFoundError(f"no .xplane.pb under {self.log_dir}")
+        return found[-1]
+
+    def offset_us(self) -> float:
+        """Microseconds to ADD to a recorder timestamp (``ts_us``) to get
+        the same instant on the clock of the profiler's events
+        (``start_ns / 1e3`` of an xplane event, host or device): the
+        marker's start as the profiler saw it minus its start as the
+        recorder saw it.  Reads the trace once and keeps the answer."""
+        if self._offset_us is None:
+            import jax.profiler
+            data = jax.profiler.ProfileData.from_file(self.xplane_path())
+            self._offset_us = _marker_start_ns(data) / 1e3 - \
+                self.marker_ts_us
+        return self._offset_us
+
+
+def _marker_start_ns(data) -> float:
+    for plane in data.planes:
+        if plane.name != _HOST_PLANE:
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                if event.name == CAPTURE_MARKER:
+                    return event.start_ns
+    raise ValueError(f"the profiler's trace holds no {CAPTURE_MARKER!r} "
+                     "event: the offset between the clocks is unknown")
+
+
+# (log_dir, enabled() before, the marker's annotation, its start)
+_CAPTURE: Optional[tuple] = None
+
+
+def start_capture(log_dir: str) -> None:
+    """Start tracing in this process: clear the recorder, switch the
+    spans on, start ``jax.profiler`` writing to ``log_dir`` (no python
+    call stacks; host annotations on), and open the marker span.  End
+    with :func:`stop_capture`; the pair can be used again and again."""
+    global _CAPTURE
+    if _CAPTURE is not None:
+        raise RuntimeError("a capture is already running "
+                           f"(into {_CAPTURE[0]})")
+    import jax.profiler
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    _RECORDER.clear()
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    was = set_enabled(True)
+    note = jax.profiler.TraceAnnotation(CAPTURE_MARKER)
+    note.__enter__()
+    _CAPTURE = (log_dir, was, note, _now_us())
+
+
+def stop_capture() -> Capture:
+    """Close the marker, stop the profiler, put ``enabled()`` back to
+    what :func:`start_capture` found, and return the :class:`Capture`.
+    The recorder keeps its spans until it is cleared."""
+    global _CAPTURE
+    if _CAPTURE is None:
+        raise RuntimeError("no capture is running")
+    import jax.profiler
+    log_dir, was, note, ts = _CAPTURE
+    _CAPTURE = None
+    end = _now_us()
+    note.__exit__(None, None, None)
+    _RECORDER.complete(CAPTURE_MARKER, "runtime", ts, end - ts,
+                       track="capture")
+    try:
+        jax.profiler.stop_trace()
+    finally:
+        set_enabled(was)
+    return Capture(log_dir, _RECORDER.spans(), ts)

@@ -6,6 +6,13 @@ the traffic mix says.
 Every time is taken at the client: a token's time is when its event was
 read from the socket.  An open-loop request is timed from when it was DUE,
 not from when it was sent, so a stall counts for the requests behind it.
+
+``--trace 2`` goes the way of ``--trace 0`` to the end of the window, where
+every number of the window is decided.  Then the same traffic goes on for
+the traced seconds inside a capture of the program: the closed loop's
+callers simply carry on, and an open loop gets arrivals of its own at the
+mix's rate and sizes.  A request sent after the window is of kind
+``after`` and enters no metric of the window.
 """
 import http.client
 import json
@@ -24,16 +31,19 @@ class _Client:
     def __init__(self, ctx, port: int, model: str):
         self.ctx, self.port, self.model = ctx, port, model
         self.records = []
-        self.open = set()           # connections of requests in flight
-        self.closed = False         # set when a closed-loop window ends
+        self.open = {}              # connection -> record, while in flight
+        self.closed = False         # set when the callers are to stop
+        self.kind = "measured"      # of the requests sent from now on
         self.lock = threading.Lock()
 
-    def request(self, req: dict, due: float, kind: str = "measured"):
+    def request(self, req: dict, due: float, kind: str = None):
         rec = {"due": due, "sent": None, "token_times": [], "tokens": [],
                "prompt_ids": req["prompt_ids"],
                "asked": req["max_new_tokens"], "error": None,
                "cut": False, "kind": kind}
         with self.lock:
+            if kind is None:
+                rec["kind"] = self.kind
             self.records.append(rec)
             if self.closed:
                 rec["cut"] = True
@@ -44,7 +54,7 @@ class _Client:
         conn = http.client.HTTPConnection("127.0.0.1", self.port,
                                           timeout=300)
         with self.lock:
-            self.open.add(conn)
+            self.open[conn] = rec
         try:
             with self.ctx.spans.span("request_send"):
                 rec["sent"] = time.perf_counter()
@@ -79,22 +89,35 @@ class _Client:
                 rec["error"] = f"{type(e).__name__}: {e}"
         finally:
             with self.lock:
-                self.open.discard(conn)
+                self.open.pop(conn, None)
             conn.close()
         return rec
 
-    def cut_open_requests(self):
-        """End of a closed-loop window: the requests in flight are cut off,
-        and neither counted as attempted nor failed.  The tokens they
+    def end_window(self, cut: bool):
+        """The measured window ends: requests sent from now on are of kind
+        ``after``.  ``cut`` (a closed loop): the requests in flight are cut
+        off, and neither counted as attempted nor failed.  The tokens they
         streamed inside the window stay in the record: they are work the
         window did."""
         with self.lock:
-            self.closed = True
-            for rec in self.records:
+            self.kind = "after"
+            for rec in self.records if cut else ():
                 if rec["error"] is None and \
                         len(rec["tokens"]) < rec["asked"]:
                     rec["cut"] = True
-            conns = list(self.open)
+
+    def close_cut_requests(self, stop: bool):
+        """Shut the sockets of the requests that are cut off (and of those
+        sent after the window, which nobody waits for); ``stop``: and let
+        no caller send another."""
+        with self.lock:
+            self.closed = self.closed or stop
+            conns = []
+            for conn, rec in self.open.items():
+                if rec["kind"] == "after":
+                    rec["cut"] = True
+                if rec["cut"]:
+                    conns.append(conn)
         for conn in conns:
             try:
                 if conn.sock is not None:
@@ -143,8 +166,9 @@ def _closed_loop(ctx, client, mix, vocab):
     return stop, threads, threads
 
 
-def _open_loop(ctx, client, mix, vocab, t0):
-    schedule = traffic.open_loop(mix, ctx.seed, vocab, ctx.seconds)
+def _open_loop(client, schedule, t0):
+    """Send ``schedule`` (``traffic.open_loop``) with its times from
+    ``t0``."""
     stop = threading.Event()
     threads = []
 
@@ -213,12 +237,16 @@ def run(ctx):
     import jax.numpy as jnp
     from alpa_tpu.model.gpt_model import GPTModel
     from alpa_tpu.serve import get_model, run_controller
+    from alpa_tpu.telemetry import metrics as tmetrics
     from alpa_tpu.telemetry import trace as ttrace
 
     config, mix = ctx.config, ctx.mix
     gcfg = program.gpt_config(config)
     vocab = gcfg.vocab_size
-    ttrace.set_enabled(ctx.trace)
+    # the program's spans: all through a traced run of its own, and only
+    # inside the capture of --trace 2
+    ttrace.set_enabled(ctx.trace == 1)
+    registry = tmetrics.get_registry()
     timers = {}
 
     # the weights: on the device, from the seed, in one jitted call
@@ -236,6 +264,7 @@ def run(ctx):
         if "wpe" not in jax.tree_util.keystr(path))
 
     name = config["name"]
+    closed = mix["kind"] == "closed_loop"
     server = run_controller(port=0)
     engine = None
     try:
@@ -254,14 +283,17 @@ def run(ctx):
             observe.CompileEvents.COMPILE, 0)
         trace = program.DeviceTrace(ctx) if ctx.trace else None
         setup_s = observe.seconds_since_process_start()
+        counters_t0 = registry.snapshot()
         window_t0_us = ttrace.now_us()
         t0 = time.perf_counter()
         # (stop, the threads that send, the threads that wait for answers)
-        if mix["kind"] == "closed_loop":
+        if closed:
             stop, senders, workers = _closed_loop(ctx, client, mix, vocab)
         else:
-            stop, senders, workers = _open_loop(ctx, client, mix, vocab, t0)
-        if trace is not None:
+            stop, senders, workers = _open_loop(
+                client, traffic.open_loop(mix, ctx.seed, vocab, ctx.seconds),
+                t0)
+        if ctx.trace == 1:
             _sleep_until(t0 + min(mix["trace_after_s"], ctx.seconds / 2))
             trace.start()
             _sleep_until(time.perf_counter() + mix["trace_seconds"])
@@ -269,17 +301,34 @@ def run(ctx):
         _sleep_until(t0 + ctx.seconds)
         t1 = time.perf_counter()
         window_t1_us = ttrace.now_us()
+        client.end_window(cut=closed)
+        counters = (counters_t0, registry.snapshot())
         compiles_in_window = ctx.compile_events.counts.get(
             observe.CompileEvents.COMPILE, 0) - compiles_before
         memory = observe.device_memory(jax.local_devices())
 
+        if ctx.trace == 2:
+            # the same traffic for the traced seconds, inside a capture
+            trace.warm_up()
+            trace.start()
+            if not closed:
+                more = traffic.open_loop(mix, ctx.seed + 1, vocab,
+                                         mix["trace_seconds"])
+                stop_more, send_more, _ = _open_loop(
+                    client, more, time.perf_counter())
+                senders = senders + send_more
+            _sleep_until(time.perf_counter() + mix["trace_seconds"])
+            trace.stop()
+            if not closed:
+                stop_more.set()
+        memory_run = observe.device_memory(jax.local_devices())
+
         stop.set()
-        if mix["kind"] == "closed_loop":
-            client.cut_open_requests()
+        client.close_cut_requests(stop=closed)
         for t in senders:
             t.join(timeout=30)
         # the drain: requests that were due get a stated time to finish
-        deadline = time.perf_counter() + mix["drain_s"]
+        deadline = t1 + mix["drain_s"]
         for t in list(workers):
             t.join(timeout=max(0.0, deadline - time.perf_counter()))
         drain_end = time.perf_counter()
@@ -301,6 +350,18 @@ def run(ctx):
     # for the record: any other statistic of the waits can be had from it
     ctx.info({"info": "ttft_ms", "sorted": sorted(
         round(w * 1e3, 3) for w in stats.ttft_waits(requests, drain_end))})
+    # where in the window the process stood still, if it did: the latest
+    # sends and the longest silences between any two tokens,
+    # [seconds, at which second of the window]
+    times = sorted(t for r in requests for t in r["token_times"]
+                   if t0 <= t <= t1)
+    ctx.info({"info": "stalls",
+              "latest_sends": sorted(
+                  ([r["sent"] - r["due"], r["due"] - t0] for r in requests
+                   if r["sent"] is not None), reverse=True)[:3],
+              "longest_silences": sorted(
+                  ([b - a, a - t0] for a, b in zip(times, times[1:])),
+                  reverse=True)[:3]})
     checks = _check(ctx, generator.params, client.records, config)
     checks["compiles_in_window"] = compiles_in_window
     checks["errors"] = sorted({r["error"] for r in records
@@ -320,8 +381,17 @@ def run(ctx):
         "engine_rows": engine.B,
         "weight_bytes": weight_bytes,
         "cache_itemsize": jnp.dtype(gcfg.dtype).itemsize,
-        "program_spans": ttrace.get_recorder().spans() if ctx.trace else [],
+        "program_spans": trace.program_spans() if trace else [],
         "program_window_us": (window_t0_us, window_t1_us),
+        "counters": counters,
         "memory": memory,
+        "memory_run": memory_run,
         "device_trace": trace.summary() if trace else None,
+        # what the readers of spans see in place of the window's: the
+        # traced interval, and every request that streamed in it
+        "traced": {"window": trace.interval,
+                   "program_window_us": trace.interval_us,
+                   "requests": [r for r in client.records
+                                if r["kind"] != "warmup"]}
+        if ctx.trace == 2 else {},
     }

@@ -6,7 +6,13 @@ Order of a run: plan and compile from shapes (``get_executable``); create
 the state already placed (``CreateStateParallel``); the plain reference's
 loss on the state's own parameters and the first batch; one warm-up step on
 that batch, whose loss is compared with the reference's; the window; in a
-traced run, a few more steps under jax's profiler.
+traced run (``--trace 1`` or ``2``), ``trace_steps`` more steps inside a
+capture of the program (its spans and jax's profiler).
+
+The program's own spans are on while the step is planned and compiled (the
+planner's ``compile`` spans are what ``plan_s`` reads) and off from the
+warm-up step on, in ``--trace 0`` and ``--trace 2`` alike; ``--trace 1``
+keeps them on through its window, as it always has.
 """
 import math
 import time
@@ -58,6 +64,7 @@ def run(ctx):
     from alpa_tpu.create_state_parallel import CreateStateParallel
     from alpa_tpu.model.gpt_model import GPTModel
     from alpa_tpu.model.model_util import gpt_lm_loss
+    from alpa_tpu.telemetry import metrics as tmetrics
     from alpa_tpu.telemetry import trace as ttrace
 
     config, mix = ctx.config, ctx.mix
@@ -97,9 +104,8 @@ def run(ctx):
                 jax.tree_util.tree_map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), first))
 
-    # the program's own spans (ILP, stage construction, per-op dispatch)
-    # are recorded in the traced run only
-    ttrace.set_enabled(ctx.trace)
+    # the program's own spans: on for planning and compiling in every mode
+    ttrace.set_enabled(True)
     timers = {}
     tic = time.perf_counter()
     executable, _ = train_step.get_executable(*abstract)
@@ -129,6 +135,10 @@ def run(ctx):
     del weights
     timers["reference_s"] = time.perf_counter() - tic
 
+    # ... and off from here on, but in a traced run of its own
+    ttrace.set_enabled(ctx.trace == 1)
+    setup_spans = ttrace.get_recorder().spans()
+    ttrace.get_recorder().clear()
     tic = time.perf_counter()
     state, loss = train_step(state, first)
     jax.block_until_ready((state, loss))
@@ -151,7 +161,9 @@ def run(ctx):
             jax.block_until_ready((state, loss))
         return state, loss, (called, returned, time.perf_counter())
 
+    registry = tmetrics.get_registry()
     setup_s = observe.seconds_since_process_start()
+    counters_t0 = registry.snapshot()
     window_t0_us = ttrace.now_us()
     t0 = time.perf_counter()
     while not steps or steps[-1][2] < ctx.seconds:
@@ -159,20 +171,36 @@ def run(ctx):
         steps.append(tuple(t - t0 for t in times))
         losses.append(loss)
     window_t1_us = ttrace.now_us()
+    counters = (counters_t0, registry.snapshot())
     compiles_in_window = ctx.compile_events.counts.get(
         observe.CompileEvents.COMPILE, 0) - compiles_before
     memory = observe.device_memory(jax.local_devices())
+    ctx.info({"info": "dispatch", "mode": (getattr(
+        executable, "last_dispatch_stats", None) or {}).get("mode")})
+    # every step of the window [call to return, call to done], for the
+    # record: a host that was slow for a while shows here
+    ctx.info({"info": "steps", "ms": [
+        [round((ret - call) * 1e3, 1), round((done - call) * 1e3, 1)]
+        for call, ret, done in steps]})
 
-    # the device trace: a few more steps straight after the window, so that
+    # the capture: a few more steps straight after the window, so that
     # the profiler's own cost is in none of the window's host timings
-    trace = None
+    trace, traced_steps = None, []
     if ctx.trace:
         trace = program.DeviceTrace(ctx)
+        if ctx.trace == 2:
+            trace.warm_up()
         trace.start()
         for _ in range(mix["trace_steps"]):
-            state, loss, _ = one_step(state)
+            state, loss, times = one_step(state)
+            traced_steps.append(tuple(t - t0 for t in times))
             losses.append(loss)
         trace.stop()
+        # a step inside a capture against one outside: what tracing costs
+        ctx.info({"info": "traced_steps",
+                  "step_s": [t[2] - t[0] for t in traced_steps],
+                  "call_s": [t[1] - t[0] for t in traced_steps]})
+    memory_run = observe.device_memory(jax.local_devices())
 
     losses = [float(x) for x in losses]
     finite = [math.isfinite(x) for x in [first_loss] + losses]
@@ -198,10 +226,16 @@ def run(ctx):
         "train_flops_per_token": arithmetic.decoder_train_flops_per_token(
             gcfg.hidden_size, gcfg.num_layers, gcfg.seq_len,
             gcfg.vocab_size),
-        "program_spans": ttrace.get_recorder().spans() if ctx.trace else [],
+        "program_spans": setup_spans + trace.program_spans() if trace
+        else setup_spans,
         "program_window_us": (window_t0_us, window_t1_us),
+        "counters": counters,
         "memory": memory,
+        "memory_run": memory_run,
         "device_trace": trace.summary() if trace else None,
+        # what the readers of spans see in place of the window's
+        "traced": {"program_window_us": trace.interval_us}
+        if ctx.trace == 2 else {},
     }
 
 

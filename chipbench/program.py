@@ -2,6 +2,7 @@
 configuration file turned into the program's ``GPTConfig``, a seed turned
 into a jax key, and the profiler switched on and off."""
 import shutil
+import time
 
 
 def key_from_seed(seed: int):
@@ -42,41 +43,68 @@ def reference_settings(config: dict) -> dict:
 
 
 class DeviceTrace:
-    """jax's profiler around a part of the measured window.  ``start`` and
-    ``stop`` are called by a driver; ``summary`` reduces the trace with
-    ``chipbench.xplane`` after the window."""
+    """The program's capture control (``alpa_tpu.telemetry.trace``
+    ``start_capture`` / ``stop_capture``: its spans and jax's profiler, on
+    and off together) around a part of a run.  ``start`` and ``stop`` are
+    called by a driver; ``summary`` reduces the trace with
+    ``chipbench.xplane`` afterwards and deletes it."""
 
     def __init__(self, ctx):
+        from alpa_tpu.telemetry import trace
+        self.ttrace = trace
         self.ctx = ctx
         self.dir = ctx.trace_dir
-        self.done = False
+        self.capture = None
+        # the traced interval: on time.perf_counter, and the same instants
+        # on the clock of the program's recorder
+        self.interval = self.interval_us = None
+        self._before = []           # the recorder's spans start() cleared
         self._window = None
 
-    def start(self):
-        import jax.profiler
+    def warm_up(self):
+        """Start and stop the profiler once and throw the trace away: the
+        first start costs seconds, which then fall into no number."""
+        self.ttrace.start_capture(self.dir)
+        self.ttrace.stop_capture()
+        self.ttrace.get_recorder().clear()
         shutil.rmtree(self.dir, ignore_errors=True)
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0     # no python call stacks
-        options.host_tracer_level = 2       # the benchmark's annotations
-        jax.profiler.start_trace(self.dir, profiler_options=options)
+
+    def start(self):
+        shutil.rmtree(self.dir, ignore_errors=True)
+        self._before = self.ttrace.get_recorder().spans()
+        self.ttrace.start_capture(self.dir)
         self.ctx.spans.annotate = True
         self._window = self.ctx.spans.span("traced_window")
         self._window.__enter__()
+        self._tic = (time.perf_counter(), self.ttrace.now_us())
 
     def stop(self):
-        import jax.profiler
+        toc = (time.perf_counter(), self.ttrace.now_us())
+        self.interval = (self._tic[0], toc[0])
+        self.interval_us = (self._tic[1], toc[1])
         self._window.__exit__(None, None, None)
         self.ctx.spans.annotate = False
-        jax.profiler.stop_trace()
-        self.done = True
+        self.capture = self.ttrace.stop_capture()
+
+    def program_spans(self) -> list:
+        """The program's spans of the whole run: what the recorder held
+        when the capture began, and all it has held since."""
+        return self._before + self.ttrace.get_recorder().spans()
 
     def summary(self):
         from chipbench import xplane
-        if not self.done:
+        if self.capture is None:
             return None
         try:
-            return xplane.reduce_trace(self.dir)
+            shift = self.capture.offset_us()
+            program = [(s["name"], (s["ts_us"] + shift) * 1e3,
+                        (s["ts_us"] + s["dur_us"] + shift) * 1e3)
+                       for s in self.capture.spans
+                       if s["name"] != self.ttrace.CAPTURE_MARKER]
+            return xplane.reduce_trace(self.dir, program)
         except ValueError:
             if self.ctx.rehearsal:   # a CPU trace has no TPU plane
                 return None
             raise
+        finally:
+            shutil.rmtree(self.dir, ignore_errors=True)

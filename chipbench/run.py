@@ -1,6 +1,6 @@
 """Run one cell of the benchmark once and print its result line.
 
-    python3 -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json`` (or, for the CPU
 rehearsal, of ``chipbench/rehearsal.json``).  Everything that belongs to one
@@ -16,6 +16,23 @@ The last line of standard output is the result: one JSON object with
 ``busy_s`` and ``window_s`` of the traced seconds or steps.  Earlier lines
 (``{"info": ...}``) are for the record.  Without a TPU, or with another
 number of chips than the cell asks for, the run fails and prints no result.
+
+``--trace 2`` is a ``--trace 0`` run followed by a short traced part in the
+same process.  Up to the end of the measured window it does what
+``--trace 0`` does, and ``correct``, ``attempted``, ``failed`` and the
+end-to-end metrics are the window's.  Then it starts and stops the
+program's capture once (thrown away), captures ``trace_steps`` more steps
+or ``trace_seconds`` more seconds of the same traffic, and prints both
+kinds of metric side by side in ``metrics``; ``device`` holds ``busy_s``
+and ``window_s`` of the traced part and the memory peak of the whole run.
+
+What a reader is given (``obs``): in every mode ``obs["counters"]`` is the
+program's metrics registry at the window's start and end (two
+``snapshot()`` dicts).  In ``--trace 2`` the program's spans exist only in
+the traced part, so a per-layer metric whose ``source`` is ``program_span``
+or ``device_trace`` is handed the traced interval as its ``window`` and
+``program_window_us`` (and, serving, every request that streamed in it as
+``requests``); every other reader sees the measured window.
 """
 import argparse
 import dataclasses
@@ -87,7 +104,7 @@ class Context:
     mix: dict
     seed: int
     seconds: float
-    trace: bool
+    trace: int      # 0, 1 (a traced run of its own) or 2 (traced after)
     rehearsal: bool
     spans: Any
     compile_events: Any
@@ -107,7 +124,8 @@ def main(argv=None) -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, required=True)
-    parser.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    parser.add_argument("--trace", type=int, choices=(0, 1, 2),
+                        required=True)
     args = parser.parse_args(argv)
 
     bench, cell, rehearsal = find_cell(args.workload)
@@ -115,7 +133,7 @@ def main(argv=None) -> int:
     reports_as = cell.get("as", cell["name"])
     config = load_json(HERE, "configs", cell["config"] + ".json")
 
-    from chipbench import observe, peaks, traffic
+    from chipbench import counters, observe, peaks, stats, traffic
     mix = traffic.load_mix(cell["traffic"])
 
     import jax
@@ -143,7 +161,7 @@ def main(argv=None) -> int:
     chip_peaks = None if rehearsal else peaks.peaks_for(device["kind"])
 
     ctx = Context(cell=cell, config=config, mix=mix, seed=args.seed,
-                  seconds=args.seconds, trace=bool(args.trace),
+                  seconds=args.seconds, trace=args.trace,
                   rehearsal=rehearsal, spans=observe.Spans(),
                   compile_events=compile_events,
                   trace_dir=os.path.join(ROOT, ".chipbench_trace",
@@ -156,14 +174,23 @@ def main(argv=None) -> int:
                peaks=chip_peaks, chips=len(devices), seconds=args.seconds,
                config=config, mix=mix)
 
-    group = "per_layer" if args.trace else "end_to_end"
+    # the readers of spans and of the device trace see the traced part
+    traced_obs = {**obs, **obs["traced"]}
+    groups = {0: ["end_to_end"], 1: ["per_layer"],
+              2: ["end_to_end", "per_layer"]}[args.trace]
     metrics = {}
-    for entry in metrics_of(bench, group, reports_as):
-        value = metric_reader(entry["name"])(obs)
-        if value is not None:   # a reader that finds nothing reports nothing
-            metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    for group in groups:
+        for entry in metrics_of(bench, group, reports_as):
+            view = traced_obs if group == "per_layer" and entry["source"] \
+                in ("program_span", "device_trace") else obs
+            value = metric_reader(entry["name"])(view)
+            # a reader that finds nothing reports nothing
+            if value is not None:
+                metrics[entry["name"]] = {"value": value,
+                                          "unit": entry["unit"]}
 
-    peak = max((m["peak_bytes_in_use"] or 0) for m in obs["memory"])
+    # the peak of the whole run: read again after the traced part
+    peak = max((m["peak_bytes_in_use"] or 0) for m in obs["memory_run"])
     device["memory_peak_bytes"] = peak
     line = {"correct": obs["correct"], "attempted": obs["attempted"],
             "failed": obs["failed"], "metrics": metrics, "device": device}
@@ -174,11 +201,24 @@ def main(argv=None) -> int:
         device["busy_s_by_chip"] = summary["busy_s_by_chip"]
         line["breakdown"] = {"device_ops": summary["device_ops"],
                              "idle_gaps": summary["idle_gaps"]}
+        ctx.info({"info": "uncovered", "s_at_s": summary["uncovered"]})
         # each program's runs on the device: [how many, median s, total s]
         ctx.info({"info": "program_runs", **{
             name: [len(secs), secs[len(secs) // 2], sum(secs)]
             for name, secs in sorted(summary["program_runs"].items(),
                                      key=lambda kv: -sum(kv[1]))[:10]}})
+    if args.trace:
+        # the program's spans of the traced part, by name:
+        # [how many, total ms]
+        table = {}
+        for span in stats.program_spans(traced_obs, ""):
+            row = table.setdefault(span["name"], [0, 0.0])
+            row[0] += 1
+            row[1] += span["dur_us"] / 1e3
+        ctx.info({"info": "program_spans", **dict(sorted(
+            table.items(), key=lambda kv: -kv[1][1])[:16])})
+    # what the program's registry counted over the window
+    ctx.info({"info": "counters", **counters.moved(obs)})
     ctx.info({"info": "checks", **obs["checks"]})
     ctx.info({"info": "timers", **obs["timers"],
               "compile": compile_events.snapshot()})

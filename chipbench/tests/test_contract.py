@@ -1,5 +1,7 @@
 """BENCHMARK.json against the files it names: every configuration, mix,
 metric, reference and driver is found by its name."""
+import os
+
 import pytest
 
 from chipbench import run, stats, traffic
@@ -28,6 +30,21 @@ def test_metric_has_a_reader(entry):
     assert callable(run.metric_reader(entry["name"]))
     cells = {c["name"] for c in BENCH["workloads"]}
     assert set(entry.get("workloads", cells)) <= cells
+
+
+def test_tracing_is_in_the_runs_that_measure():
+    assert BENCH["trace_in_run"] is True
+    # PR 24's metrics, added at the end of the list, each with a reader of
+    # its own name (test_metric_has_a_reader) and a layer that was there
+    new = ["reshard_wait_ms_per_step", "reshard_busy_ms_per_step",
+           "reshard_mb_per_step", "driver_launch_ms", "tick_host_ms",
+           "queue_wait_ms", "prefill_useful_pct"]
+    assert [m["name"] for m in BENCH["per_layer"]][-len(new):] == new
+    layers = {m["layer"] for m in BENCH["per_layer"][:-len(new)]}
+    for m in BENCH["per_layer"][-len(new):]:
+        assert m["layer"] in layers
+        assert os.path.exists(os.path.join(run.HERE, "metrics",
+                                           m["name"] + ".py"))
 
 
 def test_per_layer_moves_a_metric_of_the_same_cells():

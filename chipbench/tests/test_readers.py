@@ -63,3 +63,78 @@ def test_split_metrics_share_the_reader_of_their_stem():
     # a file of the full name wins over the stem's
     assert "enqueueing one step" in \
         run.metric_reader("host_dispatch_ms.train").__globals__["__doc__"]
+
+
+def _span(name, ts, dur, **args):
+    return {"name": name, "category": "x", "ts_us": ts, "dur_us": dur,
+            "args": args or None}
+
+
+def test_counter_readers_read_the_rise_over_the_window():
+    before = {"alpa_overlap_steps_total": 3.0,
+              "alpa_overlap_wait_blocked_seconds_total": 1.0,
+              "alpa_overlap_transfer_busy_seconds_total": 2.0,
+              "alpa_mesh_dispatch_seconds": {"count": 10, "sum": 1.0},
+              "alpa_serving_prefill_prompt_tokens_total": 100.0,
+              "alpa_serving_prefill_padded_tokens_total": 2048.0}
+    after = {"alpa_overlap_steps_total": 7.0,
+             "alpa_overlap_wait_blocked_seconds_total": 1.8,
+             "alpa_overlap_transfer_busy_seconds_total": 6.0,
+             "alpa_mesh_dispatch_seconds": {"count": 30, "sum": 1.5},
+             "alpa_pipeshard_dispatch_seconds": {"count": 4, "sum": 8.0},
+             "alpa_serving_queue_wait_seconds": {"count": 5, "sum": 0.25},
+             "alpa_serving_prefill_prompt_tokens_total": 612.0,
+             "alpa_serving_prefill_padded_tokens_total": 6144.0}
+    obs = {"counters": (before, after)}
+    read = run.metric_reader
+    assert read("reshard_wait_ms_per_step")(obs) == pytest.approx(200.0)
+    assert read("reshard_busy_ms_per_step")(obs) == pytest.approx(1000.0)
+    # the pipeshard histogram ticked, so it is the step's; else one mesh's
+    assert read("driver_launch_ms")(obs) == pytest.approx(2000.0)
+    after.pop("alpa_pipeshard_dispatch_seconds")
+    assert read("driver_launch_ms")(obs) == pytest.approx(25.0)
+    assert read("queue_wait_ms")(obs) == pytest.approx(50.0)
+    assert read("prefill_useful_pct")(obs) == pytest.approx(12.5)
+
+
+NEW_COUNTER_METRICS = ["reshard_wait_ms_per_step", "reshard_busy_ms_per_step",
+                       "driver_launch_ms", "queue_wait_ms",
+                       "prefill_useful_pct"]
+
+
+@pytest.mark.parametrize("name", NEW_COUNTER_METRICS)
+def test_counter_readers_find_nothing_where_the_program_has_no_series(name):
+    still = {"alpa_overlap_steps_total": 2.0,
+             "alpa_mesh_dispatch_seconds": {"count": 1, "sum": 1.0},
+             "alpa_serving_prefill_padded_tokens_total": 9.0}
+    for obs in ({}, {"counters": ({}, {})}, {"counters": (still, still)}):
+        assert run.metric_reader(name)(obs) is None
+
+
+def test_span_readers_of_the_new_metrics():
+    spans = [
+        _span("pipeshard.step", 0, 100, step=0),
+        _span("reshard.edge", 10, 5, bytes=4_000_000, fast=True),
+        _span("reshard.edge-group", 50, 5, bytes=2_000_000),
+        _span("pipeshard.step", 200, 100, step=1),
+        _span("reshard.edge", 210, 5, bytes=5_000_000, fast=True),
+        _span("reshard.edge", 900, 5, bytes=7_000_000, fast=True),
+        _span("engine.decode-tick", 1000, 40, active=4),
+        _span("engine.wait", 1002, 30),
+        _span("engine.decode-tick", 1100, 50, active=4),
+        _span("engine.wait", 1101, 20),
+        _span("engine.decode-tick", 1200, 45, active=4),
+        _span("engine.wait", 1203, 33)]
+    obs = {"program_spans": spans, "program_window_us": (0, 2000)}
+    # 6 MB in the first step, 5 in the second; the edge outside any step
+    # is no step's
+    assert run.metric_reader("reshard_mb_per_step")(obs) == \
+        pytest.approx(5.5)
+    # ticks less their waits: 10, 30, 12
+    assert run.metric_reader("tick_host_ms")(obs) == pytest.approx(0.012)
+    # handed a narrower interval, the readers see only what began in it
+    obs["program_window_us"] = (1050, 1150)
+    assert run.metric_reader("tick_host_ms")(obs) == pytest.approx(0.030)
+    assert run.metric_reader("reshard_mb_per_step")(obs) is None
+    assert run.metric_reader("tick_host_ms")(
+        {"program_spans": [], "program_window_us": (0, 1)}) is None

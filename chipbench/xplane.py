@@ -3,6 +3,13 @@ benchmark reports: busy and idle seconds of each chip, the device
 operations that took most time (summed by kind and result type), and the
 idle gaps by what the host was doing.  Read with ``jax.profiler.ProfileData``, nothing else.
 
+An idle gap is named by spans: the benchmark's own, and the program's
+``TraceRecorder`` spans of a capture, which the caller has shifted onto the
+profiler's clock (``trace.Capture.offset_us``).  Each instant of a gap goes
+to the SHORTEST span open at that instant, on whatever thread, so a span
+keeps only the time none of its children (and no shorter span elsewhere)
+covers, and a gap that straddles two phases is split between them.
+
 A TPU's plane is named ``/device:TPU:<n>``.  Its line ``XLA Ops`` holds one
 event per executed HLO operation; ``XLA Modules`` holds one event per run of
 a compiled program, named ``jit_<function>(<fingerprint>)``, from its first
@@ -11,7 +18,9 @@ the busy time again, but give each program's time on the device.  Host threads a
 plane ``/host:CPU``; the benchmark's own spans appear there as events named
 ``chipbench.<span>`` (see ``observe.Spans``), on the same clock.
 """
+import bisect
 import glob
+import heapq
 import os
 import re
 
@@ -51,6 +60,48 @@ def _clip(intervals, lo, hi):
 
 def _overlap(a0, a1, b0, b1):
     return max(0.0, min(a1, b1) - max(a0, b0))
+
+
+def shortest_cover(spans: list) -> list:
+    """Sorted, non-overlapping [(start, end, name)]: over each stretch the
+    name of the shortest of the spans (name, start, end) open there.  Where
+    no span is open there is no entry.  Two spans of one length: the name
+    first in the alphabet, so that the answer does not depend on order."""
+    spans = sorted((s, e, n) for n, s, e in spans if e > s)
+    points = sorted({t for s, e, _ in spans for t in (s, e)})
+    cover, open_, nxt = [], [], 0       # open_: heap of (length, name, end)
+    for lo, hi in zip(points, points[1:]):
+        while nxt < len(spans) and spans[nxt][0] <= lo:
+            s, e, n = spans[nxt]
+            heapq.heappush(open_, (e - s, n, e))
+            nxt += 1
+        while open_ and open_[0][2] <= lo:
+            heapq.heappop(open_)
+        if not open_:
+            continue
+        name = open_[0][1]
+        if cover and cover[-1][2] == name and cover[-1][1] == lo:
+            cover[-1][1] = hi
+        else:
+            cover.append([lo, hi, name])
+    return [tuple(c) for c in cover]
+
+
+def gap_shares(cover: list, starts: list, g0, g1) -> dict:
+    """{name: nanoseconds} of the gap [g0, g1] by ``cover`` (as
+    ``shortest_cover`` gives it; ``starts`` its start times); what no span
+    covers goes to ``unattributed``."""
+    shares, named = {}, 0.0
+    i = max(0, bisect.bisect_right(starts, g0) - 1)
+    while i < len(cover) and cover[i][0] < g1:
+        d = _overlap(g0, g1, cover[i][0], cover[i][1])
+        if d > 0:
+            shares[cover[i][2]] = shares.get(cover[i][2], 0.0) + d
+            named += d
+        i += 1
+    if g1 - g0 > named:
+        shares[UNATTRIBUTED] = (g1 - g0) - named
+    return shares
 
 
 def op_label(hlo: str) -> str:
@@ -111,13 +162,15 @@ def program_runs(modules: dict, lo: float, hi: float) -> dict:
 
 
 def reduce_events(device: dict, host: list, modules: dict = None,
-                  top: int = 10) -> dict:
+                  top: int = 10, program: list = ()) -> dict:
     """The summary of one traced window.  The window is the benchmark's
     span ``chipbench.traced_window`` where the trace holds it, else from
     the first to the last device event.  Chips with no event at all in the
     window count as idle all through it.  All seconds are means over the
     chips in ``device``; ``program_runs`` holds the seconds of every single
-    run."""
+    run.  ``program`` holds the program's own spans (name, start_ns,
+    end_ns), already on the trace's clock: they name idle gaps beside the
+    benchmark's spans in ``host``."""
     if not device or not any(device.values()):
         raise ValueError("the trace holds no device operation")
     window = [(s, e) for n, s, e in host if n == WINDOW_SPAN]
@@ -126,8 +179,17 @@ def reduce_events(device: dict, host: list, modules: dict = None,
     else:
         lo = min(s for ev in device.values() for _, s, _e in ev)
         hi = max(e for ev in device.values() for _, _s, e in ev)
-    labels = [(n[len(SPAN_PREFIX):], s, e) for n, s, e in host
-              if n != WINDOW_SPAN]
+    cover = shortest_cover(
+        [(n[len(SPAN_PREFIX):], s, e) for n, s, e in host
+         if n != WINDOW_SPAN] + list(program))
+    cover_starts = [c[0] for c in cover]
+    # the longest stretches of the window that no span covers, [seconds,
+    # at which second of the window]: where "unattributed" comes from
+    covered = union(_clip([(s, e) for s, e, _ in cover], lo, hi))
+    edges = [lo] + [t for iv in covered for t in iv] + [hi]
+    bare = sorted(([(g1 - g0) / 1e9, (g0 - lo) / 1e9]
+                   for g0, g1 in zip(edges[0::2], edges[1::2]) if g1 > g0),
+                  reverse=True)[:5]
     n_chips = len(device)
     busy_by_chip, op_ns, op_count, gap_ns = {}, {}, {}, {}
     for chip, events in device.items():
@@ -143,18 +205,9 @@ def reduce_events(device: dict, host: list, modules: dict = None,
         for g0, g1 in zip(edges[0::2], edges[1::2]):
             if g1 <= g0:
                 continue
-            # the gap goes to the span that covers most of it; the part no
-            # span covers, where it is the largest part, to "unattributed"
-            cover = {}
-            for name, s, e in labels:
-                d = _overlap(g0, g1, s, e)
-                if d > 0:
-                    cover[name] = cover.get(name, 0.0) + d
-            name, covered = max(cover.items(), key=lambda kv: kv[1],
-                                default=(UNATTRIBUTED, 0.0))
-            if covered < (g1 - g0) / 2:
-                name = UNATTRIBUTED
-            gap_ns[name] = gap_ns.get(name, 0.0) + (g1 - g0)
+            # each instant of the gap goes to the shortest span open then
+            for name, ns in gap_shares(cover, cover_starts, g0, g1).items():
+                gap_ns[name] = gap_ns.get(name, 0.0) + ns
 
     def ranked(table):
         rows = sorted(table.items(), key=lambda kv: -kv[1])[:top]
@@ -169,9 +222,11 @@ def reduce_events(device: dict, host: list, modules: dict = None,
         "device_ops": [[f"{label} x{round(op_count[label] / n_chips)}", sec]
                        for label, sec in ranked(op_ns)],
         "idle_gaps": ranked(gap_ns),
+        "uncovered": bare,
         "program_runs": program_runs(modules or {}, lo, hi),
     }
 
 
-def reduce_trace(trace_dir: str) -> dict:
-    return reduce_events(*read_trace(find_xplane(trace_dir)))
+def reduce_trace(trace_dir: str, program: list = ()) -> dict:
+    return reduce_events(*read_trace(find_xplane(trace_dir)),
+                         program=program)

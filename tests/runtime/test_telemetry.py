@@ -427,6 +427,18 @@ class TestTracedPipeshard:
         assert "pipeshard.step" in all_names
         assert any(n in ("ilp-solve", "ilp-cache-replay")
                    for n in all_names)
+        # each step carries its running number, and its two phases (placing
+        # the inputs, the replay) lie inside it, one after the other
+        spans = fresh_trace.spans()
+        steps = [s for s in spans if s["name"] == "pipeshard.step"]
+        assert [s["args"]["step"] for s in steps] == [0, 1]
+        for name in ("pipeshard.place-inputs", "pipeshard.replay"):
+            inner = [s for s in spans if s["name"] == name]
+            assert len(inner) == len(steps)
+            for step_span, s in zip(steps, inner):
+                assert step_span["ts_us"] <= s["ts_us"] and \
+                    s["ts_us"] + s["dur_us"] <= \
+                    step_span["ts_us"] + step_span["dur_us"]
         # the transfer in-flight window rides a counter track
         assert any(e.get("ph") == "C" and
                    e["name"] == "transfers_in_flight"
