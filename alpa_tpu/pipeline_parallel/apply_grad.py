@@ -9,7 +9,9 @@ Analog of ref ``alpa/pipeline_parallel/apply_grad.py`` (SURVEY.md §2.4):
 * ``apply_grad_get_mean`` (ref :650) — divide accumulated values by the
   number of microbatches,
 * ``process_apply_gradient`` (ref :591) — partition the apply_grad eqns
-  across meshes following the placement of the gradients they consume.
+  across meshes: forward from the gradients and parameters they consume,
+  backward from their readers for what has no placed input, so that the
+  optimizer's moments live on the mesh of their parameter.
 """
 import dataclasses
 import logging
@@ -148,6 +150,75 @@ def apply_partition_is_acyclic(comps: List[JaxPipelineComputation]) -> bool:
     return all(visit(m) for m in range(len(comps)))
 
 
+def _numel(v: Var) -> float:
+    return float(np.prod(getattr(v.aval, "shape", ())))
+
+
+def assign_apply_grad_meshes(apply_eqns: List,
+                             var_mesh: Dict[Var, int]) -> List[int]:
+    """The mesh of every apply-grad eqn, from the eqns' data flow alone
+    (ref propagate_mesh_assignment).
+
+    Forward, in program order: an eqn goes to the mesh holding its largest
+    placed input; one with no placed input stays open.  Backward, in
+    reverse order: an open eqn goes to the mesh that reads most bytes of
+    its results (lowest mesh on a tie), which places its results and so
+    makes the readers of its own inputs known.  The two sweeps alternate
+    to a fixed point, so that ``decay * mu`` lands beside the gradient
+    term it is added to, and ``mu`` itself (placed at launch on the mesh
+    of its first reader) beside its parameter.  What nothing placed reads
+    (``step + 1``) goes to mesh 0.
+    """
+    placed = dict(var_mesh)
+    eqn_mesh: List[Optional[int]] = [None] * len(apply_eqns)
+    readers: Dict[Var, List[int]] = {}
+    for i, e in enumerate(apply_eqns):
+        for v in OrderedSet(v for v in e.invars if isinstance(v, Var)):
+            readers.setdefault(v, []).append(i)
+
+    def place(i, m):
+        eqn_mesh[i] = m
+        for v in apply_eqns[i].outvars:
+            placed[v] = m
+
+    def forward():
+        changed = False
+        for i, e in enumerate(apply_eqns):
+            if eqn_mesh[i] is not None:
+                continue
+            best_m, best_size = None, -1.0
+            for v in e.invars:
+                if isinstance(v, Var) and v in placed and \
+                        _numel(v) > best_size:
+                    best_m, best_size = placed[v], _numel(v)
+            if best_m is not None:
+                place(i, best_m)
+                changed = True
+        return changed
+
+    def backward():
+        changed = False
+        for i in reversed(range(len(apply_eqns))):
+            if eqn_mesh[i] is not None:
+                continue
+            read_bytes: Dict[int, float] = {}
+            for v in apply_eqns[i].outvars:
+                nbytes = _numel(v) * v.aval.dtype.itemsize
+                for j in readers.get(v, ()):
+                    m = eqn_mesh[j]
+                    if m is not None:
+                        read_bytes[m] = read_bytes.get(m, 0.0) + nbytes
+            if read_bytes:
+                place(i, min(read_bytes, key=lambda m: (-read_bytes[m], m)))
+                changed = True
+        return changed
+
+    forward()
+    while backward() and forward():
+        pass
+    return [0 if m is None else m for m in eqn_mesh]
+
+
 def partition_apply_grad(apply_eqns: List,
                          var_mesh: Dict[Var, int],
                          num_meshes: int,
@@ -155,31 +226,21 @@ def partition_apply_grad(apply_eqns: List,
                          consts_map: Dict[Var, Any],
                          force_mesh: Optional[int] = None
                          ) -> Tuple[List[JaxPipelineComputation], Dict[Var, int]]:
-    """Assign each apply-grad eqn to a mesh by propagating the placement of
-    its inputs (ref process_apply_gradient:591 / propagate_mesh_assignment).
+    """Partition the apply-grad eqns into one computation per mesh (ref
+    process_apply_gradient:591), each eqn on the mesh
+    :func:`assign_apply_grad_meshes` gives it, or all on ``force_mesh``.
 
     Eqns whose inputs span meshes go to the mesh holding the largest input
     (so gradient-sized values stay put and scalars travel); values are
     ferried by the runtime's cross-mesh resharding.  Returns one computation
-    per mesh (possibly empty) and the output->mesh map.
+    per mesh (possibly empty) and the var->mesh map.
     """
-    import numpy as _np
-
-    eqn_mesh: List[int] = []
+    if force_mesh is not None:
+        eqn_mesh = [force_mesh] * len(apply_eqns)
+    else:
+        eqn_mesh = assign_apply_grad_meshes(apply_eqns, var_mesh)
     local_var_mesh = dict(var_mesh)
-    for e in apply_eqns:
-        if force_mesh is not None:
-            m = force_mesh
-        else:
-            best_m, best_size = None, -1.0
-            for v in e.invars:
-                if isinstance(v, Var) and v in local_var_mesh:
-                    size = float(_np.prod(v.aval.shape)) if getattr(
-                        v.aval, "shape", None) else 1.0
-                    if size > best_size:
-                        best_m, best_size = local_var_mesh[v], size
-            m = best_m if best_m is not None else 0
-        eqn_mesh.append(m)
+    for e, m in zip(apply_eqns, eqn_mesh):
         for v in e.outvars:
             local_var_mesh[v] = m
 

@@ -11,6 +11,7 @@ the single Python loop plays the role of the reference's per-host
 interpreter loops (``execute_on_worker``, ref pipeshard_executable.py:489).
 """
 import contextlib
+import dataclasses
 import itertools
 import logging
 import threading
@@ -27,8 +28,10 @@ from alpa_tpu import fault
 from alpa_tpu.global_env import global_config
 from alpa_tpu.mesh_executable import alloc_zero_buffers
 from alpa_tpu.pipeline_parallel.runtime_emitter import (
-    PipelineInstType, PipelineInstruction, PipeshardConfig,
-    PlacementSpecEntry, emit_free_instructions, partition_streams)
+    LAUNCH_MOVED_ARRAYS, LAUNCH_MOVED_BYTES, LAUNCH_RELAID_ARRAYS,
+    LAUNCH_RELAID_BYTES, PipelineInstType, PipelineInstruction,
+    PipeshardConfig, PlacementSpecEntry, emit_free_instructions,
+    partition_streams)
 from alpa_tpu.pipeline_parallel.schedules import create_pipeline_schedule
 from alpa_tpu.shard_parallel.auto_sharding import MESH_AXIS_NAMES
 from alpa_tpu.telemetry import flight as _flight
@@ -44,6 +47,23 @@ logger = logging.getLogger(__name__)
 _DISPATCH_SECONDS = _tmetrics.get_registry().histogram(
     "alpa_pipeshard_dispatch_seconds",
     "launch_on_driver dispatch latency per pipeshard step")
+
+
+@dataclasses.dataclass
+class _InputLoad:
+    """Where one flat argument goes at a register launch: resolved once,
+    replayed every step."""
+    arg_idx: int
+    is_batch: bool
+    # (slot, sharding, micro-batch or -1) of every residency
+    entries: List[Tuple[int, Any, int]]
+    # the sharding the argument last came with, how many of the
+    # ``entries`` lie on another device set than that, and how many on the
+    # same devices in another layout (a state leaf comes back with the
+    # same sharding object every step, so the comparison is made once)
+    src_sharding: Any = None
+    n_moved: int = 0
+    n_relaid: int = 0
 
 
 class StageExecutable:
@@ -318,7 +338,19 @@ class PipeshardDriverExecutable:
             post: acc_info[pre][1]
             for pre, post in grad_pairs if pre in acc_info
         }
-        _unify_same_mesh_shardings(all_execs, post_to_sum)
+        # A donated state leaf comes back as the output at its own flat
+        # position (state in, new state out).  Naming the new value as
+        # the old one makes the program that writes it pin its output to
+        # the sharding the next step reads the leaf with, so the next
+        # launch finds it laid out as wanted instead of re-laying it out
+        # through the host, and the write can reuse the donated buffer.
+        new_to_old = {
+            o: i for i, o, d in zip(global_invars, global_outvars,
+                                    donated_invars)
+            if d and isinstance(o, Var) and o is not i and
+            o.aval.shape == i.aval.shape and o.aval.dtype == i.aval.dtype
+        }
+        _unify_same_mesh_shardings(all_execs, {**new_to_old, **post_to_sum})
         for e in all_execs:
             e.compile()
         if global_config.print_compilation_time:
@@ -1059,7 +1091,8 @@ class PipeshardDriverExecutable:
             else:
                 for mesh_id, sh in places:
                     entries.append((slot_of[(v, -1, mesh_id)], sh, -1))
-            self._reg_input_loads.append((i, self.batch_invars[i], entries))
+            self._reg_input_loads.append(
+                _InputLoad(i, self.batch_invars[i], entries))
 
         # outputs: mirror output_specs with slots
         out_specs = []
@@ -1142,9 +1175,17 @@ class PipeshardDriverExecutable:
         with _ttrace.span("pipeshard.place-inputs", "runtime"):
             # place global inputs in one batched device_put
             put_vals, put_shs, put_slots = [], [], []
-            for arg_idx, is_batch, entries in self._reg_input_loads:
-                arg = flat_args[arg_idx]
-                if is_batch:
+            # state that arrives on another device set than it is wanted
+            # on, or there in another layout, counted from the second step
+            # on: the first places what the caller hands over, every later
+            # one what the plan left on the wrong mesh or in the wrong
+            # sharding
+            count_moved = self._n_launches > 1
+            moved_arrays = moved_bytes = relaid_arrays = relaid_bytes = 0
+            for load in self._reg_input_loads:
+                arg = flat_args[load.arg_idx]
+                entries = load.entries
+                if load.is_batch:
                     if n_mb == 1:
                         mbs = [arg]
                     elif isinstance(arg, jax.Array):
@@ -1160,8 +1201,37 @@ class PipeshardDriverExecutable:
                         put_vals.append(arg)
                         put_shs.append(sh)
                         put_slots.append(s)
+                    if count_moved and isinstance(arg, jax.Array):
+                        src = arg.sharding
+                        if src is not load.src_sharding:
+                            load.src_sharding = src
+                            load.n_moved = load.n_relaid = 0
+                            for _s, sh, _mb in entries:
+                                if sh.device_set != src.device_set:
+                                    load.n_moved += 1
+                                elif not src.is_equivalent_to(sh, arg.ndim):
+                                    load.n_relaid += 1
+                        if load.n_moved:
+                            moved_arrays += load.n_moved
+                            moved_bytes += load.n_moved * arg.nbytes
+                        if load.n_relaid:
+                            relaid_arrays += load.n_relaid
+                            relaid_bytes += load.n_relaid * arg.nbytes
+            if moved_arrays:
+                LAUNCH_MOVED_ARRAYS.inc(moved_arrays)
+                LAUNCH_MOVED_BYTES.inc(moved_bytes)
+            if relaid_arrays:
+                LAUNCH_RELAID_ARRAYS.inc(relaid_arrays)
+                LAUNCH_RELAID_BYTES.inc(relaid_bytes)
             if put_vals:
-                placed = jax.device_put(put_vals, put_shs)
+                # one call for every leaf: those already laid out as
+                # wanted take jax's fast path and a replicated one crosses
+                # to another mesh device to device, but one that is wanted
+                # in another sharding goes through the host
+                # (``shard_sharded_device_array_slow_path``), and this
+                # call waits for it
+                with _ttrace.span("pipeshard.place-inputs.put", "runtime"):
+                    placed = jax.device_put(put_vals, put_shs)
                 for s, o in zip(put_slots, placed):
                     regs[s] = o
 
@@ -1186,9 +1256,10 @@ class PipeshardDriverExecutable:
                     (compiled, [slot_of[(v, -1, mesh_id)] for v in vs])
                     for mesh_id, vs, compiled in self._zero_exec_cache
                 ]
-            for compiled, slots in self._reg_acc_slots:
-                for s, buf in zip(slots, compiled()):
-                    regs[s] = buf
+            with _ttrace.span("pipeshard.place-inputs.zero", "runtime"):
+                for compiled, slots in self._reg_acc_slots:
+                    for s, buf in zip(slots, compiled()):
+                        regs[s] = buf
 
         # replay
         loop_tic = time.perf_counter()

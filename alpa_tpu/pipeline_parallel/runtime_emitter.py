@@ -1128,6 +1128,30 @@ _OVERLAP_LAST_WINDOW = _OVERLAP_REG.gauge(
     "alpa_overlap_last_window",
     "Last step's in-flight transfer window")
 
+# train state that a step's launch had to carry to another device set
+# than the previous step left it on (pipeshard_executable
+# ``_launch_registers``): 0 for a plan that keeps every leaf of the state
+# beside the stage that reads it
+LAUNCH_MOVED_BYTES = _OVERLAP_REG.counter(
+    "alpa_pipeshard_launch_moved_bytes_total",
+    "Bytes of non-batch input arrays put onto another device set at a "
+    "pipeshard step's launch, from the second step on")
+LAUNCH_MOVED_ARRAYS = _OVERLAP_REG.counter(
+    "alpa_pipeshard_launch_moved_arrays_total",
+    "Non-batch input arrays put onto another device set at a pipeshard "
+    "step's launch, from the second step on (one per target mesh)")
+# ... and what it found on the right devices in another sharding than
+# the reading stage planned: jax re-lays such an array out through the
+# host (``shard_sharded_device_array_slow_path``), with every chip idle
+LAUNCH_RELAID_BYTES = _OVERLAP_REG.counter(
+    "alpa_pipeshard_launch_relayout_bytes_total",
+    "Bytes of non-batch input arrays a pipeshard step's launch found on "
+    "their mesh in another sharding than wanted, from the second step on")
+LAUNCH_RELAID_ARRAYS = _OVERLAP_REG.counter(
+    "alpa_pipeshard_launch_relayout_arrays_total",
+    "Non-batch input arrays a pipeshard step's launch found on their "
+    "mesh in another sharding than wanted, from the second step on")
+
 
 def record_overlap_step(stats: Dict[str, Any]) -> None:
     """Fold one overlap-mode step's dispatch stats into the registry

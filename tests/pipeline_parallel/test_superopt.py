@@ -34,8 +34,9 @@ from alpa_tpu.analysis.model_check import model_from_dict
 from alpa_tpu.global_env import global_config
 from alpa_tpu.pipeline_parallel.runtime_emitter import (
     OpHook, PipelineInstType, PipelineInstruction, instruction_accesses)
-from alpa_tpu.testing import create_mlp_train_state_and_batch
 
+from tests.pipeline_parallel.test_apply_grad_placement import (
+    tied_lm_state_and_batch, tied_lm_train_step)
 from tests.pipeline_parallel.test_plan_verifier import _compile_pipeline
 
 FIXTURE = os.path.join(
@@ -254,8 +255,24 @@ def test_coalescer_honors_fission_knob():
 # deoptimize / score / search (pure, over a real compiled plan)
 # ---------------------------------------------------------------------
 
+def _compile_tied_lm():
+    """The two-stage Adam step of a tied-embedding LM (registers mode).
+    The recovery tests run on it and not on ``_compile_pipeline``'s MLP:
+    its activations (4 x 8 x 64 logits a micro-batch) outweigh its
+    parameters, so FREEs deferred to the end do raise the simulated peak,
+    and the tied table's gradient crosses beside the activation's, so the
+    baseline already holds a grouped transfer and a regrouping is no new
+    finding.  (Until ISSUE 25 the MLP had both by accident: its momentum
+    products crossed 0->1 in a row before ``apply_grad_1``.)"""
+    alpa_tpu.init("local")
+    global_config.pipeline_dispatch_mode = "registers"
+    step = tied_lm_train_step()
+    step(*tied_lm_state_and_batch())
+    return step.get_last_executable(), step
+
+
 def test_deoptimize_is_legal_and_search_recovers():
-    ex, *_ = _compile_pipeline(num_stages=2)
+    ex, _ = _compile_tied_lm()
     insts = list(ex.instructions)
     cm = so._CostModel()
     nm = ex.num_meshes
@@ -400,12 +417,6 @@ def test_fuzz_quantized_edge_fused_into_group_rejected():
 # oracle 4: end-to-end auto recovery + bitwise outputs + warm replay
 # ---------------------------------------------------------------------
 
-def _fresh_pair():
-    return create_mlp_train_state_and_batch(
-        batch_size=8, input_dim=8, hidden_dim=8, output_dim=8,
-        num_layers=4, manual_pipeline_layer=False)
-
-
 def _param_leaves(state):
     import jax
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(
@@ -430,11 +441,11 @@ def _reset_lowering(ex):
 def test_auto_recovers_deoptimized_plan_bitwise(tmp_path):
     from alpa_tpu.compile_cache import reset_compile_cache
     from alpa_tpu.telemetry.metrics import get_registry
-    ex, state, batch, step = _compile_pipeline(num_stages=2)
+    ex, step = _compile_tied_lm()
     global_config.compile_cache_dir = str(tmp_path)
     reset_compile_cache()
 
-    s0, b0 = _fresh_pair()
+    s0, b0 = tied_lm_state_and_batch()
     ns0, _ = step(s0, b0)
     want = _param_leaves(ns0)
     assert any(bool(np.any(x)) for x in want)
@@ -443,7 +454,7 @@ def test_auto_recovers_deoptimized_plan_bitwise(tmp_path):
     ex.instructions = so.deoptimize_instructions(list(ex.instructions))
     _reset_lowering(ex)
     ex._ensure_lowered("registers")
-    s1, b1 = _fresh_pair()
+    s1, b1 = tied_lm_state_and_batch()
     ns1, _ = step(s1, b1)
     assert all((a == b).all()
                for a, b in zip(want, _param_leaves(ns1))), \
@@ -459,7 +470,7 @@ def test_auto_recovers_deoptimized_plan_bitwise(tmp_path):
     assert out.critical_path_delta_us < 0
     assert out.peak_bytes_delta < 0
     assert out.fingerprint != out.baseline_fingerprint
-    s2, b2 = _fresh_pair()
+    s2, b2 = tied_lm_state_and_batch()
     ns2, _ = step(s2, b2)
     assert all((a == b).all()
                for a, b in zip(want, _param_leaves(ns2))), \
@@ -494,7 +505,7 @@ def test_auto_recovers_deoptimized_plan_bitwise(tmp_path):
     out3 = ex._superopt_outcome
     assert out3.cache_hit and not out3.searched and out3.accepted
     assert out3.fingerprint == out.fingerprint
-    s3, b3 = _fresh_pair()
+    s3, b3 = tied_lm_state_and_batch()
     ns3, _ = step(s3, b3)
     assert all((a == b).all()
                for a, b in zip(want, _param_leaves(ns3)))
