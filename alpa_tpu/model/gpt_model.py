@@ -63,6 +63,35 @@ class GPTConfig:
     # learned-positional-table offset (OPT reserves the first 2 rows,
     # ref examples/llm_serving/model/opt_model.py position handling)
     pos_offset: int = 0
+    # --- the kinds of one decoder block.  The defaults are the GPT-2 /
+    # OPT block; OLMoE is norm "rmsnorm", positions "rotary", qk_norm,
+    # no bias, mlp "experts", activation "silu", an untied head.
+    # "layernorm" | "rmsnorm" (layer_norm_eps is the epsilon of either)
+    norm: str = "layernorm"
+    # "learned" (a table, wpe) | "rotary" (rotate-half RoPE on q and k)
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    # RMSNorm over the whole flat q and k projections, before the heads
+    # are split and rotated (OLMoE)
+    qk_norm: bool = False
+    use_bias: bool = True
+    # the MLP of every layer, or one kind a layer (a tuple num_layers
+    # long): "dense" (in, activation, out) | "gated" (act(gate) * up,
+    # down) | "experts" (top-k routed gated experts, no token dropped:
+    # model/moe.py DroplessExperts)
+    mlp: Any = "dense"
+    # width of the MLP, of one expert where routed; None: mlp_ratio * h
+    intermediate_size: Optional[int] = None
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+
+    def mlp_kind(self, layer: int) -> str:
+        return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
+
+    @property
+    def mlp_width(self) -> int:
+        return self.intermediate_size or self.mlp_ratio * self.hidden_size
 
 
 # The reference benchmark ladder: name -> (hidden, layers, heads)
@@ -109,6 +138,66 @@ def config_from_opt_spec(name: str, **kwargs) -> GPTConfig:
     defaults.update(kwargs)
     return GPTConfig(hidden_size=hidden, num_layers=layers,
                      num_heads=heads, **defaults)
+
+
+# The kinds of the decoder block by Hugging Face ``model_type``.
+_HF_KINDS = {
+    "olmoe": dict(norm="rmsnorm", positions="rotary", qk_norm=True,
+                  mlp="experts"),
+}
+
+
+def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
+    """``GPTConfig`` from the keys of a Hugging Face ``config.json`` (a
+    dict), for the model types in ``_HF_KINDS``."""
+    kinds = _HF_KINDS.get(hf["model_type"])
+    if kinds is None:
+        raise ValueError(f"no decoder kinds for model_type "
+                         f"{hf['model_type']!r} (known: {sorted(_HF_KINDS)})")
+    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        raise ValueError("grouped-query attention is not supported")
+    if hf.get("rope_scaling") or hf.get("clip_qkv"):
+        raise ValueError("rope_scaling and clip_qkv are not supported")
+    return GPTConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        seq_len=hf["max_position_embeddings"],
+        intermediate_size=hf["intermediate_size"],
+        activation=hf["hidden_act"], layer_norm_eps=hf["rms_norm_eps"],
+        rope_theta=float(hf["rope_theta"]), use_bias=hf["attention_bias"],
+        tie_embeddings=hf["tie_word_embeddings"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        norm_topk_prob=hf["norm_topk_prob"], **kinds, **kwargs)
+
+
+def make_norm(config: GPTConfig, name: str) -> nn.Module:
+    """The normalisation the configuration names, computed in float32."""
+    if config.norm == "rmsnorm":
+        # scale * x / sqrt(mean(x^2) + eps)
+        return nn.RMSNorm(epsilon=config.layer_norm_eps, dtype=jnp.float32,
+                          name=name)
+    if config.norm != "layernorm":
+        raise ValueError(f"unknown norm {config.norm!r}")
+    return nn.LayerNorm(epsilon=config.layer_norm_eps, dtype=jnp.float32,
+                        name=name)
+
+
+def apply_rotary(x, position_ids, theta: float):
+    """Rotate-half rotary position embedding (Su et al. 2021, as in
+    Hugging Face's ``apply_rotary_pos_emb``): x (B, S, H, D), positions
+    (B, S).  Channel i is paired with channel i + D/2; the angle of pair i
+    is position * theta^(-2i/D).  Computed in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = position_ids.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None):
@@ -197,16 +286,23 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None):
+                 attn_bias=None, position_ids=None):
         cfg = self.config
         h, nh = cfg.hidden_size, cfg.num_heads
         hd = h // nh
-        qkv = nn.Dense(3 * h, dtype=cfg.dtype, name="qkv")(x)
+        qkv = nn.Dense(3 * h, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                       name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         b, s = x.shape[0], x.shape[1]
+        if cfg.qk_norm:
+            q = make_norm(cfg, "q_norm")(q).astype(cfg.dtype)
+            k = make_norm(cfg, "k_norm")(k).astype(cfg.dtype)
         q = q.reshape(b, s, nh, hd)
         k = k.reshape(b, s, nh, hd)
         v = v.reshape(b, s, nh, hd)
+        if cfg.positions == "rotary":
+            q = apply_rotary(q, position_ids, cfg.rope_theta)
+            k = apply_rotary(k, position_ids, cfg.rope_theta)
 
         new_cache = None
         if kv_cache is not None:
@@ -227,44 +323,72 @@ class SelfAttention(nn.Module):
                 attn_fn = get_attention_fn(cfg)
                 out = attn_fn(q, k, v, causal=cfg.causal)
         out = out.reshape(b, s, h)
-        out = nn.Dense(h, dtype=cfg.dtype, name="out")(out)
+        out = nn.Dense(h, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                       name="out")(out)
         return out, new_cache
 
 
+def activation_fn(name: str) -> Callable:
+    if name == "relu":
+        return nn.relu
+    if name == "silu":
+        return nn.silu
+    return partial(nn.gelu, approximate=True)
+
+
 class MLPBlock(nn.Module):
+    """The dense MLP: in, activation, out; ``gated``: act(gate) * up, down
+    (Shazeer 2020, the SwiGLU of today's decoders with "silu")."""
     config: GPTConfig
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        h = cfg.hidden_size
-        x = nn.Dense(cfg.mlp_ratio * h, dtype=cfg.dtype, name="fc_in")(x)
-        x = (nn.relu(x) if cfg.activation == "relu" else
-             nn.gelu(x, approximate=True))
-        x = nn.Dense(h, dtype=cfg.dtype, name="fc_out")(x)
-        return x
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias)
+        act = activation_fn(cfg.activation)
+        if self.gated:
+            x = act(dense(cfg.mlp_width, name="gate")(x)) * \
+                dense(cfg.mlp_width, name="up")(x)
+            return dense(cfg.hidden_size, name="down")(x)
+        x = act(dense(cfg.mlp_width, name="fc_in")(x))
+        return dense(cfg.hidden_size, name="fc_out")(x)
 
 
 class TransformerBlock(nn.Module):
+    """One pre-norm decoder block.  ``mlp`` is the kind of its MLP
+    (``GPTConfig.mlp``; None: the configuration's, which must then be one
+    kind for all layers).  Returns ``(x, new_cache)``, and a block of
+    routed experts ``(x, new_cache, routing)``: what its router did
+    (``moe.DroplessExperts``)."""
     config: GPTConfig
+    mlp: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None):
+                 attn_bias=None, position_ids=None):
         cfg = self.config
-        ln1 = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                           name="ln1")(x)
+        kind = self.mlp or cfg.mlp_kind(0)
+        ln1 = make_norm(cfg, "ln1")(x)
         attn_out, new_cache = SelfAttention(cfg, name="attn")(
-            ln1, kv_cache, deterministic, attn_bias)
+            ln1, kv_cache, deterministic, attn_bias, position_ids)
         x = x + attn_out.astype(x.dtype)
-        ln2 = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                           name="ln2")(x)
-        x = x + MLPBlock(cfg, name="mlp")(ln2).astype(x.dtype)
-        return x, new_cache
+        ln2 = make_norm(cfg, "ln2")(x)
+        if kind == "experts":
+            from alpa_tpu.model.moe import DroplessExperts
+            y, routing = DroplessExperts(cfg, name="mlp")(ln2)
+            return x + y.astype(x.dtype), new_cache, routing
+        if kind not in ("dense", "gated"):
+            raise ValueError(f"unknown mlp kind {kind!r}")
+        y = MLPBlock(cfg, gated=kind == "gated", name="mlp")(ln2)
+        return x + y.astype(x.dtype), new_cache
 
 
 class GPTModel(nn.Module):
-    """Decoder-only LM.  Returns logits (and new kv caches if given)."""
+    """Decoder-only LM.  Returns logits (and new kv caches if given).  A
+    configuration with routed-expert layers returns ``(logits, routing)``
+    from the training call: ``moe.routing_summary`` of its layers (the
+    load-balancing term, each expert's rows, every token's experts)."""
     config: GPTConfig
 
     @nn.compact
@@ -315,9 +439,14 @@ class GPTModel(nn.Module):
         tok_emb = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                            dtype=cfg.dtype, name="wte")
         x = tok_emb(input_ids)
-        x = x + nn.Embed(cfg.seq_len + cfg.pos_offset, cfg.hidden_size,
-                         dtype=cfg.dtype,
-                         name="wpe")(position_ids + cfg.pos_offset)
+        if cfg.positions == "learned":
+            x = x + nn.Embed(cfg.seq_len + cfg.pos_offset, cfg.hidden_size,
+                             dtype=cfg.dtype,
+                             name="wpe")(position_ids + cfg.pos_offset)
+        elif cfg.positions != "rotary":
+            raise ValueError(f"unknown positions {cfg.positions!r}")
+        # the blocks of learned positions never see them
+        block_positions = position_ids if cfg.positions == "rotary" else None
         block_cls = TransformerBlock
         if cfg.remat_blocks and kv_caches is None:
             policy = None
@@ -335,17 +464,19 @@ class GPTModel(nn.Module):
                                  static_argnums=(2, 3),
                                  policy=policy)
         new_caches = [] if kv_caches is not None else None
+        routings = []
         for i in range(cfg.num_layers):
             if (cfg.pipeline_boundary_every and i > 0 and
                     i % cfg.pipeline_boundary_every == 0):
                 mark_pipeline_boundary()
             cache_i = kv_caches[i] if kv_caches is not None else None
-            x, new_cache = block_cls(cfg, name=f"h{i}")(
-                x, cache_i, deterministic, seg_bias)
+            x, new_cache, *routing = block_cls(
+                cfg, mlp=cfg.mlp_kind(i), name=f"h{i}")(
+                    x, cache_i, deterministic, seg_bias, block_positions)
+            routings += routing
             if new_caches is not None:
                 new_caches.append(new_cache)
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                         name="ln_f")(x)
+        x = make_norm(cfg, "ln_f")(x)
         if return_hidden:
             return x
         if cfg.tie_embeddings:
@@ -355,6 +486,9 @@ class GPTModel(nn.Module):
                               use_bias=False, name="lm_head")(x)
         if new_caches is not None:
             return logits, new_caches
+        if routings:
+            from alpa_tpu.model.moe import routing_summary
+            return logits, routing_summary(routings)
         return logits
 
 

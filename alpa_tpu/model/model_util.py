@@ -84,6 +84,16 @@ def gpt_lm_loss(apply_fn, params, batch, chunked=False):
     return cross_entropy_loss(logits.astype(jnp.float32), batch["labels"])
 
 
+def routed_lm_loss(apply_fn, params, batch, aux_loss_coef: float):
+    """LM loss of a model with routed-expert layers (``GPTModel`` whose
+    ``mlp`` names "experts"): mean token cross-entropy plus
+    ``aux_loss_coef`` times the routers' load-balancing term.  Returns
+    ``(loss, routing)`` for ``value_and_grad(..., has_aux=True)``."""
+    logits, routing = apply_fn(params, batch["input_ids"])
+    loss = cross_entropy_loss(logits.astype(jnp.float32), batch["labels"])
+    return loss + aux_loss_coef * routing["load_balance_loss"], routing
+
+
 def cross_entropy_loss(logits, labels, label_mask=None, vocab_size=None):
     """Mean token cross-entropy with optional mask."""
     loss = optax.softmax_cross_entropy_with_integer_labels(logits, labels)

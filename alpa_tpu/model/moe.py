@@ -1,4 +1,15 @@
-"""Mixture-of-Experts transformer (GShard-style top-2 gating).
+"""Mixture-of-Experts layers: dropless top-k routed experts (the MLP kind
+"experts" of ``gpt_model.TransformerBlock``: OLMoE, Muennighoff et al.
+2024) and, below it, the older GShard-style top-2 model with capacity
+dropping.
+
+Dropless: every one of the k x tokens pairs is computed.  The rows are
+sorted by expert, each expert's consecutive rows meet its weights in one
+grouped matmul (``ops/grouped_matmul.py``), and the results are put back
+in token order and summed with the routing weights.  Nothing depends on an
+expert's load, so there is no capacity and no padding to steal it.
+
+GShard top-2 (the rest of this file):
 
 Analog of ref ``alpa/model/moe.py`` (einsum-formulated top-2 gating,
 ref :151-184): the expert dimension is a leading einsum dim, and expert
@@ -24,6 +35,183 @@ import numpy as np
 from alpa_tpu.model.gpt_model import GPTConfig, SelfAttention
 
 logger = logging.getLogger(__name__)
+
+
+########################################
+# dropless top-k routing
+########################################
+
+# the scope the expert path is traced under: the benchmark finds its
+# device events by it (HLO metadata ``op_name``)
+SCOPE = "moe"
+
+
+def topk_routing(router_logits, k: int, norm_topk_prob: bool = False):
+    """Softmax over the experts in float32, then the k largest: (T, E)
+    logits -> (weights (T, k) float32, experts (T, k) int32, probs (T, E)).
+    The weights are the probabilities themselves unless ``norm_topk_prob``
+    (OLMoE's published configuration leaves it off)."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    return weights, experts.astype(jnp.int32), probs
+
+
+def load_balancing_loss(prob_sums, counts, n_rows):
+    """Hugging Face's ``load_balancing_loss_func`` from per-layer sums:
+    ``prob_sums`` (L, E) the router probabilities summed over a layer's
+    tokens, ``counts`` (L, E) how many of the token-slots chose each
+    expert, ``n_rows`` = L x tokens.  All layers' tokens are pooled, as
+    there: E * sum_e (slots that chose e / rows) * (mean probability of
+    e).  k at a perfectly even routing.  No gradient flows through the
+    counts."""
+    share = jax.lax.stop_gradient(counts.sum(0).astype(jnp.float32)) / n_rows
+    mean_prob = prob_sums.sum(0) / n_rows
+    return prob_sums.shape[-1] * (share * mean_prob).sum()
+
+
+def routing_summary(routings: list) -> dict:
+    """What a model of routed-expert layers returns beside its logits,
+    from each layer's ``DroplessExperts`` routing: ``load_balance_loss``
+    (above), ``expert_counts`` (L, E) int32 and ``experts`` (L, T, k) int32,
+    the experts of every token."""
+    counts = jnp.stack([r["counts"] for r in routings])
+    prob_sums = jnp.stack([r["prob_sums"] for r in routings])
+    experts = jnp.stack([r["experts"] for r in routings])
+    n_rows = experts.shape[0] * experts.shape[1]
+    return {"load_balance_loss": load_balancing_loss(prob_sums, counts,
+                                                     n_rows),
+            "expert_counts": counts, "experts": experts}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_experts(x, order, inverse, k):
+    """(T, H) tokens -> (T k, H) rows sorted by expert: row r is token
+    ``order[r] // k``.  ``inverse`` is the inverse permutation of
+    ``order``, so the gradient is a gather too (XLA would scatter-add)."""
+    del inverse
+    return x[order // k]
+
+
+def _rows_to_experts_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _rows_to_experts_bwd(k, inverse, g):
+    by_token = g[inverse].reshape(g.shape[0] // k, k, g.shape[1])
+    return by_token.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation, with its inverse for the gradient."""
+    del inverse
+    return x[perm]
+
+
+_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], inverse),
+                     lambda inverse, g: (g[inverse], None, None))
+
+
+class DroplessExperts(nn.Module):
+    """Top-k routed, SiLU-gated experts without capacity (module
+    docstring).  ``config`` is a ``GPTConfig``: ``num_experts``,
+    ``num_experts_per_tok``, ``mlp_width`` (one expert's), ``activation``,
+    ``norm_topk_prob``, ``dtype``.  The router and its softmax are float32
+    at full matmul precision (a near-tie between two experts then flips
+    only on the activations' own rounding); the experts multiply in
+    ``dtype`` with float32 accumulation.
+
+    Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32,
+    ``prob_sums`` (E,) float32 and ``experts`` (T, k) int32."""
+    config: Any
+
+    @nn.compact
+    def __call__(self, x):
+        from alpa_tpu.model.gpt_model import activation_fn
+        from alpa_tpu.ops.grouped_matmul import grouped_matmul
+        cfg = self.config
+        e, k, width = cfg.num_experts, cfg.num_experts_per_tok, cfg.mlp_width
+        h = x.shape[-1]
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("w_gate", init, (e, h, width))
+        w_up = self.param("w_up", init, (e, h, width))
+        w_down = self.param("w_down", init, (e, width, h))
+        tokens = x.reshape(-1, h)
+        with jax.named_scope(SCOPE):
+            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(tokens.astype(jnp.float32))
+            weights, experts, probs = topk_routing(logits, k,
+                                                   cfg.norm_topk_prob)
+            flat = experts.reshape(-1)
+            # stable: an expert's rows stay in token order
+            order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            counts = (flat[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
+                0, dtype=jnp.int32)
+            rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
+                                    inverse, k)
+            # gate and up in one pass over the rows
+            gate_up = grouped_matmul(
+                rows, jnp.concatenate([w_gate, w_up], axis=-1), counts)
+            act = activation_fn(cfg.activation)
+            hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
+                      gate_up[:, width:].astype(jnp.float32))
+            out_rows = grouped_matmul(hidden.astype(cfg.dtype), w_down,
+                                      counts)
+            by_token = _permute_rows(out_rows, inverse, order).reshape(
+                tokens.shape[0], k, h)
+            y = (by_token.astype(jnp.float32) * weights[..., None]).sum(1)
+        routing = {"counts": counts, "prob_sums": probs.sum(0),
+                   "experts": experts}
+        return y.astype(cfg.dtype).reshape(x.shape), routing
+
+
+def record_routing(expert_counts, dropped_rows: int = 0):
+    """Feed the metrics registry from a step's routing, on the host: the
+    caller has the step's ``expert_counts`` ((L, E), or (E,)) already read
+    back and calls this where it wants the reading (a warm-up or a traced
+    step: it is a device-to-host copy, so never in a timed loop).
+    ``alpa_moe_routed_rows_total`` and ``alpa_moe_dropped_rows_total``
+    count token-expert rows computed and rows a capacity limit dropped (0
+    on the dropless path); the gauge ``alpa_moe_expert_load_max_over_mean``
+    is the busiest expert's rows over the mean, the largest over the
+    layers."""
+    from alpa_tpu.telemetry import metrics as tmetrics
+    counts = np.atleast_2d(np.asarray(expert_counts, dtype=np.float64))
+    registry = tmetrics.get_registry()
+    registry.counter("alpa_moe_routed_rows_total",
+                     "token-expert rows the experts computed"
+                     ).inc(float(counts.sum()))
+    registry.counter("alpa_moe_dropped_rows_total",
+                     "token-expert rows dropped by an expert's capacity"
+                     ).inc(float(dropped_rows))
+    load = (counts.max(-1) / np.maximum(counts.mean(-1), 1e-9)).max()
+    registry.gauge("alpa_moe_expert_load_max_over_mean",
+                   "rows of the busiest expert over the mean, largest over "
+                   "the layers").set(float(load))
+
+
+########################################
+# GShard top-2 with capacity (the older model)
+########################################
+
+
+def legacy_routing(intermediates) -> tuple:
+    """(rows each expert kept (E,), rows dropped) of one application of a
+    model of ``MoEMLP`` layers, from its ``intermediates`` collection
+    (``apply(..., mutable=["intermediates"])``), summed over the layers:
+    what a caller hands to ``record_routing``."""
+    flat = {jax.tree_util.keystr(path): leaf for path, leaf in
+            jax.tree_util.tree_leaves_with_path(intermediates)}
+    kept = sum(np.asarray(v) for k, v in flat.items() if "kept_rows" in k)
+    wanted = sum(int(v) for k, v in flat.items() if "wanted_rows" in k)
+    return kept, int(wanted - kept.sum())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,6 +377,10 @@ class MoEMLP(nn.Module):
                           name="router")(tokens)
         combine, dispatch, aux_loss = top2_gating(router, capacity)
         self.sow("intermediates", "aux_loss", aux_loss)
+        # rows each expert kept; top-2 wants two a token, the rest were
+        # dropped by the capacity (``legacy_routing`` reads both)
+        self.sow("intermediates", "kept_rows", dispatch.sum((0, 1, 3)))
+        self.sow("intermediates", "wanted_rows", 2 * g * sp)
 
         # per-expert MLP weights (leading expert dim)
         wi = self.param("wi", nn.initializers.lecun_normal(),
@@ -257,6 +449,8 @@ class MoELMModel(nn.Module):
     from real tokens and change their logits.  Serve with
     ``capacity_factor >= num_experts`` (no-drop regime; the Generator
     warns otherwise).  Training is unaffected (no padding there).
+    The dropless path (``DroplessExperts``, ``GPTModel`` with ``mlp``
+    "experts") has no capacity and so no such limit.
     """
     config: MoEConfig
 

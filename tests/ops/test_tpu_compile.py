@@ -78,3 +78,34 @@ def test_flash_kernels_compile_for_v5e(one_chip, fn, shape, residuals,
     assert jax.default_backend() == "cpu"
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == n_kernels
+
+
+# the expert layer's grouped matmuls at OLMoE's widths, 64 groups, batch 2
+# of 4096 tokens with 8 experts each: (rows, contracted, produced)
+GROUPED_CASES = [("gate-up", (65536, 2048, 2048)),
+                 ("down", (65536, 1024, 2048))]
+
+
+@pytest.mark.parametrize("shape", [c[1] for c in GROUPED_CASES],
+                         ids=[c[0] for c in GROUPED_CASES])
+def test_grouped_matmul_compiles_for_v5e(one_chip, shape):
+    """Forward and both gradients: three Pallas kernels, none interpreted,
+    all inside the kernel's fast memory at the tiling the op chooses."""
+    from alpa_tpu.ops.grouped_matmul import grouped_matmul
+    m, k, n = shape
+
+    def value_and_grads(lhs, rhs, sizes, cot):
+        def loss(lhs, rhs):
+            out = grouped_matmul(lhs, rhs, sizes)
+            return (out.astype(jnp.float32) * cot.astype(jnp.float32)).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1))(lhs, rhs)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert jax.default_backend() == "cpu"
+    hlo = jax.jit(value_and_grads).lower(
+        spec((m, k), jnp.bfloat16), spec((64, k, n), jnp.float32),
+        spec((64,), jnp.int32), spec((m, n), jnp.bfloat16)
+    ).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
