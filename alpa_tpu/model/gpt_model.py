@@ -243,6 +243,29 @@ def get_attention_fn(config: GPTConfig) -> Callable:
     return reference_attention
 
 
+def _write_rows(cache, new, index):
+    """``cache`` (B, S, H, D) with ``new`` (B, s, H, D) written at
+    positions ``index[r] .. index[r] + s - 1`` of each row ``r``: one
+    ``dynamic_update_slice`` a row.  The TPU compiler runs those in place
+    in whatever dimension order it keeps the cache in, where a ``scatter``
+    wants its operand row-major and costs a copy of the whole cache into
+    that order and one back (``tests/serve/test_decode_in_place.py``).
+
+    A row whose write does not fit (``index[r]`` outside ``[0, S - s]``)
+    stays as it was: ``dynamic_update_slice`` clamps its start, so such a
+    row writes back the ``s`` positions it read there.
+    """
+    seq_len, s = cache.shape[1], new.shape[1]
+    fits = (index >= 0) & (index <= seq_len - s)
+    start = jnp.clip(index, 0, seq_len - s)
+    for r in range(cache.shape[0]):
+        at = (r, start[r], 0, 0)
+        old = jax.lax.dynamic_slice(cache, at, (1,) + new.shape[1:])
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.where(fits[r], new[r:r + 1], old), at)
+    return cache
+
+
 def update_kv_cache(kv_cache, k, v):
     """Write step K/V into a resident cache and return the attendable
     views — the mechanics shared by every decoder family (GPT/OPT,
@@ -254,6 +277,16 @@ def update_kv_cache(kv_cache, k, v):
     ``(k_use, v_use, new_cache)`` where k_use/v_use are the full-length
     caches with unwritten positions zeroed (masked from attention by the
     caller's causal offset) and ``new_cache`` carries index + s.
+
+    Per-row indices: a row whose ``s`` positions do not all fit in the
+    cache is not written at all (``_write_rows``), where the scatter this
+    replaced still wrote the positions that fit; its index advances all
+    the same.  No caller lets an active row get there (``generate``, the
+    speculative rounds and the engine's ``submit`` refuse a request that
+    would).  The rows that do are the engine's free rows, which are
+    decoded along in every tick with an index that only grows: nothing
+    reads them, and the next admission overwrites the whole row and its
+    index.
     """
     k_cache, v_cache, index = kv_cache
     b, s = k.shape[0], k.shape[1]
@@ -265,10 +298,8 @@ def update_kv_cache(kv_cache, k, v):
             v_cache, v.astype(v_cache.dtype), index, axis=1)
         keep_len = index + s
     else:
-        rows = jnp.arange(b)[:, None]
-        cols = index[:, None] + jnp.arange(s)[None, :]
-        k_full = k_cache.at[rows, cols].set(k.astype(k_cache.dtype))
-        v_full = v_cache.at[rows, cols].set(v.astype(v_cache.dtype))
+        k_full = _write_rows(k_cache, k.astype(k_cache.dtype), index)
+        v_full = _write_rows(v_cache, v.astype(v_cache.dtype), index)
         keep_len = (index + s)[:, None]
     pos = jax.lax.broadcasted_iota(jnp.int32, (k_full.shape[1],), 0)
     keep = pos < keep_len

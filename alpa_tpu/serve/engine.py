@@ -226,11 +226,7 @@ class ContinuousBatchingEngine:
         self.packed_admissions = 0
 
         # resident state: batch KV caches + per-row bookkeeping
-        self._caches = init_kv_caches(cfgm, self.B)
-        # replace scalar indices with per-row vectors
-        self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
-                        for (k, v, _i) in self._caches]
-        self._logits = jnp.zeros((self.B, cfgm.vocab_size), jnp.float32)
+        self._init_resident()
         self._active = np.zeros((self.B,), bool)
         self._rows: List[Optional[dict]] = [None] * self.B
         if scheduler is None:
@@ -253,7 +249,11 @@ class ContinuousBatchingEngine:
                             idx.at[row].set(idx1[0])))
             return new, logits.at[row].set(logits1[0])
 
-        self._scatter_row = jax.jit(scatter_row)
+        # both scatters donate the engine's own caches and logits (every
+        # caller replaces them by the result) and write the admitted rows
+        # in place; caches1 / rowc may still belong to a prefix handle or
+        # a disaggregated prefill and are only read
+        self._scatter_row = jax.jit(scatter_row, donate_argnums=(0, 2))
 
         def scatter_packed(caches, rowc, logits, last, rowmap, mask):
             new = []
@@ -264,7 +264,8 @@ class ContinuousBatchingEngine:
                             jnp.where(mask, rlen[rowmap], idx)))
             return new, jnp.where(mask[:, None], last[rowmap], logits)
 
-        self._scatter_packed = jax.jit(scatter_packed)
+        self._scatter_packed = jax.jit(scatter_packed,
+                                       donate_argnums=(0, 2))
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -349,6 +350,34 @@ class ContinuousBatchingEngine:
             self._cv.notify()
         return _TokenStream(item, q)
 
+    def _init_resident(self):
+        """Fresh batch caches (per-row index vectors) and logits."""
+        cfgm = self.gen.config
+        self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
+                        for (k, v, _i) in init_kv_caches(cfgm, self.B)]
+        self._logits = jnp.zeros((self.B, cfgm.vocab_size), jnp.float32)
+
+    def _fail_active_locked(self, err):
+        """Fail every resident request with ``err`` and free its row."""
+        for r in range(self.B):
+            if self._active[r]:
+                self._rows[r]["error"] = err
+                self._release_table(r, self._rows[r])
+                self._rows[r]["done"].set()
+                self._active[r] = False
+                self._rows[r] = None
+
+    def _recover_resident_locked(self, err):
+        """The decode and the scatters donate the resident arrays.  A call
+        that fails while it traces or compiles has taken nothing; one that
+        fails later leaves them deleted, and with them every resident
+        row: those requests fail with ``err`` and the engine goes on with
+        fresh caches."""
+        if self._logits.is_deleted() or any(
+                k.is_deleted() or v.is_deleted() for k, v, _ in self._caches):
+            self._fail_active_locked(err)
+            self._init_resident()
+
     def _make_item(self, prompt, cfg, on_token, on_done=None, queue=None,
                    prefilled=None):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -427,7 +456,9 @@ class ContinuousBatchingEngine:
 
         Admission failures (trace/compile/device errors) fail ONLY the
         requests being admitted — the engine loop and resident rows
-        survive (a dead loop thread would deadlock every submitter).
+        survive (a dead loop thread would deadlock every submitter) —
+        unless the failing call had already taken the donated resident
+        arrays (``_recover_resident_locked``).
 
         ``rec`` is the trace recorder while tracing is on (see
         ``_phase``): a call that admits is then one ``engine.admit`` span
@@ -496,6 +527,7 @@ class ContinuousBatchingEngine:
                             if self._rows[r] is item:
                                 self._active[r] = False
                                 self._rows[r] = None
+                    self._recover_resident_locked(e)
             else:
                 # not enough for a pack: put back and fall through
                 self._queue.pushback(take)
@@ -608,6 +640,7 @@ class ContinuousBatchingEngine:
                     self._pool.release(seq, register=False)
                 item["error"] = e
                 item["done"].set()
+                self._recover_resident_locked(e)
         n = self.admissions - admitted_before
         if rec is not None and n:
             rec.complete("engine.admit", "serving", t_admit,
@@ -653,13 +686,7 @@ class ContinuousBatchingEngine:
                     for item in self._queue.drain():
                         item["error"] = err
                         item["done"].set()
-                    for r in range(self.B):
-                        if self._active[r]:
-                            self._rows[r]["error"] = err
-                            self._release_table(r, self._rows[r])
-                            self._rows[r]["done"].set()
-                            self._active[r] = False
-                            self._rows[r] = None
+                    self._fail_active_locked(err)
                     return
                 # the one look at the flag for this turn of the loop
                 rec = _ttrace.get_recorder() if _ttrace.enabled() \
@@ -675,13 +702,8 @@ class ContinuousBatchingEngine:
                 self.step_failures += 1
                 _STEP_FAILURES.inc()
                 with self._cv:
-                    for r in range(self.B):
-                        if self._active[r]:
-                            self._rows[r]["error"] = e
-                            self._release_table(r, self._rows[r])
-                            self._rows[r]["done"].set()
-                            self._active[r] = False
-                            self._rows[r] = None
+                    self._fail_active_locked(e)
+                    self._recover_resident_locked(e)
 
     def _step(self, rec=None):
         """One decode tick for every active row.  ``rec``: see ``_phase``;

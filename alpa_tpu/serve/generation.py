@@ -121,6 +121,38 @@ def speculative_accept(props, q_probs, p_probs, us, u_extra):
     return k, _sample_from_probs(p_probs[k], u_extra)
 
 
+def _jit_donating_kv(step):
+    """``jax.jit`` of a cached step ``step(params, tokens, index, caches)``
+    that donates the K and V arrays of ``caches`` (``[(k, v, index)]`` a
+    layer), so that the step writes the resident cache in place, and not
+    their indices: a caller may pass one index array as ``index`` and in
+    every layer's triple, and may read it after the call.
+
+    jax pairs donated arrays with outputs of the same shape in the order
+    of the two flattened trees, not by data flow, so the jitted function
+    (the result's ``jitted``) takes K and V as ``[(k, v)]`` a layer, the
+    order its result has them in: handed over as all K and then all V,
+    each layer's output would land in another layer's buffer at the price
+    of a copy of every cache."""
+
+    def split(params, tokens, index, kv, idxs):
+        return step(params, tokens, index,
+                    [(k, v, i) for (k, v), i in zip(kv, idxs)])
+
+    # the compiled program keeps the step's name (``jit_decode``): traces
+    # and the benchmark's readers find it by that
+    split.__name__ = split.__qualname__ = step.__name__
+    jitted = jax.jit(split, donate_argnums=(3,))
+
+    def call(params, tokens, index, caches):
+        return jitted(params, tokens, index,
+                      [(k, v) for k, v, _ in caches],
+                      [i for _, _, i in caches])
+
+    call.jitted = jitted
+    return call
+
+
 def default_prompt_buckets(seq_len: int) -> List[int]:
     """Power-of-two prompt-length buckets up to seq_len."""
     buckets, b = [], 32
@@ -144,6 +176,14 @@ class Generator:
     Mixed prompt lengths share one batch via per-row KV-cache indices.
     ``prefill_traces`` / ``decode_traces`` count actual retraces so tests
     can hold the bucketing to its promise.
+
+    ``_decode(params, token, index, caches)`` takes the K and V arrays of
+    ``caches`` away from its caller (``_jit_donating_kv``): the step writes
+    them in place, they are deleted after the call, and the caller goes on
+    with the caches the call returns.  The indices stay the caller's.
+    ``_prefill``, ``_chunk_prefill`` and the verify step donate nothing (a
+    ``PrefixHandle``'s caches are prefilled from again and again), nor
+    does the ``parallel_method`` decode.
     """
 
     def __init__(self, model: GPTModel, params, config: GPTConfig,
@@ -230,7 +270,7 @@ class Generator:
                 chunk_prefill, method=parallel_method, donate_argnums=())
         else:
             self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(decode)
+            self._decode = _jit_donating_kv(decode)
             self._chunk_prefill = jax.jit(chunk_prefill)
         # beam-search KV-cache gather, compiled once (per cache shapes)
         self._reorder = jax.jit(

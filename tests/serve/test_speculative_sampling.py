@@ -125,9 +125,12 @@ class TestEndToEndSampled:
         support0 = np.nonzero(p0)[0]
         joint = {}
         for t0 in support0:
+            # _decode takes the K and V arrays it is given: every branch
+            # from the prefilled state decodes a copy of them
+            branch = [(jnp.copy(k), jnp.copy(v), i) for k, v, i in caches0]
             l1, _ = target._decode(
                 target.params, jnp.asarray([[int(t0)]], jnp.int32),
-                caches0[0][2], caches0)
+                caches0[0][2], branch)
             p1 = _warp_probs_np(np.asarray(l1)[0], gcfg)
             for t1 in np.nonzero(p1)[0]:
                 joint[(int(t0), int(t1))] = float(p0[t0] * p1[t1])
