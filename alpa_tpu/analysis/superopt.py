@@ -54,7 +54,7 @@ Knobs: ``superopt_mode`` off|suggest|auto (+ ``superopt_beam_width``,
 ``superopt_max_group``; all under ``ALPA_TPU_SUPEROPT*``).  Metrics:
 ``alpa_superopt_*``.  Debug dump: ``superopt.txt``
 (``monitoring.dump_debug_info``).  Tooling: ``scripts/perf_tool.py
-superopt``; bench: ``benchmark/superopt_bench.py``.
+superopt``.
 """
 import copy
 import dataclasses
@@ -311,20 +311,15 @@ class _CostModel:
     """Per-instruction durations: calibrated medians when the store has
     enough samples (``calibration_min_samples``), analytic fallback
     otherwise.  Group-marginal pricing: a cross-mesh RESHARD directly
-    following a same-edge RESHARD pays only the byte leg (no per-message
-    latency) — the lowering will coalesce the pair into one batched
-    group, which is exactly what makes the fusion family profitable."""
+    following a same-edge RESHARD pays only the byte leg — the lowering
+    will coalesce the pair into one batched group.  No per-message
+    latency is priced (none is measured), so the two coincide above
+    1 us."""
 
     def __init__(self, store=None, min_samples: Optional[int] = None):
         self.store = store
         self.min_samples = min_samples
         self._cache: Dict[int, Tuple[str, float, float]] = {}
-        latency_s = float(getattr(
-            global_config, "resharding_transfer_latency_s", 0.0) or 0.0)
-        self.latency_us = latency_s * 1e6
-        bw = float(getattr(
-            global_config, "resharding_wire_bandwidth", 0.0) or 0.0)
-        self.bytes_per_us = (bw or _DEFAULT_WIRE_BYTES_PER_S) / 1e6
 
     def _measured(self, kind: str, signature: str) -> Optional[float]:
         if self.store is None:
@@ -347,15 +342,13 @@ class _CostModel:
             out = ("RUN", c, c)
         elif inst.opcode == PipelineInstType.RESHARD:
             nbytes = _key_nbytes(inst.var_key[0])
-            wire = nbytes / self.bytes_per_us if self.bytes_per_us else 0.0
+            wire = nbytes / (_DEFAULT_WIRE_BYTES_PER_S / 1e6)
             cross = inst.src_mesh != inst.dst_mesh
             c = self._measured("reshard_wire", _cal.edge_signature(
                 str(inst.src_mesh), str(inst.dst_mesh)))
             if c is None:
-                c = (self.latency_us + wire) if cross else \
-                    max(1.0, 0.5 * wire)
-            out = ("RESHARD", c, max(1.0, c - self.latency_us)
-                   if cross else c)
+                c = wire if cross else max(1.0, 0.5 * wire)
+            out = ("RESHARD", c, max(1.0, c) if cross else c)
         else:
             out = ("FREE", _FREE_US, _FREE_US)
         self._cache[key] = out
@@ -688,10 +681,9 @@ def deoptimize_instructions(instructions: Sequence[Any],
     identical), but with inverted list-scheduling priority and every
     FREE deferred as late as legality allows.  Live ranges stretch
     (peak bytes inflate) and streams serialize badly (the simulated
-    critical path inflates).  This is the bench's adversarial baseline
-    (``benchmark/superopt_bench.py``): the plan a register-file
-    emitter *could* legally have produced, which ``superopt_mode=auto``
-    must then recover."""
+    critical path inflates).  This is the tests' adversarial baseline:
+    the plan a register-file emitter *could* legally have produced,
+    which ``superopt_mode=auto`` must then recover."""
     import heapq
     from alpa_tpu.pipeline_parallel.runtime_emitter import (
         PipelineInstType)
@@ -869,7 +861,7 @@ def verdict_diff(baseline, candidate) -> Dict[str, Any]:
 @dataclasses.dataclass
 class SuperoptOutcome:
     """Everything one superopt run decided, for the executable, the
-    ``superopt.txt`` dump, tooling, and the bench."""
+    ``superopt.txt`` dump and tooling."""
     mode: str                           # superopt_mode at decision time
     searched: bool                      # False on a warm cache replay
     cache_hit: bool

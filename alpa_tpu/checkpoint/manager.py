@@ -9,8 +9,7 @@
   state.  Hashing + chunk writes + manifest commit + retention GC all
   run on a background thread, so train step N+1 overlaps the disk write
   of step N.  ``last_blocking_seconds`` records exactly how long the
-  training loop was stalled — the number the <10%-of-sync acceptance
-  test asserts on.
+  training loop was stalled.
 * **Save-failure surfacing** — a background write that fails is never
   silent: the first exception re-raises (wrapped in
   :class:`CheckpointSaveError`) from the next ``save()`` or ``wait()``.
@@ -111,7 +110,7 @@ class CheckpointManager:
         self._pending_step: Optional[int] = None
         self._errors: List[CheckpointSaveError] = []
         self._err_lock = threading.Lock()
-        # stall accounting for the <10%-blocking acceptance criterion
+        # stall accounting: what save() kept its caller for
         self.last_staging_seconds = 0.0
         self.last_write_seconds = 0.0
         self.last_blocking_seconds = 0.0

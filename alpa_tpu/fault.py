@@ -601,7 +601,7 @@ class RecoveryManager:
     * RECOVERING, retries exhaust -> DEGRADED (``on_degrade`` fires;
       the serving stack sheds load with 503s instead of crashing).
     * DEGRADED, probe succeeds    -> HEALTHY (meshes un-wedge on their
-      own; see bench.py's probe-and-wait discipline).
+      own).
 
     All callbacks are best-effort: a raising hook is logged, never
     allowed to kill the watchdog thread.

@@ -26,8 +26,6 @@ class GlobalConfig:
         # ---------- backend ----------
         # "tpu" | "cpu" | "gpu".  Used to pick the jax platform for meshes.
         self.backend = os.environ.get("ALPA_TPU_BACKEND", None)  # None = jax default
-        # Treated like the reference's has_cuda: whether real accelerators exist.
-        self.debug_single_device = _env_bool("ALPA_TPU_DEBUG_SINGLE_DEVICE", False)
 
         # ---------- compilation ----------
         # Print compilation phase timings (ref: debug_compilation_time).
@@ -43,8 +41,6 @@ class GlobalConfig:
             "ALPA_TPU_PROF_DATABASE", None)
         # Time limit (seconds) handed to the ILP solver.
         self.ilp_time_limit = int(os.environ.get("ALPA_TPU_ILP_TIME_LIMIT", "600"))
-        # Seed used for deterministic compilation decisions.
-        self.compile_seed = int(os.environ.get("ALPA_TPU_COMPILE_SEED", "42"))
         # Weight-update (ZeRO) sharding stage: "auto" lets the ILP choose
         # sharded optimizer state by cost (memory term vs all-gather
         # traffic), "0" disables it, "2" shards optimizer state over the
@@ -103,13 +99,6 @@ class GlobalConfig:
         # (any mode — recorded via the unified telemetry recorder and
         # exported by dump_stage_execution_trace).
         self.collect_trace = _env_bool("ALPA_TPU_COLLECT_TRACE", False)
-        # Use dummy data for benchmarking (skip real input transfer).
-        self.use_dummy_value_for_benchmarking = _env_bool(
-            "ALPA_TPU_DUMMY_VALUES", False)
-        # Shard the apply_grad computation over the pipeline meshes instead of
-        # replicating (ref: grad accumulation + apply grad placement).
-        self.pipeline_distributed_apply_grad = _env_bool(
-            "ALPA_TPU_DISTRIBUTED_APPLY_GRAD", True)
         # Static plan verification (ISSUE 8): every lowered register-file
         # program runs the alpa_tpu.analysis.plan_verifier analyses (slot
         # typing, cross-mesh deadlock freedom, liveness/leaks, structural
@@ -178,42 +167,10 @@ class GlobalConfig:
         # overlap_window_hint().
         self.overlap_inflight_window = int(os.environ.get(
             "ALPA_TPU_OVERLAP_WINDOW", "0"))
-        # Treat every cross-mesh transfer as synchronous: block until the
-        # destination arrays have materialized before returning.  The CPU
-        # test backend's copies are fully asynchronous, so RESHARD never
-        # blocks the dispatching thread there; multi-host send/recv
-        # backends do block.  This knob emulates that regime (used by
-        # benchmark/bench_dispatch.py's reshard-dominated payload to
-        # compare dispatch modes under blocking transfers).
-        self.sync_resharding_transfers = _env_bool(
-            "ALPA_TPU_SYNC_TRANSFERS", False)
-        # Emulated wire latency per cross-mesh transfer call, in seconds
-        # (implies synchronous semantics: the transfer materializes, then
-        # the calling thread idles for the latency).  The CPU test
-        # backend moves shards with an in-process memcpy, so the
-        # send/recv wire time a real multi-host link adds is absent;
-        # this knob reintroduces it so the dispatch-mode benchmark can
-        # measure how much of that idle time each mode hides.  0 = off.
-        self.resharding_transfer_latency_s = float(os.environ.get(
-            "ALPA_TPU_TRANSFER_LATENCY", "0"))
-        # How resharding_transfer_latency_s is charged (ISSUE 7):
-        # "call" (legacy) idles once per transfer call regardless of the
-        # transfer's link structure; "link" idles latency x the busiest
-        # link's message count (plus bytes/bandwidth when
-        # resharding_wire_bandwidth is set), so collective strategies
-        # that cut per-link messages show their wall-clock win under
-        # emulation.  The strategy cost model mirrors whichever model is
-        # active, keeping auto selection honest about what it is timed
-        # against.
-        self.resharding_wire_model = os.environ.get(
-            "ALPA_TPU_WIRE_MODEL", "call")
-        # Emulated per-link wire bandwidth in bytes/s for the "link"
-        # model; 0 = latency-only emulation.
-        self.resharding_wire_bandwidth = float(os.environ.get(
-            "ALPA_TPU_WIRE_BANDWIDTH", "0"))
         # Cross-mesh RESHARD lowering strategy (ISSUE 7): "auto" picks
-        # per edge by the collective cost model (wire-emulation cross
-        # leg + mesh_profiling intra-mesh collective leg); forcing
+        # per edge by the collective cost model (mesh_profiling's
+        # intra-mesh collective leg; the cross-mesh leg has no price
+        # yet, so "auto" is direct_p2p until one is measured); forcing
         # "direct_p2p" | "slice_all_gather" | "all_to_all" |
         # "reduce_scatter_gather" pins every edge where the strategy is
         # eligible (ineligible edges fall back to direct_p2p).
@@ -356,10 +313,6 @@ class GlobalConfig:
         # register-replay hot path stays within 2% of the no-telemetry
         # baseline when this is off (guarded in tier-1).
         self.telemetry_enabled = _env_bool("ALPA_TPU_TRACE", False)
-        # Where scripts/trace_tool.py and instrumented entry points drop
-        # Chrome-trace JSON files.  None = caller chooses.
-        self.telemetry_trace_dir = os.environ.get(
-            "ALPA_TPU_TRACE_DIR", None)
         # Cap on buffered events per TraceRecorder store (spans /
         # instants / counters each); overflow increments a drop counter
         # in the exported trace instead of growing without bound.
@@ -380,8 +333,7 @@ class GlobalConfig:
         # system temp dir.
         self.flight_dump_dir = os.environ.get("ALPA_TPU_FLIGHT_DIR", None)
         # Chip peak bf16 TFLOPS used by the MFU attribution
-        # (telemetry/perf.py — the single formula bench.py and
-        # scripts/mfu_breakdown.py also ride).  0 = auto-detect from the
+        # (telemetry/perf.py).  0 = auto-detect from the
         # TPU generation via mesh_profiling.TPU_GENERATION_SPECS; set
         # explicitly for CPU/emulated runs so stage-MFU numbers stay
         # meaningful.
@@ -467,23 +419,8 @@ class GlobalConfig:
         self.disagg_retain_artifacts = int(os.environ.get(
             "ALPA_TPU_DISAGG_RETAIN_ARTIFACTS", "64"))
 
-        # ---------- checkpointing ----------
-        # Local cache dir drained asynchronously to the shared FS
-        # (ref: DaemonMoveWorker).
-        self.checkpoint_cache_dir = os.environ.get("ALPA_TPU_CKPT_CACHE", None)
-
-        # ---------- testing ----------
-        # Replace heavy compile paths with fast ones in unit tests.
-        self.testing_mode = _env_bool("ALPA_TPU_TESTING", False)
-
     def show(self):
         return {k: v for k, v in self.__dict__.items()}
 
 
 global_config = GlobalConfig()
-
-# Flags appended to XLA_FLAGS at import, mirroring the reference's
-# global_env.py:144-146.  Kept minimal: libtpu picks good defaults.
-_xla_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_tpu_spmd_threshold_for_allgather_cse" not in _xla_flags:
-    pass  # placeholder: no forced flags; users own XLA_FLAGS.

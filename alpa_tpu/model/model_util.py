@@ -74,8 +74,8 @@ def create_adamw(learning_rate=1e-3, weight_decay=0.01, b1=0.9, b2=0.999,
 def gpt_lm_loss(apply_fn, params, batch, chunked=False):
     """LM loss for a GPT-family model with tied embeddings: dense fp32
     CE, or the fused/chunked lm-head + CE that never materializes the
-    full logits tensor (shared by bench.py and scripts/bench_sweep.py so
-    the measured loss formulation cannot drift between them)."""
+    full logits tensor (shared by the benchmark's drivers, the smoke
+    and the tools so the loss formulation cannot drift between them)."""
     if chunked:
         hidden = apply_fn(params, batch["input_ids"], return_hidden=True)
         emb = params["params"]["wte"]["embedding"]

@@ -1,12 +1,12 @@
-"""2D UNets (diffusion-style) for the vision benchmark suite.
+"""2D UNets (diffusion-style).
 
 Analog of ref ``alpa/model/unet_2d.py`` (1207 LoC diffusers-style
 ``FlaxUNet2DConditionModel`` used by ``benchmark/alpa/suite_unet.py``).
 
 Two models live here:
 
-* ``UNet2D`` — compact unconditioned UNet (kept for the CPU-runnable
-  benchmark suites and conv-planner tests).
+* ``UNet2D`` — compact unconditioned UNet (kept for the conv-planner
+  tests).
 * ``UNet2DConditionModel`` — the reference-scale conditioned UNet:
   sinusoidal timestep embeddings + MLP, ResNet blocks with time-embedding
   injection, spatial transformers with cross-attention on encoder hidden
@@ -44,7 +44,7 @@ class UNetConfig:
 class UNetConditionConfig:
     """Reference-scale conditioned UNet (ref FlaxUNet2DConditionModel,
     unet_2d.py:900; defaults shrunk from the SD-class (320,640,1280,1280)
-    so tests stay fast — benchmark suites pass the full widths)."""
+    so tests stay fast)."""
     sample_size: int = 32
     in_channels: int = 4
     out_channels: int = 4
@@ -436,7 +436,7 @@ class AttnBlock2D(nn.Module):
 
 
 class UNet2D(nn.Module):
-    """Compact unconditioned UNet (benchmark suites, conv-planner tests)."""
+    """Compact unconditioned UNet (conv-planner tests)."""
     config: UNetConfig
 
     @nn.compact

@@ -180,7 +180,7 @@ class ReshardingTaskSpec:
     strategy_stats: Dict[str, Dict[str, float]] = dataclasses.field(
         default_factory=dict)
     # the CHOSEN strategy's busiest-link message count and total wire
-    # bytes (feeds the "link" wire-emulation model and reports)
+    # bytes (reports)
     wire_messages: int = 1
     wire_bytes: float = 0.0
     # whether the strategy decision came from the compile cache
@@ -740,31 +740,20 @@ def collective_options(shape, itemsize, src_sharding, dst_sharding
     return opts
 
 
-def _strategy_cost(stats: Dict[str, float], kind: Optional[str],
-                   nbytes: float, cal, lat: float, bw: float,
-                   model: str, intra_us: Optional[float] = None) -> float:
-    """Estimated edge seconds = cross-mesh wire leg (mirroring the
-    active emulation model, so auto selection is honest about what it is
-    timed against) + intra-destination collective leg from
+def _strategy_cost(kind: Optional[str], nbytes: float, cal,
+                   intra_us: Optional[float] = None) -> float:
+    """Estimated edge seconds: the intra-destination collective leg from
     mesh_profiling's calibrated (alpha, beta) cost dicts.
 
     ``intra_us`` (ISSUE 12): a measured collective cost from the
-    calibration store that supersedes the alpha-beta estimate for the
-    intra leg."""
-    if model == "link":
-        cross = lat * stats["max_link_messages"]
-    else:                       # "call": one idle per transfer call
-        cross = lat
-    if bw:
-        cross += stats["max_link_bytes"] / bw
-    intra = 0.0
+    calibration store that supersedes the alpha-beta estimate."""
     if intra_us is not None:
-        intra = intra_us * 1e-6
-    elif kind is not None and cal is not None:
+        return intra_us * 1e-6
+    if kind is not None and cal is not None:
         ab = cal.alpha_beta(kind)
         if ab is not None:
-            intra = ab[0] + ab[1] * nbytes
-    return cross + intra
+            return ab[0] + ab[1] * nbytes
+    return 0.0
 
 
 def choose_strategy(shape, itemsize, src_sharding, dst_sharding
@@ -774,6 +763,11 @@ def choose_strategy(shape, itemsize, src_sharding, dst_sharding
     (``global_config.reshard_strategy`` forces a specific one when not
     "auto"; ineligible forced strategies fall back to direct_p2p).
     Returns (strategy, per-candidate costs, candidate options).
+
+    The cross-mesh leg has no analytic price: a candidate costs its
+    intra-destination collective leg only, so without measurements
+    ``direct_p2p`` always wins, until ROADMAP A1a gives that leg a
+    measured price.
 
     Under ``replan_mode != off`` (ISSUE 12) the calibration store
     supersedes the analytic price wherever it has enough measured
@@ -791,9 +785,6 @@ def choose_strategy(shape, itemsize, src_sharding, dst_sharding
         cal = get_effective_calibration()
     except Exception:  # pylint: disable=broad-except
         cal = None
-    lat = global_config.resharding_transfer_latency_s
-    bw = getattr(global_config, "resharding_wire_bandwidth", 0.0)
-    model = getattr(global_config, "resharding_wire_model", "call")
     nbytes = float(np.prod(shape, dtype=np.int64)) * itemsize \
         if shape else float(itemsize)
     store = _calibration.get_calibration_store() \
@@ -805,8 +796,7 @@ def choose_strategy(shape, itemsize, src_sharding, dst_sharding
         return store.measured_us(
             "collective", _calibration.collective_signature(kind, nbytes))
 
-    costs = {name: _strategy_cost(o["stats"], o["kind"], nbytes, cal,
-                                  lat, bw, model,
+    costs = {name: _strategy_cost(o["kind"], nbytes, cal,
                                   intra_us=_intra_us(o["kind"]))
              for name, o in opts.items()}
     if store is not None:
@@ -856,10 +846,7 @@ def resolve_strategy(shape, itemsize, src_sharding, dst_sharding
     tok = calibration_cache_token()
     parts = (tuple(shape), int(itemsize),
              _sharding_key(src_sharding), _sharding_key(dst_sharding),
-             getattr(global_config, "reshard_strategy", "auto"),
-             getattr(global_config, "resharding_wire_model", "call"),
-             global_config.resharding_transfer_latency_s,
-             getattr(global_config, "resharding_wire_bandwidth", 0.0)) \
+             getattr(global_config, "reshard_strategy", "auto")) \
         + ((tok,) if tok else ())
     cache = get_compile_cache() if cache_enabled() else None
     key = cache.make_key("reshard_strategy", parts) if cache else None
@@ -891,8 +878,8 @@ _STRATEGY_COUNT = _PLANNER_REG.counter(
 def strategy_plan_fingerprint() -> str:
     """Content hash over the recorded per-edge strategy decisions (in
     recording order): two runs that planned the same edges to the same
-    strategies fingerprint identically — the warm-restart replay check
-    in benchmark/resharding_bench.py."""
+    strategies fingerprint identically (the warm-restart replay
+    check)."""
     import hashlib
     h = hashlib.sha256()
     for p in _RECENT_PLANS:
@@ -951,49 +938,6 @@ def shard_structures_match(shape, src_sharding, dst_sharding) -> bool:
     return list(src_map.values()) == list(dst_map.values())
 
 
-def _apply_sync_semantics(out, wire=None):
-    """Blocking-transfer emulation (ISSUE 4 benchmark support).
-
-    The CPU test backend's shard moves are asynchronous in-process
-    memcpys, so a RESHARD never blocks the thread that issued it —
-    unlike multi-host send/recv, which blocks for producer readiness
-    plus wire latency.  With ``sync_resharding_transfers`` the calling
-    thread blocks until the destination arrays materialize; with
-    ``resharding_transfer_latency_s`` it additionally idles for the
-    emulated wire time.  Both default off and cost one attribute read
-    per transfer call.
-
-    ``wire``, when given, is the transfer's ``(max_link_messages,
-    max_link_bytes)`` from the planner's link stats.  Under
-    ``resharding_wire_model == "link"`` the idle time scales with the
-    busiest link — ``latency × messages + bytes / bandwidth`` — so a
-    strategy that sends fewer, bigger messages per link actually runs
-    faster under emulation, matching what the cost model charges it.
-    The default ``"call"`` model keeps the legacy one-idle-per-call
-    semantics regardless of ``wire``.
-    """
-    from alpa_tpu.global_env import global_config
-    lat = global_config.resharding_transfer_latency_s
-    bw = getattr(global_config, "resharding_wire_bandwidth", 0.0)
-    if lat or bw or global_config.sync_resharding_transfers:
-        import time as _time
-
-        import jax
-        jax.block_until_ready(out)
-        idle = 0.0
-        if (wire is not None and
-                getattr(global_config, "resharding_wire_model",
-                        "call") == "link"):
-            msgs, link_bytes = wire
-            idle = lat * max(1, int(msgs))
-            if bw:
-                idle += link_bytes / bw
-        elif lat:
-            idle = lat
-        if idle:
-            _time.sleep(idle)
-
-
 class DirectTransfer:
     """Pre-resolved, reusable executor for one RESHARD edge (ISSUE 2:
     "plan once, replay as pre-resolved tasks", arXiv:2211.05322).
@@ -1014,14 +958,11 @@ class DirectTransfer:
     """
 
     __slots__ = ("dst_sharding", "src_sharding", "ndim", "fast",
-                 "nbytes", "wire", "_dst_devices", "_semantics")
+                 "nbytes", "_dst_devices", "_semantics")
 
     def __init__(self, aval, src_sharding, dst_sharding):
         self.dst_sharding = dst_sharding
         self.src_sharding = src_sharding
-        # (max_link_messages, max_link_bytes) for the "link" wire model;
-        # set by make_transfer from the planner's link stats
-        self.wire = None
         self.ndim = len(getattr(aval, "shape", ()))
         shape = tuple(getattr(aval, "shape", ()))
         try:
@@ -1053,22 +994,18 @@ class DirectTransfer:
         return self._transfer(val)
 
     def _transfer(self, val):
-        out = None
         if self.fast:
             try:
                 if val.sharding.is_equivalent_to(self.src_sharding,
                                                  self.ndim):
                     import jaxlib.xla_extension as xe
-                    out = xe.batched_copy_array_to_devices_with_sharding(
+                    return xe.batched_copy_array_to_devices_with_sharding(
                         [val], [self._dst_devices], [self.dst_sharding],
                         [self._semantics])[0]
             except Exception:  # pylint: disable=broad-except
-                out = None
-        if out is None:
-            import jax
-            out = jax.device_put(val, self.dst_sharding)
-        _apply_sync_semantics(out, wire=self.wire)
-        return out
+                pass
+        import jax
+        return jax.device_put(val, self.dst_sharding)
 
 
 class DirectTransferGroup:
@@ -1099,28 +1036,19 @@ class DirectTransferGroup:
 
     def _transfer(self, vals):
         ts = self.transfers
-        out = None
         if self.all_fast:
             try:
                 if all(v.sharding.is_equivalent_to(t.src_sharding, t.ndim)
                        for v, t in zip(vals, ts)):
                     import jaxlib.xla_extension as xe
-                    out = xe.batched_copy_array_to_devices_with_sharding(
+                    return xe.batched_copy_array_to_devices_with_sharding(
                         list(vals), [t._dst_devices for t in ts],
                         [t.dst_sharding for t in ts],
                         [t._semantics for t in ts])
             except Exception:  # pylint: disable=broad-except
-                out = None
-        if out is None:
-            import jax
-            out = jax.device_put(list(vals), [t.dst_sharding for t in ts])
-        # one emulated wire round-trip for the whole coalesced message;
-        # under the link model, member messages on a link still queue
-        wires = [t.wire for t in ts if t.wire is not None]
-        wire = (sum(w[0] for w in wires),
-                sum(w[1] for w in wires)) if wires else None
-        _apply_sync_semantics(out, wire=wire)
-        return out
+                pass
+        import jax
+        return jax.device_put(list(vals), [t.dst_sharding for t in ts])
 
 
 class CollectiveTransfer:
@@ -1140,17 +1068,14 @@ class CollectiveTransfer:
        item 1).
 
     Both legs are pure data movement — no arithmetic — so every strategy
-    here is bit-exact against ``direct_p2p``.  The emulated wire idle is
-    applied to the wire leg only, scaled by this strategy's busiest-link
-    message count under the ``"link"`` wire model.
+    here is bit-exact against ``direct_p2p``.
     """
 
     __slots__ = ("strategy", "dst_sharding", "src_sharding",
-                 "inter_sharding", "ndim", "nbytes", "wire", "fast",
-                 "_relayout")
+                 "inter_sharding", "ndim", "nbytes", "fast", "_relayout")
 
     def __init__(self, aval, src_sharding, dst_sharding, strategy,
-                 inter_sharding, wire=None):
+                 inter_sharding):
         self.strategy = strategy
         self.dst_sharding = dst_sharding
         self.src_sharding = src_sharding
@@ -1163,7 +1088,6 @@ class CollectiveTransfer:
                               np.dtype(aval.dtype).itemsize)
         except Exception:  # pylint: disable=broad-except
             self.nbytes = 0
-        self.wire = wire
         self._relayout = None
 
     def __call__(self, val):
@@ -1177,7 +1101,6 @@ class CollectiveTransfer:
     def _transfer(self, val):
         import jax
         staged = jax.device_put(val, self.inter_sharding)
-        _apply_sync_semantics(staged, wire=self.wire)
         if self._relayout is None:
             self._relayout = jax.jit(lambda x: x,
                                      out_shardings=self.dst_sharding)
@@ -1219,15 +1142,10 @@ def make_transfer(aval, src_sharding, dst_sharding, cross=False,
                 shape, itemsize, src_sharding, dst_sharding)
             if strat not in opts:
                 strat = "direct_p2p"
-        st = opts[strat]["stats"]
-        wire = (st["max_link_messages"], st["max_link_bytes"])
         if strat == "direct_p2p":
-            t = DirectTransfer(aval, src_sharding, dst_sharding)
-            t.wire = wire
-            return t
+            return DirectTransfer(aval, src_sharding, dst_sharding)
         return CollectiveTransfer(aval, src_sharding, dst_sharding,
-                                  strat, opts[strat]["landing"],
-                                  wire=wire)
+                                  strat, opts[strat]["landing"])
     except Exception:  # pylint: disable=broad-except
         logger.warning("make_transfer: collective lowering failed; "
                        "using DirectTransfer", exc_info=True)
@@ -1241,12 +1159,8 @@ def make_ingest_transfer(aval, dst_sharding):
     KV handoff, serve.disagg: the prefill replica's payload arrives as
     numpy and must land exactly where the decode engine's resident
     caches live).  A plain :class:`DirectTransfer` with no source
-    sharding: the fast copy path is off, ``device_put`` lands it, and
-    the wire-emulation knobs (``resharding_transfer_latency_s``,
-    ``resharding_wire_bandwidth``) still model the hop."""
-    t = DirectTransfer(aval, None, dst_sharding)
-    t.wire = (1, float(t.nbytes))
-    return t
+    sharding: the fast copy path is off and ``device_put`` lands it."""
+    return DirectTransfer(aval, None, dst_sharding)
 
 
 @dataclasses.dataclass

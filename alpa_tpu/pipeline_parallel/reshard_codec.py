@@ -25,8 +25,7 @@ tests/pipeline_parallel/test_reshard_strategies.py):
   step is 1/16 ≈ 6.25%).
 
 Wire accounting: an fp32 edge under int8 moves ``N + 4 * ceil(N/256)``
-bytes instead of ``4 * N`` — a ~3.94x reduction for block-aligned sizes
-(the ≥3.5x acceptance floor in benchmark/resharding_collectives.json).
+bytes instead of ``4 * N`` — a ~3.94x reduction for block-aligned sizes.
 
 Gradient variant (ISSUE 19; same EQuARX lineage): the ``grad_*``
 entries of :data:`ERROR_BOUND` cover the quantized gradient-collective
@@ -181,12 +180,11 @@ class QuantizedTransfer:
     """Executor for one quantized cross-mesh RESHARD edge: encode on the
     source mesh (jit), ``device_put`` the narrow payload + scales to the
     destination mesh, decode on the destination mesh (jit, straight into
-    ``dst_sharding``).  The emulated wire idle sees only the narrow
-    payload's byte count, so the bench's wall-clock win is honest."""
+    ``dst_sharding``)."""
 
     __slots__ = ("mode", "dst_sharding", "src_sharding", "shape",
-                 "dtype", "ndim", "nbytes", "wire", "fast", "_enc",
-                 "_dec", "_land_q", "_land_s")
+                 "dtype", "ndim", "nbytes", "fast", "_enc", "_dec",
+                 "_land_q", "_land_s")
 
     def __init__(self, aval, src_sharding, dst_sharding, mode):
         self.mode = mode
@@ -198,7 +196,6 @@ class QuantizedTransfer:
         self.fast = False
         self.nbytes = int(np.prod(self.shape, dtype=np.int64) *
                           np.dtype(aval.dtype).itemsize)
-        self.wire = None
         self._enc = None
         self._dec = None
         self._land_q = None
@@ -249,18 +246,10 @@ class QuantizedTransfer:
         land_q, land_s = self._landing_shardings()
         q = jax.device_put(q, land_q)
         scale = jax.device_put(scale, land_s)
-        _apply = _sync()
-        _apply((q, scale), wire=self.wire)
         out = self._dec(q, scale)
         _Q_EDGES.labels(self.mode).inc()
         _Q_BYTES_SAVED.inc(max(0, self.nbytes - self.wire_nbytes))
         return out
-
-
-def _sync():
-    from alpa_tpu.pipeline_parallel.cross_mesh_resharding import (
-        _apply_sync_semantics)
-    return _apply_sync_semantics
 
 
 def maybe_quantized_transfer(aval, src_sharding, dst_sharding,
@@ -270,13 +259,7 @@ def maybe_quantized_transfer(aval, src_sharding, dst_sharding,
     try:
         if not eligible(aval, mode):
             return None
-        t = QuantizedTransfer(aval, src_sharding, dst_sharding, mode)
-        # busiest-link wire stats for the narrow payload: the "link"
-        # model charges the quantized edge only its reduced bytes
-        wb = t.wire_nbytes
-        ndst = max(1, len(dst_sharding.mesh.devices.flat))
-        t.wire = (1, float(wb) / ndst)
-        return t
+        return QuantizedTransfer(aval, src_sharding, dst_sharding, mode)
     except Exception:  # pylint: disable=broad-except
         logger.warning("quantized transfer setup failed; falling back",
                        exc_info=True)
@@ -325,8 +308,8 @@ def note_grad_quantized(codec: str, full_bytes: int,
 
 
 def note_error_feedback_norm(value: float) -> None:
-    """Export the residual-buffer L2 norm (host-side, set by the bench
-    and tests after pulling the residual off the device)."""
+    """Export the residual-buffer L2 norm (host-side, set by a caller
+    after pulling the residual off the device)."""
     _GQ_EF_NORM.set(float(value))
 
 

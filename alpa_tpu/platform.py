@@ -55,8 +55,8 @@ def enable_compilation_cache() -> str:
     directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it
     itself and no other directory is set here; where it is not, the
     cache lands at ``DEFAULT_COMPILATION_CACHE_DIR``.  Called by entry
-    points (``chip_smoke.py``, ``bench.py``, the benchmarks, the
-    examples), never by ``import alpa_tpu``.  This is jax's cache of
+    points (``chip_smoke.py``, ``chipbench/run.py``, the examples),
+    never by ``import alpa_tpu``.  This is jax's cache of
     compiled programs; ``ALPA_TPU_CACHE_DIR`` (the plan cache) is a
     different thing."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")

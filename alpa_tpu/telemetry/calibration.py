@@ -39,8 +39,7 @@ This module closes the loop:
 Consumers: ``cross_mesh_resharding.choose_strategy`` (wire + collective
 legs), ``mesh_profiling.estimate_stage_cost`` (stage compute), and
 ``PipeshardDriverExecutable.consider_replan`` (the suggest/auto replan
-driver).  ``benchmark/replan_bench.py`` replays the committed fixture
-trace through calibrate→replan and gates the result.
+driver).
 """
 import dataclasses
 import hashlib
@@ -690,7 +689,7 @@ def ingest_chrome_trace(trace: Dict[str, Any],
                         store: Optional[CalibrationStore] = None,
                         modeled: Optional[Dict[str, float]] = None
                         ) -> Dict[str, int]:
-    """Ingest a saved Chrome trace (scripts / replan_bench entry point):
+    """Ingest a saved Chrome trace (scripts entry point):
     the last ``pipeshard.step`` envelope's spans, joined exactly like
     the perf analyzer joins them."""
     from alpa_tpu.telemetry import perf as _perf

@@ -25,9 +25,8 @@ Published to the central metrics registry as ``alpa_stage_mfu{stage}``,
 ``alpa_step_bubble_fraction{mesh}`` and ``alpa_critical_path_us``;
 surfaced as ``perf_report.txt`` in debug dumps,
 ``PipeshardDriverExecutable.get_perf_report()``, and
-``scripts/perf_tool.py``.  This module is also the single home of the
-peak-FLOPs/MFU formula (``bench.py`` and ``scripts/mfu_breakdown.py``
-are thin callers).
+``scripts/perf_tool.py``.  This module is also the home of the
+library's peak-FLOPs/MFU formula.
 """
 import collections
 import dataclasses

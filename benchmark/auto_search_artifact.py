@@ -3,8 +3,8 @@
 Runs the full auto path (layer clustering -> cost model [checked-in DB or
 analytic TPU calibration] -> OSDI'22 stage DP) COMPILE-ONLY on a virtual
 8-device mesh for a GPT-6.7B-class model, and commits the chosen plan
-(stages x submeshes x microbatches) next to the suites — the analog of the
-reference's recorded GPT-39B solution (ref benchmark/alpa/
+(stages x submeshes x microbatches) under benchmark/results/ — the analog
+of the reference's recorded GPT-39B solution (ref benchmark/alpa/
 suite_auto_gpt.py:71-84).  No TPU or model weights needed: parameters are
 abstract (jax.eval_shape), the search runs on jaxprs.
 
@@ -20,6 +20,14 @@ sys.path.insert(0, REPO)
 
 DEFAULT_OUT = os.path.join(REPO, "benchmark", "results",
                            "auto_plan_gpt{model}_8dev.json")
+
+# the rungs of the reference's GPT ladder (seq 1024, vocab 51200, ref
+# suite_manual_gpt.py:18-26) that have a recorded plan under results/
+GPT_SPECS = {
+    "6.7B": dict(hidden_size=4096, num_layers=32, num_heads=32),
+    "15B": dict(hidden_size=5120, num_layers=48, num_heads=40),
+    "39B": dict(hidden_size=8192, num_layers=48, num_heads=64),
+}
 
 
 def search_gpt_plan(model_name="6.7B", n_devices=8, batch_size=32,
@@ -41,7 +49,6 @@ def search_gpt_plan(model_name="6.7B", n_devices=8, batch_size=32,
     from alpa_tpu.pipeline_parallel.layer_construction import AutoLayerOption
     from alpa_tpu.pipeline_parallel.stage_construction import AutoStageOption
     from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
-    from benchmark.suites import GPT_SPECS
 
     spec = GPT_SPECS[model_name]
     cfg = GPTConfig(seq_len=seq_len, vocab_size=51200, dtype=jnp.bfloat16,
@@ -66,8 +73,8 @@ def search_gpt_plan(model_name="6.7B", n_devices=8, batch_size=32,
         state, batch = jax.tree_util.tree_unflatten(tree, leaves)
 
         def loss_fn(p):
-            # the same loss formulation bench.py measures (shared helper
-            # so the searched jaxpr cannot drift from the benchmarked one)
+            # the loss formulation the benchmark's driver measures (shared
+            # helper: the searched jaxpr cannot drift from the benchmarked)
             return gpt_lm_loss(state.apply_fn, p, batch)
 
         loss, grads = alpa_tpu.value_and_grad(loss_fn)(state.params)
@@ -98,7 +105,7 @@ def search_gpt_plan(model_name="6.7B", n_devices=8, batch_size=32,
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="6.7B")
+    ap.add_argument("--model", default="6.7B", choices=sorted(GPT_SPECS))
     ap.add_argument("--out", default=None)
     ap.add_argument("--pod", action="store_true",
                     help="pod-scale search: 8 hosts x 8 devices, bigger "

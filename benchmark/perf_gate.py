@@ -1,24 +1,26 @@
-"""Perf regression gate: fresh measurements vs committed baselines
-(ISSUE 9).
+"""Gate: fresh values against committed ones (ISSUE 9).
 
-Compares a flat metric dict — produced by analyzing a trace with
-``alpa_tpu.telemetry.perf`` and/or by the dispatch/resharding benches —
-against ``benchmark/results/perf_gate_baseline.json``, which names each
-gated metric with its committed value and tolerance::
+Compares a flat metric dict against
+``benchmark/results/perf_gate_baseline.json``, which names each gated
+metric with its committed value and tolerance::
 
     {"metrics": {
-        "critical_path_us":       {"value": 596.0, "max_ratio": 1.05},
-        "modes.registers.per_inst_us": {"value": 40.0, "max_ratio": 5.0}
+        "critical_path_us":  {"value": 596.0, "max_ratio": 1.05},
+        "modelcheck.states": {"value": 22.0, "max_ratio": 1.0,
+                              "min_ratio": 1.0}
     }}
 
-``max_ratio`` bounds fresh/baseline above (regressions); optional
-``min_ratio`` bounds it below (for metrics where *shrinking* is the
-regression, e.g. overlap_fraction); optional ``max_abs`` is an absolute
-ceiling.  Only metrics present in BOTH the fresh dict and the baseline
-are checked, so one committed baseline serves both the deterministic
-fixture-trace test (tier-1) and the machine-dependent bench ``--gate``
-runs.  The verdict is machine-readable and every run increments
-``alpa_perf_gate_total{result}`` in the central registry.
+The baseline holds no time measured on a machine: its entries are the
+values ``alpa_tpu.telemetry.perf`` computes from the committed fixture
+trace, and the exact counts (states explored, terms, error bounds,
+byte ratios) that tier-1 tests pass to :func:`gate`.  Speed is measured by ``chipbench/``
+on the chip and recorded in ``PERF_LEDGER.jsonl``, not here.
+
+``max_ratio`` bounds fresh/baseline above; optional ``min_ratio`` bounds
+it below; optional ``max_abs`` is an absolute ceiling.  Only metrics
+present in BOTH the fresh dict and the baseline are checked, so one
+baseline serves every caller.  The verdict is machine-readable and every
+run increments ``alpa_perf_gate_total{result}`` in the central registry.
 
 Usage::
 

@@ -155,7 +155,7 @@ def _gpt_config(depth: int, tiny: bool, boundary_every: int = 0):
 
 
 def _build_train(method, config, batch_size, seed):
-    """The train step of bench.py under ``method``, and what it runs on:
+    """The GPT train step under ``method``, and what it runs on:
     (train_step, create_state, batch, abstract (state, batch))."""
     import jax
     import jax.numpy as jnp

@@ -1,4 +1,4 @@
-"""BASELINE config #2: GPT-2-class intra-op auto-sharding on one host.
+"""GPT-2-class intra-op auto-sharding on one host.
 
   python examples/gpt2_training.py                 # real chip(s)
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \

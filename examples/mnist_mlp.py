@@ -1,4 +1,4 @@
-"""BASELINE config #1: MLP trained via @alpa_tpu.parallelize.
+"""MLP trained via @alpa_tpu.parallelize.
 
 Runs on any device set; use the virtual CPU mesh for a pod stand-in:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \

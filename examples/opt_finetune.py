@@ -1,4 +1,4 @@
-"""BASELINE config #3 shape: decoder-LM finetuning with inter+intra-op
+"""Decoder-LM finetuning with inter+intra-op
 (pipeshard) parallelism.
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -1,4 +1,4 @@
-"""BASELINE config #5 shape: serving a decoder LM over HTTP.
+"""Serving a decoder LM over HTTP.
 
   python examples/serve_gpt.py --port 8000 [--family gpt|bloom|codegen]
 

@@ -67,39 +67,6 @@ def _report(path):
     return report
 
 
-def mfu_summary(tflops_per_chip):
-    """Shared MFU framing for bench tooling (scripts/mfu_breakdown.py,
-    bench.py): achieved TFLOPS/chip against the one peak-FLOPs source
-    (``telemetry.perf`` — the ``device_peak_tflops`` knob or the
-    detected generation's bf16 peak)."""
-    info = _perf.peak_flops_info()
-    return {
-        "generation": info["generation"],
-        "peak_bf16_tflops": info["peak_bf16_tflops"],
-        "mfu": round(_perf.compute_mfu(tflops_per_chip,
-                                       info["peak_bf16_tflops"]), 4),
-    }
-
-
-def attribute_legs(results):
-    """Subtraction-based step-time attribution over mfu_breakdown's
-    timed legs (forward / lm-head+CE / backward / optimizer)."""
-    def s(leg):
-        return results.get(leg, {}).get("s")
-
-    full, fb, fwd, fh = (s("train_step"), s("fwd_bwd"), s("forward"),
-                         s("forward_hidden"))
-    if any(v is None for v in (full, fb, fwd, fh)):
-        return {}
-    return {
-        "forward_body_s": round(fh, 4),
-        "lm_head_ce_s": round(fwd - fh, 4),
-        "backward_s": round(fb - fwd, 4),
-        "optimizer_s": round(full - fb, 4),
-        "total_s": round(full, 4),
-    }
-
-
 def cmd_analyze(args):
     report = _report(args.trace)
     if args.json:

@@ -5,8 +5,7 @@ Usage::
     python scripts/reshard_tool.py plan --shape 1024,1024 \
         --src-devices 4 --dst-devices 4 \
         --src-spec x,None --dst-spec None,None \
-        [--dtype float32] [--latency-ms 2.0] [--bandwidth 0] \
-        [--wire-model link]
+        [--dtype float32]
 
     python scripts/reshard_tool.py grad --shapes 1024x1024,4096x256,64 \
         --devices 8 --mode int8 [--min-bytes 65536] \
@@ -58,12 +57,7 @@ def cmd_plan(args):
     import jax
     from jax.sharding import Mesh
 
-    from alpa_tpu.global_env import global_config
     from alpa_tpu.pipeline_parallel import cross_mesh_resharding as cmr
-
-    global_config.resharding_transfer_latency_s = args.latency_ms / 1e3
-    global_config.resharding_wire_bandwidth = args.bandwidth
-    global_config.resharding_wire_model = args.wire_model
 
     devices = jax.devices()
     if len(devices) < n_dev:
@@ -78,8 +72,6 @@ def cmd_plan(args):
     spec = cmr.plan_resharding(shape, itemsize, src, dst)
     print(f"edge: {shape} {args.dtype} "
           f"{cmr._sharding_key(src)} -> {cmr._sharding_key(dst)}")
-    print(f"wire model: {args.wire_model}  "
-          f"latency={args.latency_ms}ms  bandwidth={args.bandwidth}")
     print(f"chosen strategy: {spec.strategy}"
           f"{' (from compile cache)' if spec.strategy_cached else ''}")
     print(f"planned cross-mesh bytes: {spec.transfer_bytes:.0f} "
@@ -169,12 +161,6 @@ def main(argv=None):
     pp.add_argument("--src-spec", default="x,None",
                     help="source PartitionSpec entries, e.g. x,None")
     pp.add_argument("--dst-spec", default="None,None")
-    pp.add_argument("--latency-ms", type=float, default=2.0,
-                    help="emulated per-message wire latency")
-    pp.add_argument("--bandwidth", type=float, default=0.0,
-                    help="emulated per-link bandwidth, bytes/s (0 = off)")
-    pp.add_argument("--wire-model", default="link",
-                    choices=("call", "link"))
     pp.add_argument("--verify", action="store_true",
                     help="append the static per-edge typing verdict "
                          "(plan_verifier.verify_edge)")

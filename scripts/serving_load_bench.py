@@ -7,8 +7,9 @@ percentiles and aggregate decoded tokens/s.  The point is behavior
 UNDER CONCURRENCY: ThreadingHTTPServer thread-per-connection fan-in,
 engine decode-tick sharing, batcher coalescing.
 
-Writes benchmark/results/serving_load.json when run as a script; the
-assertions live in tests/serve/test_serving_load.py.
+The harness of tests/serve/test_serving_load.py, where the assertions
+live; what the engine carries on the chip is the benchmark's to say
+(chipbench/, the ``opt-1.3b`` cells).
 """
 import http.client
 import json
@@ -154,17 +155,3 @@ def run_load(n_clients=16, n_requests=3, max_new_tokens=8):
         "aggregate_tokens_per_s": round(total_tokens / wall, 1),
         "sum_of_individual_s": round(sum(r["total_s"] for r in ok), 3),
     }
-
-
-def main():
-    from alpa_tpu.platform import pin_cpu_platform
-    pin_cpu_platform(8)
-    stats = run_load()
-    out = os.path.join(REPO, "benchmark", "results", "serving_load.json")
-    with open(out, "w", encoding="utf-8") as f:
-        json.dump(stats, f, indent=1)
-    print(json.dumps(stats))
-
-
-if __name__ == "__main__":
-    main()

@@ -389,12 +389,9 @@ def test_fixture_certifies_and_perf_gate_pins_it():
                               numerics=True, equiv=True)
     assert verdict.ok and not verdict.warnings, verdict.format_table()
     from benchmark.perf_gate import gate
-    gv = gate({
-        "equiv.terms": float(res.stats["n_terms"]),
-        "equiv.seconds": float(res.stats["seconds"]),
-    })
+    gv = gate({"equiv.terms": float(res.stats["n_terms"])})
     checked = {c["metric"] for c in gv["checks"]}
-    assert {"equiv.terms", "equiv.seconds"} <= checked
+    assert "equiv.terms" in checked
     assert gv["pass"], gv
 
 

@@ -463,14 +463,13 @@ def test_verify_tool_modelcheck_cli_on_committed_fixture():
 
 def test_perf_gate_pins_fixture_state_count():
     """Exploration is deterministic: the committed baseline pins the
-    exact state count (ratio 1.0) and a generous wall-clock cap."""
+    exact state count (ratio 1.0)."""
     from benchmark.perf_gate import gate
     model, hooks, window = mc.load_fixture(FIXTURE)
     result = mc.check_model(model, hooks=hooks, overlap_window=window)
-    verdict = gate({"modelcheck.states": float(result.stats["states"]),
-                    "modelcheck.seconds": result.stats["seconds"]})
+    verdict = gate({"modelcheck.states": float(result.stats["states"])})
     checked = {c["metric"] for c in verdict["checks"]}
-    assert {"modelcheck.states", "modelcheck.seconds"} <= checked
+    assert "modelcheck.states" in checked
     assert verdict["pass"], verdict
 
 

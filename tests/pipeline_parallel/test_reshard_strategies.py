@@ -6,9 +6,9 @@ collective) and end-to-end on the unified graph executor (forced
 strategy over the 4-stage MLP train step, grouped + donated, registers
 and overlap modes).  Oracle 2: the codec's documented error contract,
 property-style over seeded shapes.  Oracle 3: strategy selection — the
-cost model picks collectives exactly when the link wire model makes
-them cheaper, forced-but-ineligible strategies degrade to direct, and
-decisions replay from the compile cache."""
+default is direct on every edge, a forced strategy is taken exactly
+where it is eligible and degrades to direct elsewhere, and decisions
+replay from the compile cache."""
 import numpy as np
 import pytest
 
@@ -27,17 +27,11 @@ def _restore_knobs():
     prev = (global_config.reshard_strategy,
             global_config.reshard_quantize,
             global_config.reshard_quantize_min_bytes,
-            global_config.resharding_wire_model,
-            global_config.resharding_wire_bandwidth,
-            global_config.resharding_transfer_latency_s,
             global_config.pipeline_dispatch_mode)
     yield
     (global_config.reshard_strategy,
      global_config.reshard_quantize,
      global_config.reshard_quantize_min_bytes,
-     global_config.resharding_wire_model,
-     global_config.resharding_wire_bandwidth,
-     global_config.resharding_transfer_latency_s,
      global_config.pipeline_dispatch_mode) = prev
 
 
@@ -58,48 +52,44 @@ class _Aval:
 # strategy selection (cost model + eligibility + cache)
 # ---------------------------------------------------------------------
 
+CASES = {
+    "rowshard->replicated": (P("x", None), P()),
+    "rowshard->colshard": (P("x", None), P(None, "x")),
+    "replicated->rowshard": (P(), P("x", None)),
+    "rowshard->rowshard": (P("x", None), P("x", None)),
+}
+
+# the strategies each edge is eligible for besides direct_p2p
+ELIGIBLE = {
+    "rowshard->replicated": {"slice_all_gather"},
+    "rowshard->colshard": {"all_to_all"},
+    "replicated->rowshard": set(),
+    "rowshard->rowshard": set(),
+}
+
+
+def _shardings(case):
+    src_mesh, dst_mesh = _two_meshes()
+    ss, ds = CASES[case]
+    return NamedSharding(src_mesh, ss), NamedSharding(dst_mesh, ds)
+
+
 class TestStrategySelection:
 
-    CASES = {
-        "rowshard->replicated": (P("x", None), P()),
-        "rowshard->colshard": (P("x", None), P(None, "x")),
-        "replicated->rowshard": (P(), P("x", None)),
-        "rowshard->rowshard": (P("x", None), P("x", None)),
-    }
-
-    def _shardings(self, case):
-        src_mesh, dst_mesh = _two_meshes()
-        ss, ds = self.CASES[case]
-        return NamedSharding(src_mesh, ss), NamedSharding(dst_mesh, ds)
-
-    def test_default_knobs_always_direct(self):
-        # latency 0 → all candidates tie → direct wins the tie-break,
-        # so the default configuration is byte-identical to before
-        for case in self.CASES:
-            src, dst = self._shardings(case)
-            strat, _, _ = cmr.choose_strategy((8, 8), 4, src, dst)
-            assert strat == "direct_p2p", case
-
-    def test_link_model_picks_collectives(self):
-        global_config.resharding_wire_model = "link"
-        global_config.resharding_transfer_latency_s = 0.002
-        expect = {
-            "rowshard->replicated": "slice_all_gather",
-            "rowshard->colshard": "all_to_all",
-            "replicated->rowshard": "direct_p2p",   # already 1 msg/link
-            "rowshard->rowshard": "direct_p2p",     # aligned, 1 msg/link
-        }
-        for case, want in expect.items():
-            src, dst = self._shardings(case)
-            strat, costs, _ = cmr.choose_strategy((8, 8), 4, src, dst)
-            assert strat == want, (case, costs)
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_default_knobs_always_direct(self, case):
+        # the cross-mesh leg has no price, so no candidate is cheaper
+        # than direct, which wins the tie-break
+        src, dst = _shardings(case)
+        strat, _, _ = cmr.choose_strategy((8, 8), 4, src, dst)
+        assert strat == "direct_p2p"
 
     def test_link_stats_pinned_4p4(self):
         # rowshard -> replicated, (8,8) f32: direct sends each 64 B
         # shard to all 4 replicas (4 msgs, 256 B per link, 1024 B
         # total); the scattered landing is a 1:1 aligned move (1 msg,
         # 64 B per link, 256 B total)
-        src, dst = self._shardings("rowshard->replicated")
+        src, dst = _shardings("rowshard->replicated")
         _, _, opts = cmr.choose_strategy((8, 8), 4, src, dst)
         d = opts["direct_p2p"]["stats"]
         assert (d["max_link_messages"], d["max_link_bytes"],
@@ -108,42 +98,40 @@ class TestStrategySelection:
         assert (s["max_link_messages"], s["max_link_bytes"],
                 s["total_bytes"]) == (1, 64.0, 256.0)
 
-    def test_forced_ineligible_falls_back_to_direct(self):
-        global_config.reshard_strategy = "all_to_all"
-        src, dst = self._shardings("rowshard->replicated")  # repl dst
-        strat, _, _ = cmr.choose_strategy((8, 8), 4, src, dst)
-        assert strat == "direct_p2p"
-
-    def test_forced_eligible_is_taken(self):
-        global_config.reshard_strategy = "slice_all_gather"
-        src, dst = self._shardings("rowshard->replicated")
-        strat, _, _ = cmr.choose_strategy((8, 8), 4, src, dst)
-        assert strat == "slice_all_gather"
+    @pytest.mark.parametrize("case", list(CASES))
+    @pytest.mark.parametrize("forced", [s for s in cmr.RESHARD_STRATEGIES
+                                        if s != "direct_p2p"])
+    def test_forced_strategy_taken_where_eligible(self, forced, case):
+        # a forced strategy pins the edges it is eligible for; the
+        # others fall back to direct_p2p
+        global_config.reshard_strategy = forced
+        src, dst = _shardings(case)
+        strat, _, opts = cmr.choose_strategy((8, 8), 4, src, dst)
+        assert set(opts) - {"direct_p2p"} == ELIGIBLE[case]
+        assert strat == (forced if forced in ELIGIBLE[case]
+                         else "direct_p2p")
 
     def test_resolve_strategy_replays_from_cache(self):
-        global_config.resharding_wire_model = "link"
-        global_config.resharding_transfer_latency_s = 0.002
-        src, dst = self._shardings("rowshard->replicated")
+        global_config.reshard_strategy = "slice_all_gather"
+        src, dst = _shardings("rowshard->replicated")
         s1, c1, hit1 = cmr.resolve_strategy((8, 8), 4, src, dst)
         s2, c2, hit2 = cmr.resolve_strategy((8, 8), 4, src, dst)
         assert not hit1 and hit2
         assert s1 == s2 == "slice_all_gather"
         assert c1 == c2
 
-    def test_cache_key_covers_knobs(self):
-        # same edge, different knobs → independent decisions
-        src, dst = self._shardings("rowshard->replicated")
+    def test_cache_key_covers_reshard_strategy(self):
+        # same edge, another forced strategy → an independent decision
+        src, dst = _shardings("rowshard->replicated")
         s1, _, _ = cmr.resolve_strategy((8, 8), 4, src, dst)
-        global_config.resharding_wire_model = "link"
-        global_config.resharding_transfer_latency_s = 0.002
+        global_config.reshard_strategy = "slice_all_gather"
         s2, _, hit2 = cmr.resolve_strategy((8, 8), 4, src, dst)
         assert not hit2
         assert (s1, s2) == ("direct_p2p", "slice_all_gather")
 
     def test_plan_resharding_carries_strategy(self):
-        global_config.resharding_wire_model = "link"
-        global_config.resharding_transfer_latency_s = 0.002
-        src, dst = self._shardings("rowshard->replicated")
+        global_config.reshard_strategy = "slice_all_gather"
+        src, dst = _shardings("rowshard->replicated")
         spec = cmr.plan_resharding((8, 8), 4, src, dst)
         assert spec.strategy == "slice_all_gather"
         assert spec.wire_messages == 1

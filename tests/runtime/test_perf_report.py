@@ -177,7 +177,7 @@ class TestFixtureReport:
 
 
 # ---------------------------------------------------------------------
-# MFU formula (the single source bench.py / mfu_breakdown.py ride)
+# MFU formula
 # ---------------------------------------------------------------------
 
 class TestMfu:

@@ -27,9 +27,9 @@ def _restore_dispatch_mode():
     global_config.pipeline_dispatch_mode = prev
 
 
-def _fresh_step_and_state(num_layers=4, num_stages=4):
+def _fresh_step_and_state(num_layers=4, num_stages=4, num_micro_batches=2):
     method = PipeshardParallel(
-        num_micro_batches=2,
+        num_micro_batches=num_micro_batches,
         layer_option=AutoLayerOption(layer_num=num_layers),
         stage_option=UniformStageOption(num_stages=num_stages))
     step = get_mlp_train_step(method, use_value_and_grad=False)
@@ -117,3 +117,77 @@ def test_planned_resharding_falls_back_to_interpreter():
         assert ex.last_dispatch_stats["mode"] != "registers"
     finally:
         global_config.resharding_execution = prev
+
+
+def _two_stage_step(n_micro_batches):
+    step, state, batch = _fresh_step_and_state(2, 2, n_micro_batches)
+    state, _ = step(state, batch)           # compile + lower
+    jax.block_until_ready(state)
+    return step, state, batch
+
+
+@pytest.mark.parametrize("n_mb", [2, 4])
+def test_ops_per_step_follow_the_instruction_list(n_mb):
+    """The CPU twin of the benchmark's ``pipeshard_ops_per_step``: the
+    op spans one step records are what the emitter's instruction list
+    predicts for 2 stages and ``n_mb`` micro-batches, in the mode the
+    four-chip cell runs (``auto`` -> ``overlap``)."""
+    from alpa_tpu.telemetry import trace as ttrace
+    alpa_tpu.init("local")
+    step, state, batch = _two_stage_step(n_mb)
+    ex = step.get_last_executable()
+    prog = ex._register_programs["overlap"]
+    by = prog.by_opcode
+    # forward and backward of each stage a micro-batch and one
+    # apply-grad a stage; one activation 0->1 and one gradient 1->0 a
+    # micro-batch, every one of them between the meshes
+    assert by["RUN"] == 2 * 2 * n_mb + 2
+    assert by["RESHARD"] == prog.n_cross_mesh == 2 * n_mb
+    assert sum(by.values()) == prog.n_instructions == len(ex.instructions)
+    # overlap splits a cross-mesh RESHARD into its launch and its wait
+    assert len(prog.ops) == prog.n_instructions + prog.n_cross_mesh
+
+    prev = ttrace.set_enabled(True)
+    try:
+        ttrace.get_recorder().clear()
+        state, _ = step(state, batch)
+        jax.block_until_ready(state)
+    finally:
+        ttrace.set_enabled(prev)
+    assert ex.last_dispatch_stats["n_ops"] == len(prog.ops)
+    spans = ttrace.get_recorder().spans()
+    [step_span] = [s for s in spans if s["name"] == "pipeshard.step"]
+    end = step_span["ts_us"] + step_span["dur_us"]
+    ops = [s for s in spans
+           if s["category"] in ("instruction", "transfer") and
+           step_span["ts_us"] <= s["ts_us"] <= end]
+    # one span a RUN and a FREE; five a cross-mesh transfer (LAUNCH,
+    # the pool's RESHARD with its wait and wire parts, WAIT)
+    assert len(ops) == by["RUN"] + by["FREE"] + 5 * prog.n_cross_mesh
+
+
+def test_replay_without_instrumentation_calls_no_hook(monkeypatch):
+    """With tracing, fault sites, race checks and the flight recorder
+    all off, a step replays the raw op closures: no hook is compiled or
+    called, and nothing is recorded."""
+    from alpa_tpu.telemetry import flight as tflight
+    from alpa_tpu.telemetry import trace as ttrace
+    alpa_tpu.init("local")
+    global_config.pipeline_dispatch_mode = "registers"
+    step, state, batch = _two_stage_step(2)
+    prog = step.get_last_executable()._register_program
+
+    def no_hooks(*args, **kwargs):
+        raise AssertionError("a hook path ran with nothing to instrument")
+
+    monkeypatch.setattr(global_config, "flight_recorder", False)
+    monkeypatch.setattr(prog, "_execute_hooked", no_hooks)
+    monkeypatch.setattr(prog, "_compile_hooks", no_hooks)
+    assert not ttrace.enabled()
+    ttrace.get_recorder().clear()
+    flight_before = tflight.get_recorder().snapshot()
+    state, _ = step(state, batch)
+    jax.block_until_ready(state)
+    assert step.get_last_executable().last_dispatch_stats["hooks"] == ()
+    assert ttrace.get_recorder().spans() == []
+    assert tflight.get_recorder().snapshot() == flight_before

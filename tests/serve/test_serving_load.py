@@ -2,9 +2,7 @@
 HTTP clients — mixed SSE + non-streaming — against the controller +
 engine must all succeed, overlap their work (no serialization through
 the ThreadingHTTPServer or the engine lock), and keep streaming TTFT
-bounded.  The committed artifact (benchmark/results/serving_load.json)
-is produced by scripts/serving_load_bench.py with the same harness at
-16 clients.
+bounded.  The harness is scripts/serving_load_bench.py.
 """
 from scripts.serving_load_bench import run_load
 

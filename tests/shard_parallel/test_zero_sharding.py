@@ -1,8 +1,10 @@
 """ZeRO weight-update sharding (ISSUE 10): path classification,
 cost-modeled optimizer-state partitioning, and numeric equivalence.
 
-Oracle: ZeRO-2/ZeRO-3 are pure *layout* changes — losses must match the
-replicated data-parallel baseline bitwise; the memory-budgeted ILP must
+Oracle: ZeRO-2/ZeRO-3 are pure *layout* changes — the updated
+parameters must match the replicated data-parallel baseline (bitwise
+where the partitioner reduces in the same order, else to a few units in
+the last place of a leaf's largest entry); the memory-budgeted ILP must
 pick sharded optimizer state on its own (chosen by cost, not forced).
 """
 import numpy as np
@@ -82,8 +84,36 @@ def _sharded_input_count(ex):
     return n
 
 
+def _is_partitioned(leaf):
+    return np.prod(leaf.sharding.shard_shape(leaf.shape)) < \
+        np.prod(leaf.shape)
+
+
+def _assert_same_training(state_a, loss_a, state_b, loss_b, ulps=8):
+    """Two layouts of one computation after ``_train``'s two steps.
+
+    Bit equality cannot hold in general: once parameters or gradients
+    are sharded, XLA's SPMD partitioner splits the reductions (the batch
+    mean of the loss, the gradients' sums over the batch) over the
+    devices in another order, and float32 addition is not associative.
+    On this XLA:CPU the loss moves by up to 3 units in the last place
+    and a parameter by up to 2.5 of its leaf's largest entry; what the
+    design promises is the same update, so both are held to ``ulps``."""
+    import jax
+    la, lb = np.float32(loss_a), np.float32(loss_b)
+    assert abs(la - lb) <= ulps * np.spacing(max(la, lb)), (la, lb)
+    flat_a = jax.tree_util.tree_leaves_with_path(state_a.params)
+    flat_b = jax.tree_util.tree_leaves(state_b.params)
+    assert len(flat_a) == len(flat_b)
+    for (path, a), b in zip(flat_a, flat_b):
+        a, b = np.asarray(a), np.asarray(b)
+        tol = ulps * np.spacing(np.abs(a).max())
+        assert np.abs(a - b).max() <= tol, \
+            (jax.tree_util.keystr(path), np.abs(a - b).max(), tol)
+
+
 class TestZeroNumerics:
-    """ZeRO stages vs replicated DP: identical losses, sharded state."""
+    """ZeRO stages vs replicated DP: the same update, sharded state."""
 
     def test_zero2_bit_exact_vs_dp(self):
         alpa_tpu.init("local")
@@ -93,27 +123,30 @@ class TestZeroNumerics:
                                       np.asarray(loss_z2))
         # the optimizer-state leaves really are partitioned
         opt_leaf = state2.opt_state[0].trace["params"]["Dense_0"]["kernel"]
-        assert np.prod(opt_leaf.sharding.shard_shape(opt_leaf.shape)) < \
-            np.prod(opt_leaf.shape)
+        assert _is_partitioned(opt_leaf)
 
-    def test_zero3_bit_exact_vs_dp(self):
+    def test_zero3_same_update_as_dp(self):
         alpa_tpu.init("local")
-        _, loss_dp, _ = _train(DataParallel())
+        state_dp, loss_dp, _ = _train(DataParallel())
         state3, loss_z3, _ = _train(Zero3Parallel())
-        np.testing.assert_array_equal(np.asarray(loss_dp),
-                                      np.asarray(loss_z3))
-        # ZeRO-3 also shards the parameters
-        p = state3.params["params"]["Dense_0"]["kernel"]
-        assert np.prod(p.sharding.shard_shape(p.shape)) < np.prod(p.shape)
+        _assert_same_training(state_dp, loss_dp, state3, loss_z3)
+        # ZeRO-3 also shards the parameters; DP keeps them whole
+        assert _is_partitioned(state3.params["params"]["Dense_0"]["kernel"])
+        assert not _is_partitioned(
+            state_dp.params["params"]["Dense_0"]["kernel"])
 
     def test_zero_stage_knob_forces_sharding(self):
         alpa_tpu.init("local")
-        _, loss0, ex0 = _train(ShardParallel(
+        state0, loss0, ex0 = _train(ShardParallel(
             auto_sharding_option=AutoShardingOption(zero_stage="0")))
-        _, loss2, ex2 = _train(ShardParallel(
+        state2, loss2, ex2 = _train(ShardParallel(
             auto_sharding_option=AutoShardingOption(zero_stage="2")))
-        np.testing.assert_array_equal(np.asarray(loss0),
-                                      np.asarray(loss2))
+        _assert_same_training(state0, loss0, state2, loss2)
+        # the knob's point: stage 2 partitions the optimizer state,
+        # stage 0 keeps it whole
+        opt0 = state0.opt_state[0].trace["params"]["Dense_0"]["kernel"]
+        opt2 = state2.opt_state[0].trace["params"]["Dense_0"]["kernel"]
+        assert _is_partitioned(opt2) and not _is_partitioned(opt0)
         assert _sharded_input_count(ex2) > _sharded_input_count(ex0)
         # zero_stage is part of the parallel plan: resume validation
         # (checkpoint manager) must distinguish the two layouts
