@@ -10,7 +10,6 @@ decode executable compiles exactly once for the engine's lifetime.
 The per-row KV-cache indices introduced in ``model.gpt_model`` are what
 make this possible: every row decodes at its own position.
 """
-import dataclasses
 import itertools
 import logging
 import threading
@@ -24,7 +23,7 @@ import numpy as np
 from alpa_tpu import fault
 from alpa_tpu.model.gpt_model import init_kv_caches
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
-                                       _sample_logits)
+                                       sample_rows)
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.telemetry import trace as _ttrace
 
@@ -39,6 +38,11 @@ _TOKENS = _REG.counter(
     "alpa_serving_tokens_total", "Tokens generated across all requests")
 _STEP_FAILURES = _REG.counter(
     "alpa_serving_step_failures_total", "Engine decode ticks that raised")
+_SAMPLE_TICKS = _REG.counter(
+    "alpa_serving_sample_ticks_total",
+    "Engine decode ticks by what their sampling had to do: greedy (no "
+    "active row samples) or sampled (at least one does, so the tick pays "
+    "for the sort and the draw)", labelnames=("mode",))
 _ACTIVE_ROWS = _REG.gauge(
     "alpa_serving_active_rows", "KV-cache rows currently decoding")
 _TTFT = _REG.histogram(
@@ -123,7 +127,19 @@ class _DoneEvent(threading.Event):
 
 
 class ContinuousBatchingEngine:
-    """Persistent decode loop with immediate row refill."""
+    """Persistent decode loop with immediate row refill.
+
+    A tick (``_step``) is: sample one token a row from the resident logits
+    in one device program (``generation.sample_rows``, every row under the
+    settings its request was admitted with) -> enqueue the decode on that
+    program's own output -> only then read the tokens back -> deliver them
+    -> admit into the rows that ended.  The token does not leave the device
+    between two decodes, so the chip works on the next decode while the
+    host reads, delivers and admits.  The decode is enqueued before the
+    host knows which rows ended with this token: such a row is decoded
+    once more, for nobody, and the next admission overwrites it whole
+    (its cache row, index and logits), so the extra step is harmless.
+    """
 
     def __init__(self, generator: Generator, max_batch: int = 4,
                  prompt_bucket: Optional[int] = None,
@@ -229,12 +245,21 @@ class ContinuousBatchingEngine:
         self._init_resident()
         self._active = np.zeros((self.B,), bool)
         self._rows: List[Optional[dict]] = [None] * self.B
+        # each row's sampling settings, written when the row is given to a
+        # request (a free row keeps its last ones, and nobody reads its
+        # token), and their device copies, None until the next tick sends
+        # them
+        self._do_sample = np.zeros((self.B,), bool)
+        self._temperature = np.ones((self.B,), np.float32)
+        self._top_k = np.zeros((self.B,), np.int32)
+        self._settings = None
         if scheduler is None:
             from alpa_tpu.serve.scheduler import FIFOQueue
             scheduler = FIFOQueue()
         self._queue = scheduler
         self._cv = threading.Condition()
-        self._rng = jax.random.PRNGKey(0)
+        self._key = jax.random.PRNGKey(0)   # carried through sample_rows
+        self._sample_rows = jax.jit(sample_rows)
         self._rids = itertools.count()
         self.admissions = 0
         self.decode_steps = 0
@@ -247,7 +272,7 @@ class ContinuousBatchingEngine:
                 new.append((k.at[row].set(k1[0]),
                             v.at[row].set(v1[0]),
                             idx.at[row].set(idx1[0])))
-            return new, logits.at[row].set(logits1[0])
+            return new, logits.at[row].set(logits1[0].astype(logits.dtype))
 
         # both scatters donate the engine's own caches and logits (every
         # caller replaces them by the result) and write the admitted rows
@@ -262,7 +287,8 @@ class ContinuousBatchingEngine:
                 new.append((jnp.where(m4, rk[rowmap], k),
                             jnp.where(m4, rv[rowmap], v),
                             jnp.where(mask, rlen[rowmap], idx)))
-            return new, jnp.where(mask[:, None], last[rowmap], logits)
+            return new, jnp.where(mask[:, None],
+                                  last[rowmap].astype(logits.dtype), logits)
 
         self._scatter_packed = jax.jit(scatter_packed,
                                        donate_argnums=(0, 2))
@@ -355,7 +381,9 @@ class ContinuousBatchingEngine:
         cfgm = self.gen.config
         self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
                         for (k, v, _i) in init_kv_caches(cfgm, self.B)]
-        self._logits = jnp.zeros((self.B, cfgm.vocab_size), jnp.float32)
+        # the decode's logits as it returns them (every family computes
+        # them in the configuration's dtype); sample_rows casts to float32
+        self._logits = jnp.zeros((self.B, cfgm.vocab_size), cfgm.dtype)
 
     def _fail_active_locked(self, err):
         """Fail every resident request with ``err`` and free its row."""
@@ -445,6 +473,20 @@ class ContinuousBatchingEngine:
                           "prompt_len": len(item["prompt"])},
                          _QUEUE_TRACK)
 
+    def _give_row(self, r: int, item: dict):
+        """Row ``r`` is ``item``'s from here on, sampled under its settings
+        (``max_new_tokens`` and ``eos_token_id`` are no sampling settings:
+        the deliver phase reads them off the request)."""
+        cfg = item["cfg"]
+        self._rows[r] = item
+        self._active[r] = True
+        self._do_sample[r] = cfg.do_sample
+        self._temperature[r] = cfg.temperature
+        self._top_k[r] = cfg.top_k
+        self._settings = None
+        self.admissions += 1
+        _ADMISSIONS.inc()
+
     def _chunk_padded(self, n: int) -> int:
         """Positions the chunked prefill runs over for ``n`` tokens."""
         c = self.gen.prefill_chunk
@@ -509,14 +551,10 @@ class ContinuousBatchingEngine:
                         r = free[slot]
                         rowmap[r] = slot
                         mask[r] = True
-                        self._rows[r] = item
-                        self._active[r] = True
-                        self.admissions += 1
-                        _ADMISSIONS.inc()
+                        self._give_row(r, item)
                     self._caches, self._logits = self._scatter_packed(
-                        self._caches, row_caches, self._logits,
-                        last.astype(jnp.float32), jnp.asarray(rowmap),
-                        jnp.asarray(mask))
+                        self._caches, row_caches, self._logits, last,
+                        jnp.asarray(rowmap), jnp.asarray(mask))
                     self.packed_admissions += 1
                 except Exception as e:  # pylint: disable=broad-except
                     logger.exception("packed admission failed")
@@ -614,8 +652,7 @@ class ContinuousBatchingEngine:
                             self.gen.params, jnp.asarray(ids), caches1,
                             jnp.asarray([len(p)], jnp.int32))
                     self._caches, self._logits = self._scatter_row(
-                        self._caches, caches1, self._logits,
-                        logits1.astype(jnp.float32), r)
+                        self._caches, caches1, self._logits, logits1, r)
                     if rec is not None:
                         prefill_span.args = {
                             "rid": item["rid"], "prompt_len": len(p),
@@ -630,10 +667,7 @@ class ContinuousBatchingEngine:
                     if self._pool_reuse:
                         self._pool.register_prompt(seq, p)
                     self._tables[r] = seq
-                self._rows[r] = item
-                self._active[r] = True
-                self.admissions += 1
-                _ADMISSIONS.inc()
+                self._give_row(r, item)
             except Exception as e:  # pylint: disable=broad-except
                 logger.exception("row admission failed")
                 if seq is not None:
@@ -707,42 +741,37 @@ class ContinuousBatchingEngine:
 
     def _step(self, rec=None):
         """One decode tick for every active row.  ``rec``: see ``_phase``;
-        the tick's phases are child spans of ``engine.decode-tick``."""
+        the tick's phases are child spans of ``engine.decode-tick``, in
+        the order sample, dispatch, wait, deliver: the sampled tokens go
+        from one device program into the next, and the host reads them
+        only once the decode that consumes them is enqueued (why that is
+        safe for a row that ends with this token: the class docstring)."""
         fault.fire("scheduler_tick", step=self.decode_steps,
                    active=int(self._active.sum()))
-        self._rng, sub = jax.random.split(self._rng)
-        # sampling settings come from each row's cfg; rows with identical
-        # settings dominate in practice — sample with row 0's active cfg
-        # and resample per-row only when configs differ (greedy default).
-        cfgs = [self._rows[r]["cfg"] if self._active[r] else None
-                for r in range(self.B)]
-        base = next((c for c in cfgs if c is not None),
-                    GenerationConfig())
-        sampled = _sample_logits(self._logits, sub, base)
-        with _phase(rec, "engine.wait"):
-            # the first read-back: the host waits here for the previous
-            # tick's decode (and any prefill behind it) to finish
-            nxt = np.asarray(sampled).astype(np.int32)
-        with _phase(rec, "engine.resample") as resample_span:
-            resampled = 0
-            for r, c in enumerate(cfgs):
-                if c is not None and dataclasses.astuple(c) != \
-                        dataclasses.astuple(base):
-                    self._rng, sub_r = jax.random.split(self._rng)
-                    nxt[r] = int(np.asarray(_sample_logits(
-                        self._logits[r:r + 1], sub_r, c))[0])
-                    resampled += 1
-            if rec is not None:
-                resample_span.args = {"rows": resampled}
-
+        if self._settings is None:
+            # the first tick, and every tick that follows an admission;
+            # copies, because the host arrays are written in place
+            self._settings = jax.device_put(tuple(
+                a.copy() for a in
+                (self._do_sample, self._temperature, self._top_k)))
+        sampling = int((self._do_sample & self._active).sum())
+        _SAMPLE_TICKS.labels("sampled" if sampling else "greedy").inc()
+        with _phase(rec, "engine.sample",
+                    {"rows": sampling} if rec is not None else None):
+            tokens, self._key = self._sample_rows(
+                self._logits, self._key, *self._settings)
         with _phase(rec, "engine.dispatch"):
             index = self._caches[0][2]          # per-row positions
-            tok = jnp.asarray(nxt[:, None])
-            logits, self._caches = self.gen._decode(
-                self.gen.params, tok, index, self._caches)
-            self._logits = logits.astype(jnp.float32)
+            self._logits, self._caches = self.gen._decode(
+                self.gen.params, tokens, index, self._caches)
         self.decode_steps += 1
         _DECODE_STEPS.inc()
+        with _phase(rec, "engine.wait"):
+            # the read-back behind the enqueue: the host waits here for
+            # the previous tick's decode, any prefill behind it, and this
+            # tick's sampling; a decode that failed on the device raises
+            # here, one tick late
+            nxt = np.asarray(tokens)[:, 0]
         if self._pool is not None:
             # the tick wrote each row's new K/V at its pre-decode index;
             # mirror those positions into the block pool (rows without a

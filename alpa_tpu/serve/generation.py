@@ -69,6 +69,37 @@ def _sample_logits(logits, rng, cfg: GenerationConfig):
     return jax.random.categorical(rng, logits, axis=-1)
 
 
+def sample_rows(logits, key, do_sample, temperature, top_k):
+    """One token a row, every row under its own settings, in one program:
+    ``_sample_logits`` with the three settings as arrays of length ``B``
+    (data, so no setting of any request compiles anything).  Rows with
+    ``do_sample`` false take the argmax; the others divide by
+    ``max(temperature, 1e-6)``, mask what lies below the row's k-th
+    largest value (ties at it survive; ``top_k`` 0 masks nothing) and
+    draw from what is left.  The draw and its sort sit under a ``cond``:
+    while no row samples, the program is the argmax and ``key`` comes back
+    as it went in.  Returns ``(tokens int32[B, 1], key)``, the shape the
+    decode takes its tokens in."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1)
+
+    def draw(key):
+        key, sub = jax.random.split(key)
+        x = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        # k is data, so the k-th largest value is read off a full sort
+        vocab = x.shape[-1]
+        kth = jnp.take_along_axis(
+            jnp.sort(x, axis=-1),
+            (vocab - jnp.clip(top_k, 1, vocab))[:, None], axis=-1)
+        x = jnp.where((top_k > 0)[:, None] & (x < kth), TOP_K_MASK, x)
+        drawn = jax.random.categorical(sub, x, axis=-1)
+        return jnp.where(do_sample, drawn, greedy), key
+
+    tokens, key = jax.lax.cond(jnp.any(do_sample), draw,
+                               lambda key: (greedy, key), key)
+    return tokens.astype(jnp.int32)[:, None], key
+
+
 def _warp_probs_np(logits, cfg: GenerationConfig) -> np.ndarray:
     """Host-side probabilities under the cfg's warping (temperature +
     top-k), matching ``_sample_logits``'s semantics (ties at the k-th

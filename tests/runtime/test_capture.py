@@ -23,7 +23,7 @@ from alpa_tpu.telemetry.trace import TraceRecorder
 CFG = GPTConfig(hidden_size=32, num_layers=2, num_heads=4, seq_len=64,
                 vocab_size=64)
 BUCKET = 16
-TICK_CHILDREN = ("engine.wait", "engine.resample", "engine.dispatch",
+TICK_CHILDREN = ("engine.sample", "engine.dispatch", "engine.wait",
                  "engine.deliver")
 
 
@@ -224,7 +224,8 @@ def test_engine_spans_nest_and_share_the_request_id(recorder, tmp_path):
         assert all(any(_inside(c, t) for t in ticks) for c in children)
         assert {c["track"] for c in children} == {"serve-engine"}
     assert sum(s["args"]["tokens"] for s in by_name["engine.deliver"]) == 4
-    assert all(s["args"] == {"rows": 0} for s in by_name["engine.resample"])
+    # a greedy request: no active row pays for the sort and the draw
+    assert all(s["args"] == {"rows": 0} for s in by_name["engine.sample"])
     # a tick's phases follow each other and leave the tick some self time
     for tick in ticks:
         inside = sorted((c for name in TICK_CHILDREN for c in by_name[name]
