@@ -71,9 +71,10 @@ class GPTConfig:
     # "learned" (a table, wpe) | "rotary" (rotate-half RoPE on q and k)
     positions: str = "learned"
     rope_theta: float = 10000.0
-    # RMSNorm over the whole flat q and k projections, before the heads
-    # are split and rotated (OLMoE)
-    qk_norm: bool = False
+    # True: RMSNorm over the whole flat q and k projections, before the
+    # heads are split and rotated (OLMoE); "head": over the channels of
+    # every head of q and of k, one weight vector for all heads
+    qk_norm: Any = False
     use_bias: bool = True
     # the MLP of every layer, or one kind a layer (a tuple num_layers
     # long): "dense" (in, activation, out) | "gated" (act(gate) * up,
@@ -85,13 +86,70 @@ class GPTConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 0
     norm_topk_prob: bool = False
+    # --- what a current served decoder adds to the block (Trinity-Mini,
+    # ``model_type`` afmoe).  Every default is the block of today.
+    # key/value heads, each read by num_heads / num_kv_heads query heads
+    # (grouped-query attention); None: one a query head
+    num_kv_heads: Optional[int] = None
+    # channels of one head; None: hidden_size / num_heads
+    head_dim: Optional[int] = None
+    # the attention of every layer, or one kind a layer: "full" (every
+    # earlier position; its cache holds seq_len positions) | "sliding"
+    # (position q sees k where q - k < sliding_window; its cache is a ring
+    # of sliding_window positions, written at position % sliding_window)
+    attention: Any = "full"
+    sliding_window: int = 0
+    # False: rotary positions turn the q and k of "sliding" layers only,
+    # "full" layers see no positions at all
+    rope_on_full_attention: bool = True
+    # sigmoid(gate(h)) on the heads' output, before the output projection
+    attn_gate: bool = False
+    # four norms a block: one more on what the attention and the MLP
+    # return, before it joins the residual stream
+    post_norms: bool = False
+    # the embedding times sqrt(hidden_size)
+    scale_embedding: bool = False
+    # width of one routed (and of the shared) expert where it is not the
+    # dense layers' ``mlp_width``
+    moe_intermediate_size: Optional[int] = None
+    # "softmax" | "sigmoid": how the router's logits become scores
+    router_score: str = "softmax"
+    # a stored bias an expert, added to the scores for the CHOICE of the
+    # k experts and not to their weights (no gradient reaches it)
+    router_bias: bool = False
+    # the (renormalised) routing weights times this
+    route_scale: float = 1.0
+    # gated MLPs of an expert's width applied to every token and added to
+    # the routed sum
+    num_shared_experts: int = 0
+    # the routed experts' gate and up matrices stored as one (E, h, 2w)
+    # parameter: a served decode then concatenates no expert weights
+    fused_gate_up: bool = False
+    # what the parameters are STORED in (``dtype`` is what is computed in)
+    param_dtype: Any = jnp.float32
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
 
+    def attention_kind(self, layer: int) -> str:
+        return self.attention if isinstance(self.attention, str) \
+            else self.attention[layer]
+
     @property
     def mlp_width(self) -> int:
         return self.intermediate_size or self.mlp_ratio * self.hidden_size
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.mlp_width
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
 
 
 # The reference benchmark ladder: name -> (hidden, layers, heads)
@@ -144,32 +202,69 @@ def config_from_opt_spec(name: str, **kwargs) -> GPTConfig:
 _HF_KINDS = {
     "olmoe": dict(norm="rmsnorm", positions="rotary", qk_norm=True,
                   mlp="experts"),
+    # Trinity (arcee-ai): wiring read from transformers'
+    # models/afmoe/modeling_afmoe.py where config.json does not fix it
+    "afmoe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
+                  rope_on_full_attention=False, attn_gate=True,
+                  post_norms=True, router_bias=True, fused_gate_up=True),
 }
+
+
+def _afmoe_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` afmoe says beyond the keys
+    all decoders share: a kind of MLP and of attention a layer, the sizes
+    of heads and experts, the router's settings."""
+    layers = hf["num_hidden_layers"]
+    if len(hf["layer_types"]) != layers:
+        raise ValueError("layer_types must name every layer")
+    unknown = set(hf["layer_types"]) - {"sliding_attention",
+                                        "full_attention"}
+    if unknown:
+        raise ValueError(f"unknown layer_types {sorted(unknown)}")
+    return dict(
+        mlp=tuple("gated" if i < hf["num_dense_layers"] else "experts"
+                  for i in range(layers)),
+        attention=tuple("sliding" if t == "sliding_attention" else "full"
+                        for t in hf["layer_types"]),
+        sliding_window=hf["sliding_window"], head_dim=hf["head_dim"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        router_score=hf["score_func"], norm_topk_prob=hf["route_norm"],
+        route_scale=float(hf["route_scale"]),
+        num_shared_experts=hf["num_shared_experts"],
+        scale_embedding=hf["mup_enabled"])
 
 
 def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     """``GPTConfig`` from the keys of a Hugging Face ``config.json`` (a
-    dict), for the model types in ``_HF_KINDS``."""
+    dict), for the model types in ``_HF_KINDS``.  ``kwargs`` override what
+    the file says (``seq_len``: the context a deployment serves, where it
+    is less than the declared ``max_position_embeddings``)."""
     kinds = _HF_KINDS.get(hf["model_type"])
     if kinds is None:
         raise ValueError(f"no decoder kinds for model_type "
                          f"{hf['model_type']!r} (known: {sorted(_HF_KINDS)})")
-    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
-        raise ValueError("grouped-query attention is not supported")
     if hf.get("rope_scaling") or hf.get("clip_qkv"):
         raise ValueError("rope_scaling and clip_qkv are not supported")
-    return GPTConfig(
+    fields = dict(
         vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
         num_layers=hf["num_hidden_layers"],
         num_heads=hf["num_attention_heads"],
         seq_len=hf["max_position_embeddings"],
         intermediate_size=hf["intermediate_size"],
         activation=hf["hidden_act"], layer_norm_eps=hf["rms_norm_eps"],
-        rope_theta=float(hf["rope_theta"]), use_bias=hf["attention_bias"],
+        rope_theta=float(hf["rope_theta"]),
+        use_bias=hf.get("attention_bias", False),
         tie_embeddings=hf["tie_word_embeddings"],
         num_experts=hf["num_experts"],
-        num_experts_per_tok=hf["num_experts_per_tok"],
-        norm_topk_prob=hf["norm_topk_prob"], **kinds, **kwargs)
+        num_experts_per_tok=hf["num_experts_per_tok"], **kinds)
+    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        fields["num_kv_heads"] = hf["num_key_value_heads"]
+    if hf["model_type"] == "afmoe":
+        fields.update(_afmoe_fields(hf))
+    else:
+        fields["norm_topk_prob"] = hf["norm_topk_prob"]
+    fields.update(kwargs)
+    return GPTConfig(**fields)
 
 
 def make_norm(config: GPTConfig, name: str) -> nn.Module:
@@ -177,11 +272,11 @@ def make_norm(config: GPTConfig, name: str) -> nn.Module:
     if config.norm == "rmsnorm":
         # scale * x / sqrt(mean(x^2) + eps)
         return nn.RMSNorm(epsilon=config.layer_norm_eps, dtype=jnp.float32,
-                          name=name)
+                          param_dtype=config.param_dtype, name=name)
     if config.norm != "layernorm":
         raise ValueError(f"unknown norm {config.norm!r}")
     return nn.LayerNorm(epsilon=config.layer_norm_eps, dtype=jnp.float32,
-                        name=name)
+                        param_dtype=config.param_dtype, name=name)
 
 
 def apply_rotary(x, position_ids, theta: float):
@@ -200,34 +295,69 @@ def apply_rotary(x, position_ids, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None):
+def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
+                        window: int = 0, k_positions=None):
     """Plain einsum attention; XLA fuses this well on TPU for short seqs.
 
-    q: (B, Sq, H, D); k/v: (B, Sk, H, D).  fp32 softmax accumulation.
+    q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D), H a multiple of Hkv: query head
+    i reads key/value head i // (H / Hkv) (grouped-query attention; the
+    keys and values are never repeated).  fp32 softmax accumulation.
     ``offset`` shifts query positions for decode-with-cache; a scalar
     applies to every row, a (B,) vector gives per-row offsets (mixed
     prompt lengths in one continuously-batched decode).  ``bias`` is an
     fp32 additive score bias broadcastable to (B, H, Sq, Sk) — e.g. a
     padding mask for encoder models (BERT).
+
+    ``window`` > 0 (causal only): a query at position p sees the keys at
+    p - window + 1 .. p.  ``k_positions`` ((1 or B, Sk) int32, causal
+    only): the position each key holds where that is not its place in
+    ``k`` (a ring cache: ``update_ring_cache``); a negative one holds
+    nothing and is seen by no query.
     """
     dim = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    b, sq, nh = q.shape[0], q.shape[1], q.shape[2]
+    sk, nkv = k.shape[1], k.shape[2]
+    if nkv == nh:
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    else:
+        grouped = q.reshape(b, sq, nkv, nh // nkv, dim)
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k).astype(
+            jnp.float32).reshape(b, nh, sq, sk)
     scores = scores / np.sqrt(dim)
     if bias is not None:
         scores = scores + bias.astype(jnp.float32)
     if causal:
-        sq, sk = q.shape[1], k.shape[1]
         offset = jnp.asarray(offset, jnp.int32)
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        if offset.ndim == 0:
-            mask = (q_pos + offset >= k_pos)[None, None]     # (1,1,Sq,Sk)
+        if k_positions is not None:
+            # (1 or B, Sq, Sk): every row of keys has its own positions
+            k_pos = jnp.broadcast_to(k_positions[:, None, :],
+                                     (k_positions.shape[0], sq, sk))
+            q_pos = q_pos[None] + (offset if offset.ndim == 0
+                                   else offset[:, None, None])
+            mask = (q_pos >= k_pos) & (k_pos >= 0)
+            if window:
+                mask &= q_pos - k_pos < window
+            mask = mask[:, None]                             # (.,1,Sq,Sk)
+        elif offset.ndim == 0:
+            mask = q_pos + offset >= k_pos
+            if window:
+                mask &= q_pos + offset - k_pos < window
+            mask = mask[None, None]                          # (1,1,Sq,Sk)
         else:
-            mask = (q_pos[None] + offset[:, None, None]
-                    >= k_pos[None])[:, None]                 # (B,1,Sq,Sk)
+            q_pos = q_pos[None] + offset[:, None, None]
+            mask = q_pos >= k_pos[None]
+            if window:
+                mask &= q_pos - k_pos[None] < window
+            mask = mask[:, None]                             # (B,1,Sq,Sk)
         scores = jnp.where(mask, scores, jnp.float32(-1e9))
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if nkv == nh:
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd",
+                     probs.reshape(b, nkv, nh // nkv, sq, sk), v)
+    return out.reshape(b, sq, nh, dim)
 
 
 def get_attention_fn(config: GPTConfig) -> Callable:
@@ -241,6 +371,10 @@ def get_attention_fn(config: GPTConfig) -> Callable:
         from alpa_tpu.ops.ulysses_attention import ulysses_attention
         return partial(ulysses_attention, axis_name=config.sp_axis)
     return reference_attention
+
+
+# the scope the attention core of every layer is traced under
+ATTENTION_SCOPE = "attention"
 
 
 def _write_rows(cache, new, index):
@@ -312,50 +446,152 @@ def update_kv_cache(kv_cache, k, v):
     return k_use, v_use, (k_full, v_full, index + s)
 
 
+def update_ring_cache(kv_cache, k, v, lengths=None):
+    """``update_kv_cache`` for a "sliding" layer, whose cache is a ring:
+    ``kv_cache`` is (k_cache, v_cache, index) with caches of (B, W, Hkv, D),
+    position p lives in slot ``p % W``, and ``index`` (a scalar, or (B,) a
+    row) is the position of the first of the ``s`` new tokens.  Returns
+    ``(k_use, v_use, k_positions, new_cache)``: the keys and values to
+    attend over and the position each of them holds ((1 or B, Sk); negative
+    where a slot holds nothing yet), for ``reference_attention``: the mask
+    of a ring goes by the position a slot holds, not by the slot.
+
+    One new token (a decode tick): it is written first, one
+    ``dynamic_update_slice`` a row as in ``_write_rows``, and the ring is
+    what is attended over: slot j holds the latest position at or before
+    ``index`` that is congruent to j.
+
+    Several (a prefill chunk, a verify step): writing them first could
+    overwrite positions the chunk's early queries still see, so they
+    attend over the ring as it was and the new keys behind it, and the
+    ring is written after: slot j takes the LAST new token that belongs in
+    it.  ``lengths`` ((B,), the rows' whole lengths) says which new tokens
+    are real: a right-padded chunk must not write its padding, because
+    slot ``p % W`` of a padded position p holds position p - W, which the
+    row's next tokens still see (in a full-length cache the padding lands
+    past the row's end and is harmless).
+    """
+    k_cache, v_cache, index = kv_cache
+    w, s = k_cache.shape[1], k.shape[1]
+    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+    index = jnp.asarray(index, jnp.int32)
+    first = index[:, None] if index.ndim else index[None, None]  # (.,1)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+
+    def held(last):
+        """The position each slot holds when ``last`` (.,1) is the
+        newest position written (negative: none)."""
+        return last - (last - slots) % w
+
+    if s == 1:
+        if index.ndim:
+            k_new = _write_rows(k_cache, k, index % w)
+            v_new = _write_rows(v_cache, v, index % w)
+        else:
+            k_new = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k, index % w, axis=1)
+            v_new = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v, index % w, axis=1)
+        return k_new, v_new, held(first), (k_new, v_new, index + 1)
+
+    new_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+    k_positions = jnp.concatenate(
+        [jnp.broadcast_to(held(first - 1), (first.shape[0], w)), new_pos],
+        axis=1)
+    k_use = jnp.concatenate([k_cache, k], axis=1)
+    v_use = jnp.concatenate([v_cache, v], axis=1)
+    # how many of a row's new tokens are real, and the last of them
+    real = jnp.full_like(first, s) if lengths is None else \
+        jnp.clip(lengths[:, None] - first, 0, s)
+    # the new token slot j is to hold; before the chunk: the slot keeps
+    # what it has
+    source = jnp.broadcast_to(held(first + real - 1) - first,
+                              (k.shape[0], w))
+    take = (source >= 0)[:, :, None, None]
+    at = jnp.clip(source, 0, s - 1)[:, :, None, None]
+    k_new = jnp.where(take, jnp.take_along_axis(k, at, axis=1), k_cache)
+    v_new = jnp.where(take, jnp.take_along_axis(v, at, axis=1), v_cache)
+    return k_use, v_use, k_positions, (k_new, v_new, index + s)
+
+
 class SelfAttention(nn.Module):
+    """``attention`` is the layer's kind (``GPTConfig.attention``; None:
+    the configuration's, which must then be one kind for all layers)."""
     config: GPTConfig
+    attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None):
+                 attn_bias=None, position_ids=None, cache_lengths=None):
         cfg = self.config
-        h, nh = cfg.hidden_size, cfg.num_heads
-        hd = h // nh
-        qkv = nn.Dense(3 * h, dtype=cfg.dtype, use_bias=cfg.use_bias,
-                       name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        h, nh, nkv, hd = (cfg.hidden_size, cfg.num_heads, cfg.kv_heads,
+                          cfg.head_size)
+        kind = self.attention or cfg.attention_kind(0)
+        if kind not in ("full", "sliding"):
+            raise ValueError(f"unknown attention kind {kind!r}")
+        window = cfg.sliding_window if kind == "sliding" else 0
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                        param_dtype=cfg.param_dtype)
+        qkv = dense((nh + 2 * nkv) * hd, name="qkv")(x)
+        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
         b, s = x.shape[0], x.shape[1]
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             q = make_norm(cfg, "q_norm")(q).astype(cfg.dtype)
             k = make_norm(cfg, "k_norm")(k).astype(cfg.dtype)
         q = q.reshape(b, s, nh, hd)
-        k = k.reshape(b, s, nh, hd)
-        v = v.reshape(b, s, nh, hd)
-        if cfg.positions == "rotary":
+        k = k.reshape(b, s, nkv, hd)
+        v = v.reshape(b, s, nkv, hd)
+        if cfg.qk_norm == "head":
+            q = make_norm(cfg, "q_norm")(q).astype(cfg.dtype)
+            k = make_norm(cfg, "k_norm")(k).astype(cfg.dtype)
+        elif cfg.qk_norm not in (True, False):
+            raise ValueError(f"unknown qk_norm {cfg.qk_norm!r}")
+        if cfg.positions == "rotary" and (kind == "sliding" or
+                                          cfg.rope_on_full_attention):
             q = apply_rotary(q, position_ids, cfg.rope_theta)
             k = apply_rotary(k, position_ids, cfg.rope_theta)
 
         new_cache = None
-        if kv_cache is not None:
-            index = jnp.asarray(kv_cache[2], jnp.int32)
-            k_use, v_use, new_cache = update_kv_cache(kv_cache, k, v)
-            # scores to future positions masked by causal offset;
-            # attn_bias (e.g. the packed-prefill segment mask) rides on
-            # top of the causal mask over the full cache length
-            out = reference_attention(q, k_use, v_use, causal=True,
-                                      offset=index, bias=attn_bias)
-        else:
-            if attn_bias is not None:
+        # the scope of the attention core (the cache's update, scores,
+        # softmax, values; not the projections): the benchmark finds its
+        # device events by it (HLO metadata ``op_name``)
+        with jax.named_scope(ATTENTION_SCOPE):
+            if kv_cache is not None and window:
+                if attn_bias is not None:
+                    raise ValueError("a sliding-window layer's ring cache "
+                                     "takes no score bias (packed prefill)")
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                k_use, v_use, k_positions, new_cache = update_ring_cache(
+                    kv_cache, k, v, cache_lengths)
+                out = reference_attention(
+                    q, k_use, v_use, causal=True, offset=index,
+                    window=window, k_positions=k_positions)
+            elif kv_cache is not None:
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                k_use, v_use, new_cache = update_kv_cache(kv_cache, k, v)
+                # scores to future positions masked by causal offset;
+                # attn_bias (e.g. the packed-prefill segment mask) rides on
+                # top of the causal mask over the full cache length
+                out = reference_attention(q, k_use, v_use, causal=True,
+                                          offset=index, bias=attn_bias)
+            elif attn_bias is not None or window or nkv != nh:
                 # additive padding/score bias: encoder path only (the
-                # flash/ring kernels take no bias operand)
+                # flash/ring kernels take no bias operand, no window and
+                # no grouped heads)
+                if (window or nkv != nh) and \
+                        cfg.attention_impl != "reference":
+                    raise ValueError(
+                        "sliding-window and grouped-query attention need "
+                        "attention_impl 'reference'")
                 out = reference_attention(q, k, v, causal=cfg.causal,
-                                          bias=attn_bias)
+                                          bias=attn_bias, window=window)
             else:
                 attn_fn = get_attention_fn(cfg)
                 out = attn_fn(q, k, v, causal=cfg.causal)
-        out = out.reshape(b, s, h)
-        out = nn.Dense(h, dtype=cfg.dtype, use_bias=cfg.use_bias,
-                       name="out")(out)
+        out = out.reshape(b, s, nh * hd)
+        if cfg.attn_gate:
+            out = out * jax.nn.sigmoid(dense(nh * hd, name="gate")(x))
+        out = dense(h, name="out")(out)
         return out, new_cache
 
 
@@ -369,50 +605,66 @@ def activation_fn(name: str) -> Callable:
 
 class MLPBlock(nn.Module):
     """The dense MLP: in, activation, out; ``gated``: act(gate) * up, down
-    (Shazeer 2020, the SwiGLU of today's decoders with "silu")."""
+    (Shazeer 2020, the SwiGLU of today's decoders with "silu").  ``width``:
+    None is the configuration's ``mlp_width``."""
     config: GPTConfig
     gated: bool = False
+    width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias)
+        width = self.width or cfg.mlp_width
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                        param_dtype=cfg.param_dtype)
         act = activation_fn(cfg.activation)
         if self.gated:
-            x = act(dense(cfg.mlp_width, name="gate")(x)) * \
-                dense(cfg.mlp_width, name="up")(x)
+            x = act(dense(width, name="gate")(x)) * \
+                dense(width, name="up")(x)
             return dense(cfg.hidden_size, name="down")(x)
-        x = act(dense(cfg.mlp_width, name="fc_in")(x))
+        x = act(dense(width, name="fc_in")(x))
         return dense(cfg.hidden_size, name="fc_out")(x)
 
 
 class TransformerBlock(nn.Module):
     """One pre-norm decoder block.  ``mlp`` is the kind of its MLP
-    (``GPTConfig.mlp``; None: the configuration's, which must then be one
-    kind for all layers).  Returns ``(x, new_cache)``, and a block of
-    routed experts ``(x, new_cache, routing)``: what its router did
+    (``GPTConfig.mlp``) and ``attention`` of its attention
+    (``GPTConfig.attention``; None: the configuration's, which must then
+    be one kind for all layers).  With ``post_norms`` what the attention
+    and the MLP return is normalised once more before it joins the
+    residual stream.  Returns ``(x, new_cache)``, and a block of routed
+    experts ``(x, new_cache, routing)``: what its router did
     (``moe.DroplessExperts``)."""
     config: GPTConfig
     mlp: Optional[str] = None
+    attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None):
+                 attn_bias=None, position_ids=None, cache_lengths=None):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
-        attn_out, new_cache = SelfAttention(cfg, name="attn")(
-            ln1, kv_cache, deterministic, attn_bias, position_ids)
+        attn_out, new_cache = SelfAttention(
+            cfg, attention=self.attention, name="attn")(
+                ln1, kv_cache, deterministic, attn_bias, position_ids,
+                cache_lengths)
+        if cfg.post_norms:
+            attn_out = make_norm(cfg, "ln1_post")(attn_out)
         x = x + attn_out.astype(x.dtype)
         ln2 = make_norm(cfg, "ln2")(x)
+        routing = ()
         if kind == "experts":
             from alpa_tpu.model.moe import DroplessExperts
-            y, routing = DroplessExperts(cfg, name="mlp")(ln2)
-            return x + y.astype(x.dtype), new_cache, routing
-        if kind not in ("dense", "gated"):
+            y, what = DroplessExperts(cfg, name="mlp")(ln2)
+            routing = (what,)
+        elif kind in ("dense", "gated"):
+            y = MLPBlock(cfg, gated=kind == "gated", name="mlp")(ln2)
+        else:
             raise ValueError(f"unknown mlp kind {kind!r}")
-        y = MLPBlock(cfg, gated=kind == "gated", name="mlp")(ln2)
-        return x + y.astype(x.dtype), new_cache
+        if cfg.post_norms:
+            y = make_norm(cfg, "ln2_post")(y)
+        return (x + y.astype(x.dtype), new_cache) + routing
 
 
 class GPTModel(nn.Module):
@@ -425,7 +677,8 @@ class GPTModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, position_ids=None, kv_caches=None,
                  deterministic=True, return_hidden=False,
-                 segment_ids=None):
+                 segment_ids=None, cache_lengths=None,
+                 return_routing=False):
         """``return_hidden=True`` returns the final (B, S, H) hidden states
         instead of logits, for a fused/chunked lm-head + loss (see
         model_util.chunked_cross_entropy_loss).
@@ -438,6 +691,14 @@ class GPTModel(nn.Module):
         many prompts, masked by segments instead of a custom kernel.
         Pass per-segment ``position_ids`` so positional embeddings
         restart at each segment start.
+
+        ``cache_lengths`` ((B,), with ``kv_caches``): the rows' whole
+        lengths, where the ids are right-padded past them: a layer whose
+        cache is a ring must not write the padding
+        (``update_ring_cache``).  ``return_routing`` (with ``kv_caches``,
+        routed layers): a third result, what the routed layers' routers
+        did: ``experts`` (expert layers, tokens, k) int32, every token's
+        experts.
         """
         cfg = self.config
         b, s = input_ids.shape
@@ -468,11 +729,14 @@ class GPTModel(nn.Module):
                     (segment_ids[:, :, None] >= 0)
             seg_bias = jnp.where(same, 0.0, -1e9)[:, None]  # (B,1,S,L)
         tok_emb = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                           dtype=cfg.dtype, name="wte")
+                           dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                           name="wte")
         x = tok_emb(input_ids)
+        if cfg.scale_embedding:
+            x = x * jnp.asarray(np.sqrt(cfg.hidden_size), x.dtype)
         if cfg.positions == "learned":
             x = x + nn.Embed(cfg.seq_len + cfg.pos_offset, cfg.hidden_size,
-                             dtype=cfg.dtype,
+                             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                              name="wpe")(position_ids + cfg.pos_offset)
         elif cfg.positions != "rotary":
             raise ValueError(f"unknown positions {cfg.positions!r}")
@@ -500,10 +764,12 @@ class GPTModel(nn.Module):
             if (cfg.pipeline_boundary_every and i > 0 and
                     i % cfg.pipeline_boundary_every == 0):
                 mark_pipeline_boundary()
+            block = block_cls(cfg, mlp=cfg.mlp_kind(i),
+                              attention=cfg.attention_kind(i), name=f"h{i}")
             cache_i = kv_caches[i] if kv_caches is not None else None
-            x, new_cache, *routing = block_cls(
-                cfg, mlp=cfg.mlp_kind(i), name=f"h{i}")(
-                    x, cache_i, deterministic, seg_bias, block_positions)
+            x, new_cache, *routing = block(
+                x, cache_i, deterministic, seg_bias, block_positions,
+                cache_lengths)
             routings += routing
             if new_caches is not None:
                 new_caches.append(new_cache)
@@ -514,8 +780,12 @@ class GPTModel(nn.Module):
             logits = tok_emb.attend(x.astype(cfg.dtype))
         else:
             logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
-                              use_bias=False, name="lm_head")(x)
+                              use_bias=False, param_dtype=cfg.param_dtype,
+                              name="lm_head")(x)
         if new_caches is not None:
+            if return_routing:
+                return logits, new_caches, {
+                    "experts": jnp.stack([r["experts"] for r in routings])}
             return logits, new_caches
         if routings:
             from alpa_tpu.model.moe import routing_summary
@@ -523,14 +793,49 @@ class GPTModel(nn.Module):
         return logits
 
 
+def kv_cache_shapes(config, batch_size: int) -> list:
+    """The (B, positions, key/value heads, head size) of every layer's K
+    and V cache: ``seq_len`` positions in a "full" layer, a ring of
+    ``sliding_window`` (at most ``seq_len``) in a "sliding" one.  Takes
+    any decoder family's configuration: what ``GPTConfig`` alone has reads
+    as its default."""
+    heads = getattr(config, "num_kv_heads", None) or config.num_heads
+    hd = getattr(config, "head_dim", None) or \
+        config.hidden_size // config.num_heads
+    kinds = getattr(config, "attention", "full")
+    shapes = []
+    for i in range(config.num_layers):
+        kind = kinds if isinstance(kinds, str) else kinds[i]
+        length = min(config.sliding_window, config.seq_len) \
+            if kind == "sliding" else config.seq_len
+        shapes.append((batch_size, length, heads, hd))
+    return shapes
+
+
+def uniform_kv_caches(config) -> bool:
+    """Whether every layer's cache has one shape: what the block pool, the
+    packed prefill, the speculative verify step and beam search count on
+    (one block table, one length and one index for all layers)."""
+    return len(set(kv_cache_shapes(config, 1))) == 1
+
+
+def require_uniform_kv_caches(config, what: str):
+    if not uniform_kv_caches(config):
+        raise ValueError(
+            f"{what} indexes one cache shape for all layers, and this "
+            "configuration's layers differ (sliding-window layers hold a "
+            "ring of the window's positions, full layers the context): "
+            f"{sorted(set(kv_cache_shapes(config, 1)))}")
+
+
 def init_kv_caches(config: GPTConfig, batch_size: int,
                    dtype=None) -> list:
-    """KV caches as explicit arrays (ref opt_model.py:605 init_cache_aval)."""
+    """KV caches as explicit arrays (ref opt_model.py:605 init_cache_aval):
+    ``[(k, v, index)]`` a layer, each layer's of its own shape
+    (``kv_cache_shapes``)."""
     dtype = dtype or config.dtype
-    hd = config.hidden_size // config.num_heads
-    shape = (batch_size, config.seq_len, config.num_heads, hd)
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
-             jnp.int32(0)) for _ in range(config.num_layers)]
+    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), jnp.int32(0))
+            for shape in kv_cache_shapes(config, batch_size)]
 
 
 def init_gpt(config: GPTConfig, batch_size: int, rngkey=None):

@@ -46,16 +46,37 @@ logger = logging.getLogger(__name__)
 SCOPE = "moe"
 
 
-def topk_routing(router_logits, k: int, norm_topk_prob: bool = False):
-    """Softmax over the experts in float32, then the k largest: (T, E)
-    logits -> (weights (T, k) float32, experts (T, k) int32, probs (T, E)).
-    The weights are the probabilities themselves unless ``norm_topk_prob``
-    (OLMoE's published configuration leaves it off)."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+def topk_routing(router_logits, k: int, norm_topk_prob: bool = False,
+                 score: str = "softmax", bias=None, scale: float = 1.0):
+    """The experts' scores in float32, then the k largest: (T, E) logits ->
+    (weights (T, k) float32, experts (T, k) int32, scores (T, E)).
+
+    ``score`` "softmax" (OLMoE): the scores are the softmax over the
+    experts; "sigmoid" (Trinity, DeepSeek-V3): each expert's own sigmoid.
+    ``bias`` ((E,), None: none) is added to the scores for the CHOICE of
+    the k experts only: the weights are the chosen experts' scores
+    themselves, divided by their sum (+ 1e-20) where ``norm_topk_prob``
+    (OLMoE's published configuration leaves it off), times ``scale``."""
+    logits = router_logits.astype(jnp.float32)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / weights.sum(-1, keepdims=True)
-    return weights, experts.astype(jnp.int32), probs
+        if score == "softmax":
+            weights = weights / weights.sum(-1, keepdims=True)
+        else:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts.astype(jnp.int32), scores
 
 
 def load_balancing_loss(prob_sums, counts, n_rows):
@@ -120,11 +141,15 @@ _permute_rows.defvjp(lambda x, perm, inverse: (x[perm], inverse),
 class DroplessExperts(nn.Module):
     """Top-k routed, SiLU-gated experts without capacity (module
     docstring).  ``config`` is a ``GPTConfig``: ``num_experts``,
-    ``num_experts_per_tok``, ``mlp_width`` (one expert's), ``activation``,
-    ``norm_topk_prob``, ``dtype``.  The router and its softmax are float32
-    at full matmul precision (a near-tie between two experts then flips
-    only on the activations' own rounding); the experts multiply in
-    ``dtype`` with float32 accumulation.
+    ``num_experts_per_tok``, ``expert_width`` (one expert's),
+    ``activation``, the router's settings (``router_score``,
+    ``router_bias``, ``norm_topk_prob``, ``route_scale``:
+    ``topk_routing``), ``num_shared_experts``, ``dtype``.  The router and
+    its scores are float32 at full matmul precision (a near-tie between
+    two experts then flips only on the activations' own rounding); the
+    experts multiply in ``dtype`` with float32 accumulation.  The shared
+    experts are one gated MLP each, applied to every token and added to
+    the routed sum.
 
     Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32,
     ``prob_sums`` (E,) float32 and ``experts`` (T, k) int32."""
@@ -132,22 +157,35 @@ class DroplessExperts(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from alpa_tpu.model.gpt_model import activation_fn
+        from alpa_tpu.model.gpt_model import MLPBlock, activation_fn
         from alpa_tpu.ops.grouped_matmul import grouped_matmul
         cfg = self.config
-        e, k, width = cfg.num_experts, cfg.num_experts_per_tok, cfg.mlp_width
+        e, k, width = (cfg.num_experts, cfg.num_experts_per_tok,
+                       cfg.expert_width)
         h = x.shape[-1]
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("w_gate", init, (e, h, width))
-        w_up = self.param("w_up", init, (e, h, width))
-        w_down = self.param("w_down", init, (e, width, h))
+        if cfg.fused_gate_up:
+            # [gate | up] of every expert, side by side as the grouped
+            # matmul takes them
+            w_gate_up = self.param("w_gate_up", init, (e, h, 2 * width),
+                                   cfg.param_dtype)
+        else:
+            w_gate = self.param("w_gate", init, (e, h, width),
+                                cfg.param_dtype)
+            w_up = self.param("w_up", init, (e, h, width), cfg.param_dtype)
+        w_down = self.param("w_down", init, (e, width, h), cfg.param_dtype)
+        bias = self.param("router_bias", nn.initializers.zeros, (e,),
+                          jnp.float32) if cfg.router_bias else None
         tokens = x.reshape(-1, h)
         with jax.named_scope(SCOPE):
             logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
+                              param_dtype=cfg.param_dtype,
                               name="router")(tokens.astype(jnp.float32))
-            weights, experts, probs = topk_routing(logits, k,
-                                                   cfg.norm_topk_prob)
+            weights, experts, probs = topk_routing(
+                logits, k, cfg.norm_topk_prob, cfg.router_score,
+                None if bias is None else jax.lax.stop_gradient(bias),
+                cfg.route_scale)
             flat = experts.reshape(-1)
             # stable: an expert's rows stay in token order
             order = jnp.argsort(flat, stable=True).astype(jnp.int32)
@@ -157,8 +195,9 @@ class DroplessExperts(nn.Module):
             rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
                                     inverse, k)
             # gate and up in one pass over the rows
-            gate_up = grouped_matmul(
-                rows, jnp.concatenate([w_gate, w_up], axis=-1), counts)
+            if not cfg.fused_gate_up:
+                w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1)
+            gate_up = grouped_matmul(rows, w_gate_up, counts)
             act = activation_fn(cfg.activation)
             hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
                       gate_up[:, width:].astype(jnp.float32))
@@ -167,6 +206,10 @@ class DroplessExperts(nn.Module):
             by_token = _permute_rows(out_rows, inverse, order).reshape(
                 tokens.shape[0], k, h)
             y = (by_token.astype(jnp.float32) * weights[..., None]).sum(1)
+            for i in range(cfg.num_shared_experts):
+                y = y + MLPBlock(cfg, gated=True, width=width,
+                                 name=f"shared{i}")(tokens).astype(
+                                     jnp.float32)
         routing = {"counts": counts, "prob_sums": probs.sum(0),
                    "experts": experts}
         return y.astype(cfg.dtype).reshape(x.shape), routing

@@ -240,8 +240,12 @@ class _Replica:
 
     def __init__(self, generator: Generator, prefix=None,
                  scheduler_factory=None, on_degraded=None,
-                 warm_prefix_ids=None):
+                 warm_prefix_ids=None, engine_rows=None,
+                 chunked_admission=False):
         self.generator = generator
+        #: sizes of the deployment the streaming engine is built to
+        self.engine_rows = engine_rows
+        self.chunked_admission = chunked_admission
         self.batcher = RequestBatcher(
             generator, prefix=prefix,
             scheduler=scheduler_factory() if scheduler_factory else None)
@@ -335,11 +339,14 @@ class _Replica:
                     else:
                         from alpa_tpu.serve.kv_cache import KVBlockPool
                         pool = KVBlockPool.for_generator(self.generator)
+                rows = {} if self.engine_rows is None else \
+                    {"max_batch": self.engine_rows}
                 self._engine = ContinuousBatchingEngine(
                     self.generator,
                     prompt_bucket=self.generator.prompt_buckets[-1],
                     prefix=None if pool is not None else self.prefix,
-                    scheduler=sched, kv_pool=pool)
+                    scheduler=sched, kv_pool=pool,
+                    chunked_admission=self.chunked_admission, **rows)
                 if pool is not None and self.warm_prefix_ids is not None:
                     pool.warm_prefix(self.generator, self.warm_prefix_ids)
             return self._engine
@@ -467,8 +474,17 @@ class Controller:
                 f"service unavailable: {reason or 'backend recovering'}")
 
     def register_model(self, name: str, generator: Generator,
-                       prefix_ids=None, scheduler_factory=None):
+                       prefix_ids=None, scheduler_factory=None,
+                       engine_rows: Optional[int] = None,
+                       chunked_admission: bool = False):
         """``prefix_ids``: optional shared system prompt.
+
+        ``engine_rows``, ``chunked_admission``: sizes of the deployment,
+        for this replica's streaming engine: how many KV-cache rows it
+        decodes at once (None: the engine's default), and whether it
+        admits a prompt in the generator's prefill chunks
+        (``ContinuousBatchingEngine``; the served context is the
+        generator's ``config.seq_len``).
 
         Default (``kv_paged`` off, or ``kv_prefix_reuse`` off): its KV
         is precomputed once (Generator.cache_prefix; requires the
@@ -501,6 +517,8 @@ class Controller:
                     _Replica(generator,
                              scheduler_factory=scheduler_factory,
                              warm_prefix_ids=prefix_ids,
+                             engine_rows=engine_rows,
+                             chunked_admission=chunked_admission,
                              on_degraded=lambda e, n=name: logger.warning(
                                  "model %s replica degraded to FIFO: %s",
                                  n, e)))
@@ -543,6 +561,8 @@ class Controller:
             self._models.setdefault(name, []).append(
                 _Replica(generator, prefix=prefix,
                          scheduler_factory=scheduler_factory,
+                         engine_rows=engine_rows,
+                         chunked_admission=chunked_admission,
                          on_degraded=lambda e, n=name: logger.warning(
                              "model %s replica degraded to FIFO: %s",
                              n, e)))

@@ -57,6 +57,16 @@ _PREFILL_PROMPT = _REG.counter(
 _PREFILL_PADDED = _REG.counter(
     "alpa_serving_prefill_padded_tokens_total",
     "Token positions the engine's prefill programs ran over")
+_EXPERTS_TOUCHED = _REG.counter(
+    "alpa_moe_experts_touched_total",
+    "Distinct experts the decode ticks' routed layers touched, summed "
+    "over the layers and the ticks (over alpa_serving_decode_steps_total "
+    "and the expert layers: experts a layer a tick)")
+_KV_CACHE_BYTES = _REG.gauge(
+    "alpa_serving_kv_cache_bytes",
+    "Bytes of the engine's resident K and V caches, by the kind of the "
+    "layers' cache: window (a ring of the sliding window's positions) or "
+    "full (the served context)", labelnames=("kind",))
 
 # every engine span: category "serving", on this track (the queue waits,
 # which overlap each other, on their own)
@@ -147,7 +157,8 @@ class ContinuousBatchingEngine:
                  packed_bucket: Optional[int] = None,
                  prefix: Optional[Any] = None,
                  scheduler: Optional[Any] = None,
-                 kv_pool: Optional[Any] = None):
+                 kv_pool: Optional[Any] = None,
+                 chunked_admission: bool = False):
         """``packed_admission=True`` admits multiple queued prompts with
         ONE packed prefill (segment-masked, serve.packed.PackedPrefill —
         the 1-D batching analog) instead of one prefill per row; falls
@@ -175,7 +186,20 @@ class ContinuousBatchingEngine:
         into the row's current block.  Decode math still runs on the
         dense resident caches, so paged output is bit-exact vs unpaged.
         Mutually exclusive with ``prefix`` (warmed prefixes live in the
-        pool's index instead); disables ``packed_admission``."""
+        pool's index instead); disables ``packed_admission``.
+
+        ``chunked_admission``: a prompt is prefilled in the generator's
+        fixed chunks (``Generator(prefill_chunk=...)``, the step prefix
+        reuse takes its suffixes through) and not by the one dense prefill
+        padded to ``prompt_bucket``: one compiled chunk serves every
+        prompt up to the cache's length, which a deployment whose longest
+        prompt's dense attention would not fit the chip needs.  The
+        chunks of one admission run back to back; the resident rows wait
+        for them as they wait for a dense prefill."""
+        if chunked_admission and not generator.prefill_chunk:
+            raise ValueError("chunked admission requires "
+                             "Generator(prefill_chunk=...)")
+        self._chunked = chunked_admission
         self.gen = generator
         self.B = max_batch
         self.bucket = prompt_bucket or generator.prompt_buckets[0]
@@ -381,6 +405,15 @@ class ContinuousBatchingEngine:
         cfgm = self.gen.config
         self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
                         for (k, v, _i) in init_kv_caches(cfgm, self.B)]
+        by_kind = {"window": 0, "full": 0}
+        for k, v, _i in self._caches:
+            by_kind["full" if k.shape[1] == cfgm.seq_len
+                    else "window"] += k.nbytes + v.nbytes
+        for kind, nbytes in by_kind.items():
+            _KV_CACHE_BYTES.labels(kind).set(nbytes)
+        # what the last decode said of its routed layers ({}: no decode
+        # yet, or no such layers): read back with the next tick's tokens
+        self._routing = {}
         # the decode's logits as it returns them (every family computes
         # them in the configuration's dtype); sample_rows casts to float32
         self._logits = jnp.zeros((self.B, cfgm.vocab_size), cfgm.dtype)
@@ -417,7 +450,13 @@ class ContinuousBatchingEngine:
                 "prefilled admission is incompatible with a static "
                 "PrefixHandle engine (ingested caches carry the full "
                 "prompt)")
-        if prefilled is None and len(prompt) > self.bucket:
+        if prefilled is None and self._chunked:
+            padded = self._chunk_padded(len(prompt))
+            if padded > seq_len:
+                raise ValueError(
+                    f"prompt {len(prompt)} pads to {padded} in chunks of "
+                    f"{self.gen.prefill_chunk}, exceeding seq_len {seq_len}")
+        elif prefilled is None and len(prompt) > self.bucket:
             # prefilled rows never run this engine's prefill, so the
             # prefill bucket does not constrain them (seq_len does)
             raise ValueError(
@@ -643,6 +682,11 @@ class ContinuousBatchingEngine:
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p], total, 1, caches=h.caches,
                             start=h.length, init_last=h.last_logits)
+                    elif self._chunked:
+                        path, asked = "chunked", len(p)
+                        padded = self._chunk_padded(asked)
+                        logits1, caches1 = self.gen._run_chunked_prefill(
+                            [p], jnp.asarray([len(p)], jnp.int32), 1)
                     else:
                         path, asked, padded = "dense", len(p), self.bucket
                         ids = np.zeros((1, self.bucket), np.int32)
@@ -656,7 +700,12 @@ class ContinuousBatchingEngine:
                     if rec is not None:
                         prefill_span.args = {
                             "rid": item["rid"], "prompt_len": len(p),
-                            "padded_len": padded, "path": path}
+                            "padded_len": padded, "path": path,
+                            # programs the prefill ran: the chunks of the
+                            # chunk step, or the one dense prefill
+                            "chunks": padded // self.gen.prefill_chunk
+                            if path in ("chunked", "prefix") else
+                            int(path == "dense")}
                 _PREFILL_PROMPT.inc(asked)
                 _PREFILL_PADDED.inc(padded)
                 if seq is not None:
@@ -762,7 +811,8 @@ class ContinuousBatchingEngine:
                 self._logits, self._key, *self._settings)
         with _phase(rec, "engine.dispatch"):
             index = self._caches[0][2]          # per-row positions
-            self._logits, self._caches = self.gen._decode(
+            routing = self._routing
+            self._logits, self._caches, self._routing = self.gen._decode(
                 self.gen.params, tokens, index, self._caches)
         self.decode_steps += 1
         _DECODE_STEPS.inc()
@@ -770,8 +820,13 @@ class ContinuousBatchingEngine:
             # the read-back behind the enqueue: the host waits here for
             # the previous tick's decode, any prefill behind it, and this
             # tick's sampling; a decode that failed on the device raises
-            # here, one tick late
-            nxt = np.asarray(tokens)[:, 0]
+            # here, one tick late.  What the previous decode said of its
+            # routed layers comes along: it was done before this tick's
+            # sampling began, so nothing more is waited for
+            nxt, routing = jax.device_get((tokens, routing))
+            nxt = nxt[:, 0]
+            for layer in routing.get("experts", ()):
+                _EXPERTS_TOUCHED.inc(len(np.unique(layer)))
         if self._pool is not None:
             # the tick wrote each row's new K/V at its pre-decode index;
             # mirror those positions into the block pool (rows without a

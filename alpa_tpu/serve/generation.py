@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import GPTConfig, GPTModel, init_kv_caches
+from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, init_kv_caches,
+                                      require_uniform_kv_caches,
+                                      uniform_kv_caches)
 
 logger = logging.getLogger(__name__)
 
@@ -215,6 +217,16 @@ class Generator:
     ``_prefill``, ``_chunk_prefill`` and the verify step donate nothing (a
     ``PrefixHandle``'s caches are prefilled from again and again), nor
     does the ``parallel_method`` decode.
+
+    A configuration whose layers' caches differ (``GPTConfig.attention``:
+    a ring of the window's positions in a "sliding" layer, the context in
+    a "full" one) is prefilled with the rows' lengths handed to the model,
+    so that no ring takes a chunk's padding.
+
+    ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
+    ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
+    every routed-expert layer, and ``{}`` (no output of the compiled
+    program) for a configuration without such layers.
     """
 
     def __init__(self, model: GPTModel, params, config: GPTConfig,
@@ -243,6 +255,15 @@ class Generator:
                                      default_prompt_buckets(config.seq_len))
         self.prefill_traces = 0
         self.decode_traces = 0
+        # what the cached calls hand the model beyond ids, positions and
+        # caches (other decoder families take neither)
+        kinds = getattr(config, "mlp", "dense")
+        routed = "experts" in ([kinds] if isinstance(kinds, str) else kinds)
+        rings = not uniform_kv_caches(config)
+
+        def lengths_kw(lengths):
+            return {"cache_lengths": lengths} if rings else {}
+
         # MoE capacity hazard: bucket pads enter routing and can steal
         # expert capacity from real tokens below the no-drop regime
         # (see MoELMModel docstring)
@@ -260,7 +281,8 @@ class Generator:
             self.prefill_traces += 1
             b, s = input_ids.shape
             pos = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-            logits, caches = model.apply(params, input_ids, pos, caches)
+            logits, caches = model.apply(params, input_ids, pos, caches,
+                                         **lengths_kw(lengths))
             last = logits[jnp.arange(b), lengths - 1]
             # per-row cache indices: each row continues at its own length
             caches = [(kc, vc, lengths) for (kc, vc, _i) in caches]
@@ -269,8 +291,12 @@ class Generator:
         def decode(params, token, index, caches):
             self.decode_traces += 1
             pos = index[:, None]
+            if routed:
+                logits, caches, routing = model.apply(
+                    params, token, pos, caches, return_routing=True)
+                return logits[:, 0, :], caches, routing
             logits, caches = model.apply(params, token, pos, caches)
-            return logits[:, 0, :], caches
+            return logits[:, 0, :], caches, {}
 
         self.prefill_chunk = prefill_chunk
         self._parallel_method = parallel_method
@@ -284,7 +310,8 @@ class Generator:
             b, c = ids_chunk.shape
             start = caches[0][2]                     # scalar chunk start
             pos = start + jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
-            logits, caches = model.apply(params, ids_chunk, pos, caches)
+            logits, caches = model.apply(params, ids_chunk, pos, caches,
+                                         **lengths_kw(lengths))
             off = lengths - 1 - start                # (B,)
             hit = (off >= 0) & (off < c)
             sel = logits[jnp.arange(b), jnp.clip(off, 0, c - 1)]
@@ -463,8 +490,8 @@ class Generator:
                 nxt = jnp.where(finished, cfg.eos_token_id, nxt)
                 finished = finished | (nxt == cfg.eos_token_id)
             generated.append(nxt)
-            logits, caches = self._decode(self.params, nxt[:, None], index,
-                                          caches)
+            logits, caches, _ = self._decode(self.params, nxt[:, None],
+                                             index, caches)
             index = index + 1
             if cfg.eos_token_id is not None and bool(finished.all()):
                 break
@@ -510,6 +537,11 @@ class Generator:
         Returns (output_row, stats) where stats has ``rounds`` /
         ``proposed`` / ``accepted``.
         """
+        # a rejected round is rolled back by resetting ONE index, which a
+        # ring that the rejected tokens were written into does not undo
+        for gen in (self, draft):
+            require_uniform_kv_caches(gen.config,
+                                      "the speculative verify step")
         cfg = generation_config or GenerationConfig()
         np_rng = np.random.default_rng(seed)
         prompt = np.asarray(input_ids, np.int32).reshape(-1)
@@ -555,7 +587,7 @@ class Generator:
                       cfg.max_new_tokens - len(generated))
             if k_r < 1:
                 # no room for a proposal round: plain single decode
-                t_logits, t_caches = self._decode(
+                t_logits, t_caches, _ = self._decode(
                     self.params, jnp.asarray([[pending]], jnp.int32),
                     t_caches[0][2], t_caches)
                 pending = pick_target(t_logits)
@@ -566,7 +598,7 @@ class Generator:
             props, q_rows = [], []
             tok = pending
             for _ in range(k_r):
-                d_logits, d_caches = draft._decode(
+                d_logits, d_caches, _ = draft._decode(
                     draft.params, jnp.asarray([[tok]], jnp.int32),
                     d_caches[0][2], d_caches)
                 if cfg.do_sample:
@@ -576,7 +608,7 @@ class Generator:
                 else:
                     tok = int(np.argmax(np.asarray(d_logits)[0]))
                 props.append(tok)
-            _discard, d_caches = draft._decode(
+            _discard, d_caches, _ = draft._decode(
                 draft.params, jnp.asarray([[props[-1]]], jnp.int32),
                 d_caches[0][2], d_caches)
 
@@ -667,6 +699,7 @@ class Generator:
         ``get_index_select_mesh_executable`` beam-cache reordering
         (ref mesh_executable.py:1168 / wrapper.py:20).
         """
+        require_uniform_kv_caches(self.config, "beam search")
         input_ids = jnp.asarray(input_ids, jnp.int32)
         if input_ids.ndim == 1:
             input_ids = input_ids[None]
@@ -726,7 +759,7 @@ class Generator:
             if last_step:
                 break
             caches = self._reorder(caches, beam_idx)
-            logits, caches = self._decode(
+            logits, caches, _ = self._decode(
                 self.params, tok_idx[:, None],
                 jnp.full((num_beams,), index, jnp.int32), caches)
             index += 1

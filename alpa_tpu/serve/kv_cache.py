@@ -43,7 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu.global_env import global_config
-from alpa_tpu.model.gpt_model import init_kv_caches
+from alpa_tpu.model.gpt_model import (init_kv_caches,
+                                      require_uniform_kv_caches)
 from alpa_tpu.telemetry import metrics as _tmetrics
 
 logger = logging.getLogger(__name__)
@@ -148,6 +149,7 @@ class KVBlockPool:
         # per-layer pool arrays mirror the engine cache convention via
         # the same init used for the dense caches (works for any family
         # honoring the (k, v, index) contract)
+        require_uniform_kv_caches(config, "the KV block pool")
         template = init_kv_caches(config, 1)
         self._kp, self._vp = [], []
         self.token_bytes = 0

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import GPTConfig, init_kv_caches
+from alpa_tpu.model.gpt_model import (GPTConfig, init_kv_caches,
+                                      require_uniform_kv_caches)
 
 logger = logging.getLogger(__name__)
 
@@ -78,6 +79,7 @@ class PackedPrefill:
         offset ``prefix.length``: every segment attends to the prefix
         K/V plus its own span, positions continue from the prefix, and
         the per-row re-gather lays each row out as [prefix | suffix]."""
+        require_uniform_kv_caches(config, "the packed prefill")
         self.model = model
         self.params = params
         self.config = config

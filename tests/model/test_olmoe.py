@@ -390,7 +390,8 @@ def test_config_from_hf_refuses_what_it_cannot_build():
     assert not cfg.use_bias and not cfg.tie_embeddings
     with pytest.raises(ValueError, match="model_type"):
         config_from_hf(dict(TOY, model_type="mamba"))
-    with pytest.raises(ValueError, match="grouped-query"):
-        config_from_hf(dict(TOY, num_key_value_heads=2))
+    # grouped-query attention builds since PR 30 (tests/model/test_trinity.py)
+    assert config_from_hf(dict(TOY, num_key_value_heads=2)).kv_heads == 2
+    assert cfg.kv_heads == cfg.num_heads and cfg.num_kv_heads is None
     with pytest.raises(ValueError, match="rope_scaling"):
         config_from_hf(dict(TOY, rope_scaling={"type": "yarn"}))

@@ -198,7 +198,8 @@ def test_engine_spans_nest_and_share_the_request_id(recorder, tmp_path):
     rid = request["args"]["rid"]
     assert wait["args"] == {"rid": rid, "prompt_len": 5}
     assert prefill["args"] == {"rid": rid, "prompt_len": 5,
-                               "padded_len": BUCKET, "path": "dense"}
+                               "padded_len": BUCKET, "path": "dense",
+                               "chunks": 1}
     assert admit["args"] == {"n": 1}
     assert all(s["category"] == "serving" for s in
                (request, wait, admit, prefill))

@@ -385,7 +385,7 @@ def test_decode_takes_k_and_v_and_leaves_the_index():
     index = caches[0][2]
     caches = [(k, v, index) for k, v, _i in caches]
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-    _logits, new = gen._decode(gen.params, tok, index, caches)
+    _logits, new, _ = gen._decode(gen.params, tok, index, caches)
     assert all(k.is_deleted() and v.is_deleted() for k, v, _i in caches)
     assert int(index[0]) == len(PROMPTS[0])
     assert int(new[0][2][0]) == len(PROMPTS[0]) + 1
@@ -417,3 +417,98 @@ def test_engine_survives_a_decode_that_took_its_caches():
         gen._decode = decode
         engine.shutdown()
     assert engine.step_failures == 1
+
+
+# ---- a configuration whose layers' caches differ (PR 30) ---------------
+
+TRINITY_ROWS = 16
+
+
+def _trinity_decode_hlo(one_chip):
+    """The decode of ``trinity-mini-1chip`` as its cell compiles it: the
+    published widths, one leading dense layer and one period of window,
+    window, window and full attention layers over routed experts, 16
+    rows, served context 16,384, bfloat16 parameters and caches."""
+    import json
+    from alpa_tpu.model.gpt_model import config_from_hf
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "trinity-mini-1chip.json")) as f:
+        hf = json.load(f)
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=hf["serve"]["served_context"])
+    model = GPTModel(cfg)
+    params = _abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                      jnp.ones((1, 8), jnp.int32)),
+                       one_chip)
+    caches = _abstract(jax.eval_shape(
+        lambda: [(k, v, jnp.zeros((TRINITY_ROWS,), jnp.int32))
+                 for k, v, _ in init_kv_caches(cfg, TRINITY_ROWS)]),
+        one_chip)
+    gen = Generator(model, params, cfg, prefill_chunk=1024)
+    tok = jax.ShapeDtypeStruct((TRINITY_ROWS, 1), jnp.int32,
+                               sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((TRINITY_ROWS,), jnp.int32,
+                               sharding=one_chip)
+    hlo = gen._decode.jitted.lower(
+        params, tok, idx, [(k, v) for k, v, _ in caches],
+        [i for _, _, i in caches]).compile().as_text()
+    return hlo, [k.shape for k, _v, _i in caches], \
+        len(jax.tree_util.tree_leaves(params))
+
+
+@pytest.fixture(scope="module")
+def trinity_decode(one_chip):
+    return _trinity_decode_hlo(one_chip)
+
+
+def _cache_type(shape):
+    return "bf16[%s]" % ",".join(str(d) for d in shape)
+
+
+def test_caches_of_two_shapes_land_in_their_own_buffers(trinity_decode):
+    """Window layers hold (16, 2048, 4, 128), the full layer (16, 16384,
+    4, 128): jax pairs donated arrays with outputs by shape and order, and
+    the pairing still gives every layer's K and V the buffer it came in.
+    Every row is written with one ``dynamic-update-slice``, the ring's at
+    ``position % 2048``."""
+    hlo, shapes, n_params = trinity_decode
+    assert hlo.startswith("HloModule jit_decode")
+    # (the grouped matmul's group metadata scatter-adds a few integers)
+    assert not re.search(r"= bf16\[16,\d+,4,128\]\S* scatter", hlo)
+    assert shapes == 4 * [(16, 2048, 4, 128)] + [(16, 16384, 4, 128)]
+    aliases = _aliases(hlo)
+    # outputs: logits, then (k, v, index) a layer, then the experts
+    # touched; arguments: parameters, tokens, index, (k, v) a layer
+    assert sorted(aliases) == [o + 3 * layer for layer in range(5)
+                               for o in (1, 2)]
+    given = [aliases[o] for o in sorted(aliases)]
+    assert given == list(range(given[0], given[0] + 10))
+    assert given[0] <= n_params + 2
+    # the entry layout's types, argument by argument
+    layout = re.search(r"entry_computation_layout=\{\((.*?)\)->", hlo,
+                       re.S).group(1)
+    types = re.findall(r"[a-z]+\d+\[[\d,]*\]", layout)
+    for layer, shape in enumerate(shapes):
+        for which in (0, 1):
+            assert types[given[2 * layer + which]] == _cache_type(shape)
+    for shape, layers in (((16, 2048, 4, 128), 4), ((16, 16384, 4, 128), 1)):
+        writes = re.findall(r"= %s\S* dynamic-update-slice\(" %
+                            re.escape(_cache_type(shape)), hlo)
+        assert len(writes) == 2 * layers * TRINITY_ROWS
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "the compiler re-lays every grouped-query cache out once a tick: the "
+    "rows are written in place in the entry layout {3,2,1,0:T(4,128)}, "
+    "and the product of 8 query heads with each of the 4 key/value heads "
+    "then wants the heads outside the positions, {3,1,2,0:T(8,128)}: one "
+    "copy of all 0.81 GB of caches a tick, written and read again "
+    "(PERF.md, PR 30 and section 7)"))
+def test_trinity_decode_relays_no_cache(trinity_decode):
+    hlo, shapes, _ = trinity_decode
+    for shape in set(shapes):
+        other = re.findall(r"%s\{(?!3,2,1,0)[\d,]+" %
+                           re.escape(_cache_type(shape)), hlo)
+        assert other == []

@@ -145,6 +145,26 @@ def _serve(engine, prompts, cfgs):
     return outs
 
 
+def _serve_together(engine, prompts, cfgs):
+    """``_serve`` with every request in the queue before the engine's loop
+    sees any of them: they are queued under the loop's own lock, so one
+    admission gives them their rows and their first tick is shared.  (From
+    threads of their own the second may be admitted only after the first
+    has been served, on a loaded machine.)"""
+    with engine._cv:
+        items = [engine._make_item(p, cfg, None)
+                 for p, cfg in zip(prompts, cfgs)]
+        for item in items:
+            engine._queue.append(item)
+        engine._cv.notify()
+    outs = []
+    for item in items:
+        assert item["done"].wait(120)
+        outs.append(item["error"] or np.concatenate(
+            [item["prompt"], np.asarray(item["tokens"], np.int32)]))
+    return outs
+
+
 def _sample_ticks():
     snap = tmetrics.get_registry().snapshot()
     return {mode: snap.get(
@@ -333,7 +353,7 @@ def test_a_decode_that_fails_behind_its_enqueue_fails_the_resident_rows():
     try:
         want = engine.submit(PROMPTS[1], greedy)
         gen._decode = failing
-        outs = _serve(engine, PROMPTS[:2], [sampling, sampling])
+        outs = _serve_together(engine, PROMPTS[:2], [sampling, sampling])
         assert both_in.is_set()
         assert all(isinstance(o, RuntimeError) and
                    "lost the step" in str(o) for o in outs)

@@ -59,7 +59,7 @@ class TestPackedPrefill:
                       for (k, v, idx) in row_caches]
             toks = [int(np.argmax(np.asarray(last[r])))]
             for _ in range(4):
-                step, caches = gen._decode(
+                step, caches, _ = gen._decode(
                     gen.params, jnp.asarray([[toks[-1]]], jnp.int32),
                     caches[0][2], caches)
                 toks.append(int(np.argmax(np.asarray(step)[0])))

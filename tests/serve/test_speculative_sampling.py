@@ -128,7 +128,7 @@ class TestEndToEndSampled:
             # _decode takes the K and V arrays it is given: every branch
             # from the prefilled state decodes a copy of them
             branch = [(jnp.copy(k), jnp.copy(v), i) for k, v, i in caches0]
-            l1, _ = target._decode(
+            l1, _, _ = target._decode(
                 target.params, jnp.asarray([[int(t0)]], jnp.int32),
                 caches0[0][2], branch)
             p1 = _warp_probs_np(np.asarray(l1)[0], gcfg)
