@@ -19,9 +19,24 @@ float32; results come in the inputs' dtypes.
 lowers to a Mosaic kernel of its own with 512 x 512 x 512 tiles, not to 64
 dense products), and was measured against this on the chip at the expert
 layer's shapes, forward and backward: 32.0 ms against 23.4 ms for 131,072
-rows of 2048 x 2048 over 64 groups (PERF.md, PR 26).  The larger tiles
-below are the difference; the kernel's default of 128 x 128 x 128 is 7
-times slower than either.
+rows of 2048 x 2048 over 64 groups (PERF.md, PR 26).  The larger tiles are
+the difference; the kernel's default of 128 x 128 x 128 is 7 times slower
+than either.
+
+The tiles of a call follow from its static shape alone (``tiling``; the
+sweeps are in PERF.md, PR 26 and PR 31).  The kernel walks (row tile,
+group) pairs: every pair that shares a row is one grid step of a whole
+row tile's multiply-adds with the other groups' rows masked, up to
+``m / tm + groups - 1`` steps.  So the row tile follows ``m / groups``, the
+rows a group can expect: 512 where a group holds a thousand rows (a
+training step), 128 where it holds 64 (a served prompt's chunk of 1,024
+positions over 128 experts, where 512 multiplies nine tiles of padding
+for every tile of rows).  The contracted dimension is the grid's
+innermost, so while it is tiled every step fetches its group's weights
+anew; walked whole, consecutive steps of one group keep their block and
+a group's weights are read once a produced column.  It is walked whole
+where the blocks then fit the kernel's fast memory and there is more than
+one row tile to fetch them for.
 
 The kernels are compiled where the program is lowered for a TPU and
 interpreted on any other platform (``lax.platform_dependent``, at lowering
@@ -34,26 +49,63 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
+from alpa_tpu.telemetry import metrics as tmetrics
+
 # the scope every call is traced under: the benchmark finds the kernels'
 # device events by it (HLO metadata ``op_name``)
 SCOPE = "grouped_matmul"
 
-# rows, contracted and produced columns of one tile: the fastest of the
-# tilings tried on the v5e (PERF.md, PR 26), and the largest whose
-# backward pass fits the kernel's 16 MB of fast memory
+# rows, contracted and produced columns of one tile at their largest: the
+# fastest of the tilings tried on the v5e where a group holds a thousand
+# rows (PERF.md, PR 26), and the largest whose backward pass fits the
+# kernel's 16 MB of fast memory
 TILING = (512, 1024, 1024)
+# no row tile under the matrix unit's 128 rows: at 64 rows a group, 64 was
+# no faster than 128 on the chip (PERF.md, PR 31)
+MIN_ROW_TILE = 128
+# of the kernel's 16 MiB, what its blocks may take: the rest is the
+# compiler's (the store's mask and select over a float32 tile)
+VMEM_BUDGET = 12 * 2**20
 
 
-def _tiles(*dims):
-    """The tiling for a product of these (m, k, n): ``TILING`` cut down to
-    divisors of smaller dimensions."""
-    tiles = []
-    for size, tile in zip(dims, TILING):
-        tile = min(tile, size)
-        while size % tile:
-            tile //= 2
-        tiles.append(tile)
-    return tuple(tiles)
+def _gmm_vmem(tm, tk, tn):
+    """Bytes of ``gmm``'s blocks in fast memory: the three bfloat16 blocks
+    twice (the pipeline fetches a step ahead) and the float32
+    accumulator."""
+    return 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _divisor(size, tile):
+    tile = min(tile, size)
+    while size % tile:
+        tile //= 2
+    return tile
+
+
+def tiling(m, k, n, groups):
+    """(tm, tk, tn) for ``gmm`` of (m, k) x (groups, k, n), from the shape
+    alone (module docstring).  The row tile is the rows a group can expect,
+    ``m / groups``, up to the next power of two, between ``MIN_ROW_TILE``
+    and ``TILING``'s; the other two are ``TILING``'s, then, where the rows
+    are more than one tile, the contracted dimension whole and the produced
+    one whole, each if the blocks still fit ``VMEM_BUDGET``.  Every tile is
+    cut down to a divisor of its dimension."""
+    rows = -(-m // groups)
+    tm = _divisor(m, min(TILING[0],
+                         max(MIN_ROW_TILE, 1 << (rows - 1).bit_length())))
+    tk, tn = _divisor(k, TILING[1]), _divisor(n, TILING[2])
+    if m > tm:
+        if _gmm_vmem(tm, k, tn) <= VMEM_BUDGET:
+            tk = k
+        if _gmm_vmem(tm, tk, n) <= VMEM_BUDGET:
+            tn = n
+    return tm, tk, tn
+
+
+def padded_work_ratio(m, groups, tm):
+    """The bound on the rows the kernel multiplies over the rows it has:
+    ``m / tm + groups - 1`` grid steps of ``tm`` rows each."""
+    return (m // tm + groups - 1) * tm / m
 
 
 def _run(kernel, *args, **static):
@@ -64,10 +116,17 @@ def _run(kernel, *args, **static):
 
 @jax.custom_vjp
 def _grouped_matmul(lhs, rhs, group_sizes):
-    (m, k), n = lhs.shape, rhs.shape[2]
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    tiles = tiling(m, k, n, groups)
+    # at trace time: what the shape made of the row tile
+    tmetrics.get_registry().gauge(
+        "alpa_grouped_matmul_padded_work_ratio",
+        "bound on the rows the grouped matmul multiplies over the rows it "
+        "has, by the call's shape", ("m", "groups")).labels(m, groups).set(
+            padded_work_ratio(m, groups, tiles[0]))
     with jax.named_scope(SCOPE):
         return _run(gmm, lhs, rhs, group_sizes,
-                    preferred_element_type=lhs.dtype, tiling=_tiles(m, k, n))
+                    preferred_element_type=lhs.dtype, tiling=tiles)
 
 
 def _forward(lhs, rhs, group_sizes):
@@ -76,14 +135,20 @@ def _forward(lhs, rhs, group_sizes):
 
 def _backward(residuals, g):
     lhs, rhs, group_sizes = residuals
-    (m, k), n = lhs.shape, rhs.shape[2]
+    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    # tgmm produces (tk, tn) tiles with a float32 accumulator each: they
+    # stay TILING's, which is what fits (PERF.md, PR 26), under the rule's
+    # row tile
+    tm = tiling(m, k, n, groups)[0]
     with jax.named_scope(SCOPE):
         d_lhs = _run(gmm, g, rhs, group_sizes,
                      preferred_element_type=lhs.dtype,
-                     tiling=_tiles(m, n, k), transpose_rhs=True)
+                     tiling=tiling(m, n, k, groups), transpose_rhs=True)
         d_rhs = _run(tgmm, lhs.swapaxes(0, 1), g, group_sizes,
                      preferred_element_type=rhs.dtype,
-                     tiling=_tiles(m, k, n), num_actual_groups=rhs.shape[0])
+                     tiling=(tm, _divisor(k, TILING[1]),
+                             _divisor(n, TILING[2])),
+                     num_actual_groups=groups)
     return d_lhs, d_rhs, None
 
 

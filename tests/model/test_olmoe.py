@@ -166,11 +166,20 @@ def test_every_token_to_the_same_experts_still_agrees(toy, reference):
         ref.lm_loss(weights, batch["input_ids"], batch["labels"]), rel=2e-6)
 
 
-@pytest.mark.parametrize("sizes", [[40, 0, 3, 85], [128, 0, 0, 0],
-                                   [0, 0, 0, 128], [32, 32, 32, 32],
-                                   [1, 126, 1, 0]],
-                         ids=["uneven", "first-only", "last-only", "even",
-                              "single-rows"])
+# the last four have more groups than row tiles (256 rows over 16 groups:
+# a row tile of 128, so two tiles), as a served chunk has: groups smaller
+# than a tile, empty groups between them, and one group with a quarter of
+# the rows
+@pytest.mark.parametrize("sizes", [
+    [40, 0, 3, 85], [128, 0, 0, 0], [0, 0, 0, 128], [32, 32, 32, 32],
+    [1, 126, 1, 0],
+    [16] * 16,
+    [20, 9, 17, 0, 23, 14, 0, 0, 31, 6, 19, 25, 0, 80, 11, 1],
+    [64, 13, 0, 12, 14, 13, 0, 12, 64, 13, 12, 13, 0, 13, 0, 13],
+    [0, 0, 0, 0, 0, 0, 0, 129, 0, 0, 0, 0, 0, 0, 0, 127]],
+    ids=["uneven", "first-only", "last-only", "even", "single-rows",
+         "more-groups-than-tiles", "small-and-empty-groups",
+         "a-quarter-on-a-group", "straddling-the-tile"])
 def test_grouped_matmul_against_a_loop_over_experts(sizes):
     m, k, n = sum(sizes), 64, 32
     lhs = jax.random.normal(jax.random.PRNGKey(0), (m, k))
@@ -318,6 +327,26 @@ def test_record_routing_feeds_the_registry():
     assert after["alpa_moe_dropped_rows_total"] - \
         before.get("alpa_moe_dropped_rows_total", 0) == 3
     assert after["alpa_moe_expert_load_max_over_mean"] == 2.0
+
+
+@pytest.mark.parametrize("m,k,n,groups,ratio", [
+    (65536, 2048, 2048, 64, (128 + 63) * 512 / 65536),
+    (8192, 2048, 2048, 128, (64 + 127) * 128 / 8192),
+    (128, 1024, 2048, 128, 128.0)],
+    ids=["olmoe-train", "trinity-chunk", "trinity-decode"])
+def test_a_traced_grouped_matmul_sets_the_padded_work_gauge(m, k, n, groups,
+                                                            ratio):
+    """Tracing a call (nothing runs) leaves, under the call's rows and
+    groups, the bound on multiplied over useful rows that its row tile
+    gives: ``(m / tm + groups - 1) x tm / m``."""
+    from alpa_tpu.telemetry import metrics as tmetrics
+    jax.eval_shape(grouped_matmul,
+                   jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((groups,), jnp.int32))
+    series = ('alpa_grouped_matmul_padded_work_ratio'
+              f'{{m="{m}",groups="{groups}"}}')
+    assert tmetrics.get_registry().snapshot()[series] == ratio
 
 
 def test_legacy_capacity_path_reports_its_drops():

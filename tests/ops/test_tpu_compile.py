@@ -80,17 +80,36 @@ def test_flash_kernels_compile_for_v5e(one_chip, fn, shape, residuals,
     assert hlo.count('custom_call_target="tpu_custom_call"') == n_kernels
 
 
-# the expert layer's grouped matmuls at OLMoE's widths, 64 groups, batch 2
-# of 4096 tokens with 8 experts each: (rows, contracted, produced)
-GROUPED_CASES = [("gate-up", (65536, 2048, 2048)),
-                 ("down", (65536, 1024, 2048))]
+# the expert layers' grouped matmuls at published widths, as (id, (rows,
+# contracted, produced), groups, the weights' dtype, gradients too, Pallas
+# kernels expected): OLMoE's training step (batch 2 of 4096 tokens with 8
+# of 64 experts each, float32 parameters), Trinity's served chunk of 1,024
+# positions and its decode tick of 16 rows (8 of 128 experts, bfloat16
+# parameters, forward only), and the chunk's shapes under a gradient
+GROUPED_CASES = [
+    ("gate-up", (65536, 2048, 2048), 64, jnp.float32, True, 3),
+    ("down", (65536, 1024, 2048), 64, jnp.float32, True, 3),
+    ("trinity-chunk-gate-up", (8192, 2048, 2048), 128, jnp.bfloat16, False,
+     1),
+    ("trinity-chunk-down", (8192, 1024, 2048), 128, jnp.bfloat16, False, 1),
+    ("trinity-decode-gate-up", (128, 2048, 2048), 128, jnp.bfloat16, False,
+     1),
+    ("trinity-decode-down", (128, 1024, 2048), 128, jnp.bfloat16, False, 1),
+    ("trinity-chunk-gate-up-grad", (8192, 2048, 2048), 128, jnp.bfloat16,
+     True, 3),
+    ("trinity-chunk-down-grad", (8192, 1024, 2048), 128, jnp.bfloat16, True,
+     3),
+]
 
 
-@pytest.mark.parametrize("shape", [c[1] for c in GROUPED_CASES],
+@pytest.mark.parametrize("shape,groups,dtype,grads,n_kernels",
+                         [c[1:] for c in GROUPED_CASES],
                          ids=[c[0] for c in GROUPED_CASES])
-def test_grouped_matmul_compiles_for_v5e(one_chip, shape):
-    """Forward and both gradients: three Pallas kernels, none interpreted,
-    all inside the kernel's fast memory at the tiling the op chooses."""
+def test_grouped_matmul_compiles_for_v5e(one_chip, shape, groups, dtype,
+                                         grads, n_kernels):
+    """Forward, and both gradients where a step takes them: one or three
+    Pallas kernels, none interpreted, all inside the kernel's fast memory
+    at the tiling the op chooses for the shape."""
     from alpa_tpu.ops.grouped_matmul import grouped_matmul
     m, k, n = shape
 
@@ -104,8 +123,74 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     assert jax.default_backend() == "cpu"
-    hlo = jax.jit(value_and_grads).lower(
-        spec((m, k), jnp.bfloat16), spec((64, k, n), jnp.float32),
-        spec((64,), jnp.int32), spec((m, n), jnp.bfloat16)
-    ).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    args = [spec((m, k), jnp.bfloat16), spec((groups, k, n), dtype),
+            spec((groups,), jnp.int32)]
+    if grads:
+        hlo = jax.jit(value_and_grads).lower(
+            *args, spec((m, n), jnp.bfloat16)).compile().as_text()
+    else:
+        hlo = jax.jit(grouped_matmul).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == n_kernels
+
+
+# (rows, contracted, produced, groups) of a call -> its tiles: OLMoE's six
+# (forward, gradient of the rows with the weights transposed, gradient of
+# the weights; gate-and-up and down) keep PR 26's, the decode keeps what
+# PR 26's cut down to divisors gave it, the chunk gets the sweep's (PERF.md,
+# PR 31)
+TILING_CASES = [
+    ("olmoe-gate-up-forward", (65536, 2048, 2048, 64), (512, 1024, 1024)),
+    ("olmoe-gate-up-rows-gradient", (65536, 2048, 2048, 64),
+     (512, 1024, 1024)),
+    ("olmoe-gate-up-weights-gradient", (65536, 2048, 2048, 64),
+     (512, 1024, 1024)),
+    ("olmoe-down-forward", (65536, 1024, 2048, 64), (512, 1024, 1024)),
+    ("olmoe-down-rows-gradient", (65536, 2048, 1024, 64),
+     (512, 1024, 1024)),
+    ("olmoe-down-weights-gradient", (65536, 1024, 2048, 64),
+     (512, 1024, 1024)),
+    ("trinity-decode-gate-up", (128, 2048, 2048, 128), (128, 1024, 1024)),
+    ("trinity-decode-down", (128, 1024, 2048, 128), (128, 1024, 1024)),
+    ("trinity-chunk-gate-up", (8192, 2048, 2048, 128), (128, 2048, 1024)),
+    ("trinity-chunk-down", (8192, 1024, 2048, 128), (128, 1024, 2048)),
+    ("toy-rows-no-multiple-of-the-tile", (192, 48, 40, 4), (64, 48, 40)),
+]
+
+
+@pytest.mark.parametrize("call,tiles", [c[1:] for c in TILING_CASES],
+                         ids=[c[0] for c in TILING_CASES])
+def test_grouped_matmul_tiling_follows_the_shape(call, tiles):
+    from alpa_tpu.ops import grouped_matmul as gm
+    assert gm.tiling(*call) == tiles
+    m, _, _, groups = call
+    assert gm.padded_work_ratio(m, groups, tiles[0]) == \
+        (m // tiles[0] + groups - 1) * tiles[0] / m
+
+
+def test_grouped_matmul_kernels_get_the_rules_tiles(monkeypatch):
+    """What reaches the three kernels at a shape where the rule departs
+    from ``TILING``: the forward and the rows' gradient take the rule's
+    tiles for their own (rows, contracted, produced); the weights'
+    gradient keeps its produced tiles within ``TILING``'s."""
+    from alpa_tpu.ops import grouped_matmul as gm
+    seen = []
+
+    def record(kernel):
+        def call(*args, tiling, interpret, **static):
+            seen.append((kernel.__name__, tiling))
+            return kernel(*args, tiling=tiling, interpret=interpret,
+                          **static)
+        call.__name__ = kernel.__name__
+        return call
+
+    monkeypatch.setattr(gm, "gmm", record(gm.gmm))
+    monkeypatch.setattr(gm, "tgmm", record(gm.tgmm))
+    m, k, n, groups = 8192, 2048, 2048, 128
+    jax.eval_shape(
+        jax.grad(lambda a, b, s: gm.grouped_matmul(a, b, s).sum().astype(
+            jnp.float32), (0, 1)),
+        jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups,), jnp.int32))
+    assert set(seen) == {("gmm", (128, 2048, 1024)),
+                         ("tgmm", (128, 1024, 1024))}
