@@ -97,6 +97,7 @@ class GPTConfig:
     # earlier position; its cache holds seq_len positions) | "sliding"
     # (position q sees k where q - k < sliding_window; its cache is a ring
     # of sliding_window positions, written at position % sliding_window)
+    # | "latent" (below: its cache holds seq_len positions of a latent)
     attention: Any = "full"
     sliding_window: int = 0
     # False: rotary positions turn the q and k of "sliding" layers only,
@@ -127,6 +128,40 @@ class GPTConfig:
     fused_gate_up: bool = False
     # what the parameters are STORED in (``dtype`` is what is computed in)
     param_dtype: Any = jnp.float32
+    # --- latent attention (MLA) and group-limited routing over a share of
+    # the experts (DeepSeek-V2, ``model_type`` deepseek_v2).  Every default
+    # is the block of today.  An ``attention`` kind "latent": queries
+    # through a low-rank projection of ``q_lora_rank`` (0: one full
+    # matrix), keys and values through one of ``kv_lora_rank`` with an
+    # RMSNorm each; a head scores with ``qk_nope_head_dim`` channels that
+    # see no positions and ``qk_rope_head_dim`` rotated ones, whose key is
+    # ONE for all heads, and carries values of ``v_head_dim``.  The cache
+    # of such a layer holds the normed latent and the rotated key of every
+    # position (``kv_lora_rank + qk_rope_head_dim`` values) and nothing a
+    # head (``LatentAttention``).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # what a latent layer's scores are multiplied by; None:
+    # (qk_nope_head_dim + qk_rope_head_dim) ** -0.5
+    attn_scale: Optional[float] = None
+    # rotary pairs: channel i with i + D/2 (rotate-half), or 2i with 2i + 1
+    rope_interleaved: bool = False
+    # YaRN (Peng et al. 2023): (factor, original context, beta_fast,
+    # beta_slow, what cosine and sine are multiplied by); None: plain
+    # frequencies (``yarn_inv_freq``)
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    # group-limited routing: the experts in ``n_group`` groups of
+    # consecutive experts, a token's experts chosen inside its best
+    # ``topk_group`` groups (``moe.topk_routing``)
+    n_group: int = 1
+    topk_group: int = 1
+    # (first, count): the routed experts THIS program holds, where the
+    # layer's experts are divided over several chips; the router stays
+    # ``num_experts`` wide (``moe.DroplessExperts``).  None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
@@ -207,6 +242,12 @@ _HF_KINDS = {
     "afmoe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
                   rope_on_full_attention=False, attn_gate=True,
                   post_norms=True, router_bias=True, fused_gate_up=True),
+    # DeepSeek-V2: wiring read from modeling_deepseek.py beside the
+    # config.json and transformers' models/deepseek_v2 where config.json
+    # does not fix it
+    "deepseek_v2": dict(norm="rmsnorm", positions="rotary",
+                        attention="latent", rope_interleaved=True,
+                        fused_gate_up=True),
 }
 
 
@@ -234,16 +275,87 @@ def _afmoe_fields(hf: dict) -> dict:
         scale_embedding=hf["mup_enabled"])
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 m ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """The ``dim / 2`` rotary frequencies under YaRN and the pairs
+    ``(low, high)`` between which they are blended: pair i turns at
+    ``f_i = theta^(-2i/dim)`` where it makes more than ``beta_fast`` turns
+    over the ``original`` context (i <= low), at ``f_i / factor`` where
+    fewer than ``beta_slow`` (i >= high), and at a linear blend of the two
+    between."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_of(turns):
+        return dim * np.log(original / (turns * 2 * np.pi)) / \
+            (2 * np.log(theta))
+
+    low = max(int(np.floor(pair_of(beta_fast))), 0)
+    high = min(int(np.ceil(pair_of(beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return f * (1.0 - ramp) + f / factor * ramp, (low, high)
+
+
+def _deepseek_v2_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` deepseek_v2 says beyond the
+    keys all decoders share: the ranks and head sizes of latent attention,
+    YaRN, the leading dense layers, the routed and shared experts and the
+    group-limited choice among them."""
+    layers = hf["num_hidden_layers"]
+    if hf.get("topk_method", "group_limited_greedy") != \
+            "group_limited_greedy" or hf["scoring_func"] != "softmax":
+        raise ValueError("deepseek_v2: only softmax scores under "
+                         "group_limited_greedy are supported")
+    fields = dict(
+        mlp=tuple("experts" if i >= hf["first_k_dense_replace"] and
+                  i % hf["moe_layer_freq"] == 0 else "gated"
+                  for i in range(layers)),
+        q_lora_rank=hf["q_lora_rank"] or 0,
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        num_shared_experts=hf["n_shared_experts"],
+        norm_topk_prob=hf["norm_topk_prob"],
+        route_scale=float(hf["routed_scaling_factor"]),
+        n_group=hf["n_group"], topk_group=hf["topk_group"])
+    scale = (hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]) ** -0.5
+    yarn = hf.get("rope_scaling")
+    if yarn:
+        if yarn.get("type") != "yarn":
+            raise ValueError(f"unknown rope_scaling type "
+                             f"{yarn.get('type')!r} (known: 'yarn')")
+        all_dim = yarn_mscale(yarn["factor"], yarn["mscale_all_dim"])
+        fields["rope_yarn"] = (
+            float(yarn["factor"]),
+            int(yarn["original_max_position_embeddings"]),
+            float(yarn["beta_fast"]), float(yarn["beta_slow"]),
+            float(yarn_mscale(yarn["factor"], yarn["mscale"]) / all_dim))
+        scale *= all_dim * all_dim
+    fields["attn_scale"] = float(scale)
+    return fields
+
+
 def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     """``GPTConfig`` from the keys of a Hugging Face ``config.json`` (a
     dict), for the model types in ``_HF_KINDS``.  ``kwargs`` override what
     the file says (``seq_len``: the context a deployment serves, where it
-    is less than the declared ``max_position_embeddings``)."""
+    is less than the declared ``max_position_embeddings``;
+    ``experts_held``: this chip's share of the routed experts).  Of
+    ``rope_scaling`` only deepseek_v2's ``yarn`` is known."""
     kinds = _HF_KINDS.get(hf["model_type"])
     if kinds is None:
         raise ValueError(f"no decoder kinds for model_type "
                          f"{hf['model_type']!r} (known: {sorted(_HF_KINDS)})")
-    if hf.get("rope_scaling") or hf.get("clip_qkv"):
+    if hf.get("clip_qkv") or (hf.get("rope_scaling") and
+                              hf["model_type"] != "deepseek_v2"):
         raise ValueError("rope_scaling and clip_qkv are not supported")
     fields = dict(
         vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
@@ -255,14 +367,16 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         rope_theta=float(hf["rope_theta"]),
         use_bias=hf.get("attention_bias", False),
         tie_embeddings=hf["tie_word_embeddings"],
-        num_experts=hf["num_experts"],
         num_experts_per_tok=hf["num_experts_per_tok"], **kinds)
     if hf["num_key_value_heads"] != hf["num_attention_heads"]:
         fields["num_kv_heads"] = hf["num_key_value_heads"]
-    if hf["model_type"] == "afmoe":
-        fields.update(_afmoe_fields(hf))
+    if hf["model_type"] == "deepseek_v2":
+        fields.update(_deepseek_v2_fields(hf))
+    elif hf["model_type"] == "afmoe":
+        fields.update(num_experts=hf["num_experts"], **_afmoe_fields(hf))
     else:
-        fields["norm_topk_prob"] = hf["norm_topk_prob"]
+        fields.update(num_experts=hf["num_experts"],
+                      norm_topk_prob=hf["norm_topk_prob"])
     fields.update(kwargs)
     return GPTConfig(**fields)
 
@@ -279,18 +393,35 @@ def make_norm(config: GPTConfig, name: str) -> nn.Module:
                         param_dtype=config.param_dtype, name=name)
 
 
-def apply_rotary(x, position_ids, theta: float):
+def apply_rotary(x, position_ids, theta: float, interleaved: bool = False,
+                 yarn=None):
     """Rotate-half rotary position embedding (Su et al. 2021, as in
     Hugging Face's ``apply_rotary_pos_emb``): x (B, S, H, D), positions
     (B, S).  Channel i is paired with channel i + D/2; the angle of pair i
-    is position * theta^(-2i/D).  Computed in float32."""
+    is position * theta^(-2i/D).  Computed in float32.
+
+    ``interleaved``: pair i is channels 2i and 2i + 1 (DeepSeek-V2); the
+    result then holds the pairs' first channels in its first half and
+    their second in its second (the same order for every vector rotated,
+    so no dot product of two of them sees it).  ``yarn``
+    (``GPTConfig.rope_yarn``): the frequencies of ``yarn_inv_freq``, and
+    cosine and sine times its last entry."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = jnp.asarray(
+            yarn_inv_freq(x.shape[-1], theta, *yarn[:4])[0], jnp.float32)
     angles = position_ids.astype(jnp.float32)[..., None] * inv_freq
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None and yarn[4] != 1.0:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
+    if interleaved:
+        x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    else:
+        x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
@@ -514,6 +645,271 @@ def update_ring_cache(kv_cache, k, v, lengths=None):
     return k_use, v_use, k_positions, (k_new, v_new, index + s)
 
 
+def _write_latent_rows(cache, new, index, axis):
+    """``_write_rows`` for a latent layer's cache, which has no heads:
+    ``cache`` (B, S, D) with ``new`` (B, s, D) written at positions
+    ``index[r] ..`` of each row ``r`` along ``axis`` 1, or (B, D, S) with
+    (B, D, s) along ``axis`` 2.  The rows are written into the cache seen
+    as two dimensions, (B S, D) or (B D, S), one ``dynamic_update_slice``
+    a row.  Seen as three the TPU compiler moves the whole cache into an
+    order with the rows next to the channels for the writes and back for
+    the attention, two copies of the cache a layer a tick; two dimensions
+    leave it one order (``tests/serve/test_decode_in_place.py``)."""
+    b, seq_len, s = cache.shape[0], cache.shape[axis], new.shape[axis]
+    fits = (index >= 0) & (index <= seq_len - s)
+    start = jnp.clip(index, 0, seq_len - s)
+    flat = cache.reshape(b * cache.shape[1], cache.shape[2])
+    for r in range(b):
+        at = (r * seq_len + start[r], 0) if axis == 1 else \
+            (r * cache.shape[1], start[r])
+        old = jax.lax.dynamic_slice(flat, at, new.shape[1:])
+        flat = jax.lax.dynamic_update_slice(
+            flat, jnp.where(fits[r], new[r], old), at)
+    return flat.reshape(cache.shape)
+
+
+def update_latent_cache(kv_cache, c, k_pe):
+    """``update_kv_cache`` for a "latent" layer: ``kv_cache`` is ``(c_cache
+    (B, S, kv_lora_rank), pe_cache (B, qk_rope_head_dim, S), index)``, the
+    normed latents and the rotated shared keys of the positions so far,
+    ``index`` a scalar or (B,) a row.  The keys lie with the positions
+    last: 64 channels last would be padded to 128 on the chip and re-laid
+    out for every product, and so they are what the scores' product takes
+    as it is.  The ``s`` new positions ``c`` (B, s, r) and ``k_pe`` (B, s,
+    dr) are written at ``index`` (a row whose write does not fit stays as
+    it was: ``_write_latent_rows``); returns the cache entry with ``index +
+    s``.  Nothing is zeroed: the attention masks by position."""
+    c_cache, pe_cache, index = kv_cache
+    index = jnp.asarray(index, jnp.int32)
+    c = c.astype(c_cache.dtype)
+    k_pe = k_pe.astype(pe_cache.dtype).swapaxes(1, 2)
+    if index.ndim == 0:
+        c_cache = jax.lax.dynamic_update_slice_in_dim(c_cache, c, index,
+                                                      axis=1)
+        pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, k_pe,
+                                                       index, axis=2)
+    else:
+        c_cache = _write_latent_rows(c_cache, c, index, 1)
+        pe_cache = _write_latent_rows(pe_cache, k_pe, index, 2)
+    return c_cache, pe_cache, index + c.shape[1]
+
+
+def _einsum_f32(spec, a, b):
+    """``einsum`` with a float32 result.  Operands in a lower precision are
+    multiplied as they are and accumulated in float32 where the platform
+    has such a product (the TPU's matrix unit); the CPU's runtime has none
+    for bfloat16, and converts them first."""
+    if a.dtype == jnp.float32:
+        return jnp.einsum(spec, a, b)
+    return jax.lax.platform_dependent(
+        a, b,
+        tpu=lambda a, b: jnp.einsum(spec, a, b,
+                                    preferred_element_type=jnp.float32),
+        default=lambda a, b: jnp.einsum(spec, a.astype(jnp.float32),
+                                        b.astype(jnp.float32)))
+
+
+def _query_positions(offset, b, s):
+    """(1 or B, s) int32: the positions of ``s`` queries a row that start
+    at ``offset`` (a scalar, or (B,) a row)."""
+    offset = jnp.asarray(offset, jnp.int32)
+    steps = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+    return steps + (offset[:, None] if offset.ndim else offset)
+
+
+def latent_attention_expanded(q_nope, q_pe, c, k_pe, w_kv_b, scale,
+                              offset=None):
+    """Latent attention in its published form (``_latent_attention_blocks``
+    says what that is, and is it, in ``jax.numpy``).  Over a cache
+    (``offset`` given) whose shapes the Pallas kernel takes
+    (``ops/latent_attention.py``: whole lanes and whole key blocks, the
+    published sizes) a program lowered for a TPU runs the kernel, in which
+    a key block's scores never leave fast memory; the choice is made at
+    lowering time from the platform compiled for."""
+    from alpa_tpu.ops import latent_attention as kernel
+    if offset is None or not kernel.fits(q_nope, c, w_kv_b):
+        return _latent_attention_blocks(q_nope, q_pe, c, k_pe, w_kv_b,
+                                        offset, scale=scale)
+    offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32),
+                              (q_nope.shape[0],))
+    return jax.lax.platform_dependent(
+        q_nope, q_pe, c, k_pe, w_kv_b, offset,
+        tpu=partial(kernel.expanded, scale=scale),
+        default=partial(_latent_attention_blocks, scale=scale))
+
+
+def _latent_attention_blocks(q_nope, q_pe, c, k_pe, w_kv_b, offset=None, *,
+                             scale):
+    """Latent attention in its published form, over key blocks: the keys'
+    ``k_nope`` and the values of a block of positions are EXPANDED from
+    their latents ``c`` (B, Sk, r) by ``w_kv_b`` (r, H, dn + dv) inside
+    the loop, scored against ``q_nope`` (B, Sq, H, dn) (and the block's
+    shared rotated keys ``k_pe`` (B, dr, Sk) against ``q_pe`` (B, Sq, H,
+    dr)), and folded into a running maximum, sum and weighted values
+    (float32), so that only one block's per-head keys, values and scores
+    exist at a time: ``H x Sq x block`` scores, the block ``min(Sq, Sk)``
+    positions.  Causal: query i of a row sits at ``offset + i`` (a scalar,
+    or (B,) a row) and sees the keys at or before it.  ``offset`` None:
+    the keys ARE the queries' own positions (no cache).  With a cache the
+    loop ends at the block of the last query, so positions no row holds
+    yet are not read.  Returns (B, Sq, H, dv) in the queries' dtype."""
+    b, sq, nh, dn = q_nope.shape
+    sk = c.shape[1]
+    block = min(sq, sk)
+    q_pos = _query_positions(0 if offset is None else offset, b, sq)
+    if offset is None:
+        n_blocks = -(-sk // block)
+    else:
+        n_blocks = jnp.minimum(jnp.max(q_pos) // block + 1,
+                               -(-sk // block))
+    steps = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block), 2)
+
+    def one_block(j, carry):
+        top, total, acc = carry
+        # the last block of a cache that is no multiple of it overlaps
+        # the one before: its first positions are then masked
+        start = jnp.minimum(j * block, sk - block)
+        kv = jnp.einsum(
+            "bkr,rhd->bkhd",
+            jax.lax.dynamic_slice_in_dim(c, start, block, axis=1), w_kv_b)
+        scores = scale * (
+            _einsum_f32("bqhd,bkhd->bhqk", q_nope, kv[..., :dn]) +
+            _einsum_f32("bqhd,bdk->bhqk", q_pe,
+                        jax.lax.dynamic_slice_in_dim(k_pe, start, block,
+                                                     axis=2)))
+        k_pos = start + steps
+        seen = ((k_pos <= q_pos[:, :, None]) &
+                (k_pos >= j * block))[:, None]               # (.,1,Sq,blk)
+        top_new = jnp.maximum(
+            top, jnp.where(seen, scores, -jnp.inf).max(-1))
+        probs = jnp.where(seen, jnp.exp(scores - top_new[..., None]), 0.0)
+        keep = jnp.exp(top - top_new)
+        total = total * keep + probs.sum(-1)
+        acc = acc * keep.transpose(0, 2, 1)[..., None] + _einsum_f32(
+            "bhqk,bkhd->bqhd", probs.astype(q_nope.dtype), kv[..., dn:])
+        return top_new, total, acc
+
+    dv = w_kv_b.shape[-1] - dn
+    # a finite floor: a block that a query sees nothing of leaves its
+    # maximum there, and exp(floor - floor) is 1 and not NaN
+    init = (jnp.full((b, nh, sq), -1e30, jnp.float32),
+            jnp.zeros((b, nh, sq), jnp.float32),
+            jnp.zeros((b, sq, nh, dv), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(0, n_blocks, one_block, init)
+    total = jnp.maximum(total, 1e-30).transpose(0, 2, 1)[..., None]
+    return (acc / total).astype(q_nope.dtype)
+
+
+def latent_attention_absorbed(q_nope, q_pe, c, k_pe, w_kv_b, scale, offset):
+    """The same function with the expansion ABSORBED into the query and
+    the output (``w_kv_b`` a head split into ``W_uk`` (r, dn) and ``W_uv``
+    (r, dv)): ``q_lat = q_nope W_uk^T`` scores against the latents
+    themselves, the probabilities weigh the latents, and ``W_uv`` expands
+    what comes out: the cache is read as it lies, for all heads at once,
+    and no per-head key or value of any cached position exists.  What a
+    decode wants (few queries, a long cache); for many queries the ``H x
+    r`` wide products cost more than expanding does.
+
+    One query a row over a cache whose shapes the Pallas kernel takes
+    (``ops/latent_attention.py`` ``absorbed``): a program lowered for a
+    TPU runs the kernel, which reads of each row's cache the positions
+    the row holds; ``_absorbed_core`` is its ``jax.numpy`` twin, over every
+    row's whole cache."""
+    from alpa_tpu.ops import latent_attention as kernel
+    dn = q_nope.shape[-1]
+    q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kv_b[..., :dn])
+    offset = jnp.asarray(offset, jnp.int32)
+    if kernel.absorbed_fits(q_lat, c) and offset.ndim == 1:
+        o_lat = jax.lax.platform_dependent(
+            q_lat, q_pe, c, k_pe, offset,
+            tpu=partial(kernel.absorbed, scale=scale),
+            default=partial(_absorbed_core, scale=scale))
+    else:
+        o_lat = _absorbed_core(q_lat, q_pe, c, k_pe, offset, scale=scale)
+    return jnp.einsum("bqhr,rhd->bqhd", o_lat, w_kv_b[..., dn:])
+
+
+def _absorbed_core(q_lat, q_pe, c, k_pe, offset, *, scale):
+    """Scores of ``[q_lat | q_pe]`` against ``[c | k_pe]``, a float32
+    softmax over the positions at or before each query's, and the
+    probabilities' weighted latents (B, Sq, H, r)."""
+    b, sq = q_lat.shape[:2]
+    scores = scale * (_einsum_f32("bqhr,bkr->bhqk", q_lat, c) +
+                      _einsum_f32("bqhd,bdk->bhqk", q_pe, k_pe))
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c.shape[1]), 2)
+    seen = k_pos <= _query_positions(offset, b, sq)[:, :, None]
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None], scores, jnp.float32(-1e9)), axis=-1)
+    return jnp.einsum("bhqk,bkr->bqhr", probs.astype(c.dtype), c)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, 2024), the ``attention``
+    kind "latent" (``GPTConfig``): ``c_q = norm(x Wq_a)``, ``q = c_q
+    Wq_b`` a head ``[q_nope | q_pe]``; ``[c | k_pe] = x Wkv_a``, ``c =
+    norm(c)``; rotary positions on ``q_pe`` and on ``k_pe``, ONE key for
+    all heads; ``[k_nope | v] = c Wkv_b`` a head; scores ``(q_nope .
+    k_nope + q_pe . k_pe) * attn_scale``; the heads' values (``v_head_dim``
+    each) through ``out``.
+
+    The cache is ``(c, k_pe with the positions last, index)``
+    (``update_latent_cache``).  Over it
+    one new position a row (a decode) takes ``latent_attention_absorbed``,
+    several (a prefill's chunk) ``latent_attention_expanded``: the choice
+    is the static number of new positions, nothing else."""
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, deterministic=True,
+                 attn_bias=None, position_ids=None, cache_lengths=None):
+        cfg = self.config
+        if attn_bias is not None or not cfg.causal or position_ids is None:
+            raise ValueError("latent attention is causal over rotary "
+                             "positions and takes no score bias (packed "
+                             "sequences, padding masks)")
+        nh, rank = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias,
+                        param_dtype=cfg.param_dtype)
+        b, s = x.shape[0], x.shape[1]
+        c_q = x
+        if cfg.q_lora_rank:
+            c_q = make_norm(cfg, "q_a_norm")(
+                dense(cfg.q_lora_rank, name="q_a")(x)).astype(cfg.dtype)
+        q = dense(nh * (dn + dr), name="q_b")(c_q).reshape(b, s, nh, dn + dr)
+        kv_a = dense(rank + dr, name="kv_a")(x)
+        c = make_norm(cfg, "kv_a_norm")(kv_a[..., :rank]).astype(cfg.dtype)
+        w_kv_b = self.param(
+            "kv_b", nn.initializers.lecun_normal(), (rank, nh * (dn + dv)),
+            cfg.param_dtype).astype(cfg.dtype).reshape(rank, nh, dn + dv)
+        rotate = partial(apply_rotary, position_ids=position_ids,
+                         theta=cfg.rope_theta,
+                         interleaved=cfg.rope_interleaved,
+                         yarn=cfg.rope_yarn)
+        q_nope, q_pe = q[..., :dn], rotate(q[..., dn:])
+        k_pe = rotate(kv_a[:, :, None, rank:])[:, :, 0]
+        scale = cfg.attn_scale or (dn + dr) ** -0.5
+
+        new_cache = None
+        # the scope of the attention core (the cache's write, the
+        # expansion or the absorption, scores, softmax, values; not the
+        # low-rank projections)
+        with jax.named_scope(ATTENTION_SCOPE):
+            if kv_cache is None:
+                out = latent_attention_expanded(
+                    q_nope, q_pe, c, k_pe.swapaxes(1, 2), w_kv_b, scale)
+            else:
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                new_cache = update_latent_cache(kv_cache, c, k_pe)
+                core = latent_attention_absorbed if s == 1 else \
+                    latent_attention_expanded
+                out = core(q_nope, q_pe, new_cache[0], new_cache[1], w_kv_b,
+                           scale, index)
+        return dense(cfg.hidden_size, name="out")(
+            out.reshape(b, s, nh * dv)), new_cache
+
+
 class SelfAttention(nn.Module):
     """``attention`` is the layer's kind (``GPTConfig.attention``; None:
     the configuration's, which must then be one kind for all layers)."""
@@ -645,10 +1041,12 @@ class TransformerBlock(nn.Module):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
-        attn_out, new_cache = SelfAttention(
-            cfg, attention=self.attention, name="attn")(
-                ln1, kv_cache, deterministic, attn_bias, position_ids,
-                cache_lengths)
+        if (self.attention or cfg.attention_kind(0)) == "latent":
+            attn = LatentAttention(cfg, name="attn")
+        else:
+            attn = SelfAttention(cfg, attention=self.attention, name="attn")
+        attn_out, new_cache = attn(ln1, kv_cache, deterministic, attn_bias,
+                                   position_ids, cache_lengths)
         if cfg.post_norms:
             attn_out = make_norm(cfg, "ln1_post")(attn_out)
         x = x + attn_out.astype(x.dtype)
@@ -796,9 +1194,11 @@ class GPTModel(nn.Module):
 def kv_cache_shapes(config, batch_size: int) -> list:
     """The (B, positions, key/value heads, head size) of every layer's K
     and V cache: ``seq_len`` positions in a "full" layer, a ring of
-    ``sliding_window`` (at most ``seq_len``) in a "sliding" one.  Takes
-    any decoder family's configuration: what ``GPTConfig`` alone has reads
-    as its default."""
+    ``sliding_window`` (at most ``seq_len``) in a "sliding" one.  A
+    "latent" layer's two arrays differ and have no heads: its entry is the
+    pair ((B, seq_len, kv_lora_rank), (B, qk_rope_head_dim, seq_len)).
+    Takes any decoder family's configuration: what ``GPTConfig`` alone has
+    reads as its default."""
     heads = getattr(config, "num_kv_heads", None) or config.num_heads
     hd = getattr(config, "head_dim", None) or \
         config.hidden_size // config.num_heads
@@ -806,10 +1206,21 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     shapes = []
     for i in range(config.num_layers):
         kind = kinds if isinstance(kinds, str) else kinds[i]
+        if kind == "latent":
+            shapes.append((
+                (batch_size, config.seq_len, config.kv_lora_rank),
+                (batch_size, config.qk_rope_head_dim, config.seq_len)))
+            continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
         shapes.append((batch_size, length, heads, hd))
     return shapes
+
+
+def latent_kv_caches(config) -> bool:
+    """Whether any layer's cache is a latent one (no per-head K and V)."""
+    kinds = getattr(config, "attention", "full")
+    return "latent" in ((kinds,) if isinstance(kinds, str) else kinds)
 
 
 def uniform_kv_caches(config) -> bool:
@@ -820,6 +1231,15 @@ def uniform_kv_caches(config) -> bool:
 
 
 def require_uniform_kv_caches(config, what: str):
+    """Raise unless every layer caches per-head K and V of one shape, as
+    ``what`` indexes them."""
+    if latent_kv_caches(config):
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape, and "
+            "this configuration's layers hold a latent cache (latent "
+            "attention: a latent and a shared rotary key a position, in "
+            "two arrays of unlike shapes, no heads): "
+            f"{sorted(set(kv_cache_shapes(config, 1)))}")
     if not uniform_kv_caches(config):
         raise ValueError(
             f"{what} indexes one cache shape for all layers, and this "
@@ -832,10 +1252,15 @@ def init_kv_caches(config: GPTConfig, batch_size: int,
                    dtype=None) -> list:
     """KV caches as explicit arrays (ref opt_model.py:605 init_cache_aval):
     ``[(k, v, index)]`` a layer, each layer's of its own shape
-    (``kv_cache_shapes``)."""
+    (``kv_cache_shapes``); a "latent" layer's ``(c, k_pe, index)``."""
     dtype = dtype or config.dtype
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), jnp.int32(0))
-            for shape in kv_cache_shapes(config, batch_size)]
+    caches = []
+    for shape in kv_cache_shapes(config, batch_size):
+        k_shape, v_shape = shape if isinstance(shape[0], tuple) else \
+            (shape, shape)
+        caches.append((jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype),
+                       jnp.int32(0)))
+    return caches
 
 
 def init_gpt(config: GPTConfig, batch_size: int, rngkey=None):

@@ -47,7 +47,8 @@ SCOPE = "moe"
 
 
 def topk_routing(router_logits, k: int, norm_topk_prob: bool = False,
-                 score: str = "softmax", bias=None, scale: float = 1.0):
+                 score: str = "softmax", bias=None, scale: float = 1.0,
+                 n_group: int = 1, topk_group: int = 1):
     """The experts' scores in float32, then the k largest: (T, E) logits ->
     (weights (T, k) float32, experts (T, k) int32, scores (T, E)).
 
@@ -56,7 +57,13 @@ def topk_routing(router_logits, k: int, norm_topk_prob: bool = False,
     ``bias`` ((E,), None: none) is added to the scores for the CHOICE of
     the k experts only: the weights are the chosen experts' scores
     themselves, divided by their sum (+ 1e-20) where ``norm_topk_prob``
-    (OLMoE's published configuration leaves it off), times ``scale``."""
+    (OLMoE's published configuration leaves it off), times ``scale``.
+
+    ``n_group`` > 1 (DeepSeek-V2's ``group_limited_greedy``, its
+    device-limited routing): the experts come in ``n_group`` groups of
+    consecutive experts, a group scores as its best expert does, and the k
+    experts are chosen among those of the ``topk_group`` best groups (the
+    others' scores count as 0 in the choice)."""
     logits = router_logits.astype(jnp.float32)
     if score == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
@@ -64,7 +71,16 @@ def topk_routing(router_logits, k: int, norm_topk_prob: bool = False,
         scores = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"unknown router score {score!r}")
-    if bias is None:
+    if n_group > 1:
+        if bias is not None:
+            raise ValueError("group-limited routing takes no bias")
+        grouped = scores.reshape(scores.shape[:-1] + (n_group, -1))
+        _, groups = jax.lax.top_k(grouped.max(-1), topk_group)
+        chosen = (groups[..., None] == jnp.arange(n_group)).any(-2)
+        weights, experts = jax.lax.top_k(
+            jnp.where(chosen[..., None], grouped, 0.0).reshape(scores.shape),
+            k)
+    elif bias is None:
         weights, experts = jax.lax.top_k(scores, k)
     else:
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
@@ -151,8 +167,19 @@ class DroplessExperts(nn.Module):
     experts are one gated MLP each, applied to every token and added to
     the routed sum.
 
+    ``experts_held`` (first, count): the layer's experts are divided over
+    several chips and this program holds ``count`` of them from ``first``
+    on (expert parallelism's share, without its exchange).  The router is
+    still ``num_experts`` wide and chooses among all of them; the
+    parameters are the held experts' alone; the rows routed to an absent
+    expert are sorted behind the held groups, which the grouped matmul
+    does not walk, and take no part: ``y`` is the held experts' part of
+    the routed sum (and the shared experts, which every chip computes
+    alike for its own tokens).
+
     Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32,
-    ``prob_sums`` (E,) float32 and ``experts`` (T, k) int32."""
+    ``prob_sums`` (E,) float32 and ``experts`` (T, k) int32, of all the
+    ``num_experts`` whichever are held."""
     config: Any
 
     @nn.compact
@@ -162,18 +189,23 @@ class DroplessExperts(nn.Module):
         cfg = self.config
         e, k, width = (cfg.num_experts, cfg.num_experts_per_tok,
                        cfg.expert_width)
+        held = getattr(cfg, "experts_held", None)
+        # the experts whose parameters are here
+        mine = e if held is None else held[1]
         h = x.shape[-1]
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         if cfg.fused_gate_up:
             # [gate | up] of every expert, side by side as the grouped
             # matmul takes them
-            w_gate_up = self.param("w_gate_up", init, (e, h, 2 * width),
+            w_gate_up = self.param("w_gate_up", init, (mine, h, 2 * width),
                                    cfg.param_dtype)
         else:
-            w_gate = self.param("w_gate", init, (e, h, width),
+            w_gate = self.param("w_gate", init, (mine, h, width),
                                 cfg.param_dtype)
-            w_up = self.param("w_up", init, (e, h, width), cfg.param_dtype)
-        w_down = self.param("w_down", init, (e, width, h), cfg.param_dtype)
+            w_up = self.param("w_up", init, (mine, h, width),
+                              cfg.param_dtype)
+        w_down = self.param("w_down", init, (mine, width, h),
+                            cfg.param_dtype)
         bias = self.param("router_bias", nn.initializers.zeros, (e,),
                           jnp.float32) if cfg.router_bias else None
         tokens = x.reshape(-1, h)
@@ -185,24 +217,38 @@ class DroplessExperts(nn.Module):
             weights, experts, probs = topk_routing(
                 logits, k, cfg.norm_topk_prob, cfg.router_score,
                 None if bias is None else jax.lax.stop_gradient(bias),
-                cfg.route_scale)
+                cfg.route_scale, getattr(cfg, "n_group", 1),
+                getattr(cfg, "topk_group", 1))
             flat = experts.reshape(-1)
+            slot = flat
+            if held is not None:
+                # the held experts' rows first, by expert; the absent
+                # experts' rows behind them, where no group reaches
+                here = (flat >= held[0]) & (flat < held[0] + mine)
+                slot = jnp.where(here, flat - held[0], mine)
             # stable: an expert's rows stay in token order
-            order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+            order = jnp.argsort(slot, stable=True).astype(jnp.int32)
             inverse = jnp.argsort(order).astype(jnp.int32)
             counts = (flat[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
                 0, dtype=jnp.int32)
+            group_sizes = counts if held is None else \
+                counts[held[0]:held[0] + mine]
             rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
                                     inverse, k)
             # gate and up in one pass over the rows
             if not cfg.fused_gate_up:
                 w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1)
-            gate_up = grouped_matmul(rows, w_gate_up, counts)
+            gate_up = grouped_matmul(rows, w_gate_up, group_sizes)
             act = activation_fn(cfg.activation)
             hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
                       gate_up[:, width:].astype(jnp.float32))
             out_rows = grouped_matmul(hidden.astype(cfg.dtype), w_down,
-                                      counts)
+                                      group_sizes)
+            if held is not None:
+                # what lies behind the groups was not multiplied, and is
+                # whatever the kernel's output buffer held
+                walked = jnp.arange(out_rows.shape[0]) < group_sizes.sum()
+                out_rows = jnp.where(walked[:, None], out_rows, 0)
             by_token = _permute_rows(out_rows, inverse, order).reshape(
                 tokens.shape[0], k, h)
             y = (by_token.astype(jnp.float32) * weights[..., None]).sum(1)

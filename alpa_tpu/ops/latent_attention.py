@@ -1,0 +1,264 @@
+"""Latent attention (MLA) over a latent cache: the expanded core of a
+prefill's chunk and the absorbed core of a decode, one Pallas kernel each.
+
+**Expanded** (``expanded``).
+
+What a prefill's chunk needs of ``model/gpt_model.py`` ``LatentAttention``:
+many new queries against every cached position so far, in the published
+(expanded) form.  The cache holds a position's normed latent ``c`` and its
+shared rotated key ``k_pe``; a head's keys and values are ``c W_kv_b``.
+Written in ``jax.numpy`` over key blocks (``gpt_model.
+_latent_attention_blocks``, which this equals) a block's ``heads x queries x
+keys`` float32 scores go through the chip's memory three times (the row
+maximum, the exponentials, their sum and product with the values): 4.3 ms a
+layer a block of 1,024 keys at DeepSeek-V2's 128 heads, of which the matrix
+unit works 0.6 ms (PERF.md, PR 32).  Here they never leave the kernel's
+fast memory:
+
+Grid ``(rows, heads, key blocks)``.  One program holds ONE head's queries
+(all ``Sq`` of them, ``q_nope`` and ``q_pe``) and that head's slice of
+``W_kv_b``; the key-block axis is the innermost, sequential one, and the
+running maximum, sum and weighted values of the online softmax live in
+scratch across it (the structure of ``ops/flash_attention.py``'s streaming
+kernel).  A step fetches one block of latents ``(block_k, r)`` and of shared
+keys ``(dr, block_k)``, EXPANDS the block for its head in the kernel (``c
+W``: ``block_k x (dn + dv)``, no per-head key or value ever in HBM), scores
+``q_nope k_nope^T + q_pe k_pe``, masks by position and folds the block in.
+
+How far a row's queries see is data (a chunk's start): ``blocks[b]``, the
+key blocks row ``b`` needs, and ``offset[b]``, the position of its first
+query, are prefetched scalars.  Steps past ``blocks[b]`` compute nothing
+and, their block index clamped to the last needed one, fetch nothing.
+
+**Absorbed** (``absorbed``).  What a decode needs: ONE new query a row
+against the row's cache, the expansion absorbed into the query
+(``gpt_model.latent_attention_absorbed``).  In ``jax.numpy`` it is two
+products over every row's whole cache with a float32 softmax between them:
+each reads the latents of all ``Sk`` positions whatever the rows hold, and
+the ``rows x heads x Sk`` scores go through memory (18 ms of a 26.5 ms
+tick at 32 rows of 16,384 positions, PERF.md, PR 32).  Here the grid is
+``(rows, key blocks)``: a program holds one row's ``heads x (r + dr)``
+query, a step fetches a block of the row's latents and shared keys ONCE
+for all heads, scores, and folds ``probabilities x latents`` into the
+running ``heads x r`` output; steps past the row's newest position fetch
+and compute nothing, so a tick reads what its rows hold and no more.  At
+128 heads a step's operations and bytes balance on a v5e (242 a byte).
+
+The kernels are compiled where the program is lowered for a TPU
+(``gpt_model`` chooses between each and its ``jax.numpy`` twin with
+``lax.platform_dependent``); ``interpret=True`` runs them anywhere, for the
+tests.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# keys a step of the expanded kernel: with 1,024 queries a step's float32
+# scores are 2 MB
+BLOCK_K = 512
+# and of the absorbed one, whose step is a microsecond of work: 1 MB of
+# latents, so that a step's fixed cost stays a small part of it
+DECODE_BLOCK_K = 1024
+# of the chip's 128 MiB of fast memory, what one program may take: the
+# queries, the scores and their exponentials, two blocks in flight
+VMEM_LIMIT = 48 * 2**20
+_FLOOR = -1e30
+
+
+def fits(q_nope, c, w_kv_b) -> bool:
+    """Whether the kernel takes these shapes: the heads' channels and the
+    latent's in whole lanes of 128, the queries in whole sublanes, the
+    cache in whole key blocks."""
+    sq, dn = q_nope.shape[1], q_nope.shape[3]
+    rank, dv = w_kv_b.shape[0], w_kv_b.shape[2] - dn
+    return (dn % 128 == 0 and dv % 128 == 0 and rank % 128 == 0 and
+            sq % 16 == 0 and c.shape[1] % BLOCK_K == 0)
+
+
+def _start(m_ref, l_ref, acc_ref):
+    m_ref[:] = jnp.full_like(m_ref, _FLOOR)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _fold_in(s, seen, values, m_ref, l_ref, acc_ref):
+    """One block of the online softmax: scores ``s`` (queries, keys)
+    float32 of which ``seen`` count, and the keys' ``values`` (keys, d),
+    into the running maximum, sum and weighted values."""
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(
+        m_prev, jnp.max(jnp.where(seen, s, _FLOOR), axis=1, keepdims=True))
+    p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+    keep = jnp.exp(m_prev - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_ref[:] * keep + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * keep + jnp.dot(
+        p.astype(values.dtype), values, preferred_element_type=jnp.float32)
+
+
+def _finish(o_ref, l_ref, acc_ref):
+    o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(
+        o_ref.dtype)
+
+
+def _block_of(b, kb, blocks_ref):
+    """The key block step ``kb`` of row ``b`` fetches: its own while the
+    row needs it, the last needed one after (the same again: no fetch)."""
+    return jnp.minimum(kb, blocks_ref[b] - 1)
+
+
+def _kernel(blocks_ref, offset_ref, qn_ref, qp_ref, c_ref, kpe_ref, w_ref,
+            o_ref, m_ref, l_ref, acc_ref, *, scale: float, dn: int):
+    b, kb = pl.program_id(0), pl.program_id(2)
+    sq, block_k = qn_ref.shape[0], c_ref.shape[0]
+    pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        # this head's keys and values of the block, from its latents
+        kv = jnp.dot(c_ref[:], w_ref[:],
+                     preferred_element_type=jnp.float32).astype(c_ref.dtype)
+        s = scale * (
+            lax.dot_general(qn_ref[:], kv[:, :dn], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) +
+            jnp.dot(qp_ref[:], kpe_ref[:],
+                    preferred_element_type=jnp.float32))
+        q_pos = offset_ref[b] + lax.broadcasted_iota(
+            jnp.int32, (sq, block_k), 0)
+        k_pos = kb * block_k + lax.broadcasted_iota(
+            jnp.int32, (sq, block_k), 1)
+        _fold_in(s, k_pos <= q_pos, kv[:, dn:], m_ref, l_ref, acc_ref)
+
+    pl.when(kb == pl.num_programs(2) - 1)(
+        lambda: _finish(o_ref, l_ref, acc_ref))
+
+
+def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
+             interpret: bool = False):
+    """``q_nope`` (B, Sq, H, dn), ``q_pe`` (B, Sq, H, dr) against the cache
+    ``c`` (B, Sk, r), ``k_pe`` (B, dr, Sk) through ``w_kv_b`` (r, H, dn +
+    dv); row ``b``'s query i sits at ``offset[b] + i`` ((B,) int32) and
+    sees the keys at or before it.  Returns (B, Sq, H, dv) in the queries'
+    dtype."""
+    b, sq, nh, dn = q_nope.shape
+    dr, sk = k_pe.shape[1], c.shape[1]
+    rank, dv = w_kv_b.shape[0], w_kv_b.shape[2] - dn
+    nk = sk // BLOCK_K
+    offset = offset.astype(jnp.int32)
+    # the key blocks a row's last query reaches into
+    blocks = jnp.clip((offset + sq - 1) // BLOCK_K + 1, 1, nk)
+
+    def per_head(b_, h, kb, blocks_ref, offset_ref):
+        return b_, h, 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, dn=dn),
+        out_shape=jax.ShapeDtypeStruct((b, nh, sq, dv), q_nope.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nh, nk),
+            in_specs=[
+                pl.BlockSpec((None, None, sq, dn), per_head),
+                pl.BlockSpec((None, None, sq, dr), per_head),
+                pl.BlockSpec(
+                    (None, BLOCK_K, rank),
+                    lambda b_, h, kb, blocks_ref, offset_ref:
+                    (b_, _block_of(b_, kb, blocks_ref), 0)),
+                pl.BlockSpec(
+                    (None, dr, BLOCK_K),
+                    lambda b_, h, kb, blocks_ref, offset_ref:
+                    (b_, 0, _block_of(b_, kb, blocks_ref))),
+                pl.BlockSpec(
+                    (None, rank, dn + dv),
+                    lambda b_, h, kb, blocks_ref, offset_ref: (h, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, None, sq, dv), per_head),
+            scratch_shapes=[pltpu.VMEM((sq, 1), jnp.float32),
+                            pltpu.VMEM((sq, 1), jnp.float32),
+                            pltpu.VMEM((sq, dv), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(blocks, offset, q_nope.transpose(0, 2, 1, 3),
+      q_pe.transpose(0, 2, 1, 3), c, k_pe, w_kv_b.transpose(1, 0, 2))
+    return out.transpose(0, 2, 1, 3)
+
+
+def absorbed_fits(q_lat, c) -> bool:
+    """Whether the absorbed kernel takes these shapes: the latent in whole
+    lanes, the heads in whole sublanes, the cache in whole key blocks."""
+    return (q_lat.shape[1] == 1 and q_lat.shape[3] % 128 == 0 and
+            q_lat.shape[2] % 16 == 0 and c.shape[1] % DECODE_BLOCK_K == 0)
+
+
+def _absorbed_kernel(blocks_ref, index_ref, ql_ref, qp_ref, c_ref, kpe_ref,
+                     o_ref, m_ref, l_ref, acc_ref, *, scale: float):
+    b, kb = pl.program_id(0), pl.program_id(1)
+    nh, block_k = ql_ref.shape[0], c_ref.shape[0]
+    pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        latents = c_ref[:]
+        s = scale * (
+            lax.dot_general(ql_ref[:], latents, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) +
+            jnp.dot(qp_ref[:], kpe_ref[:],
+                    preferred_element_type=jnp.float32))
+        k_pos = kb * block_k + lax.broadcasted_iota(
+            jnp.int32, (nh, block_k), 1)
+        _fold_in(s, k_pos <= index_ref[b], latents, m_ref, l_ref, acc_ref)
+
+    pl.when(kb == pl.num_programs(1) - 1)(
+        lambda: _finish(o_ref, l_ref, acc_ref))
+
+
+def absorbed(q_lat, q_pe, c, k_pe, index, *, scale: float,
+             interpret: bool = False):
+    """``q_lat`` (B, 1, H, r) (a row's one query a head, already through
+    ``W_uk``) and ``q_pe`` (B, 1, H, dr) against the cache ``c`` (B, Sk,
+    r), ``k_pe`` (B, dr, Sk); row ``b``'s query sits at ``index[b]`` ((B,)
+    int32) and sees the positions up to it.  Returns the probabilities'
+    weighted latents (B, 1, H, r), for ``W_uv`` to expand."""
+    b, _, nh, rank = q_lat.shape
+    dr, sk = k_pe.shape[1], c.shape[1]
+    nk = sk // DECODE_BLOCK_K
+    index = index.astype(jnp.int32)
+    blocks = jnp.clip(index // DECODE_BLOCK_K + 1, 1, nk)
+
+    def per_row(b_, kb, blocks_ref, index_ref):
+        return b_, 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_absorbed_kernel, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, nh, rank), q_lat.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nk),
+            in_specs=[
+                pl.BlockSpec((None, nh, rank), per_row),
+                pl.BlockSpec((None, nh, dr), per_row),
+                pl.BlockSpec(
+                    (None, DECODE_BLOCK_K, rank),
+                    lambda b_, kb, blocks_ref, index_ref:
+                    (b_, _block_of(b_, kb, blocks_ref), 0)),
+                pl.BlockSpec(
+                    (None, dr, DECODE_BLOCK_K),
+                    lambda b_, kb, blocks_ref, index_ref:
+                    (b_, 0, _block_of(b_, kb, blocks_ref))),
+            ],
+            out_specs=pl.BlockSpec((None, nh, rank), per_row),
+            scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
+                            pltpu.VMEM((nh, 1), jnp.float32),
+                            pltpu.VMEM((nh, rank), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(blocks, index, q_lat[:, 0], q_pe[:, 0], c, k_pe)
+    return out[:, None]

@@ -61,12 +61,27 @@ _EXPERTS_TOUCHED = _REG.counter(
     "alpa_moe_experts_touched_total",
     "Distinct experts the decode ticks' routed layers touched, summed "
     "over the layers and the ticks (over alpa_serving_decode_steps_total "
-    "and the expert layers: experts a layer a tick)")
+    "and the expert layers: experts a layer a tick); where the program "
+    "holds a share of a layer's experts, those among them")
+_ROUTED_ROWS = _REG.counter(
+    "alpa_moe_routed_rows_total", "token-expert rows the experts computed")
+_LOCAL_ROWS = _REG.counter(
+    "alpa_moe_local_rows_total",
+    "token-expert rows the decode ticks routed to an expert this program "
+    "holds (GPTConfig.experts_held; over alpa_moe_routed_rows_total of the "
+    "same ticks: the share of the routed work that lands here)")
+_DECODE_POSITIONS = _REG.counter(
+    "alpa_serving_decode_positions_total",
+    "Cache positions the decode ticks' active rows attended over (a row's "
+    "prompt and the tokens it has so far, the new one included), summed "
+    "over the rows and the ticks: what an attention that reads of a cache "
+    "what its row holds has to read, in positions")
 _KV_CACHE_BYTES = _REG.gauge(
     "alpa_serving_kv_cache_bytes",
     "Bytes of the engine's resident K and V caches, by the kind of the "
-    "layers' cache: window (a ring of the sliding window's positions) or "
-    "full (the served context)", labelnames=("kind",))
+    "layers' cache: window (a ring of the sliding window's positions), "
+    "full (the served context) or latent (the served context of a latent "
+    "layer's normed latent and shared rotary key)", labelnames=("kind",))
 
 # every engine span: category "serving", on this track (the queue waits,
 # which overlap each other, on their own)
@@ -405,10 +420,12 @@ class ContinuousBatchingEngine:
         cfgm = self.gen.config
         self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
                         for (k, v, _i) in init_kv_caches(cfgm, self.B)]
-        by_kind = {"window": 0, "full": 0}
+        by_kind = {"window": 0, "full": 0, "latent": 0}
         for k, v, _i in self._caches:
-            by_kind["full" if k.shape[1] == cfgm.seq_len
-                    else "window"] += k.nbytes + v.nbytes
+            # a latent layer's arrays have no heads
+            kind = "latent" if k.ndim == 3 else \
+                "full" if k.shape[1] == cfgm.seq_len else "window"
+            by_kind[kind] += k.nbytes + v.nbytes
         for kind, nbytes in by_kind.items():
             _KV_CACHE_BYTES.labels(kind).set(nbytes)
         # what the last decode said of its routed layers ({}: no decode
@@ -825,7 +842,14 @@ class ContinuousBatchingEngine:
             # sampling began, so nothing more is waited for
             nxt, routing = jax.device_get((tokens, routing))
             nxt = nxt[:, 0]
+            held = getattr(self.gen.config, "experts_held", None)
             for layer in routing.get("experts", ()):
+                _ROUTED_ROWS.inc(layer.size)
+                if held is not None:
+                    # this program's share of the layer's experts
+                    layer = layer[(layer >= held[0]) &
+                                  (layer < held[0] + held[1])]
+                    _LOCAL_ROWS.inc(layer.size)
                 _EXPERTS_TOUCHED.inc(len(np.unique(layer)))
         if self._pool is not None:
             # the tick wrote each row's new K/V at its pre-decode index;
@@ -836,7 +860,7 @@ class ContinuousBatchingEngine:
 
         with self._cv:
             with _phase(rec, "engine.deliver") as deliver_span:
-                delivered = 0
+                delivered = positions = 0
                 for r in range(self.B):
                     if not self._active[r]:
                         continue
@@ -845,6 +869,8 @@ class ContinuousBatchingEngine:
                     t = int(nxt[r])
                     item["tokens"].append(t)
                     delivered += 1
+                    # what the decode just enqueued attends over for row r
+                    positions += len(item["prompt"]) + len(item["tokens"])
                     _TOKENS.inc()
                     if len(item["tokens"]) == 1 and "t_submit" in item:
                         _TTFT.observe(time.monotonic() - item["t_submit"])
@@ -861,6 +887,7 @@ class ContinuousBatchingEngine:
                         item["done"].set()
                         self._active[r] = False
                         self._rows[r] = None
+                _DECODE_POSITIONS.inc(positions)
                 if rec is not None:
                     deliver_span.args = {"tokens": delivered}
             # refill freed rows before the next tick

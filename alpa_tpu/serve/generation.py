@@ -223,6 +223,15 @@ class Generator:
     a "full" one) is prefilled with the rows' lengths handed to the model,
     so that no ring takes a chunk's padding.
 
+    A layer of latent attention (``GPTConfig.attention`` "latent") caches
+    ``(c, k_pe, index)``, two arrays of unlike shapes and no heads, where
+    the others cache ``(k, v, index)``: everything here hands a layer's
+    entry on as a triple and looks into none, so prefill in chunks, the
+    donating decode and the engine's ``_scatter_row`` serve it as they are;
+    what indexes per-head K and V (the block pool, the packed prefill, the
+    speculative verify step, beam search) refuses it
+    (``require_uniform_kv_caches``).
+
     ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
     every routed-expert layer, and ``{}`` (no output of the compiled

@@ -231,6 +231,96 @@ def test_scatter_row_moves_no_cache(one_chip, options):
     assert _aliases(hlo) == want
 
 
+# ---- a latent cache (ISSUE 32) ----------------------------------------
+
+LATENT_CONTEXT = 2048
+
+
+def _latent_generator(one_chip):
+    """DeepSeek-V2 at its published widths (128 heads of 128 + 64 and 128,
+    ranks 1536 and 512, 160 experts of which 20 are held), two layers (the
+    leading dense one and an expert layer), a slice of the vocabulary."""
+    import json
+    from alpa_tpu.model.gpt_model import config_from_hf
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "..", "chipbench", "configs",
+                           "deepseek-v2-1chip.json")) as f:
+        hf = json.load(f)
+    hf.update(num_hidden_layers=LAYERS, vocab_size=1024,
+              n_routed_experts=hf["published"]["n_routed_experts"])
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=LATENT_CONTEXT, experts_held=(0, 20))
+    model = GPTModel(cfg)
+    params = _abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                      jnp.ones((1, 8), jnp.int32)),
+                       one_chip)
+    caches = _abstract(jax.eval_shape(
+        lambda: [(k, v, jnp.zeros((ROWS,), jnp.int32))
+                 for k, v, _ in init_kv_caches(cfg, ROWS)]), one_chip)
+    return Generator(model, params, cfg, prefill_chunk=256), params, caches
+
+
+def _entry_arrays(hlo):
+    """The shapes of the ENTRY computation's parameters and of its result,
+    and of the values its loops carry."""
+    found, _types = _entry(hlo)
+    return [result for _name, result, op, _operand in found
+            if op in ("parameter", "tuple", "while")]
+
+
+def test_latent_decode_holds_no_per_head_cache(one_chip):
+    """Nothing of 128 heads times the context goes into the decode, comes
+    out of it or is carried by a loop of it: the cache is 512 + 64 values
+    a position, the decode absorbs the expansion, and no whole cache is
+    copied for the rows' writes."""
+    gen, params, caches = _latent_generator(one_chip)
+    assert [(k.shape, v.shape) for k, v, _ in caches] == LAYERS * [
+        ((ROWS, LATENT_CONTEXT, 512), (ROWS, 64, LATENT_CONTEXT))]
+    hlo = _compile_decode(gen, params, caches, one_chip, NO_MSA)
+    assert hlo.startswith("HloModule jit_decode")
+    arrays = " ".join(_entry_arrays(hlo))
+    assert "[%d,%d,512]" % (ROWS, LATENT_CONTEXT) in arrays
+    per_head = re.compile(r"\[%d,(%d,128|128,%d),\d+\]" % (
+        ROWS, LATENT_CONTEXT, LATENT_CONTEXT))
+    assert not per_head.search(arrays)
+    # both arrays of every layer given to the output that replaces them
+    assert len(_aliases(hlo)) == 2 * LAYERS
+    # (the expert layer's counts are a scatter-add; no cache is)
+    assert not re.search(r"= \S*\[%d,(%d,512|64,%d)\]\S* scatter\(" % (
+        ROWS, LATENT_CONTEXT, LATENT_CONTEXT), hlo)
+    found, _types = _entry(hlo)
+    copies = [result for _name, result, op, _operand in found
+              if op in ("copy", "copy-start") and
+              ("[%d,%d,512]" % (ROWS, LATENT_CONTEXT) in result or
+               "[%d,64,%d]" % (ROWS, LATENT_CONTEXT) in result)]
+    assert copies == []
+
+
+def test_latent_chunk_step_expands_a_block_at_a_time(one_chip):
+    """The chunk step's attention is the kernel of
+    ``ops/latent_attention.py``, compiled by Mosaic for this chip at the
+    published widths (one a layer beside the expert layer's grouped
+    matmuls), and no per-head key or value of the context goes in, comes
+    out or is carried by a loop."""
+    gen, params, _caches = _latent_generator(one_chip)
+    cfg = gen.config
+    caches1 = _abstract(jax.eval_shape(lambda: init_kv_caches(cfg, 1)),
+                        one_chip)
+    hlo = gen._chunk_prefill.lower(
+        params, jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip), caches1,
+        jax.ShapeDtypeStruct((1, cfg.vocab_size), jnp.bfloat16,
+                             sharding=one_chip)).compile().as_text()
+    assert hlo.startswith("HloModule jit_chunk_prefill")
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert len([k for k in kernels if "/attn/attention/" in k]) == LAYERS
+    arrays = " ".join(_entry_arrays(hlo))
+    assert "[1,%d,512]" % LATENT_CONTEXT in arrays
+    assert not re.search(r"\[1,(%d,128|128,%d),\d+\]" % (
+        LATENT_CONTEXT, LATENT_CONTEXT), arrays)
+
+
 # ---- numerics, on the CPU ---------------------------------------------
 
 SEQ, HEADS, HEAD_DIM = 24, 2, 4
