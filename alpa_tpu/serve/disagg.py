@@ -479,7 +479,6 @@ class PrefillEngine:
 
         import jax.numpy as jnp
 
-        from alpa_tpu.model.gpt_model import init_kv_caches
         p = item["prompt"]
         # max_new_tokens=0: this pool never decodes — it only needs the
         # prompt's blocks, and releases them (into the prefix index)
@@ -503,9 +502,8 @@ class PrefillEngine:
             else:
                 ids = np.zeros((1, self.bucket), np.int32)
                 ids[0, :len(p)] = p
-                caches1 = init_kv_caches(self.gen.config, 1)
                 logits1, caches1 = self.gen._prefill(
-                    self.gen.params, jnp.asarray(ids), caches1, total)
+                    self.gen.params, jnp.asarray(ids), None, total)
             self.pool.scatter_prompt(seq, caches1)
             if self._reuse:
                 self.pool.register_prompt(seq, p)

@@ -98,6 +98,14 @@ def _phase(rec, name, args=None):
         return _ttrace.NULL_SPAN
     return rec.span(name, "serving", args, _TRACK)
 
+
+def _row_length(n: int):
+    """``int32[1]`` on the device, the one row's length a prefill takes:
+    from a numpy array, which is a transfer, where a Python list would be
+    a transfer and a program that converts it."""
+    return jnp.asarray(np.array([n], np.int32))
+
+
 _STREAM_END = object()
 
 
@@ -682,7 +690,7 @@ class ContinuousBatchingEngine:
                         m = seq.matched_tokens
                         path, asked = "prefix", len(p) - m
                         padded = self._chunk_padded(asked)
-                        total = jnp.asarray([len(p)], jnp.int32)
+                        total = _row_length(len(p))
                         gathered = self._pool.gather_dense(seq)
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p[m:]], total, 1, caches=gathered, start=m)
@@ -694,8 +702,7 @@ class ContinuousBatchingEngine:
                         h = self._prefix
                         path, asked = "prefix", len(p)
                         padded = self._chunk_padded(asked)
-                        total = jnp.asarray([h.length + len(p)],
-                                            jnp.int32)
+                        total = _row_length(h.length + len(p))
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p], total, 1, caches=h.caches,
                             start=h.length, init_last=h.last_logits)
@@ -703,15 +710,14 @@ class ContinuousBatchingEngine:
                         path, asked = "chunked", len(p)
                         padded = self._chunk_padded(asked)
                         logits1, caches1 = self.gen._run_chunked_prefill(
-                            [p], jnp.asarray([len(p)], jnp.int32), 1)
+                            [p], _row_length(len(p)), 1)
                     else:
                         path, asked, padded = "dense", len(p), self.bucket
                         ids = np.zeros((1, self.bucket), np.int32)
                         ids[0, :len(p)] = p
-                        caches1 = init_kv_caches(self.gen.config, 1)
                         logits1, caches1 = self.gen._prefill(
-                            self.gen.params, jnp.asarray(ids), caches1,
-                            jnp.asarray([len(p)], jnp.int32))
+                            self.gen.params, jnp.asarray(ids), None,
+                            _row_length(len(p)))
                     self._caches, self._logits = self._scatter_row(
                         self._caches, caches1, self._logits, logits1, r)
                     if rec is not None:

@@ -186,6 +186,17 @@ def _jit_donating_kv(step):
     return call
 
 
+@partial(jax.jit, static_argnums=(0, 1))
+def fresh_kv_caches(config, rows: int):
+    """Fresh caches for ``rows`` rows, ``[(k, v, index)]`` a layer with
+    every index the scalar 0: the one way serving code gets them.  From
+    the host it is ONE dispatch whatever the model's depth (a program
+    compiled once a configuration and number of rows, where
+    ``init_kv_caches`` called eagerly is three small programs a layer);
+    inside a traced program it is inlined and costs none."""
+    return init_kv_caches(config, rows)
+
+
 def default_prompt_buckets(seq_len: int) -> List[int]:
     """Power-of-two prompt-length buckets up to seq_len."""
     buckets, b = [], 32
@@ -217,6 +228,12 @@ class Generator:
     ``_prefill``, ``_chunk_prefill`` and the verify step donate nothing (a
     ``PrefixHandle``'s caches are prefilled from again and again), nor
     does the ``parallel_method`` decode.
+
+    Fresh caches come from ``fresh_kv_caches`` and from nowhere else: the
+    dense ``_prefill`` handed ``None`` for its caches makes them inside
+    its own program, the chunk step's first chunk is handed the one
+    program's result, so what an admission dispatches does not grow with
+    the model's depth.
 
     A configuration whose layers' caches differ (``GPTConfig.attention``:
     a ring of the window's positions in a "sliding" layer, the context in
@@ -289,6 +306,11 @@ class Generator:
         def prefill(params, input_ids, caches, lengths):
             self.prefill_traces += 1
             b, s = input_ids.shape
+            if caches is None:
+                # a prefill from nothing makes its zeros itself: nothing
+                # is dispatched for them, and the compiler keeps only
+                # those the prompt's positions do not overwrite
+                caches = fresh_kv_caches(config, b)
             pos = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
             logits, caches = model.apply(params, input_ids, pos, caches,
                                          **lengths_kw(lengths))
@@ -353,9 +375,7 @@ class Generator:
         ids = np.zeros((b, bucket), np.int32)
         for i, p in enumerate(prompts):
             ids[i, :len(p)] = p
-        caches = init_kv_caches(self.config, b)
-        return self._prefill(self.params, jnp.asarray(ids), caches,
-                             lengths_j)
+        return self._prefill(self.params, jnp.asarray(ids), None, lengths_j)
 
     def _run_chunked_prefill(self, prompts, lengths_j, b, caches=None,
                              start=0, init_last=None):
@@ -388,7 +408,7 @@ class Generator:
         for i, p in enumerate(prompts):
             ids[i, :len(p)] = p
         if caches is None:
-            caches = init_kv_caches(self.config, b)   # scalar index 0
+            caches = fresh_kv_caches(self.config, b)   # scalar index 0
         if init_last is None:
             init_last = jnp.zeros((b, self.config.vocab_size),
                                   self.config.dtype)
@@ -727,9 +747,7 @@ class Generator:
                 [np.asarray(input_ids[0])],
                 jnp.full((1,), s, jnp.int32), 1)
         else:
-            caches1 = init_kv_caches(self.config, 1)
-            logits1, caches1 = self._prefill(self.params, input_ids,
-                                             caches1,
+            logits1, caches1 = self._prefill(self.params, input_ids, None,
                                              jnp.full((1,), s, jnp.int32))
         beams = jnp.repeat(input_ids, num_beams, axis=0)     # (K, S)
         logits = jnp.repeat(logits1, num_beams, axis=0)

@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import (GPTConfig, init_kv_caches,
-                                      require_uniform_kv_caches)
+from alpa_tpu.model.gpt_model import GPTConfig, require_uniform_kv_caches
+from alpa_tpu.serve.generation import fresh_kv_caches
 
 logger = logging.getLogger(__name__)
 
@@ -100,6 +100,9 @@ class PackedPrefill:
 
         def prefill(params, ids, seg, pos, starts, lens, caches):
             self.traces += 1
+            if caches is None:
+                # no prefix: the program makes its own zeros
+                caches = fresh_kv_caches(config, 1)
             # packed caches sized to prefix + bucket, not full seq_len
             caches = [(k[:, :cap], v[:, :cap], i)
                       for (k, v, i) in caches]
@@ -126,11 +129,10 @@ class PackedPrefill:
     def __call__(self, prompts: Sequence[np.ndarray]):
         ids, seg, pos, starts, lens = pack_prompts(
             prompts, self.total_bucket, self.max_rows)
+        caches = None
         if self.prefix is not None:
             caches = self.prefix.caches
             pos = pos + self.prefix_len  # global positions after prefix
-        else:
-            caches = init_kv_caches(self.config, 1)
         return self._prefill(self.params, jnp.asarray(ids),
                              jnp.asarray(seg), jnp.asarray(pos),
                              jnp.asarray(starts), jnp.asarray(lens),

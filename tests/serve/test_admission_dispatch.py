@@ -1,0 +1,165 @@
+"""What an admission hands the device (ISSUE 33): a number of programs
+that does not grow with the model's depth, its fresh one-row caches coming
+from ``generation.fresh_kv_caches`` (one program, or none where the
+prefill makes its zeros itself) and never from ``init_kv_caches`` called
+eagerly, three small programs a layer; and nothing of one admission left
+for the next, in the rows or on the device."""
+import gc
+import threading
+
+import jax
+import numpy as np
+import pytest
+from jax._src import pjit
+from jax._src.interpreters import pxla
+
+from alpa_tpu.model.gpt_model import GPTConfig, init_gpt_real
+from alpa_tpu.serve import engine as engine_module
+from alpa_tpu.serve import generation
+from alpa_tpu.serve.engine import ContinuousBatchingEngine
+from alpa_tpu.serve.generation import GenerationConfig, Generator
+
+BUCKET, CHUNK = 32, 16
+PATHS = {"dense": {}, "chunked": {"chunked_admission": True},
+         "packed": {"packed_admission": True, "packed_bucket": 2 * BUCKET}}
+
+
+def _engine(layers, path, vocab=67):
+    # a vocabulary no other test file uses: ``fresh_kv_caches`` is one
+    # jit a process, keyed by the configuration
+    cfg = GPTConfig(hidden_size=32, num_layers=layers, num_heads=4,
+                    seq_len=64, vocab_size=vocab)
+    model, params = init_gpt_real(cfg, 1)
+    gen = Generator(model, params, cfg, prompt_buckets=[BUCKET],
+                    prefill_chunk=CHUNK)
+    return gen, ContinuousBatchingEngine(gen, max_batch=2,
+                                         prompt_bucket=BUCKET,
+                                         **PATHS[path])
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(1, 60, n).astype(np.int32)
+
+
+# ---- the programs of one admission ----
+
+@pytest.fixture
+def dispatched(monkeypatch):
+    """Every program the process runs from here on, by name and thread,
+    eager primitives (``jit(broadcast_in_dim)``) and jitted calls alike:
+    jax's fast path, which would run a program it has seen without coming
+    back to Python, is off for programs first called under this fixture,
+    and the Python path is counted where it executes."""
+    ran = []
+    monkeypatch.setattr(pjit, "_get_fastpath_data", lambda *a, **k: None)
+    execute = pxla.ExecuteReplicated.__call__
+
+    def counted(self, *args):
+        ran.append((threading.get_ident(), self.name))
+        return execute(self, *args)
+
+    monkeypatch.setattr(pxla.ExecuteReplicated, "__call__", counted)
+    return ran
+
+
+def _programs_of_one_admission(layers, path, ran, monkeypatch):
+    """Names of the programs the engine's thread ran between taking a row
+    for a request and giving it, after a warm-up admission."""
+    del ran[:]              # a thread's ident may be an ended thread's
+    _, engine = _engine(layers, path, vocab=71)
+    me = engine._thread.ident
+    taken, give = engine._row_taken, engine._give_row
+
+    def eager(*_a, **_k):
+        raise AssertionError("init_kv_caches called by an admission")
+
+    def row_taken(rec, item):
+        ran.append((me, "ROW TAKEN"))
+        return taken(rec, item)
+
+    def give_row(r, item):
+        ran.append((me, "ROW GIVEN"))
+        return give(r, item)
+
+    cfg = GenerationConfig(max_new_tokens=3)
+    try:
+        engine.submit(_prompt(20, 0), cfg)              # compiles
+        with monkeypatch.context() as patch:
+            # the zeros are compiled in: from here on nothing may build
+            # caches array by array
+            patch.setattr(generation, "init_kv_caches", eager)
+            patch.setattr(engine_module, "init_kv_caches", eager)
+            patch.setattr(engine, "_row_taken", row_taken)
+            patch.setattr(engine, "_give_row", give_row)
+            out = engine.submit(_prompt(20, 1), cfg)
+        assert len(out) == 23
+    finally:
+        engine.shutdown()
+    mine = [name for ident, name in ran if ident == me]
+    lo, hi = mine.index("ROW TAKEN"), mine.index("ROW GIVEN")
+    assert mine.count("ROW TAKEN") == 1 and lo < hi
+    return mine[lo + 1:hi]
+
+
+@pytest.mark.parametrize("path", ["dense", "chunked"])
+def test_an_admission_dispatches_the_same_programs_at_any_depth(
+        path, dispatched, monkeypatch):
+    shallow = _programs_of_one_admission(2, path, dispatched, monkeypatch)
+    deep = _programs_of_one_admission(6, path, dispatched, monkeypatch)
+    assert shallow == deep
+    if path == "dense":
+        # the prefill builds its own zeros: no program in front of it
+        assert shallow == ["jit(prefill)", "jit(scatter_row)"]
+    else:
+        # one program for all layers' fresh caches, the zeroed row of last
+        # logits, the two chunks of a 20-token prompt, the scatter
+        assert shallow[0] == "jit(fresh_kv_caches)"
+        assert shallow[-3:] == ["jit(chunk_prefill)", "jit(chunk_prefill)",
+                                "jit(scatter_row)"]
+        assert len(shallow) <= 6
+
+
+# ---- nothing of one admission is left for the next ----
+
+def _stream_pair(engine, prompts, cfg):
+    """Both prompts queued before the engine can take either (the lock is
+    re-entrant), so a packing engine packs them; their streamed tokens."""
+    with engine._cv:
+        streams = [engine.submit_stream(p, cfg) for p in prompts]
+    return [list(s) for s in streams]
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_a_short_prompt_after_a_long_one_reads_nothing_of_it(path):
+    gen, engine = _engine(2, path)
+    cfg = GenerationConfig(max_new_tokens=6)
+    long_a, long_b = _prompt(29, 2), _prompt(27, 3)
+    short_a, short_b = _prompt(3, 4), _prompt(5, 5)
+    try:
+        alone = {p.tobytes(): list(gen.generate([p], cfg)[0][len(p):])
+                 for p in (long_a, long_b, short_a, short_b)}
+        # rows 0 and 1 hold the long prompts; then a short prompt goes
+        # into each: the row the first long one had, and another
+        for pair in ((long_a, long_b), (short_a, short_b)):
+            got = _stream_pair(engine, pair, cfg)
+            assert got == [alone[p.tobytes()] for p in pair]
+        if path == "packed":
+            assert engine.packed_admissions == 2
+
+        def live():
+            # with the lock held the engine's thread is between two turns
+            # of its loop: nothing of a tick or an admission is in flight
+            gc.collect()
+            with engine._cv:
+                assert not engine._active.any()
+                return len(jax.live_arrays())
+
+        first = live()
+        for _ in range(25):                       # 50 admissions
+            got = _stream_pair(engine, (short_a, long_b), cfg)
+            assert got == [alone[short_a.tobytes()],
+                           alone[long_b.tobytes()]]
+        assert engine.admissions == 54
+        assert live() == first
+    finally:
+        engine.shutdown()
