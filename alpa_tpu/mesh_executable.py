@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from alpa_tpu.device_mesh import PhysicalDeviceMesh
+from alpa_tpu.telemetry import device_time as _device_time
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.util import benchmark_func
 
@@ -107,6 +108,11 @@ class NormalMeshExecutable(MeshExecutable):
         self.donated_invars = donated_invars or (False,) * len(in_avals)
         self.flop_count = flop_count
         self.timer_name = f"exec-{self.exec_uuid}"
+        # a capture finds the program's HLO text by the name the profiler
+        # gives its runs (telemetry/device_time.py)
+        _device_time.register_program(
+            _device_time.compiled_name(compiled), self,
+            NormalMeshExecutable.get_hlo_text)
 
     def launch_on_driver(self, *flat_args):
         """Execute on flat (already tree-flattened) args.

@@ -506,6 +506,9 @@ def get_attention_fn(config: GPTConfig) -> Callable:
 
 # the scope the attention core of every layer is traced under
 ATTENTION_SCOPE = "attention"
+# inside it, the scope of the step's keys and values written into the
+# cache (a capture reads both: telemetry/device_time.py)
+CACHE_WRITE_SCOPE = "cache_write"
 
 
 def _write_rows(cache, new, index):
@@ -556,16 +559,16 @@ def update_kv_cache(kv_cache, k, v):
     k_cache, v_cache, index = kv_cache
     b, s = k.shape[0], k.shape[1]
     index = jnp.asarray(index, jnp.int32)
-    if index.ndim == 0:
-        k_full = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), index, axis=1)
-        v_full = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), index, axis=1)
-        keep_len = index + s
-    else:
-        k_full = _write_rows(k_cache, k.astype(k_cache.dtype), index)
-        v_full = _write_rows(v_cache, v.astype(v_cache.dtype), index)
-        keep_len = (index + s)[:, None]
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        if index.ndim == 0:
+            k_full = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), index, axis=1)
+            v_full = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), index, axis=1)
+        else:
+            k_full = _write_rows(k_cache, k.astype(k_cache.dtype), index)
+            v_full = _write_rows(v_cache, v.astype(v_cache.dtype), index)
+    keep_len = index + s if index.ndim == 0 else (index + s)[:, None]
     pos = jax.lax.broadcasted_iota(jnp.int32, (k_full.shape[1],), 0)
     keep = pos < keep_len
     if keep.ndim == 1:
@@ -615,14 +618,15 @@ def update_ring_cache(kv_cache, k, v, lengths=None):
         return last - (last - slots) % w
 
     if s == 1:
-        if index.ndim:
-            k_new = _write_rows(k_cache, k, index % w)
-            v_new = _write_rows(v_cache, v, index % w)
-        else:
-            k_new = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k, index % w, axis=1)
-            v_new = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v, index % w, axis=1)
+        with jax.named_scope(CACHE_WRITE_SCOPE):
+            if index.ndim:
+                k_new = _write_rows(k_cache, k, index % w)
+                v_new = _write_rows(v_cache, v, index % w)
+            else:
+                k_new = jax.lax.dynamic_update_slice_in_dim(
+                    k_cache, k, index % w, axis=1)
+                v_new = jax.lax.dynamic_update_slice_in_dim(
+                    v_cache, v, index % w, axis=1)
         return k_new, v_new, held(first), (k_new, v_new, index + 1)
 
     new_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
@@ -638,10 +642,11 @@ def update_ring_cache(kv_cache, k, v, lengths=None):
     # what it has
     source = jnp.broadcast_to(held(first + real - 1) - first,
                               (k.shape[0], w))
-    take = (source >= 0)[:, :, None, None]
-    at = jnp.clip(source, 0, s - 1)[:, :, None, None]
-    k_new = jnp.where(take, jnp.take_along_axis(k, at, axis=1), k_cache)
-    v_new = jnp.where(take, jnp.take_along_axis(v, at, axis=1), v_cache)
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        take = (source >= 0)[:, :, None, None]
+        at = jnp.clip(source, 0, s - 1)[:, :, None, None]
+        k_new = jnp.where(take, jnp.take_along_axis(k, at, axis=1), k_cache)
+        v_new = jnp.where(take, jnp.take_along_axis(v, at, axis=1), v_cache)
     return k_use, v_use, k_positions, (k_new, v_new, index + s)
 
 
@@ -683,14 +688,15 @@ def update_latent_cache(kv_cache, c, k_pe):
     index = jnp.asarray(index, jnp.int32)
     c = c.astype(c_cache.dtype)
     k_pe = k_pe.astype(pe_cache.dtype).swapaxes(1, 2)
-    if index.ndim == 0:
-        c_cache = jax.lax.dynamic_update_slice_in_dim(c_cache, c, index,
-                                                      axis=1)
-        pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, k_pe,
-                                                       index, axis=2)
-    else:
-        c_cache = _write_latent_rows(c_cache, c, index, 1)
-        pe_cache = _write_latent_rows(pe_cache, k_pe, index, 2)
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        if index.ndim == 0:
+            c_cache = jax.lax.dynamic_update_slice_in_dim(c_cache, c, index,
+                                                          axis=1)
+            pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, k_pe,
+                                                           index, axis=2)
+        else:
+            c_cache = _write_latent_rows(c_cache, c, index, 1)
+            pe_cache = _write_latent_rows(pe_cache, k_pe, index, 2)
     return c_cache, pe_cache, index + c.shape[1]
 
 

@@ -71,6 +71,12 @@ def create_adamw(learning_rate=1e-3, weight_decay=0.01, b1=0.9, b2=0.999,
     return optax.chain(*chain)
 
 
+# the scope the loss is traced under, from the logits (or, chunked, the
+# hidden states) to the scalar: a capture reads device time by it
+# (telemetry/device_time.py)
+LOSS_SCOPE = "loss"
+
+
 def gpt_lm_loss(apply_fn, params, batch, chunked=False):
     """LM loss for a GPT-family model with tied embeddings: dense fp32
     CE, or the fused/chunked lm-head + CE that never materializes the
@@ -81,7 +87,9 @@ def gpt_lm_loss(apply_fn, params, batch, chunked=False):
         emb = params["params"]["wte"]["embedding"]
         return chunked_cross_entropy_loss(hidden, emb, batch["labels"])
     logits = apply_fn(params, batch["input_ids"])
-    return cross_entropy_loss(logits.astype(jnp.float32), batch["labels"])
+    with jax.named_scope(LOSS_SCOPE):
+        return cross_entropy_loss(logits.astype(jnp.float32),
+                                  batch["labels"])
 
 
 def routed_lm_loss(apply_fn, params, batch, aux_loss_coef: float):
@@ -90,8 +98,10 @@ def routed_lm_loss(apply_fn, params, batch, aux_loss_coef: float):
     ``aux_loss_coef`` times the routers' load-balancing term.  Returns
     ``(loss, routing)`` for ``value_and_grad(..., has_aux=True)``."""
     logits, routing = apply_fn(params, batch["input_ids"])
-    loss = cross_entropy_loss(logits.astype(jnp.float32), batch["labels"])
-    return loss + aux_loss_coef * routing["load_balance_loss"], routing
+    with jax.named_scope(LOSS_SCOPE):
+        loss = cross_entropy_loss(logits.astype(jnp.float32),
+                                  batch["labels"])
+        return loss + aux_loss_coef * routing["load_balance_loss"], routing
 
 
 def cross_entropy_loss(logits, labels, label_mask=None, vocab_size=None):
@@ -102,6 +112,7 @@ def cross_entropy_loss(logits, labels, label_mask=None, vocab_size=None):
     return loss.mean()
 
 
+@jax.named_scope(LOSS_SCOPE)
 def chunked_cross_entropy_loss(hidden, embedding, labels, chunk_size=512):
     """Fused lm-head + mean cross-entropy without materializing the full
     logits tensor.
@@ -130,7 +141,9 @@ def chunked_cross_entropy_loss(hidden, embedding, labels, chunk_size=512):
     @jax.checkpoint
     def one_chunk(args):
         xc, yc = args
-        logits = (xc @ embedding.T).astype(jnp.float32)
+        with jax.named_scope("lm_head"):    # the head's product, not loss
+            logits = xc @ embedding.T
+        logits = logits.astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
         return lse - gold

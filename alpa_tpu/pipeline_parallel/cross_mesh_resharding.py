@@ -1220,15 +1220,6 @@ class ReshardingTask:
         self.last_report: Optional[ExecutionReport] = None
 
     def run(self, src_array, mode: Optional[str] = None):
-        if _ttrace.enabled():
-            with _ttrace.get_recorder().span(
-                    "reshard.task", "resharding",
-                    {"mode": mode or self.mode,
-                     "bytes": self.spec.transfer_bytes}):
-                return self._run(src_array, mode)
-        return self._run(src_array, mode)
-
-    def _run(self, src_array, mode: Optional[str] = None):
         import jax
         mode = mode or self.mode
         fault.fire("cross_mesh_recv", mode=mode,

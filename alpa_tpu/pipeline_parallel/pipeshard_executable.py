@@ -34,6 +34,7 @@ from alpa_tpu.pipeline_parallel.runtime_emitter import (
     partition_streams)
 from alpa_tpu.pipeline_parallel.schedules import create_pipeline_schedule
 from alpa_tpu.shard_parallel.auto_sharding import MESH_AXIS_NAMES
+from alpa_tpu.telemetry import device_time as _device_time
 from alpa_tpu.telemetry import flight as _flight
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.telemetry import trace as _ttrace
@@ -175,7 +176,14 @@ class StageExecutable:
                 out_shardings.append(None)
         in_shardings = self.in_shardings
 
-        jitted = jax.jit(self._fun,
+        def program(*args):
+            return self._fun(*args)
+
+        # the compiled program is called what the stage is called
+        # (``jit_stage_0_fwd``): the profiler labels its runs so, and a
+        # capture finds its HLO text by that label
+        program.__name__ = program.__qualname__ = self.name
+        jitted = jax.jit(program,
                          in_shardings=tuple(in_shardings),
                          out_shardings=out_shardings,
                          donate_argnums=self.donate_idx)
@@ -184,6 +192,9 @@ class StageExecutable:
                           else None):
             lowered = jitted.lower(*self._avals)
             self.compiled = lowered.compile()
+        _device_time.register_program(
+            _device_time.compiled_name(self.compiled), self,
+            lambda stage: stage.compiled.as_text())
         self.out_shardings = list(self.compiled.output_shardings)
 
     def sharding_for(self, var) -> Any:

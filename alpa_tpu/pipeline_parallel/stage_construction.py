@@ -232,7 +232,6 @@ def cluster_layers_and_slice_mesh(
                         virtual_mesh, entry["phys_shapes"])
                     cache.record_saved_seconds(
                         "stage_dp", entry.get("solve_seconds", 0.0))
-                    _ttrace.instant("stage-dp-cache-hit", "compile")
                     return (entry["fwd_ids"], submeshes,
                             entry["logical_shapes"], entry["as_dicts"])
                 except Exception:  # pylint: disable=broad-except

@@ -19,7 +19,6 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from alpa_tpu.global_env import global_config
-from alpa_tpu.telemetry import trace as _ttrace
 
 logger = logging.getLogger(__name__)
 
@@ -455,13 +454,9 @@ def auto_stage_dp(num_layers, virtual_mesh, stage_option, layer_flops,
         B_eff, inflight_mode = 4096, "inference"
     else:
         B_eff, inflight_mode = num_micro_batches, schedule
-    _ttrace.instant("stage-dp-costs", "compile",
-                    {"L": L, "M": M})
     part = stage_dp_solve(costs, sizes, D, B_eff, mem_param,
                           mem_act, mem_budget=mem_budget,
                           inflight_mode=inflight_mode)
-    _ttrace.instant("stage-dp-solved", "compile",
-                    {"stages": len(part) if part else 0})
     if part is None:
         raise RuntimeError(
             "auto stage construction found no feasible partition")

@@ -22,6 +22,7 @@ import numpy as np
 from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, init_kv_caches,
                                       require_uniform_kv_caches,
                                       uniform_kv_caches)
+from alpa_tpu.telemetry import device_time
 
 logger = logging.getLogger(__name__)
 
@@ -154,6 +155,33 @@ def speculative_accept(props, q_probs, p_probs, us, u_extra):
     return k, _sample_from_probs(p_probs[k], u_extra)
 
 
+def _jit_registered(fun, **jit_kwargs):
+    """``jax.jit(fun, **jit_kwargs)`` whose compiled program a capture can
+    read by part of the model (``telemetry/device_time.py``): whenever the
+    function is traced, which is when a program of it compiles, it
+    registers under the name the profiler gives that program's runs
+    (``jit_<fun's name>``) a way to the program's optimised HLO text, by
+    lowering the function again for the shapes it was traced for, one way
+    a set of shapes.  jax keeps a function's lowering and its executable
+    for shapes it has run (a serving run counts the same 71 compiles with
+    and without a capture: PERF.md §6, PR 34); where it does not, the
+    persistent compilation cache answers.  Nothing runs in a call that
+    does not trace."""
+
+    def traced(*args):
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        device_time.register_program(
+            "jit_" + fun.__name__, jitted,
+            lambda again: again.lower(*shapes).compile().as_text(),
+            variant=str(shapes))
+        return fun(*args)
+
+    traced.__name__ = traced.__qualname__ = fun.__name__
+    jitted = jax.jit(traced, **jit_kwargs)
+    return jitted
+
+
 def _jit_donating_kv(step):
     """``jax.jit`` of a cached step ``step(params, tokens, index, caches)``
     that donates the K and V arrays of ``caches`` (``[(k, v, index)]`` a
@@ -175,7 +203,7 @@ def _jit_donating_kv(step):
     # the compiled program keeps the step's name (``jit_decode``): traces
     # and the benchmark's readers find it by that
     split.__name__ = split.__qualname__ = step.__name__
-    jitted = jax.jit(split, donate_argnums=(3,))
+    jitted = _jit_registered(split, donate_argnums=(3,))
 
     def call(params, tokens, index, caches):
         return jitted(params, tokens, index,
@@ -358,9 +386,9 @@ class Generator:
             self._chunk_prefill = alpa_tpu.parallelize(
                 chunk_prefill, method=parallel_method, donate_argnums=())
         else:
-            self._prefill = jax.jit(prefill)
+            self._prefill = _jit_registered(prefill)
             self._decode = _jit_donating_kv(decode)
-            self._chunk_prefill = jax.jit(chunk_prefill)
+            self._chunk_prefill = _jit_registered(chunk_prefill)
         # beam-search KV-cache gather, compiled once (per cache shapes)
         self._reorder = jax.jit(
             lambda caches, idx: jax.tree_util.tree_map(

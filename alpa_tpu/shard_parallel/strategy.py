@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend import source_info_util
 from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 from alpa_tpu.shard_parallel.sharding_spec import (Spec, enumerate_var_specs,
@@ -1332,6 +1333,51 @@ def make_constrained_fun(graph: StrategyGraph, choice, jax_mesh,
     def constrained(*args):
         counter = [0]  # position in the flattened eqn order
 
+        def _bind(eqn, env, read, depth):
+            prim = eqn.primitive.name
+            site = _inline_site(eqn, depth)
+            if site is not None:
+                sub_jaxpr, sub_consts = site
+                outer_in = [read(v) for v in eqn.invars]
+                aligned = _align_call_args(outer_in, sub_jaxpr.invars)
+                if prim in ("remat", "checkpoint", "remat2"):
+                    fn = functools.partial(
+                        _remat_body, eval_jaxpr, sub_jaxpr, sub_consts,
+                        depth)
+                    fn = _jax.checkpoint(
+                        fn,
+                        policy=eqn.params.get("policy"),
+                        prevent_cse=eqn.params.get("prevent_cse", True))
+                    ans = fn(*aligned)
+                else:
+                    ans = eval_jaxpr(sub_jaxpr, sub_consts, aligned,
+                                     depth + 1)
+                for ov, a in zip(eqn.outvars, ans):
+                    env[ov] = a
+                return
+            if prim == "pipeline":
+                # boundary marker: identity passthrough (one flat slot)
+                counter[0] += 1
+                for iv, ov in zip(eqn.invars, eqn.outvars):
+                    env[ov] = read(iv)
+                return
+            pos = counter[0]
+            counter[0] += 1
+            vals = [read(v) for v in eqn.invars]
+            for ii in range(len(vals)):
+                sh = in_cons.get((pos, ii))
+                if sh is not None:
+                    vals[ii] = _jax.lax.with_sharding_constraint(
+                        vals[ii], sh)
+            ans = eqn.primitive.bind(*vals, **eqn.params)
+            if not eqn.primitive.multiple_results:
+                ans = [ans]
+            for oi, (ov, a) in enumerate(zip(eqn.outvars, ans)):
+                sh = out_cons.get((pos, oi))
+                if sh is not None:
+                    a = _jax.lax.with_sharding_constraint(a, sh)
+                env[ov] = a
+
         def eval_jaxpr(jaxpr, jconsts, jargs, depth):
             env = {}
             for v, c in zip(jaxpr.constvars, jconsts):
@@ -1345,49 +1391,16 @@ def make_constrained_fun(graph: StrategyGraph, choice, jax_mesh,
                 return env[v]
 
             for eqn in jaxpr.eqns:
-                prim = eqn.primitive.name
-                site = _inline_site(eqn, depth)
-                if site is not None:
-                    sub_jaxpr, sub_consts = site
-                    outer_in = [read(v) for v in eqn.invars]
-                    aligned = _align_call_args(outer_in, sub_jaxpr.invars)
-                    if prim in ("remat", "checkpoint", "remat2"):
-                        fn = functools.partial(
-                            _remat_body, eval_jaxpr, sub_jaxpr, sub_consts,
-                            depth)
-                        fn = _jax.checkpoint(
-                            fn,
-                            policy=eqn.params.get("policy"),
-                            prevent_cse=eqn.params.get("prevent_cse", True))
-                        ans = fn(*aligned)
-                    else:
-                        ans = eval_jaxpr(sub_jaxpr, sub_consts, aligned,
-                                         depth + 1)
-                    for ov, a in zip(eqn.outvars, ans):
-                        env[ov] = a
-                    continue
-                if prim == "pipeline":
-                    # boundary marker: identity passthrough (one flat slot)
-                    counter[0] += 1
-                    for iv, ov in zip(eqn.invars, eqn.outvars):
-                        env[ov] = read(iv)
-                    continue
-                pos = counter[0]
-                counter[0] += 1
-                vals = [read(v) for v in eqn.invars]
-                for ii in range(len(vals)):
-                    sh = in_cons.get((pos, ii))
-                    if sh is not None:
-                        vals[ii] = _jax.lax.with_sharding_constraint(
-                            vals[ii], sh)
-                ans = eqn.primitive.bind(*vals, **eqn.params)
-                if not eqn.primitive.multiple_results:
-                    ans = [ans]
-                for oi, (ov, a) in enumerate(zip(eqn.outvars, ans)):
-                    sh = out_cons.get((pos, oi))
-                    if sh is not None:
-                        a = _jax.lax.with_sharding_constraint(a, sh)
-                    env[ov] = a
+                # the equation keeps its place in the model (and its
+                # traceback): the name stack it was traced under, below
+                # the one it is bound under here, as jax.core.eval_jaxpr
+                # does.  A device trace is read by these names
+                # (telemetry/device_time.py).
+                with source_info_util.user_context(
+                        eqn.source_info.traceback,
+                        name_stack=source_info_util.current_name_stack() +
+                        eqn.source_info.name_stack):
+                    _bind(eqn, env, read, depth)
             return [read(v) for v in jaxpr.outvars]
 
         return eval_jaxpr(root.jaxpr, consts, args, 0)

@@ -37,12 +37,14 @@ import time
 from typing import Any, Dict, List, Optional
 
 from alpa_tpu.global_env import global_config
+from alpa_tpu.telemetry import device_time
 
 __all__ = [
     "TraceRecorder", "get_recorder", "set_recorder", "enabled",
     "set_enabled", "span", "instant", "counter", "begin", "end",
     "now_us", "merge_chrome_traces", "CATEGORIES", "NULL_SPAN",
-    "Capture", "start_capture", "stop_capture", "CAPTURE_MARKER",
+    "Capture", "start_capture", "stop_capture", "last_capture",
+    "CAPTURE_MARKER",
 ]
 
 # category taxonomy (docs/observability.md) — free-form strings are
@@ -362,7 +364,6 @@ def counter(name: str, value: float, track: Optional[str] = None):
 
 # the one span written both to the recorder and to the profiler's trace
 CAPTURE_MARKER = "alpa.capture"
-_HOST_PLANE = "/host:CPU"
 
 
 @dataclasses.dataclass
@@ -370,11 +371,14 @@ class Capture:
     """What :func:`stop_capture` returns: the recorder's spans of the
     capture (dicts as :meth:`TraceRecorder.spans` gives them, the marker
     among them), where the profiler wrote its trace, and the marker's
-    start on the recorder's clock."""
+    start on the recorder's clock.  :func:`stop_capture` reads the
+    profiler's trace once, while the file is there, and the capture keeps
+    what :meth:`offset_us` and :meth:`device_time` answer from."""
     log_dir: str
     spans: List[Dict[str, Any]]
     marker_ts_us: float
     _offset_us: Optional[float] = None
+    _device_time: Optional[Dict[str, Any]] = None
 
     def xplane_path(self) -> str:
         found = sorted(glob.glob(os.path.join(
@@ -383,34 +387,49 @@ class Capture:
             raise FileNotFoundError(f"no .xplane.pb under {self.log_dir}")
         return found[-1]
 
+    def _read(self) -> None:
+        """The one read of the profiler's trace: the marker (the offset
+        between the clocks, the window) and the device events, reduced."""
+        marker, chips = device_time.read_profile(self.xplane_path(),
+                                                 CAPTURE_MARKER)
+        if marker is not None:
+            self._offset_us = marker[0] / 1e3 - self.marker_ts_us
+        self._device_time = device_time.reduce_events(chips, marker)
+
     def offset_us(self) -> float:
         """Microseconds to ADD to a recorder timestamp (``ts_us``) to get
         the same instant on the clock of the profiler's events
         (``start_ns / 1e3`` of an xplane event, host or device): the
         marker's start as the profiler saw it minus its start as the
-        recorder saw it.  Reads the trace once and keeps the answer."""
+        recorder saw it."""
+        if self._device_time is None:
+            self._read()
         if self._offset_us is None:
-            import jax.profiler
-            data = jax.profiler.ProfileData.from_file(self.xplane_path())
-            self._offset_us = _marker_start_ns(data) / 1e3 - \
-                self.marker_ts_us
+            raise ValueError(
+                f"the profiler's trace holds no {CAPTURE_MARKER!r} event: "
+                "the offset between the clocks is unknown")
         return self._offset_us
 
-
-def _marker_start_ns(data) -> float:
-    for plane in data.planes:
-        if plane.name != _HOST_PLANE:
-            continue
-        for line in plane.lines:
-            for event in line.events:
-                if event.name == CAPTURE_MARKER:
-                    return event.start_ns
-    raise ValueError(f"the profiler's trace holds no {CAPTURE_MARKER!r} "
-                     "event: the offset between the clocks is unknown")
+    def device_time(self) -> Dict[str, Any]:
+        """The capture's device time by chip, program and part of the
+        model (``telemetry/device_time.py`` ``reduce_events`` says what the
+        table holds).  Without a TPU's plane in the trace, as on the CPU,
+        it holds no chip."""
+        if self._device_time is None:
+            self._read()
+        return self._device_time
 
 
 # (log_dir, enabled() before, the marker's annotation, its start)
 _CAPTURE: Optional[tuple] = None
+_LAST_CAPTURE: Optional[Capture] = None
+
+
+def last_capture() -> Optional[Capture]:
+    """The newest :class:`Capture` that :func:`stop_capture` returned in
+    this process (None before the first): for a reader that is handed no
+    capture."""
+    return _LAST_CAPTURE
 
 
 def start_capture(log_dir: str) -> None:
@@ -436,9 +455,13 @@ def start_capture(log_dir: str) -> None:
 
 def stop_capture() -> Capture:
     """Close the marker, stop the profiler, put ``enabled()`` back to
-    what :func:`start_capture` found, and return the :class:`Capture`.
-    The recorder keeps its spans until it is cleared."""
-    global _CAPTURE
+    what :func:`start_capture` found, read the profiler's trace once (the
+    offset between the clocks; the device time by program and by part of
+    the model, for which the programs that ran are asked for their HLO
+    text: ``telemetry/device_time.py``) and return the :class:`Capture`,
+    which :func:`last_capture` gives again.  The recorder keeps its spans
+    until it is cleared."""
+    global _CAPTURE, _LAST_CAPTURE
     if _CAPTURE is None:
         raise RuntimeError("no capture is running")
     import jax.profiler
@@ -452,4 +475,10 @@ def stop_capture() -> Capture:
         jax.profiler.stop_trace()
     finally:
         set_enabled(was)
-    return Capture(log_dir, _RECORDER.spans(), ts)
+    capture = Capture(log_dir, _RECORDER.spans(), ts)
+    try:
+        capture._read()     # pylint: disable=protected-access
+    except FileNotFoundError:   # the profiler wrote nothing: asked again,
+        pass                    # offset_us() and device_time() say so
+    _LAST_CAPTURE = capture
+    return capture
