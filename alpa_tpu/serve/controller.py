@@ -355,7 +355,8 @@ class _Replica:
     def prefill_engine(self):
         """Lazy prefill-only engine for the disaggregated prefill pool
         (serve.disagg).  Mirrors the decode engine's admission exactly
-        (same prompt bucket, same prefix-hit path), so the handoff
+        (same cap and so the same admission ladder, same prefix-hit
+        path), so the handoff
         artifact carries bit-identical KV to what the monolithic engine
         would have computed in place.  A static-PrefixHandle replica
         cannot serve the prefill pool (block tables need kv_paged +

@@ -32,10 +32,11 @@ separate replica pools:
   cross-request reuse keeps working on the decode side too.
 
 Bit-exactness: the prefill replica computes the SAME prefill function
-(same code path: bucketed ``_prefill`` on a miss, gather + chunked
-suffix prefill on a prefix hit) the monolithic engine would run, the
-verbatim payload moves bits unchanged, and the decode engine's
-admission/tick path is shared — so the disaggregated decode stream is
+(same code path: ``Generator.prefill_row`` over the same admission
+ladder on a miss, gather + chunked suffix prefill on a prefix hit) the
+monolithic engine would run, the verbatim payload moves bits unchanged,
+and the decode engine's admission/tick path is shared — so the
+disaggregated decode stream is
 ``np.array_equal`` with the monolithic engine on miss, full-hit, and
 shared-prefix paths (pinned in tests/serve/test_disagg.py).
 
@@ -361,6 +362,8 @@ class PrefillEngine:
         self.model = model
         self.weights_tag = weights_tag
         self.bucket = prompt_bucket or generator.prompt_buckets[-1]
+        # a miss pads as the decode engine's dense admission does
+        self._ladder = generator.admission_ladder(self.bucket)
         self.pool = kv_pool or KVBlockPool.for_generator(generator)
         if self.pool.seq_len != generator.config.seq_len:
             raise ValueError(
@@ -456,6 +459,8 @@ class PrefillEngine:
     # ---- worker -----------------------------------------------------
 
     def _run(self):
+        # as the decode engine: no prefill of a window compiles
+        self.gen.compile_row_prefills(self._ladder)
         while True:
             with self._cv:
                 while not self._stop and len(self._queue) == 0:
@@ -477,7 +482,7 @@ class PrefillEngine:
     def _prefill_one(self, item) -> KVHandoffArtifact:
         import dataclasses as _dc
 
-        import jax.numpy as jnp
+        from alpa_tpu.serve.generation import row_length
 
         p = item["prompt"]
         # max_new_tokens=0: this pool never decodes — it only needs the
@@ -491,19 +496,16 @@ class PrefillEngine:
         clean = False
         try:
             m = seq.matched_tokens
-            total = jnp.asarray([len(p)], jnp.int32)
             if m:
                 # prefix hit: identical to the monolithic engine's hit
                 # path (gather + chunked suffix prefill from the match
                 # offset) — bit-exactness rides the same ops
                 gathered = self.pool.gather_dense(seq)
                 logits1, caches1 = self.gen._run_chunked_prefill(
-                    [p[m:]], total, 1, caches=gathered, start=m)
+                    [p[m:]], row_length(len(p)), 1, caches=gathered,
+                    start=m)
             else:
-                ids = np.zeros((1, self.bucket), np.int32)
-                ids[0, :len(p)] = p
-                logits1, caches1 = self.gen._prefill(
-                    self.gen.params, jnp.asarray(ids), None, total)
+                logits1, caches1, _ = self.gen.prefill_row(p, self._ladder)
             self.pool.scatter_prompt(seq, caches1)
             if self._reuse:
                 self.pool.register_prompt(seq, p)

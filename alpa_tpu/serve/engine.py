@@ -9,6 +9,10 @@ decode executable compiles exactly once for the engine's lifetime.
 
 The per-row KV-cache indices introduced in ``model.gpt_model`` are what
 make this possible: every row decodes at its own position.
+
+A dense admission pads its prompt to the smaller of two buckets that holds
+it (``Generator.admission_ladder``), and the engine compiles both programs
+before it admits anything: ``ContinuousBatchingEngine.__init__``.
 """
 import itertools
 import logging
@@ -23,7 +27,7 @@ import numpy as np
 from alpa_tpu import fault
 from alpa_tpu.model.gpt_model import init_kv_caches
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
-                                       sample_rows)
+                                       row_length, sample_rows)
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.telemetry import trace as _ttrace
 
@@ -57,6 +61,11 @@ _PREFILL_PROMPT = _REG.counter(
 _PREFILL_PADDED = _REG.counter(
     "alpa_serving_prefill_padded_tokens_total",
     "Token positions the engine's prefill programs ran over")
+_DENSE_PREFILLS = _REG.counter(
+    "alpa_serving_dense_prefills_total",
+    "Admissions prefilled by the dense prefill program, by the bucket of "
+    "the engine's admission ladder their prompt was padded to (which "
+    "program ran)", labelnames=("bucket",))
 _EXPERTS_TOUCHED = _REG.counter(
     "alpa_moe_experts_touched_total",
     "Distinct experts the decode ticks' routed layers touched, summed "
@@ -97,13 +106,6 @@ def _phase(rec, name, args=None):
     if rec is None:
         return _ttrace.NULL_SPAN
     return rec.span(name, "serving", args, _TRACK)
-
-
-def _row_length(n: int):
-    """``int32[1]`` on the device, the one row's length a prefill takes:
-    from a numpy array, which is a transfer, where a Python list would be
-    a transfer and a program that converts it."""
-    return jnp.asarray(np.array([n], np.int32))
 
 
 _STREAM_END = object()
@@ -182,7 +184,18 @@ class ContinuousBatchingEngine:
                  scheduler: Optional[Any] = None,
                  kv_pool: Optional[Any] = None,
                  chunked_admission: bool = False):
-        """``packed_admission=True`` admits multiple queued prompts with
+        """``prompt_bucket`` is the cap of the dense admission: the longest
+        prompt ``submit`` takes (the generator's smallest bucket if not
+        given), and the top of the ladder a dense admission pads to
+        (``Generator.admission_ladder``: the cap and the generator's
+        bucket nearest a quarter of it).  An admission runs the prefill of
+        the smallest step that holds its prompt, and the engine's thread
+        compiles every step's program before it admits anything, so that
+        no admission compiles whatever its prompt's length.  An engine
+        that cannot reach the dense prefill (``chunked_admission``, a
+        static ``prefix``) has no ladder and compiles none of it.
+
+        ``packed_admission=True`` admits multiple queued prompts with
         ONE packed prefill (segment-masked, serve.packed.PackedPrefill —
         the 1-D batching analog) instead of one prefill per row; falls
         back to per-row prefill when fewer than two prompts wait or the
@@ -228,6 +241,9 @@ class ContinuousBatchingEngine:
         self.bucket = prompt_bucket or generator.prompt_buckets[0]
         cfgm = generator.config
         self._prefix = prefix
+        # what a dense admission pads to; empty where none can happen
+        self._ladder = [] if chunked_admission or prefix is not None \
+            else generator.admission_ladder(self.bucket)
         self._pool = kv_pool
         self._tables: List[Optional[Any]] = [None] * max_batch
         self._pool_reuse = False
@@ -690,7 +706,7 @@ class ContinuousBatchingEngine:
                         m = seq.matched_tokens
                         path, asked = "prefix", len(p) - m
                         padded = self._chunk_padded(asked)
-                        total = _row_length(len(p))
+                        total = row_length(len(p))
                         gathered = self._pool.gather_dense(seq)
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p[m:]], total, 1, caches=gathered, start=m)
@@ -702,7 +718,7 @@ class ContinuousBatchingEngine:
                         h = self._prefix
                         path, asked = "prefix", len(p)
                         padded = self._chunk_padded(asked)
-                        total = _row_length(h.length + len(p))
+                        total = row_length(h.length + len(p))
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p], total, 1, caches=h.caches,
                             start=h.length, init_last=h.last_logits)
@@ -710,14 +726,11 @@ class ContinuousBatchingEngine:
                         path, asked = "chunked", len(p)
                         padded = self._chunk_padded(asked)
                         logits1, caches1 = self.gen._run_chunked_prefill(
-                            [p], _row_length(len(p)), 1)
+                            [p], row_length(len(p)), 1)
                     else:
-                        path, asked, padded = "dense", len(p), self.bucket
-                        ids = np.zeros((1, self.bucket), np.int32)
-                        ids[0, :len(p)] = p
-                        logits1, caches1 = self.gen._prefill(
-                            self.gen.params, jnp.asarray(ids), None,
-                            _row_length(len(p)))
+                        path, asked = "dense", len(p)
+                        logits1, caches1, padded = self.gen.prefill_row(
+                            p, self._ladder)
                     self._caches, self._logits = self._scatter_row(
                         self._caches, caches1, self._logits, logits1, r)
                     if rec is not None:
@@ -731,6 +744,8 @@ class ContinuousBatchingEngine:
                             int(path == "dense")}
                 _PREFILL_PROMPT.inc(asked)
                 _PREFILL_PADDED.inc(padded)
+                if path == "dense":
+                    _DENSE_PREFILLS.labels(str(padded)).inc()
                 if seq is not None:
                     # publish the prompt's full blocks while the row is
                     # still live, so concurrent shared-prefix requests
@@ -774,6 +789,8 @@ class ContinuousBatchingEngine:
             logger.exception("KV pool release failed for row %d", r)
 
     def _run(self):
+        # the engine is not ready before its ladder is
+        self.gen.compile_row_prefills(self._ladder)
         while True:
             with self._cv:
                 if not self._stop and len(self._queue) == 0 and \

@@ -12,6 +12,7 @@ inference schedule slots in via the same executable interface).
 """
 import dataclasses
 import logging
+import threading
 from functools import partial
 from typing import Any, Callable, List, Optional, Sequence
 
@@ -225,6 +226,19 @@ def fresh_kv_caches(config, rows: int):
     return init_kv_caches(config, rows)
 
 
+def row_length(n: int):
+    """``int32[1]`` on the device, the one row's length a prefill takes:
+    from a numpy array, which is a transfer, where a Python list would be
+    a transfer and a program that converts it."""
+    return jnp.asarray(np.array([n], np.int32))
+
+
+# The lower step of an engine's admission ladder, as a share of its cap
+# (``Generator.admission_ladder``).  One run of OPT-1.3B's ``jit_prefill``
+# on a v5e by bucket is in PERF.md §6, PR 35.
+ADMISSION_STEP = 0.25
+
+
 def default_prompt_buckets(seq_len: int) -> List[int]:
     """Power-of-two prompt-length buckets up to seq_len."""
     buckets, b = [], 32
@@ -395,11 +409,13 @@ class Generator:
                 lambda x: jnp.take(x, idx, axis=0)
                 if hasattr(x, "ndim") and x.ndim > 0 else x, caches))
 
-    def _run_bucketed_prefill(self, prompts, lengths_j, b):
+    def _run_bucketed_prefill(self, prompts, lengths_j, b, bucket=None):
         """Classic bucketed prefill: right-pad to the bucket ladder (one
-        compile per bucket).  The single shared implementation for
-        generate and speculative decoding."""
-        bucket = self._bucket_len(int(max(len(p) for p in prompts)))
+        compile per bucket), or to ``bucket`` where the caller chose it.
+        The single shared implementation for generate, speculative
+        decoding and an engine's dense admission."""
+        bucket = bucket or \
+            self._bucket_len(int(max(len(p) for p in prompts)))
         ids = np.zeros((b, bucket), np.int32)
         for i, p in enumerate(prompts):
             ids[i, :len(p)] = p
@@ -464,12 +480,83 @@ class Generator:
         return PrefixHandle(caches=caches, length=len(p),
                             last_logits=last, params=self.params)
 
-    def _bucket_len(self, n: int) -> int:
-        for b in self.prompt_buckets:
+    def _bucket_len(self, n: int,
+                    buckets: Optional[Sequence[int]] = None) -> int:
+        """The smallest bucket that holds ``n`` positions, of ``buckets``
+        (ascending; the engines hand their admission ladder) or of the
+        generator's whole ladder."""
+        buckets = buckets or self.prompt_buckets
+        for b in buckets:
             if b >= n:
                 return b
         raise ValueError(f"prompt length {n} exceeds the largest bucket "
-                         f"{self.prompt_buckets[-1]}")
+                         f"{buckets[-1]}")
+
+    def admission_ladder(self, cap: int) -> List[int]:
+        """The buckets an engine's dense admission pads a prompt to:
+        ``cap`` (the longest prompt the engine takes) and, where the
+        generator's ladder has a bucket below it, the one nearest to
+        ``ADMISSION_STEP`` of it.  Two steps and not the whole ladder,
+        because an engine compiles every program it can run before it
+        admits (``compile_row_prefills``) and each costs its set-up."""
+        below = [b for b in self.prompt_buckets if b < cap]
+        if not below:
+            return [cap]
+        return [min(below, key=lambda b: abs(b - ADMISSION_STEP * cap)), cap]
+
+    def prefill_row(self, prompt: np.ndarray, ladder: Sequence[int]):
+        """One prompt prefilled from nothing for an engine's row: padded
+        to the smallest bucket of ``ladder`` that holds it, through the
+        dense ``_prefill`` for one row.  ``(last-token logits, caches,
+        bucket)``; the caches are of the full length whatever the bucket,
+        so a row scatters the result of any step alike.  The one way the
+        decode engine and the disaggregated ``PrefillEngine`` pad, which
+        keeps a handed-off row bit-identical to one computed in place."""
+        bucket = self._bucket_len(len(prompt), ladder)
+        logits1, caches1 = self._run_bucketed_prefill(
+            [prompt], row_length(len(prompt)), 1, bucket)
+        return logits1, caches1, bucket
+
+    def compile_row_prefills(self, ladder: Sequence[int]):
+        """Compile (or read back) every program ``prefill_row`` can run
+        over ``ladder`` and run each once over a prompt of padding, so
+        that no admission compiles: which program an admission runs
+        follows from its prompt's length, and a window must not meet one
+        for the first time.
+
+        A program's set-up is a second of tracing and lowering, which
+        holds the interpreter, and then the compile or the read from the
+        compile cache, which does not (one to three seconds for OPT-1.3B
+        on a v5e: PERF.md §6, PR 35).  So the calling thread traces the
+        programs one after the other and leaves the compile of all but
+        the last to a thread of its own, beside the next program's trace;
+        two traces at once would only take turns."""
+        padding = {b: np.zeros((b,), np.int32) for b in ladder}
+        compiling = []
+        try:
+            # the pipelined (parallel_method) prefill has no lowering
+            # apart from its call: it compiles in the runs below
+            ahead = ladder[:-1] if self._parallel_method is None else []
+            for bucket in ahead:
+                lowered = self._prefill.lower(
+                    self.params, jnp.asarray(padding[bucket][None]), None,
+                    row_length(bucket))
+                compiling.append(threading.Thread(
+                    target=lowered.compile, name="ladder-compile",
+                    daemon=True))
+                compiling[-1].start()
+            # the last bucket compiles here, in its run, beside the
+            # others; then theirs, through the call an admission makes
+            for bucket in reversed(ladder):
+                jax.block_until_ready(
+                    self.prefill_row(padding[bucket], ladder)[0])
+                while compiling:
+                    compiling.pop().join()
+        except Exception:  # pylint: disable=broad-except
+            # the caller is an engine's thread, which has to live: the
+            # admission that meets the fault then fails alone, as it
+            # did when it was the first to compile
+            logger.exception("compiling the admission ladder failed")
 
     def generate(self,
                  input_ids,

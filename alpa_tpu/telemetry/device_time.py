@@ -455,8 +455,13 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
     that of instructions with no ``op_name`` of their own, counted with
     the part of the instruction that uses them.
     ``parts_of(program)`` gives :func:`instruction_parts` of every program
-    registered under the name: the one that accounts for most of the
-    program's seconds is taken."""
+    registered under the name.  Runs that hold the same instructions are
+    reduced together, by the registered program that accounts for most of
+    their seconds (of two that account for the same, the one with fewer
+    instructions besides): where one jitted function ran as two programs
+    (an engine's dense prefill at two buckets, both ``jit_prefill``), each
+    run is read by its own, and the entry's ``runs``, ``run_s`` and
+    ``parts`` hold both."""
     if window_ns is None:
         times = [t for ops, _ in chips.values() for _n, s, e in ops
                  for t in (s, e)]
@@ -468,7 +473,8 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
     for chip, (ops, runs) in sorted(chips.items()):
         table["busy_s"][chip] = _busy_ns(ops, lo, hi) / 1e9
         programs = table["programs"][chip] = {}
-        own_ns: Dict[str, Dict[str, float]] = {}  # program: instruction: ns
+        # program: the instructions a run held: instruction: ns
+        own_ns: Dict[str, Dict[frozenset, Dict[str, float]]] = {}
         runs = sorted((s, e, name) for name, s, e in runs
                       if lo <= s and e <= hi)
         ops = sorted((s, -e, name) for name, s, e in ops)
@@ -479,7 +485,7 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
                 "inherited_s": 0.0, "unscoped_s": 0.0})
             entry["runs"] += 1
             entry["run_s"].append((run_end - run_start) / 1e9)
-            own = own_ns.setdefault(name, {})
+            own: Dict[str, float] = {}
             open_ops = []           # [end, instruction] of operations open
             while at < len(ops) and ops[at][0] < run_start:
                 at += 1
@@ -492,16 +498,23 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
                 own[op] = own.get(op, 0.0) + e - s
                 open_ops.append((e, op))
                 at += 1
-        for name, own in own_ns.items():
-            known = max(parts_of(name), default={}, key=lambda parts: sum(
-                ns for op, ns in own.items() if op in parts))
-            entry = programs[name]
+            alike = own_ns.setdefault(name, {}).setdefault(
+                frozenset(own), {})
             for op, ns in own.items():
-                part, how = known.get(op, (UNSCOPED, None))
-                entry["parts"][part] = entry["parts"].get(part, 0.0) + \
-                    ns / 1e9
-                if how:
-                    entry[how + "_s"] += ns / 1e9
+                alike[op] = alike.get(op, 0.0) + ns
+        for name, by_instructions in own_ns.items():
+            registered = parts_of(name)
+            entry = programs[name]
+            for own in by_instructions.values():
+                known = max(registered, default={}, key=lambda parts: (
+                    sum(ns for op, ns in own.items() if op in parts),
+                    -len(parts)))
+                for op, ns in own.items():
+                    part, how = known.get(op, (UNSCOPED, None))
+                    entry["parts"][part] = \
+                        entry["parts"].get(part, 0.0) + ns / 1e9
+                    if how:
+                        entry[how + "_s"] += ns / 1e9
             entry["unscoped_s"] = entry["parts"].get(UNSCOPED, 0.0)
     return table
 

@@ -324,6 +324,36 @@ def test_of_programs_that_share_a_name_the_one_that_ran_is_taken():
         {"mlp": 10e-9, "attention": 90e-9, "projection": 30e-9})
 
 
+def test_each_run_is_read_by_the_program_that_knows_its_instructions():
+    """One jitted function that ran as two programs, an engine's dense
+    prefill at two buckets: both are ``jit_prefill``, their instructions
+    share names (``a`` is the small one's head and the large one's MLP),
+    and the entry holds the runs of both, each read by its own."""
+    small = {"a": ("head", None), "s": ("attention", None)}
+    large = dict(KNOWN, big=("embed", None))
+    ops = [("a", 0, 10), ("s", 10, 30),                 # the small program
+           ("a", 100, 150), ("w", 150, 300), ("big", 300, 310),
+           ("a", 400, 410), ("s", 410, 430)]
+    runs = [("jit_prefill", 0, 30), ("jit_prefill", 100, 310),
+            ("jit_prefill", 400, 430)]
+    for known in ((small, large), (large, small)):
+        entry = _reduce({0: (ops, runs)}, known=known)[
+            "programs"][0]["jit_prefill"]
+        assert entry["runs"] == 3
+        assert entry["run_s"] == pytest.approx([30e-9, 210e-9, 30e-9])
+        assert entry["parts"] == pytest.approx({
+            "head": 20e-9, "attention": 40e-9 + 150e-9, "mlp": 50e-9,
+            "embed": 10e-9})
+        assert entry["unscoped_s"] == 0.0
+    # a registered program that also knows every instruction of a run and
+    # more besides does not take it from the one that ran
+    superset = dict(small, a=("norm", None), extra=("loss", None))
+    entry = _reduce({0: (ops[:2], runs[:1])}, known=(superset, small))[
+        "programs"][0]["jit_prefill"]
+    assert entry["parts"] == pytest.approx({"head": 10e-9,
+                                            "attention": 20e-9})
+
+
 def test_without_a_marker_the_window_is_the_events_and_none_is_empty():
     table = dt.reduce_events(
         {0: ([("a", 100, 200)], [("jit_p", 100, 200)])}, None,

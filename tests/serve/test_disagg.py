@@ -156,6 +156,38 @@ class TestBitExact:
             mono.shutdown()
             dec.shutdown()
 
+    @pytest.mark.parametrize("n", [12, 40])
+    def test_a_miss_pads_as_the_monolithic_admission_does(self, tiny, n):
+        """Both engines pad a miss to the smallest step of one admission
+        ladder (32 and the cap, 64, here) through one function: a prompt
+        under the step and one over it are handed off bit for bit."""
+        model, params, cfg = tiny
+        gen = Generator(model, params, cfg)
+        cap = gen.prompt_buckets[-1]
+        assert gen.admission_ladder(cap) == [32, 64]
+        prompt = np.random.default_rng(n).integers(
+            1, 60, n).astype(np.int32)
+        engines = [ContinuousBatchingEngine(gen, max_batch=2,
+                                            prompt_bucket=cap)]
+        try:
+            ref = engines[0].submit(prompt, GCFG)
+            # the ladder's two programs are compiled; the engines made
+            # from here on run them, and trace none
+            assert gen.prefill_traces == 2
+            dec = ContinuousBatchingEngine(gen, max_batch=2,
+                                           prompt_bucket=cap)
+            pe = disagg.PrefillEngine(gen, model="m", prompt_bucket=cap)
+            engines += [dec, pe]
+            assert pe._ladder == engines[0]._ladder == [32, 64]
+            art = disagg.KVHandoffArtifact.from_wire(
+                pe.prefill(prompt, GCFG).to_wire())
+            assert np.array_equal(np.asarray(ref),
+                                  np.asarray(disagg.ingest(dec, art)))
+            assert gen.prefill_traces == 2
+        finally:
+            for engine in engines:
+                engine.shutdown()
+
     def test_prefill_side_prefix_hits_accumulate(self, tiny):
         gen = _gen(tiny)
         pool = KVBlockPool.for_generator(gen, block_size=4,
