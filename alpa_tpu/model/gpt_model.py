@@ -162,6 +162,12 @@ class GPTConfig:
     # layer's experts are divided over several chips; the router stays
     # ``num_experts`` wide (``moe.DroplessExperts``).  None: all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # --- generation by diffusion over blocks (``model_type`` sdar_moe).
+    # Positions come in blocks of ``block_length``; a query sees every key
+    # of its own block and of the blocks before it (the later positions of
+    # its block too: ``reference_attention``'s ``block``), and the logit at
+    # a position predicts the token AT it.  0: the causal mask of today.
+    block_length: int = 0
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
@@ -248,7 +254,30 @@ _HF_KINDS = {
     "deepseek_v2": dict(norm="rmsnorm", positions="rotary",
                         attention="latent", rope_interleaved=True,
                         fused_gate_up=True),
+    # SDAR (JetLM): the Qwen3-MoE block (transformers'
+    # models/qwen3_moe/modeling_qwen3_moe.py) under a block-causal mask
+    "sdar_moe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
+                     fused_gate_up=True),
 }
+
+
+def _sdar_moe_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` sdar_moe says beyond the keys
+    all decoders share: which layers route (Qwen3-MoE's rule: layer i has
+    experts unless it is in ``mlp_only_layers`` or ``(i + 1) %
+    decoder_sparse_step``), the sizes of heads and experts.  The block
+    length is not in the file: a deployment states it (``block_length``)."""
+    if hf.get("use_sliding_window"):
+        raise ValueError("sdar_moe: use_sliding_window is not supported")
+    step, dense_only = hf["decoder_sparse_step"], hf["mlp_only_layers"]
+    return dict(
+        mlp=tuple("experts" if i not in dense_only and hf["num_experts"] > 0
+                  and (i + 1) % step == 0 else "gated"
+                  for i in range(hf["num_hidden_layers"])),
+        head_dim=hf["head_dim"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["num_experts"],
+        norm_topk_prob=hf["norm_topk_prob"])
 
 
 def _afmoe_fields(hf: dict) -> dict:
@@ -348,7 +377,8 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     dict), for the model types in ``_HF_KINDS``.  ``kwargs`` override what
     the file says (``seq_len``: the context a deployment serves, where it
     is less than the declared ``max_position_embeddings``;
-    ``experts_held``: this chip's share of the routed experts).  Of
+    ``experts_held``: this chip's share of the routed experts;
+    ``block_length``: the blocks a diffusion decoder generates in).  Of
     ``rope_scaling`` only deepseek_v2's ``yarn`` is known."""
     kinds = _HF_KINDS.get(hf["model_type"])
     if kinds is None:
@@ -374,6 +404,8 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         fields.update(_deepseek_v2_fields(hf))
     elif hf["model_type"] == "afmoe":
         fields.update(num_experts=hf["num_experts"], **_afmoe_fields(hf))
+    elif hf["model_type"] == "sdar_moe":
+        fields.update(_sdar_moe_fields(hf))
     else:
         fields.update(num_experts=hf["num_experts"],
                       norm_topk_prob=hf["norm_topk_prob"])
@@ -427,7 +459,7 @@ def apply_rotary(x, position_ids, theta: float, interleaved: bool = False,
 
 
 def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
-                        window: int = 0, k_positions=None):
+                        window: int = 0, k_positions=None, block: int = 0):
     """Plain einsum attention; XLA fuses this well on TPU for short seqs.
 
     q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D), H a multiple of Hkv: query head
@@ -444,7 +476,16 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
     only): the position each key holds where that is not its place in
     ``k`` (a ring cache: ``update_ring_cache``); a negative one holds
     nothing and is seen by no query.
+
+    ``block`` > 0 (causal only, no window and no ``k_positions``): the
+    positions come in blocks of ``block`` and a query at position p sees
+    the keys at positions ``< (p // block + 1) * block``: every key of its
+    own block, the later ones too, and of the blocks before it (generation
+    by diffusion over blocks, ``GPTConfig.block_length``).
     """
+    if block and (not causal or window or k_positions is not None):
+        raise ValueError("a block-causal mask goes with a causal mask over "
+                         "a full cache, not with a window or a ring")
     dim = q.shape[-1]
     b, sq, nh = q.shape[0], q.shape[1], q.shape[2]
     sk, nkv = k.shape[1], k.shape[2]
@@ -461,6 +502,11 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
         offset = jnp.asarray(offset, jnp.int32)
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+
+        def last_seen(pos):
+            """The last position a query at ``pos`` sees."""
+            return (pos // block + 1) * block - 1 if block else pos
+
         if k_positions is not None:
             # (1 or B, Sq, Sk): every row of keys has its own positions
             k_pos = jnp.broadcast_to(k_positions[:, None, :],
@@ -472,13 +518,13 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
                 mask &= q_pos - k_pos < window
             mask = mask[:, None]                             # (.,1,Sq,Sk)
         elif offset.ndim == 0:
-            mask = q_pos + offset >= k_pos
+            mask = last_seen(q_pos + offset) >= k_pos
             if window:
                 mask &= q_pos + offset - k_pos < window
             mask = mask[None, None]                          # (1,1,Sq,Sk)
         else:
             q_pos = q_pos[None] + offset[:, None, None]
-            mask = q_pos >= k_pos[None]
+            mask = last_seen(q_pos) >= k_pos[None]
             if window:
                 mask &= q_pos - k_pos[None] < window
             mask = mask[:, None]                             # (B,1,Sq,Sk)
@@ -869,6 +915,9 @@ class LatentAttention(nn.Module):
     def __call__(self, x, kv_cache=None, deterministic=True,
                  attn_bias=None, position_ids=None, cache_lengths=None):
         cfg = self.config
+        if cfg.block_length:
+            raise ValueError("latent attention has no block-causal mask "
+                             "(GPTConfig.block_length)")
         if attn_bias is not None or not cfg.causal or position_ids is None:
             raise ValueError("latent attention is causal over rotary "
                              "positions and takes no score bias (packed "
@@ -957,6 +1006,10 @@ class SelfAttention(nn.Module):
         # the scope of the attention core (the cache's update, scores,
         # softmax, values; not the projections): the benchmark finds its
         # device events by it (HLO metadata ``op_name``)
+        block = cfg.block_length
+        if block and window:
+            raise ValueError("a block-causal mask (GPTConfig.block_length) "
+                             "goes with full attention layers only")
         with jax.named_scope(ATTENTION_SCOPE):
             if kv_cache is not None and window:
                 if attn_bias is not None:
@@ -975,18 +1028,20 @@ class SelfAttention(nn.Module):
                 # attn_bias (e.g. the packed-prefill segment mask) rides on
                 # top of the causal mask over the full cache length
                 out = reference_attention(q, k_use, v_use, causal=True,
-                                          offset=index, bias=attn_bias)
-            elif attn_bias is not None or window or nkv != nh:
+                                          offset=index, bias=attn_bias,
+                                          block=block)
+            elif attn_bias is not None or window or nkv != nh or block:
                 # additive padding/score bias: encoder path only (the
                 # flash/ring kernels take no bias operand, no window and
                 # no grouped heads)
-                if (window or nkv != nh) and \
+                if (window or nkv != nh or block) and \
                         cfg.attention_impl != "reference":
                     raise ValueError(
-                        "sliding-window and grouped-query attention need "
-                        "attention_impl 'reference'")
+                        "sliding-window, grouped-query and block-causal "
+                        "attention need attention_impl 'reference'")
                 out = reference_attention(q, k, v, causal=cfg.causal,
-                                          bias=attn_bias, window=window)
+                                          bias=attn_bias, window=window,
+                                          block=block)
             else:
                 attn_fn = get_attention_fn(cfg)
                 out = attn_fn(q, k, v, causal=cfg.causal)
@@ -1252,6 +1307,21 @@ def require_uniform_kv_caches(config, what: str):
             "configuration's layers differ (sliding-window layers hold a "
             "ring of the window's positions, full layers the context): "
             f"{sorted(set(kv_cache_shapes(config, 1)))}")
+
+
+def require_one_token_steps(config, what: str):
+    """Raise if the configuration generates by diffusion over blocks:
+    ``what`` is built on one token a row a step (a step there yields
+    between none and ``block_length`` tokens a row, and writes a whole
+    block's keys and values whether or not it keeps them)."""
+    block = getattr(config, "block_length", 0)
+    if block:
+        raise ValueError(
+            f"{what} is built on one token a row a step, and this "
+            f"configuration generates by diffusion over blocks of {block} "
+            "positions (GPTConfig.block_length): serve it through "
+            "Generator.generate or a ContinuousBatchingEngine with "
+            "chunked_admission and nothing else")
 
 
 def init_kv_caches(config: GPTConfig, batch_size: int,

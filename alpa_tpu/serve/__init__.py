@@ -5,8 +5,8 @@ Analog of ref ``alpa/serve/`` + ``examples/llm_serving`` (SURVEY.md §2.8,
 autoregressive generation engine with resident KV caches compiled per
 (batch, length-bucket).
 """
-from alpa_tpu.serve.generation import (GenerationConfig, Generator,
-                                       PrefixHandle, get_model)
+from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
+                                       Generator, PrefixHandle, get_model)
 from alpa_tpu.serve.controller import (Controller, ControllerServer,
                                        RequestBatcher, run_controller)
 from alpa_tpu.serve.engine import ContinuousBatchingEngine

@@ -357,7 +357,12 @@ class PrefillEngine:
                  prompt_bucket: Optional[int] = None, model: str = "",
                  weights_tag: str = "", codec: Optional[str] = None,
                  max_retained: Optional[int] = None):
+        from alpa_tpu.model.gpt_model import require_one_token_steps
         from alpa_tpu.serve.kv_cache import KVBlockPool
+        # a handed-off row is a prompt's cache and its last logits, which
+        # the decode half samples its first token from
+        require_one_token_steps(generator.config, "disaggregated serving "
+                                "(serve/disagg.py PrefillEngine)")
         self.gen = generator
         self.model = model
         self.weights_tag = weights_tag

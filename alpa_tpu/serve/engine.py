@@ -25,8 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu import fault
-from alpa_tpu.model.gpt_model import init_kv_caches
+from alpa_tpu.model.gpt_model import init_kv_caches, require_one_token_steps
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
+                                       fresh_kv_caches, read_block,
                                        row_length, sample_rows)
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.telemetry import trace as _ttrace
@@ -85,6 +86,20 @@ _DECODE_POSITIONS = _REG.counter(
     "prompt and the tokens it has so far, the new one included), summed "
     "over the rows and the ticks: what an attention that reads of a cache "
     "what its row holds has to read, in positions")
+_BLOCK_FORWARDS = _REG.counter(
+    "alpa_serving_block_forwards_total",
+    "Forwards of a block by the active rows of an engine that generates "
+    "by diffusion over blocks (row-forwards: every tick is one a row), by "
+    "what the forward did: denoise (the block still held a mask) or "
+    "commit (it held none, and its keys and values stay)",
+    labelnames=("phase",))
+_BLOCK_UNMASKED = _REG.counter(
+    "alpa_serving_block_tokens_unmasked_total",
+    "Positions the active rows' denoising forwards decided (over "
+    "alpa_serving_block_forwards_total of both phases: tokens a forward)")
+_BLOCKS_COMMITTED = _REG.counter(
+    "alpa_serving_blocks_committed_total",
+    "Blocks the active rows committed to their caches")
 _KV_CACHE_BYTES = _REG.gauge(
     "alpa_serving_kv_cache_bytes",
     "Bytes of the engine's resident K and V caches, by the kind of the "
@@ -174,6 +189,28 @@ class ContinuousBatchingEngine:
     host knows which rows ended with this token: such a row is decoded
     once more, for nobody, and the next admission overwrites it whole
     (its cache row, index and logits), so the extra step is harmless.
+
+    A generator that generates by diffusion over blocks
+    (``Generator.diffusion``, ``GPTConfig.block_length`` L) gets another
+    tick in the same order (``_block_tick``): every tick is ONE forward of
+    a whole block a row through the generator's one ``_block_step``,
+    whatever the rows' phases, which the program decides from the ids (a
+    block that still holds a mask is denoised, one that holds none is
+    committed to the cache and the row goes on to its next block).  The
+    rows' blocks (B, L) and their budgets stay on the device between two
+    forwards: the tick enqueues the step on the previous step's own
+    output, then reads back the ids it has just handed in, which are what
+    the previous step made of them, and delivers a block's tokens in
+    position order at the tick that first reads the block without a mask
+    (the tick that enqueues its commit).  So a tick yields between none
+    and L tokens a row.  An admission prefills the prompt's whole blocks
+    in chunks and scatters the row as ever; the prompt's last ``len % L``
+    tokens are the fixed head of the row's first block.  A request ends
+    inside a block with exactly what it asked for.  The step donates the
+    K and V of the resident caches as the decode does.  Such an engine
+    needs ``chunked_admission`` and refuses, by name, what is built on one
+    token a row a step: ``kv_pool``, ``packed_admission``, a static
+    ``prefix`` and a prefilled (disaggregated) admission.
     """
 
     def __init__(self, generator: Generator, max_batch: int = 4,
@@ -235,6 +272,19 @@ class ContinuousBatchingEngine:
         if chunked_admission and not generator.prefill_chunk:
             raise ValueError("chunked admission requires "
                              "Generator(prefill_chunk=...)")
+        # generation by diffusion over blocks: the other tick
+        self._blocks = getattr(generator, "diffusion", None) is not None
+        if self._blocks:
+            for what, given in (("kv_pool (KVBlockPool)", kv_pool),
+                                ("packed_admission", packed_admission),
+                                ("a static prefix", prefix)):
+                if given:
+                    require_one_token_steps(generator.config, what)
+            if not chunked_admission:
+                raise ValueError(
+                    "an engine over a generator that generates by "
+                    "diffusion over blocks admits in chunks: "
+                    "chunked_admission=True")
         self._chunked = chunked_admission
         self.gen = generator
         self.B = max_batch
@@ -355,6 +405,13 @@ class ContinuousBatchingEngine:
 
         self._scatter_packed = jax.jit(scatter_packed,
                                        donate_argnums=(0, 2))
+
+        def set_block(blocks, left, row, block):
+            return (blocks.at[row].set(block),
+                    left.at[row].set(generator.denoising_steps))
+
+        # an admitted row's first block and a fresh budget
+        self._set_block = jax.jit(set_block, donate_argnums=(0, 1))
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -458,6 +515,14 @@ class ContinuousBatchingEngine:
         # the decode's logits as it returns them (every family computes
         # them in the configuration's dtype); sample_rows casts to float32
         self._logits = jnp.zeros((self.B, cfgm.vocab_size), cfgm.dtype)
+        if self._blocks:
+            # every row's block and what is left of its budget: all
+            # masks, as a row that has just committed
+            self._block_ids = jnp.full(
+                (self.B, cfgm.block_length),
+                self.gen.diffusion.mask_token_id, jnp.int32)
+            self._left = jnp.full((self.B,), self.gen.denoising_steps,
+                                  jnp.int32)
 
     def _fail_active_locked(self, err):
         """Fail every resident request with ``err`` and free its row."""
@@ -475,8 +540,11 @@ class ContinuousBatchingEngine:
         fails later leaves them deleted, and with them every resident
         row: those requests fail with ``err`` and the engine goes on with
         fresh caches."""
-        if self._logits.is_deleted() or any(
-                k.is_deleted() or v.is_deleted() for k, v, _ in self._caches):
+        gone = [self._logits] + [a for k, v, _ in self._caches
+                                 for a in (k, v)]
+        if self._blocks:
+            gone += [self._block_ids, self._left]
+        if any(a.is_deleted() for a in gone):
             self._fail_active_locked(err)
             self._init_resident()
 
@@ -486,6 +554,20 @@ class ContinuousBatchingEngine:
         cfg = cfg or GenerationConfig()
         seq_len = self.gen.config.seq_len
         plen = self._prefix.length if self._prefix is not None else 0
+        if self._blocks:
+            if prefilled is not None:
+                require_one_token_steps(self.gen.config,
+                                        "a prefilled (disaggregated) "
+                                        "admission")
+            # refuses a prompt that holds the mask id
+            self.gen.first_block(prompt)
+            if self.gen.blocks_end(len(prompt), cfg.max_new_tokens) > \
+                    seq_len:
+                raise ValueError(
+                    f"prompt {len(prompt)} + max_new_tokens "
+                    f"{cfg.max_new_tokens}, in blocks of "
+                    f"{self.gen.config.block_length}, exceeds seq_len "
+                    f"{seq_len}")
         if prefilled is not None and self._prefix is not None:
             raise ValueError(
                 "prefilled admission is incompatible with a static "
@@ -722,6 +804,26 @@ class ContinuousBatchingEngine:
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p], total, 1, caches=h.caches,
                             start=h.length, init_last=h.last_logits)
+                    elif self._blocks:
+                        # the prompt's whole blocks; what is left of it
+                        # heads the row's first block
+                        asked, block = self.gen.first_block(p)
+                        path = "chunked"
+                        padded = self._chunk_padded(asked)
+                        if asked:
+                            logits1, caches1 = \
+                                self.gen._run_chunked_prefill(
+                                    [p[:asked]], row_length(asked), 1)
+                        else:
+                            logits1 = self._logits[:1]
+                            caches1 = [(k, v, row_length(0)) for k, v, _
+                                       in fresh_kv_caches(self.gen.config,
+                                                          1)]
+                        self._block_ids, self._left = self._set_block(
+                            self._block_ids, self._left, r,
+                            jnp.asarray(block))
+                        item.update(block=None, skip=len(p) - asked,
+                                    held=asked)
                     elif self._chunked:
                         path, asked = "chunked", len(p)
                         padded = self._chunk_padded(asked)
@@ -818,8 +920,8 @@ class ContinuousBatchingEngine:
             try:
                 with _phase(rec, "engine.decode-tick",
                             {"active": int(self._active.sum())}
-                            if rec is not None else None):
-                    self._step(rec)
+                            if rec is not None else None) as tick_span:
+                    self._step(rec, tick_span)
             except Exception as e:  # pylint: disable=broad-except
                 logger.exception("engine step failed")
                 self.step_failures += 1
@@ -828,13 +930,120 @@ class ContinuousBatchingEngine:
                     self._fail_active_locked(e)
                     self._recover_resident_locked(e)
 
-    def _step(self, rec=None):
+    def _count_routing(self, routing):
+        """What a step said of its routed layers, into the counters."""
+        held = getattr(self.gen.config, "experts_held", None)
+        for layer in routing.get("experts", ()):
+            _ROUTED_ROWS.inc(layer.size)
+            if held is not None:
+                # this program's share of the layer's experts
+                layer = layer[(layer >= held[0]) &
+                              (layer < held[0] + held[1])]
+                _LOCAL_ROWS.inc(layer.size)
+            _EXPERTS_TOUCHED.inc(len(np.unique(layer)))
+
+    def _finish_row(self, r: int, item: dict):
+        """Row ``r``'s request is over: the row is free."""
+        self._release_table(r, item)
+        item["done"].set()
+        self._active[r] = False
+        self._rows[r] = None
+
+    def _deliver_token(self, item: dict, t: int) -> bool:
+        """One token to its request; whether it was the request's last."""
+        cfg = item["cfg"]
+        item["tokens"].append(t)
+        _TOKENS.inc()
+        if len(item["tokens"]) == 1 and "t_submit" in item:
+            _TTFT.observe(time.monotonic() - item["t_submit"])
+        if item.get("on_token") is not None:
+            try:
+                item["on_token"](t)
+            except Exception:  # pylint: disable=broad-except
+                logger.exception("on_token callback failed")
+        return (cfg.eos_token_id is not None and t == cfg.eos_token_id) or \
+            len(item["tokens"]) >= cfg.max_new_tokens
+
+    def _block_tick(self, rec, tick_span):
+        """One forward of a whole block for every row (class docstring):
+        dispatch, wait, deliver, as ``_step`` has them.  What the host
+        reads back are the ids it has just handed to the step, which is
+        what the previous step made of every row's block (and what an
+        admission since wrote): a row's phase in the previous step, what
+        that unmasked and whether the block is finished all follow from
+        the row's block as the host read it a tick ago."""
+        gen = self.gen
+        mask, length = gen.diffusion.mask_token_id, gen.config.block_length
+        with _phase(rec, "engine.dispatch"):
+            ids = self._block_ids
+            routing = self._routing
+            (self._block_ids, self._left, _unmasked, _commits, _logits,
+             self._caches, self._routing, self._key) = gen._block_step(
+                 gen.params, ids, self._caches[0][2], self._caches,
+                 self._left, self._settings, self._key)
+        self.decode_steps += 1
+        _DECODE_STEPS.inc()
+        with _phase(rec, "engine.wait"):
+            # behind the enqueue: waits for the previous step and for any
+            # prefill and scatter behind it, not for this one
+            now, routing = jax.device_get((ids, routing))
+            self._count_routing(routing)
+        with self._cv:
+            with _phase(rec, "engine.deliver") as deliver_span:
+                delivered = positions = 0
+                denoising = committing = unmasked = 0
+                for r in range(self.B):
+                    if not self._active[r]:
+                        continue
+                    item = self._rows[r]
+                    was, item["block"] = item["block"], now[r]
+                    over = bool(item.get("cancelled"))
+                    # was None: admitted since the last tick, and the
+                    # step just enqueued is its first
+                    denoised, decided, finished = (False, (), False) \
+                        if was is None else read_block(was, now[r], mask)
+                    if denoised:
+                        denoising += 1
+                        unmasked += int(decided.sum())
+                    elif was is not None:
+                        committing += 1
+                        item["held"] += length
+                    if finished:
+                        # the block's tokens in order, but for the
+                        # prompt's in the first block
+                        for t in now[r][item["skip"]:]:
+                            delivered += 1
+                            if self._deliver_token(item, int(t)):
+                                over = True
+                                break
+                        item["skip"] = 0
+                    # what the step just enqueued attends over for row r
+                    positions += item["held"] + length
+                    if over:
+                        self._finish_row(r, item)
+                _BLOCK_FORWARDS.labels("denoise").inc(denoising)
+                _BLOCK_FORWARDS.labels("commit").inc(committing)
+                _BLOCKS_COMMITTED.inc(committing)
+                _BLOCK_UNMASKED.inc(unmasked)
+                _DECODE_POSITIONS.inc(positions)
+                if rec is not None:
+                    deliver_span.args = {"tokens": delivered}
+                    tick_span.args.update(denoising=denoising,
+                                          committing=committing,
+                                          unmasked=unmasked)
+            # refill freed rows before the next tick
+            self._admit_locked(rec)
+            _ACTIVE_ROWS.set(int(self._active.sum()))
+
+    def _step(self, rec=None, tick_span=None):
         """One decode tick for every active row.  ``rec``: see ``_phase``;
         the tick's phases are child spans of ``engine.decode-tick``, in
         the order sample, dispatch, wait, deliver: the sampled tokens go
         from one device program into the next, and the host reads them
         only once the decode that consumes them is enqueued (why that is
-        safe for a row that ends with this token: the class docstring)."""
+        safe for a row that ends with this token: the class docstring).
+        ``tick_span``: the tick's own span, for what only the read-back
+        tells of it."""
         fault.fire("scheduler_tick", step=self.decode_steps,
                    active=int(self._active.sum()))
         if self._settings is None:
@@ -845,6 +1054,9 @@ class ContinuousBatchingEngine:
                 (self._do_sample, self._temperature, self._top_k)))
         sampling = int((self._do_sample & self._active).sum())
         _SAMPLE_TICKS.labels("sampled" if sampling else "greedy").inc()
+        if self._blocks:
+            # the sampling is a part of the block step's own program
+            return self._block_tick(rec, tick_span)
         with _phase(rec, "engine.sample",
                     {"rows": sampling} if rec is not None else None):
             tokens, self._key = self._sample_rows(
@@ -865,15 +1077,7 @@ class ContinuousBatchingEngine:
             # sampling began, so nothing more is waited for
             nxt, routing = jax.device_get((tokens, routing))
             nxt = nxt[:, 0]
-            held = getattr(self.gen.config, "experts_held", None)
-            for layer in routing.get("experts", ()):
-                _ROUTED_ROWS.inc(layer.size)
-                if held is not None:
-                    # this program's share of the layer's experts
-                    layer = layer[(layer >= held[0]) &
-                                  (layer < held[0] + held[1])]
-                    _LOCAL_ROWS.inc(layer.size)
-                _EXPERTS_TOUCHED.inc(len(np.unique(layer)))
+            self._count_routing(routing)
         if self._pool is not None:
             # the tick wrote each row's new K/V at its pre-decode index;
             # mirror those positions into the block pool (rows without a
@@ -888,28 +1092,12 @@ class ContinuousBatchingEngine:
                     if not self._active[r]:
                         continue
                     item = self._rows[r]
-                    cfg = item["cfg"]
-                    t = int(nxt[r])
-                    item["tokens"].append(t)
+                    over = self._deliver_token(item, int(nxt[r]))
                     delivered += 1
                     # what the decode just enqueued attends over for row r
                     positions += len(item["prompt"]) + len(item["tokens"])
-                    _TOKENS.inc()
-                    if len(item["tokens"]) == 1 and "t_submit" in item:
-                        _TTFT.observe(time.monotonic() - item["t_submit"])
-                    if item.get("on_token") is not None:
-                        try:
-                            item["on_token"](t)
-                        except Exception:  # pylint: disable=broad-except
-                            logger.exception("on_token callback failed")
-                    hit_eos = (cfg.eos_token_id is not None and
-                               t == cfg.eos_token_id)
-                    if (hit_eos or item.get("cancelled") or
-                            len(item["tokens"]) >= cfg.max_new_tokens):
-                        self._release_table(r, item)
-                        item["done"].set()
-                        self._active[r] = False
-                        self._rows[r] = None
+                    if over or item.get("cancelled"):
+                        self._finish_row(r, item)
                 _DECODE_POSITIONS.inc(positions)
                 if rec is not None:
                     deliver_span.args = {"tokens": delivered}

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, init_kv_caches,
+                                      require_one_token_steps,
                                       require_uniform_kv_caches,
                                       uniform_kv_caches)
 from alpa_tpu.telemetry import device_time
@@ -51,6 +52,38 @@ class GenerationConfig:
     top_k: int = 0           # 0 = no top-k filtering
     do_sample: bool = False
     eos_token_id: Optional[int] = None
+
+
+# the two rules by which a denoising forward chooses what it unmasks
+REMASKING = ("low_confidence_static", "low_confidence_dynamic")
+# the scope the unmasking rule of a block step is traced under (a capture
+# reads it: telemetry/device_time.py)
+UNMASK_SCOPE = "unmask"
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """How a configuration with ``GPTConfig.block_length`` L generates
+    (diffusion over blocks: ``Generator``'s class docstring): the id that
+    stands for a position not yet decided, how many denoising forwards a
+    block may take (None: L, one position a forward), and the rule by
+    which a forward chooses what it unmasks of the ``m`` positions still
+    masked with ``r`` forwards of the budget left.
+    ``low_confidence_static``: the ``ceil(m / r)`` of highest confidence.
+    ``low_confidence_dynamic``: every masked position whose confidence is
+    over ``threshold``, and at least the static quota.  Ties go to the
+    lower position."""
+    mask_token_id: int
+    denoising_steps: Optional[int] = None
+    remasking: str = "low_confidence_dynamic"
+    threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.remasking not in REMASKING:
+            raise ValueError(f"unknown remasking {self.remasking!r} "
+                             f"(known: {REMASKING})")
+        if self.denoising_steps is not None and self.denoising_steps < 1:
+            raise ValueError("denoising_steps must be at least 1")
 
 
 # The ONE top-k mask value, shared by the device sampler and the
@@ -102,6 +135,71 @@ def sample_rows(logits, key, do_sample, temperature, top_k):
     tokens, key = jax.lax.cond(jnp.any(do_sample), draw,
                                lambda key: (greedy, key), key)
     return tokens.astype(jnp.int32)[:, None], key
+
+
+def sample_positions(logits, key, do_sample, temperature, top_k):
+    """``sample_rows`` for the ``L`` positions of a block a row: ``logits``
+    (B, L, V), every position of a row drawn under the row's own settings
+    ((B,) each).  Returns ``(x0 int32 (B, L), confidence float32 (B, L),
+    key)``: the confidence of a position is ``softmax(logits / T)[x0]``,
+    ``T`` the row's temperature where it samples and 1 where it takes the
+    argmax (``top_k`` narrows the draw, not the confidence)."""
+    b, l, v = logits.shape
+    tokens, key = sample_rows(
+        logits.reshape(b * l, v), key, jnp.repeat(do_sample, l),
+        jnp.repeat(temperature, l), jnp.repeat(top_k, l))
+    x0 = tokens.reshape(b, l)
+    scaled = logits.astype(jnp.float32) / jnp.where(
+        do_sample, jnp.maximum(temperature, 1e-6), 1.0)[:, None, None]
+    chosen = jnp.take_along_axis(scaled, x0[..., None], axis=-1)[..., 0]
+    confidence = jnp.exp(chosen - jax.nn.logsumexp(scaled, axis=-1))
+    return x0, confidence, key
+
+
+def unmask_quota(masked_count, forwards_left):
+    """How many positions a denoising forward must unmask at least: the
+    ``m`` masked spread evenly over the ``r`` forwards left,
+    ``ceil(m / r)`` (a full block of 4 in 2 forwards: 2, 2; a block with 3
+    masked: 2, 1).  Integers or integer arrays."""
+    r = jnp.maximum(forwards_left, 1)
+    return -(-masked_count // r)
+
+
+def choose_unmasked(masked, confidence, forwards_left, remasking: str,
+                    threshold: float):
+    """Which positions a denoising forward unmasks: ``masked`` bool (B, L),
+    ``confidence`` float32 (B, L), ``forwards_left`` int32 (B,) of the
+    block's budget, this forward included.  Static rule: the
+    ``unmask_quota`` masked positions of highest confidence, ties to the
+    lower position; dynamic: those, and every masked position whose
+    confidence is over ``threshold``.  A position that is not masked is
+    never chosen.  bool (B, L)."""
+    quota = unmask_quota(masked.sum(-1), forwards_left)
+    conf = jnp.where(masked, confidence, -jnp.inf)
+    at = jnp.arange(masked.shape[-1])
+    # a position's rank: how many positions go before it
+    before = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None]) & (at[None, :] < at[:, None]))
+    take = masked & (before.sum(-1) < quota[:, None])
+    if remasking == "low_confidence_dynamic":
+        take |= masked & (confidence > threshold)
+    elif remasking != "low_confidence_static":
+        raise ValueError(f"unknown remasking {remasking!r}")
+    return take
+
+
+def read_block(was, now, mask: int):
+    """What a forward made of one row's block, from the host's copies of
+    the block as the forward met it (``was``) and as it left it (``now``),
+    (L,) ids each: ``(denoised, unmasked, finished)``.  ``denoised``: the
+    block still held a mask, so the forward was a denoising one (else it
+    committed the block, and ``now`` is the row's next block);
+    ``unmasked`` (L,) bool: the positions it decided; ``finished``: it
+    decided the last of them, and the block's tokens can go out."""
+    was_masked, now_masked = was == mask, now == mask
+    denoised = bool(was_masked.any())
+    return (denoised, was_masked & ~now_masked,
+            denoised and not now_masked.any())
 
 
 def _warp_probs_np(logits, cfg: GenerationConfig) -> np.ndarray:
@@ -184,9 +282,9 @@ def _jit_registered(fun, **jit_kwargs):
 
 
 def _jit_donating_kv(step):
-    """``jax.jit`` of a cached step ``step(params, tokens, index, caches)``
-    that donates the K and V arrays of ``caches`` (``[(k, v, index)]`` a
-    layer), so that the step writes the resident cache in place, and not
+    """``jax.jit`` of a cached step ``step(params, tokens, index, caches,
+    *more)`` that donates the K and V arrays of ``caches`` (``[(k, v,
+    index)]`` a layer), so that the step writes the resident cache in place, and not
     their indices: a caller may pass one index array as ``index`` and in
     every layer's triple, and may read it after the call.
 
@@ -197,19 +295,19 @@ def _jit_donating_kv(step):
     each layer's output would land in another layer's buffer at the price
     of a copy of every cache."""
 
-    def split(params, tokens, index, kv, idxs):
+    def split(params, tokens, index, kv, idxs, *more):
         return step(params, tokens, index,
-                    [(k, v, i) for (k, v), i in zip(kv, idxs)])
+                    [(k, v, i) for (k, v), i in zip(kv, idxs)], *more)
 
     # the compiled program keeps the step's name (``jit_decode``): traces
     # and the benchmark's readers find it by that
     split.__name__ = split.__qualname__ = step.__name__
     jitted = _jit_registered(split, donate_argnums=(3,))
 
-    def call(params, tokens, index, caches):
+    def call(params, tokens, index, caches, *more):
         return jitted(params, tokens, index,
                       [(k, v) for k, v, _ in caches],
-                      [i for _, _, i in caches])
+                      [i for _, _, i in caches], *more)
 
     call.jitted = jitted
     return call
@@ -295,13 +393,48 @@ class Generator:
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
     every routed-expert layer, and ``{}`` (no output of the compiled
     program) for a configuration without such layers.
+
+    **Generation by diffusion over blocks** (a configuration with
+    ``block_length`` L, and ``diffusion=BlockDiffusion(...)``).  The
+    sequence is the prompt followed by masks up to a multiple of L; the
+    prompt's first ``(len // L) * L`` positions are prefilled (by the same
+    ``_prefill`` / ``_chunk_prefill``, under the block-causal mask) and its
+    last ``len % L`` tokens are the fixed head of the first generated
+    block.  A third compiled step, ``_block_step(params, ids, index,
+    caches, left, settings, key)``, is ONE forward of a whole block a row,
+    whatever the rows' phases, donating K and V as ``_decode`` does: ``ids``
+    (rows, L) are the rows' blocks (the mask id where a position is not
+    decided), ``index`` (rows,) the blocks' first positions, ``left``
+    (rows,) what is left of each block's budget of ``denoising_steps``
+    forwards, ``settings`` the rows' ``(do_sample, temperature, top_k)``.
+    Inside the program: a row whose block holds no mask COMMITS (decided
+    from the ids, on the device); the model runs once on (rows, L) at the
+    positions ``index + 0..L-1`` and writes the block's keys and values
+    there in every forward (a later forward of the same block overwrites
+    them), but the index advances by L only in committing rows; under
+    ``jax.named_scope("unmask")`` every position draws ``x0`` under its
+    row's settings with the confidence ``softmax(logits / T)[x0]``
+    (``sample_positions``), the rule chooses what a denoising row unmasks
+    (``choose_unmasked``), and a committing row's next block comes out all
+    masks with a fresh budget.  It returns ``(ids, left, unmasked, commits,
+    logits, caches, routing, key)``: the new blocks and budgets, which
+    positions were unmasked (rows, L) and which rows committed (rows,),
+    the logits at every position (rows, L, V), ``routing`` for rows x L
+    tokens.  ``decode_traces`` counts one trace of it whatever the rows do.
+    ``generate`` runs it for its batch (``generate_blocks``), so that a
+    request alone and the same request among an engine's rows run the same
+    program.  What is built on one token a row a step refuses such a
+    configuration by name (``require_one_token_steps``):
+    ``generate_speculative``, ``generate_beam``, ``cache_prefix`` (a static
+    prefix), the KV block pool, the packed prefill, ``serve/disagg.py``.
     """
 
     def __init__(self, model: GPTModel, params, config: GPTConfig,
                  batch_size: int = 1,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  parallel_method: Optional[Any] = None,
-                 prefill_chunk: Optional[int] = None):
+                 prefill_chunk: Optional[int] = None,
+                 diffusion: Optional[BlockDiffusion] = None):
         """``parallel_method``: optional alpa_tpu ParallelMethod for the
         prefill/decode executables — e.g. ``PipeshardParallel(
         pipeline_schedule="inference")`` with a layer-marked model config
@@ -314,7 +447,29 @@ class Generator:
         step serves every prompt length (no bucket ladder, no per-bucket
         compiles; the long-context serving mode).  Positions enter via
         the cache write index, so it applies to every decoder family.
+
+        ``diffusion``: the settings of generation by diffusion over
+        blocks, which a configuration with ``block_length`` needs and no
+        other takes (``BlockDiffusion``).
         """
+        block = getattr(config, "block_length", 0)
+        if bool(block) != (diffusion is not None):
+            raise ValueError(
+                "a configuration with block_length generates by diffusion "
+                "over blocks and needs Generator(diffusion="
+                "BlockDiffusion(mask_token_id=...)); no other takes one")
+        if block:
+            if parallel_method is not None:
+                raise ValueError("generation by diffusion over blocks has "
+                                 "no parallel_method block step")
+            if prefill_chunk and prefill_chunk % block:
+                raise ValueError(
+                    f"prefill_chunk {prefill_chunk} is no multiple of the "
+                    f"block length {block}: a block would straddle two "
+                    "chunks and see half of itself")
+            if not 0 <= diffusion.mask_token_id < config.vocab_size:
+                raise ValueError("mask_token_id lies outside the vocabulary")
+        self.diffusion = diffusion
         self.model = model
         self.params = params
         self.config = config
@@ -390,6 +545,47 @@ class Generator:
             sel = logits[jnp.arange(b), jnp.clip(off, 0, c - 1)]
             last = jnp.where(hit[:, None], sel, last)
             return last, caches
+
+        def block_step(params, ids, index, caches, left, settings, key):
+            """One forward of a whole block a row (class docstring)."""
+            self.decode_traces += 1
+            b, l = ids.shape
+            masked = ids == diffusion.mask_token_id
+            # a row whose block holds no mask commits it
+            commits = ~masked.any(-1)
+            pos = index[:, None] + jax.lax.broadcasted_iota(
+                jnp.int32, (b, l), 1)
+            if routed:
+                logits, caches, routing = model.apply(
+                    params, ids, pos, caches, return_routing=True)
+            else:
+                logits, caches = model.apply(params, ids, pos, caches)
+                routing = {}
+            with jax.named_scope(UNMASK_SCOPE):
+                x0, confidence, key = sample_positions(logits, key,
+                                                       *settings)
+                unmasked = choose_unmasked(
+                    masked, confidence, left, diffusion.remasking,
+                    diffusion.threshold)
+                ids = jnp.where(unmasked, x0, ids)
+                # a committing row goes on to its next block: all masks,
+                # a fresh budget
+                ids = jnp.where(commits[:, None],
+                                jnp.int32(diffusion.mask_token_id), ids)
+                left = jnp.where(commits, jnp.int32(self.denoising_steps),
+                                 jnp.maximum(left - 1, 1))
+            # the block's keys and values were written at its positions;
+            # only a commit keeps them (the next forward of a block that
+            # denoises on writes the same positions again)
+            index = jnp.where(commits, index + l, index)
+            caches = [(k, v, index) for (k, v, _i) in caches]
+            return (ids, left, unmasked, commits, logits, caches, routing,
+                    key)
+
+        #: denoising forwards a block may take (its budget)
+        self.denoising_steps = (diffusion.denoising_steps or block) \
+            if block else 0
+        self._block_step = _jit_donating_kv(block_step) if block else None
 
         if parallel_method is not None:
             import alpa_tpu
@@ -469,6 +665,8 @@ class Generator:
         """Precompute KV for a shared prefix (system prompt caching).
         Chunked mode only — the chunk step is what lets suffixes resume
         at an arbitrary cache offset with one compile."""
+        require_one_token_steps(self.config, "a static prefix "
+                                "(cache_prefix)")
         if not self.prefill_chunk:
             raise ValueError(
                 "cache_prefix requires Generator(prefill_chunk=...)")
@@ -587,6 +785,15 @@ class Generator:
                 arr = arr[None]
             prompts = list(arr)
         b = len(prompts)
+        if self.diffusion is not None:
+            if prefix is not None:
+                require_one_token_steps(self.config, "a static prefix")
+            rows = self.generate_blocks(prompts, cfg, rng)[0]
+            outs = [np.concatenate([p, np.asarray(row, np.int32)])
+                    for p, row in zip(prompts, rows)]
+            if len({len(o) for o in outs}) == 1:
+                return np.stack(outs)
+            return outs
         plen = 0
         if prefix is not None:
             if not self.prefill_chunk:
@@ -657,6 +864,104 @@ class Generator:
         return outs
 
 
+    def first_block(self, prompt: np.ndarray) -> tuple:
+        """``(prefilled, block)`` of a prompt under diffusion over blocks
+        of L: the first ``(len // L) * L`` positions are prefilled, and the
+        prompt's last ``len % L`` tokens are the fixed head of the first
+        generated block, ``block`` (L,) int32 with masks behind them.  A
+        prompt that holds the mask id is refused."""
+        l, mask = self.config.block_length, self.diffusion.mask_token_id
+        prompt = np.asarray(prompt, np.int32).reshape(-1)
+        if (prompt == mask).any():
+            raise ValueError(
+                f"the prompt holds the mask token id {mask}, which stands "
+                "for a position not yet decided: it cannot be told from "
+                "one")
+        prefilled = len(prompt) // l * l
+        block = np.full((l,), mask, np.int32)
+        block[:len(prompt) - prefilled] = prompt[prefilled:]
+        return prefilled, block
+
+    def blocks_end(self, prompt_len: int, max_new_tokens: int) -> int:
+        """The position past the last block a request of ``max_new_tokens``
+        writes: the cache must hold it."""
+        l = self.config.block_length
+        return -(-(prompt_len + max_new_tokens) // l) * l
+
+    def sampling_settings(self, rows: int, cfg: GenerationConfig):
+        """``cfg``'s sampling settings as the arrays a block step takes
+        them in, one entry a row."""
+        return (jnp.full((rows,), cfg.do_sample, bool),
+                jnp.full((rows,), cfg.temperature, jnp.float32),
+                jnp.full((rows,), cfg.top_k, jnp.int32))
+
+    def generate_blocks(self, prompts, cfg: GenerationConfig, rng=None):
+        """Generation by diffusion over blocks for a batch of prompts (1-D
+        int arrays): the prompts' whole blocks prefilled, then the one
+        compiled ``_block_step`` forward after forward, every row in its
+        own phase, until every row has its ``max_new_tokens`` (or its EOS).
+        Returns ``(tokens, forwards)``, a list a row each: the generated
+        tokens in position order, and the number of the forward (from 1, a
+        row's denoising and committing forwards alike) that unmasked each
+        of them."""
+        l, mask = self.config.block_length, self.diffusion.mask_token_id
+        rng = rng if rng is not None else jax.random.PRNGKey(0)
+        prompts = [np.asarray(p, np.int32).reshape(-1) for p in prompts]
+        b = len(prompts)
+        firsts = [self.first_block(p) for p in prompts]
+        for p in prompts:
+            if self.blocks_end(len(p), cfg.max_new_tokens) > \
+                    self.config.seq_len:
+                raise ValueError(
+                    f"prompt {len(p)} + max_new_tokens "
+                    f"{cfg.max_new_tokens}, in blocks of {l}, exceeds "
+                    f"seq_len {self.config.seq_len}")
+        lengths = jnp.asarray([n for n, _ in firsts], jnp.int32)
+        heads = [p[:n] for p, (n, _) in zip(prompts, firsts)]
+        if not any(n for n, _ in firsts):
+            caches = [(k, v, lengths) for k, v, _ in
+                      fresh_kv_caches(self.config, b)]
+        elif self.prefill_chunk:
+            _, caches = self._run_chunked_prefill(heads, lengths, b)
+        else:
+            _, caches = self._run_bucketed_prefill(heads, lengths, b)
+        ids = jnp.asarray(np.stack([block for _, block in firsts]))
+        left = jnp.full((b,), self.denoising_steps, jnp.int32)
+        settings = self.sampling_settings(b, cfg)
+        # the host's copy of every row's block, the positions of the first
+        # block that are the prompt's, and what came out so far
+        held = [block.copy() for _, block in firsts]
+        skip = [len(p) - n for p, (n, _) in zip(prompts, firsts)]
+        since = [np.zeros((l,), np.int64) for _ in range(b)]
+        tokens = [[] for _ in range(b)]
+        forwards = [[] for _ in range(b)]
+        done = [cfg.max_new_tokens <= 0] * b
+        n = 0
+        while not all(done):
+            n += 1
+            ids, left, _unmasked, _commits, _logits, caches, _routing, \
+                rng = self._block_step(self.params, ids, caches[0][2],
+                                       caches, left, settings, rng)
+            now = np.asarray(ids)
+            for r in range(b):
+                if done[r]:
+                    continue
+                was, held[r] = held[r], now[r]
+                _denoised, unmasked, finished = read_block(was, now[r], mask)
+                since[r][unmasked] = n
+                if not finished:
+                    continue
+                # the block holds no mask any more: its tokens, in order
+                for t, at in zip(now[r][skip[r]:], since[r][skip[r]:]):
+                    tokens[r].append(int(t))
+                    forwards[r].append(int(at))
+                    if len(tokens[r]) >= cfg.max_new_tokens or \
+                            t == cfg.eos_token_id:
+                        done[r] = True
+                        break
+                skip[r] = 0
+        return tokens, forwards
+
     def generate_speculative(self,
                              draft: "Generator",
                              input_ids,
@@ -684,6 +989,8 @@ class Generator:
         # a rejected round is rolled back by resetting ONE index, which a
         # ring that the rejected tokens were written into does not undo
         for gen in (self, draft):
+            require_one_token_steps(gen.config, "speculative decoding "
+                                    "(generate_speculative)")
             require_uniform_kv_caches(gen.config,
                                       "the speculative verify step")
         cfg = generation_config or GenerationConfig()
@@ -843,6 +1150,7 @@ class Generator:
         ``get_index_select_mesh_executable`` beam-cache reordering
         (ref mesh_executable.py:1168 / wrapper.py:20).
         """
+        require_one_token_steps(self.config, "beam search (generate_beam)")
         require_uniform_kv_caches(self.config, "beam search")
         input_ids = jnp.asarray(input_ids, jnp.int32)
         if input_ids.ndim == 1:

@@ -44,6 +44,7 @@ import numpy as np
 
 from alpa_tpu.global_env import global_config
 from alpa_tpu.model.gpt_model import (init_kv_caches,
+                                      require_one_token_steps,
                                       require_uniform_kv_caches)
 from alpa_tpu.telemetry import metrics as _tmetrics
 
@@ -149,6 +150,8 @@ class KVBlockPool:
         # per-layer pool arrays mirror the engine cache convention via
         # the same init used for the dense caches (works for any family
         # honoring the (k, v, index) contract)
+        # write_tokens mirrors ONE position a row a tick
+        require_one_token_steps(config, "the KV block pool (KVBlockPool)")
         require_uniform_kv_caches(config, "the KV block pool")
         template = init_kv_caches(config, 1)
         self._kp, self._vp = [], []

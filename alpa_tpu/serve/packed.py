@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import GPTConfig, require_uniform_kv_caches
+from alpa_tpu.model.gpt_model import (GPTConfig, require_one_token_steps,
+                                      require_uniform_kv_caches)
 from alpa_tpu.serve.generation import fresh_kv_caches
 
 logger = logging.getLogger(__name__)
@@ -79,6 +80,8 @@ class PackedPrefill:
         offset ``prefix.length``: every segment attends to the prefix
         K/V plus its own span, positions continue from the prefix, and
         the per-row re-gather lays each row out as [prefix | suffix]."""
+        require_one_token_steps(config, "the packed prefill "
+                                "(packed_admission)")
         require_uniform_kv_caches(config, "the packed prefill")
         self.model = model
         self.params = params

@@ -123,6 +123,9 @@ _COMPONENTS = {
     "grouped_matmul": "moe.grouped_matmul",
     "wte.attend": "head", "lm_head": "head",
     "loss": "loss",
+    # serve/generation.py UNMASK_SCOPE: a block step's choice of what it
+    # unmasks (the draw, the confidences, the rule)
+    "unmask": "unmask",
 }
 _PATTERNS = (
     (re.compile(r".+_(?:post|norm)$"), "norm"),
