@@ -102,7 +102,7 @@ class BloomAttention(nn.Module):
         if kv_cache is not None:
             index = jnp.asarray(kv_cache[2], jnp.int32)
             cache_len = kv_cache[0].shape[1]
-            k_use, v_use, new_cache = update_kv_cache(kv_cache, k, v)
+            new_cache = update_kv_cache(kv_cache, k, v)
             if index.ndim == 0:
                 q_pos = index + jnp.arange(s)
             else:
@@ -112,7 +112,7 @@ class BloomAttention(nn.Module):
                 bias = alibi_bias(nh, q_pos, k_pos)[None]      # (1,H,S,L)
             else:
                 bias = jax.vmap(lambda qp: alibi_bias(nh, qp, k_pos))(q_pos)
-            out = reference_attention(q, k_use, v_use, causal=True,
+            out = reference_attention(q, *new_cache[:2], causal=True,
                                       offset=index, bias=bias)
         else:
             pos = jnp.arange(s)

@@ -117,8 +117,8 @@ class CodeGenAttention(nn.Module):
             # rotary positions are absolute: offset by the write index
             q = apply_rotary(q, index, cfg.rotary_dim)
             k = apply_rotary(k, index, cfg.rotary_dim)
-            k_use, v_use, new_cache = update_kv_cache(kv_cache, k, v)
-            out = reference_attention(q, k_use, v_use, causal=True,
+            new_cache = update_kv_cache(kv_cache, k, v)
+            out = reference_attention(q, *new_cache[:2], causal=True,
                                       offset=index)
         else:
             q = apply_rotary(q, 0, cfg.rotary_dim)

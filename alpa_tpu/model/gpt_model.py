@@ -581,49 +581,48 @@ def _write_rows(cache, new, index):
 
 
 def update_kv_cache(kv_cache, k, v):
-    """Write step K/V into a resident cache and return the attendable
-    views — the mechanics shared by every decoder family (GPT/OPT,
-    Bloom, CodeGen).
+    """Write the step's keys and values into a resident cache: the
+    mechanics shared by every decoder family (GPT/OPT, Bloom, CodeGen).
 
     ``kv_cache`` is (k_cache, v_cache, index) with a scalar index
     (uniform write position) or a (B,) vector (per-row positions for
-    mixed-length continuous batching).  Returns
-    ``(k_use, v_use, new_cache)`` where k_use/v_use are the full-length
-    caches with unwritten positions zeroed (masked from attention by the
-    caller's causal offset) and ``new_cache`` carries index + s.
+    mixed-length continuous batching).  Returns the cache entry with the
+    ``s`` new positions written at ``index``, and ``index + s``: its
+    arrays, as they lie, are what the caller attends over.
+
+    Nothing is zeroed.  The positions from a row's ``index + s`` on hold
+    whatever was written there before (a fresh cache's zeros, a padded
+    prefill's padding, a rejected draft, a block still being denoised).
+    They are finite, since caches start as zeros (``fresh_kv_caches``) and
+    only the model's own keys and values are written into them; and the
+    caller's mask hides them, and nothing else: ``reference_attention(...,
+    causal=True, offset=index)`` replaces the score of every key past a
+    query's position, so the float32 softmax gives it a probability of
+    exactly 0, and 0 times a finite value adds nothing.  (A block-causal
+    mask shows a query its whole block: what such a step holds of a row
+    ends on a block's edge, ``Generator`` sees to that.)
 
     Per-row indices: a row whose ``s`` positions do not all fit in the
-    cache is not written at all (``_write_rows``), where the scatter this
-    replaced still wrote the positions that fit; its index advances all
+    cache is not written at all (``_write_rows``); its index advances all
     the same.  No caller lets an active row get there (``generate``, the
     speculative rounds and the engine's ``submit`` refuse a request that
-    would).  The rows that do are the engine's free rows, which are
-    decoded along in every tick with an index that only grows: nothing
-    reads them, and the next admission overwrites the whole row and its
-    index.
+    would).  The rows that do are the engine's free rows, decoded along in
+    every tick with an index that only grows: nothing reads them, and the
+    next admission overwrites the whole row and its index.
     """
     k_cache, v_cache, index = kv_cache
-    b, s = k.shape[0], k.shape[1]
     index = jnp.asarray(index, jnp.int32)
+    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
     with jax.named_scope(CACHE_WRITE_SCOPE):
         if index.ndim == 0:
-            k_full = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.astype(k_cache.dtype), index, axis=1)
-            v_full = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.astype(v_cache.dtype), index, axis=1)
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, index,
+                                                          axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, index,
+                                                          axis=1)
         else:
-            k_full = _write_rows(k_cache, k.astype(k_cache.dtype), index)
-            v_full = _write_rows(v_cache, v.astype(v_cache.dtype), index)
-    keep_len = index + s if index.ndim == 0 else (index + s)[:, None]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (k_full.shape[1],), 0)
-    keep = pos < keep_len
-    if keep.ndim == 1:
-        keep = keep[None]
-    k_use = jnp.where(keep[:, :, None, None], k_full,
-                      jnp.zeros_like(k_full))
-    v_use = jnp.where(keep[:, :, None, None], v_full,
-                      jnp.zeros_like(v_full))
-    return k_use, v_use, (k_full, v_full, index + s)
+            k_cache = _write_rows(k_cache, k, index)
+            v_cache = _write_rows(v_cache, v, index)
+    return k_cache, v_cache, index + k.shape[1]
 
 
 def update_ring_cache(kv_cache, k, v, lengths=None):
@@ -1023,11 +1022,11 @@ class SelfAttention(nn.Module):
                     window=window, k_positions=k_positions)
             elif kv_cache is not None:
                 index = jnp.asarray(kv_cache[2], jnp.int32)
-                k_use, v_use, new_cache = update_kv_cache(kv_cache, k, v)
-                # scores to future positions masked by causal offset;
-                # attn_bias (e.g. the packed-prefill segment mask) rides on
-                # top of the causal mask over the full cache length
-                out = reference_attention(q, k_use, v_use, causal=True,
+                new_cache = update_kv_cache(kv_cache, k, v)
+                # the written caches as they lie: the causal offset alone
+                # hides what a row has not reached (``update_kv_cache``);
+                # attn_bias (packed prefill's segment mask) rides on top
+                out = reference_attention(q, *new_cache[:2], causal=True,
                                           offset=index, bias=attn_bias,
                                           block=block)
             elif attn_bias is not None or window or nkv != nh or block:

@@ -8,13 +8,15 @@ no copy of a cache into another dimension order, alias every K and V they
 are given to the output that replaces it, and, with the compiler's
 memory-space assignment off, move no whole cache at all.  On the CPU: the
 per-row write against the scatter it replaced, bit for bit; what happens
-to a row past the cache's edge; and that nothing a caller still owns is
-donated.
+to a row past the cache's edge; that nothing a caller still owns is
+donated; and that what lies in a cache past a row's index reaches no
+output (ISSUE 37: ``update_kv_cache`` zeroes nothing, the mask hides it).
 
 The topology is described inside a module-scoped fixture, never at import
 (only one process may hold the TPU library).
 """
 import dataclasses
+import json
 import os
 import re
 import threading
@@ -25,13 +27,16 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from alpa_tpu.model.bloom_model import BloomConfig, BloomModel
+from alpa_tpu.model.bloom_model import (BloomConfig, BloomModel,
+                                        alibi_bias)
 from alpa_tpu.model.codegen_model import CodeGenConfig, CodeGenModel
-from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel,
+from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, config_from_hf,
                                       config_from_opt_spec, init_gpt_real,
-                                      init_kv_caches, update_kv_cache)
+                                      init_kv_caches, reference_attention,
+                                      update_kv_cache)
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
-from alpa_tpu.serve.generation import GenerationConfig, Generator
+from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
+                                       Generator)
 from alpa_tpu.serve.kv_cache import KVBlockPool
 
 # ---- compiled for the described chip ---------------------------------
@@ -79,15 +84,30 @@ def _abstract(tree, sharding):
         tree)
 
 
-def _abstract_generator(name, one_chip):
-    model, cfg = _family(name)
+def _abstract_state(model, cfg, rows, one_chip):
+    """Parameters and per-row caches of ``rows`` rows, as shapes."""
     params = _abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0),
                                       jnp.ones((1, 8), jnp.int32)),
                        one_chip)
     caches = _abstract(jax.eval_shape(
-        lambda: [(k, v, jnp.zeros((ROWS,), jnp.int32))
-                 for k, v, _ in init_kv_caches(cfg, ROWS)]), one_chip)
+        lambda: [(k, v, jnp.zeros((rows,), jnp.int32))
+                 for k, v, _ in init_kv_caches(cfg, rows)]), one_chip)
+    return params, caches
+
+
+def _abstract_generator(name, one_chip):
+    model, cfg = _family(name)
+    params, caches = _abstract_state(model, cfg, ROWS, one_chip)
     return Generator(model, params, cfg), params, caches
+
+
+def _cell_config(name):
+    """A cell's configuration file, as the benchmark runs it."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
 
 
 def _entry(hlo):
@@ -327,17 +347,14 @@ SEQ, HEADS, HEAD_DIM = 24, 2, 4
 
 
 def _scatter_update(kv_cache, k, v):
-    """``update_kv_cache``'s per-row branch as it was before ISSUE 27."""
+    """``update_kv_cache``'s per-row branch as it was before ISSUE 27
+    (less the zeroed views it returned beside the cache until ISSUE 37)."""
     k_cache, v_cache, index = kv_cache
     b, s = k.shape[0], k.shape[1]
     rows = jnp.arange(b)[:, None]
     cols = index[:, None] + jnp.arange(s)[None, :]
-    k_full = k_cache.at[rows, cols].set(k.astype(k_cache.dtype))
-    v_full = v_cache.at[rows, cols].set(v.astype(v_cache.dtype))
-    keep = (jnp.arange(k_full.shape[1])[None] < (index + s)[:, None])
-    keep = keep[:, :, None, None]
-    return (jnp.where(keep, k_full, 0), jnp.where(keep, v_full, 0),
-            (k_full, v_full, index + s))
+    return (k_cache.at[rows, cols].set(k.astype(k_cache.dtype)),
+            v_cache.at[rows, cols].set(v.astype(v_cache.dtype)), index + s)
 
 
 def _random_cache(index, s, seed=0):
@@ -380,8 +397,7 @@ def test_a_row_past_the_edge_is_left_alone(s, index):
     bad = {"one-too-far": SEQ - s + 1, "far": SEQ + 1000,
            "negative": -1}[index]
     cache, k, v = _random_cache([bad, 5], s)
-    _k_use, _v_use, (k_full, v_full, new_index) = jax.jit(
-        update_kv_cache)(cache, k, v)
+    k_full, v_full, new_index = jax.jit(update_kv_cache)(cache, k, v)
     _same((k_full[0], v_full[0]), (cache[0][0], cache[1][0]))
     _same((k_full[1, 5:5 + s], v_full[1, 5:5 + s]),
           (k[1].astype(jnp.bfloat16), v[1].astype(jnp.bfloat16)))
@@ -389,7 +405,73 @@ def test_a_row_past_the_edge_is_left_alone(s, index):
     if s == 1 and bad >= 0:
         # one position a row: wholly in or wholly out, as the scatter
         # (which took a negative index from the row's end)
-        _same((k_full, v_full), _scatter_update(cache, k, v)[2][:2])
+        _same((k_full, v_full), _scatter_update(cache, k, v)[:2])
+
+
+# ---- what lies past a row's index reaches no output (ISSUE 37) --------
+
+STALE_SEQ, STALE_DIM = 32, 8
+# large, finite, of both signs: what no model writes and a sum would show
+STALE = 3e38
+
+
+def _attend(cache, q, k, v, block, bias):
+    """``update_kv_cache`` and the attention over what it returns, as the
+    three decoder families call the pair."""
+    index = cache[2]
+    k_full, v_full, _ = update_kv_cache(cache, k, v)
+    return reference_attention(q, k_full, v_full, causal=True, offset=index,
+                               bias=bias, block=block)
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["", "alibi"])
+@pytest.mark.parametrize("kv_heads", [4, 32], ids=["grouped", "ungrouped"])
+@pytest.mark.parametrize("block", [0, 4])
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per-row"])
+@pytest.mark.parametrize("s", [1, 4])
+def test_stale_positions_reach_no_output(s, per_row, block, kv_heads, bias):
+    """A cache that holds large finite values of both signs from each
+    row's ``index + s`` on gives, bit for bit, the attention output of the
+    same cache with zeros there: the mask replaces those keys' scores, the
+    float32 softmax gives them a probability of exactly 0, and 0 times a
+    finite value adds nothing.  ``s`` new positions end on a block's edge
+    where the mask goes by blocks, as every step of ``Generator`` does (a
+    query sees its whole block)."""
+    rows, heads = 3, 32
+    rng = np.random.default_rng(s + 2 * per_row + block + kv_heads + bias)
+    # the last position of a block, or a block's first
+    first = np.asarray([11, 3, 23] if s == 1 else [8, 0, 20])
+    if not per_row:
+        first = first[:1]
+    index = jnp.asarray(first if per_row else first[0], jnp.int32)
+    shape = (rows, STALE_SEQ, kv_heads, STALE_DIM)
+    held = np.broadcast_to(
+        np.arange(STALE_SEQ)[None, :] < (first + s)[:, None],
+        (rows, STALE_SEQ))[:, :, None, None]
+    written = (rng.normal(size=shape), rng.normal(size=shape))
+    signs = rng.choice([-STALE, STALE], size=shape)
+
+    def cache(stale):
+        return tuple(jnp.asarray(np.where(held, x, stale), jnp.bfloat16)
+                     for x in written) + (index,)
+
+    def draw(*dims):
+        return jnp.asarray(rng.normal(size=dims), jnp.bfloat16)
+
+    q = draw(rows, s, heads, STALE_DIM)
+    k, v = (draw(rows, s, kv_heads, STALE_DIM) for _ in range(2))
+    score_bias = None
+    if bias:
+        # Bloom's, as ``BloomAttention`` builds it over the cache's length
+        q_pos = jnp.asarray(first)[:, None] + jnp.arange(s)[None, :]
+        score_bias = jax.vmap(lambda qp: alibi_bias(
+            heads, qp, jnp.arange(STALE_SEQ)))(q_pos)
+    attend = jax.jit(_attend, static_argnums=(4,))
+    dirty = attend(cache(signs), q, k, v, block, score_bias)
+    clean = attend(cache(0.0), q, k, v, block, score_bias)
+    assert np.isfinite(np.asarray(dirty, np.float32)).all()
+    assert np.abs(np.asarray(clean, np.float32)).max() > 0
+    _same(dirty, clean)
 
 
 def _tiny(**gen_kwargs):
@@ -439,6 +521,29 @@ def test_engine_streams_equal_generate(paged):
     for p, n, out in zip(PROMPTS, NEW_TOKENS, outs):
         want = gen.generate(p[None], GenerationConfig(max_new_tokens=n))
         np.testing.assert_array_equal(out, want[0])
+
+
+@pytest.mark.parametrize("admission", ["dense", "chunked"])
+def test_a_row_admitted_again_streams_as_a_fresh_engine(admission):
+    """One row: a long request, then a short one into the row it freed,
+    which decodes over positions the first one wrote (and a padded
+    prefill's padding).  Token for token what an engine that never served
+    the first one streams."""
+    gen = _tiny(prompt_buckets=[16]) if admission == "dense" else \
+        _tiny(prefill_chunk=8)
+    long_cfg = GenerationConfig(max_new_tokens=18)
+    short_cfg = GenerationConfig(max_new_tokens=9)
+    used = ContinuousBatchingEngine(gen, max_batch=1)
+    fresh = ContinuousBatchingEngine(gen, max_batch=1)
+    try:
+        used.submit(PROMPTS[0], long_cfg)
+        again = used.submit(PROMPTS[1], short_cfg)
+        want = fresh.submit(PROMPTS[1], short_cfg)
+    finally:
+        used.shutdown()
+        fresh.shutdown()
+    np.testing.assert_array_equal(again, want)
+    assert len(want) == len(PROMPTS[1]) + 9
 
 
 def test_prefix_handle_outlives_the_decodes():
@@ -519,23 +624,11 @@ def _trinity_decode_hlo(one_chip):
     published widths, one leading dense layer and one period of window,
     window, window and full attention layers over routed experts, 16
     rows, served context 16,384, bfloat16 parameters and caches."""
-    import json
-    from alpa_tpu.model.gpt_model import config_from_hf
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    with open(os.path.join(root, "chipbench", "configs",
-                           "trinity-mini-1chip.json")) as f:
-        hf = json.load(f)
+    hf = _cell_config("trinity-mini-1chip")
     cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                          seq_len=hf["serve"]["served_context"])
     model = GPTModel(cfg)
-    params = _abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                                      jnp.ones((1, 8), jnp.int32)),
-                       one_chip)
-    caches = _abstract(jax.eval_shape(
-        lambda: [(k, v, jnp.zeros((TRINITY_ROWS,), jnp.int32))
-                 for k, v, _ in init_kv_caches(cfg, TRINITY_ROWS)]),
-        one_chip)
+    params, caches = _abstract_state(model, cfg, TRINITY_ROWS, one_chip)
     gen = Generator(model, params, cfg, prefill_chunk=1024)
     tok = jax.ShapeDtypeStruct((TRINITY_ROWS, 1), jnp.int32,
                                sharding=one_chip)
@@ -590,15 +683,119 @@ def test_caches_of_two_shapes_land_in_their_own_buffers(trinity_decode):
 
 
 @pytest.mark.xfail(strict=True, reason=(
-    "the compiler re-lays every grouped-query cache out once a tick: the "
-    "rows are written in place in the entry layout {3,2,1,0:T(4,128)}, "
-    "and the product of 8 query heads with each of the 4 key/value heads "
-    "then wants the heads outside the positions, {3,1,2,0:T(8,128)}: one "
-    "copy of all 0.81 GB of caches a tick, written and read again "
-    "(PERF.md, PR 30 and section 7)"))
+    "inside the fusions of the scores' and the values' products the "
+    "compiler still reads every grouped-query cache through a copy from "
+    "the entry layout {3,2,1,0:T(4,128)}, in which the rows are written in "
+    "place, into {3,1,2,0:T(8,128)}, the heads outside the positions: a "
+    "conversion on the way into the product, no array of its own (ENTRY "
+    "holds none since ISSUE 37: test_attention_reads_the_cache_as_it_lies); "
+    "what it costs only the chip says (PERF.md, section 7)"))
 def test_trinity_decode_relays_no_cache(trinity_decode):
     hlo, shapes, _ = trinity_decode
     for shape in set(shapes):
         other = re.findall(r"%s\{(?!3,2,1,0)[\d,]+" %
                            re.escape(_cache_type(shape)), hlo)
         assert other == []
+
+
+# ---- the attention reads the written cache as it lies (ISSUE 37) -------
+
+SDAR_ROWS, SDAR_LAYERS = 32, 6
+
+
+def _sdar_block_step(one_chip):
+    """``_block_step`` of ``sdar-30b-a3b-1chip`` as its cell compiles it:
+    the published widths, six layers of 128 experts, 32 rows, served
+    context 8,192, blocks of 4, bfloat16 parameters and caches."""
+    hf = _cell_config("sdar-30b-a3b-1chip")
+    serve = hf["serve"]
+    rows, block = serve["engine_rows"], serve["block_length"]
+    cfg = config_from_hf(hf, block_length=block, dtype=jnp.bfloat16,
+                         param_dtype=jnp.bfloat16,
+                         seq_len=serve["served_context"])
+    model = GPTModel(cfg)
+    params, caches = _abstract_state(model, cfg, rows, one_chip)
+    gen = Generator(model, params, cfg, prefill_chunk=serve["prefill_chunk"],
+                    diffusion=BlockDiffusion(
+                        mask_token_id=serve["mask_token_id"],
+                        denoising_steps=serve["denoising_steps"],
+                        remasking=serve["remasking"],
+                        threshold=serve["confidence_threshold"]))
+
+    def a_row(dtype, *more):
+        return jax.ShapeDtypeStruct((rows,) + more, dtype, sharding=one_chip)
+
+    compiled = gen._block_step.jitted.lower(
+        params, a_row(jnp.int32, block), a_row(jnp.int32),
+        [(k, v) for k, v, _ in caches], [i for _, _, i in caches],
+        a_row(jnp.int32),
+        (a_row(jnp.bool_), a_row(jnp.float32), a_row(jnp.int32)),
+        _abstract(jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+                  one_chip)).compile()
+    return (compiled.as_text(), [k.shape for k, _v, _i in caches],
+            compiled.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def sdar_block_step(one_chip):
+    return _sdar_block_step(one_chip)
+
+
+def _root_opcodes(hlo):
+    """The opcode of the ROOT instruction of every called computation."""
+    return dict(re.findall(
+        r"^%(\S+) \(.*?\n  ROOT %\S+ = (?:\(.*?\)|\S+) ([a-z\-]+)\(",
+        hlo, re.S | re.M))
+
+
+def _made_of_shape(hlo, shape):
+    """The ENTRY instructions that make a bfloat16 array of ``shape``
+    (alone or in a tuple; trailing dimensions of 1 are the same array),
+    as ``(row writes, others)``: a row write is a ``dynamic-update-slice``,
+    bare or as the root of its fusion; arguments, the result's tuple and
+    views (``bitcast``) make nothing."""
+    of_shape = re.compile(re.escape(_cache_type(shape)[:-1]) + r"(,1)*\]")
+    roots = _root_opcodes(hlo)
+    writes, others = [], []
+    for name, result, op, _operand in _entry(hlo)[0]:
+        if not of_shape.search(result) or op in (
+                "parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        called = re.search(r"%%%s = .* calls=%%([^\s,)]+)" % re.escape(name),
+                           hlo)
+        if op == "fusion" and called:
+            op = roots.get(called.group(1), op)
+        if op == "dynamic-update-slice":
+            writes.append(name)
+        else:
+            others.append((name, op))
+    return writes, others
+
+
+@pytest.mark.parametrize("program", ["sdar-block-step", "trinity-decode"])
+def test_attention_reads_the_cache_as_it_lies(request, program):
+    """The caches ``update_kv_cache`` writes (the full-length ones): ENTRY
+    makes no array of such a cache's type but the row writes, one
+    ``dynamic-update-slice`` a row for K and for V of every such layer.
+    Until ISSUE 37 a select that zeroed the positions past every row's
+    index stood between the writes and the attention, an array of the
+    cache's size a layer for K and V each (``select_bitcast_fusion``,
+    1.6 ms a layer of the SDAR cell's block step;
+    ``broadcast_select_fusion`` in the Trinity decode); now the scores'
+    and the values' fusions take the written cache itself.  The block
+    step's temporaries are under one cache's 268 MB (they were 537 MB, K
+    and V of one layer).  (The Trinity decode's four rings are
+    ``update_ring_cache``'s and may pass through the faster memory.)"""
+    if program == "sdar-block-step":
+        hlo, shapes, temporaries = request.getfixturevalue("sdar_block_step")
+        assert hlo.startswith("HloModule jit_block_step")
+        assert shapes == SDAR_LAYERS * [(SDAR_ROWS, 8192, 4, 128)]
+        rows, layers = SDAR_ROWS, SDAR_LAYERS
+        assert temporaries < 2 * np.prod(shapes[0])
+    else:
+        hlo, shapes, _ = request.getfixturevalue("trinity_decode")
+        rows, layers = TRINITY_ROWS, 1
+    full = max(shapes, key=lambda shape: shape[1])
+    writes, others = _made_of_shape(hlo, full)
+    assert others == []
+    assert len(writes) == 2 * layers * rows
