@@ -98,6 +98,9 @@ class GPTConfig:
     # (position q sees k where q - k < sliding_window; its cache is a ring
     # of sliding_window positions, written at position % sliding_window)
     # | "latent" (below: its cache holds seq_len positions of a latent)
+    # | "conv" (no attention: a gated short convolution, ``ShortConv``; its
+    # state is the last ``conv_taps - 1`` positions of a product, whatever
+    # the row's length)
     attention: Any = "full"
     sliding_window: int = 0
     # False: rotary positions turn the q and k of "sliding" layers only,
@@ -168,6 +171,10 @@ class GPTConfig:
     # its block too: ``reference_attention``'s ``block``), and the logit at
     # a position predicts the token AT it.  0: the causal mask of today.
     block_length: int = 0
+    # --- gated short convolutions among the attention layers
+    # (``model_type`` lfm2_moe).  The taps of a "conv" layer's depthwise
+    # causal convolution (the file's ``conv_L_cache``); 0: no such layer.
+    conv_taps: int = 0
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
@@ -258,7 +265,44 @@ _HF_KINDS = {
     # models/qwen3_moe/modeling_qwen3_moe.py) under a block-causal mask
     "sdar_moe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
                      fused_gate_up=True),
+    # LFM2 (LiquidAI): wiring read from transformers'
+    # models/lfm2_moe/modeling_lfm2_moe.py where config.json does not fix
+    # it (the tied head, the final norm, the head size, silu)
+    "lfm2_moe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
+                     router_score="sigmoid", fused_gate_up=True,
+                     activation="silu", tie_embeddings=True),
 }
+
+
+def _lfm2_moe_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` lfm2_moe says beyond the keys
+    all decoders share: which layers are gated short convolutions and which
+    attention, the convolution's taps, the leading dense layers, the sizes
+    of the experts and the router's settings.  The file says ``norm_eps``,
+    and has no ``hidden_act``, no ``head_dim`` and no
+    ``tie_word_embeddings`` (``_HF_KINDS`` has what the model's code
+    says of them)."""
+    layers = hf["num_hidden_layers"]
+    if len(hf["layer_types"]) != layers:
+        raise ValueError("layer_types must name every layer")
+    unknown = set(hf["layer_types"]) - {"conv", "full_attention"}
+    if unknown:
+        raise ValueError(f"unknown layer_types {sorted(unknown)}")
+    if hf["conv_bias"]:
+        raise ValueError("lfm2_moe: conv_bias true is not supported (the "
+                         "short convolution and its projections have no "
+                         "bias)")
+    return dict(
+        mlp=tuple("gated" if i < hf["num_dense_layers"] else "experts"
+                  for i in range(layers)),
+        attention=tuple("conv" if t == "conv" else "full"
+                        for t in hf["layer_types"]),
+        conv_taps=hf["conv_L_cache"], layer_norm_eps=hf["norm_eps"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["num_experts"],
+        router_bias=hf["use_expert_bias"],
+        norm_topk_prob=hf["norm_topk_prob"],
+        route_scale=float(hf["routed_scaling_factor"]))
 
 
 def _sdar_moe_fields(hf: dict) -> dict:
@@ -393,11 +437,14 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         num_heads=hf["num_attention_heads"],
         seq_len=hf["max_position_embeddings"],
         intermediate_size=hf["intermediate_size"],
-        activation=hf["hidden_act"], layer_norm_eps=hf["rms_norm_eps"],
         rope_theta=float(hf["rope_theta"]),
         use_bias=hf.get("attention_bias", False),
-        tie_embeddings=hf["tie_word_embeddings"],
         num_experts_per_tok=hf["num_experts_per_tok"], **kinds)
+    if hf["model_type"] != "lfm2_moe":
+        # (its file has none of the three: ``_lfm2_moe_fields``)
+        fields.update(activation=hf["hidden_act"],
+                      layer_norm_eps=hf["rms_norm_eps"],
+                      tie_embeddings=hf["tie_word_embeddings"])
     if hf["num_key_value_heads"] != hf["num_attention_heads"]:
         fields["num_kv_heads"] = hf["num_key_value_heads"]
     if hf["model_type"] == "deepseek_v2":
@@ -406,6 +453,8 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         fields.update(num_experts=hf["num_experts"], **_afmoe_fields(hf))
     elif hf["model_type"] == "sdar_moe":
         fields.update(_sdar_moe_fields(hf))
+    elif hf["model_type"] == "lfm2_moe":
+        fields.update(_lfm2_moe_fields(hf))
     else:
         fields.update(num_experts=hf["num_experts"],
                       norm_topk_prob=hf["norm_topk_prob"])
@@ -964,6 +1013,101 @@ class LatentAttention(nn.Module):
             out.reshape(b, s, nh * dv)), new_cache
 
 
+# the scope a gated short convolution is traced under, the whole mixer:
+# both products, the gates, the taps, the state's update (a capture reads
+# it: telemetry/device_time.py)
+CONV_SCOPE = "short_conv"
+
+
+def update_conv_state(kv_cache, g, lengths=None):
+    """``update_kv_cache`` for a "conv" layer, whose state is the last
+    ``taps - 1`` positions of the product ``g`` it convolves: ``kv_cache``
+    is ``(state (B, taps - 1, h), an empty (B, 0) array, index)``, the
+    entry a triple as every layer's is, ``index`` (a scalar, or (B,) a row)
+    the position of the first of the ``s`` new ones ``g`` (B, s, h).
+    Returns ``(full, new_cache)``: ``full`` (B, taps - 1 + s, h) is the
+    state with ``g`` behind it, what the taps run over, and the new state
+    is, a row, the ``taps - 1`` positions of ``full`` that end at the row's
+    last REAL new position.
+
+    ``lengths`` ((B,), the rows' whole lengths) says which new positions
+    are real where the ids are right-padded: a row with ``r`` real ones
+    keeps ``full[r : r + taps - 1]``: all of its old state if none is
+    real, its old state's last position before ``g_0`` if one is.  No
+    mask hides a wrong state afterwards, as one hides a cache's padding:
+    every later token of the row reads it.  None: all ``s`` are real.
+
+    Nothing of ``update_kv_cache``'s rule for unwritten positions holds
+    here: a fresh row's state has to BE zeros (position 0 sees two zero
+    positions before it), which ``fresh_kv_caches`` gives; a free row's
+    state is junk until an admission overwrites the whole row."""
+    state, empty, index = kv_cache
+    index = jnp.asarray(index, jnp.int32)
+    keep, s = state.shape[1], g.shape[1]
+    full = jnp.concatenate([state.astype(g.dtype), g], axis=1)
+    if lengths is None:
+        new_state = full[:, s:]
+    else:
+        first = index[:, None] if index.ndim else index[None, None]
+        real = jnp.clip(lengths[:, None] - first, 0, s)          # (B, 1)
+        at = real + jax.lax.broadcasted_iota(jnp.int32, (1, keep), 1)
+        new_state = jnp.take_along_axis(full, at[:, :, None], axis=1)
+    return full, (new_state.astype(state.dtype), empty, index + s)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution of LFM2 (LiquidAI 2025), the
+    ``attention`` kind "conv" (``GPTConfig``), no bias anywhere: ``[B, C,
+    X] = split3(in_proj(u))``; ``g = B * X``; ``c_t = sum_j kernel[j] *
+    g_{t - (taps - 1) + j}`` with ``g_s = 0`` for ``s < 0`` (depthwise,
+    causal, ``kernel`` (taps, h)); ``out_proj(C * c)``.
+
+    Without a cache (the training call) the convolution runs over the
+    whole sequence behind ``taps - 1`` zero positions.  With one, the
+    layer's entry is ``(state, empty, index)`` (``update_conv_state``):
+    one new position reads the state, computes its ``c`` and shifts; ``s``
+    new positions run the taps over the state with ``g`` behind it and
+    leave, a row, the state of its last real position
+    (``cache_lengths``)."""
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, deterministic=True,
+                 attn_bias=None, position_ids=None, cache_lengths=None):
+        cfg = self.config
+        if attn_bias is not None or not cfg.causal or cfg.block_length:
+            raise ValueError("a short convolution is causal over one "
+                             "sequence a row and takes no score bias "
+                             "(packed sequences, padding masks) and no "
+                             "block-causal mask")
+        h, taps = cfg.hidden_size, cfg.conv_taps
+        if taps < 2:
+            raise ValueError("a \"conv\" layer needs GPTConfig.conv_taps "
+                             f">= 2, got {taps}")
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=False,
+                        param_dtype=cfg.param_dtype)
+        s = x.shape[1]
+        new_cache = None
+        with jax.named_scope(CONV_SCOPE):
+            b_gate, c_gate, xs = jnp.split(
+                dense(3 * h, name="in_proj")(x), 3, axis=-1)
+            g = b_gate * xs
+            # lecun_normal over the taps: a tap's fan-in is ``taps``
+            kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                                (taps, h), cfg.param_dtype)
+            if kv_cache is None:
+                full = jnp.pad(g, ((0, 0), (taps - 1, 0), (0, 0)))
+            else:
+                full, new_cache = update_conv_state(kv_cache, g,
+                                                    cache_lengths)
+            # three shifted copies, summed in float32
+            conv = sum(kernel[j].astype(jnp.float32) *
+                       full[:, j:j + s].astype(jnp.float32)
+                       for j in range(taps)).astype(cfg.dtype)
+            out = dense(h, name="out_proj")(c_gate * conv)
+        return out, new_cache
+
+
 class SelfAttention(nn.Module):
     """``attention`` is the layer's kind (``GPTConfig.attention``; None:
     the configuration's, which must then be one kind for all layers)."""
@@ -1101,8 +1245,11 @@ class TransformerBlock(nn.Module):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
-        if (self.attention or cfg.attention_kind(0)) == "latent":
+        mixer = self.attention or cfg.attention_kind(0)
+        if mixer == "latent":
             attn = LatentAttention(cfg, name="attn")
+        elif mixer == "conv":
+            attn = ShortConv(cfg, name="conv")
         else:
             attn = SelfAttention(cfg, attention=self.attention, name="attn")
         attn_out, new_cache = attn(ln1, kv_cache, deterministic, attn_bias,
@@ -1153,10 +1300,11 @@ class GPTModel(nn.Module):
         ``cache_lengths`` ((B,), with ``kv_caches``): the rows' whole
         lengths, where the ids are right-padded past them: a layer whose
         cache is a ring must not write the padding
-        (``update_ring_cache``).  ``return_routing`` (with ``kv_caches``,
-        routed layers): a third result, what the routed layers' routers
-        did: ``experts`` (expert layers, tokens, k) int32, every token's
-        experts.
+        (``update_ring_cache``), and a short convolution keeps the state
+        of a row's last real position (``update_conv_state``).
+        ``return_routing`` (with ``kv_caches``, routed layers): a third
+        result, what the routed layers' routers did: ``experts`` (expert
+        layers, tokens, k) int32, every token's experts.
         """
         cfg = self.config
         b, s = input_ids.shape
@@ -1256,7 +1404,10 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     and V cache: ``seq_len`` positions in a "full" layer, a ring of
     ``sliding_window`` (at most ``seq_len``) in a "sliding" one.  A
     "latent" layer's two arrays differ and have no heads: its entry is the
-    pair ((B, seq_len, kv_lora_rank), (B, qk_rope_head_dim, seq_len)).
+    pair ((B, seq_len, kv_lora_rank), (B, qk_rope_head_dim, seq_len)).  A
+    "conv" layer holds no positions but a state: its entry is the pair
+    ((B, conv_taps - 1, hidden_size), (B, 0)), the second array empty so
+    that the entry is a triple as every layer's is.
     Takes any decoder family's configuration: what ``GPTConfig`` alone has
     reads as its default."""
     heads = getattr(config, "num_kv_heads", None) or config.num_heads
@@ -1271,28 +1422,62 @@ def kv_cache_shapes(config, batch_size: int) -> list:
                 (batch_size, config.seq_len, config.kv_lora_rank),
                 (batch_size, config.qk_rope_head_dim, config.seq_len)))
             continue
+        if kind == "conv":
+            shapes.append((
+                (batch_size, config.conv_taps - 1, config.hidden_size),
+                (batch_size, 0)))
+            continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
         shapes.append((batch_size, length, heads, hd))
     return shapes
 
 
+def kv_cache_kinds(config) -> list:
+    """The kind of every layer's cache entry, as the gauge
+    ``alpa_serving_kv_cache_bytes`` labels them: "full" (``seq_len``
+    positions of K and V), "window" (a "sliding" layer's ring), "latent",
+    "conv" (a state and no positions)."""
+    kinds = getattr(config, "attention", "full")
+    return ["window" if kind == "sliding" else kind for kind in
+            ([kinds] * config.num_layers if isinstance(kinds, str)
+             else kinds)]
+
+
 def latent_kv_caches(config) -> bool:
     """Whether any layer's cache is a latent one (no per-head K and V)."""
-    kinds = getattr(config, "attention", "full")
-    return "latent" in ((kinds,) if isinstance(kinds, str) else kinds)
+    return "latent" in kv_cache_kinds(config)
+
+
+def conv_states(config) -> bool:
+    """Whether any layer is a short convolution, whose cache entry is a
+    state of fixed size and no cache of positions."""
+    return "conv" in kv_cache_kinds(config)
 
 
 def uniform_kv_caches(config) -> bool:
-    """Whether every layer's cache has one shape: what the block pool, the
-    packed prefill, the speculative verify step and beam search count on
-    (one block table, one length and one index for all layers)."""
-    return len(set(kv_cache_shapes(config, 1))) == 1
+    """Whether every layer's cache holds positions and has one shape: what
+    the block pool, the packed prefill, the speculative verify step and
+    beam search count on (one block table, one length and one index for
+    all layers).  A short convolution's state holds no positions, so a
+    configuration with one is not uniform whatever its shapes; its cached
+    calls are handed the rows' lengths as a ring's are."""
+    return not conv_states(config) and \
+        len(set(kv_cache_shapes(config, 1))) == 1
 
 
 def require_uniform_kv_caches(config, what: str):
     """Raise unless every layer caches per-head K and V of one shape, as
     ``what`` indexes them."""
+    if conv_states(config):
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape and "
+            "rolls a row back by its index, and this configuration has "
+            "short-convolution layers (GPTConfig.attention \"conv\"), "
+            "whose entry is a state of the last conv_taps - 1 positions "
+            "that every step overwrites: no index brings an earlier state "
+            "back, and there are no positions to page, pack or reorder: "
+            f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     if latent_kv_caches(config):
         raise ValueError(
             f"{what} indexes per-head K and V caches of one shape, and "
@@ -1327,7 +1512,10 @@ def init_kv_caches(config: GPTConfig, batch_size: int,
                    dtype=None) -> list:
     """KV caches as explicit arrays (ref opt_model.py:605 init_cache_aval):
     ``[(k, v, index)]`` a layer, each layer's of its own shape
-    (``kv_cache_shapes``); a "latent" layer's ``(c, k_pe, index)``."""
+    (``kv_cache_shapes``); a "latent" layer's ``(c, k_pe, index)``, a
+    "conv" layer's ``(state, empty, index)``.  All zeros, which a "conv"
+    layer's state has to be for a row that starts
+    (``update_conv_state``)."""
     dtype = dtype or config.dtype
     caches = []
     for shape in kv_cache_shapes(config, batch_size):

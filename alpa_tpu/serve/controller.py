@@ -968,13 +968,21 @@ class _Handler(BaseHTTPRequestHandler):
             self.close_connection = True
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # the listen queue: the standard library's 5 resets the connections of
+    # a burst that opens more at once than the accepting thread has taken
+    # (seen once as ConnectionResetError in a warm-up of 33 connections;
+    # an engine of 64 rows meets 65 at once, and 128 callers after)
+    request_queue_size = 1024
+
+
 class ControllerServer:
     """The running HTTP server (ref run_controller:280)."""
 
     def __init__(self, controller: Controller, host: str, port: int):
         handler = type("BoundHandler", (_Handler,),
                        {"controller": controller})
-        self.httpd = ThreadingHTTPServer((host, port), handler)
+        self.httpd = _HTTPServer((host, port), handler)
         self.controller = controller
         self.thread = threading.Thread(target=self.httpd.serve_forever,
                                        daemon=True)

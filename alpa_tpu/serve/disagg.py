@@ -357,12 +357,16 @@ class PrefillEngine:
                  prompt_bucket: Optional[int] = None, model: str = "",
                  weights_tag: str = "", codec: Optional[str] = None,
                  max_retained: Optional[int] = None):
-        from alpa_tpu.model.gpt_model import require_one_token_steps
+        from alpa_tpu.model.gpt_model import (require_one_token_steps,
+                                              require_uniform_kv_caches)
         from alpa_tpu.serve.kv_cache import KVBlockPool
         # a handed-off row is a prompt's cache and its last logits, which
-        # the decode half samples its first token from
+        # the decode half samples its first token from; what is handed
+        # off is a slice of one block table of K and V
         require_one_token_steps(generator.config, "disaggregated serving "
                                 "(serve/disagg.py PrefillEngine)")
+        require_uniform_kv_caches(generator.config, "disaggregated serving "
+                                  "(serve/disagg.py PrefillEngine)")
         self.gen = generator
         self.model = model
         self.weights_tag = weights_tag

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu import fault
-from alpa_tpu.model.gpt_model import init_kv_caches, require_one_token_steps
+from alpa_tpu.model.gpt_model import (init_kv_caches, kv_cache_kinds,
+                                      require_one_token_steps)
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
                                        fresh_kv_caches, read_block,
                                        row_length, sample_rows)
@@ -104,8 +105,10 @@ _KV_CACHE_BYTES = _REG.gauge(
     "alpa_serving_kv_cache_bytes",
     "Bytes of the engine's resident K and V caches, by the kind of the "
     "layers' cache: window (a ring of the sliding window's positions), "
-    "full (the served context) or latent (the served context of a latent "
-    "layer's normed latent and shared rotary key)", labelnames=("kind",))
+    "full (the served context), latent (the served context of a latent "
+    "layer's normed latent and shared rotary key) or conv (a short "
+    "convolution's state: its last positions, whatever the context)",
+    labelnames=("kind",))
 
 # every engine span: category "serving", on this track (the queue waits,
 # which overlap each other, on their own)
@@ -501,11 +504,10 @@ class ContinuousBatchingEngine:
         cfgm = self.gen.config
         self._caches = [(k, v, jnp.zeros((self.B,), jnp.int32))
                         for (k, v, _i) in init_kv_caches(cfgm, self.B)]
-        by_kind = {"window": 0, "full": 0, "latent": 0}
-        for k, v, _i in self._caches:
-            # a latent layer's arrays have no heads
-            kind = "latent" if k.ndim == 3 else \
-                "full" if k.shape[1] == cfgm.seq_len else "window"
+        # by the kind of the layer's entry; a short convolution's is its
+        # state, as large a row whatever the context
+        by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0}
+        for kind, (k, v, _i) in zip(kv_cache_kinds(cfgm), self._caches):
             by_kind[kind] += k.nbytes + v.nbytes
         for kind, nbytes in by_kind.items():
             _KV_CACHE_BYTES.labels(kind).set(nbytes)

@@ -377,8 +377,8 @@ class Generator:
 
     A configuration whose layers' caches differ (``GPTConfig.attention``:
     a ring of the window's positions in a "sliding" layer, the context in
-    a "full" one) is prefilled with the rows' lengths handed to the model,
-    so that no ring takes a chunk's padding.
+    a "full" one, a state in a "conv" one) is prefilled with the rows'
+    lengths handed to the model, so that no ring takes a chunk's padding.
 
     A layer of latent attention (``GPTConfig.attention`` "latent") caches
     ``(c, k_pe, index)``, two arrays of unlike shapes and no heads, where
@@ -388,6 +388,17 @@ class Generator:
     what indexes per-head K and V (the block pool, the packed prefill, the
     speculative verify step, beam search) refuses it
     (``require_uniform_kv_caches``).
+
+    A short-convolution layer (``GPTConfig.attention`` "conv") holds no
+    positions: its entry is ``(state, empty, index)``, the last
+    ``conv_taps - 1`` positions of a product, and rides in the list of
+    caches as the others do (donated by the decode, scattered by the
+    engine's ``_scatter_row``, repeated over a batch under a
+    ``PrefixHandle``, which so snapshots the state with the prefix).  Such
+    a configuration is prefilled with the rows' lengths too: a padded
+    chunk or bucket leaves each row the state of its last real position
+    (``update_conv_state``).  What rolls a row back by its index or
+    indexes positions refuses it, by the same call.
 
     ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
@@ -664,7 +675,9 @@ class Generator:
     def cache_prefix(self, prefix_ids) -> "PrefixHandle":
         """Precompute KV for a shared prefix (system prompt caching).
         Chunked mode only — the chunk step is what lets suffixes resume
-        at an arbitrary cache offset with one compile."""
+        at an arbitrary cache offset with one compile.  A short
+        convolution's state after the prefix's last token is in the
+        handle with the keys and values (it rides in the same list)."""
         require_one_token_steps(self.config, "a static prefix "
                                 "(cache_prefix)")
         if not self.prefill_chunk:

@@ -34,6 +34,10 @@ part                         components
                              softmax, values
 ``attention.cache_write``    ``cache_write`` inside it: the step's keys
                              and values written into the cache
+``short_conv``               ``model.gpt_model.CONV_SCOPE``, a gated short
+                             convolution whole: both products, the gates,
+                             the taps, the state's update (and ``conv``,
+                             its module: a weight the compiler copies)
 ``mlp``                      ``mlp`` (``MLPBlock``; a routed layer's
                              shared expert)
 ``moe``                      ``model.moe.SCOPE``: router, top-k, sort,
@@ -109,7 +113,7 @@ COLLECTIVE = "collective"
 MIXED, INHERITED = "mixed", "inherited"
 
 # path component -> part.  The scope names are those of model/gpt_model.py
-# (ATTENTION_SCOPE, CACHE_WRITE_SCOPE), model/moe.py (SCOPE),
+# (ATTENTION_SCOPE, CACHE_WRITE_SCOPE, CONV_SCOPE), model/moe.py (SCOPE),
 # ops/grouped_matmul.py (SCOPE) and model/model_util.py (LOSS_SCOPE):
 # telemetry imports no model.
 _COMPONENTS = {
@@ -118,6 +122,7 @@ _COMPONENTS = {
     "attn": "projection",
     "attention": "attention",
     "cache_write": "attention.cache_write",
+    "short_conv": "short_conv", "conv": "short_conv",
     "mlp": "mlp",
     "moe": "moe",
     "grouped_matmul": "moe.grouped_matmul",

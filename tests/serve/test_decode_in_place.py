@@ -799,3 +799,103 @@ def test_attention_reads_the_cache_as_it_lies(request, program):
     writes, others = _made_of_shape(hlo, full)
     assert others == []
     assert len(writes) == 2 * layers * rows
+
+
+# ---- a conv layer's state beside the attention caches (PR 38) ----------
+
+LFM2_ROWS = 64
+
+
+def _lfm2_decode(one_chip):
+    """The decode of ``lfm2-8b-a1b-1chip`` as its cell compiles it, but
+    three layers deep (the leading dense conv layer, a routed conv layer,
+    a routed attention layer): the published widths, 64 rows, served
+    context 8,192, bfloat16 parameters and caches."""
+    hf = _cell_config("lfm2-8b-a1b-1chip")
+    hf.update(num_hidden_layers=3,
+              layer_types=["conv", "conv", "full_attention"])
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=hf["serve"]["served_context"])
+    model = GPTModel(cfg)
+    params, caches = _abstract_state(model, cfg, LFM2_ROWS, one_chip)
+    gen = Generator(model, params, cfg, prefill_chunk=1024)
+    tok = jax.ShapeDtypeStruct((LFM2_ROWS, 1), jnp.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((LFM2_ROWS,), jnp.int32, sharding=one_chip)
+    compiled = gen._decode.jitted.lower(
+        params, tok, idx, [(k, v) for k, v, _ in caches],
+        [i for _, _, i in caches]).compile()
+    return compiled.as_text(), [k.shape for k, _v, _i in caches]
+
+
+@pytest.fixture(scope="module")
+def lfm2_decode(one_chip):
+    return _lfm2_decode(one_chip)
+
+
+def test_lfm2_decode_gives_its_states_to_its_outputs(lfm2_decode):
+    """A conv layer's entry in the list of caches is its state, two
+    positions a row whatever the served context, and the decode is given
+    the conv layers' states and the attention layer's K and V for the
+    outputs that replace them (an empty array has nothing to alias)."""
+    hlo, shapes = lfm2_decode
+    assert hlo.startswith("HloModule jit_decode")
+    assert shapes == 2 * [(LFM2_ROWS, 2, 2048)] + \
+        [(LFM2_ROWS, 8192, 8, 64)]
+    assert len(_aliases(hlo)) >= 4
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "heads of 64 channels and rotated keys: the compiler keeps the cache "
+    "with its positions in the lanes and moves K and V of the attention "
+    "layer into the keys' order for the per-row writes and back, four "
+    "copies of a cache a layer a tick (30 of the cell's 48 ms; PERF.md "
+    "section 7, a perf_opt PR's)"))
+def test_lfm2_decode_moves_no_cache(lfm2_decode):
+    hlo, shapes = lfm2_decode
+    cache = _cache_type(shapes[-1])
+    found, _types = _entry(hlo)
+    assert [result for _name, result, op, _operand in found
+            if op in ("copy", "copy-start") and cache in result] == []
+
+
+def test_lfm2_chunk_step_runs_the_mixers_products_in_their_part(one_chip):
+    """What ``conv_chunk_roofline_pct`` rests on: in the chunk step every
+    product of a conv mixer (``in_proj`` and ``out_proj`` of each conv
+    layer) runs in a device event that ``Capture.device_time()`` counts as
+    the part ``short_conv``, and no event counted as another part holds an
+    instruction of the mixer.  So the part's time holds all of the
+    mixers' operations (the weights the compiler streams ahead move bytes,
+    not operations), and what else its fusions took in (the norm before,
+    the residual sum after) only lengthens it."""
+    from alpa_tpu.telemetry import device_time
+    hf = _cell_config("lfm2-8b-a1b-1chip")
+    hf.update(num_hidden_layers=3,
+              layer_types=["conv", "conv", "full_attention"])
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=hf["serve"]["served_context"])
+    model = GPTModel(cfg)
+    params, caches = _abstract_state(model, cfg, 1, one_chip)
+    chunk = hf["serve"]["prefill_chunk"]
+    gen = Generator(model, params, cfg, prefill_chunk=chunk)
+    hlo = gen._chunk_prefill.lower(
+        params,
+        jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip), caches,
+        jax.ShapeDtypeStruct((1, cfg.vocab_size), jnp.bfloat16,
+                             sharding=one_chip)).compile().as_text()
+    parts = device_time.instruction_parts(hlo)
+    computations = device_time._computations(hlo)
+    products, strays = 0, []
+    for fusion in (i for body in computations.values() for i in body
+                   if i["opcode"] == "fusion" and i["name"] in parts
+                   and i["calls"] in computations):
+        inside = [i for i, _types in device_time._fused_instructions(
+            computations[fusion["calls"]], computations)
+            if i["op_name"] and i["opcode"] != "parameter" and
+            device_time.part_of(i["op_name"]) == "short_conv"]
+        if parts[fusion["name"]][0] == "short_conv":
+            products += sum(i["opcode"] in device_time._HEAVY
+                            for i in inside)
+        elif inside:
+            strays.append((fusion["name"], parts[fusion["name"]][0]))
+    assert products == 2 * 2 and strays == []

@@ -345,6 +345,26 @@ class TestController:
         finally:
             server.shutdown()
 
+    def test_the_listen_queue_holds_a_burst_of_connections(self):
+        """A warm-up that fills 64 rows opens 65 connections at once and a
+        window of 128 callers as many: the server's listen queue holds
+        them (the standard library's 5 reset some of a burst of 33), and
+        every one of a burst opened before any is answered is served."""
+        import socket
+        server = run_controller(port=0)
+        try:
+            assert server.httpd.request_queue_size >= 256
+            socks = [socket.create_connection(("127.0.0.1", server.port),
+                                              timeout=30)
+                     for _ in range(160)]
+            for sock in socks:
+                sock.sendall(b"GET /models HTTP/1.0\r\n\r\n")
+            for sock in socks:
+                assert sock.recv(64).startswith(b"HTTP/1.0 200")
+                sock.close()
+        finally:
+            server.shutdown()
+
     def test_http_streaming_with_registered_prefix(self):
         """A model registered with a system prompt serves streamed
         suffixes whose outputs equal whole-prompt greedy decoding."""
