@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
+from alpa_tpu.telemetry import metrics as tmetrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -606,6 +607,11 @@ ATTENTION_SCOPE = "attention"
 CACHE_WRITE_SCOPE = "cache_write"
 
 
+# the lanes of a TPU vector register: the extent the compiler tiles an
+# array's minor-most dimension to
+LANES = 128
+
+
 def _write_rows(cache, new, index):
     """``cache`` (B, S, H, D) with ``new`` (B, s, H, D) written at
     positions ``index[r] .. index[r] + s - 1`` of each row ``r``: one
@@ -614,14 +620,44 @@ def _write_rows(cache, new, index):
     wants its operand row-major and costs a copy of the whole cache into
     that order and one back (``tests/serve/test_decode_in_place.py``).
 
+    The view the rows are written through follows the head width, and
+    nothing else.  Heads of ``LANES`` channels or more fill the lanes, the
+    compiler keeps the cache as it is named, and the rows are written into
+    it as named.  Narrower heads would be padded to the lanes, so the
+    compiler keeps such a cache with its POSITIONS minor-most
+    (``{1,3,2,0}``), and the rows are written into the cache seen as it
+    lies, ((B H D), S), through ``_write_latent_rows``: the transposes
+    and reshapes there and back compile to bitcasts.  Written as named, a
+    narrow-head cache whose new keys reach the write rotated (LFM2,
+    CodeGen) or packed a head (Bloom) is moved whole into the keys' order
+    for the writes and back for the attention, four copies of a cache a
+    layer a tick (``test_lfm2_decode_moves_no_cache``,
+    ``test_decode_moves_no_cache``); OPT's, which never was, writes its
+    rows a little faster as it lies too (PERF.md section 6, PR 39).
+
     A row whose write does not fit (``index[r]`` outside ``[0, S - s]``)
     stays as it was: ``dynamic_update_slice`` clamps its start, so such a
     row writes back the ``s`` positions it read there.
     """
-    seq_len, s = cache.shape[1], new.shape[1]
+    b, seq_len, heads, dim = cache.shape
+    s = new.shape[1]
+    narrow = dim < LANES
+    # at trace time: which view this cache's rows were written through
+    tmetrics.get_registry().gauge(
+        "alpa_cache_row_write_view",
+        "caches whose per-row writes were traced through each view, by the "
+        "cache's heads and head width", ("view", "heads", "head_dim")).labels(
+            "positions_minor" if narrow else "as_named", heads, dim).inc()
+    if narrow:
+        def as_it_lies(x):
+            return x.transpose(0, 2, 3, 1).reshape(b, heads * dim,
+                                                   x.shape[1])
+        written = _write_latent_rows(as_it_lies(cache), as_it_lies(new),
+                                     index, 2)
+        return written.reshape(b, heads, dim, seq_len).transpose(0, 3, 1, 2)
     fits = (index >= 0) & (index <= seq_len - s)
     start = jnp.clip(index, 0, seq_len - s)
-    for r in range(cache.shape[0]):
+    for r in range(b):
         at = (r, start[r], 0, 0)
         old = jax.lax.dynamic_slice(cache, at, (1,) + new.shape[1:])
         cache = jax.lax.dynamic_update_slice(
@@ -651,7 +687,11 @@ def update_kv_cache(kv_cache, k, v):
     mask shows a query its whole block: what such a step holds of a row
     ends on a block's edge, ``Generator`` sees to that.)
 
-    Per-row indices: a row whose ``s`` positions do not all fit in the
+    Per-row indices: one ``dynamic_update_slice`` a row, through the view
+    of the cache that its head width decides (``_write_rows``: as named
+    for heads that fill the chip's lanes, as the cache lies, positions
+    last, for narrower ones), so that no caller and no configuration
+    chooses.  A row whose ``s`` positions do not all fit in the
     cache is not written at all (``_write_rows``); its index advances all
     the same.  No caller lets an active row get there (``generate``, the
     speculative rounds and the engine's ``submit`` refuse a request that
@@ -745,15 +785,23 @@ def update_ring_cache(kv_cache, k, v, lengths=None):
 
 
 def _write_latent_rows(cache, new, index, axis):
-    """``_write_rows`` for a latent layer's cache, which has no heads:
-    ``cache`` (B, S, D) with ``new`` (B, s, D) written at positions
-    ``index[r] ..`` of each row ``r`` along ``axis`` 1, or (B, D, S) with
-    (B, D, s) along ``axis`` 2.  The rows are written into the cache seen
-    as two dimensions, (B S, D) or (B D, S), one ``dynamic_update_slice``
-    a row.  Seen as three the TPU compiler moves the whole cache into an
-    order with the rows next to the channels for the writes and back for
-    the attention, two copies of the cache a layer a tick; two dimensions
-    leave it one order (``tests/serve/test_decode_in_place.py``)."""
+    """The per-row write of a cache that has no heads, or whose heads
+    ``_write_rows`` has folded into the channels: ``cache`` (B, S, D) with
+    ``new`` (B, s, D) written at positions ``index[r] ..`` of each row
+    ``r`` along ``axis`` 1 (a latent layer's normed latents), or (B, D, S)
+    with (B, D, s) along ``axis`` 2 (its rotated shared keys, and every
+    narrow-head cache as it lies).  The rows are written into the cache
+    seen as two dimensions, (B S, D) or (B D, S), one
+    ``dynamic_update_slice`` a row, with ``_write_rows``' guard for a row
+    that does not fit.  Seen as three the TPU compiler moves the whole
+    cache into an order with the rows next to the channels for the writes
+    and back for the attention, two copies of the cache a layer a tick
+    (ten in an OPT decode of two layers, four in LFM2's attention layer);
+    two dimensions leave it one order
+    (``tests/serve/test_decode_in_place.py``:
+    ``test_latent_decode_holds_no_per_head_cache``,
+    ``test_decode_moves_no_cache``, ``test_lfm2_decode_moves_no_cache``).
+    """
     b, seq_len, s = cache.shape[0], cache.shape[axis], new.shape[axis]
     fits = (index >= 0) & (index <= seq_len - s)
     start = jnp.clip(index, 0, seq_len - s)

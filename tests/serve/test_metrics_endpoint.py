@@ -76,6 +76,10 @@ class TestMetricsEndpoint:
             assert 'alpa_serving_requests_total{outcome="ok"}' in text
             assert "alpa_serving_batch_size_bucket" in text
             assert "alpa_serving_queue_depth" in text
+            # set when the engine's decode was traced: heads of 8 channels
+            # are narrower than the lanes
+            assert ('alpa_cache_row_write_view{view="positions_minor",'
+                    'heads="4",head_dim="8"}') in text
             assert "alpa_fault_health_state" in text
             assert "alpa_watchdog_last_ok_timestamp" in text
         finally:
