@@ -714,6 +714,62 @@ def update_kv_cache(kv_cache, k, v):
     return k_cache, v_cache, index + k.shape[1]
 
 
+def cached_attention(q, k_cache, v_cache, offset, *, bias=None,
+                     block: int = 0):
+    """The attention of a full-attention layer's ``s`` new queries ``q``
+    (B, s, H, D) over its written caches (B, S, Hkv, D), as
+    ``update_kv_cache`` returns them; ``offset``, ``bias`` and ``block``
+    are ``reference_attention``'s.
+
+    One of two cores, by what the call's shapes say and nothing a caller
+    or a configuration sets.  Per-row offsets (the engine's decode tick
+    and block step), no score bias and shapes the kernel of
+    ``ops/cached_attention.py`` takes (a few new queries a row, the cache
+    in whole key blocks: its ``fits``): a program lowered for a TPU runs
+    that kernel, which reads of every row's cache the key blocks the
+    row's queries can see, through the view the rows were written in
+    (``_write_rows``: heads of whole lanes as named, narrower ones with
+    the positions in the lanes), and any other platform
+    ``reference_attention``.  Every other call (a scalar offset: a prefill
+    chunk, ``generate``, a verify step; a packed prefill's bias) is
+    ``reference_attention`` over every position the cache can hold, the
+    causal offset alone hiding what a row has not reached.  The gauge
+    ``alpa_cached_attention_core`` says at trace time which one a
+    program's layers took."""
+    from alpa_tpu.ops import cached_attention as kernel
+    offset = jnp.asarray(offset, jnp.int32)
+    key_blocks = offset.ndim == 1 and bias is None and kernel.fits(q, k_cache)
+    tmetrics.get_registry().gauge(
+        "alpa_cached_attention_core",
+        "full-attention layers whose attention over the written cache was "
+        "traced with each core (key_blocks: where lowered for a TPU, the "
+        "kernel that reads each row's cache as far as the row has written; "
+        "reference: every position the cache can hold), by the cache's "
+        "heads, head width and the new queries a row",
+        ("core", "heads", "head_dim", "queries")).labels(
+            "key_blocks" if key_blocks else "reference", k_cache.shape[2],
+            k_cache.shape[3], q.shape[1]).inc()
+    if not key_blocks:
+        return reference_attention(q, k_cache, v_cache, causal=True,
+                                   offset=offset, bias=bias, block=block)
+    return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
+
+
+@partial(jax.jit, static_argnames="block")
+def _attention_over_key_blocks(q, k_cache, v_cache, offset, block):
+    """``cached_attention``'s core over key blocks: the kernel where the
+    program is lowered for a TPU, ``reference_attention`` anywhere else.
+    A ``jit`` of its own, so that a program of many layers traces and
+    lowers the kernel once and not once a layer (24 times in OPT-1.3B's
+    decode, a second of every set-up)."""
+    from alpa_tpu.ops import cached_attention as kernel
+    return jax.lax.platform_dependent(
+        q, k_cache, v_cache, offset,
+        tpu=partial(kernel.cached_attention, block=block),
+        default=lambda q, k, v, offset: reference_attention(
+            q, k, v, causal=True, offset=offset, block=block))
+
+
 def update_ring_cache(kv_cache, k, v, lengths=None):
     """``update_kv_cache`` for a "sliding" layer, whose cache is a ring:
     ``kv_cache`` is (k_cache, v_cache, index) with caches of (B, W, Hkv, D),
@@ -1218,9 +1274,8 @@ class SelfAttention(nn.Module):
                 # the written caches as they lie: the causal offset alone
                 # hides what a row has not reached (``update_kv_cache``);
                 # attn_bias (packed prefill's segment mask) rides on top
-                out = reference_attention(q, *new_cache[:2], causal=True,
-                                          offset=index, bias=attn_bias,
-                                          block=block)
+                out = cached_attention(q, *new_cache[:2], index,
+                                       bias=attn_bias, block=block)
             elif attn_bias is not None or window or nkv != nh or block:
                 # additive padding/score bias: encoder path only (the
                 # flash/ring kernels take no bias operand, no window and
@@ -1490,6 +1545,36 @@ def kv_cache_kinds(config) -> list:
     return ["window" if kind == "sliding" else kind for kind in
             ([kinds] * config.num_layers if isinstance(kinds, str)
              else kinds)]
+
+
+def cached_key_block(config, queries: int) -> int:
+    """The positions in one key block of the attention core that a tick
+    of ``queries`` new positions a row, at per-row offsets, takes over the
+    caches of ``config`` that hold the served context, where the program
+    is lowered for a TPU: ``cached_attention``'s rule for "full" layers,
+    ``latent_attention_absorbed``'s for "latent" ones.  A row then reads
+    its positions rounded up to whole key blocks.  0: the core is the one
+    over every position the cache can hold (another decoder family's
+    configuration, whose layers call ``reference_attention`` themselves,
+    too)."""
+    if not isinstance(config, GPTConfig):
+        return 0
+    from alpa_tpu.ops import cached_attention, latent_attention
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, config.dtype)
+
+    kinds = kv_cache_kinds(config)
+    if "latent" in kinds:
+        takes = latent_attention.absorbed_fits(
+            of(1, queries, config.num_heads, config.kv_lora_rank),
+            of(1, config.seq_len, config.kv_lora_rank))
+        return latent_attention.DECODE_BLOCK_K if takes else 0
+    takes = "full" in kinds and cached_attention.fits(
+        of(1, queries, config.num_heads, config.head_size),
+        of(1, config.seq_len, config.kv_heads, config.head_size))
+    return cached_attention.block_k(config.kv_heads, config.head_size) \
+        if takes else 0
 
 
 def latent_kv_caches(config) -> bool:

@@ -85,10 +85,11 @@ def _start(m_ref, l_ref, acc_ref):
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
-def _fold_in(s, seen, values, m_ref, l_ref, acc_ref):
+def _fold_in(s, seen, values, m_ref, l_ref, acc_ref, keys_last=False):
     """One block of the online softmax: scores ``s`` (queries, keys)
     float32 of which ``seen`` count, and the keys' ``values`` (keys, d),
-    into the running maximum, sum and weighted values."""
+    or (d, keys) with ``keys_last``, into the running maximum, sum and
+    weighted values."""
     m_prev = m_ref[:]
     m_new = jnp.maximum(
         m_prev, jnp.max(jnp.where(seen, s, _FLOOR), axis=1, keepdims=True))
@@ -96,8 +97,10 @@ def _fold_in(s, seen, values, m_ref, l_ref, acc_ref):
     keep = jnp.exp(m_prev - m_new)
     m_ref[:] = m_new
     l_ref[:] = l_ref[:] * keep + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * keep + jnp.dot(
-        p.astype(values.dtype), values, preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * keep + lax.dot_general(
+        p.astype(values.dtype), values,
+        (((1,), (1 if keys_last else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _finish(o_ref, l_ref, acc_ref):

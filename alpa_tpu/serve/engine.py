@@ -25,8 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu import fault
-from alpa_tpu.model.gpt_model import (init_kv_caches, kv_cache_kinds,
-                                      require_one_token_steps)
+from alpa_tpu.model.gpt_model import (cached_key_block, init_kv_caches,
+                                      kv_cache_kinds, require_one_token_steps)
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
                                        fresh_kv_caches, read_block,
                                        row_length, sample_rows)
@@ -87,6 +87,14 @@ _DECODE_POSITIONS = _REG.counter(
     "prompt and the tokens it has so far, the new one included), summed "
     "over the rows and the ticks: what an attention that reads of a cache "
     "what its row holds has to read, in positions")
+_DECODE_POSITIONS_READ = _REG.counter(
+    "alpa_serving_decode_positions_read_total",
+    "Cache positions the decode ticks' attention cores fetched for the "
+    "active rows: each row's positions of "
+    "alpa_serving_decode_positions_total rounded up to the whole key "
+    "blocks its core reads, or the whole served context a row where the "
+    "program's core reads every position the cache can hold (held over "
+    "read: how much of what the ticks fetched was wanted)")
 _BLOCK_FORWARDS = _REG.counter(
     "alpa_serving_block_forwards_total",
     "Forwards of a block by the active rows of an engine that generates "
@@ -114,6 +122,12 @@ _KV_CACHE_BYTES = _REG.gauge(
 # which overlap each other, on their own)
 _TRACK = "serve-engine"
 _QUEUE_TRACK = "serve-queue"
+
+
+def _lowered_for_tpu() -> bool:
+    """Whether the generator's programs are lowered for a TPU (they run
+    on the default backend)."""
+    return jax.default_backend() == "tpu"
 
 
 def _phase(rec, name, args=None):
@@ -293,6 +307,13 @@ class ContinuousBatchingEngine:
         self.B = max_batch
         self.bucket = prompt_bucket or generator.prompt_buckets[0]
         cfgm = generator.config
+        # the positions in a key block of the tick's attention core, 0
+        # where it reads every position the cache can hold
+        # (``_positions_read``): a program lowered for a TPU takes the
+        # cores over key blocks by its shapes alone
+        self._key_block = cached_key_block(
+            cfgm, cfgm.block_length if self._blocks else 1) \
+            if _lowered_for_tpu() else 0
         self._prefix = prefix
         # what a dense admission pads to; empty where none can happen
         self._ladder = [] if chunked_admission or prefix is not None \
@@ -992,7 +1013,7 @@ class ContinuousBatchingEngine:
             self._count_routing(routing)
         with self._cv:
             with _phase(rec, "engine.deliver") as deliver_span:
-                delivered = positions = 0
+                delivered = positions = read = 0
                 denoising = committing = unmasked = 0
                 for r in range(self.B):
                     if not self._active[r]:
@@ -1021,6 +1042,7 @@ class ContinuousBatchingEngine:
                         item["skip"] = 0
                     # what the step just enqueued attends over for row r
                     positions += item["held"] + length
+                    read += self._positions_read(item["held"] + length)
                     if over:
                         self._finish_row(r, item)
                 _BLOCK_FORWARDS.labels("denoise").inc(denoising)
@@ -1028,6 +1050,7 @@ class ContinuousBatchingEngine:
                 _BLOCKS_COMMITTED.inc(committing)
                 _BLOCK_UNMASKED.inc(unmasked)
                 _DECODE_POSITIONS.inc(positions)
+                _DECODE_POSITIONS_READ.inc(read)
                 if rec is not None:
                     deliver_span.args = {"tokens": delivered}
                     tick_span.args.update(denoising=denoising,
@@ -1036,6 +1059,15 @@ class ContinuousBatchingEngine:
             # refill freed rows before the next tick
             self._admit_locked(rec)
             _ACTIVE_ROWS.set(int(self._active.sum()))
+
+    def _positions_read(self, held: int) -> int:
+        """Of a row that holds ``held`` positions, those the tick's
+        attention core fetches: whole key blocks up to the row's newest
+        position, or the whole served context."""
+        seq_len = self.gen.config.seq_len
+        if not self._key_block:
+            return seq_len
+        return min(-(-held // self._key_block) * self._key_block, seq_len)
 
     def _step(self, rec=None, tick_span=None):
         """One decode tick for every active row.  ``rec``: see ``_phase``;
@@ -1089,7 +1121,7 @@ class ContinuousBatchingEngine:
 
         with self._cv:
             with _phase(rec, "engine.deliver") as deliver_span:
-                delivered = positions = 0
+                delivered = positions = read = 0
                 for r in range(self.B):
                     if not self._active[r]:
                         continue
@@ -1097,10 +1129,13 @@ class ContinuousBatchingEngine:
                     over = self._deliver_token(item, int(nxt[r]))
                     delivered += 1
                     # what the decode just enqueued attends over for row r
-                    positions += len(item["prompt"]) + len(item["tokens"])
+                    held = len(item["prompt"]) + len(item["tokens"])
+                    positions += held
+                    read += self._positions_read(held)
                     if over or item.get("cancelled"):
                         self._finish_row(r, item)
                 _DECODE_POSITIONS.inc(positions)
+                _DECODE_POSITIONS_READ.inc(read)
                 if rec is not None:
                     deliver_span.args = {"tokens": delivered}
             # refill freed rows before the next tick

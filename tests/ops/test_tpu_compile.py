@@ -10,6 +10,7 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every pytest
 worker imports every test file.
 """
+import functools
 import importlib
 import os
 
@@ -78,6 +79,43 @@ def test_flash_kernels_compile_for_v5e(one_chip, fn, shape, residuals,
     assert jax.default_backend() == "cpu"
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == n_kernels
+
+
+# the serving cells' ticks over their written caches, as (id, rows, served
+# context, heads, key/value heads, head width, new positions a row, the
+# mask's block): SDAR's block step, Trinity's, LFM2's and OPT-1.3B's
+# decodes
+CACHED_CASES = [
+    ("sdar-block-step", 32, 8192, 32, 4, 128, 4, 4),
+    ("trinity-decode", 16, 16384, 32, 4, 128, 1, 0),
+    ("lfm2-decode", 64, 8192, 32, 8, 64, 1, 0),
+    ("opt-decode", 4, 2048, 32, 32, 64, 1, 0),
+]
+
+
+@pytest.mark.parametrize("rows,seq_len,heads,kv_heads,dim,s,block",
+                         [c[1:] for c in CACHED_CASES],
+                         ids=[c[0] for c in CACHED_CASES])
+def test_cached_attention_compiles_for_v5e(one_chip, rows, seq_len, heads,
+                                           kv_heads, dim, s, block):
+    """``ops/cached_attention.py`` at the cells' shapes, in both views of a
+    cache: one Pallas kernel, inside its fast memory, and no array of a
+    cache's size beside the caches themselves (the kernel is handed each
+    cache as it lies: a view, not a copy)."""
+    from alpa_tpu.ops import cached_attention as ca
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, cache = spec(rows, s, heads, dim), spec(rows, seq_len, kv_heads, dim)
+    assert ca.fits(q, cache)
+    assert jax.default_backend() == "cpu"
+    compiled = jax.jit(functools.partial(
+        ca.cached_attention, block=block)).lower(
+            q, cache, cache, spec(rows, dtype=jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
 # the expert layers' grouped matmuls at published widths, as (id, (rows,

@@ -772,17 +772,25 @@ def test_caches_of_two_shapes_land_in_their_own_buffers(trinity_decode):
         assert len(writes) == 2 * layers * TRINITY_ROWS
 
 
-@pytest.mark.xfail(strict=True, reason=(
-    "inside the fusions of the scores' and the values' products the "
-    "compiler still reads every grouped-query cache through a copy from "
-    "the entry layout {3,2,1,0:T(4,128)}, in which the rows are written in "
-    "place, into {3,1,2,0:T(8,128)}, the heads outside the positions: a "
-    "conversion on the way into the product, no array of its own (ENTRY "
-    "holds none since ISSUE 37: test_attention_reads_the_cache_as_it_lies); "
-    "what it costs only the chip says (PERF.md, section 7)"))
-def test_trinity_decode_relays_no_cache(trinity_decode):
+@pytest.mark.parametrize("kind", ["full", pytest.param(
+    "window", marks=pytest.mark.xfail(strict=True, reason=(
+        "inside the fusions of the scores' and the values' products the "
+        "compiler reads each of the four RING caches through a copy from "
+        "the entry layout {3,2,1,0:T(4,128)}, in which the rows are "
+        "written in place, into {3,1,2,0:T(8,128)}, the heads outside the "
+        "positions: a conversion on the way into the product, no array of "
+        "its own.  A ring's attention goes by the position a slot holds "
+        "(``k_positions``) and stays ``reference_attention``; the full "
+        "layer's cache, which the kernel over key blocks reads as it lies "
+        "since ISSUE 40, is never relaid")))])
+def test_trinity_decode_relays_no_cache(trinity_decode, kind):
+    """No instruction of the compiled decode holds a cache in another
+    dimension order than the one its rows are written in."""
     hlo, shapes, _ = trinity_decode
+    full = max(shapes, key=lambda shape: shape[1])
     for shape in set(shapes):
+        if (shape == full) != (kind == "full"):
+            continue
         other = re.findall(r"%s\{(?!3,2,1,0)[\d,]+" %
                            re.escape(_cache_type(shape)), hlo)
         assert other == []
@@ -1025,3 +1033,69 @@ def test_lfm2_chunk_step_runs_the_mixers_products_in_their_part(
         elif inside:
             strays.append((fusion["name"], parts[fusion["name"]][0]))
     assert products == 2 * 2 and strays == []
+
+
+# ---- the tick's attention reads what the rows hold (ISSUE 40) ----------
+
+def _kernel_calls(hlo):
+    """The operands of every Pallas kernel call in ENTRY."""
+    body = re.search(r"^ENTRY .*?\n\}", hlo, re.S | re.M).group(0)
+    return [re.findall(r"%([^,) ]+)", operands) for operands in re.findall(
+        r" custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+        body)]
+
+
+def _what_makes(hlo, name):
+    """The opcode that makes the array ``name`` of ENTRY, seen through
+    views (``bitcast``, an element of a fusion's tuple): a fusion counts
+    as its root."""
+    found, _types = _entry(hlo)
+    by_name = {n: (op, operand) for n, _result, op, operand in found}
+    roots = _root_opcodes(hlo)
+    op, operand = by_name[name]
+    while op in ("bitcast", "get-tuple-element"):
+        name = operand
+        op, operand = by_name[name]
+    if op == "fusion":
+        called = re.search(r"%%%s = .* calls=%%([^\s,)]+)" % re.escape(name),
+                           hlo)
+        op = roots.get(called.group(1), op)
+    return op
+
+
+@pytest.mark.parametrize("program,layers", [
+    ("sdar-block-step", SDAR_LAYERS), ("trinity-decode", 1),
+    ("lfm2-decode", 1), ("opt-decode", LAYERS)])
+def test_the_tick_hands_the_kernel_the_cache_its_rows_were_written_into(
+        request, one_chip, program, layers):
+    """SDAR's block step, Trinity's decode (its one full layer), LFM2's
+    and OPT-1.3B's decodes, as their cells compile them: every layer that
+    caches the served context calls ``ops/cached_attention.py``'s kernel
+    once, and the K and V it is handed are the arrays the per-row writes
+    made, seen through a view: between ENTRY's arguments, the row writes
+    and the call stands no copy, transpose or relayout of a cache (heads
+    of 128 as named, heads of 64 with the positions in the lanes:
+    ``_write_rows``' two views are the kernel's).  The caches stay given
+    to the outputs that replace them."""
+    if program == "opt-decode":
+        gen, params, caches = _abstract_generator("gpt-opt", one_chip)
+        hlo = _compile_decode(gen, params, caches, one_chip)
+        shapes = [k.shape for k, _v, _i in caches]
+    else:
+        hlo, shapes = request.getfixturevalue(
+            program.replace("-", "_"))[:2]
+    full = max((shape for shape in shapes if len(shape) == 4),
+               key=lambda shape: shape[1])
+    rows, seq_len, kv_heads, dim = full
+    # the view the kernel is handed: (B, S Hkv, D) or (B, Hkv D, S)
+    view = _cache_type((rows, seq_len * kv_heads, dim) if dim >= 128
+                       else (rows, kv_heads * dim, seq_len))
+    calls = [operands for operands in _kernel_calls(hlo)
+             if len(operands) == 5]
+    assert len(calls) == layers
+    _found, types = _entry(hlo)
+    for operands in calls:
+        for cache in operands[3:]:
+            assert types[cache].startswith(view)
+            assert _what_makes(hlo, cache) == "dynamic-update-slice"
+    assert len(_aliases(hlo)) >= 2 * layers

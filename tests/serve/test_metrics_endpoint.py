@@ -80,6 +80,11 @@ class TestMetricsEndpoint:
             # are narrower than the lanes
             assert ('alpa_cache_row_write_view{view="positions_minor",'
                     'heads="4",head_dim="8"}') in text
+            # and which core its attention over the written cache took: a
+            # cache of 32 positions is in no whole key block
+            assert ('alpa_cached_attention_core{core="reference",'
+                    'heads="4",head_dim="8",queries="1"}') in text
+            assert "alpa_serving_decode_positions_read_total" in text
             assert "alpa_fault_health_state" in text
             assert "alpa_watchdog_last_ok_timestamp" in text
         finally:
