@@ -89,7 +89,7 @@ class BertModel(nn.Module):
                 if attention_mask is not None else None)
         for i in range(cfg.num_layers):
             x, _ = TransformerBlock(gcfg, name=f"layer_{i}")(
-                x, None, True, bias)
+                x, None, True, padding_bias=bias)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          name="final_ln")(x).astype(cfg.dtype)
         pooled = None

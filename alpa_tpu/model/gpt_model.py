@@ -714,31 +714,30 @@ def update_kv_cache(kv_cache, k, v):
     return k_cache, v_cache, index + k.shape[1]
 
 
-def cached_attention(q, k_cache, v_cache, offset, *, bias=None,
-                     block: int = 0):
+def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0):
     """The attention of a full-attention layer's ``s`` new queries ``q``
     (B, s, H, D) over its written caches (B, S, Hkv, D), as
-    ``update_kv_cache`` returns them; ``offset``, ``bias`` and ``block``
-    are ``reference_attention``'s.
+    ``update_kv_cache`` returns them; ``offset`` and ``block`` are
+    ``reference_attention``'s.
 
     One of two cores, by what the call's shapes say and nothing a caller
     or a configuration sets.  Per-row offsets (the engine's decode tick
-    and block step), no score bias and shapes the kernel of
-    ``ops/cached_attention.py`` takes (a few new queries a row, the cache
-    in whole key blocks: its ``fits``): a program lowered for a TPU runs
+    and block step) and shapes the kernel of ``ops/cached_attention.py``
+    takes (a few new queries a row, the cache in whole key blocks: its
+    ``fits``): a program lowered for a TPU runs
     that kernel, which reads of every row's cache the key blocks the
     row's queries can see, through the view the rows were written in
     (``_write_rows``: heads of whole lanes as named, narrower ones with
     the positions in the lanes), and any other platform
     ``reference_attention``.  Every other call (a scalar offset: a prefill
-    chunk, ``generate``, a verify step; a packed prefill's bias) is
-    ``reference_attention`` over every position the cache can hold, the
+    chunk, ``generate``, a verify step) is ``reference_attention`` over
+    every position the cache can hold, the
     causal offset alone hiding what a row has not reached.  The gauge
     ``alpa_cached_attention_core`` says at trace time which one a
     program's layers took."""
     from alpa_tpu.ops import cached_attention as kernel
     offset = jnp.asarray(offset, jnp.int32)
-    key_blocks = offset.ndim == 1 and bias is None and kernel.fits(q, k_cache)
+    key_blocks = offset.ndim == 1 and kernel.fits(q, k_cache)
     tmetrics.get_registry().gauge(
         "alpa_cached_attention_core",
         "full-attention layers whose attention over the written cache was "
@@ -751,7 +750,7 @@ def cached_attention(q, k_cache, v_cache, offset, *, bias=None,
             k_cache.shape[3], q.shape[1]).inc()
     if not key_blocks:
         return reference_attention(q, k_cache, v_cache, causal=True,
-                                   offset=offset, bias=bias, block=block)
+                                   offset=offset, block=block)
     return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
 
 
@@ -1065,15 +1064,14 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None, cache_lengths=None):
+                 position_ids=None, cache_lengths=None):
         cfg = self.config
         if cfg.block_length:
             raise ValueError("latent attention has no block-causal mask "
                              "(GPTConfig.block_length)")
-        if attn_bias is not None or not cfg.causal or position_ids is None:
+        if not cfg.causal or position_ids is None:
             raise ValueError("latent attention is causal over rotary "
-                             "positions and takes no score bias (packed "
-                             "sequences, padding masks)")
+                             "positions")
         nh, rank = cfg.num_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
@@ -1177,13 +1175,12 @@ class ShortConv(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None, cache_lengths=None):
+                 position_ids=None, cache_lengths=None):
         cfg = self.config
-        if attn_bias is not None or not cfg.causal or cfg.block_length:
+        if not cfg.causal or cfg.block_length:
             raise ValueError("a short convolution is causal over one "
-                             "sequence a row and takes no score bias "
-                             "(packed sequences, padding masks) and no "
-                             "block-causal mask")
+                             "sequence a row and takes no block-causal "
+                             "mask")
         h, taps = cfg.hidden_size, cfg.conv_taps
         if taps < 2:
             raise ValueError("a \"conv\" layer needs GPTConfig.conv_taps "
@@ -1214,14 +1211,19 @@ class ShortConv(nn.Module):
 
 class SelfAttention(nn.Module):
     """``attention`` is the layer's kind (``GPTConfig.attention``; None:
-    the configuration's, which must then be one kind for all layers)."""
+    the configuration's, which must then be one kind for all layers).
+    ``padding_bias`` (B, 1, 1, S), an encoder's padding mask added to the
+    scores (``bert_model.attention_mask_to_bias``), goes with no cache."""
     config: GPTConfig
     attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None, cache_lengths=None):
+                 position_ids=None, cache_lengths=None, padding_bias=None):
         cfg = self.config
+        if padding_bias is not None and kv_cache is not None:
+            raise ValueError("a padding bias goes with no cache: a cached "
+                             "call masks by the rows' offsets")
         h, nh, nkv, hd = (cfg.hidden_size, cfg.num_heads, cfg.kv_heads,
                           cfg.head_size)
         kind = self.attention or cfg.attention_kind(0)
@@ -1259,9 +1261,6 @@ class SelfAttention(nn.Module):
                              "goes with full attention layers only")
         with jax.named_scope(ATTENTION_SCOPE):
             if kv_cache is not None and window:
-                if attn_bias is not None:
-                    raise ValueError("a sliding-window layer's ring cache "
-                                     "takes no score bias (packed prefill)")
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 k_use, v_use, k_positions, new_cache = update_ring_cache(
                     kv_cache, k, v, cache_lengths)
@@ -1272,12 +1271,11 @@ class SelfAttention(nn.Module):
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 new_cache = update_kv_cache(kv_cache, k, v)
                 # the written caches as they lie: the causal offset alone
-                # hides what a row has not reached (``update_kv_cache``);
-                # attn_bias (packed prefill's segment mask) rides on top
+                # hides what a row has not reached (``update_kv_cache``)
                 out = cached_attention(q, *new_cache[:2], index,
-                                       bias=attn_bias, block=block)
-            elif attn_bias is not None or window or nkv != nh or block:
-                # additive padding/score bias: encoder path only (the
+                                       block=block)
+            elif padding_bias is not None or window or nkv != nh or block:
+                # additive padding bias: encoder path only (the
                 # flash/ring kernels take no bias operand, no window and
                 # no grouped heads)
                 if (window or nkv != nh or block) and \
@@ -1286,7 +1284,7 @@ class SelfAttention(nn.Module):
                         "sliding-window, grouped-query and block-causal "
                         "attention need attention_impl 'reference'")
                 out = reference_attention(q, k, v, causal=cfg.causal,
-                                          bias=attn_bias, window=window,
+                                          bias=padding_bias, window=window,
                                           block=block)
             else:
                 attn_fn = get_attention_fn(cfg)
@@ -1337,14 +1335,15 @@ class TransformerBlock(nn.Module):
     and the MLP return is normalised once more before it joins the
     residual stream.  Returns ``(x, new_cache)``, and a block of routed
     experts ``(x, new_cache, routing)``: what its router did
-    (``moe.DroplessExperts``)."""
+    (``moe.DroplessExperts``).  ``padding_bias`` is ``SelfAttention``'s
+    (``BertModel``'s padding mask; no decoder passes one)."""
     config: GPTConfig
     mlp: Optional[str] = None
     attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 attn_bias=None, position_ids=None, cache_lengths=None):
+                 position_ids=None, cache_lengths=None, padding_bias=None):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
@@ -1354,8 +1353,10 @@ class TransformerBlock(nn.Module):
         elif mixer == "conv":
             attn = ShortConv(cfg, name="conv")
         else:
-            attn = SelfAttention(cfg, attention=self.attention, name="attn")
-        attn_out, new_cache = attn(ln1, kv_cache, deterministic, attn_bias,
+            attn = partial(
+                SelfAttention(cfg, attention=self.attention, name="attn"),
+                padding_bias=padding_bias)
+        attn_out, new_cache = attn(ln1, kv_cache, deterministic,
                                    position_ids, cache_lengths)
         if cfg.post_norms:
             attn_out = make_norm(cfg, "ln1_post")(attn_out)
@@ -1385,20 +1386,10 @@ class GPTModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, position_ids=None, kv_caches=None,
                  deterministic=True, return_hidden=False,
-                 segment_ids=None, cache_lengths=None,
-                 return_routing=False):
+                 cache_lengths=None, return_routing=False):
         """``return_hidden=True`` returns the final (B, S, H) hidden states
         instead of logits, for a fused/chunked lm-head + loss (see
         model_util.chunked_cross_entropy_loss).
-
-        ``segment_ids`` (B, S) int32 enables PACKED sequences: tokens only
-        attend within their own segment (block-diagonal mask on top of
-        causal); ids < 0 mark padding that attends to nothing.  This is
-        the TPU-native analog of the reference's 1-D packed batching
-        (ref opt_model_1d.py fused-MHA prompt packing): one row carries
-        many prompts, masked by segments instead of a custom kernel.
-        Pass per-segment ``position_ids`` so positional embeddings
-        restart at each segment start.
 
         ``cache_lengths`` ((B,), with ``kv_caches``): the rows' whole
         lengths, where the ids are right-padded past them: a layer whose
@@ -1413,30 +1404,6 @@ class GPTModel(nn.Module):
         b, s = input_ids.shape
         if position_ids is None:
             position_ids = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-        seg_bias = None
-        if segment_ids is not None:
-            if kv_caches is not None:
-                # The packed chunk is written at the caches' current
-                # (scalar) index — 0 for a fresh packed prefill, or the
-                # prefix length when packing over a cached system prompt.
-                # Keys before that offset are the shared prefix: visible
-                # to EVERY real segment; keys past the chunk stay -2.
-                cache_len = kv_caches[0][0].shape[1]
-                start = jnp.asarray(kv_caches[0][2], jnp.int32)
-                seg_k = jnp.full((b, cache_len), -2, jnp.int32)
-                seg_k = jax.lax.dynamic_update_slice(
-                    seg_k, segment_ids, (0, start))
-                kpos = jax.lax.broadcasted_iota(
-                    jnp.int32, (1, cache_len), 1)
-                prefix_k = kpos < start                      # (1, L)
-                same = ((segment_ids[:, :, None] == seg_k[:, None, :]) |
-                        prefix_k[:, None, :]) & \
-                    (segment_ids[:, :, None] >= 0)
-            else:
-                same = (segment_ids[:, :, None] ==
-                        segment_ids[:, None, :]) & \
-                    (segment_ids[:, :, None] >= 0)
-            seg_bias = jnp.where(same, 0.0, -1e9)[:, None]  # (B,1,S,L)
         tok_emb = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                            name="wte")
@@ -1461,9 +1428,8 @@ class GPTModel(nn.Module):
                 raise ValueError(
                     f"unknown remat_policy {cfg.remat_policy!r}")
             # Under nn.remat the module instance is arg 0, so the call
-            # (x, cache_i, deterministic, seg_bias) puts kv_cache at 2
-            # and deterministic at 3 — mark BOTH static; attn_bias (4)
-            # stays a traced pytree (None or the packed segment mask)
+            # (x, cache_i, deterministic, ...) puts kv_cache at 2 and
+            # deterministic at 3 — mark BOTH static
             block_cls = nn.remat(TransformerBlock,
                                  static_argnums=(2, 3),
                                  policy=policy)
@@ -1477,8 +1443,7 @@ class GPTModel(nn.Module):
                               attention=cfg.attention_kind(i), name=f"h{i}")
             cache_i = kv_caches[i] if kv_caches is not None else None
             x, new_cache, *routing = block(
-                x, cache_i, deterministic, seg_bias, block_positions,
-                cache_lengths)
+                x, cache_i, deterministic, block_positions, cache_lengths)
             routings += routing
             if new_caches is not None:
                 new_caches.append(new_cache)
@@ -1590,9 +1555,9 @@ def conv_states(config) -> bool:
 
 def uniform_kv_caches(config) -> bool:
     """Whether every layer's cache holds positions and has one shape: what
-    the block pool, the packed prefill, the speculative verify step and
-    beam search count on (one block table, one length and one index for
-    all layers).  A short convolution's state holds no positions, so a
+    the block pool, the speculative verify step, beam search and the
+    disaggregated prefill count on (one block table, one length and one
+    index for all layers).  A short convolution's state holds no positions, so a
     configuration with one is not uniform whatever its shapes; its cached
     calls are handed the rows' lengths as a ring's are."""
     return not conv_states(config) and \
@@ -1601,7 +1566,8 @@ def uniform_kv_caches(config) -> bool:
 
 def require_uniform_kv_caches(config, what: str):
     """Raise unless every layer caches per-head K and V of one shape, as
-    ``what`` indexes them."""
+    ``what`` indexes them: the KV block pool, ``generate_speculative``,
+    ``generate_beam`` and the disaggregated ``PrefillEngine``."""
     if conv_states(config):
         raise ValueError(
             f"{what} indexes per-head K and V caches of one shape and "
@@ -1609,7 +1575,7 @@ def require_uniform_kv_caches(config, what: str):
             "short-convolution layers (GPTConfig.attention \"conv\"), "
             "whose entry is a state of the last conv_taps - 1 positions "
             "that every step overwrites: no index brings an earlier state "
-            "back, and there are no positions to page, pack or reorder: "
+            "back, and there are no positions to page or reorder: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     if latent_kv_caches(config):
         raise ValueError(
@@ -1630,7 +1596,9 @@ def require_one_token_steps(config, what: str):
     """Raise if the configuration generates by diffusion over blocks:
     ``what`` is built on one token a row a step (a step there yields
     between none and ``block_length`` tokens a row, and writes a whole
-    block's keys and values whether or not it keeps them)."""
+    block's keys and values whether or not it keeps them): the four of
+    ``require_uniform_kv_caches``, a static prefix
+    (``Generator.cache_prefix``) and an engine's prefilled admission."""
     block = getattr(config, "block_length", 0)
     if block:
         raise ValueError(

@@ -13,7 +13,6 @@ from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.hf_wrapper import WrappedInferenceModel, get_hf_model
 from alpa_tpu.serve.kv_cache import (KVBlockPool, KVPoolExhaustedError,
                                      PagedSequence)
-from alpa_tpu.serve.packed import PackedPrefill, pack_prompts
 from alpa_tpu.serve.router import (HTTPReplicaHandle, LocalReplicaHandle,
                                    Router, RouterServer)
 from alpa_tpu.serve.scheduler import (FIFOQueue, NestedScheduler,

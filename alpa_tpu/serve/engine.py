@@ -226,14 +226,12 @@ class ContinuousBatchingEngine:
     inside a block with exactly what it asked for.  The step donates the
     K and V of the resident caches as the decode does.  Such an engine
     needs ``chunked_admission`` and refuses, by name, what is built on one
-    token a row a step: ``kv_pool``, ``packed_admission``, a static
-    ``prefix`` and a prefilled (disaggregated) admission.
+    token a row a step: ``kv_pool``, a static ``prefix`` and a prefilled
+    (disaggregated) admission.
     """
 
     def __init__(self, generator: Generator, max_batch: int = 4,
                  prompt_bucket: Optional[int] = None,
-                 packed_admission: bool = False,
-                 packed_bucket: Optional[int] = None,
                  prefix: Optional[Any] = None,
                  scheduler: Optional[Any] = None,
                  kv_pool: Optional[Any] = None,
@@ -249,19 +247,11 @@ class ContinuousBatchingEngine:
         that cannot reach the dense prefill (``chunked_admission``, a
         static ``prefix``) has no ladder and compiles none of it.
 
-        ``packed_admission=True`` admits multiple queued prompts with
-        ONE packed prefill (segment-masked, serve.packed.PackedPrefill —
-        the 1-D batching analog) instead of one prefill per row; falls
-        back to per-row prefill when fewer than two prompts wait or the
-        backlog exceeds ``packed_bucket`` total tokens.
-
         ``prefix``: a ``Generator.cache_prefix`` handle shared by EVERY
         request (system prompt): each admission prefills only its
         suffix over a copy of the prefix K/V.  Requires the generator's
         chunked-prefill mode (per-row admissions ride chunked suffix
-        prefill).  Composes with ``packed_admission``: the pack is then
-        prefilled at cache offset ``prefix.length`` with the prefix
-        region attendable by every segment.
+        prefill).
 
         ``scheduler``: an admission policy speaking the queue protocol
         (``serve.scheduler``: FIFOQueue default, WeightedFairQueue,
@@ -276,7 +266,7 @@ class ContinuousBatchingEngine:
         into the row's current block.  Decode math still runs on the
         dense resident caches, so paged output is bit-exact vs unpaged.
         Mutually exclusive with ``prefix`` (warmed prefixes live in the
-        pool's index instead); disables ``packed_admission``.
+        pool's index instead).
 
         ``chunked_admission``: a prompt is prefilled in the generator's
         fixed chunks (``Generator(prefill_chunk=...)``, the step prefix
@@ -293,7 +283,6 @@ class ContinuousBatchingEngine:
         self._blocks = getattr(generator, "diffusion", None) is not None
         if self._blocks:
             for what, given in (("kv_pool (KVBlockPool)", kv_pool),
-                                ("packed_admission", packed_admission),
                                 ("a static prefix", prefix)):
                 if given:
                     require_one_token_steps(generator.config, what)
@@ -337,11 +326,6 @@ class ContinuousBatchingEngine:
                     "kv prefix reuse needs Generator(prefill_chunk=...) "
                     "to prefill suffixes from the match offset; paging "
                     "stays on but every admission recomputes its prompt")
-            if packed_admission:
-                logger.warning(
-                    "packed_admission is not block-aware; using per-row "
-                    "prefill with the KV pool")
-                packed_admission = False
         if prefix is not None:
             if not generator.prefill_chunk:
                 raise ValueError(
@@ -352,31 +336,6 @@ class ContinuousBatchingEngine:
                 # would serve plausible-but-wrong tokens silently
                 raise ValueError(
                     "PrefixHandle was built for different params")
-        self._packed = None
-        if packed_admission:
-            # packing needs segment-mask support AND position-id-based
-            # embeddings (rotary/ALiBi bake GLOBAL positions into the
-            # packed KV, which the row-local re-gather would corrupt) —
-            # GPT/OPT qualify; Bloom/CodeGen take the per-row path
-            import inspect
-            sig = inspect.signature(generator.model.__call__)
-            if "segment_ids" in sig.parameters:
-                from alpa_tpu.serve.packed import PackedPrefill
-                # clamp to the KV-cache capacity (minus any shared
-                # prefix): a packed forward longer than that cannot be
-                # written into the caches
-                plen = prefix.length if prefix is not None else 0
-                total = max(packed_bucket or 2 * self.bucket, self.bucket)
-                self._packed = PackedPrefill(
-                    generator.model, generator.params, cfgm,
-                    total_bucket=min(total, max(1, cfgm.seq_len - plen)),
-                    max_rows=self.B, prefix=prefix)
-            else:
-                logger.warning(
-                    "packed_admission requested but %s takes no "
-                    "segment_ids — using per-row prefill",
-                    type(generator.model).__name__)
-        self.packed_admissions = 0
 
         # resident state: batch KV caches + per-row bookkeeping
         self._init_resident()
@@ -411,24 +370,11 @@ class ContinuousBatchingEngine:
                             idx.at[row].set(idx1[0])))
             return new, logits.at[row].set(logits1[0].astype(logits.dtype))
 
-        # both scatters donate the engine's own caches and logits (every
-        # caller replaces them by the result) and write the admitted rows
-        # in place; caches1 / rowc may still belong to a prefix handle or
-        # a disaggregated prefill and are only read
+        # the scatter donates the engine's own caches and logits (every
+        # caller replaces them by the result) and writes the admitted row
+        # in place; caches1 may still belong to a prefix handle or a
+        # disaggregated prefill and is only read
         self._scatter_row = jax.jit(scatter_row, donate_argnums=(0, 2))
-
-        def scatter_packed(caches, rowc, logits, last, rowmap, mask):
-            new = []
-            m4 = mask[:, None, None, None]
-            for (k, v, idx), (rk, rv, rlen) in zip(caches, rowc):
-                new.append((jnp.where(m4, rk[rowmap], k),
-                            jnp.where(m4, rv[rowmap], v),
-                            jnp.where(mask, rlen[rowmap], idx)))
-            return new, jnp.where(mask[:, None],
-                                  last[rowmap].astype(logits.dtype), logits)
-
-        self._scatter_packed = jax.jit(scatter_packed,
-                                       donate_argnums=(0, 2))
 
         def set_block(blocks, left, row, block):
             return (blocks.at[row].set(block),
@@ -678,8 +624,7 @@ class ContinuousBatchingEngine:
         return -(-n // c) * c
 
     def _admit_locked(self, rec=None):
-        """Fill free rows from the queue: one packed prefill when several
-        prompts wait (and packing is on), else per-row prefills.
+        """Fill free rows from the queue, a prefill a row.
 
         Admission failures (trace/compile/device errors) fail ONLY the
         requests being admitted — the engine loop and resident rows
@@ -705,55 +650,6 @@ class ContinuousBatchingEngine:
                     return nxt
                 self._queue.popleft()["done"].set()
 
-        if self._packed is not None and len(self._queue) >= 2:
-            free = [r for r in range(self.B) if not self._active[r]]
-            take, total = [], 0
-            while len(take) < len(free):
-                nxt = next_live()
-                if nxt is None or total + len(nxt["prompt"]) > \
-                        self._packed.total_bucket:
-                    break
-                item = self._queue.popleft()
-                take.append(item)
-                total += len(item["prompt"])
-            if len(take) >= 2:
-                try:
-                    for item in take:
-                        self._row_taken(rec, item)
-                    with _phase(rec, "engine.prefill",
-                                {"rid": [it["rid"] for it in take],
-                                 "prompt_len": total,
-                                 "padded_len": self._packed.total_bucket,
-                                 "path": "packed"}
-                                if rec is not None else None):
-                        last, row_caches = self._packed(
-                            [it["prompt"] for it in take])
-                    _PREFILL_PROMPT.inc(total)
-                    _PREFILL_PADDED.inc(self._packed.total_bucket)
-                    rowmap = np.zeros((self.B,), np.int32)
-                    mask = np.zeros((self.B,), bool)
-                    for slot, item in enumerate(take):
-                        r = free[slot]
-                        rowmap[r] = slot
-                        mask[r] = True
-                        self._give_row(r, item)
-                    self._caches, self._logits = self._scatter_packed(
-                        self._caches, row_caches, self._logits, last,
-                        jnp.asarray(rowmap), jnp.asarray(mask))
-                    self.packed_admissions += 1
-                except Exception as e:  # pylint: disable=broad-except
-                    logger.exception("packed admission failed")
-                    for item in take:
-                        item["error"] = e
-                        item["done"].set()
-                        for r in range(self.B):
-                            if self._rows[r] is item:
-                                self._active[r] = False
-                                self._rows[r] = None
-                    self._recover_resident_locked(e)
-            else:
-                # not enough for a pack: put back and fall through
-                self._queue.pushback(take)
         for r in range(self.B):
             if self._active[r]:
                 continue

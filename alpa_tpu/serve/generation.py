@@ -385,8 +385,8 @@ class Generator:
     the others cache ``(k, v, index)``: everything here hands a layer's
     entry on as a triple and looks into none, so prefill in chunks, the
     donating decode and the engine's ``_scatter_row`` serve it as they are;
-    what indexes per-head K and V (the block pool, the packed prefill, the
-    speculative verify step, beam search) refuses it
+    what indexes per-head K and V (the block pool, the speculative verify
+    step, beam search) refuses it
     (``require_uniform_kv_caches``).
 
     A short-convolution layer (``GPTConfig.attention`` "conv") holds no
@@ -437,7 +437,7 @@ class Generator:
     program.  What is built on one token a row a step refuses such a
     configuration by name (``require_one_token_steps``):
     ``generate_speculative``, ``generate_beam``, ``cache_prefix`` (a static
-    prefix), the KV block pool, the packed prefill, ``serve/disagg.py``.
+    prefix), the KV block pool, ``serve/disagg.py``.
     """
 
     def __init__(self, model: GPTModel, params, config: GPTConfig,

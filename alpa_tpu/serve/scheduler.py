@@ -19,9 +19,9 @@ All schedulers speak the engine's queue protocol:
   the request batcher forms sampling-compatible batches without
   destroying the policy state: skipped items keep their original
   virtual-time tags, and only actually-taken items advance service.
-* ``pushback(items)`` — return items popped moments ago to the FRONT
-  (the engine's speculative packed-admission path; the hold lasts one
-  engine tick, so front-of-queue semantics are exact enough there).
+* ``pushback(items)`` — return items popped moments ago to the FRONT,
+  in their order (``take``'s exception safety: what a selector that
+  raises had already taken goes back ahead of all policy-ordered work).
 * ``drain()`` — destructive empty-out in policy order (shutdown).
 * ``__len__``.
 """

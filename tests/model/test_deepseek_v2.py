@@ -29,10 +29,10 @@ from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf,
                                       latent_attention_absorbed,
                                       latent_attention_expanded,
                                       uniform_kv_caches, yarn_inv_freq)
+from alpa_tpu.serve.disagg import PrefillEngine
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
-from alpa_tpu.serve.packed import PackedPrefill
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -501,19 +501,20 @@ def test_rows_of_mixed_lengths_in_one_engine(reference, toy):
         len(p) * n + n * (n + 1) // 2 for p, n in zip(prompts, new))
 
 
-@pytest.mark.parametrize("what", ["pool", "packed", "speculative", "beam"])
+@pytest.mark.parametrize("what", ["pool", "disaggregated", "speculative",
+                                  "beam"])
 def test_a_latent_cache_is_refused_by_name(toy, what):
-    """The block pool, the packed prefill, the speculative verify step and
-    beam search index per-head K and V of one shape: they refuse a latent
-    cache, and say so."""
+    """The block pool, the disaggregated prefill, the speculative verify
+    step and beam search index per-head K and V of one shape: they refuse
+    a latent cache, and say so."""
     model, params, ids = toy
     cfg = toy_config()
     gen = Generator(model, params, cfg, prefill_chunk=8)
     with pytest.raises(ValueError, match="hold a latent cache"):
         if what == "pool":
             KVBlockPool.for_generator(gen, block_size=8)
-        elif what == "packed":
-            PackedPrefill(model, params, cfg, total_bucket=32, max_rows=2)
+        elif what == "disaggregated":
+            PrefillEngine(gen)
         elif what == "speculative":
             gen.generate_speculative(gen, np.asarray(ids[0, :5]))
         else:

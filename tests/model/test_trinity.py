@@ -25,10 +25,10 @@ from alpa_tpu.model import moe
 from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf,
                                       init_kv_caches, kv_cache_shapes,
                                       uniform_kv_caches)
+from alpa_tpu.serve.disagg import PrefillEngine
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
-from alpa_tpu.serve.packed import PackedPrefill
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -295,19 +295,20 @@ def test_engine_counts_the_experts_its_decodes_touched(toy):
         2 * CONTEXT * position
 
 
-@pytest.mark.parametrize("what", ["pool", "packed", "speculative", "beam"])
+@pytest.mark.parametrize("what", ["pool", "disaggregated", "speculative",
+                                  "beam"])
 def test_one_cache_shape_for_all_layers_is_asked_for(toy, what):
-    """The block pool, the packed prefill, the speculative verify step and
-    beam search index one cache shape for all layers: they refuse a
-    configuration whose layers differ, and say why."""
+    """The block pool, the disaggregated prefill, the speculative verify
+    step and beam search index one cache shape for all layers: they refuse
+    a configuration whose layers differ, and say why."""
     model, params, ids = toy
     cfg = toy_config()
     gen = Generator(model, params, cfg, prefill_chunk=4)
     with pytest.raises(ValueError, match="one cache shape for all layers"):
         if what == "pool":
             KVBlockPool.for_generator(gen, block_size=8)
-        elif what == "packed":
-            PackedPrefill(model, params, cfg, total_bucket=32, max_rows=2)
+        elif what == "disaggregated":
+            PrefillEngine(gen)
         elif what == "speculative":
             gen.generate_speculative(gen, np.asarray(ids[0, :5]))
         else:

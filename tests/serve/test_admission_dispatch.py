@@ -20,8 +20,7 @@ from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 
 BUCKET, CHUNK = 32, 16
-PATHS = {"dense": {}, "chunked": {"chunked_admission": True},
-         "packed": {"packed_admission": True, "packed_bucket": 2 * BUCKET}}
+PATHS = {"dense": {}, "chunked": {"chunked_admission": True}}
 
 
 def _engine(layers, path, vocab=67):
@@ -123,7 +122,7 @@ def test_an_admission_dispatches_the_same_programs_at_any_depth(
 
 def _stream_pair(engine, prompts, cfg):
     """Both prompts queued before the engine can take either (the lock is
-    re-entrant), so a packing engine packs them; their streamed tokens."""
+    re-entrant); their streamed tokens."""
     with engine._cv:
         streams = [engine.submit_stream(p, cfg) for p in prompts]
     return [list(s) for s in streams]
@@ -143,8 +142,6 @@ def test_a_short_prompt_after_a_long_one_reads_nothing_of_it(path):
         for pair in ((long_a, long_b), (short_a, short_b)):
             got = _stream_pair(engine, pair, cfg)
             assert got == [alone[p.tobytes()] for p in pair]
-        if path == "packed":
-            assert engine.packed_admissions == 2
 
         def live():
             # with the lock held the engine's thread is between two turns

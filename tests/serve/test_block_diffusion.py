@@ -278,11 +278,6 @@ def _engine(**kwargs):
     return build
 
 
-def _packed(gen):
-    from alpa_tpu.serve.packed import PackedPrefill
-    PackedPrefill(gen.model, gen.params, gen.config, 32, 2)
-
-
 def _disagg(gen):
     from alpa_tpu.serve.disagg import PrefillEngine
     PrefillEngine(gen)
@@ -300,16 +295,14 @@ def _prefilled(gen):
 @pytest.mark.parametrize("what, build", [
     ("KVBlockPool", _kv_pool),
     ("kv_pool", _engine(kv_pool=object())),
-    ("packed_admission", _engine(packed_admission=True)),
-    ("packed_admission", _packed),
     ("a static prefix", _engine(prefix=object())),
     ("a static prefix", lambda gen: gen.cache_prefix(prompt_of(8))),
     ("generate_speculative",
      lambda gen: gen.generate_speculative(gen, prompt_of(8))),
     ("generate_beam", lambda gen: gen.generate_beam(prompt_of(8))),
     ("disagg", _disagg), ("disaggregated", _prefilled)],
-    ids=["pool", "engine-pool", "engine-packed", "packed", "engine-prefix",
-         "cache-prefix", "speculative", "beam", "disagg", "prefilled"])
+    ids=["pool", "engine-pool", "engine-prefix", "cache-prefix",
+         "speculative", "beam", "disagg", "prefilled"])
 def test_one_token_a_step_refuses_the_configuration_by_name(toy, what,
                                                             build):
     with pytest.raises(ValueError) as refused:

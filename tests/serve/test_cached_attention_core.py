@@ -47,34 +47,30 @@ def _core(core, heads, head_dim, queries):
             f'head_dim="{head_dim}",queries="{queries}"}}')
 
 
-# (id, configuration, new positions, per-row index, a packed prefill's
-#  segments, the series that move)
+# (id, configuration, new positions, per-row index, the series that move)
 CALLS = [
-    ("a-decode-tick", WIDE, 1, True, False,
+    ("a-decode-tick", WIDE, 1, True,
      {_core("key_blocks", 4, 128, 1): 1}),
-    ("a-block-step", dict(WIDE, block_length=4), 4, True, False,
+    ("a-block-step", dict(WIDE, block_length=4), 4, True,
      {_core("key_blocks", 4, 128, 4): 1}),
-    ("a-narrow-head-decode-tick", NARROW, 1, True, False,
+    ("a-narrow-head-decode-tick", NARROW, 1, True,
      {_core("key_blocks", 16, 64, 1): 1}),
-    ("a-prefill-chunk", WIDE, 1024, False, False,
+    ("a-prefill-chunk", WIDE, 1024, False,
      {_core("reference", 4, 128, 1024): 1}),
-    ("a-chunk-at-per-row-offsets", WIDE, 1024, True, False,
+    ("a-chunk-at-per-row-offsets", WIDE, 1024, True,
      {_core("reference", 4, 128, 1024): 1}),
-    ("a-scalar-index-generate-step", WIDE, 1, False, False,
+    ("a-scalar-index-generate-step", WIDE, 1, False,
      {_core("reference", 4, 128, 1): 1}),
-    ("a-packed-prefill", WIDE, 64, False, True,
-     {_core("reference", 4, 128, 64): 1}),
     ("a-ring-layer", dict(WIDE, attention="sliding", sliding_window=512,
-                          positions="rotary"), 1, True, False, {}),
+                          positions="rotary"), 1, True, {}),
     ("a-cache-in-no-whole-key-blocks", dict(WIDE, seq_len=1536), 1, True,
-     False, {_core("reference", 4, 128, 1): 1}),
+     {_core("reference", 4, 128, 1): 1}),
 ]
 
 
-@pytest.mark.parametrize("config,s,per_row,packed,moves",
+@pytest.mark.parametrize("config,s,per_row,moves",
                          [c[1:] for c in CALLS], ids=[c[0] for c in CALLS])
-def test_a_traced_call_says_which_core_it_took(config, s, per_row, packed,
-                                               moves):
+def test_a_traced_call_says_which_core_it_took(config, s, per_row, moves):
     """Tracing a cached call of a one-layer model (nothing runs) moves
     exactly one series of ``alpa_cached_attention_core``: ``key_blocks``
     for per-row offsets, a few new positions and shapes the kernel takes
@@ -87,15 +83,14 @@ def test_a_traced_call_says_which_core_it_took(config, s, per_row, packed,
     index = jax.ShapeDtypeStruct((ROWS,) if per_row else (), jnp.int32)
     ids = jax.ShapeDtypeStruct((ROWS, s), jnp.int32)
 
-    def call(params, ids, index, segments):
+    def call(params, ids, index):
         caches = [(k, v, index) for k, v, _ in init_kv_caches(cfg, ROWS)]
         positions = jnp.broadcast_to(jnp.arange(s)[None], ids.shape) + (
             index[:, None] if per_row else index)
-        return model.apply(params, ids, positions, caches,
-                           segment_ids=segments)
+        return model.apply(params, ids, positions, caches)
 
     before = _series("alpa_cached_attention_core")
-    jax.eval_shape(call, params, ids, index, ids if packed else None)
+    jax.eval_shape(call, params, ids, index)
     after = _series("alpa_cached_attention_core")
     assert {series: after[series] - before.get(series, 0)
             for series in after

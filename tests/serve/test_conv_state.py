@@ -21,7 +21,6 @@ from alpa_tpu.serve.disagg import PrefillEngine
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
-from alpa_tpu.serve.packed import PackedPrefill
 from alpa_tpu.telemetry import metrics as tmetrics
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -164,11 +163,10 @@ def test_the_engine_reports_the_state_by_its_kind(toy):
     assert after['alpa_serving_kv_cache_bytes{kind="window"}'] == 0
 
 
-@pytest.mark.parametrize("what", ["pool", "packed", "packed_admission",
-                                  "speculative", "beam", "disaggregated"])
+@pytest.mark.parametrize("what", ["pool", "speculative", "beam",
+                                  "disaggregated"])
 def test_what_rolls_back_by_an_index_refuses_by_name(toy, what):
-    """The block pool, the packed prefill (alone and as an engine's
-    admission), the speculative verify step, beam search and the
+    """The block pool, the speculative verify step, beam search and the
     disaggregated prefill index positions of one cache shape or roll a
     row back by its index: they refuse a configuration with a
     short-convolution layer, and say why."""
@@ -178,12 +176,6 @@ def test_what_rolls_back_by_an_index_refuses_by_name(toy, what):
     with pytest.raises(ValueError, match="short-convolution layers"):
         if what == "pool":
             KVBlockPool.for_generator(gen, block_size=8)
-        elif what == "packed":
-            PackedPrefill(model, params, cfg, total_bucket=32, max_rows=2)
-        elif what == "packed_admission":
-            ContinuousBatchingEngine(
-                Generator(model, params, cfg), max_batch=2,
-                packed_admission=True)
         elif what == "speculative":
             gen.generate_speculative(gen, ids[0, :5])
         elif what == "beam":

@@ -97,6 +97,45 @@ def test_every_global_config_field_has_a_reader():
     assert not unread, unread
 
 
+def test_every_engine_option_has_a_caller():
+    """An option nothing passes is a path only tests run: every keyword of
+    ``ContinuousBatchingEngine.__init__`` is named by a call of the class
+    under ``alpa_tpu/`` outside ``serve/engine.py``, as a keyword or as a
+    key of a dict the call splats (``Controller.engine``'s ``rows``)."""
+    import ast
+    import inspect
+    from alpa_tpu.serve.engine import ContinuousBatchingEngine
+    named = set()
+    for path in glob.glob(os.path.join(ROOT, "alpa_tpu", "**", "*.py"),
+                          recursive=True):
+        rel = os.path.relpath(path, ROOT)
+        text = _read(rel)
+        if rel == os.path.join("alpa_tpu", "serve", "engine.py") or \
+                "ContinuousBatchingEngine(" not in text:
+            continue
+        tree = ast.parse(text)
+        dicts = {}           # a name -> the keys of the dicts it is given
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Assign):
+                keys = {k.value for d in ast.walk(node.value)
+                        if isinstance(d, ast.Dict) for k in d.keys
+                        if isinstance(k, ast.Constant)}
+                for target in node.targets:
+                    if isinstance(target, ast.Name):
+                        dicts.setdefault(target.id, set()).update(keys)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and "ContinuousBatchingEngine" == \
+                    getattr(node.func, "id", getattr(node.func, "attr", "")):
+                for kw in node.keywords:
+                    named |= {kw.arg} if kw.arg else \
+                        dicts.get(getattr(kw.value, "id", None), set())
+    options = [name for name, p in inspect.signature(
+        ContinuousBatchingEngine.__init__).parameters.items()
+        if p.default is not inspect.Parameter.empty]
+    unnamed = [name for name in options if name not in named]
+    assert not unnamed, unnamed
+
+
 def _documents():
     """(name, text) of the documents a reader is sent to: README.md,
     PERF.md's sections 1 to 5 (6 and 7 are history and plans, and name
