@@ -80,7 +80,10 @@ class GPTConfig:
     # the MLP of every layer, or one kind a layer (a tuple num_layers
     # long): "dense" (in, activation, out) | "gated" (act(gate) * up,
     # down) | "experts" (top-k routed gated experts, no token dropped:
-    # model/moe.py DroplessExperts)
+    # model/moe.py DroplessExperts) | "gated+shortcut" (a gated MLP that
+    # joins the stream as "gated" does, and beside it routed experts on
+    # the same normed input whose sum is HELD BACK: the next block adds it
+    # with its own MLP's output, ``TransformerBlock``)
     mlp: Any = "dense"
     # width of the MLP, of one expert where routed; None: mlp_ratio * h
     intermediate_size: Optional[int] = None
@@ -166,6 +169,20 @@ class GPTConfig:
     # layer's experts are divided over several chips; the router stays
     # ``num_experts`` wide (``moe.DroplessExperts``).  None: all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # --- a layer of two sub-blocks whose routed experts skip the second
+    # attention (shortcut-connected MoE), identity experts and scaled
+    # latents (``model_type`` longcat_flash).  Every default is the block
+    # of today.  Router outputs ``num_experts .. num_experts +
+    # num_zero_experts - 1`` are experts without matrices: a pick of one
+    # adds its weight times the expert layer's input
+    # (``moe.DroplessExperts``)
+    num_zero_experts: int = 0
+    # what a latent layer's normed query latent and normed key/value
+    # latent are multiplied by, in float32 before the cast (the file's
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: sqrt(hidden_size /
+    # rank)); the cache holds the scaled latent
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
     # --- generation by diffusion over blocks (``model_type`` sdar_moe).
     # Positions come in blocks of ``block_length``; a query sees every key
     # of its own block and of the blocks before it (the later positions of
@@ -199,6 +216,16 @@ class GPTConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+
+# the MLP kind of a block whose routed experts are held back for the next
+SHORTCUT_MLP = "gated+shortcut"
+
+
+def routed_mlp(kind: str) -> bool:
+    """Whether a block of this MLP kind has a router (and so returns what
+    it did)."""
+    return kind in ("experts", SHORTCUT_MLP)
 
 
 # The reference benchmark ladder: name -> (hidden, layers, heads)
@@ -272,7 +299,40 @@ _HF_KINDS = {
     "lfm2_moe": dict(norm="rmsnorm", positions="rotary", qk_norm="head",
                      router_score="sigmoid", fused_gate_up=True,
                      activation="silu", tie_embeddings=True),
+    # LongCat-Flash (meituan-longcat): wiring read from the model's
+    # modeling_longcat_flash.py where config.json does not fix it (silu,
+    # the untied head, interleaved rotary pairs, the router's bias)
+    "longcat_flash": dict(norm="rmsnorm", positions="rotary",
+                          attention="latent", rope_interleaved=True,
+                          fused_gate_up=True, router_bias=True,
+                          activation="silu", tie_embeddings=False),
 }
+
+
+def _depth_and_widths(hf: dict) -> dict:
+    """The keys by which every family before longcat_flash names its depth,
+    its dense width, its experts a token and its key/value heads."""
+    fields = dict(num_layers=hf["num_hidden_layers"],
+                  intermediate_size=hf["intermediate_size"],
+                  num_experts_per_tok=hf["num_experts_per_tok"])
+    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        fields["num_kv_heads"] = hf["num_key_value_heads"]
+    return fields
+
+
+def _act_eps_tie(hf: dict) -> dict:
+    """Activation, the norms' epsilon and the tied head, where the file
+    has all three (lfm2_moe's and longcat_flash's have not)."""
+    return dict(activation=hf["hidden_act"],
+                layer_norm_eps=hf["rms_norm_eps"],
+                tie_embeddings=hf["tie_word_embeddings"])
+
+
+def _olmoe_fields(hf: dict) -> dict:
+    """``model_type`` olmoe: every layer routed, the router's settings."""
+    return dict(_depth_and_widths(hf), **_act_eps_tie(hf),
+                num_experts=hf["num_experts"],
+                norm_topk_prob=hf["norm_topk_prob"])
 
 
 def _lfm2_moe_fields(hf: dict) -> dict:
@@ -294,6 +354,7 @@ def _lfm2_moe_fields(hf: dict) -> dict:
                          "short convolution and its projections have no "
                          "bias)")
     return dict(
+        _depth_and_widths(hf),
         mlp=tuple("gated" if i < hf["num_dense_layers"] else "experts"
                   for i in range(layers)),
         attention=tuple("conv" if t == "conv" else "full"
@@ -316,6 +377,7 @@ def _sdar_moe_fields(hf: dict) -> dict:
         raise ValueError("sdar_moe: use_sliding_window is not supported")
     step, dense_only = hf["decoder_sparse_step"], hf["mlp_only_layers"]
     return dict(
+        _depth_and_widths(hf), **_act_eps_tie(hf),
         mlp=tuple("experts" if i not in dense_only and hf["num_experts"] > 0
                   and (i + 1) % step == 0 else "gated"
                   for i in range(hf["num_hidden_layers"])),
@@ -337,11 +399,13 @@ def _afmoe_fields(hf: dict) -> dict:
     if unknown:
         raise ValueError(f"unknown layer_types {sorted(unknown)}")
     return dict(
+        _depth_and_widths(hf), **_act_eps_tie(hf),
         mlp=tuple("gated" if i < hf["num_dense_layers"] else "experts"
                   for i in range(layers)),
         attention=tuple("sliding" if t == "sliding_attention" else "full"
                         for t in hf["layer_types"]),
         sliding_window=hf["sliding_window"], head_dim=hf["head_dim"],
+        num_experts=hf["num_experts"],
         moe_intermediate_size=hf["moe_intermediate_size"],
         router_score=hf["score_func"], norm_topk_prob=hf["route_norm"],
         route_scale=float(hf["route_scale"]),
@@ -386,6 +450,7 @@ def _deepseek_v2_fields(hf: dict) -> dict:
         raise ValueError("deepseek_v2: only softmax scores under "
                          "group_limited_greedy are supported")
     fields = dict(
+        _depth_and_widths(hf), **_act_eps_tie(hf),
         mlp=tuple("experts" if i >= hf["first_k_dense_replace"] and
                   i % hf["moe_layer_freq"] == 0 else "gated"
                   for i in range(layers)),
@@ -417,14 +482,65 @@ def _deepseek_v2_fields(hf: dict) -> dict:
     return fields
 
 
+def _longcat_flash_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` longcat_flash says beyond the
+    keys all decoders share, under its own names (``num_layers``,
+    ``ffn_hidden_size``, ``expert_ffn_hidden_size``, ``moe_topk``; no
+    ``hidden_act``, ``tie_word_embeddings`` or ``num_key_value_heads``:
+    ``_HF_KINDS`` has what the model's code says of them).  A published
+    layer is TWO blocks of the one decoder definition: latent attention
+    and a dense gated MLP each, the first with the routed and the identity
+    experts beside its MLP (``GPTConfig.mlp`` "gated+shortcut"), whose sum
+    joins the stream after the second; so ``GPTConfig.num_layers`` is twice
+    the file's ``num_layers``, and a published layer is two cache entries.
+    The two latents' factors are ``sqrt(hidden_size / rank)`` where the
+    file's flags say so."""
+    if hf.get("attention_method", "MLA") != "MLA":
+        raise ValueError("longcat_flash: only attention_method MLA is "
+                         "supported")
+    if hf["zero_expert_num"] and hf["zero_expert_type"] != "identity":
+        raise ValueError("longcat_flash: only identity zero experts are "
+                         f"supported, not {hf['zero_expert_type']!r}")
+    hidden, q_rank = hf["hidden_size"], hf["q_lora_rank"] or 0
+    return dict(
+        num_layers=2 * hf["num_layers"],
+        mlp=(SHORTCUT_MLP, "gated") * hf["num_layers"],
+        intermediate_size=hf["ffn_hidden_size"],
+        moe_intermediate_size=hf["expert_ffn_hidden_size"],
+        num_experts=hf["n_routed_experts"],
+        num_zero_experts=hf["zero_expert_num"],
+        num_experts_per_tok=hf["moe_topk"],
+        route_scale=float(hf["routed_scaling_factor"]),
+        layer_norm_eps=hf["rms_norm_eps"],
+        q_lora_rank=q_rank, kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        q_lora_scale=float(np.sqrt(hidden / q_rank))
+        if q_rank and hf["mla_scale_q_lora"] else 1.0,
+        kv_lora_scale=float(np.sqrt(hidden / hf["kv_lora_rank"]))
+        if hf["mla_scale_kv_lora"] else 1.0)
+
+
+# what each model type's file says beyond the keys all share
+_HF_FIELDS = {
+    "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
+    "deepseek_v2": _deepseek_v2_fields, "sdar_moe": _sdar_moe_fields,
+    "lfm2_moe": _lfm2_moe_fields, "longcat_flash": _longcat_flash_fields,
+}
+
+
 def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     """``GPTConfig`` from the keys of a Hugging Face ``config.json`` (a
-    dict), for the model types in ``_HF_KINDS``.  ``kwargs`` override what
-    the file says (``seq_len``: the context a deployment serves, where it
-    is less than the declared ``max_position_embeddings``;
-    ``experts_held``: this chip's share of the routed experts;
-    ``block_length``: the blocks a diffusion decoder generates in).  Of
-    ``rope_scaling`` only deepseek_v2's ``yarn`` is known."""
+    dict), for the model types in ``_HF_KINDS``.  The keys every type's
+    file has are read here; ``_HF_FIELDS`` names the function that reads
+    the rest of a type's file, under that file's own names.  ``kwargs``
+    override what the file says (``seq_len``: the context a deployment
+    serves, where it is less than the declared
+    ``max_position_embeddings``; ``experts_held``: this chip's share of the
+    routed experts; ``block_length``: the blocks a diffusion decoder
+    generates in).  Of ``rope_scaling`` only deepseek_v2's ``yarn`` is
+    known."""
     kinds = _HF_KINDS.get(hf["model_type"])
     if kinds is None:
         raise ValueError(f"no decoder kinds for model_type "
@@ -434,31 +550,11 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         raise ValueError("rope_scaling and clip_qkv are not supported")
     fields = dict(
         vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
-        num_layers=hf["num_hidden_layers"],
         num_heads=hf["num_attention_heads"],
         seq_len=hf["max_position_embeddings"],
-        intermediate_size=hf["intermediate_size"],
         rope_theta=float(hf["rope_theta"]),
-        use_bias=hf.get("attention_bias", False),
-        num_experts_per_tok=hf["num_experts_per_tok"], **kinds)
-    if hf["model_type"] != "lfm2_moe":
-        # (its file has none of the three: ``_lfm2_moe_fields``)
-        fields.update(activation=hf["hidden_act"],
-                      layer_norm_eps=hf["rms_norm_eps"],
-                      tie_embeddings=hf["tie_word_embeddings"])
-    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
-        fields["num_kv_heads"] = hf["num_key_value_heads"]
-    if hf["model_type"] == "deepseek_v2":
-        fields.update(_deepseek_v2_fields(hf))
-    elif hf["model_type"] == "afmoe":
-        fields.update(num_experts=hf["num_experts"], **_afmoe_fields(hf))
-    elif hf["model_type"] == "sdar_moe":
-        fields.update(_sdar_moe_fields(hf))
-    elif hf["model_type"] == "lfm2_moe":
-        fields.update(_lfm2_moe_fields(hf))
-    else:
-        fields.update(num_experts=hf["num_experts"],
-                      norm_topk_prob=hf["norm_topk_prob"])
+        use_bias=hf.get("attention_bias", False), **kinds)
+    fields.update(_HF_FIELDS[hf["model_type"]](hf))
     fields.update(kwargs)
     return GPTConfig(**fields)
 
@@ -1055,6 +1151,12 @@ class LatentAttention(nn.Module):
     k_nope + q_pe . k_pe) * attn_scale``; the heads' values (``v_head_dim``
     each) through ``out``.
 
+    ``q_lora_scale`` and ``kv_lora_scale`` (LongCat-Flash) multiply the
+    normed ``c_q`` and the normed ``c``, in float32 before the cast to
+    ``dtype``; ``k_pe`` is not scaled.  The cache holds ``c`` SCALED, so
+    that the expanded and the absorbed core read one cache as they do
+    without the factor and no product downstream knows of it.
+
     The cache is ``(c, k_pe with the positions last, index)``
     (``update_latent_cache``).  Over it
     one new position a row (a decode) takes ``latent_attention_absorbed``,
@@ -1081,10 +1183,16 @@ class LatentAttention(nn.Module):
         c_q = x
         if cfg.q_lora_rank:
             c_q = make_norm(cfg, "q_a_norm")(
-                dense(cfg.q_lora_rank, name="q_a")(x)).astype(cfg.dtype)
+                dense(cfg.q_lora_rank, name="q_a")(x))
+            if cfg.q_lora_scale != 1.0:
+                c_q = c_q * cfg.q_lora_scale
+            c_q = c_q.astype(cfg.dtype)
         q = dense(nh * (dn + dr), name="q_b")(c_q).reshape(b, s, nh, dn + dr)
         kv_a = dense(rank + dr, name="kv_a")(x)
-        c = make_norm(cfg, "kv_a_norm")(kv_a[..., :rank]).astype(cfg.dtype)
+        c = make_norm(cfg, "kv_a_norm")(kv_a[..., :rank])
+        if cfg.kv_lora_scale != 1.0:
+            c = c * cfg.kv_lora_scale
+        c = c.astype(cfg.dtype)
         w_kv_b = self.param(
             "kv_b", nn.initializers.lecun_normal(), (rank, nh * (dn + dv)),
             cfg.param_dtype).astype(cfg.dtype).reshape(rank, nh, dn + dv)
@@ -1336,14 +1444,24 @@ class TransformerBlock(nn.Module):
     residual stream.  Returns ``(x, new_cache)``, and a block of routed
     experts ``(x, new_cache, routing)``: what its router did
     (``moe.DroplessExperts``).  ``padding_bias`` is ``SelfAttention``'s
-    (``BertModel``'s padding mask; no decoder passes one)."""
+    (``BertModel``'s padding mask; no decoder passes one).
+
+    A block of the kind "gated+shortcut" (LongCat-Flash's shortcut-connected
+    experts: the first half of a published layer) returns ``(x, new_cache,
+    routing, shortcut)``: its gated MLP has joined ``x``; what its routed
+    experts (module ``moe``) made of the same normed input has NOT, and is
+    ``shortcut``.  The caller hands it to the next block as ``shortcut=``,
+    which adds it to the stream with its own MLP's output, so that the
+    experts' sum skips that block's attention.  A block handed none and of
+    another kind adds and returns nothing more than it did."""
     config: GPTConfig
     mlp: Optional[str] = None
     attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 position_ids=None, cache_lengths=None, padding_bias=None):
+                 position_ids=None, cache_lengths=None, padding_bias=None,
+                 shortcut=None):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
@@ -1363,17 +1481,25 @@ class TransformerBlock(nn.Module):
         x = x + attn_out.astype(x.dtype)
         ln2 = make_norm(cfg, "ln2")(x)
         routing = ()
-        if kind == "experts":
+        if routed_mlp(kind):
             from alpa_tpu.model.moe import DroplessExperts
+        if kind == "experts":
             y, what = DroplessExperts(cfg, name="mlp")(ln2)
             routing = (what,)
-        elif kind in ("dense", "gated"):
-            y = MLPBlock(cfg, gated=kind == "gated", name="mlp")(ln2)
+        elif kind in ("dense", "gated", SHORTCUT_MLP):
+            y = MLPBlock(cfg, gated=kind != "dense", name="mlp")(ln2)
         else:
             raise ValueError(f"unknown mlp kind {kind!r}")
         if cfg.post_norms:
             y = make_norm(cfg, "ln2_post")(y)
-        return (x + y.astype(x.dtype), new_cache) + routing
+        x = x + y.astype(x.dtype)
+        if shortcut is not None:
+            x = x + shortcut.astype(x.dtype)
+        if kind == SHORTCUT_MLP:
+            # beside the MLP, on the same normed input, and held back
+            held_back, what = DroplessExperts(cfg, name="moe")(ln2)
+            routing = (what, held_back)
+        return (x, new_cache) + routing
 
 
 class GPTModel(nn.Module):
@@ -1435,6 +1561,9 @@ class GPTModel(nn.Module):
                                  policy=policy)
         new_caches = [] if kv_caches is not None else None
         routings = []
+        # what a "gated+shortcut" block's experts made, on its way to the
+        # next block (``TransformerBlock``)
+        carried = {}
         for i in range(cfg.num_layers):
             if (cfg.pipeline_boundary_every and i > 0 and
                     i % cfg.pipeline_boundary_every == 0):
@@ -1443,10 +1572,18 @@ class GPTModel(nn.Module):
                               attention=cfg.attention_kind(i), name=f"h{i}")
             cache_i = kv_caches[i] if kv_caches is not None else None
             x, new_cache, *routing = block(
-                x, cache_i, deterministic, block_positions, cache_lengths)
+                x, cache_i, deterministic, block_positions, cache_lengths,
+                **carried)
+            carried = {}
+            if cfg.mlp_kind(i) == SHORTCUT_MLP:
+                carried = {"shortcut": routing.pop()}
             routings += routing
             if new_caches is not None:
                 new_caches.append(new_cache)
+        if carried:
+            raise ValueError("the last block's experts are held back for a "
+                             "next block (GPTConfig.mlp \"gated+shortcut\"), "
+                             "and there is none")
         x = make_norm(cfg, "ln_f")(x)
         if return_hidden:
             return x

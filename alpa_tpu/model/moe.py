@@ -50,7 +50,10 @@ def topk_routing(router_logits, k: int, norm_topk_prob: bool = False,
                  score: str = "softmax", bias=None, scale: float = 1.0,
                  n_group: int = 1, topk_group: int = 1):
     """The experts' scores in float32, then the k largest: (T, E) logits ->
-    (weights (T, k) float32, experts (T, k) int32, scores (T, E)).
+    (weights (T, k) float32, experts (T, k) int32, scores (T, E)).  ``E`` is
+    whatever width the router has: it may exceed the experts that have
+    matrices (``GPTConfig.num_zero_experts``: the caller decides what a
+    pick past them means), and the scores are over all of it.
 
     ``score`` "softmax" (OLMoE): the scores are the softmax over the
     experts; "sigmoid" (Trinity, DeepSeek-V3): each expert's own sigmoid.
@@ -177,9 +180,24 @@ class DroplessExperts(nn.Module):
     the routed sum (and the shared experts, which every chip computes
     alike for its own tokens).
 
-    Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32,
-    ``prob_sums`` (E,) float32 and ``experts`` (T, k) int32, of all the
-    ``num_experts`` whichever are held."""
+    ``num_zero_experts`` Z (LongCat-Flash's zero-computation experts): the
+    router (and its bias) is ``num_experts + Z`` wide, and a pick of an
+    index from ``num_experts`` on is an IDENTITY expert, which has no
+    matrix and adds its weight times the layer's input.  A pick is then
+    one of three kinds: *held* (an expert whose matrices are here: its row
+    goes through the grouped matmuls), *absent* (another chip's expert:
+    its row lies behind the held groups and adds nothing here) and
+    *identity* (its row lies behind the groups too, and ``y`` gains
+    ``weight x input`` in float32, computed here for this program's own
+    tokens whatever ``experts_held`` says, as the shared experts are).
+    How many rows the experts multiply is so decided by the data; the
+    grouped matmul's row count stays the static tokens x k.
+
+    Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32 and
+    ``prob_sums`` (E,) float32 over the ``num_experts`` with matrices,
+    whichever are held, ``experts`` (T, k) int32, every pick as the router
+    numbers it (0 .. E + Z - 1), and, where there are identity experts,
+    ``zero_picks`` (a scalar): how many of the picks were of one."""
     config: Any
 
     @nn.compact
@@ -190,8 +208,10 @@ class DroplessExperts(nn.Module):
         e, k, width = (cfg.num_experts, cfg.num_experts_per_tok,
                        cfg.expert_width)
         held = getattr(cfg, "experts_held", None)
+        # router outputs past the experts: identity experts
+        zeros = getattr(cfg, "num_zero_experts", 0)
         # the experts whose parameters are here
-        mine = e if held is None else held[1]
+        first, mine = (0, e) if held is None else held
         h = x.shape[-1]
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         if cfg.fused_gate_up:
@@ -206,11 +226,12 @@ class DroplessExperts(nn.Module):
                               cfg.param_dtype)
         w_down = self.param("w_down", init, (mine, width, h),
                             cfg.param_dtype)
-        bias = self.param("router_bias", nn.initializers.zeros, (e,),
-                          jnp.float32) if cfg.router_bias else None
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (e + zeros,), jnp.float32) \
+            if cfg.router_bias else None
         tokens = x.reshape(-1, h)
         with jax.named_scope(SCOPE):
-            logits = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+            logits = nn.Dense(e + zeros, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               param_dtype=cfg.param_dtype,
                               name="router")(tokens.astype(jnp.float32))
@@ -221,18 +242,21 @@ class DroplessExperts(nn.Module):
                 getattr(cfg, "topk_group", 1))
             flat = experts.reshape(-1)
             slot = flat
-            if held is not None:
-                # the held experts' rows first, by expert; the absent
-                # experts' rows behind them, where no group reaches
-                here = (flat >= held[0]) & (flat < held[0] + mine)
-                slot = jnp.where(here, flat - held[0], mine)
+            # whether some picks are of no expert held here
+            partial = held is not None or zeros > 0
+            if partial:
+                # the held experts' rows first, by expert; the absent and
+                # the identity experts' rows behind them, where no group
+                # reaches
+                here = (flat >= first) & (flat < first + mine)
+                slot = jnp.where(here, flat - first, mine)
             # stable: an expert's rows stay in token order
             order = jnp.argsort(slot, stable=True).astype(jnp.int32)
             inverse = jnp.argsort(order).astype(jnp.int32)
             counts = (flat[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
                 0, dtype=jnp.int32)
             group_sizes = counts if held is None else \
-                counts[held[0]:held[0] + mine]
+                counts[first:first + mine]
             rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
                                     inverse, k)
             # gate and up in one pass over the rows
@@ -244,7 +268,7 @@ class DroplessExperts(nn.Module):
                       gate_up[:, width:].astype(jnp.float32))
             out_rows = grouped_matmul(hidden.astype(cfg.dtype), w_down,
                                       group_sizes)
-            if held is not None:
+            if partial:
                 # what lies behind the groups was not multiplied, and is
                 # whatever the kernel's output buffer held
                 walked = jnp.arange(out_rows.shape[0]) < group_sizes.sum()
@@ -252,12 +276,19 @@ class DroplessExperts(nn.Module):
             by_token = _permute_rows(out_rows, inverse, order).reshape(
                 tokens.shape[0], k, h)
             y = (by_token.astype(jnp.float32) * weights[..., None]).sum(1)
+            if zeros:
+                identity = experts >= e
+                y = y + jnp.where(identity, weights, 0.0).sum(
+                    -1, keepdims=True) * tokens.astype(jnp.float32)
             for i in range(cfg.num_shared_experts):
                 y = y + MLPBlock(cfg, gated=True, width=width,
                                  name=f"shared{i}")(tokens).astype(
                                      jnp.float32)
         routing = {"counts": counts, "prob_sums": probs.sum(0),
                    "experts": experts}
+        if zeros:
+            routing.update(prob_sums=probs[:, :e].sum(0),
+                           zero_picks=identity.sum(dtype=jnp.int32))
         return y.astype(cfg.dtype).reshape(x.shape), routing
 
 

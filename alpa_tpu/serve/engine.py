@@ -76,6 +76,13 @@ _EXPERTS_TOUCHED = _REG.counter(
     "holds a share of a layer's experts, those among them")
 _ROUTED_ROWS = _REG.counter(
     "alpa_moe_routed_rows_total", "token-expert rows the experts computed")
+_ZERO_PICKS = _REG.counter(
+    "alpa_moe_zero_picks_total",
+    "token-expert picks of the decode ticks that fell on an identity "
+    "expert, which has no matrix and adds its weight times the layer's "
+    "input (GPTConfig.num_zero_experts; alpa_moe_routed_rows_total counts "
+    "these picks too, so this over that is the share of the routed picks "
+    "that multiply nothing)")
 _LOCAL_ROWS = _REG.counter(
     "alpa_moe_local_rows_total",
     "token-expert rows the decode ticks routed to an expert this program "
@@ -852,8 +859,14 @@ class ContinuousBatchingEngine:
     def _count_routing(self, routing):
         """What a step said of its routed layers, into the counters."""
         held = getattr(self.gen.config, "experts_held", None)
+        zeros = getattr(self.gen.config, "num_zero_experts", 0)
         for layer in routing.get("experts", ()):
             _ROUTED_ROWS.inc(layer.size)
+            if zeros:
+                # picks past the experts with matrices: identity experts
+                real = layer < self.gen.config.num_experts
+                _ZERO_PICKS.inc(layer.size - int(real.sum()))
+                layer = layer[real]
             if held is not None:
                 # this program's share of the layer's experts
                 layer = layer[(layer >= held[0]) &

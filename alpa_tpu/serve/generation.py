@@ -22,7 +22,7 @@ import numpy as np
 
 from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, init_kv_caches,
                                       require_one_token_steps,
-                                      require_uniform_kv_caches,
+                                      require_uniform_kv_caches, routed_mlp,
                                       uniform_kv_caches)
 from alpa_tpu.telemetry import device_time
 
@@ -492,7 +492,8 @@ class Generator:
         # what the cached calls hand the model beyond ids, positions and
         # caches (other decoder families take neither)
         kinds = getattr(config, "mlp", "dense")
-        routed = "experts" in ([kinds] if isinstance(kinds, str) else kinds)
+        routed = any(routed_mlp(kind) for kind in
+                     ([kinds] if isinstance(kinds, str) else kinds))
         rings = not uniform_kv_caches(config)
 
         def lengths_kw(lengths):
