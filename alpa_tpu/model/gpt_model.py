@@ -6,9 +6,11 @@ Clean-room analog of ref ``alpa/model/gpt_model.py`` (which wraps
 * bfloat16 activations/params option; fp32 layernorm + softmax accumulation,
 * einsum-formulated attention so batch/head/seq dims are clean mesh targets
   for the auto-sharding planner,
-* pluggable attention implementation (``attention_impl``):
-  "reference" (jnp, XLA-fused) | "flash" (pallas kernel, ops/flash_attention)
-  | "ring" (sequence-parallel ring attention over a mesh axis),
+* the attention core of a layer without a cache (``attention_impl``):
+  "reference" is the core the call's shapes choose (``attention``: the
+  fused kernels of ops/flash_attention where they fit and the program is
+  lowered for a TPU, the einsums of ``reference_attention`` otherwise) |
+  "ring" | "ulysses" (sequence-parallel attention over a mesh axis),
 * optional ``mark_pipeline_boundary()`` between blocks for manual pipeline
   layer construction (ref ManualLayerOption),
 * KV-cache threading for autoregressive serving (cache as explicit
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
+from alpa_tpu.shard_parallel import kernel_choice
 from alpa_tpu.telemetry import metrics as tmetrics
 
 
@@ -41,7 +44,7 @@ class GPTConfig:
     mlp_ratio: int = 4
     dtype: Any = jnp.float32
     dropout_rate: float = 0.0
-    # "reference" | "flash" | "ring"
+    # "reference" (the core the call's shapes choose) | "ring" | "ulysses"
     attention_impl: str = "reference"
     # insert pipeline boundary markers every k blocks (0 = never)
     pipeline_boundary_every: int = 0
@@ -683,17 +686,85 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
     return out.reshape(b, sq, nh, dim)
 
 
+def attention(q, k, v, *, causal: bool):
+    """The attention of a layer without a cache (a training step, a forward
+    pass over whole sequences): ``reference_attention(q, k, v,
+    causal=causal)`` by one of two cores, by what the call's shapes say
+    and nothing a caller or a configuration sets.  Shapes the kernels of
+    ``ops/flash_attention.py`` take (as many key/value heads as query
+    heads, one length that their blocks divide, from the length at which
+    they win: its ``fits``): a program lowered for a TPU runs those
+    kernels, forward and backward, which keep a block of scores in fast
+    memory and write none of the (B, H, S, S) scores and probabilities,
+    and any other platform ``reference_attention``.  Every other call is
+    ``reference_attention``.  The gauge ``alpa_attention_core`` says at
+    trace time which one a program's layers took.
+
+    The kernels are no primitive the sharding planner can partition: on
+    a mesh of more than one device it plans and binds this choice's
+    ``reference_attention`` (``shard_parallel/kernel_choice.py``)."""
+    from alpa_tpu.ops import flash_attention as kernel
+    fused = kernel.fits(q, k)
+    _attention_core_gauge().labels(
+        "fused" if fused else "reference", q.shape[2], q.shape[3],
+        q.shape[1]).inc()
+    if not fused:
+        return reference_attention(q, k, v, causal=causal)
+    return _fused_attention(q, k, v, causal)
+
+
+def _attention_core_gauge():
+    return tmetrics.get_registry().gauge(
+        "alpa_attention_core",
+        "layers whose attention without a cache was traced with each core "
+        "(fused: where lowered for a TPU, the kernels that keep the scores "
+        "in fast memory, forward and backward; reference: the scores "
+        "written to memory, which is also what the sharding planner binds "
+        "on a mesh of several devices), by the heads, head width and length",
+        ("core", "heads", "head_dim", "seq"))
+
+
+def _planned_as_reference(avals):
+    """The sharding planner bound ``reference_attention`` in the layers
+    traced as fused at these shapes (``kernel_choice.on_default``;
+    ``avals[0]`` is the output's, or ``dq``'s): the gauge says so."""
+    _, seq, heads, dim = avals[0].shape
+    gauge = _attention_core_gauge()
+    fused = gauge.labels("fused", heads, dim, seq)
+    gauge.labels("reference", heads, dim, seq).inc(fused.value)
+    fused.set(0)
+
+
+@partial(jax.jit, static_argnames="causal")
+def _fused_attention(q, k, v, causal):
+    """``attention``'s fused core: the kernels where the program is
+    lowered for a TPU, ``reference_attention`` anywhere else.  A ``jit`` of
+    its own, so that a step of many layers traces and lowers the kernels
+    once a pass and not once a layer and pass (54 times in the 18 layers
+    of the GPT cell's step)."""
+    from alpa_tpu.ops import flash_attention as kernel
+    return jax.lax.platform_dependent(
+        q, k, v, tpu=partial(kernel.flash_attention, causal=causal),
+        default=partial(reference_attention, causal=causal))
+
+
+kernel_choice.on_default(_fused_attention.__name__, _planned_as_reference)
+
+
 def get_attention_fn(config: GPTConfig) -> Callable:
-    if config.attention_impl == "flash":
-        from alpa_tpu.ops.flash_attention import flash_attention
-        return flash_attention
+    """The core of a layer without a cache, by ``attention_impl``:
+    "reference" is ``attention`` (the core the call's shapes choose),
+    "ring" and "ulysses" shard the sequence over ``sp_axis``."""
+    if config.attention_impl == "reference":
+        return attention
     if config.attention_impl == "ring":
         from alpa_tpu.ops.ring_attention import ring_attention
         return partial(ring_attention, axis_name=config.sp_axis)
     if config.attention_impl == "ulysses":
         from alpa_tpu.ops.ulysses_attention import ulysses_attention
         return partial(ulysses_attention, axis_name=config.sp_axis)
-    return reference_attention
+    raise ValueError(f"unknown attention_impl {config.attention_impl!r}: "
+                     "\"reference\", \"ring\" or \"ulysses\"")
 
 
 # the scope the attention core of every layer is traced under
@@ -1383,9 +1454,9 @@ class SelfAttention(nn.Module):
                 out = cached_attention(q, *new_cache[:2], index,
                                        block=block)
             elif padding_bias is not None or window or nkv != nh or block:
-                # additive padding bias: encoder path only (the
-                # flash/ring kernels take no bias operand, no window and
-                # no grouped heads)
+                # additive padding bias: encoder path only (the ring and
+                # ulysses cores take no bias operand, no window and no
+                # grouped heads)
                 if (window or nkv != nh or block) and \
                         cfg.attention_impl != "reference":
                     raise ValueError(

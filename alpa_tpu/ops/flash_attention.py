@@ -1,28 +1,64 @@
-"""Flash attention forward kernels in pallas (TPU), with recompute backward.
+"""Fused attention over one sequence a row, forward and backward: two
+Pallas kernels that never write the scores to memory.
 
-Blocked online-softmax attention: the q-block stays in VMEM, the softmax
-normalizer is maintained incrementally, and the S x S score matrix never
-materializes in HBM.  Two forward paths, picked by k/v size:
+What a training step needs of ``model/gpt_model.py`` ``SelfAttention``
+without a cache: ``q``, ``k``, ``v`` (B, S, H, D) of one length, as many
+key/value heads as query heads, a causal mask or none.
+``reference_attention`` writes the (B, H, S, S) float32 scores, reads them
+for the softmax, writes the probabilities and reads them for the values,
+in the forward pass, in a rematerialised block and in the backward pass:
+at 1,024 positions that traffic, not the products, is what the core costs
+(PERF.md section 6, PR 45).  Here a block of scores lives in fast memory
+only (Dao et al., "FlashAttention", 2022; the backward in one pass as in
+"FlashAttention-2", 2023).
 
-* **resident** (short sequences): k/v for one (batch, head) live in VMEM;
-  grid (B*H, q blocks) with a fori_loop over k blocks and causal
-  early-exit.
-* **streaming** (k/v > ~4MB): grid (B*H, q blocks, k blocks) — k/v blocks
-  stream from HBM via BlockSpec index maps, the (m, l, acc) state persists
-  in VMEM scratch across the sequential innermost grid dim, and causal
-  blocks above the diagonal are skipped with ``pl.when``.  Per-chip
-  sequence length is then HBM-bound, and ring attention shards beyond
-  that.
+The mathematics is ``reference_attention``'s at the configuration's
+precision: operand blocks go to the matrix unit in the dtype they arrive
+in (bfloat16 in the models) and every product accumulates in float32; the
+scale is applied to the float32 scores; maxima, sums, the saved
+log-sum-exp and ``delta`` are float32; the probabilities are cast to
+``v``'s dtype before the values' product.  No operand of a product is
+cast to float32: the matrix unit would multiply it in several bfloat16
+passes.
 
-Backward: real pallas kernels in the VMEM-resident regime — the standard
-two-kernel flash backward (dq over q blocks; dk/dv over k blocks) off the
-saved (out, logsumexp) residuals, never materializing S x S scores.  In
-the HBM-streaming regime (k/v beyond the VMEM budget) the backward falls
-back to q-chunked recompute with the einsum reference implementation —
-the remat-style tradeoff (XLA fuses the recomputed backward well).
+**Forward**, grid (batch, group of heads, query block): the row's keys and
+values stay in fast memory, a ``fori_loop`` walks the key blocks a query block
+can see with the running maximum, sum and weighted values of the online
+softmax in its carry; under a causal mask the blocks wholly below the
+diagonal are not masked (nothing to hide) and those above it are not
+visited.  It returns the output and, a query, the log-sum-exp of its
+scaled scores, laid out with the positions in the lanes.
+
+**Backward**, grid (batch x group of heads, key block), the key blocks
+sequential:
+for a key block, a ``fori_loop`` over the query blocks that see it
+rebuilds the transposed probabilities from the saved log-sum-exp and
+accumulates the block's ``dk`` and ``dv``; each pair's ``dq`` goes into a
+float32 scratch of the whole row that stays in fast memory across the key
+blocks and is written out after the last.  Scores, probabilities and their
+gradients are computed once a pair of blocks, five products in all.
+
+Both read ``q``, ``k``, ``v`` and write their results AS THEY LIE, (B, S,
+H D) with the heads side by side in the lanes: a kernel instance takes one
+group of lanes a row, which is one head of 128 channels or more and
+``128 / D`` narrower heads (two of 64), and no array is transposed to
+heads-first and back around a kernel (eight transposes a layer of the
+first form of these kernels: a tenth of the GPT cell's step, PERF.md
+section 6, PR 45).  The narrower heads of a group are never picked out of
+the lanes they share: a head's products run over the whole group with the
+other heads' lanes of ONE operand zeroed (``q`` forward; ``k``, ``v``
+backward), which contracts over its own channels only and costs the
+matrix unit what a product over 64 of its 128 lanes costs anyway; what
+lands in the other heads' lanes of a result is masked off as it is
+written.
+
+The blocks follow the head width and the length (``blocks``); ``fits``
+says which calls the kernels take.  They are compiled where the program
+is lowered for a TPU (``gpt_model.attention`` chooses between them and
+``reference_attention`` with ``lax.platform_dependent``);
+``interpret=True`` runs them anywhere, for the tests.
 """
 import functools
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -31,474 +67,318 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from alpa_tpu.ops.latent_attention import VMEM_LIMIT
+
+# what a masked score is replaced by (``reference_attention``'s)
 NEG_INF = -1e9
+# the lanes of a vector register
+LANES = 128
+# positions of a query block and of a key block at their largest: the
+# fastest of the blocks tried on the v5e at both head widths (PERF.md
+# section 6, PR 45)
+BLOCK = 512
+# the length from which the kernels beat ``reference_attention``, forward
+# and backward, on the v5e (same place): below it the scores are small
+# enough for the compiler's own fusions
+MIN_SEQ = 512
+# the longest row whose backward pass keeps its queries, their gradients
+# and the float32 ``dq`` in the kernel's fast memory beside the blocks'
+# intermediates
+MAX_SEQ = 16384
+# a product of (rows, d) x (columns, d) over d, and of (k, rows) x (k,
+# columns) over k
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
 
 
-def _online_softmax_update(q, k_blk, v_blk, m_prev, l_prev, acc, *,
-                           causal: bool, q_start, k_start):
-    """One flash-attention block update, shared by both kernels:
-    (m, l, acc) -> (m', l', acc') after attending q to one k/v block.
-    m and l are (block_q, 1) columns: the TPU lowering has no 1-D
-    vector layout, and a column broadcasts against the scores as is."""
-    block_q = q.shape[0]
-    block_k = k_blk.shape[0]
-    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        q_pos = q_start + lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0)
-        k_pos = k_start + lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_new = acc * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return m_new, l_new, acc_new
+def blocks(seq: int, dim: int):
+    """(query block, key block) of a call: ``BLOCK`` positions, half of
+    that for heads wider than the lanes (their float32 accumulators are
+    twice the size), never more than the row."""
+    block = min(seq, BLOCK if dim <= LANES else BLOCK // 2)
+    return block, block
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      block_k: int, causal: bool, sm_scale: float,
-                      q_offset: int):
-    """One (batch*head, q-block) program instance.
+def heads_a_group(dim: int) -> int:
+    """Heads that lie side by side in one group of lanes: one of 128
+    channels or more, ``128 / dim`` narrower ones."""
+    return max(1, LANES // dim)
 
-    q_ref: (block_q, d); k_ref/v_ref: (s_k, d); o_ref: (block_q, d);
-    lse_ref: (block_q, 1) — per-row logsumexp of the scaled scores, the
-    residual the backward kernels reconstruct P from.
-    """
-    block_q, d = q_ref.shape
-    s_k = k_ref.shape[0]
-    q = q_ref[:].astype(jnp.float32) * sm_scale
 
-    q_blk = pl.program_id(1)
-    q_start = q_blk * block_q + q_offset
+def fits(q, k) -> bool:
+    """Whether the kernels take these shapes: as many key/value heads as
+    query heads, ``q`` and ``k`` of one length that the blocks divide,
+    between ``MIN_SEQ`` and ``MAX_SEQ``, the heads in whole groups of
+    lanes."""
+    _, seq, heads, dim = q.shape
+    group = heads_a_group(dim)
+    if k.shape[1] != seq or k.shape[2] != heads or \
+            (group * dim) % LANES or heads % group:
+        return False
+    return MIN_SEQ <= seq <= MAX_SEQ and seq % blocks(seq, dim)[0] == 0
 
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
 
-    num_k_blocks = pl.cdiv(s_k, block_k)
+def _row_of(col):
+    """A (n, 1) float32 column as a (1, n) row: the entries move from the
+    sublanes to the lanes through the diagonal of an (n, n) mask, 128
+    columns at a time."""
+    n = col.shape[0]
+    step = min(n, LANES)
+    eye = (lax.broadcasted_iota(jnp.int32, (step, step), 0) ==
+           lax.broadcasted_iota(jnp.int32, (step, step), 1))
+    return jnp.concatenate([
+        jnp.sum(jnp.where(eye, col[at:at + step], 0.0), axis=0,
+                keepdims=True) for at in range(0, n, step)], axis=1)
 
-    def body(kb, carry):
+
+def _seen(q_start, k_start, block_q: int, block_k: int, keys_first: bool):
+    """Which of a block's (query, key) pairs the causal mask shows, as
+    (block_q, block_k), or transposed with ``keys_first``."""
+    shape = (block_k, block_q) if keys_first else (block_q, block_k)
+    q_pos = q_start + lax.broadcasted_iota(jnp.int32, shape,
+                                           1 if keys_first else 0)
+    k_pos = k_start + lax.broadcasted_iota(jnp.int32, shape,
+                                           0 if keys_first else 1)
+    return q_pos >= k_pos
+
+
+def _of_head(x, head: int, dim: int):
+    """``x`` (rows, group's lanes) with the lanes of every head of the
+    group but ``head`` zeroed; ``x`` itself where the group is one head."""
+    if x.shape[1] == dim:
+        return x
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= head * dim) & (lane < (head + 1) * dim), x,
+                     jnp.zeros_like(x))
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, dim: int,
+                    block_k: int, causal: bool, scale: float):
+    """One (batch, group of heads, query block).  q_ref, o_ref: (block_q,
+    W), the group's W lanes; k_ref, v_ref: (S, W); lse_ref: (heads of the
+    group, block_q)."""
+    block_q, width = q_ref.shape
+    q_start = pl.program_id(2) * block_q
+    n_blocks = k_ref.shape[0] // block_k
+
+    def fold_in(q, masked, kb, carry):
         m_prev, l_prev, acc = carry
-        k_start = kb * block_k
-        k_blk = k_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        return _online_softmax_update(q, k_blk, v_blk, m_prev, l_prev,
-                                      acc, causal=causal, q_start=q_start,
-                                      k_start=k_start)
+        k_start = pl.multiple_of(kb * block_k, block_k)
+        s = scale * lax.dot_general(q, k_ref[pl.ds(k_start, block_k), :],
+                                    _NT, preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_seen(q_start, k_start, block_q, block_k, False),
+                          s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        v_blk = v_ref[pl.ds(k_start, block_k), :]
+        return (m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + jnp.dot(p.astype(v_blk.dtype), v_blk,
+                                      preferred_element_type=jnp.float32))
 
-    if causal:
-        # skip fully-masked k blocks beyond the diagonal
-        last_needed = lax.div(q_start + block_q - 1, block_k) + 1
-        n_iter = jnp.minimum(last_needed, num_k_blocks)
-    else:
-        n_iter = num_k_blocks
-    m, l, acc = lax.fori_loop(0, n_iter, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-20)
-    o_ref[:] = (acc / l).astype(o_ref.dtype)
-    lse_ref[:] = m + jnp.log(l)
+    out = None
+    for head in range(width // dim):
+        # the scores contract over this head's channels: the others' are
+        # zeros in ``q``
+        q = _of_head(q_ref[:], head, dim)
+        carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((block_q, 1), jnp.float32),
+                 jnp.zeros((block_q, width), jnp.float32))
+        if causal:
+            # the key blocks wholly at or before the block's first query,
+            # then those the diagonal crosses
+            below = q_start // block_k
+            carry = lax.fori_loop(
+                0, below, functools.partial(fold_in, q, False), carry)
+            carry = lax.fori_loop(
+                below, jnp.minimum((q_start + block_q - 1) // block_k + 1,
+                                   n_blocks),
+                functools.partial(fold_in, q, True), carry)
+        else:
+            carry = lax.fori_loop(
+                0, n_blocks, functools.partial(fold_in, q, False), carry)
+        m, l, acc = carry
+        # the head's own lanes of the weighted values
+        mine = _of_head(acc / l, head, dim)
+        out = mine if out is None else out + mine
+        lse_ref[pl.ds(head, 1), :] = _row_of(m + jnp.log(l))
+    o_ref[:] = out.astype(o_ref.dtype)
 
 
-# above this many k/v bytes per (batch, head), stream blocks from HBM
-# instead of keeping k/v VMEM-resident.  The compiler gives one kernel
-# 16 MiB of VMEM on a v5e and double-buffers every blocked operand, so
-# 4 MiB of resident pairs is what fits beside the q/o blocks and the
-# f32 block intermediates (compiled for v5e in tests/ops/test_tpu_compile.py)
-VMEM_RESIDENT_LIMIT = 4 * 1024 * 1024
-
-
-def _resident_bytes(seq: int, d: int, dtype) -> int:
-    """VMEM bytes of one resident (seq, d) pair (k+v, or q+do): the
-    minor dim is laid out in 128 lanes, so head dim 64 costs what 128
-    does."""
-    lanes = -(-d // 128) * 128
-    return 2 * seq * lanes * jnp.dtype(dtype).itemsize
-
-
-def _flash_streaming_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
-                            l_ref, acc_ref, *, causal: bool,
-                            sm_scale: float, q_offset: int, nk: int,
-                            block_q: int, block_k: int):
-    """Grid (B*H, q blocks, k blocks): k/v blocks stream from HBM; the
-    online-softmax state (m, l, acc) lives in VMEM scratch that persists
-    across the sequential innermost grid dim."""
-    kb = pl.program_id(2)
-    qb = pl.program_id(1)
+def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, *, dim: int,
+                     block_q: int, causal: bool, scale: float):
+    """One (batch x group of heads, key block).  k_ref, v_ref, dk_ref,
+    dv_ref: (block_k, W), the group's W lanes; q_ref, do_ref, dq_ref: (S,
+    W); lse_ref, delta_ref: (heads of the group x S / block_q, block_q), a
+    row a head and query block; dq_acc: (S, W) float32.  Works on the
+    transposed scores (block_k, block_q): the per-query vectors come in as
+    rows and every product but ``dq``'s is plain."""
+    block_k, width = k_ref.shape
+    kb = pl.program_id(1)
+    k_start = kb * block_k
+    n_blocks = q_ref.shape[0] // block_q
 
     @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def _start():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_start = qb * block_q + q_offset
-    k_start = kb * block_k
-    # blocks entirely above the diagonal contribute nothing (their DMA is
-    # also suppressed by the clamped k index map in _flash_forward)
-    run = (q_start + block_q - 1 >= k_start) if causal else True
+    def fold_in(k_blk, v_blk, first_row, masked, qb, carry):
+        dk, dv = carry
+        q_start = pl.multiple_of(qb * block_q, block_q)
+        q = q_ref[pl.ds(q_start, block_q), :]
+        do = do_ref[pl.ds(q_start, block_q), :]
+        s_t = scale * lax.dot_general(k_blk, q, _NT,
+                                      preferred_element_type=jnp.float32)
+        if masked:
+            s_t = jnp.where(_seen(q_start, k_start, block_q, block_k, True),
+                            s_t, NEG_INF)
+        p_t = jnp.exp(s_t - lse_ref[pl.ds(first_row + qb, 1), :])
+        dv = dv + jnp.dot(p_t.astype(do.dtype), do,
+                          preferred_element_type=jnp.float32)
+        dp_t = lax.dot_general(v_blk, do, _NT,
+                               preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - delta_ref[pl.ds(first_row + qb, 1), :])
+                ).astype(q.dtype)
+        dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+        dq_acc[pl.ds(q_start, block_q), :] += lax.dot_general(
+            ds_t, k_blk, _TN, preferred_element_type=jnp.float32)
+        return dk, dv
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[:].astype(jnp.float32) * sm_scale
-        k_blk = k_ref[:].astype(jnp.float32)
-        v_blk = v_ref[:].astype(jnp.float32)
-        m_new, l_new, acc_new = _online_softmax_update(
-            q, k_blk, v_blk, m_ref[:], l_ref[:], acc_ref[:],
-            causal=causal, q_start=q_start, k_start=k_start)
-        m_ref[:] = m_new
-        l_ref[:] = l_new
-        acc_ref[:] = acc_new
-
-    @pl.when(kb == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:], 1e-20)
-        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse_ref[:] = m_ref[:] + jnp.log(l)
-
-
-def _pick_block(size: int, target: int) -> int:
-    """Largest divisor of ``size`` not exceeding ``target`` — blocks must
-    tile the sequence exactly (no partial-block masking implemented)."""
-    b = min(target, size)
-    while size % b != 0:
-        b -= 1
-    return b
-
-
-def _pallas_call(kernel, **kwargs):
-    """``pl.pallas_call`` that compiles the kernel when the program is
-    lowered for a TPU and interprets it on any other platform.  The
-    choice is made at lowering time from the platform compiled for
-    (``lax.platform_dependent``), not from ``jax.default_backend()``:
-    on a TPU the kernel compiles or raises, it never interprets."""
-    compiled = pl.pallas_call(kernel, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
-    return lambda *args: lax.platform_dependent(
-        *args, tpu=compiled, default=interpreted)
-
-
-def _flash_forward(q, k, v, *, causal: bool, q_offset: int = 0,
-                   block_q: int = 256, block_k: int = 256):
-    """q: (B, Sq, H, D); k/v: (B, Sk, H, D) -> (out (B, Sq, H, D),
-    lse (B*H, Sq))."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    sm_scale = 1.0 / np.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-
-    # (B, Sq, H, D) -> (B*H, Sq, D)
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    # lse leaves the kernel as a (Sq, 1) column per (batch, head): the TPU
-    # lowering wants the last two block dims to be multiples of (8, 128)
-    # or the whole array dim, which (block_q, 1) is and (block_q,) is not
-    out_shape = (jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-                 jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32))
-
-    if _resident_bytes(sk, d, k.dtype) > VMEM_RESIDENT_LIMIT:
-        # long-sequence path: stream k/v blocks, carry softmax state in
-        # scratch across the innermost (sequential) grid dim
-        nk = sk // block_k
-        grid = (b * h, sq // block_q, nk)
+    dk_out = dv_out = None
+    for head in range(width // dim):
+        # every product contracts over, or lands in, this head's channels:
+        # the others' are zeros in ``k`` and ``v``
+        fold = functools.partial(fold_in, _of_head(k_ref[:], head, dim),
+                                 _of_head(v_ref[:], head, dim),
+                                 head * n_blocks)
+        carry = (jnp.zeros((block_k, width), jnp.float32),
+                 jnp.zeros((block_k, width), jnp.float32))
         if causal:
-            # clamp the k index for fully-masked blocks to the last needed
-            # block: pl.when skips their compute, and the clamp means no
-            # fresh DMA is issued for them either (the previous block's
-            # buffer is reused) — saves ~half the k/v HBM traffic
-            def kv_index(i, j, kb):
-                last_needed = (j * block_q + block_q - 1 + q_offset) \
-                    // block_k
-                return (i, jnp.minimum(kb, last_needed), 0)
+            # the query blocks the diagonal crosses, then those wholly
+            # after the block's last key
+            first = k_start // block_q
+            after = jnp.minimum((k_start + block_k - 1) // block_q + 1,
+                                n_blocks)
+            carry = lax.fori_loop(first, after,
+                                  functools.partial(fold, True), carry)
+            carry = lax.fori_loop(after, n_blocks,
+                                  functools.partial(fold, False), carry)
         else:
-            def kv_index(i, j, kb):
-                return (i, kb, 0)
-        out, lse = _pallas_call(
-            partial(_flash_streaming_kernel, causal=causal,
-                    sm_scale=sm_scale, q_offset=q_offset, nk=nk,
-                    block_q=block_q, block_k=block_k),
-            out_shape=out_shape,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((None, block_q, d),
-                             lambda i, j, kb: (i, j, 0)),
-                pl.BlockSpec((None, block_k, d), kv_index),
-                pl.BlockSpec((None, block_k, d), kv_index),
-            ],
-            out_specs=(
-                pl.BlockSpec((None, block_q, d),
-                             lambda i, j, kb: (i, j, 0)),
-                pl.BlockSpec((None, block_q, 1),
-                             lambda i, j, kb: (i, j, 0)),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
-            ],
-        )(qt, kt, vt)
-        return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
-                lse.reshape(b * h, sq))
+            carry = lax.fori_loop(0, n_blocks,
+                                  functools.partial(fold, False), carry)
+        dk, dv = (_of_head(x, head, dim) for x in carry)
+        dk_out = dk if dk_out is None else dk_out + dk
+        dv_out = dv if dv_out is None else dv_out + dv
+    dk_ref[:] = (scale * dk_out).astype(dk_ref.dtype)
+    dv_ref[:] = dv_out.astype(dv_ref.dtype)
 
-    grid = (b * h, pl.cdiv(sq, block_q))
-    out, lse = _pallas_call(
-        partial(_flash_fwd_kernel, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, q_offset=q_offset),
-        out_shape=out_shape,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
-        ),
-    )(qt, kt, vt)
-    return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
-            lse.reshape(b * h, sq))
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _finish():
+        dq_ref[:] = (scale * dq_acc[:]).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k: int, causal: bool,
-                         sm_scale: float, q_offset: int):
-    """dq for one (batch*head, q-block): loop over k/v blocks up to the
-    diagonal.  P is rebuilt from the saved logsumexp; delta is the
-    precomputed rowsum(dO * O).  lse_ref/delta_ref: (block_q, 1)."""
-    block_q, d = q_ref.shape
-    s_k = k_ref.shape[0]
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:]
-    delta = delta_ref[:]
-    q_start = pl.program_id(1) * block_q + q_offset
-
-    def body(kb, dq_acc):
-        k_start = kb * block_k
-        k_blk = k_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
-        s = sm_scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq_acc + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    num_k_blocks = pl.cdiv(s_k, block_k)
-    if causal:
-        last_needed = lax.div(q_start + block_q - 1, block_k) + 1
-        n_iter = jnp.minimum(last_needed, num_k_blocks)
-    else:
-        n_iter = num_k_blocks
-    dq = lax.fori_loop(0, n_iter, body,
-                       jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[:] = (dq * sm_scale).astype(dq_ref.dtype)
+def _groups(heads: int, dim: int):
+    """(heads a group of lanes, the group's lanes, groups a row)."""
+    group = heads_a_group(dim)
+    return group, group * dim, heads // group
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          sm_scale: float, q_offset: int):
-    """dk/dv for one (batch*head, k-block): loop over q blocks from the
-    diagonal down.  Works on the transposed scores S^T (block_k, block_q),
-    so lse/delta come in as rows — lse_ref/delta_ref: (num q blocks,
-    block_q), one row per q block — and every product is a plain matmul."""
-    block_k, d = k_ref.shape
-    s_q = q_ref.shape[0]
-    k_blk = k_ref[:].astype(jnp.float32)
-    v_blk = v_ref[:].astype(jnp.float32)
-    k_start = pl.program_id(1) * block_k
-
-    def body(qb, carry):
-        dk_acc, dv_acc = carry
-        q_start_local = qb * block_q
-        q_start = q_start_local + q_offset
-        q = q_ref[pl.ds(q_start_local, block_q), :].astype(jnp.float32)
-        do = do_ref[pl.ds(q_start_local, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(qb, 1), :]
-        delta = delta_ref[pl.ds(qb, 1), :]
-        s_t = sm_scale * jax.lax.dot_general(
-            k_blk, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            k_pos = k_start + lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, NEG_INF)
-        p_t = jnp.exp(s_t - lse)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p_t, do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds_t, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
-
-    num_q_blocks = pl.cdiv(s_q, block_q)
-    if causal:
-        # the first q block whose rows can see this k block
-        first = lax.div(jnp.maximum(k_start - q_offset, 0), block_q)
-    else:
-        first = 0
-    z = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(first, num_q_blocks, body, (z, z))
-    dk_ref[:] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+def _forward(q, k, v, causal: bool, block_q: int, block_k: int,
+             interpret: bool):
+    """-> (out (B, S, H, D), lse (B, H, S) float32)."""
+    b, seq, h, d = q.shape
+    group, width, groups = _groups(h, d)
+    as_it_lies = lambda x: x.reshape(b, seq, h * d)   # noqa: E731
+    block = lambda i, g, j: (i, j, g)   # noqa: E731
+    row = lambda i, g, j: (i, 0, g)   # noqa: E731
+    out, lse = pl.pallas_call(
+        functools.partial(_forward_kernel, dim=d, block_k=block_k,
+                          causal=causal, scale=float(1 / np.sqrt(d))),
+        out_shape=(jax.ShapeDtypeStruct((b, seq, h * d), q.dtype),
+                   jax.ShapeDtypeStruct((b, groups, group, seq),
+                                        jnp.float32)),
+        grid=(b, groups, seq // block_q),
+        in_specs=[pl.BlockSpec((None, block_q, width), block),
+                  pl.BlockSpec((None, seq, width), row),
+                  pl.BlockSpec((None, seq, width), row)],
+        out_specs=(pl.BlockSpec((None, block_q, width), block),
+                   pl.BlockSpec((None, None, group, block_q),
+                                lambda i, g, j: (i, g, 0, j))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        # what a device trace calls the kernel's events
+        name="flash_attention_forward",
+    )(as_it_lies(q), as_it_lies(k), as_it_lies(v))
+    return out.reshape(q.shape), lse.reshape(b, h, seq)
 
 
-def _flash_backward_kernels(q, k, v, out, lse, do, *, causal: bool,
-                            q_offset: int, block_q: int = 256,
-                            block_k: int = 256):
-    """Two-pass flash backward (dq; dk/dv), VMEM-resident regime."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    sm_scale = 1.0 / np.sqrt(d)
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
-    nq = sq // block_q
-
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    ot = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    # delta = rowsum(dO * O): cheap elementwise reduce, XLA-fused
-    delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
-                    axis=-1)
-    # the per-row vectors in the two layouts the TPU lowering accepts
-    # (see _flash_forward): (Sq, 1) columns for the dq kernel, and one
-    # (block_q,) row per q block for the dkv kernel
-    as_col = lambda x: x.reshape(b * h, sq, 1)        # noqa: E731
-    as_rows = lambda x: x.reshape(b * h, nq, block_q)  # noqa: E731
-
-    full = lambda i, j: (i, 0, 0)  # noqa: E731
-    blk = lambda i, j: (i, j, 0)   # noqa: E731
-
-    dq = _pallas_call(
-        partial(_flash_bwd_dq_kernel, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, q_offset=q_offset),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        grid=(b * h, nq),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), blk),       # q
-            pl.BlockSpec((None, sk, d), full),           # k
-            pl.BlockSpec((None, sk, d), full),           # v
-            pl.BlockSpec((None, block_q, d), blk),       # do
-            pl.BlockSpec((None, block_q, 1), blk),       # lse
-            pl.BlockSpec((None, block_q, 1), blk),       # delta
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d), blk),
-    )(qt, kt, vt, dot, as_col(lse), as_col(delta))
-
-    dk, dv = _pallas_call(
-        partial(_flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
-                sm_scale=sm_scale, q_offset=q_offset),
-        out_shape=(jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)),
-        grid=(b * h, sk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, sq, d), full),           # q
-            pl.BlockSpec((None, block_k, d), blk),       # k
-            pl.BlockSpec((None, block_k, d), blk),       # v
-            pl.BlockSpec((None, sq, d), full),           # do
-            pl.BlockSpec((None, nq, block_q), full),     # lse
-            pl.BlockSpec((None, nq, block_q), full),     # delta
-        ],
-        out_specs=(pl.BlockSpec((None, block_k, d), blk),
-                   pl.BlockSpec((None, block_k, d), blk)),
-    )(qt, kt, vt, dot, as_rows(lse), as_rows(delta))
-
-    unt = lambda x, s: x.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
-    return unt(dq, sq), unt(dk, sk), unt(dv, sk)
+def _backward(q, k, v, out, lse, do, causal: bool, block_q: int,
+              block_k: int, interpret: bool):
+    """-> (dq, dk, dv), each (B, S, H, D)."""
+    b, seq, h, d = q.shape
+    group, width, groups = _groups(h, d)
+    # delta = rowsum(dO * O): one fused pass of the compiler's own
+    delta = jnp.einsum("bshd,bshd->bhs", do.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    # a row a head of the group and query block
+    rows = (b * groups, group * (seq // block_q), block_q)
+    as_it_lies = lambda x: x.reshape(b, seq, h * d)   # noqa: E731
+    row = lambda i, j: (i // groups, 0, i % groups)   # noqa: E731
+    blk = lambda i, j: (i // groups, j, i % groups)   # noqa: E731
+    vec = lambda i, j: (i, 0, 0)   # noqa: E731
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_backward_kernel, dim=d, block_q=block_q,
+                          causal=causal, scale=float(1 / np.sqrt(d))),
+        out_shape=tuple(jax.ShapeDtypeStruct((b, seq, h * d), x.dtype)
+                        for x in (q, k, v)),
+        grid=(b * groups, seq // block_k),
+        in_specs=[pl.BlockSpec((None, seq, width), row),          # q
+                  pl.BlockSpec((None, block_k, width), blk),      # k
+                  pl.BlockSpec((None, block_k, width), blk),      # v
+                  pl.BlockSpec((None, seq, width), row),          # do
+                  pl.BlockSpec((None,) + rows[1:], vec),          # lse
+                  pl.BlockSpec((None,) + rows[1:], vec)],         # delta
+        out_specs=(pl.BlockSpec((None, seq, width), row),
+                   pl.BlockSpec((None, block_k, width), blk),
+                   pl.BlockSpec((None, block_k, width), blk)),
+        scratch_shapes=[pltpu.VMEM((seq, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="flash_attention_backward",
+    )(as_it_lies(q), as_it_lies(k), as_it_lies(v), as_it_lies(do),
+      lse.reshape(rows), delta.reshape(rows))
+    return tuple(x.reshape(q.shape) for x in (dq, dk, dv))
 
 
-def _bwd_kernels_feasible(q, k) -> bool:
-    """Static predicate: the dq kernel keeps k+v (and the dkv kernel
-    q+do) resident per (batch, head) — beyond the VMEM budget the
-    backward recomputes instead."""
-    d = q.shape[-1]
-    return _resident_bytes(max(q.shape[1], k.shape[1]), d,
-                           q.dtype) <= VMEM_RESIDENT_LIMIT
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, block_q, block_k, interpret):
+    return _forward(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, q_offset, block_q, block_k):
-    return _flash_forward(q, k, v, causal=causal, q_offset=q_offset,
-                          block_q=block_q, block_k=block_k)[0]
+def _forward_rule(q, k, v, causal, block_q, block_k, interpret):
+    out, lse = _forward(q, k, v, causal, block_q, block_k, interpret)
+    return out, (q, k, v, out, lse)
 
 
-def _flash_fwd_rule(q, k, v, causal, q_offset, block_q, block_k):
-    out, lse = _flash_forward(q, k, v, causal=causal, q_offset=q_offset,
-                              block_q=block_q, block_k=block_k)
-    if _bwd_kernels_feasible(q, k):
-        return out, (q, k, v, out, lse)
-    # streaming regime: the recompute backward reads only (q, k, v) —
-    # do not hold activation-sized out/lse residuals exactly where
-    # memory is tightest
-    return out, (q, k, v, None, None)
+def _backward_rule(causal, block_q, block_k, interpret, residuals, do):
+    return _backward(*residuals, do, causal, block_q, block_k, interpret)
 
 
-def _chunked_reference_attention(q, k, v, *, causal: bool, offset: int,
-                                 chunk: int = 512):
-    """Reference attention computed q-chunk-wise with lax.map: peak score
-    memory is chunk x S instead of S x S, so the recompute backward stays
-    feasible at the long sequence lengths the streaming forward unlocks."""
-    from alpa_tpu.model.gpt_model import reference_attention
-    b, s, h, d = q.shape
-    if s % chunk != 0 or s <= chunk:
-        return reference_attention(q, k, v, causal=causal, offset=offset)
-    n = s // chunk
-    qc = q.reshape(b, n, chunk, h, d).transpose(1, 0, 2, 3, 4)
-
-    def one_chunk(args):
-        i, q_i = args
-        return reference_attention(q_i, k, v, causal=causal,
-                                   offset=offset + i * chunk)
-
-    # checkpointed: the vjp of the map keeps each chunk's inputs, not its
-    # (chunk, S) scores stacked over all chunks (S x S again)
-    outs = jax.lax.map(jax.checkpoint(one_chunk), (jnp.arange(n), qc))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(b, s, h, d)
+_flash_attention.defvjp(_forward_rule, _backward_rule)
 
 
-def _flash_bwd_rule(causal, q_offset, block_q, block_k, res, do):
-    q, k, v, out, lse = res
-    if out is not None:  # resident regime (see _flash_fwd_rule)
-        return _flash_backward_kernels(q, k, v, out, lse, do,
-                                       causal=causal, q_offset=q_offset,
-                                       block_q=block_q, block_k=block_k)
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _chunked_reference_attention(
-            q_, k_, v_, causal=causal, offset=q_offset), q, k, v)
-    return vjp(do)
-
-
-_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, offset: int = 0,
-                    block_q: int = 256, block_k: int = 256):
-    """Drop-in replacement for ``reference_attention`` (gpt_model.py).
-    ``block_q``/``block_k`` tune the kernel tiling (targets; clipped to
-    divisors of the sequence lengths)."""
-    return _flash_attention(q, k, v, causal, offset, block_q, block_k)
+def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 0,
+                    block_k: int = 0, interpret: bool = False):
+    """``reference_attention(q, k, v, causal=causal)`` for shapes that
+    ``fits`` takes, differentiable in all three.  ``block_q``, ``block_k``:
+    0 is ``blocks``' (the tests and the timings pass others)."""
+    seq, dim = q.shape[1], q.shape[3]
+    return _flash_attention(q, k, v, causal,
+                            block_q or blocks(seq, dim)[0],
+                            block_k or blocks(seq, dim)[1], interpret)

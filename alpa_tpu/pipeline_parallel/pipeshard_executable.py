@@ -33,6 +33,7 @@ from alpa_tpu.pipeline_parallel.runtime_emitter import (
     PipeshardConfig, PlacementSpecEntry, emit_free_instructions,
     partition_streams)
 from alpa_tpu.pipeline_parallel.schedules import create_pipeline_schedule
+from alpa_tpu.shard_parallel import kernel_choice
 from alpa_tpu.shard_parallel.auto_sharding import MESH_AXIS_NAMES
 from alpa_tpu.telemetry import device_time as _device_time
 from alpa_tpu.telemetry import flight as _flight
@@ -100,9 +101,13 @@ class StageExecutable:
 
     def plan(self):
         closed = self.comp.closed_jaxpr()
+        physical_mesh = self._physical_mesh
+        if physical_mesh.num_devices > 1:
+            # a kernel the planner cannot partition stays on a one-device
+            # mesh only
+            closed = kernel_choice.bind_defaults(closed)
         fun = jaxpr_as_fun(closed)
         avals = [v.aval for v in self.comp.invars]
-        physical_mesh = self._physical_mesh
         as_option = self._as_option
 
         if physical_mesh.num_devices > 1 and as_option.enable_auto_sharding:

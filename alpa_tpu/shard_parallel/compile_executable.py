@@ -18,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from alpa_tpu.device_mesh import LogicalDeviceMesh, PhysicalDeviceMesh
 from alpa_tpu.global_env import global_config
 from alpa_tpu.mesh_executable import GradAccMeshExecutable, NormalMeshExecutable
+from alpa_tpu.shard_parallel import kernel_choice
 from alpa_tpu.shard_parallel.auto_sharding import (AutoShardingOption,
                                                   MESH_AXIS_NAMES,
                                                   plan_rule_based, replicated)
@@ -80,6 +81,8 @@ def compile_shard_executable(
     """
     tic = time.time()
     batch_flat_idx = [i for i, b in enumerate(batch_invars) if b]
+    # a kernel the planner cannot partition stays on a one-device mesh only
+    fun = kernel_choice.for_mesh_of(physical_mesh.num_devices, fun)
 
     # ---- plan input shardings (on the original, scan-free function) ----
     if as_option.enable_auto_sharding and not as_option.force_data_parallel:

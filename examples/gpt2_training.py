@@ -20,9 +20,13 @@ from alpa_tpu.util import compute_gpt_tflops
 MODELS = {
     "tiny": GPTConfig(hidden_size=128, num_layers=4, num_heads=8,
                       seq_len=128, vocab_size=1024),
+    # 1,024 positions of 12 heads of 64: on one TPU chip the attention of
+    # every layer is the fused kernels of ops/flash_attention.py, chosen
+    # from these shapes (``gpt_model.attention``); on a mesh of several
+    # chips, and on the CPU, ``reference_attention``
     "125M": GPTConfig(hidden_size=768, num_layers=12, num_heads=12,
                       seq_len=1024, vocab_size=51200,
-                      dtype=jnp.bfloat16, attention_impl="flash"),
+                      dtype=jnp.bfloat16),
 }
 
 

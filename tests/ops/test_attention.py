@@ -1,13 +1,16 @@
-"""Flash / ring attention vs the einsum reference."""
+"""Fused / ring attention vs the einsum reference."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from alpa_tpu.model.gpt_model import reference_attention, update_kv_cache
-from alpa_tpu.ops.flash_attention import flash_attention
+from alpa_tpu.model.gpt_model import (GPTConfig, SelfAttention,
+                                      get_attention_fn,
+                                      reference_attention, update_kv_cache)
+from alpa_tpu.ops import flash_attention as fa
 from alpa_tpu.ops.ring_attention import make_ring_attention_fn, ring_attention
+
 
 
 def _rand_qkv(b=2, s=128, h=4, d=32, dtype=jnp.float32):
@@ -16,78 +19,186 @@ def _rand_qkv(b=2, s=128, h=4, d=32, dtype=jnp.float32):
         jax.random.normal(k, (b, s, h, d), dtype) * 0.5 for k in ks)
 
 
+def _interpreted(causal, **blocks):
+    return lambda q, k, v: fa.flash_attention(q, k, v, causal=causal,
+                                              interpret=True, **blocks)
+
+
+def _out_and_grads(core, q, k, v, do):
+    out, vjp = jax.vjp(core, q, k, v)
+    return [np.asarray(x, np.float32) for x in (out,) + vjp(do)]
+
+
 class TestFlashAttention:
+    """``ops/flash_attention.py``'s kernels (interpreted) against
+    ``reference_attention``."""
 
+    @pytest.mark.parametrize("remat", [False, True],
+                             ids=["plain", "checkpoint"])
     @pytest.mark.parametrize("causal", [True, False])
-    def test_forward_matches_reference(self, causal):
-        q, k, v = _rand_qkv()
-        out = flash_attention(q, k, v, causal=causal)
-        ref = reference_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+    @pytest.mark.parametrize("dim", [64, 128])
+    def test_output_and_gradients_match_reference(self, dim, causal, remat):
+        """bfloat16 operands as the models pass them, both head widths
+        (two heads of 64 side by side in a group of lanes, one of 128 a
+        group; two groups a row): the output and all three gradients are
+        the reference's to the rounding of bfloat16 (both multiply
+        bfloat16 and accumulate in float32; they differ in the order of
+        the sums), across block boundaries (two blocks a row) and under
+        ``jax.checkpoint`` (the forward kernel runs twice)."""
+        heads = 2 * fa.heads_a_group(dim)
+        q, k, v = _rand_qkv(b=1, s=256, h=heads, d=dim, dtype=jnp.bfloat16)
+        do = _rand_qkv(b=1, s=256, h=heads, d=dim, dtype=jnp.bfloat16)[1]
+        kernel = _interpreted(causal, block_q=128, block_k=128)
+        reference = lambda q, k, v: reference_attention(  # noqa: E731
+            q, k, v, causal=causal)
+        if remat:
+            kernel, reference = (jax.checkpoint(kernel),
+                                 jax.checkpoint(reference))
+        for got, want in zip(_out_and_grads(kernel, q, k, v, do),
+                             _out_and_grads(reference, q, k, v, do)):
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=8e-3)
 
-    def test_backward_matches_reference(self):
-        q, k, v = _rand_qkv(s=64)
+    def test_float32_inputs_match_to_float32(self):
+        """Nothing in the kernels rounds to bfloat16 on its own: float32
+        operands give the reference's float32 result."""
+        q, k, v = _rand_qkv(b=1, s=256, h=2, d=64)
+        do = _rand_qkv(b=1, s=256, h=2, d=64)[2]
+        for got, want in zip(
+                _out_and_grads(_interpreted(True, block_q=128, block_k=128),
+                               q, k, v, do),
+                _out_and_grads(lambda q, k, v: reference_attention(
+                    q, k, v, causal=True), q, k, v, do)):
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-        def loss_flash(q, k, v):
-            return (flash_attention(q, k, v, causal=True)**2).sum()
+    @pytest.mark.parametrize("block_q,block_k", [(64, 128), (128, 64)])
+    def test_blocks_of_two_sizes(self, block_q, block_k):
+        """Query and key blocks of different sizes: the diagonal crosses
+        more than one block of the other kind."""
+        q, k, v = _rand_qkv(b=1, s=256, h=2, d=64)
+        do = _rand_qkv(b=1, s=256, h=2, d=64)[0]
+        for got, want in zip(
+                _out_and_grads(_interpreted(True, block_q=block_q,
+                                            block_k=block_k), q, k, v, do),
+                _out_and_grads(lambda q, k, v: reference_attention(
+                    q, k, v, causal=True), q, k, v, do)):
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-        def loss_ref(q, k, v):
-            return (reference_attention(q, k, v, causal=True)**2).sum()
+    def test_four_narrow_heads_share_a_group_of_lanes(self):
+        q, k, v = _rand_qkv(b=2, s=256, h=8, d=32)
+        do = _rand_qkv(b=2, s=256, h=8, d=32)[2]
+        assert fa.heads_a_group(32) == 4
+        for got, want in zip(
+                _out_and_grads(_interpreted(True, block_q=128, block_k=128),
+                               q, k, v, do),
+                _out_and_grads(lambda q, k, v: reference_attention(
+                    q, k, v, causal=True), q, k, v, do)):
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
+    @pytest.mark.parametrize("seq,dim,block", [
+        (512, 64, 512), (1024, 64, 512), (4096, 128, 512), (2048, 256, 256)])
+    def test_blocks_follow_the_call(self, seq, dim, block):
+        assert fa.blocks(seq, dim) == (block, block)
 
-    def test_uneven_blocks(self):
-        q, k, v = _rand_qkv(s=96)  # not a multiple of default block sizes
-        out = flash_attention(q, k, v, causal=True)
-        ref = reference_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+    def test_no_operand_of_a_product_is_cast_to_float32(self):
+        """The kernels feed the matrix unit the dtype they are handed:
+        every ``dot_general`` inside them multiplies bfloat16 operands
+        into float32."""
+        q, k, v = _rand_qkv(b=1, s=256, h=1, d=128, dtype=jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: _interpreted(
+            True, block_q=128, block_k=128)(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
 
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_kernel_backward_matches_reference(self, causal):
-        """The VMEM-resident regime uses the real pallas backward kernels
-        (dq; dk/dv off saved out+logsumexp) — gradients must match the
-        reference, including across block boundaries (s > block sizes)."""
-        from alpa_tpu.ops.flash_attention import VMEM_RESIDENT_LIMIT
-        q, k, v = _rand_qkv(s=512, d=64)
-        itemsize = jnp.dtype(q.dtype).itemsize
-        assert 2 * 512 * 64 * itemsize <= VMEM_RESIDENT_LIMIT
+        def products(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "dot_general":
+                    yield eqn
+                for value in eqn.params.values():
+                    for sub in (value if isinstance(value, (tuple, list))
+                                else [value]):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            yield from products(inner)
 
-        def loss_flash(q, k, v):
-            return (flash_attention(q, k, v, causal=causal)**2).sum()
+        found = [eqn for eqn in products(jaxpr.jaxpr)
+                 if eqn.invars[0].aval.ndim == 2]
+        # two products a pair of blocks forward and five backward, once
+        # in the loop over masked pairs and once in the loop over the rest
+        assert len(found) == 2 * (2 + 5)
+        for eqn in found:
+            assert {v.aval.dtype for v in eqn.invars} == {
+                jnp.dtype(jnp.bfloat16)}
+            assert eqn.outvars[0].aval.dtype == jnp.float32
 
-        def loss_ref(q, k, v):
-            return (reference_attention(q, k, v, causal=causal)**2).sum()
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
+def _traced_core(config, seq, padding_bias=False):
+    """Which core ``SelfAttention`` without a cache traces at these
+    shapes: "fused" where the program holds the kernels' ``pallas_call``
+    (``lax.platform_dependent`` traces both branches)."""
+    attn = SelfAttention(config)
+    x = jax.ShapeDtypeStruct((2, seq, config.hidden_size), config.dtype)
+    bias = jnp.zeros((2, 1, 1, seq), jnp.float32) if padding_bias else None
+    positions = jnp.broadcast_to(jnp.arange(seq), (2, seq))
 
-    def test_streaming_backward_falls_back(self):
-        """Beyond the VMEM budget the backward takes the chunked
-        recompute path and still matches the reference."""
-        from alpa_tpu.ops.flash_attention import VMEM_RESIDENT_LIMIT
-        q, k, v = _rand_qkv(b=1, s=16384, h=1, d=64)
-        assert 2 * 16384 * 64 * 4 > VMEM_RESIDENT_LIMIT
+    def apply(x):
+        params = attn.init(jax.random.PRNGKey(0), x, position_ids=positions,
+                           padding_bias=bias)
+        return attn.apply(params, x, position_ids=positions,
+                          padding_bias=bias)[0]
 
-        def loss_flash(q, k, v):
-            return (flash_attention(q, k, v, causal=True)**2).sum()
+    text = str(jax.make_jaxpr(apply)(x))
+    return "fused" if "pallas_call" in text else "reference"
 
-        def loss_ref(q, k, v):
-            return (reference_attention(q, k, v, causal=True)**2).sum()
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=3e-4, atol=3e-4)
+_WIDE = dict(hidden_size=256, num_heads=4, num_layers=1,
+             dtype=jnp.bfloat16, vocab_size=128)
+
+
+@pytest.mark.parametrize("what,overrides,seq,bias,core", [
+    ("causal", {}, 512, False, "fused"),
+    ("no-mask", dict(causal=False), 1024, False, "fused"),
+    ("grouped-heads", dict(num_kv_heads=2), 512, False, "reference"),
+    ("window", dict(attention="sliding", sliding_window=128,
+                    positions="rotary"), 512, False, "reference"),
+    ("block-mask", dict(block_length=4), 512, False, "reference"),
+    ("padding-bias", dict(causal=False), 512, True, "reference"),
+    ("odd-length", {}, 520, False, "reference"),
+    ("short-length", {}, 256, False, "reference"),
+    ("ring", dict(attention_impl="ring", sp_axis="sp"), 512, False, None),
+])
+def test_the_shapes_choose_the_core(what, overrides, seq, bias, core):
+    """``attention_impl`` "reference" is the core the call chooses: the
+    fused kernels for full attention with a causal mask or none, one
+    length their blocks divide, from ``MIN_SEQ`` positions;
+    ``reference_attention`` for everything the kernels do not take."""
+    config = GPTConfig(seq_len=seq, **{**_WIDE, **overrides})
+    if core is None:
+        # the sequence-parallel cores are chosen by the field, as before
+        assert get_attention_fn(config).func is ring_attention
+        return
+    assert _traced_core(config, seq, bias) == core
+
+
+def test_flash_is_no_value_of_attention_impl():
+    with pytest.raises(ValueError, match="unknown attention_impl"):
+        get_attention_fn(GPTConfig(attention_impl="flash"))
+
+
+@pytest.mark.parametrize("what,q_shape,k_shape,takes", [
+    ("gpt-1.3b-train", (8, 1024, 32, 64), (8, 1024, 32, 64), True),
+    ("olmoe-train", (2, 4096, 16, 128), (2, 4096, 16, 128), True),
+    ("the-shortest", (16, 512, 32, 64), (16, 512, 32, 64), True),
+    ("the-longest", (1, 16384, 8, 128), (1, 16384, 8, 128), True),
+    ("grouped-heads", (2, 1024, 32, 128), (2, 1024, 4, 128), False),
+    ("queries-over-a-longer-cache", (2, 512, 8, 64), (2, 1024, 8, 64),
+     False),
+    ("no-whole-blocks", (2, 1000, 8, 64), (2, 1000, 8, 64), False),
+    ("too-short", (2, 256, 8, 64), (2, 256, 8, 64), False),
+    ("an-odd-head-of-64", (2, 512, 7, 64), (2, 512, 7, 64), False),
+    ("heads-of-no-whole-lanes", (2, 512, 8, 96), (2, 512, 8, 96), False),
+    ("too-long", (1, 32768, 8, 128), (1, 32768, 8, 128), False)])
+def test_flash_attention_fits(what, q_shape, k_shape, takes):
+    assert fa.fits(jax.ShapeDtypeStruct(q_shape, jnp.bfloat16),
+                   jax.ShapeDtypeStruct(k_shape, jnp.bfloat16)) is takes
 
 
 class TestRingAttention:
@@ -170,16 +281,17 @@ class TestUlyssesAttention:
                                        rtol=2e-5, atol=2e-5)
 
     def test_composes_with_flash_kernel(self):
-        """Ulysses SP + the pallas flash kernel per head shard: the
-        all-to-all hands each device the FULL sequence for its heads, so
-        the blocked kernel applies unchanged — fwd and grads match the
-        reference."""
-        from alpa_tpu.ops.flash_attention import flash_attention
+        """Ulysses SP + the fused kernels per head shard: the all-to-all
+        hands each device the FULL sequence for its heads, so the blocked
+        kernels apply unchanged — fwd and grads match the reference."""
         from alpa_tpu.ops.ulysses_attention import make_ulysses_attention_fn
         mesh = self._mesh()
-        q, k, v = _rand_qkv(s=256, h=8, d=32)
-        attn = make_ulysses_attention_fn(mesh, "sp",
-                                         attn_fn=flash_attention)
+        # two heads of 64 a device: one group of lanes
+        q, k, v = _rand_qkv(s=256, h=8, d=64)
+        attn = make_ulysses_attention_fn(
+            mesh, "sp", attn_fn=lambda q, k, v, causal: fa.flash_attention(
+                q, k, v, causal=causal, block_q=128, block_k=128,
+                interpret=True))
         with jax.set_mesh(mesh):
             out = jax.jit(lambda q, k, v: attn(q, k, v, causal=True))(
                 q, k, v)
@@ -207,21 +319,18 @@ class TestUlyssesAttention:
                 jax.jit(lambda q, k, v: attn(q, k, v))(q, k, v)
 
 
-class TestStreamingFlash:
-
-    def test_long_sequence_streaming_path(self):
-        """k/v beyond the VMEM-resident limit take the HBM-streaming
-        kernel; result must match the reference exactly."""
-        from alpa_tpu.ops.flash_attention import (VMEM_RESIDENT_LIMIT,
-                                                  flash_attention)
-        s, d = 16384, 64
-        assert 2 * s * d * 4 > VMEM_RESIDENT_LIMIT  # streaming triggers
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(kk, (1, s, 1, d)) * 0.5 for kk in ks)
-        out = flash_attention(q, k, v, causal=True)
-        ref = reference_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+def test_a_long_row_stays_in_the_kernels_fast_memory():
+    """A row of several blocks a side (the streaming kernel this one
+    replaces took over from 8,192 positions; this one keeps a row of up
+    to ``MAX_SEQ``): forward and backward against the reference."""
+    q, k, v = _rand_qkv(b=1, s=1024, h=2, d=64)
+    do = _rand_qkv(b=1, s=1024, h=2, d=64)[1]
+    for got, want in zip(
+            _out_and_grads(_interpreted(True, block_q=256, block_k=256),
+                           q, k, v, do),
+            _out_and_grads(lambda q, k, v: reference_attention(
+                q, k, v, causal=True), q, k, v, do)):
+        np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
 
 # ---- a few new queries a row over the row's written cache (ISSUE 40) ----

@@ -1,4 +1,4 @@
-"""Compile the Pallas flash-attention kernels for a described TPU v5e.
+"""Compile the Pallas kernels for a described TPU v5e.
 
 No chip is needed: the TPU compiler is installed and compiles for a
 topology that is described and not attached.  What it refuses here
@@ -11,37 +11,45 @@ import: only one process may hold the TPU library, and every pytest
 worker imports every test file.
 """
 import functools
-import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-# the package re-exports the function under the module's name
-fa = importlib.import_module("alpa_tpu.ops.flash_attention")
+from alpa_tpu.ops import flash_attention as fa
+
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # pylint: disable=broad-except
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
 def _fwd(q, k, v):
-    return fa._flash_forward(q, k, v, causal=True)
+    block_q, block_k = fa.blocks(q.shape[1], q.shape[3])
+    return fa._forward(q, k, v, True, block_q, block_k, False)
 
 
 def _bwd(q, k, v, out, lse, do):
-    return fa._flash_backward_kernels(q, k, v, out, lse, do, causal=True,
-                                      q_offset=0)
+    block_q, block_k = fa.blocks(q.shape[1], q.shape[3])
+    return fa._backward(q, k, v, out, lse, do, True, block_q, block_k,
+                        False)
 
 
 def _public_grad(q, k, v):
@@ -51,15 +59,17 @@ def _public_grad(q, k, v):
 
 
 # (id, function, (B, S, H, D), takes the backward's residuals,
-#  Pallas kernels expected in the compiled program)
+#  Pallas kernels expected in the compiled program): the two training
+# cells' shapes and the longest row ``fits`` takes
 CASES = [
-    ("fwd-resident-d64", _fwd, (8, 1024, 32, 64), False, 1),
-    ("fwd-resident-d128", _fwd, (2, 2048, 16, 128), False, 1),
-    ("fwd-resident-limit-d64", _fwd, (1, 8192, 32, 64), False, 1),
-    ("fwd-streaming-d128", _fwd, (1, 32768, 8, 128), False, 1),
-    ("bwd-kernels-d64", _bwd, (8, 1024, 32, 64), True, 2),
-    ("bwd-kernels-d128", _bwd, (2, 2048, 16, 128), True, 2),
-    ("public-grad-d64", _public_grad, (8, 1024, 32, 64), False, 3),
+    ("fwd-d64", _fwd, (8, 1024, 32, 64), False, 1),
+    ("fwd-d128", _fwd, (2, 4096, 16, 128), False, 1),
+    ("fwd-longest-d64", _fwd, (1, 16384, 4, 64), False, 1),
+    ("fwd-longest-d128", _fwd, (1, 16384, 4, 128), False, 1),
+    ("bwd-d64", _bwd, (8, 1024, 32, 64), True, 1),
+    ("bwd-d128", _bwd, (2, 4096, 16, 128), True, 1),
+    ("bwd-longest-d128", _bwd, (1, 16384, 4, 128), True, 1),
+    ("public-grad-d64", _public_grad, (8, 1024, 32, 64), False, 2),
 ]
 
 
@@ -69,16 +79,101 @@ def test_flash_kernels_compile_for_v5e(one_chip, fn, shape, residuals,
                                        n_kernels):
     b, s, h, _ = shape
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    assert fa.fits(x, x)
     args = [x, x, x]
     if residuals:
-        lse = jax.ShapeDtypeStruct((b * h, s), jnp.float32,
+        lse = jax.ShapeDtypeStruct((b, h, s), jnp.float32,
                                    sharding=one_chip)
         args += [x, lse, x]
-    # the default backend here is the CPU: the kernels must pick compiled
-    # mode from the platform they are lowered for, not from the backend
+    # the default backend here is the CPU: the kernels are compiled
+    # because the program is lowered for a TPU
     assert jax.default_backend() == "cpu"
     hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == n_kernels
+    assert hlo.count(KERNEL) == n_kernels
+
+
+def _block_step(shape, remat):
+    """``value_and_grad`` of one ``TransformerBlock`` at a cell's batch,
+    length, heads and head width, and its abstract arguments."""
+    from alpa_tpu.model.gpt_model import GPTConfig, TransformerBlock
+    b, s, h, d = shape
+    cfg = GPTConfig(hidden_size=h * d, num_heads=h, num_layers=1, seq_len=s,
+                    dtype=jnp.bfloat16, vocab_size=512)
+    block = TransformerBlock(cfg)
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16)
+    params = jax.eval_shape(lambda: block.init(
+        jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype)))
+
+    def step(params, x):
+        apply = lambda p, x: block.apply(p, x)[0]   # noqa: E731
+        if remat:
+            apply = jax.checkpoint(apply)
+        return jax.value_and_grad(
+            lambda p: apply(p, x).astype(jnp.float32).sum())(params)
+
+    return step, params, x
+
+
+def _scores(text, seq):
+    """The arrays of a program's text that are a row's whole scores:
+    ``[., ., seq, seq]``."""
+    return set(re.findall(r"\w+\[\d+,\d+,%d,%d\]" % (seq, seq), text))
+
+
+# (id, (B, S, H, D), under jax.checkpoint, kernels): the GPT cell's block
+# (rematerialised: the forward kernel twice) and OLMoE's
+BLOCK_CASES = [
+    ("gpt-1.3b-train", (8, 1024, 32, 64), True, 3),
+    ("olmoe-train", (2, 4096, 16, 128), False, 2),
+]
+
+
+@pytest.mark.parametrize("shape,remat,n_kernels",
+                         [c[1:] for c in BLOCK_CASES],
+                         ids=[c[0] for c in BLOCK_CASES])
+def test_a_blocks_step_keeps_no_scores_on_v5e(one_chip, shape, remat,
+                                              n_kernels):
+    """The whole ``value_and_grad`` of one block at a training cell's
+    shapes, lowered for one v5e: its attention is the kernels (under the
+    scope a capture reads them by), and no array of a row's whole scores
+    is left in the program: what ``reference_attention`` would have saved
+    for its backward pass is dead beside the kernels' branch."""
+    from alpa_tpu.model.gpt_model import ATTENTION_SCOPE
+    from alpa_tpu.telemetry.device_time import part_of
+    step, params, x = _block_step(shape, remat)
+    on_chip = lambda a: jax.ShapeDtypeStruct(   # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    text = jax.jit(step).lower(jax.tree_util.tree_map(on_chip, params),
+                               on_chip(x)).compile().as_text()
+    kernels = [line for line in text.splitlines() if KERNEL in line]
+    assert len(kernels) == n_kernels
+    for line in kernels:
+        assert part_of(re.search(r'op_name="([^"]*)"', line).group(
+            1)) == ATTENTION_SCOPE
+    assert not _scores(text, shape[1])
+
+
+@pytest.mark.parametrize("devices,n_kernels", [(1, 3), (2, 0)],
+                         ids=["one-device", "two-devices"])
+def test_a_mesh_of_two_plans_the_reference_core(topo, devices, n_kernels):
+    """The GPT cell's block under ``ShardParallel`` on described v5e
+    devices: on one the step holds its three kernels, on a mesh of two
+    none (``shard_parallel/kernel_choice.py``: the planner binds the
+    choice's ``reference_attention``, whose einsums it can shard)."""
+    import alpa_tpu
+    step, params, x = _block_step((8, 1024, 32, 64), True)
+    alpa_tpu.init("local", devices=topo.devices[:devices])
+    try:
+        planned = alpa_tpu.parallelize(
+            lambda params, x: step(params, x),
+            method=alpa_tpu.ShardParallel(), static_argnums=(),
+            donate_argnums=())
+        text = planned.get_executable(params, x)[0].get_hlo_text()
+    finally:
+        alpa_tpu.shutdown()
+    assert text.count(KERNEL) == n_kernels
+    assert bool(_scores(text, 1024) or
+                re.search(r"\[\d+,\d+,512,1024\]", text)) == (devices == 2)
 
 
 # the serving cells' ticks over their written caches, as (id, rows, served

@@ -74,6 +74,25 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
      "rematted_computation/h0/attn/attention/exp", "attention"),
     ("jit(constrained)/transpose(jvp(GPTModel))/jvp(GPTModel)/checkpoint/"
      "h0/mlp/fc_out/dot_general", "mlp"),
+    # the fused attention kernels of a training step, as a lowering for
+    # the v5e names them (``tests/ops/test_tpu_compile.py`` reads the live
+    # ones): the forward pass's, the rematerialised block's, and the
+    # backward pass's under ``checkpoint`` and without
+    ("jit(loss)/jvp(TransformerBlock)/attn/attention/jit(_fused_attention)/"
+     "cond/branch_0_fun/flash_attention_forward/pallas_call", "attention"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "TransformerBlock/attn/attention/jit(_fused_attention)/cond/"
+     "branch_0_fun/flash_attention_forward/pallas_call", "attention"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/TransformerBlock/attn/"
+     "attention/jit(_fused_attention)/cond/jit(loss)/"
+     "transpose(jvp(jvp()))/checkpoint/TransformerBlock/attn/attention/"
+     "jit(_fused_attention)/cond/branch_0_fun/flash_attention_backward/"
+     "pallas_call", "attention"),
+    ("jit(loss)/transpose(jvp(TransformerBlock))/attn/attention/"
+     "jit(_fused_attention)/cond/jit(loss)/"
+     "transpose(jvp(TransformerBlock))/attn/attention/"
+     "jit(_fused_attention)/cond/branch_0_fun/flash_attention_backward/"
+     "pallas_call", "attention"),
     # a weight the compiler re-lays out keeps the argument's name, as the
     # HLO text escapes it and as jax writes it
     ("params[\\'params\\'][\\'h0\\'][\\'attn\\'][\\'q_b\\'][\\'kernel\\']",
