@@ -168,12 +168,13 @@ class GlobalConfig:
         self.overlap_inflight_window = int(os.environ.get(
             "ALPA_TPU_OVERLAP_WINDOW", "0"))
         # Cross-mesh RESHARD lowering strategy (ISSUE 7): "auto" picks
-        # per edge by the collective cost model (mesh_profiling's
-        # intra-mesh collective leg; the cross-mesh leg has no price
-        # yet, so "auto" is direct_p2p until one is measured); forcing
-        # "direct_p2p" | "slice_all_gather" | "all_to_all" |
-        # "reduce_scatter_gather" pins every edge where the strategy is
-        # eligible (ineligible edges fall back to direct_p2p).
+        # per edge from the two shardings: direct_p2p where that move is
+        # chip to chip, else the candidate whose wire leg is
+        # (aligned_relayout), priced among several by the collective
+        # cost model; forcing "direct_p2p" | "slice_all_gather" |
+        # "all_to_all" | "reduce_scatter_gather" | "aligned_relayout"
+        # pins every edge where the strategy is eligible (ineligible
+        # edges fall back to direct_p2p).
         self.reshard_strategy = os.environ.get(
             "ALPA_TPU_RESHARD_STRATEGY", "auto")
         # Lossy transfer codec for cross-mesh ACTIVATION edges (ISSUE 7):

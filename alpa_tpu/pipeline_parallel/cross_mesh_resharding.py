@@ -578,17 +578,14 @@ def naive_transfer_bytes(shape, itemsize, dst_sharding,
 # * ``reduce_scatter_gather`` — source is replicated / partial-
 #   reducible: pull disjoint scattered pieces from distinct source
 #   replicas, then gather inside the destination mesh.
+# * ``aligned_relayout`` — the edge crosses in the layout whose move is
+#   1:1 and is re-laid out on ONE of the two meshes: a sharded source
+#   lands in its own spec written on the destination mesh and is re-laid
+#   out there; a replicated source is first sliced on its own mesh to
+#   the destination's spec written there, and then crosses.  What
+#   ``auto`` takes wherever the direct move is not chip to chip.
 RESHARD_STRATEGIES = ("direct_p2p", "slice_all_gather", "all_to_all",
-                      "reduce_scatter_gather")
-
-# intra-destination-mesh collective each strategy's second leg emits,
-# charged from mesh_profiling's per-kind (alpha, beta) calibration
-_STRATEGY_COLLECTIVE_KIND = {
-    "direct_p2p": None,
-    "slice_all_gather": "all_gather",
-    "all_to_all": "all_to_all",
-    "reduce_scatter_gather": "reduce_scatter",
-}
+                      "reduce_scatter_gather", "aligned_relayout")
 
 
 def _sharding_key(sharding) -> str:
@@ -654,9 +651,10 @@ def _scatter_sharding(dst_sharding, shape):
 
 
 def _translate_spec(src_sharding, dst_sharding, shape):
-    """The source layout re-expressed on the destination mesh (the
-    all-to-all landing layout), or None when the meshes' axis structures
-    do not line up (conservative: same-named equal-size axes only)."""
+    """``src_sharding``'s layout re-expressed on ``dst_sharding``'s mesh
+    (the landing layout of ``all_to_all`` and ``aligned_relayout``), or
+    None when the meshes' axis structures do not line up (conservative:
+    same-named equal-size axes only)."""
     from jax.sharding import NamedSharding, PartitionSpec
     entries = _spec_entries(src_sharding, len(shape))
     if entries is None:
@@ -702,41 +700,77 @@ def _strategy_link_stats(shape, itemsize, src_sharding,
     }
 
 
+def moves_chip_to_chip(shape, src_sharding, dst_sharding) -> bool:
+    """Whether ``jax.device_put`` carries ``src_sharding -> dst_sharding``
+    from device to device: a 1:1 shard move, or fully replicated on both
+    sides.  Any other cross-mesh move fetches the array to the host
+    (``ArrayImpl._value``) and slices it back in, which first waits for
+    whatever produces the array."""
+    if shard_structures_match(shape, src_sharding, dst_sharding):
+        return True
+    try:
+        return bool(src_sharding.is_fully_replicated and
+                    dst_sharding.is_fully_replicated)
+    except Exception:  # pylint: disable=broad-except
+        return False
+
+
 def collective_options(shape, itemsize, src_sharding, dst_sharding
                        ) -> Dict[str, Dict[str, Any]]:
     """Eligible strategies for one edge, in preference (tie-break)
-    order: name -> {"landing": sharding the wire leg targets, "kind":
-    intra-mesh collective kind (None for direct), "stats": wire-leg link
-    stats}.  ``direct_p2p`` is always present."""
+    order: name -> {"landing": sharding of the staged value, "kind":
+    intra-mesh collective kind (None where there is none), "stats":
+    wire-leg link stats, "chip_to_chip": whether the wire leg stays off
+    the host (:func:`moves_chip_to_chip`), "relayout_first": whether the
+    landing lies on the SOURCE mesh, so that the relayout runs before
+    the wire leg}.  ``direct_p2p`` is always present."""
     opts: Dict[str, Dict[str, Any]] = {}
 
-    def add(name, landing):
+    def add(name, landing, kind, relayout_first=False):
+        wire = (landing, dst_sharding) if relayout_first else \
+            (src_sharding, landing)
         opts[name] = {
             "landing": landing,
-            "kind": _STRATEGY_COLLECTIVE_KIND[name],
-            "stats": _strategy_link_stats(shape, itemsize, src_sharding,
-                                          landing),
+            "kind": kind,
+            "stats": _strategy_link_stats(shape, itemsize, *wire),
+            "chip_to_chip": moves_chip_to_chip(shape, *wire),
+            "relayout_first": relayout_first,
         }
 
-    add("direct_p2p", dst_sharding)
+    add("direct_p2p", dst_sharding, None)
     try:
         src_repl = _replication(src_sharding, shape)
         dst_repl = _replication(dst_sharding, shape)
+        src_whole = src_sharding.is_fully_replicated
     except Exception:  # pylint: disable=broad-except
         return opts
+    if not opts["direct_p2p"]["chip_to_chip"]:
+        # the layout whose move is 1:1: a whole source is sliced on its
+        # own mesh first (no collective) and then crosses; a sharded one
+        # crosses as it lies and is re-laid out on the destination mesh
+        landing = _translate_spec(dst_sharding, src_sharding, shape) \
+            if src_whole else \
+            _translate_spec(src_sharding, dst_sharding, shape)
+        if landing is not None:
+            add("aligned_relayout", landing,
+                None if src_whole else
+                "all_gather" if dst_repl > 1 else "all_to_all",
+                relayout_first=src_whole)
+            if not opts["aligned_relayout"]["chip_to_chip"]:
+                del opts["aligned_relayout"]
     dst_entries = _spec_entries(dst_sharding, len(shape))
     scattered = _scatter_sharding(dst_sharding, shape) \
         if dst_entries is not None else None
     if src_repl > 1 and scattered is not None:
         # distinct source replicas serve disjoint scattered pieces
-        add("reduce_scatter_gather", scattered)
+        add("reduce_scatter_gather", scattered, "reduce_scatter")
     if dst_repl > 1 and scattered is not None:
-        add("slice_all_gather", scattered)
+        add("slice_all_gather", scattered, "all_gather")
     if src_repl == 1 and dst_repl == 1:
         translated = _translate_spec(src_sharding, dst_sharding, shape)
         if (translated is not None and dst_entries is not None and
                 _spec_entries(translated, len(shape)) != dst_entries):
-            add("all_to_all", translated)
+            add("all_to_all", translated, "all_to_all")
     return opts
 
 
@@ -764,10 +798,14 @@ def choose_strategy(shape, itemsize, src_sharding, dst_sharding
     "auto"; ineligible forced strategies fall back to direct_p2p).
     Returns (strategy, per-candidate costs, candidate options).
 
-    The cross-mesh leg has no analytic price: a candidate costs its
-    intra-destination collective leg only, so without measurements
-    ``direct_p2p`` always wins, until ROADMAP A1a gives that leg a
-    measured price.
+    ``auto`` chooses among the candidates whose wire leg moves chip to
+    chip (``collective_options``' ``chip_to_chip``, read off the two
+    shardings), since any other leg goes through the host; where no
+    candidate does (the meshes' axes do not line up) the edge stays
+    ``direct_p2p``.  Among those the cross-mesh leg has no analytic
+    price, so a candidate costs its intra-mesh collective leg only and
+    ties go to the first offered: ``direct_p2p`` wherever it is chip to
+    chip, ``aligned_relayout`` elsewhere.
 
     Under ``replan_mode != off`` (ISSUE 12) the calibration store
     supersedes the analytic price wherever it has enough measured
@@ -824,7 +862,8 @@ def choose_strategy(shape, itemsize, src_sharding, dst_sharding
     if forced != "auto":
         chosen = forced if forced in opts else "direct_p2p"
     else:
-        order = list(opts)
+        order = [n for n in opts if opts[n]["chip_to_chip"]] or \
+            ["direct_p2p"]
         chosen = min(order, key=lambda n: (costs[n], order.index(n)))
     return chosen, costs, opts
 
@@ -834,7 +873,9 @@ def resolve_strategy(shape, itemsize, src_sharding, dst_sharding
     """Cache-backed :func:`choose_strategy`: per-edge decisions persist
     in the compile cache (namespace ``reshard_strategy``), so a warm
     restart replays the identical plan without re-costing.  The key
-    covers the edge signature AND every knob the cost model reads —
+    covers the edge signature, every knob the cost model reads AND the
+    name of ``auto``'s rule (a decision cached by a tree in which
+    ``auto`` meant ``direct_p2p`` everywhere must not replay) —
     plus, when replanning is active, the calibration-store fingerprint
     (ISSUE 12): a calibrated re-solve caches like any other plan, an
     unchanged store replays it, and ``replan_mode=off`` keys stay
@@ -846,8 +887,8 @@ def resolve_strategy(shape, itemsize, src_sharding, dst_sharding
     tok = calibration_cache_token()
     parts = (tuple(shape), int(itemsize),
              _sharding_key(src_sharding), _sharding_key(dst_sharding),
-             getattr(global_config, "reshard_strategy", "auto")) \
-        + ((tok,) if tok else ())
+             getattr(global_config, "reshard_strategy", "auto"),
+             "auto=chip_to_chip") + ((tok,) if tok else ())
     cache = get_compile_cache() if cache_enabled() else None
     key = cache.make_key("reshard_strategy", parts) if cache else None
     if cache is not None:
@@ -873,6 +914,13 @@ _STRATEGY_COUNT = _PLANNER_REG.counter(
     "alpa_reshard_strategy_total",
     "Cross-mesh resharding edges planned, per chosen strategy",
     labelnames=("kind",))
+# a planned two-leg edge that found another source layout at run time
+# than the plan assumed, and took plain device_put (per call, where the
+# family above counts per planned edge)
+_RUNTIME_FALLBACKS = _PLANNER_REG.counter(
+    "alpa_reshard_runtime_fallback_total",
+    "Calls of a two-leg resharding edge that fell back to device_put "
+    "because the runtime array's sharding was not the planned one")
 
 
 def strategy_plan_fingerprint() -> str:
@@ -1052,34 +1100,53 @@ class DirectTransferGroup:
 
 
 class CollectiveTransfer:
-    """Pre-resolved executor for one RESHARD edge lowered to a two-leg
-    collective sequence (ISSUE 7; "Memory-efficient array redistribution
-    through portable collective communication", PAPERS.md):
+    """Pre-resolved executor for one RESHARD edge lowered to two legs
+    (ISSUE 7; "Memory-efficient array redistribution through portable
+    collective communication", PAPERS.md):
 
-    1. **wire leg** — ``jax.device_put`` to the *landing* sharding on the
-       destination mesh (the 1/k scattered layout for
-       ``slice_all_gather`` / ``reduce_scatter_gather``, the translated
-       source layout for ``all_to_all``), so only the strategy's reduced
-       byte volume crosses meshes;
-    2. **collective leg** — a cached identity ``jax.jit`` with
-       ``out_shardings=dst_sharding``: XLA emits the intra-destination
-       all-gather / all-to-all over the mesh's own links (the same
-       lowering will emit real DCN collectives on multi-host, ROADMAP
-       item 1).
+    1. **wire leg** — ``jax.device_put`` from the source mesh to the
+       destination mesh, in the *landing* layout (the 1/k scattered
+       layout for ``slice_all_gather`` / ``reduce_scatter_gather``, the
+       translated source layout for ``all_to_all`` and
+       ``aligned_relayout``), so only the strategy's reduced byte volume
+       crosses meshes, and, where the move is 1:1, nothing of it goes
+       through the host;
+    2. **relayout leg** — a cached identity ``jax.jit`` with
+       ``out_shardings``: XLA emits the all-gather / all-to-all / local
+       slice over one mesh's own links.
 
-    Both legs are pure data movement — no arithmetic — so every strategy
-    here is bit-exact against ``direct_p2p``.
+    The relayout runs on the destination mesh after the wire leg, or,
+    with ``relayout_first``, on the source mesh before it (the landing
+    is then the destination's layout written on the source mesh).  Both
+    legs are pure data movement — no arithmetic — so every strategy here
+    is bit-exact against ``direct_p2p``.
+
+    A per-call guard (``is_equivalent_to``, as in
+    :class:`DirectTransfer`) confirms the runtime array has the sharding
+    the plan assumed; a divergent one takes plain ``jax.device_put`` and
+    is counted (``alpa_reshard_runtime_fallback_total``), so a landing
+    chosen for another layout can never assemble wrong values.
+
+    ``on_driver``: the relayout is a program over a whole mesh, so the
+    overlap replay calls this executor on the driver thread, which
+    launches every other program of that mesh, and not on its transfer
+    pool (programs with collectives must reach a mesh's chips in one
+    order).  Both legs only enqueue.
     """
 
     __slots__ = ("strategy", "dst_sharding", "src_sharding",
-                 "inter_sharding", "ndim", "nbytes", "fast", "_relayout")
+                 "inter_sharding", "relayout_first", "ndim", "nbytes",
+                 "fast", "_relayout")
+
+    on_driver = True
 
     def __init__(self, aval, src_sharding, dst_sharding, strategy,
-                 inter_sharding):
+                 inter_sharding, relayout_first=False):
         self.strategy = strategy
         self.dst_sharding = dst_sharding
         self.src_sharding = src_sharding
         self.inter_sharding = inter_sharding
+        self.relayout_first = relayout_first
         self.ndim = len(getattr(aval, "shape", ()))
         self.fast = False   # never the batched-copy fast path
         shape = tuple(getattr(aval, "shape", ()))
@@ -1092,19 +1159,27 @@ class CollectiveTransfer:
 
     def __call__(self, val):
         if _ttrace.enabled():
-            with _ttrace.get_recorder().span(
-                    "reshard.edge", "resharding",
-                    {"bytes": self.nbytes, "strategy": self.strategy}):
-                return self._transfer(val)
+            args = {"bytes": self.nbytes, "strategy": self.strategy}
+            with _ttrace.get_recorder().span("reshard.edge", "resharding",
+                                             args):
+                return self._transfer(val, args)
         return self._transfer(val)
 
-    def _transfer(self, val):
+    def _transfer(self, val, span_args=None):
         import jax
-        staged = jax.device_put(val, self.inter_sharding)
+        if not val.sharding.is_equivalent_to(self.src_sharding, self.ndim):
+            _RUNTIME_FALLBACKS.inc()
+            if span_args is not None:
+                span_args["fallback"] = True
+            return jax.device_put(val, self.dst_sharding)
         if self._relayout is None:
-            self._relayout = jax.jit(lambda x: x,
-                                     out_shardings=self.dst_sharding)
-        return self._relayout(staged)
+            self._relayout = jax.jit(
+                lambda x: x,
+                out_shardings=(self.inter_sharding if self.relayout_first
+                               else self.dst_sharding))
+        if self.relayout_first:
+            return jax.device_put(self._relayout(val), self.dst_sharding)
+        return self._relayout(jax.device_put(val, self.inter_sharding))
 
 
 def make_transfer(aval, src_sharding, dst_sharding, cross=False,
@@ -1114,8 +1189,10 @@ def make_transfer(aval, src_sharding, dst_sharding, cross=False,
 
     Same-mesh relayouts always stay direct.  Cross-mesh edges take the
     plan's strategy decision when a :class:`ReshardingTaskSpec` is given
-    (so the emitter replays exactly what the planner chose and cached),
-    else resolve it here.  The quantized codec
+    and that strategy is one ``choose_strategy`` could pick for THESE
+    two shardings (so the emitter replays what the planner chose and
+    cached, unless the planner saw another source layout than the
+    emitter tracks), else resolve it here.  The quantized codec
     (``global_config.reshard_quantize``) takes precedence for eligible
     activation edges but is NEVER applied when ``weight`` is True —
     microbatch-invariant values (parameters, optimizer state) must cross
@@ -1135,9 +1212,10 @@ def make_transfer(aval, src_sharding, dst_sharding, cross=False,
                 return qt
         opts = collective_options(shape, itemsize, src_sharding,
                                   dst_sharding)
-        if plan is not None and getattr(plan, "strategy", None) in opts:
-            strat = plan.strategy
-        else:
+        strat = getattr(plan, "strategy", None)
+        forced = getattr(global_config, "reshard_strategy", "auto")
+        if strat not in opts or (forced == "auto" and
+                                 not opts[strat]["chip_to_chip"]):
             strat, _costs, _cached = resolve_strategy(
                 shape, itemsize, src_sharding, dst_sharding)
             if strat not in opts:
@@ -1145,7 +1223,8 @@ def make_transfer(aval, src_sharding, dst_sharding, cross=False,
         if strat == "direct_p2p":
             return DirectTransfer(aval, src_sharding, dst_sharding)
         return CollectiveTransfer(aval, src_sharding, dst_sharding,
-                                  strat, opts[strat]["landing"])
+                                  strat, opts[strat]["landing"],
+                                  opts[strat]["relayout_first"])
     except Exception:  # pylint: disable=broad-except
         logger.warning("make_transfer: collective lowering failed; "
                        "using DirectTransfer", exc_info=True)

@@ -23,12 +23,14 @@ Value identity: (var, instance) where instance = microbatch index for
 per-microbatch values and -1 for microbatch-invariant ones (params, grad
 accumulators, apply-grad results).
 """
+import collections
 import dataclasses
 import enum
 import heapq
 import logging
 import threading
 import time
+from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from jax.extend.core import Var
@@ -38,6 +40,7 @@ from alpa_tpu.global_env import global_config
 from alpa_tpu.telemetry import flight as _flight
 from alpa_tpu.telemetry import metrics as _tmetrics
 from alpa_tpu.telemetry import trace as _ttrace
+from alpa_tpu.util import aval_bytes
 
 logger = logging.getLogger(__name__)
 
@@ -786,6 +789,9 @@ class RegisterFileProgram:
     run_stats: Dict[str, float] = dataclasses.field(
         default_factory=lambda: {"transfer_busy_s": 0.0,
                                  "wait_blocked_s": 0.0})
+    # per mesh, one output of each RUN dispatched and not yet known to
+    # have finished (``_settle_run_ahead``); the RUN ops hold the queues
+    run_ahead: Dict[int, Any] = dataclasses.field(default_factory=dict)
     # telemetry (ISSUE 5): per-op (span name, category, track) built at
     # lowering time; only consulted when tracing is on — the hot replay
     # checks the enabled flag ONCE per step, not per op.
@@ -813,6 +819,8 @@ class RegisterFileProgram:
         rs = self.run_stats
         rs["transfer_busy_s"] = 0.0
         rs["wait_blocked_s"] = 0.0
+        for queue in self.run_ahead.values():
+            queue.clear()       # the last step's: its state may be gone
         if self.hooks is not None:
             sig = self._active_hook_signature()
             if sig is not None:
@@ -908,30 +916,64 @@ class RegisterFileProgram:
         return hashlib.sha256(self.text.encode()).hexdigest()
 
 
-def _make_run_op(compiled, in_slots, out_slots, fixups):
+# RUNs a mesh may have dispatched and not finished when the driver
+# dispatches its next one.  Nothing else holds the driver back once no
+# cross-mesh edge goes through the host: a program's outputs are
+# allocated when it is dispatched, so a driver that runs a whole step
+# ahead of the chips holds every micro-batch's buffers at once (the
+# four-chip cell's second mesh peaked at 15.3 GB for 8.3, and the driver
+# then stood in the allocator with the chips idle; PERF.md §6, PR 46).
+# Two keeps one program queued behind the one that runs, which is what
+# hides the dispatch.
+_RUN_AHEAD = 2
+
+
+def _settle_run_ahead(queue):
+    """Blocks until the oldest of a mesh's dispatched RUNs has finished,
+    once ``_RUN_AHEAD`` of them are out.  ``queue`` holds one output of
+    each (its smallest); one that a later RUN was given to donate is
+    gone, and so is the need to wait for it."""
+    if len(queue) >= _RUN_AHEAD:
+        token = queue.popleft()
+        if not token.is_deleted():
+            token.block_until_ready()
+
+
+def _make_run_op(compiled, in_slots, out_slots, fixups, ahead, token):
     """RUN as a closure: gather args by slot index, call the compiled
     fast path, scatter outputs.  ``fixups`` carries the (rare) arg
     positions whose statically-tracked layout differs from the stage's
     expected sharding — the register-file analog of the interpreter's
-    per-arg safety net, resolved at lowering instead of per call."""
+    per-arg safety net, resolved at lowering instead of per call.
+    ``ahead`` is the mesh's queue of dispatched RUNs
+    (:func:`_settle_run_ahead`) and ``token`` the position of the output
+    that stands for this one in it (None: the program has no output)."""
     if fixups:
 
-        def op(regs, _c=compiled, _i=in_slots, _o=out_slots, _f=fixups):
+        def op(regs, _c=compiled, _i=in_slots, _o=out_slots, _f=fixups,
+               _q=ahead, _t=token):
             import jax
             args = [regs[s] for s in _i]
             for pos, sh, ndim in _f:
                 a = args[pos]
                 if not a.sharding.is_equivalent_to(sh, ndim):
                     args[pos] = jax.device_put(a, sh)
+            _settle_run_ahead(_q)
             outs = _c(*args)
             for s, o in zip(_o, outs):
                 regs[s] = o
+            if _t is not None:
+                _q.append(outs[_t])
     else:
 
-        def op(regs, _c=compiled, _i=in_slots, _o=out_slots):
+        def op(regs, _c=compiled, _i=in_slots, _o=out_slots, _q=ahead,
+               _t=token):
+            _settle_run_ahead(_q)
             outs = _c(*[regs[s] for s in _i])
             for s, o in zip(_o, outs):
                 regs[s] = o
+            if _t is not None:
+                _q.append(outs[_t])
 
     return op
 
@@ -1026,6 +1068,13 @@ def _make_launch_op(transfer, src_slot, dst_slot, label="transfer"):
     # regs[src] is captured on the driver thread at launch time, so a
     # later donation/FREE of the src slot (which the schedule orders
     # after this launch's wait anyway) can never race the worker.
+    # A transfer that launches a program over a whole mesh
+    # (``on_driver``: CollectiveTransfer's relayout) runs here, on the
+    # thread that launches the mesh's stage programs, so that programs
+    # with collectives reach a mesh's chips in one order; it only
+    # enqueues, and its wait finds the future resolved.
+    on_driver = getattr(transfer, "on_driver", False)
+
     def op(regs, _t=transfer, _s=src_slot, _d=dst_slot, _l=label):
         v = regs[_s]
         traced = _ttrace.enabled()
@@ -1042,7 +1091,16 @@ def _make_launch_op(transfer, src_slot, dst_slot, label="transfer"):
 
         if traced:
             _inflight_delta(1)
-        regs[_d] = _PendingTransfer(_transfer_pool().submit(work))
+        if on_driver:
+            fut = Future()
+            try:
+                fut.set_result(work())
+            except Exception as e:  # pylint: disable=broad-except
+                fut.set_exception(e)    # raised by the wait, as a
+                #                         worker's would be
+        else:
+            fut = _transfer_pool().submit(work)
+        regs[_d] = _PendingTransfer(fut)
 
     return op
 
@@ -1239,6 +1297,9 @@ def lower_to_register_file(
             s = slot_of[key] = len(slot_of)
         return s
 
+    # per mesh, the RUNs dispatched and not yet known to have finished
+    run_ahead: Dict[int, Any] = collections.defaultdict(collections.deque)
+
     cur_sharding: Dict[int, Any] = {}
     for key, sh in preplaced_shardings.items():
         cur_sharding[slot(key)] = sh
@@ -1328,10 +1389,14 @@ def lower_to_register_file(
             donated = set(getattr(ex, "donate_idx", ()) or ())
             kills = tuple(sorted({in_slots[p] for p in donated
                                   if p < len(in_slots)}))
+            sizes = [aval_bytes(v.aval)
+                     for v in getattr(ex, "outvars", ())]
             recs.append({
                 "kind": "RUN",
-                "op": _make_run_op(ex.compiled, tuple(in_slots),
-                                   tuple(out_slots), tuple(fixups)),
+                "op": _make_run_op(
+                    ex.compiled, tuple(in_slots), tuple(out_slots),
+                    tuple(fixups), run_ahead[inst.dst_mesh],
+                    sizes.index(min(sizes)) if sizes else None),
                 "reads": tuple(in_slots),
                 "writes": tuple(out_slots),
                 "kills": kills,
@@ -1636,6 +1701,7 @@ def lower_to_register_file(
                                overlap_window=(window if mode == "overlap"
                                                else 0),
                                run_stats=run_stats,
+                               run_ahead=run_ahead,
                                op_meta=meta,
                                hooks=hooks)
     # static plan verification (ISSUE 8): typed abstract interpretation

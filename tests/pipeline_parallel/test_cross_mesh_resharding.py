@@ -372,9 +372,12 @@ class TestLinkAccounting:
         assert direct["total_bytes"] == 4 * self.S == 1024
         assert direct["max_link_bytes"] == self.S == 256
         assert direct["max_link_messages"] == 4
-        # default knobs (no emulated latency): ties resolve to direct,
-        # keeping the default path byte-identical to pre-ISSUE-7
-        assert spec.strategy == "direct_p2p"
+        # default knobs: direct would go through the host on this edge
+        # (sharded -> replicated).  The meshes' axes have other names
+        # ("x", "y"), so no aligned_relayout is offered; the scattered
+        # landing is the one candidate whose wire leg is 1:1
+        assert "aligned_relayout" not in stats
+        assert spec.strategy == "slice_all_gather"
         assert set(spec.strategy_costs) == set(stats)
 
     def test_planner_counters_accumulate(self):

@@ -5,10 +5,12 @@ Oracle 1: every lossless strategy is bit-exact against the
 collective) and end-to-end on the unified graph executor (forced
 strategy over the 4-stage MLP train step, grouped + donated, registers
 and overlap modes).  Oracle 2: the codec's documented error contract,
-property-style over seeded shapes.  Oracle 3: strategy selection — the
-default is direct on every edge, a forced strategy is taken exactly
-where it is eligible and degrades to direct elsewhere, and decisions
-replay from the compile cache."""
+property-style over seeded shapes.  Oracle 3: strategy selection — ``auto``
+keeps an edge direct where the direct move is chip to chip and takes
+``aligned_relayout`` elsewhere (ISSUE 46: no edge hands an array to the
+host), a forced strategy is taken exactly where it is eligible and
+degrades to direct elsewhere, and decisions replay from the compile
+cache."""
 import numpy as np
 import pytest
 
@@ -61,10 +63,19 @@ CASES = {
 
 # the strategies each edge is eligible for besides direct_p2p
 ELIGIBLE = {
-    "rowshard->replicated": {"slice_all_gather"},
-    "rowshard->colshard": {"all_to_all"},
-    "replicated->rowshard": set(),
+    "rowshard->replicated": {"slice_all_gather", "aligned_relayout"},
+    "rowshard->colshard": {"all_to_all", "aligned_relayout"},
+    "replicated->rowshard": {"aligned_relayout"},
     "rowshard->rowshard": set(),
+}
+
+# what ``auto`` takes: direct where the direct move is 1:1, else the
+# landing whose move is
+AUTO = {
+    "rowshard->replicated": "aligned_relayout",
+    "rowshard->colshard": "aligned_relayout",
+    "replicated->rowshard": "aligned_relayout",
+    "rowshard->rowshard": "direct_p2p",
 }
 
 
@@ -77,12 +88,15 @@ def _shardings(case):
 class TestStrategySelection:
 
     @pytest.mark.parametrize("case", list(CASES))
-    def test_default_knobs_always_direct(self, case):
-        # the cross-mesh leg has no price, so no candidate is cheaper
-        # than direct, which wins the tie-break
+    def test_default_knobs_choose_by_the_shardings(self, case):
+        # only a candidate whose wire leg moves chip to chip; among
+        # those none has a price, and the first offered wins
         src, dst = _shardings(case)
-        strat, _, _ = cmr.choose_strategy((8, 8), 4, src, dst)
-        assert strat == "direct_p2p"
+        strat, _, opts = cmr.choose_strategy((8, 8), 4, src, dst)
+        assert strat == AUTO[case]
+        assert opts[strat]["chip_to_chip"]
+        assert opts["direct_p2p"]["chip_to_chip"] == \
+            (AUTO[case] == "direct_p2p")
 
     def test_link_stats_pinned_4p4(self):
         # rowshard -> replicated, (8,8) f32: direct sends each 64 B
@@ -127,7 +141,7 @@ class TestStrategySelection:
         global_config.reshard_strategy = "slice_all_gather"
         s2, _, hit2 = cmr.resolve_strategy((8, 8), 4, src, dst)
         assert not hit2
-        assert (s1, s2) == ("direct_p2p", "slice_all_gather")
+        assert (s1, s2) == ("aligned_relayout", "slice_all_gather")
 
     def test_plan_resharding_carries_strategy(self):
         global_config.reshard_strategy = "slice_all_gather"
@@ -156,7 +170,8 @@ class TestExecutorBitExactness:
         _, _, opts = cmr.choose_strategy(shape, 4, src, dst)
         assert strategy in opts, f"{strategy} ineligible for this edge"
         t = cmr.CollectiveTransfer(_Aval(shape), src, dst, strategy,
-                                   opts[strategy]["landing"])
+                                   opts[strategy]["landing"],
+                                   opts[strategy]["relayout_first"])
         out = t(val)
         assert out.sharding.is_equivalent_to(dst, 2)
         np.testing.assert_array_equal(np.asarray(out), x)
@@ -206,6 +221,138 @@ class TestExecutorBitExactness:
         # whole array is one block: error ≤ amax / 254
         bound = np.abs(x).max() / 250 + 1e-7
         assert np.abs(np.asarray(out) - x).max() <= bound
+
+
+# ---------------------------------------------------------------------
+# no edge hands an array to the host (ISSUE 46)
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def host_fetches(monkeypatch):
+    """Counts reads of ``ArrayImpl._value``: what ``jax.device_put``
+    does to an array it cannot move shard by shard."""
+    from jax._src import array as jarray
+    fetched = []
+    inner = jarray.ArrayImpl._value.fget
+
+    def counted(self):
+        fetched.append(self.shape)
+        return inner(self)
+
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(counted))
+    return fetched
+
+
+def _stage_meshes():
+    """Two 1x2 submeshes with the axes a pipeshard plan gives them."""
+    devs = jax.devices()
+    return (Mesh(np.array(devs[:2]).reshape(1, 2), ("mesh0", "mesh1")),
+            Mesh(np.array(devs[2:4]).reshape(1, 2), ("mesh0", "mesh1")))
+
+
+# (source spec, destination spec) -> (strategy, relayout on the source
+# mesh first, host fetches of the direct device_put)
+EDGES = {
+    "sharded->replicated": (P(None, "mesh1"), P(),
+                            "aligned_relayout", False, 1),
+    "dim0->dim1": (P("mesh1"), P(None, "mesh1"),
+                   "aligned_relayout", False, 1),
+    "replicated->sharded": (P(), P(None, "mesh1"),
+                            "aligned_relayout", True, 1),
+    "replicated->replicated": (P(), P(), "direct_p2p", None, 0),
+    "1:1": (P(None, "mesh1"), P(None, "mesh1"), "direct_p2p", None, 0),
+}
+
+
+@pytest.mark.parametrize("edge", list(EDGES))
+def test_auto_crosses_chip_to_chip(edge, host_fetches):
+    src_spec, dst_spec, strategy, relayout_first, direct_fetches = \
+        EDGES[edge]
+    mesh_a, mesh_b = _stage_meshes()
+    src = NamedSharding(mesh_a, src_spec)
+    dst = NamedSharding(mesh_b, dst_spec)
+    shape = (4, 16, 32)
+    x = (np.arange(np.prod(shape)).reshape(shape) * 0.37 - 11.0) \
+        .astype(jnp.bfloat16)
+    val = jax.block_until_ready(jax.device_put(jnp.asarray(x), src))
+
+    t = cmr.make_transfer(_Aval(shape, jnp.bfloat16), src, dst, cross=True)
+    if strategy == "direct_p2p":
+        assert isinstance(t, cmr.DirectTransfer)
+    else:
+        assert isinstance(t, cmr.CollectiveTransfer)
+        assert t.strategy == strategy
+        assert t.relayout_first == relayout_first
+        # the wire leg is the 1:1 move, on whichever side the landing is
+        wire = (t.inter_sharding, dst) if relayout_first else \
+            (src, t.inter_sharding)
+        assert cmr.shard_structures_match(shape, *wire)
+
+    del host_fetches[:]
+    out = jax.block_until_ready(t(val))
+    assert host_fetches == []               # the edge stays on the chips
+    # ... where jax's own move of the same edge does not (this says when
+    # jax stops doing so, and the rule can go)
+    want = jax.block_until_ready(jax.device_put(val, dst))
+    assert len(host_fetches) == direct_fetches
+    assert out.sharding.is_equivalent_to(dst, len(shape))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+
+
+def test_forced_strategy_honoured_and_unaligned_meshes_stay_direct():
+    mesh_a, mesh_b = _stage_meshes()
+    src = NamedSharding(mesh_a, P(None, "mesh1"))
+    dst = NamedSharding(mesh_b, P())
+    aval = _Aval((4, 16, 32), jnp.bfloat16)
+    # the parent's path, for whoever wants the comparison
+    global_config.reshard_strategy = "direct_p2p"
+    assert isinstance(cmr.make_transfer(aval, src, dst, cross=True),
+                      cmr.DirectTransfer)
+    # a forced landing that goes through the host is still taken
+    global_config.reshard_strategy = "slice_all_gather"
+    t = cmr.make_transfer(aval, src, dst, cross=True)
+    assert t.strategy == "slice_all_gather"
+    assert t.inter_sharding.spec == P(None, None, "mesh1")
+    # ... also against a plan that chose otherwise under another knob
+    plan = cmr.plan_resharding(aval.shape, 2, src, dst)
+    assert plan.strategy == "slice_all_gather"
+    global_config.reshard_strategy = "auto"
+    assert cmr.make_transfer(aval, src, dst, cross=True,
+                             plan=plan).strategy == "aligned_relayout"
+    # meshes whose axes do not line up (names, and 4 chips against 2):
+    # no landing is a 1:1 move, and the edge stays direct
+    devs = jax.devices()
+    four = Mesh(np.array(devs[4:8]), ("x",))
+    src4 = NamedSharding(four, P(None, "x"))
+    strat, _, opts = cmr.choose_strategy(aval.shape, 2, src4, dst)
+    assert "aligned_relayout" not in opts
+    assert not any(o["chip_to_chip"] for o in opts.values())
+    assert strat == "direct_p2p"
+    assert isinstance(cmr.make_transfer(aval, src4, dst, cross=True),
+                      cmr.DirectTransfer)
+
+
+def test_two_leg_executor_guards_the_runtime_sharding(host_fetches):
+    """An array that arrives in another layout than the plan assumed
+    takes plain device_put (right, through the host) and is counted."""
+    from alpa_tpu.telemetry import metrics as _tmetrics
+    mesh_a, mesh_b = _stage_meshes()
+    src = NamedSharding(mesh_a, P(None, "mesh1"))
+    dst = NamedSharding(mesh_b, P())
+    shape = (4, 16, 32)
+    t = cmr.make_transfer(_Aval(shape), src, dst, cross=True)
+    x = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+    fam = _tmetrics.get_registry().get("alpa_reshard_runtime_fallback_total")
+    before = fam.value
+    planned = t(jax.device_put(jnp.asarray(x), src))
+    assert fam.value == before and host_fetches == []
+    other = t(jax.device_put(jnp.asarray(x),
+                             NamedSharding(mesh_a, P("mesh1"))))
+    assert fam.value == before + 1 and len(host_fetches) == 1
+    for out in (planned, other):
+        assert out.sharding.is_equivalent_to(dst, 3)
+        np.testing.assert_array_equal(np.asarray(out), x)
 
 
 # ---------------------------------------------------------------------
@@ -341,7 +488,8 @@ class TestCodecContract:
             x = (np.arange(64).reshape(8, 8) * 0.123).astype(dtype)
             t = cmr.make_transfer(_Aval((8, 8), dtype), src, dst,
                                   cross=True)
-            assert isinstance(t, cmr.DirectTransfer)  # codec off
+            assert isinstance(t, cmr.CollectiveTransfer)  # codec off
+            assert t.strategy == "aligned_relayout"
             out = t(jax.device_put(jnp.asarray(x), src))
             np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 

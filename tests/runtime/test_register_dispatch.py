@@ -191,3 +191,50 @@ def test_replay_without_instrumentation_calls_no_hook(monkeypatch):
     assert step.get_last_executable().last_dispatch_stats["hooks"] == ()
     assert ttrace.get_recorder().spans() == []
     assert tflight.get_recorder().snapshot() == flight_before
+
+
+class _Token:
+    """Stands for one output of a dispatched RUN."""
+
+    def __init__(self, log, name, deleted=False):
+        self.log, self.name, self.deleted = log, name, deleted
+
+    def is_deleted(self):
+        return self.deleted
+
+    def block_until_ready(self):
+        self.log.append(self.name)
+
+
+def test_run_ahead_settles_the_oldest_and_skips_a_donated_one():
+    """A mesh's queue of dispatched RUNs: nothing waits until
+    ``_RUN_AHEAD`` are out, then the oldest is waited for, unless a later
+    RUN was given it to donate."""
+    import collections
+    from alpa_tpu.pipeline_parallel import runtime_emitter as re_
+    waited, queue = [], collections.deque()
+    for i in range(re_._RUN_AHEAD):
+        re_._settle_run_ahead(queue)
+        queue.append(_Token(waited, f"run{i}", deleted=(i == 1)))
+    assert waited == []
+    re_._settle_run_ahead(queue)
+    assert waited == ["run0"] and len(queue) == re_._RUN_AHEAD - 1
+    queue.append(_Token(waited, "run2"))
+    re_._settle_run_ahead(queue)            # run1 was donated: no wait
+    assert waited == ["run0"] and [t.name for t in queue] == ["run2"]
+
+
+@pytest.mark.parametrize("mode", ["registers", "overlap"])
+def test_a_step_leaves_at_most_run_ahead_runs_of_a_mesh_unsettled(mode):
+    """The lowered program bounds how far the driver dispatches ahead of
+    a mesh, in both lowered modes: each RUN op settles its mesh's queue
+    before it dispatches, and stands in it by its smallest output."""
+    from alpa_tpu.pipeline_parallel import runtime_emitter as re_
+    alpa_tpu.init("local")
+    _, _, ex = _run_steps(mode, n_steps=2)
+    prog = ex._register_programs[mode]
+    assert sorted(prog.run_ahead) == list(range(4))      # four stages
+    for queue in prog.run_ahead.values():
+        assert 1 <= len(queue) <= re_._RUN_AHEAD
+        for token in queue:
+            assert isinstance(token, jax.Array)
