@@ -105,6 +105,8 @@ class GPTConfig:
     # (position q sees k where q - k < sliding_window; its cache is a ring
     # of sliding_window positions, written at position % sliding_window)
     # | "latent" (below: its cache holds seq_len positions of a latent)
+    # | "latent_sliding" (below: latent attention under the window, with
+    # the widths of ``sliding_latent``; its cache is a ring of latents)
     # | "conv" (no attention: a gated short convolution, ``ShortConv``; its
     # state is the last ``conv_taps - 1`` positions of a product, whatever
     # the row's length)
@@ -113,8 +115,10 @@ class GPTConfig:
     # False: rotary positions turn the q and k of "sliding" layers only,
     # "full" layers see no positions at all
     rope_on_full_attention: bool = True
-    # sigmoid(gate(h)) on the heads' output, before the output projection
-    attn_gate: bool = False
+    # sigmoid(gate(h)) on the heads' output, before the output projection:
+    # True, a gate a channel of every head (``SelfAttention``); "head", one
+    # gate a head (``LatentAttention``)
+    attn_gate: Any = False
     # four norms a block: one more on what the attention and the MLP
     # return, before it joins the residual stream
     post_norms: bool = False
@@ -196,6 +200,22 @@ class GPTConfig:
     # (``model_type`` lfm2_moe).  The taps of a "conv" layer's depthwise
     # causal convolution (the file's ``conv_L_cache``); 0: no such layer.
     conv_taps: int = 0
+    # --- latent attention over a learned selection of positions, beside
+    # windowed latent attention with widths of its own (``model_type``
+    # dots3_note).  Every default is the block of today.  A "latent"
+    # layer with ``index_topk`` > 0 attends, a query, over the
+    # ``index_topk`` positions at or before it that an indexer scores
+    # highest (all of them while there are no more): ``index_n_heads``
+    # heads of ``index_head_dim`` channels (``LatentAttention``); its cache
+    # holds an index key a position beside the latent.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    # the widths of the ``attention`` kind "latent_sliding" (a latent
+    # layer under ``sliding_window``, its cache a ring of the window's
+    # latents), which need not be the "latent" layers': heads, ranks, head
+    # sizes, rotary base and factors (``LatentWidths``); None: no such layer
+    sliding_latent: Optional["LatentWidths"] = None
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
@@ -219,6 +239,47 @@ class GPTConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    def latent_widths(self, kind: str) -> "LatentWidths":
+        """The widths of a latent layer of this kind: the configuration's
+        own for "latent", ``sliding_latent`` for "latent_sliding"."""
+        if kind == LATENT_SLIDING:
+            if self.sliding_latent is None:
+                raise ValueError("a \"latent_sliding\" layer needs "
+                                 "GPTConfig.sliding_latent")
+            return self.sliding_latent
+        if kind != "latent":
+            raise ValueError(f"no latent attention of the kind {kind!r}")
+        return LatentWidths(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, self.attn_scale, self.q_lora_scale,
+            self.kv_lora_scale)
+
+    def selects(self, kind: str) -> bool:
+        """Whether a layer of this attention kind reads a selection of
+        its positions (``index_topk``)."""
+        return kind == "latent" and self.index_topk > 0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentWidths:
+    """What one kind of latent layer is sized by (``GPTConfig``'s fields of
+    the same names say what each is)."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    attn_scale: Optional[float] = None
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
+
+
+# the attention kind of a latent layer under the window
+LATENT_SLIDING = "latent_sliding"
 
 
 # the MLP kind of a block whose routed experts are held back for the next
@@ -309,6 +370,14 @@ _HF_KINDS = {
                           attention="latent", rope_interleaved=True,
                           fused_gate_up=True, router_bias=True,
                           activation="silu", tie_embeddings=False),
+    # dots3-note: wiring read as the families its keys name where
+    # config.json does not fix it (deepseek_v2's latent attention and
+    # interleaved rotary pairs, DeepSeek-V3's sigmoid router with a choice
+    # bias, DeepSeek-V3.2-Exp's indexer, LongCat-Flash's factors on the
+    # latents, gated attention's head-wise gate)
+    "dots3_note": dict(norm="rmsnorm", positions="rotary",
+                       rope_interleaved=True, fused_gate_up=True,
+                       router_score="sigmoid", router_bias=True),
 }
 
 
@@ -525,11 +594,81 @@ def _longcat_flash_fields(hf: dict) -> dict:
         if hf["mla_scale_kv_lora"] else 1.0)
 
 
+def _dots3_note_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` dots3_note says beyond the
+    keys all decoders share: which layers are full (latent attention over
+    the positions an indexer selects) and which sliding (latent attention
+    under the window, with the ``swa_`` widths), the indexer's sizes, the
+    head-wise gates, the leading dense layers, the routed and shared
+    experts and their sigmoid router with a choice bias.  Of a
+    ``layer_types`` longer than ``num_hidden_layers`` (a file cut in depth
+    that keeps the published list) the leading layers count.
+    ``apply_mla_qkv_lora_rescale``: both kinds' normed latents times
+    ``sqrt(hidden_size / rank)``."""
+    layers, hidden = hf["num_hidden_layers"], hf["hidden_size"]
+    # a file cut in depth keeps the published list: its leading layers
+    layer_types = hf["layer_types"][:layers]
+    if len(layer_types) != layers:
+        raise ValueError("layer_types must name every layer")
+    unknown = set(layer_types) - {"sliding_attention", "full_attention"}
+    if unknown:
+        raise ValueError(f"unknown layer_types {sorted(unknown)}")
+    if hf["topk_method"] != "noaux_tc" or hf["scoring_func"] != "sigmoid" \
+            or hf.get("n_group", 1) != 1:
+        raise ValueError("dots3_note: only sigmoid scores under noaux_tc "
+                         "in one group are supported")
+    gates = {hf["attention_gate_type"], hf["swa_attention_gate_type"]}
+    if gates != {"headwise"}:
+        raise ValueError("dots3_note: only head-wise attention gates are "
+                         f"supported, not {sorted(gates)}")
+    if hf["swa_num_key_value_heads"] != hf["swa_num_attention_heads"]:
+        raise ValueError("dots3_note: a latent layer has no grouped "
+                         "key/value heads")
+    rescale = hf["apply_mla_qkv_lora_rescale"]
+
+    def factor(rank):
+        return float(np.sqrt(hidden / rank)) if rescale and rank else 1.0
+
+    return dict(
+        _depth_and_widths(hf), **_act_eps_tie(hf),
+        mlp=tuple("experts" if i >= hf["first_k_dense_replace"] and
+                  i % hf["moe_layer_freq"] == 0 else "gated"
+                  for i in range(layers)),
+        attention=tuple(LATENT_SLIDING if t == "sliding_attention"
+                        else "latent" for t in layer_types),
+        sliding_window=hf["sliding_window_size"], attn_gate="head",
+        q_lora_rank=hf["q_lora_rank"] or 0,
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        q_lora_scale=factor(hf["q_lora_rank"] or 0),
+        kv_lora_scale=factor(hf["kv_lora_rank"]),
+        sliding_latent=LatentWidths(
+            num_heads=hf["swa_num_attention_heads"],
+            q_lora_rank=hf["swa_q_lora_rank"] or 0,
+            kv_lora_rank=hf["swa_kv_lora_rank"],
+            qk_nope_head_dim=hf["swa_qk_nope_head_dim"],
+            qk_rope_head_dim=hf["swa_qk_rope_head_dim"],
+            v_head_dim=hf["swa_v_head_dim"],
+            rope_theta=float(hf["swa_rope_theta"]),
+            q_lora_scale=factor(hf["swa_q_lora_rank"] or 0),
+            kv_lora_scale=factor(hf["swa_kv_lora_rank"])),
+        index_topk=hf["index_topk"], index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        num_shared_experts=hf["n_shared_experts"],
+        norm_topk_prob=hf["norm_topk_prob"],
+        route_scale=float(hf["routed_scaling_factor"]))
+
+
 # what each model type's file says beyond the keys all share
 _HF_FIELDS = {
     "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
     "deepseek_v2": _deepseek_v2_fields, "sdar_moe": _sdar_moe_fields,
     "lfm2_moe": _lfm2_moe_fields, "longcat_flash": _longcat_flash_fields,
+    "dots3_note": _dots3_note_fields,
 }
 
 
@@ -772,6 +911,11 @@ ATTENTION_SCOPE = "attention"
 # inside it, the scope of the step's keys and values written into the
 # cache (a capture reads both: telemetry/device_time.py)
 CACHE_WRITE_SCOPE = "cache_write"
+# inside it too, on a layer that selects its positions: the indexer (its
+# projections, the index scores, the choice) and the selected positions'
+# attention (the gather of their latents and the core over them)
+INDEXER_SCOPE = "indexer"
+SELECT_SCOPE = "latent_select"
 
 
 # the lanes of a TPU vector register: the extent the compiler tiles an
@@ -1170,7 +1314,8 @@ def _latent_attention_blocks(q_nope, q_pe, c, k_pe, w_kv_b, offset=None, *,
     return (acc / total).astype(q_nope.dtype)
 
 
-def latent_attention_absorbed(q_nope, q_pe, c, k_pe, w_kv_b, scale, offset):
+def latent_attention_absorbed(q_nope, q_pe, c, k_pe, w_kv_b, scale, offset,
+                              seen=None):
     """The same function with the expansion ABSORBED into the query and
     the output (``w_kv_b`` a head split into ``W_uk`` (r, dn) and ``W_uv``
     (r, dv)): ``q_lat = q_nope W_uk^T`` scores against the latents
@@ -1184,43 +1329,316 @@ def latent_attention_absorbed(q_nope, q_pe, c, k_pe, w_kv_b, scale, offset):
     (``ops/latent_attention.py`` ``absorbed``): a program lowered for a
     TPU runs the kernel, which reads of each row's cache the positions
     the row holds; ``_absorbed_core`` is its ``jax.numpy`` twin, over every
-    row's whole cache."""
+    row's whole cache.  ``seen`` ((1 or B, Sq, Sk) bool): the positions
+    each query sees where the causal rule over ``offset`` does not say it
+    (a ring of latents, ``update_latent_ring``): always the twin."""
     from alpa_tpu.ops import latent_attention as kernel
     dn = q_nope.shape[-1]
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kv_b[..., :dn])
     offset = jnp.asarray(offset, jnp.int32)
-    if kernel.absorbed_fits(q_lat, c) and offset.ndim == 1:
+    if seen is None and kernel.absorbed_fits(q_lat, c) and offset.ndim == 1:
         o_lat = jax.lax.platform_dependent(
             q_lat, q_pe, c, k_pe, offset,
             tpu=partial(kernel.absorbed, scale=scale),
             default=partial(_absorbed_core, scale=scale))
     else:
-        o_lat = _absorbed_core(q_lat, q_pe, c, k_pe, offset, scale=scale)
+        o_lat = _absorbed_core(q_lat, q_pe, c, k_pe, offset, scale=scale,
+                               seen=seen)
     return jnp.einsum("bqhr,rhd->bqhd", o_lat, w_kv_b[..., dn:])
 
 
-def _absorbed_core(q_lat, q_pe, c, k_pe, offset, *, scale):
+def _absorbed_core(q_lat, q_pe, c, k_pe, offset, *, scale, seen=None):
     """Scores of ``[q_lat | q_pe]`` against ``[c | k_pe]``, a float32
-    softmax over the positions at or before each query's, and the
-    probabilities' weighted latents (B, Sq, H, r)."""
+    softmax over the positions at or before each query's (or over those
+    ``seen`` (1 or B, Sq, Sk) says, a ring's), and the probabilities'
+    weighted latents (B, Sq, H, r)."""
     b, sq = q_lat.shape[:2]
     scores = scale * (_einsum_f32("bqhr,bkr->bhqk", q_lat, c) +
                       _einsum_f32("bqhd,bdk->bhqk", q_pe, k_pe))
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c.shape[1]), 2)
-    seen = k_pos <= _query_positions(offset, b, sq)[:, :, None]
+    if seen is None:
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c.shape[1]), 2)
+        seen = k_pos <= _query_positions(offset, b, sq)[:, :, None]
     probs = jax.nn.softmax(
         jnp.where(seen[:, None], scores, jnp.float32(-1e9)), axis=-1)
     return jnp.einsum("bhqk,bkr->bqhr", probs.astype(c.dtype), c)
 
 
+def update_latent_ring(kv_cache, c, k_pe, lengths=None):
+    """``update_ring_cache``'s rule on ``update_latent_cache``'s layout,
+    for a "latent_sliding" layer: ``kv_cache`` is ``(c_ring (B, W, r),
+    pe_ring (B, dr, W), index)``, position p lives in slot ``p % W``, and
+    ``index`` (a scalar, or (B,) a row) is the position of the first of the
+    ``s`` new ones ``c`` (B, s, r), ``k_pe`` (B, s, dr).  Returns ``(c_use,
+    pe_use, k_positions, new_cache)``: the latents (B, Sk, r) and shared
+    keys (B, dr, Sk) to attend over and the position each of them holds
+    ((1 or B, Sk); negative: nothing yet).  One new position is written
+    first and the ring attended over; several attend over the ring as it
+    was with the new ones behind it, and the ring then takes, a slot, the
+    LAST real new position that belongs in it (``lengths``: the rows'
+    whole lengths, which say what of a right-padded chunk is real)."""
+    c_ring, pe_ring, index = kv_cache
+    w, s = c_ring.shape[1], c.shape[1]
+    c = c.astype(c_ring.dtype)
+    k_pe = k_pe.astype(pe_ring.dtype).swapaxes(1, 2)             # (B,dr,s)
+    index = jnp.asarray(index, jnp.int32)
+    first = index[:, None] if index.ndim else index[None, None]  # (.,1)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+
+    def held(last):
+        """The position each slot holds when ``last`` (.,1) is the
+        newest position written (negative: none)."""
+        return last - (last - slots) % w
+
+    if s == 1:
+        with jax.named_scope(CACHE_WRITE_SCOPE):
+            if index.ndim:
+                c_new = _write_latent_rows(c_ring, c, index % w, 1)
+                pe_new = _write_latent_rows(pe_ring, k_pe, index % w, 2)
+            else:
+                c_new = jax.lax.dynamic_update_slice_in_dim(
+                    c_ring, c, index % w, axis=1)
+                pe_new = jax.lax.dynamic_update_slice_in_dim(
+                    pe_ring, k_pe, index % w, axis=2)
+        return c_new, pe_new, held(first), (c_new, pe_new, index + 1)
+
+    new_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+    k_positions = jnp.concatenate(
+        [jnp.broadcast_to(held(first - 1), (first.shape[0], w)), new_pos],
+        axis=1)
+    c_use = jnp.concatenate([c_ring, c], axis=1)
+    pe_use = jnp.concatenate([pe_ring, k_pe], axis=2)
+    real = jnp.full_like(first, s) if lengths is None else \
+        jnp.clip(lengths[:, None] - first, 0, s)
+    # the new position slot j is to hold; before the chunk: the slot keeps
+    # what it has
+    source = jnp.broadcast_to(held(first + real - 1) - first,
+                              (c.shape[0], w))
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        take, at = source >= 0, jnp.clip(source, 0, s - 1)
+        c_new = jnp.where(take[:, :, None], jnp.take_along_axis(
+            c, at[:, :, None], axis=1), c_ring)
+        pe_new = jnp.where(take[:, None, :], jnp.take_along_axis(
+            k_pe, at[:, None, :], axis=2), pe_ring)
+    return c_use, pe_use, k_positions, (c_new, pe_new, index + s)
+
+
+def latent_row_width(rank: int, rope_dim: int) -> int:
+    """The channels of one row of a selecting layer's cache: the latent
+    and the shared rotary key side by side, rounded up to whole lanes.  A
+    row that ends inside a lane makes the TPU compiler keep the cache with
+    its positions minor-most for the per-row writes and move it whole into
+    row order for the gather, two copies of the cache a layer a tick (512
+    + 64 channels: 604 MB each at 16 rows of 32,768 positions); rounded
+    up, the writes and the gather share one order
+    (``tests/serve/test_decode_in_place.py``).  The channels past the key
+    are never read."""
+    return -(-(rank + rope_dim) // LANES) * LANES
+
+
+def update_latent_index_cache(kv_cache, c, k_pe, k_index):
+    """``update_latent_cache`` for a "latent" layer that selects its
+    positions: ``kv_cache`` is ``(rows (B, S, latent_row_width), keys (B,
+    S, di), index)``.  A position's normed latent and rotated shared key
+    lie side by side in one row of ``rows`` (``[c | k_pe | unused]``), so
+    that a decode fetches a selected position with one gather of one row;
+    its index key is a row of ``keys``.  The ``s`` new positions ``c`` (B,
+    s, r), ``k_pe`` (B, s, dr), ``k_index`` (B, s, di) are written at
+    ``index`` (a row whose write does not fit stays as it was); returns
+    the entry with ``index + s``."""
+    rows, keys, index = kv_cache
+    index = jnp.asarray(index, jnp.int32)
+    spare = rows.shape[2] - c.shape[2] - k_pe.shape[2]
+    new = jnp.concatenate(
+        [c, k_pe, jnp.zeros(c.shape[:2] + (spare,), c.dtype)],
+        axis=-1).astype(rows.dtype)
+    k_index = k_index.astype(keys.dtype)
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        if index.ndim == 0:
+            rows = jax.lax.dynamic_update_slice_in_dim(rows, new, index,
+                                                       axis=1)
+            keys = jax.lax.dynamic_update_slice_in_dim(keys, k_index, index,
+                                                       axis=1)
+        else:
+            rows = _write_latent_rows(rows, new, index, 1)
+            keys = _write_latent_rows(keys, k_index, index, 1)
+    return rows, keys, index + new.shape[1]
+
+
+def index_scores(q_index, weights, keys, q_pos):
+    """The indexer's score of every position for every query (DeepSeek-
+    V3.2-Exp's ``Indexer``): ``I[t, s] = sum_j w[t, j] relu(q[t, j] .
+    k[s])`` for ``s <= t``, ``-inf`` after.  ``q_index`` (B, Sq, J, di),
+    ``weights`` (B, Sq, J) float32, ``keys`` (B, Sk, di), ``q_pos`` (1 or
+    B, Sq) the queries' positions; returns (B, Sq, Sk) float32.  The
+    products are reduced over the J heads a block of keys at a time: no
+    (Sq, J, Sk) scores exist.  Shapes the kernels of
+    ``ops/latent_attention.py`` take (``index_scores_fits``), in a
+    program lowered for a TPU: those kernels, which compute of every row
+    the key blocks its queries see and read no others."""
+    from alpa_tpu.ops import latent_attention as kernel
+    if not kernel.index_scores_fits(q_index, keys):
+        return _index_scores_blocks(q_index, weights, keys, q_pos)
+    q_pos = jnp.broadcast_to(q_pos, q_index.shape[:2])
+    return jax.lax.platform_dependent(
+        q_index, weights, keys, q_pos, tpu=kernel.index_scores,
+        default=_index_scores_blocks)
+
+
+def _index_scores_blocks(q_index, weights, keys, q_pos):
+    """``index_scores`` in ``jax.numpy``, over every position the keys
+    hold, a block of keys at a time."""
+    b, sq = q_index.shape[:2]
+    sk = keys.shape[1]
+    block = 512 if sk % 512 == 0 else sk
+
+    def one_block(at):
+        k = jax.lax.dynamic_slice_in_dim(keys, at * block, block, axis=1)
+        products = _einsum_f32("bqjd,bkd->bqjk", q_index, k)
+        return (jax.nn.relu(products) * weights[..., None]).sum(2)
+
+    scores = jax.lax.map(one_block, jnp.arange(sk // block))
+    scores = scores.transpose(1, 2, 0, 3).reshape(b, sq, sk)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, sk), 2)
+    return jnp.where(k_pos <= q_pos[:, :, None], scores, -jnp.inf)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 that orders as the floats do."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    bits = jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ \
+        jnp.uint32(0x80000000)
+
+
+def selected_mask(scores, k: int):
+    """(.., Sk) bool: the ``k`` largest of ``scores`` (.., Sk) float32 a
+    row, ties to the lower position; ``-inf`` is never selected (a row
+    with fewer than ``k`` finite scores selects those).  No sort: the
+    k-th largest value a row is found bit by bit (32 counts of the scores
+    at or above a candidate), and everything at or above it is selected;
+    only where a tie straddles the k-th place is it broken, by a running
+    count of the tied."""
+    bits = _ordered_bits(scores)
+
+    def one_bit(i, kth):
+        candidate = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = (bits >= candidate[..., None]).sum(
+            -1, dtype=jnp.int32) >= k
+        return jnp.where(enough, candidate, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, one_bit, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    at_or_above = bits >= kth[..., None]
+
+    def break_ties(_):
+        above = bits > kth[..., None]
+        tied = at_or_above & ~above
+        room = k - above.sum(-1, dtype=jnp.int32)
+        return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                                <= room[..., None]))
+
+    exact = jax.lax.cond(
+        (at_or_above.sum(-1, dtype=jnp.int32) > k).any(), break_ties,
+        lambda _: at_or_above, None)
+    return exact & (scores > -jnp.inf)
+
+
+def selected_mask_upto(scores, k: int, upto):
+    """``selected_mask`` where only the first ``upto`` positions (a traced
+    scalar) can hold a finite score, as in a cache that the chunk's last
+    query ends at: the counts go over a leading part of the positions that
+    holds them all, the shortest of a few static lengths (powers of two up
+    to the whole), and where that part is no longer than ``k`` nothing is
+    counted at all: every finite score is selected."""
+    sk = scores.shape[-1]
+    lengths = [n for n in (k, 2 * k, 4 * k, 8 * k) if n < sk] + [sk]
+
+    def within(n):
+        def select(x):
+            part = x[..., :n]
+            chosen = part > -jnp.inf if n <= k else selected_mask(part, k)
+            return jnp.pad(chosen,
+                           [(0, 0)] * (x.ndim - 1) + [(0, sk - n)])
+        return select
+
+    return jax.lax.switch(
+        sum((upto > n).astype(jnp.int32) for n in lengths[:-1]),
+        [within(n) for n in lengths], scores)
+
+
+def selected_positions(scores, k: int):
+    """((.., k) int32, (..,) int32): the positions of the ``k`` largest of
+    ``scores`` (.., Sk) a row, ties to the lower position
+    (``jax.lax.top_k``), and how many of them are real: the first ``min(k,
+    finite scores)``, the largest first; the rest name positions whose
+    score is ``-inf``, which the caller masks."""
+    _, positions = jax.lax.top_k(scores, k)
+    real = jnp.minimum((scores > -jnp.inf).sum(-1, dtype=jnp.int32), k)
+    return positions.astype(jnp.int32), real
+
+
+def _latent_attention_masked(q_nope, q_pe, c, k_pe, w_kv_b, seen, *, scale):
+    """Latent attention in its published form over the keys ``seen`` ((1
+    or B, Sq, Sk) bool) says, all keys at once: ``c`` (B, Sk, r) expanded
+    by ``w_kv_b`` for all heads, ``k_pe`` (B, dr, Sk), a float32 softmax.
+    What a window or a selection makes of ``_latent_attention_blocks``;
+    (B, Sq, H, dv) in the queries' dtype."""
+    dn = q_nope.shape[-1]
+    kv = jnp.einsum("bkr,rhd->bkhd", c, w_kv_b)
+    scores = scale * (_einsum_f32("bqhd,bkhd->bhqk", q_nope, kv[..., :dn]) +
+                      _einsum_f32("bqhd,bdk->bhqk", q_pe, k_pe))
+    probs = jax.nn.softmax(
+        jnp.where(seen[:, None], scores, jnp.float32(-1e9)), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q_nope.dtype),
+                      kv[..., dn:])
+
+
+def latent_attention_selected(q_nope, q_pe, rows, w_kv_b, scale, offset,
+                              selected):
+    """A chunk's queries over the cached positions ``selected`` ((B, Sq,
+    Sk) bool, at or before each query) of a selecting layer's ``rows`` (B,
+    Sk, ``latent_row_width``): the expanded core restricted by a mask.
+    Over shapes the Pallas kernel takes, in a program lowered for a TPU:
+    ``ops/latent_attention.py`` ``expanded`` with the mask as one more operand
+    (a key block's scores never leave fast memory; it still walks every
+    key block up to the chunk's last query); ``_latent_attention_masked``
+    anywhere else."""
+    from alpa_tpu.ops import latent_attention as kernel
+    rank, dr = w_kv_b.shape[0], q_pe.shape[-1]
+    k_pe = rows[..., rank:rank + dr].swapaxes(1, 2)
+
+    def masked(q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected):
+        return _latent_attention_masked(
+            q_nope, q_pe, rows[..., :rank], k_pe, w_kv_b, selected,
+            scale=scale)
+
+    offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32),
+                              (q_nope.shape[0],))
+    if not kernel.fits(q_nope, rows, w_kv_b):
+        return masked(q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected)
+    return jax.lax.platform_dependent(
+        q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected,
+        tpu=lambda q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected:
+        kernel.expanded(q_nope, q_pe, rows, k_pe, w_kv_b, offset,
+                        scale=scale, selected=selected.astype(jnp.int8)),
+        default=masked)
+
+
+def head_gates(logits):
+    """The head-wise gates of ``LatentAttention``: a sigmoid a head."""
+    return jax.nn.sigmoid(logits)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2, 2024), the ``attention``
-    kind "latent" (``GPTConfig``): ``c_q = norm(x Wq_a)``, ``q = c_q
-    Wq_b`` a head ``[q_nope | q_pe]``; ``[c | k_pe] = x Wkv_a``, ``c =
-    norm(c)``; rotary positions on ``q_pe`` and on ``k_pe``, ONE key for
-    all heads; ``[k_nope | v] = c Wkv_b`` a head; scores ``(q_nope .
-    k_nope + q_pe . k_pe) * attn_scale``; the heads' values (``v_head_dim``
-    each) through ``out``.
+    kinds "latent" and "latent_sliding" (``GPTConfig``): ``c_q = norm(x
+    Wq_a)``, ``q = c_q Wq_b`` a head ``[q_nope | q_pe]``; ``[c | k_pe] = x
+    Wkv_a``, ``c = norm(c)``; rotary positions on ``q_pe`` and on ``k_pe``,
+    ONE key for all heads; ``[k_nope | v] = c Wkv_b`` a head; scores
+    ``(q_nope . k_nope + q_pe . k_pe) * attn_scale``; the heads' values
+    (``v_head_dim`` each) through ``out``.  ``attention`` is the layer's
+    kind (None: "latent"), and the widths are that kind's
+    (``GPTConfig.latent_widths``): one module, whatever the widths.
 
     ``q_lora_scale`` and ``kv_lora_scale`` (LongCat-Flash) multiply the
     normed ``c_q`` and the normed ``c``, in float32 before the cast to
@@ -1232,12 +1650,37 @@ class LatentAttention(nn.Module):
     (``update_latent_cache``).  Over it
     one new position a row (a decode) takes ``latent_attention_absorbed``,
     several (a prefill's chunk) ``latent_attention_expanded``: the choice
-    is the static number of new positions, nothing else."""
+    is the static number of new positions, nothing else.
+
+    ``attn_gate`` "head": the heads' outputs times ``sigmoid(x Wg)``, one
+    gate a head, before ``out``.
+
+    "latent_sliding": query p sees the keys with ``p - k <
+    sliding_window``; the cache is a ring of the window's latents
+    (``update_latent_ring``), a decode the absorbed core over the ring, a
+    chunk the expanded form over the ring with the chunk behind it.
+
+    A "latent" layer of a configuration with ``index_topk`` selects: an
+    indexer (``index_q`` from the scaled ``c_q``, ``index_k`` and a
+    LayerNorm from ``x``, rotary positions on the first
+    ``qk_rope_head_dim`` channels of both, rotate-half; ``index_w`` from
+    ``x``) scores every earlier position for every query (``index_scores``),
+    and the softmax and the values go over the ``index_topk`` best alone.
+    Its cache is ``(rows, index keys, index)``
+    (``update_latent_index_cache``).  A decode takes the selected
+    positions' rows out of the cache (one gather) and runs the absorbed
+    core over those alone; a chunk, and a call without a cache, the
+    expanded form under the selection's mask
+    (``latent_attention_selected``).  With ``return_selected`` the third
+    result is what a decode selected: ``(positions (B, index_topk), how
+    many of them are real (B,))``."""
     config: GPTConfig
+    attention: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
-                 position_ids=None, cache_lengths=None):
+                 position_ids=None, cache_lengths=None,
+                 return_selected=False):
         cfg = self.config
         if cfg.block_length:
             raise ValueError("latent attention has no block-causal mask "
@@ -1245,44 +1688,111 @@ class LatentAttention(nn.Module):
         if not cfg.causal or position_ids is None:
             raise ValueError("latent attention is causal over rotary "
                              "positions")
-        nh, rank = cfg.num_heads, cfg.kv_lora_rank
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
+        kind = self.attention or "latent"
+        w = cfg.latent_widths(kind)
+        window = cfg.sliding_window if kind == LATENT_SLIDING else 0
+        selects = cfg.selects(kind)
+        nh, rank = w.num_heads, w.kv_lora_rank
+        dn, dr, dv = w.qk_nope_head_dim, w.qk_rope_head_dim, w.v_head_dim
         dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias,
                         param_dtype=cfg.param_dtype)
         b, s = x.shape[0], x.shape[1]
         c_q = x
-        if cfg.q_lora_rank:
+        if w.q_lora_rank:
             c_q = make_norm(cfg, "q_a_norm")(
-                dense(cfg.q_lora_rank, name="q_a")(x))
-            if cfg.q_lora_scale != 1.0:
-                c_q = c_q * cfg.q_lora_scale
+                dense(w.q_lora_rank, name="q_a")(x))
+            if w.q_lora_scale != 1.0:
+                c_q = c_q * w.q_lora_scale
             c_q = c_q.astype(cfg.dtype)
         q = dense(nh * (dn + dr), name="q_b")(c_q).reshape(b, s, nh, dn + dr)
         kv_a = dense(rank + dr, name="kv_a")(x)
         c = make_norm(cfg, "kv_a_norm")(kv_a[..., :rank])
-        if cfg.kv_lora_scale != 1.0:
-            c = c * cfg.kv_lora_scale
+        if w.kv_lora_scale != 1.0:
+            c = c * w.kv_lora_scale
         c = c.astype(cfg.dtype)
         w_kv_b = self.param(
             "kv_b", nn.initializers.lecun_normal(), (rank, nh * (dn + dv)),
             cfg.param_dtype).astype(cfg.dtype).reshape(rank, nh, dn + dv)
         rotate = partial(apply_rotary, position_ids=position_ids,
-                         theta=cfg.rope_theta,
+                         theta=w.rope_theta,
                          interleaved=cfg.rope_interleaved,
                          yarn=cfg.rope_yarn)
         q_nope, q_pe = q[..., :dn], rotate(q[..., dn:])
         k_pe = rotate(kv_a[:, :, None, rank:])[:, :, 0]
-        scale = cfg.attn_scale or (dn + dr) ** -0.5
+        scale = w.attn_scale or (dn + dr) ** -0.5
+        q_pos = position_ids.astype(jnp.int32)
 
         new_cache = None
+        selected = None
         # the scope of the attention core (the cache's write, the
         # expansion or the absorption, scores, softmax, values; not the
-        # low-rank projections)
+        # low-rank projections; of a selecting layer the indexer whole)
         with jax.named_scope(ATTENTION_SCOPE):
-            if kv_cache is None:
+            if selects:
+                with jax.named_scope(INDEXER_SCOPE):
+                    q_index, k_index, w_index = self._indexer(
+                        x, c_q, position_ids, dense, dr, w.rope_theta)
+            if kv_cache is None and not (window or selects):
                 out = latent_attention_expanded(
                     q_nope, q_pe, c, k_pe.swapaxes(1, 2), w_kv_b, scale)
+            elif kv_cache is None:
+                # every key at once under a mask: what the window or the
+                # selection shows each query of its own sequence
+                k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, s), 2)
+                seen = k_pos <= q_pos[:, :, None]
+                if window:
+                    seen &= q_pos[:, :, None] - k_pos < window
+                else:
+                    with jax.named_scope(INDEXER_SCOPE):
+                        seen = selected_mask(index_scores(
+                            q_index, w_index, k_index, q_pos),
+                            cfg.index_topk)
+                out = _latent_attention_masked(
+                    q_nope, q_pe, c, k_pe.swapaxes(1, 2), w_kv_b, seen,
+                    scale=scale)
+            elif window:
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                c_use, pe_use, k_positions, new_cache = update_latent_ring(
+                    kv_cache, c, k_pe, cache_lengths)
+                k_held = k_positions[:, None, :]
+                seen = (k_held <= q_pos[:, :, None]) & (k_held >= 0) & \
+                    (q_pos[:, :, None] - k_held < window)
+                if s == 1:
+                    out = latent_attention_absorbed(
+                        q_nope, q_pe, c_use, pe_use, w_kv_b, scale, index,
+                        seen=seen)
+                else:
+                    out = _latent_attention_masked(
+                        q_nope, q_pe, c_use, pe_use, w_kv_b, seen,
+                        scale=scale)
+            elif selects:
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                new_cache = update_latent_index_cache(kv_cache, c, k_pe,
+                                                      k_index)
+                rows, keys = new_cache[:2]
+                with jax.named_scope(INDEXER_SCOPE):
+                    scores = index_scores(q_index, w_index, keys, q_pos)
+                    if s == 1:
+                        selected = selected_positions(scores[:, 0],
+                                                      cfg.index_topk)
+                    else:
+                        chosen = selected_mask_upto(
+                            scores, cfg.index_topk, jnp.max(index) + s)
+                with jax.named_scope(SELECT_SCOPE):
+                    if s == 1:
+                        # the selected positions' rows, the best first, and
+                        # the core over those alone: the real ones are the
+                        # first ``selected[1]``
+                        taken = jnp.take_along_axis(
+                            rows, selected[0][:, :, None], axis=1)
+                        out = latent_attention_absorbed(
+                            q_nope, q_pe, taken[..., :rank],
+                            taken[..., rank:rank + dr].swapaxes(1, 2),
+                            w_kv_b, scale, selected[1] - 1)
+                    else:
+                        out = latent_attention_selected(
+                            q_nope, q_pe, rows, w_kv_b, scale, index,
+                            chosen)
             else:
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 new_cache = update_latent_cache(kv_cache, c, k_pe)
@@ -1290,8 +1800,35 @@ class LatentAttention(nn.Module):
                     latent_attention_expanded
                 out = core(q_nope, q_pe, new_cache[0], new_cache[1], w_kv_b,
                            scale, index)
-        return dense(cfg.hidden_size, name="out")(
-            out.reshape(b, s, nh * dv)), new_cache
+        if cfg.attn_gate == "head":
+            out = out * head_gates(dense(nh, name="gate")(x))[..., None]
+        elif cfg.attn_gate:
+            raise ValueError("latent attention takes attn_gate \"head\" "
+                             f"or none, not {cfg.attn_gate!r}")
+        out = dense(cfg.hidden_size, name="out")(out.reshape(b, s, nh * dv))
+        if return_selected:
+            return out, new_cache, selected
+        return out, new_cache
+
+    def _indexer(self, x, c_q, position_ids, dense, dr, theta):
+        """The indexer's queries (B, S, J, di), keys (B, S, di) and head
+        weights (B, S, J) float32 of the layer's normed input ``x`` and
+        scaled query latent ``c_q``."""
+        cfg = self.config
+        heads, di = cfg.index_n_heads, cfg.index_head_dim
+        b, s = x.shape[:2]
+        rotate = partial(apply_rotary, position_ids=position_ids,
+                         theta=theta)
+        q = dense(heads * di, name="index_q")(c_q).reshape(b, s, heads, di)
+        k = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32,
+                         param_dtype=cfg.param_dtype, name="index_k_ln")(
+                             dense(di, name="index_k")(x)).astype(cfg.dtype)
+        q = jnp.concatenate([rotate(q[..., :dr]), q[..., dr:]], axis=-1)
+        k = jnp.concatenate([rotate(k[:, :, None, :dr])[:, :, 0],
+                             k[..., dr:]], axis=-1)
+        weights = dense(heads, name="index_w")(x).astype(jnp.float32) * \
+            (heads ** -0.5 * di ** -0.5)
+        return q, k, weights
 
 
 # the scope a gated short convolution is traced under, the whole mixer:
@@ -1524,7 +2061,11 @@ class TransformerBlock(nn.Module):
     ``shortcut``.  The caller hands it to the next block as ``shortcut=``,
     which adds it to the stream with its own MLP's output, so that the
     experts' sum skips that block's attention.  A block handed none and of
-    another kind adds and returns nothing more than it did."""
+    another kind adds and returns nothing more than it did.
+
+    ``return_selected`` (a latent layer that selects its positions, one
+    new position a row over a cache): what the layer selected comes third,
+    before the routing (``LatentAttention``)."""
     config: GPTConfig
     mlp: Optional[str] = None
     attention: Optional[str] = None
@@ -1532,21 +2073,23 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, kv_cache=None, deterministic=True,
                  position_ids=None, cache_lengths=None, padding_bias=None,
-                 shortcut=None):
+                 shortcut=None, return_selected=False):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
         ln1 = make_norm(cfg, "ln1")(x)
         mixer = self.attention or cfg.attention_kind(0)
-        if mixer == "latent":
-            attn = LatentAttention(cfg, name="attn")
+        if mixer in ("latent", LATENT_SLIDING):
+            attn = LatentAttention(cfg, attention=mixer, name="attn")
+            if return_selected:
+                attn = partial(attn, return_selected=True)
         elif mixer == "conv":
             attn = ShortConv(cfg, name="conv")
         else:
             attn = partial(
                 SelfAttention(cfg, attention=self.attention, name="attn"),
                 padding_bias=padding_bias)
-        attn_out, new_cache = attn(ln1, kv_cache, deterministic,
-                                   position_ids, cache_lengths)
+        attn_out, new_cache, *selection = attn(
+            ln1, kv_cache, deterministic, position_ids, cache_lengths)
         if cfg.post_norms:
             attn_out = make_norm(cfg, "ln1_post")(attn_out)
         x = x + attn_out.astype(x.dtype)
@@ -1570,7 +2113,7 @@ class TransformerBlock(nn.Module):
             # beside the MLP, on the same normed input, and held back
             held_back, what = DroplessExperts(cfg, name="moe")(ln2)
             routing = (what, held_back)
-        return (x, new_cache) + routing
+        return (x, new_cache) + tuple(selection) + routing
 
 
 class GPTModel(nn.Module):
@@ -1595,7 +2138,12 @@ class GPTModel(nn.Module):
         of a row's last real position (``update_conv_state``).
         ``return_routing`` (with ``kv_caches``, routed layers): a third
         result, what the routed layers' routers did: ``experts`` (expert
-        layers, tokens, k) int32, every token's experts.
+        layers, tokens, k) int32, every token's experts; and, where layers
+        select their positions and the call is one new position a row,
+        ``selected`` (selecting layers, B, index_topk) int32, the positions
+        each row's query attended over, the best first, and
+        ``selected_real`` (selecting layers, B): how many of them are real
+        (the first ones).
         """
         cfg = self.config
         b, s = input_ids.shape
@@ -1631,7 +2179,7 @@ class GPTModel(nn.Module):
                                  static_argnums=(2, 3),
                                  policy=policy)
         new_caches = [] if kv_caches is not None else None
-        routings = []
+        routings, selections = [], []
         # what a "gated+shortcut" block's experts made, on its way to the
         # next block (``TransformerBlock``)
         carried = {}
@@ -1642,9 +2190,16 @@ class GPTModel(nn.Module):
             block = block_cls(cfg, mlp=cfg.mlp_kind(i),
                               attention=cfg.attention_kind(i), name=f"h{i}")
             cache_i = kv_caches[i] if kv_caches is not None else None
+            # what a selecting layer's decode selected, with the routing
+            asked = return_routing and cache_i is not None and s == 1 and \
+                cfg.selects(cfg.attention_kind(i))
+            if asked:
+                carried["return_selected"] = True
             x, new_cache, *routing = block(
                 x, cache_i, deterministic, block_positions, cache_lengths,
                 **carried)
+            if asked:
+                selections.append(routing.pop(0))
             carried = {}
             if cfg.mlp_kind(i) == SHORTCUT_MLP:
                 carried = {"shortcut": routing.pop()}
@@ -1666,8 +2221,14 @@ class GPTModel(nn.Module):
                               name="lm_head")(x)
         if new_caches is not None:
             if return_routing:
-                return logits, new_caches, {
-                    "experts": jnp.stack([r["experts"] for r in routings])}
+                said = {"experts": jnp.stack(
+                    [r["experts"] for r in routings])} if routings else {}
+                if selections:
+                    said.update(
+                        selected=jnp.stack([p for p, _ in selections]),
+                        selected_real=jnp.stack(
+                            [n for _, n in selections]))
+                return logits, new_caches, said
             return logits, new_caches
         if routings:
             from alpa_tpu.model.moe import routing_summary
@@ -1680,7 +2241,11 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     and V cache: ``seq_len`` positions in a "full" layer, a ring of
     ``sliding_window`` (at most ``seq_len``) in a "sliding" one.  A
     "latent" layer's two arrays differ and have no heads: its entry is the
-    pair ((B, seq_len, kv_lora_rank), (B, qk_rope_head_dim, seq_len)).  A
+    pair ((B, seq_len, kv_lora_rank), (B, qk_rope_head_dim, seq_len)); one
+    that selects its positions holds a row a position and an index key:
+    ((B, seq_len, ``latent_row_width``), (B, seq_len, index_head_dim)); a
+    "latent_sliding" layer's is a ring of its own widths:
+    ((B, window, rank), (B, rope dim, window)).  A
     "conv" layer holds no positions but a state: its entry is the pair
     ((B, conv_taps - 1, hidden_size), (B, 0)), the second array empty so
     that the entry is a triple as every layer's is.
@@ -1693,10 +2258,23 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     shapes = []
     for i in range(config.num_layers):
         kind = kinds if isinstance(kinds, str) else kinds[i]
+        if kind == "latent" and getattr(config, "index_topk", 0):
+            shapes.append((
+                (batch_size, config.seq_len, latent_row_width(
+                    config.kv_lora_rank, config.qk_rope_head_dim)),
+                (batch_size, config.seq_len, config.index_head_dim)))
+            continue
         if kind == "latent":
             shapes.append((
                 (batch_size, config.seq_len, config.kv_lora_rank),
                 (batch_size, config.qk_rope_head_dim, config.seq_len)))
+            continue
+        if kind == LATENT_SLIDING:
+            ring = min(config.sliding_window, config.seq_len)
+            widths = config.sliding_latent
+            shapes.append((
+                (batch_size, ring, widths.kv_lora_rank),
+                (batch_size, widths.qk_rope_head_dim, ring)))
             continue
         if kind == "conv":
             shapes.append((
@@ -1713,9 +2291,18 @@ def kv_cache_kinds(config) -> list:
     """The kind of every layer's cache entry, as the gauge
     ``alpa_serving_kv_cache_bytes`` labels them: "full" (``seq_len``
     positions of K and V), "window" (a "sliding" layer's ring), "latent",
-    "conv" (a state and no positions)."""
+    "latent_index" (a latent layer that selects its positions: a row and
+    an index key a position), "latent_window" (a "latent_sliding" layer's
+    ring of latents), "conv" (a state and no positions)."""
     kinds = getattr(config, "attention", "full")
-    return ["window" if kind == "sliding" else kind for kind in
+
+    def label(kind):
+        if kind == "latent" and getattr(config, "index_topk", 0):
+            return "latent_index"
+        return {"sliding": "window", LATENT_SLIDING: "latent_window"}.get(
+            kind, kind)
+
+    return [label(kind) for kind in
             ([kinds] * config.num_layers if isinstance(kinds, str)
              else kinds)]
 
@@ -1738,6 +2325,9 @@ def cached_key_block(config, queries: int) -> int:
         return jax.ShapeDtypeStruct(shape, config.dtype)
 
     kinds = kv_cache_kinds(config)
+    if "latent_index" in kinds:
+        # a selecting layer's core goes over the selection, not the cache
+        return 0
     if "latent" in kinds:
         takes = latent_attention.absorbed_fits(
             of(1, queries, config.num_heads, config.kv_lora_rank),
@@ -1752,7 +2342,14 @@ def cached_key_block(config, queries: int) -> int:
 
 def latent_kv_caches(config) -> bool:
     """Whether any layer's cache is a latent one (no per-head K and V)."""
-    return "latent" in kv_cache_kinds(config)
+    return any(kind.startswith("latent") for kind in kv_cache_kinds(config))
+
+
+def selected_per_row(config) -> int:
+    """The positions a decode's selecting layers fetch a row at most
+    (``index_topk``); 0: no layer selects."""
+    return config.index_topk if "latent_index" in kv_cache_kinds(config) \
+        else 0
 
 
 def conv_states(config) -> bool:
@@ -1785,6 +2382,19 @@ def require_uniform_kv_caches(config, what: str):
             "that every step overwrites: no index brings an earlier state "
             "back, and there are no positions to page or reorder: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
+    kinds = set(kv_cache_kinds(config))
+    if kinds & {"latent_index", "latent_window"}:
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape, and "
+            "this configuration's latent layers hold " + " and ".join(
+                name for kind, name in (
+                    ("latent_index", "a row of latent and shared rotary "
+                     "key and an index key a position, of which a decode "
+                     "reads a learned selection (GPTConfig.index_topk)"),
+                    ("latent_window", "a ring of the window's latents "
+                     "(GPTConfig.attention \"latent_sliding\")"))
+                if kind in kinds) +
+            f": {sorted(set(kv_cache_shapes(config, 1)))}")
     if latent_kv_caches(config):
         raise ValueError(
             f"{what} indexes per-head K and V caches of one shape, and "

@@ -1,5 +1,6 @@
 """Latent attention (MLA) over a latent cache: the expanded core of a
-prefill's chunk and the absorbed core of a decode, one Pallas kernel each.
+prefill's chunk and the absorbed core of a decode, one Pallas kernel each,
+and the index scores of a layer that selects its positions.
 
 **Expanded** (``expanded``).
 
@@ -43,6 +44,23 @@ for all heads, scores, and folds ``probabilities x latents`` into the
 running ``heads x r`` output; steps past the row's newest position fetch
 and compute nothing, so a tick reads what its rows hold and no more.  At
 128 heads a step's operations and bytes balance on a v5e (242 a byte).
+
+**Under a selection** (``expanded(selected=...)``, ``index_scores``).  A
+latent layer that selects its positions (``GPTConfig.index_topk``) scores
+every position a row holds for every query, ``I[t, s] = sum_j w[t, j]
+relu(q[t, j] . k[s])`` over 64 index heads, and attends over the 2,048
+best.  In ``jax.numpy`` a chunk's products are ``queries x heads x keys``
+float32 before the heads are summed, 8.6 GB at 1,024 queries of 32,768
+keys; ``index_scores`` reduces them in fast memory: a chunk's grid is
+``(rows, query blocks, key blocks)``, a step 64 products of one head's
+block of queries with a block of index keys, weighted and summed; a
+decode's is ``(rows, key blocks)``, a step ONE product of all heads'
+queries with a block of the row's keys, reduced over the heads in the
+kernel.  Key blocks past a row's last query are filled with ``-inf`` and
+not fetched.  A chunk then runs ``expanded`` with the selection as one more
+operand, a block of (queries, keys) int8 a step; a decode gathers the
+selected rows and runs ``absorbed`` over them as over a cache of 2,048
+positions, the real ones first.
 
 The kernels are compiled where the program is lowered for a TPU
 (``gpt_model`` chooses between each and its ``jax.numpy`` twin with
@@ -115,7 +133,11 @@ def _block_of(b, kb, blocks_ref):
 
 
 def _kernel(blocks_ref, offset_ref, qn_ref, qp_ref, c_ref, kpe_ref, w_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, scale: float, dn: int):
+            *rest, scale: float, dn: int, masked: bool = False):
+    # with ``masked`` one more operand before the output: the block's
+    # (queries, keys) int8 of the keys each query may see at all
+    sel_ref = rest[0] if masked else None
+    o_ref, m_ref, l_ref, acc_ref = rest[masked:]
     b, kb = pl.program_id(0), pl.program_id(2)
     sq, block_k = qn_ref.shape[0], c_ref.shape[0]
     pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
@@ -134,19 +156,26 @@ def _kernel(blocks_ref, offset_ref, qn_ref, qp_ref, c_ref, kpe_ref, w_ref,
             jnp.int32, (sq, block_k), 0)
         k_pos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (sq, block_k), 1)
-        _fold_in(s, k_pos <= q_pos, kv[:, dn:], m_ref, l_ref, acc_ref)
+        seen = k_pos <= q_pos
+        if masked:
+            seen &= sel_ref[:].astype(jnp.int32) != 0
+        _fold_in(s, seen, kv[:, dn:], m_ref, l_ref, acc_ref)
 
     pl.when(kb == pl.num_programs(2) - 1)(
         lambda: _finish(o_ref, l_ref, acc_ref))
 
 
 def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
-             interpret: bool = False):
+             selected=None, interpret: bool = False):
     """``q_nope`` (B, Sq, H, dn), ``q_pe`` (B, Sq, H, dr) against the cache
     ``c`` (B, Sk, r), ``k_pe`` (B, dr, Sk) through ``w_kv_b`` (r, H, dn +
     dv); row ``b``'s query i sits at ``offset[b] + i`` ((B,) int32) and
     sees the keys at or before it.  Returns (B, Sq, H, dv) in the queries'
-    dtype."""
+    dtype.  ``c`` may be wider than ``r`` (a selecting layer's rows): its
+    first ``r`` channels are the latent.  ``selected`` ((B, Sq, Sk) int8,
+    None: all): of the keys at or before a query, those it sees; the
+    kernel walks the same key blocks and fetches a block of the mask a
+    head beside them."""
     b, sq, nh, dn = q_nope.shape
     dr, sk = k_pe.shape[1], c.shape[1]
     rank, dv = w_kv_b.shape[0], w_kv_b.shape[2] - dn
@@ -158,8 +187,14 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
     def per_head(b_, h, kb, blocks_ref, offset_ref):
         return b_, h, 0, 0
 
+    masked = selected is not None
+    mask_spec = [pl.BlockSpec(
+        (None, sq, BLOCK_K),
+        lambda b_, h, kb, blocks_ref, offset_ref:
+        (b_, 0, _block_of(b_, kb, blocks_ref)))] if masked else []
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, dn=dn),
+        functools.partial(_kernel, scale=scale, dn=dn, masked=True)
+        if masked else functools.partial(_kernel, scale=scale, dn=dn),
         out_shape=jax.ShapeDtypeStruct((b, nh, sq, dv), q_nope.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -178,7 +213,7 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
                 pl.BlockSpec(
                     (None, rank, dn + dv),
                     lambda b_, h, kb, blocks_ref, offset_ref: (h, 0, 0)),
-            ],
+            ] + mask_spec,
             out_specs=pl.BlockSpec((None, None, sq, dv), per_head),
             scratch_shapes=[pltpu.VMEM((sq, 1), jnp.float32),
                             pltpu.VMEM((sq, 1), jnp.float32),
@@ -188,7 +223,8 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(blocks, offset, q_nope.transpose(0, 2, 1, 3),
-      q_pe.transpose(0, 2, 1, 3), c, k_pe, w_kv_b.transpose(1, 0, 2))
+      q_pe.transpose(0, 2, 1, 3), c, k_pe, w_kv_b.transpose(1, 0, 2),
+      *([selected] if masked else []))
     return out.transpose(0, 2, 1, 3)
 
 
@@ -265,3 +301,140 @@ def absorbed(q_lat, q_pe, c, k_pe, index, *, scale: float,
         interpret=interpret,
     )(blocks, index, q_lat[:, 0], q_pe[:, 0], c, k_pe)
     return out[:, None]
+
+
+# --- the indexer's scores (a layer that selects its positions) ---
+
+# queries and keys a step of the chunk's kernel: 64 heads' queries of a
+# block are 4 MB, a step's float32 scores 512 KB
+INDEX_BLOCK_Q = 256
+INDEX_BLOCK_K = 512
+
+
+def index_scores_fits(q_index, keys) -> bool:
+    """Whether the kernels take these shapes: the index heads' channels in
+    whole lanes, the heads in whole sublanes, the keys in whole key blocks
+    of either kernel, one query a row or whole blocks of them."""
+    sq, heads, dim = q_index.shape[1:]
+    return (dim % 128 == 0 and heads % 8 == 0 and
+            keys.shape[1] % DECODE_BLOCK_K == 0 and
+            (sq == 1 or sq % INDEX_BLOCK_Q == 0))
+
+
+def _index_chunk_kernel(blocks_ref, offset_ref, q_ref, w_ref, k_ref, o_ref):
+    b, qb, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[0]
+    tq, tk = o_ref.shape
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        keys = k_ref[:]
+        weights = w_ref[:]
+        total = jnp.zeros((tq, tk), jnp.float32)
+        for j in range(heads):
+            total += weights[:, j:j + 1] * jnp.maximum(
+                lax.dot_general(q_ref[j], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32), 0.0)
+        q_pos = offset_ref[b] + qb * tq + lax.broadcasted_iota(
+            jnp.int32, (tq, tk), 0)
+        k_pos = kb * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        o_ref[:] = jnp.where(k_pos <= q_pos, total, -jnp.inf)
+
+    @pl.when(kb >= blocks_ref[b])
+    def _unseen():
+        o_ref[:] = jnp.full_like(o_ref, -jnp.inf)
+
+
+def _index_decode_kernel(blocks_ref, index_ref, q_ref, w_ref, k_ref, o_ref):
+    b, kb = pl.program_id(0), pl.program_id(1)
+    tk = o_ref.shape[1]
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        products = lax.dot_general(q_ref[:], k_ref[:],
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        total = jnp.sum(w_ref[:] * jnp.maximum(products, 0.0), axis=0,
+                        keepdims=True)
+        k_pos = kb * tk + lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        o_ref[:] = jnp.where(k_pos <= index_ref[b], total, -jnp.inf)
+
+    @pl.when(kb >= blocks_ref[b])
+    def _unseen():
+        o_ref[:] = jnp.full_like(o_ref, -jnp.inf)
+
+
+def index_scores(q_index, weights, keys, q_pos, *, interpret: bool = False):
+    """``gpt_model.index_scores`` (which says what it computes): ``q_index``
+    (B, Sq, J, di), ``weights`` (B, Sq, J) float32, ``keys`` (B, Sk, di),
+    ``q_pos`` (B, Sq) int32, a row's queries at consecutive positions;
+    (B, Sq, Sk) float32, ``-inf`` past each query.  One query a row (a
+    decode): grid ``(rows, key blocks)``, a step one product of all heads'
+    queries with a block of the row's index keys, reduced over the heads
+    in the kernel.  Several (a chunk): grid ``(rows, query blocks, key
+    blocks)``, a step 64 products of one head's block of queries with the
+    block of keys, summed in fast memory.  Key blocks past a row's last
+    query are filled and not fetched."""
+    b, sq, heads, dim = q_index.shape
+    sk = keys.shape[1]
+    offset = q_pos[:, 0].astype(jnp.int32)
+    weights = weights.astype(jnp.float32)
+    if sq == 1:
+        tk = DECODE_BLOCK_K
+        blocks = jnp.clip(offset // tk + 1, 1, sk // tk)
+
+        def per_row(b_, kb, blocks_ref, index_ref):
+            return b_, 0, 0
+
+        return pl.pallas_call(
+            _index_decode_kernel,
+            out_shape=jax.ShapeDtypeStruct((b, 1, sk), jnp.float32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b, sk // tk),
+                in_specs=[
+                    pl.BlockSpec((None, heads, dim), per_row),
+                    pl.BlockSpec((None, heads, 1), per_row),
+                    pl.BlockSpec(
+                        (None, tk, dim),
+                        lambda b_, kb, blocks_ref, index_ref:
+                        (b_, _block_of(b_, kb, blocks_ref), 0)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, 1, tk),
+                    lambda b_, kb, blocks_ref, index_ref: (b_, 0, kb))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT),
+            interpret=interpret,
+        )(blocks, offset, q_index[:, 0], weights[:, 0, :, None], keys)
+    tq, tk = INDEX_BLOCK_Q, INDEX_BLOCK_K
+    blocks = jnp.clip((offset + sq - 1) // tk + 1, 1, sk // tk)
+    return pl.pallas_call(
+        _index_chunk_kernel,
+        out_shape=jax.ShapeDtypeStruct((b, sq, sk), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, sq // tq, sk // tk),
+            in_specs=[
+                pl.BlockSpec(
+                    (None, heads, tq, dim),
+                    lambda b_, qb, kb, blocks_ref, offset_ref:
+                    (b_, 0, qb, 0)),
+                pl.BlockSpec(
+                    (None, tq, heads),
+                    lambda b_, qb, kb, blocks_ref, offset_ref:
+                    (b_, qb, 0)),
+                pl.BlockSpec(
+                    (None, tk, dim),
+                    lambda b_, qb, kb, blocks_ref, offset_ref:
+                    (b_, _block_of(b_, kb, blocks_ref), 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, tq, tk),
+                lambda b_, qb, kb, blocks_ref, offset_ref: (b_, qb, kb))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(blocks, offset, q_index.transpose(0, 2, 1, 3), weights, keys)

@@ -26,7 +26,8 @@ import numpy as np
 
 from alpa_tpu import fault
 from alpa_tpu.model.gpt_model import (cached_key_block, init_kv_caches,
-                                      kv_cache_kinds, require_one_token_steps)
+                                      kv_cache_kinds, require_one_token_steps,
+                                      selected_per_row)
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
                                        fresh_kv_caches, read_block,
                                        row_length, sample_rows)
@@ -101,7 +102,18 @@ _DECODE_POSITIONS_READ = _REG.counter(
     "alpa_serving_decode_positions_total rounded up to the whole key "
     "blocks its core reads, or the whole served context a row where the "
     "program's core reads every position the cache can hold (held over "
-    "read: how much of what the ticks fetched was wanted)")
+    "read: how much of what the ticks fetched was wanted); where layers "
+    "select their positions (GPTConfig.index_topk), what such a layer's "
+    "core fetches a row: the selection, or all the row holds while that "
+    "is less")
+_SELECT_POSITIONS = _REG.counter(
+    "alpa_serving_select_positions_total",
+    "Cache positions on the decode ticks' layers that select their "
+    "positions, summed over those layers, the active rows and the ticks: "
+    "held (what the rows held there, which the indexer scored) and "
+    "selected (what the core fetched and attended over: index_topk a row "
+    "a layer, or all it holds while that is less)",
+    labelnames=("what",))
 _BLOCK_FORWARDS = _REG.counter(
     "alpa_serving_block_forwards_total",
     "Forwards of a block by the active rows of an engine that generates "
@@ -121,8 +133,10 @@ _KV_CACHE_BYTES = _REG.gauge(
     "Bytes of the engine's resident K and V caches, by the kind of the "
     "layers' cache: window (a ring of the sliding window's positions), "
     "full (the served context), latent (the served context of a latent "
-    "layer's normed latent and shared rotary key) or conv (a short "
-    "convolution's state: its last positions, whatever the context)",
+    "layer's normed latent and shared rotary key; with an index key a "
+    "position where the layer selects its positions; a ring of the "
+    "window's latents where the layer is under the window) or conv (a "
+    "short convolution's state: its last positions, whatever the context)",
     labelnames=("kind",))
 
 # every engine span: category "serving", on this track (the queue waits,
@@ -310,6 +324,10 @@ class ContinuousBatchingEngine:
         self._key_block = cached_key_block(
             cfgm, cfgm.block_length if self._blocks else 1) \
             if _lowered_for_tpu() else 0
+        # what a selecting layer's core fetches a row at most (0: no layer
+        # selects), and how many layers select
+        self._selected = selected_per_row(cfgm)
+        self._select_layers = kv_cache_kinds(cfgm).count("latent_index")
         self._prefix = prefix
         # what a dense admission pads to; empty where none can happen
         self._ladder = [] if chunked_admission or prefix is not None \
@@ -482,7 +500,8 @@ class ContinuousBatchingEngine:
         # state, as large a row whatever the context
         by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0}
         for kind, (k, v, _i) in zip(kv_cache_kinds(cfgm), self._caches):
-            by_kind[kind] += k.nbytes + v.nbytes
+            # every latent layer's entry is "latent", whatever it holds
+            by_kind[kind.partition("_")[0]] += k.nbytes + v.nbytes
         for kind, nbytes in by_kind.items():
             _KV_CACHE_BYTES.labels(kind).set(nbytes)
         # what the last decode said of its routed layers ({}: no decode
@@ -972,8 +991,11 @@ class ContinuousBatchingEngine:
     def _positions_read(self, held: int) -> int:
         """Of a row that holds ``held`` positions, those the tick's
         attention core fetches: whole key blocks up to the row's newest
-        position, or the whole served context."""
+        position, or the whole served context; where layers select their
+        positions, what such a layer's core fetches: the selection."""
         seq_len = self.gen.config.seq_len
+        if self._selected:
+            return min(held, self._selected)
         if not self._key_block:
             return seq_len
         return min(-(-held // self._key_block) * self._key_block, seq_len)
@@ -1018,7 +1040,11 @@ class ContinuousBatchingEngine:
             # here, one tick late.  What the previous decode said of its
             # routed layers comes along: it was done before this tick's
             # sampling began, so nothing more is waited for
-            nxt, routing = jax.device_get((tokens, routing))
+            # (of what it said, the experts alone: what its selecting
+            # layers selected stays on the device)
+            nxt, routing = jax.device_get(
+                (tokens, {k: v for k, v in routing.items()
+                          if k == "experts"}))
             nxt = nxt[:, 0]
             self._count_routing(routing)
         if self._pool is not None:
@@ -1045,6 +1071,11 @@ class ContinuousBatchingEngine:
                         self._finish_row(r, item)
                 _DECODE_POSITIONS.inc(positions)
                 _DECODE_POSITIONS_READ.inc(read)
+                if self._selected:
+                    _SELECT_POSITIONS.labels("held").inc(
+                        positions * self._select_layers)
+                    _SELECT_POSITIONS.labels("selected").inc(
+                        read * self._select_layers)
                 if rec is not None:
                     deliver_span.args = {"tokens": delivered}
             # refill freed rows before the next tick

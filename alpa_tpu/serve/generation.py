@@ -387,7 +387,13 @@ class Generator:
     donating decode and the engine's ``_scatter_row`` serve it as they are;
     what indexes per-head K and V (the block pool, the speculative verify
     step, beam search) refuses it
-    (``require_uniform_kv_caches``).
+    (``require_uniform_kv_caches``).  So do a latent layer under the window
+    (``"latent_sliding"``: a ring of the window's latents, prefilled with
+    the rows' lengths as every ring is) and one that selects its positions
+    (``GPTConfig.index_topk``: a row and an index key a position); of such
+    a configuration ``_decode``'s ``routing`` also holds what each
+    selecting layer selected (``selected``, ``selected_real``:
+    ``GPTModel``).
 
     A short-convolution layer (``GPTConfig.attention`` "conv") holds no
     positions: its entry is ``(state, empty, index)``, the last
@@ -492,8 +498,10 @@ class Generator:
         # what the cached calls hand the model beyond ids, positions and
         # caches (other decoder families take neither)
         kinds = getattr(config, "mlp", "dense")
+        # (or what its selecting layers selected)
         routed = any(routed_mlp(kind) for kind in
-                     ([kinds] if isinstance(kinds, str) else kinds))
+                     ([kinds] if isinstance(kinds, str) else kinds)) or \
+            getattr(config, "index_topk", 0) > 0
         rings = not uniform_kv_caches(config)
 
         def lengths_kw(lengths):

@@ -34,6 +34,11 @@ part                         components
                              softmax, values
 ``attention.cache_write``    ``cache_write`` inside it: the step's keys
                              and values written into the cache
+``attention.indexer``        ``indexer`` inside it: of a latent layer that
+                             selects its positions, the indexer's
+                             projections, scores and choice
+``attention.latent_select``  ``latent_select`` inside it: the selected
+                             positions' gather and the core over them
 ``short_conv``               ``model.gpt_model.CONV_SCOPE``, a gated short
                              convolution whole: both products, the gates,
                              the taps, the state's update (and ``conv``,
@@ -122,6 +127,10 @@ _COMPONENTS = {
     "attn": "projection",
     "attention": "attention",
     "cache_write": "attention.cache_write",
+    # model/gpt_model.py INDEXER_SCOPE, SELECT_SCOPE: inside the attention
+    # core of a latent layer that selects its positions
+    "indexer": "attention.indexer",
+    "latent_select": "attention.latent_select",
     "short_conv": "short_conv", "conv": "short_conv",
     "mlp": "mlp",
     "moe": "moe",

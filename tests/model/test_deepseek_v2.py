@@ -604,14 +604,16 @@ def test_rows_routed_to_an_absent_expert_take_no_part():
 
 # ---- the driver -------------------------------------------------------
 
-def _toy_context(tmp_path):
-    return run.Context(
+def _toy_context(tmp_path, steady):
+    """The toy cell's context, its check on the same requests whatever the
+    machine's load (``conftest.checks_the_same_requests``)."""
+    return steady(run.Context(
         cell={"name": "toy-deepseek-v2.longdoc", "config": "toy-deepseek-v2",
               "traffic": "toy-longdoc", "chips": 1},
         config=TOY, mix=traffic.load_mix("toy-longdoc"), seed=2147483659,
         seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
         compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace"))
+        trace_dir=str(tmp_path / "trace")))
 
 
 def test_the_routers_balance_leaves_the_mean_input_unscored():
@@ -650,12 +652,12 @@ def test_the_routers_balance_leaves_the_mean_input_unscored():
     assert abs(cosine) > 0.9999
 
 
-def test_driver_runs_the_toy_cell(tmp_path):
+def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
     """``chipbench/drivers/serve_mla.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference."""
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -683,8 +685,8 @@ def test_driver_runs_the_toy_cell(tmp_path):
         for s in spans)
 
 
-def test_driver_holds_the_latent_cache_to_its_precision(tmp_path,
-                                                        monkeypatch):
+def test_driver_holds_the_latent_cache_to_its_precision(
+        tmp_path, monkeypatch, checks_the_same_requests):
     """The control the cell's limits are set against, at the toy size: a
     latent cache rounded to float8 (e4m3) on its way in serves plausible
     tokens and is not correct."""
@@ -695,7 +697,7 @@ def test_driver_holds_the_latent_cache_to_its_precision(tmp_path,
                       jax.lax.reduce_precision(k_pe, 4, 3))
 
     monkeypatch.setattr(gpt_model, "update_latent_cache", rounded)
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks

@@ -529,14 +529,16 @@ def test_rows_behind_the_groups_take_no_part():
 
 # ---- the driver -------------------------------------------------------
 
-def _toy_context(tmp_path):
-    return run.Context(
+def _toy_context(tmp_path, steady):
+    """The toy cell's context, its check on the same requests whatever the
+    machine's load (``conftest.checks_the_same_requests``)."""
+    return steady(run.Context(
         cell={"name": "toy-longcat.agent", "config": "toy-longcat",
               "traffic": "toy-agent", "chips": 1},
         config=TOY, mix=traffic.load_mix("toy-agent"), seed=2147483659,
         seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
         compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace"))
+        trace_dir=str(tmp_path / "trace")))
 
 
 def test_the_routers_balance_leaves_the_mean_input_unscored():
@@ -561,13 +563,13 @@ def test_the_routers_balance_leaves_the_mean_input_unscored():
         assert np.abs(u[:, 0] @ w_new).max() < 1e-5  # and none of it left
 
 
-def test_driver_runs_the_toy_cell(tmp_path):
+def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
     """``chipbench/drivers/serve_scmoe.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference; and what the
     cell's readers make of it."""
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -603,13 +605,14 @@ def test_driver_runs_the_toy_cell(tmp_path):
 @pytest.mark.parametrize("control", ["cache_in_float8",
                                      "matrices_in_float8",
                                      "identity_left_out"])
-def test_driver_fails_a_control(tmp_path, monkeypatch, control):
+def test_driver_fails_a_control(tmp_path, monkeypatch, control,
+                                checks_the_same_requests):
     """The controls the cell's limits are set against
     (``chipbench/controls_longcat.py``), planted at the toy size: each
     serves plausible tokens and is not correct."""
     from chipbench import controls_longcat
     controls_longcat.CONTROLS[control](TOY, monkeypatch.setattr)
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks

@@ -327,3 +327,44 @@ def test_grouped_matmul_kernels_get_the_rules_tiles(monkeypatch):
         jax.ShapeDtypeStruct((groups,), jnp.int32))
     assert set(seen) == {("gmm", (128, 2048, 1024)),
                          ("tgmm", (128, 1024, 1024))}
+
+
+# dots3-note's full layers at their published widths: the indexer's scores
+# of a chunk and of a decode, the chunk's expanded core under the
+# selection's mask, the decode's absorbed core over the gathered rows
+SELECTING_CASES = [
+    ("index-scores-chunk", "index_scores",
+     [((1, 1024, 64, 128), jnp.bfloat16), ((1, 1024, 64), jnp.float32),
+      ((1, 32768, 128), jnp.bfloat16), ((1, 1024), jnp.int32)]),
+    ("index-scores-decode", "index_scores",
+     [((16, 1, 64, 128), jnp.bfloat16), ((16, 1, 64), jnp.float32),
+      ((16, 32768, 128), jnp.bfloat16), ((16, 1), jnp.int32)]),
+    ("expanded-under-a-mask", "expanded",
+     [((1, 1024, 128, 128), jnp.bfloat16), ((1, 1024, 128, 64), jnp.bfloat16),
+      ((1, 32768, 640), jnp.bfloat16), ((1, 64, 32768), jnp.bfloat16),
+      ((512, 128, 256), jnp.bfloat16), ((1,), jnp.int32),
+      ((1, 1024, 32768), jnp.int8)]),
+    ("absorbed-over-the-selection", "absorbed",
+     [((16, 1, 128, 512), jnp.bfloat16), ((16, 1, 128, 64), jnp.bfloat16),
+      ((16, 2048, 512), jnp.bfloat16), ((16, 64, 2048), jnp.bfloat16),
+      ((16,), jnp.int32)]),
+]
+
+
+@pytest.mark.parametrize("kernel,shapes", [c[1:] for c in SELECTING_CASES],
+                         ids=[c[0] for c in SELECTING_CASES])
+def test_selecting_latent_kernels_compile_for_v5e(one_chip, kernel, shapes):
+    from alpa_tpu.ops import latent_attention as la
+
+    def call(*args):
+        if kernel == "index_scores":
+            return la.index_scores(*args)
+        if kernel == "absorbed":
+            return la.absorbed(*args, scale=192 ** -0.5)
+        *rest, selected = args
+        return la.expanded(*rest, scale=192 ** -0.5, selected=selected)
+
+    compiled = jax.jit(call).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes]).compile()
+    assert compiled.as_text().count(KERNEL) == 1

@@ -52,6 +52,17 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
      "attention"),
     ("jit(decode)/GPTModel/h0/attn/attention/cache_write/"
      "dynamic_update_slice", "attention.cache_write"),
+    # a latent layer that selects its positions (PR 47)
+    ("jit(decode)/GPTModel/h1/attn/attention/indexer/index_q/dot_general",
+     "attention.indexer"),
+    ("jit(decode)/GPTModel/h1/attn/attention/indexer/index_k_ln/rsqrt",
+     "attention.indexer"),
+    ("jit(decode)/GPTModel/h1/attn/attention/indexer/top_k",
+     "attention.indexer"),
+    ("jit(decode)/GPTModel/h1/attn/attention/latent_select/gather",
+     "attention.latent_select"),
+    ("jit(chunk_prefill)/GPTModel/h5/attn/attention/latent_select/"
+     "cond/branch_1_fun/pallas_call", "attention.latent_select"),
     ("jit(decode)/GPTModel/h0/mlp/fc_in/dot_general", "mlp"),
     ("jit(decode)/GPTModel/h2/mlp/moe/router/dot_general", "moe"),
     ("jit(decode)/GPTModel/h2/mlp/moe/grouped_matmul/cond/branch_0_fun/"

@@ -324,6 +324,54 @@ def test_latent_decode_holds_no_per_head_cache(one_chip):
     assert copies == []
 
 
+SELECT_CONTEXT = 4096
+
+
+def test_selecting_decode_moves_no_cache(one_chip):
+    """dots3-note at its published widths (one full layer that selects its
+    positions and one sliding layer over a ring of 513 latents): a full
+    layer's row is the latent and the shared rotary key in whole lanes
+    (512 + 64 channels in 640), so that the per-row writes and the gather
+    of the selected rows share one order.  At 576 channels the compiler
+    kept the cache with its positions minor-most for the writes and moved
+    it whole into row order for the gather, two copies of the cache a
+    layer a tick, 2.07 GB of temporaries at 16 rows of 32,768 positions
+    (compile for the described v5e, PR 47).  The decode holds no copy of
+    a cache, gives every cache array to the output that replaces it and
+    fetches the selected rows with one gather a layer."""
+    import json
+    from alpa_tpu.model.gpt_model import config_from_hf
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "..", "chipbench", "configs",
+                           "dots3-note-prev-1chip.json")) as f:
+        hf = json.load(f)
+    hf.update(num_hidden_layers=2, vocab_size=1024, first_k_dense_replace=2,
+              layer_types=["full_attention", "sliding_attention"],
+              n_routed_experts=hf["published"]["n_routed_experts"])
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=SELECT_CONTEXT)
+    model = GPTModel(cfg)
+    params, caches = _abstract_state(model, cfg, ROWS, one_chip)
+    assert [(k.shape, v.shape) for k, v, _ in caches] == [
+        ((ROWS, SELECT_CONTEXT, 640), (ROWS, SELECT_CONTEXT, 128)),
+        ((ROWS, 513, 1024), (ROWS, 64, 513))]
+    gen = Generator(model, params, cfg, prefill_chunk=1024)
+    hlo = _compile_decode(gen, params, caches, one_chip, NO_MSA)
+    assert len(_aliases(hlo)) == 4
+    found, _types = _entry(hlo)
+    whole = re.compile(r"\[(%d,%d|%d),(640|128)\]" % (
+        ROWS, SELECT_CONTEXT, ROWS * SELECT_CONTEXT))
+    assert [result for _name, result, op, _operand in found
+            if op in ("copy", "copy-start") and whole.search(result)] == []
+    assert len(re.findall(r"= \S*\[%d,2048,640\]\S* gather\(" % ROWS,
+                          hlo)) == 1
+    # the index scores, and the absorbed core over the gathered rows
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert sum("/indexer/" in name for name in kernels) == 1
+    assert sum("/latent_select/" in name for name in kernels) == 1
+
+
 def test_latent_chunk_step_expands_a_block_at_a_time(one_chip):
     """The chunk step's attention is the kernel of
     ``ops/latent_attention.py``, compiled by Mosaic for this chip at the
