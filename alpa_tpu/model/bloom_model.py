@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import reference_attention, update_kv_cache
+from alpa_tpu.model.gpt_model import (keep_positions, reference_attention,
+                                      update_kv_cache)
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
 
 
@@ -146,7 +147,8 @@ class BloomModel(nn.Module):
     config: BloomConfig
 
     @nn.compact
-    def __call__(self, input_ids, position_ids=None, kv_caches=None):
+    def __call__(self, input_ids, position_ids=None, kv_caches=None,
+                 logits_at=None):
         # position_ids accepted for Generator interface compatibility;
         # ALiBi needs no position table (positions come from cache indices)
         del position_ids
@@ -166,7 +168,7 @@ class BloomModel(nn.Module):
             if new_caches is not None:
                 new_caches.append(c)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                         name="ln_f")(x)
+                         name="ln_f")(keep_positions(x, logits_at))
         if cfg.tie_embeddings:
             logits = tok_emb.attend(x.astype(cfg.dtype))
         else:

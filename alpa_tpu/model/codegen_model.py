@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import reference_attention, update_kv_cache
+from alpa_tpu.model.gpt_model import (keep_positions, reference_attention,
+                                      update_kv_cache)
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
 
 
@@ -152,7 +153,8 @@ class CodeGenModel(nn.Module):
     config: CodeGenConfig
 
     @nn.compact
-    def __call__(self, input_ids, position_ids=None, kv_caches=None):
+    def __call__(self, input_ids, position_ids=None, kv_caches=None,
+                 logits_at=None):
         # positions come from rotary offsets (cache indices); the argument
         # is accepted for Generator interface compatibility
         del position_ids
@@ -169,7 +171,7 @@ class CodeGenModel(nn.Module):
             if new_caches is not None:
                 new_caches.append(c)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                         name="ln_f")(x)
+                         name="ln_f")(keep_positions(x, logits_at))
         logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=True,
                           name="lm_head")(x.astype(cfg.dtype))
         if new_caches is not None:

@@ -2116,6 +2116,20 @@ class TransformerBlock(nn.Module):
         return (x, new_cache) + tuple(selection) + routing
 
 
+def keep_positions(x, logits_at):
+    """The rows of the stream ``x`` (B, S, H) that a decoder's final norm
+    and head have to see: all of them, or with ``logits_at`` ((B, K)
+    int32, offsets into S) those K a row, (B, K, H).  Norm and head work a
+    position at a time, so the logits of a kept position are what they
+    were among all S; a prefill chunk keeps one position a row
+    (``serve/generation.py``), and a head over S rows it throws away is
+    the largest matmul of the step."""
+    if logits_at is None:
+        return x
+    return jnp.take_along_axis(x, logits_at[:, :, None], axis=1,
+                               mode="clip")
+
+
 class GPTModel(nn.Module):
     """Decoder-only LM.  Returns logits (and new kv caches if given).  A
     configuration with routed-expert layers returns ``(logits, routing)``
@@ -2126,10 +2140,15 @@ class GPTModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, position_ids=None, kv_caches=None,
                  deterministic=True, return_hidden=False,
-                 cache_lengths=None, return_routing=False):
+                 cache_lengths=None, return_routing=False, logits_at=None):
         """``return_hidden=True`` returns the final (B, S, H) hidden states
         instead of logits, for a fused/chunked lm-head + loss (see
         model_util.chunked_cross_entropy_loss).
+
+        ``logits_at`` ((B, K) int32): the offsets into the call's S
+        positions whose logits are wanted, where not all are.  The final
+        norm and the head then run over those K rows of the stream alone
+        and the logits are (B, K, V) (``keep_positions``).
 
         ``cache_lengths`` ((B,), with ``kv_caches``): the rows' whole
         lengths, where the ids are right-padded past them: a layer whose
@@ -2210,7 +2229,7 @@ class GPTModel(nn.Module):
             raise ValueError("the last block's experts are held back for a "
                              "next block (GPTConfig.mlp \"gated+shortcut\"), "
                              "and there is none")
-        x = make_norm(cfg, "ln_f")(x)
+        x = make_norm(cfg, "ln_f")(keep_positions(x, logits_at))
         if return_hidden:
             return x
         if cfg.tie_embeddings:

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from alpa_tpu.model.gpt_model import GPTConfig, SelfAttention
+from alpa_tpu.model.gpt_model import GPTConfig, SelfAttention, keep_positions
 
 logger = logging.getLogger(__name__)
 
@@ -575,7 +575,8 @@ class MoELMModel(nn.Module):
     config: MoEConfig
 
     @nn.compact
-    def __call__(self, input_ids, position_ids=None, kv_caches=None):
+    def __call__(self, input_ids, position_ids=None, kv_caches=None,
+                 logits_at=None):
         cfg = self.config
         b, s = input_ids.shape
         if position_ids is None:
@@ -596,7 +597,7 @@ class MoELMModel(nn.Module):
             if new_caches is not None:
                 new_caches.append(c)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                         name="ln_f")(x)
+                         name="ln_f")(keep_positions(x, logits_at))
         logits = emb.attend(x.astype(cfg.dtype))
         if new_caches is not None:
             return logits, new_caches

@@ -781,14 +781,19 @@ class ContinuousBatchingEngine:
                     self._caches, self._logits = self._scatter_row(
                         self._caches, caches1, self._logits, logits1, r)
                     if rec is not None:
+                        # programs the prefill ran: the chunks of the
+                        # chunk step, or the one dense prefill
+                        chunks = padded // self.gen.prefill_chunk \
+                            if path in ("chunked", "prefix") else \
+                            int(path == "dense")
                         prefill_span.args = {
                             "rid": item["rid"], "prompt_len": len(p),
                             "padded_len": padded, "path": path,
-                            # programs the prefill ran: the chunks of the
-                            # chunk step, or the one dense prefill
-                            "chunks": padded // self.gen.prefill_chunk
-                            if path in ("chunked", "prefix") else
-                            int(path == "dense")}
+                            "chunks": chunks,
+                            # positions the head ran over: the one a
+                            # chunk keeps, every one of a dense prefill
+                            "head_rows": padded if path == "dense"
+                            else chunks}
                 _PREFILL_PROMPT.inc(asked)
                 _PREFILL_PADDED.inc(padded)
                 if path == "dense":

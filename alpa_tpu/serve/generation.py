@@ -550,20 +550,26 @@ class Generator:
         self._parallel_method = parallel_method
 
         def chunk_prefill(params, ids_chunk, lengths, caches, last):
-            """One fixed-shape chunk through the cached path.  The
-            chunk's absolute start position rides the caches' scalar
-            write index; ``last`` accumulates each row's final-token
-            logits from whichever chunk contains position length-1."""
+            """One fixed-shape chunk through the cached path: ``(last,
+            caches)``.  The chunk's absolute start position rides the
+            caches' scalar write index.  The final norm and the head run
+            over one position a row, the one at ``lengths - 1`` where the
+            chunk holds it, and ``last`` (B, V) takes that row's logits
+            there.  A row whose prompt goes on past the chunk, or ended
+            before it, keeps what ``last`` held: for a chunk that no
+            prompt ends in, ``last`` comes back as it was given (the one
+            row the head ran over is thrown away)."""
             self.prefill_traces += 1
             b, c = ids_chunk.shape
             start = caches[0][2]                     # scalar chunk start
             pos = start + jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
-            logits, caches = model.apply(params, ids_chunk, pos, caches,
-                                         **lengths_kw(lengths))
             off = lengths - 1 - start                # (B,)
+            logits, caches = model.apply(
+                params, ids_chunk, pos, caches,
+                logits_at=jnp.clip(off, 0, c - 1)[:, None],
+                **lengths_kw(lengths))
             hit = (off >= 0) & (off < c)
-            sel = logits[jnp.arange(b), jnp.clip(off, 0, c - 1)]
-            last = jnp.where(hit[:, None], sel, last)
+            last = jnp.where(hit[:, None], logits[:, 0], last)
             return last, caches
 
         def block_step(params, ids, index, caches, left, settings, key):

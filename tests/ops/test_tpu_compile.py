@@ -368,3 +368,44 @@ def test_selecting_latent_kernels_compile_for_v5e(one_chip, kernel, shapes):
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes]).compile()
     assert compiled.as_text().count(KERNEL) == 1
+
+
+# ---- a prefill chunk's head (PR 48) -------------------------------------
+
+def test_a_trinity_chunk_holds_no_chunk_of_logits_on_v5e(one_chip):
+    """The chunk step of one admission of ``trinity-mini-1chip`` as its
+    cell compiles it (the published widths, the cell's depth, one row of
+    1,024 positions, served context 16,384, bfloat16): its head runs over
+    the one position it keeps, so no array of it is a chunk's logits
+    (``[1024, 200192]``, ``[1, 1024, 200192]``), and the one row's are
+    there."""
+    import json
+    from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf,
+                                          init_kv_caches)
+    from alpa_tpu.serve.generation import Generator
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "trinity-mini-1chip.json")) as f:
+        hf = json.load(f)
+    chunk, vocab = hf["serve"]["prefill_chunk"], hf["vocab_size"]
+    assert (chunk, vocab) == (1024, 200192)
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                         seq_len=hf["serve"]["served_context"])
+    model = GPTModel(cfg)
+    on_chip = lambda tree: jax.tree_util.tree_map(   # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)))
+    gen = Generator(model, params, cfg, prefill_chunk=chunk)
+    text = gen._chunk_prefill.lower(
+        params,
+        jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+        on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1))),
+        jax.ShapeDtypeStruct((1, vocab), jnp.bfloat16, sharding=one_chip)
+    ).compile().as_text()
+    assert text.startswith("HloModule jit_chunk_prefill")
+    assert re.search(r"bf16\[1,%d\]" % vocab, text)
+    assert not re.search(r"\[(\d+,)?%d,%d\]" % (chunk, vocab), text)

@@ -199,7 +199,7 @@ def test_engine_spans_nest_and_share_the_request_id(recorder, tmp_path):
     assert wait["args"] == {"rid": rid, "prompt_len": 5}
     assert prefill["args"] == {"rid": rid, "prompt_len": 5,
                                "padded_len": BUCKET, "path": "dense",
-                               "chunks": 1}
+                               "chunks": 1, "head_rows": BUCKET}
     assert admit["args"] == {"n": 1}
     assert all(s["category"] == "serving" for s in
                (request, wait, admit, prefill))

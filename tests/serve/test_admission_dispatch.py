@@ -5,19 +5,29 @@ prefill makes its zeros itself) and never from ``init_kv_caches`` called
 eagerly, three small programs a layer; and nothing of one admission left
 for the next, in the rows or on the device."""
 import gc
+import json
+import os
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax._src import pjit
 from jax._src.interpreters import pxla
 
-from alpa_tpu.model.gpt_model import GPTConfig, init_gpt_real
+from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, config_from_hf,
+                                      init_gpt_real, uniform_kv_caches)
 from alpa_tpu.serve import engine as engine_module
 from alpa_tpu.serve import generation
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
-from alpa_tpu.serve.generation import GenerationConfig, Generator
+from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
+                                       Generator)
+from alpa_tpu.telemetry import trace as ttrace
+
+# the toy configurations of the benchmark's chunked cells (data alone)
+TOYS = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "chipbench", "configs")
 
 BUCKET, CHUNK = 32, 16
 PATHS = {"dense": {}, "chunked": {"chunked_admission": True}}
@@ -160,3 +170,106 @@ def test_a_short_prompt_after_a_long_one_reads_nothing_of_it(path):
         assert live() == first
     finally:
         engine.shutdown()
+
+
+# ---- a chunk's head sees the one position a row it keeps (PR 48) ----
+
+TOY_CHUNK, TOY_CONTEXT = 8, 64
+# the six configurations the benchmark admits in chunks, at their toy
+# widths
+KINDS = {"trinity": "toy-trinity.json", "deepseek-v2": "toy-deepseek-v2.json",
+         "sdar": "toy-sdar.json", "lfm2": "toy-lfm2.json",
+         "longcat": "toy-longcat.json", "dots3": "toy-dots3.json"}
+
+
+def _toy_generator(kind):
+    """A float32 generator of the kind's toy configuration, in chunks of 8
+    over a context of 64."""
+    with open(os.path.join(TOYS, KINDS[kind])) as f:
+        toy = json.load(f)
+    blocks = {"block_length": toy["serve"]["block_length"]} \
+        if "block_length" in toy["serve"] else {}
+    cfg = config_from_hf(toy, dtype=jnp.float32, param_dtype=jnp.float32,
+                         seq_len=TOY_CONTEXT, **blocks)
+    model = GPTModel(cfg)
+    params = model.init(jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32))
+    diffusion = BlockDiffusion(toy["serve"]["mask_token_id"]) \
+        if blocks else None
+    return Generator(model, params, cfg, prefill_chunk=TOY_CHUNK,
+                     diffusion=diffusion)
+
+
+def _with_the_parents_chunk_step(gen):
+    """From here on every chunk of ``gen`` runs the chunk step as it was
+    before PR 48: the head over all the chunk's positions, one row of it
+    read (or none)."""
+    model, rings = gen.model, not uniform_kv_caches(gen.config)
+
+    @jax.jit
+    def step(params, ids_chunk, lengths, caches, last):
+        b, c = ids_chunk.shape
+        start = caches[0][2]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
+        logits, caches = model.apply(
+            params, ids_chunk, pos, caches,
+            **({"cache_lengths": lengths} if rings else {}))
+        off = lengths - 1 - start
+        hit = (off >= 0) & (off < c)
+        sel = logits[jnp.arange(b), jnp.clip(off, 0, c - 1)]
+        return jnp.where(hit[:, None], sel, last), caches
+
+    gen._chunk_prefill = step
+
+
+@pytest.fixture
+def recorder():
+    rec = ttrace.TraceRecorder()
+    old, was = ttrace.set_recorder(rec), ttrace.set_enabled(True)
+    yield rec
+    ttrace.set_enabled(was)
+    ttrace.set_recorder(old)
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_a_chunks_head_gives_the_parents_logits_and_tokens(kind, recorder):
+    """A prompt of two and a half chunks beside one that ends inside the
+    second chunk (while the first goes on) and one that ends inside the
+    first: the kept rows' logits and every cache are the parent's to the
+    bit in float32, the head having run over one position a row in each
+    chunk; and the engine, admitting the same prompts a row at a time,
+    gives the parent's tokens and says in its spans what its chunks' heads
+    ran over."""
+    gen = _toy_generator(kind)
+    prompts = [_prompt(n, seed) for seed, n in enumerate((20, 11, 5))]
+    lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    cfg = GenerationConfig(max_new_tokens=6)
+
+    def served():
+        last, caches = gen._run_chunked_prefill(prompts, lengths, 3)
+        engine = ContinuousBatchingEngine(gen, max_batch=2,
+                                          chunked_admission=True)
+        try:
+            tokens = [engine.submit(p, cfg) for p in prompts]
+        finally:
+            engine.shutdown()
+        return last, jax.tree_util.tree_leaves(caches), tokens
+
+    last, caches, tokens = served()
+    spans = [s["args"] for s in recorder.spans()
+             if s["name"] == "engine.prefill"]
+    # the same generator, its chunks through the parent's step
+    _with_the_parents_chunk_step(gen)
+    want, want_caches, want_tokens = served()
+
+    assert last.dtype == want.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(last), np.asarray(want))
+    # every row's logits are its own: no two rows kept the same position
+    assert len({np.asarray(row).tobytes() for row in last}) == 3
+    for mine, theirs in zip(caches, want_caches):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    for mine, theirs in zip(tokens, want_tokens):
+        np.testing.assert_array_equal(mine, theirs)
+    # an engine's chunks: 3, 2 and 1, the head over one position in each
+    # (SDAR prefills a prompt's whole blocks of 4: 20, 8 and 4)
+    assert [(s["chunks"], s["head_rows"]) for s in spans] == [
+        (3, 3), (1, 1) if kind == "sdar" else (2, 2), (1, 1)]
