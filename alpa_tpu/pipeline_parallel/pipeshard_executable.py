@@ -16,6 +16,7 @@ import itertools
 import logging
 import threading
 import time
+import types
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -197,8 +198,10 @@ class StageExecutable:
                           else None):
             lowered = jitted.lower(*self._avals)
             self.compiled = lowered.compile()
+        # what the profiler calls this program's runs
+        self.program_name = _device_time.compiled_name(self.compiled)
         _device_time.register_program(
-            _device_time.compiled_name(self.compiled), self,
+            self.program_name, self,
             lambda stage: stage.compiled.as_text())
         self.out_shardings = list(self.compiled.output_shardings)
 
@@ -1088,6 +1091,10 @@ class PipeshardDriverExecutable:
         self._register_programs[mode] = prog
         if mode == "registers":
             self._register_program = prog
+        # for a capture's reader that holds no executable
+        # (``Capture.pipeline_time``): nothing is computed here
+        _device_time.register_pipeline(
+            self, PipeshardDriverExecutable._describe_pipeline)
         slot_of = prog.slot_of
         if self._reg_input_loads is not None:
             return prog
@@ -1647,19 +1654,65 @@ class PipeshardDriverExecutable:
                        if f.analysis == "equiv"]
         return _eq.format_equiv(eq_stats, eq_findings)
 
-    def get_perf_report(self):
+    def _last_program(self):
+        """The register program of the last launch's dispatch mode."""
+        stats = getattr(self, "last_dispatch_stats", None) or {}
+        return self._register_programs.get(stats.get("mode"))
+
+    def _describe_pipeline(self):
+        """What ``telemetry/perf.py`` ``joined_from_capture`` needs of
+        this executable, without the compiled programs: the lowered
+        program's metadata, each mesh's chips, each RUN op's program."""
+        prog = self._last_program()
+        if prog is None:
+            return None
+        execs = self.stage_execs + [e for e in self.apply_execs
+                                    if e is not None]
+        return {
+            "program": types.SimpleNamespace(
+                hooks=prog.hooks, op_meta=prog.op_meta, graph=prog.graph),
+            "mesh_chips": {m: [d.id for d in mesh.flat_devices]
+                           for m, mesh in enumerate(self.mesh_group)},
+            "run_programs": {f"RUN {e.name}": e.program_name
+                             for e in execs
+                             if getattr(e, "program_name", None)}}
+
+    def get_perf_report(self, capture=None):
         """Post-step :class:`~alpa_tpu.telemetry.perf.StepPerfReport`
         (ISSUE 9): critical path, per-mesh bubbles, transfer overlap,
         stage MFU — joined from the last launch's trace spans (or the
         flight ring when full tracing is off) against the lowered
         program's dataflow graph.  Publishes the ``alpa_stage_mfu``/
         ``alpa_step_bubble_fraction``/``alpa_critical_path_us`` gauges.
-        None when no step has been recorded."""
+        None when no step has been recorded.
+
+        Those spans are the driver's, and a RUN is an asynchronous
+        enqueue.  Given a ``capture`` (``telemetry.trace.stop_capture``),
+        or with ``trace.last_capture()`` holding the recorder's last step,
+        the report is of the capture's last traced step on the device's
+        clock (``source == "device"``: each RUN the interval its program
+        ran on its mesh's chips, idle time by cause, collectives exposed
+        and hidden, ``alpa_step_idle_seconds``), or says in its ``notes``
+        why it could not be.  On a TPU only such a report's RUN costs go
+        to the calibration store."""
         from alpa_tpu.telemetry import perf as _perf
         stats = getattr(self, "last_dispatch_stats", None) or {}
         mode = stats.get("mode")
-        prog = self._register_programs.get(mode) if mode else None
-        joined = _perf.joined_from_recorder(_ttrace.get_recorder(), prog)
+        prog = self._last_program()
+        joined = None
+        held = capture if capture is not None else _ttrace.last_capture()
+        if held is not None and prog is not None and (
+                capture is not None or
+                _perf.last_step_start(held.spans) == _perf.last_step_start(
+                    _ttrace.get_recorder().spans()) is not None):
+            described = self._describe_pipeline()
+            steps = _perf.joined_from_capture(
+                held, prog, described["mesh_chips"],
+                described["run_programs"])
+            joined = steps[-1] if steps else None
+        if joined is None:
+            joined = _perf.joined_from_recorder(_ttrace.get_recorder(),
+                                                prog)
         if joined is None and _flight.enabled():
             joined = _perf.joined_from_flight(
                 _flight.get_recorder().snapshot(), prog)
@@ -1675,9 +1728,13 @@ class PipeshardDriverExecutable:
             # fold the measured step into the calibration store (ISSUE
             # 12): per-stage RUN costs and per-edge wire costs become
             # the drift gauges' samples and, under replan_mode, the
-            # planners' measured overrides
+            # planners' measured overrides.  On a TPU only from a step
+            # read on the device's clock: a host span around a RUN is
+            # the enqueue's time there, not the stage's
             from alpa_tpu.telemetry import calibration as _calibration
-            _calibration.ingest_joined(joined)
+            on_tpu = self.mesh_group[0].flat_devices[0].platform == "tpu"
+            if joined.source == "device" or not on_tpu:
+                _calibration.ingest_joined(joined)
         except Exception:  # pylint: disable=broad-except
             logger.exception("calibration ingest failed")
         return report

@@ -928,18 +928,26 @@ class RegisterFileProgram:
 _RUN_AHEAD = 2
 
 
-def _settle_run_ahead(queue):
+def _settle_run_ahead(queue, mesh=None):
     """Blocks until the oldest of a mesh's dispatched RUNs has finished,
     once ``_RUN_AHEAD`` of them are out.  ``queue`` holds one output of
     each (its smallest); one that a later RUN was given to donate is
-    gone, and so is the need to wait for it."""
+    gone, and so is the need to wait for it.  While tracing is on, a wait
+    that does block is the span ``pipeshard.run-ahead`` of ``mesh``: the
+    driver held by that mesh's queue, whatever else waits for it."""
     if len(queue) >= _RUN_AHEAD:
         token = queue.popleft()
         if not token.is_deleted():
-            token.block_until_ready()
+            if _ttrace.enabled() and not token.is_ready():
+                with _ttrace.span("pipeshard.run-ahead", "runtime",
+                                  {"mesh": mesh}):
+                    token.block_until_ready()
+            else:
+                token.block_until_ready()
 
 
-def _make_run_op(compiled, in_slots, out_slots, fixups, ahead, token):
+def _make_run_op(compiled, in_slots, out_slots, fixups, ahead, token,
+                 mesh=None):
     """RUN as a closure: gather args by slot index, call the compiled
     fast path, scatter outputs.  ``fixups`` carries the (rare) arg
     positions whose statically-tracked layout differs from the stage's
@@ -947,18 +955,19 @@ def _make_run_op(compiled, in_slots, out_slots, fixups, ahead, token):
     per-arg safety net, resolved at lowering instead of per call.
     ``ahead`` is the mesh's queue of dispatched RUNs
     (:func:`_settle_run_ahead`) and ``token`` the position of the output
-    that stands for this one in it (None: the program has no output)."""
+    that stands for this one in it (None: the program has no output);
+    ``mesh`` names the queue in the span of a wait for it."""
     if fixups:
 
         def op(regs, _c=compiled, _i=in_slots, _o=out_slots, _f=fixups,
-               _q=ahead, _t=token):
+               _q=ahead, _t=token, _m=mesh):
             import jax
             args = [regs[s] for s in _i]
             for pos, sh, ndim in _f:
                 a = args[pos]
                 if not a.sharding.is_equivalent_to(sh, ndim):
                     args[pos] = jax.device_put(a, sh)
-            _settle_run_ahead(_q)
+            _settle_run_ahead(_q, _m)
             outs = _c(*args)
             for s, o in zip(_o, outs):
                 regs[s] = o
@@ -967,8 +976,8 @@ def _make_run_op(compiled, in_slots, out_slots, fixups, ahead, token):
     else:
 
         def op(regs, _c=compiled, _i=in_slots, _o=out_slots, _q=ahead,
-               _t=token):
-            _settle_run_ahead(_q)
+               _t=token, _m=mesh):
+            _settle_run_ahead(_q, _m)
             outs = _c(*[regs[s] for s in _i])
             for s, o in zip(_o, outs):
                 regs[s] = o
@@ -1396,7 +1405,8 @@ def lower_to_register_file(
                 "op": _make_run_op(
                     ex.compiled, tuple(in_slots), tuple(out_slots),
                     tuple(fixups), run_ahead[inst.dst_mesh],
-                    sizes.index(min(sizes)) if sizes else None),
+                    sizes.index(min(sizes)) if sizes else None,
+                    mesh=inst.dst_mesh),
                 "reads": tuple(in_slots),
                 "writes": tuple(out_slots),
                 "kills": kills,

@@ -89,7 +89,10 @@ optimised HLO text is got.  Written when a program compiles, read only when
 a capture's table is made; a program that registered nothing is reduced
 by program only and all its time is ``unscoped``.  Programs that share a
 name are all kept, and the one that knows a run's instructions is taken.
-The registry holds its owners weakly.
+The registry holds its owners weakly.  A pipeshard executable also says,
+when it lowers its register program, what lays a step's RUN ops over the
+device events (:func:`register_pipeline`; ``telemetry/perf.py`` makes the
+join).
 
 jax's persistent compilation cache leaves metadata out of its key, so a
 program read back from a cache that an earlier tree filled keeps that
@@ -108,6 +111,7 @@ __all__ = [
     "PARTS", "UNSCOPED", "OUTSIDE_MODEL", "MIXED", "INHERITED", "part_of",
     "instruction_parts",
     "register_program", "registered_parts", "compiled_name",
+    "register_pipeline", "registered_pipelines",
     "read_profile", "reduce_events", "part_seconds", "empty_table",
 ]
 
@@ -149,10 +153,16 @@ PARTS = tuple(dict.fromkeys(
     list(_COMPONENTS.values()) + [p for _, p in _PATTERNS] +
     [COLLECTIVE, OUTSIDE_MODEL, UNSCOPED]))
 
-_COLLECTIVES = frozenset(
-    base + suffix for base in ("all-gather", "all-reduce", "reduce-scatter",
-                               "collective-permute", "all-to-all")
-    for suffix in ("", "-start", "-done"))
+_COLLECTIVE_BASES = ("all-gather", "all-reduce", "reduce-scatter",
+                     "collective-permute", "all-to-all")
+_COLLECTIVES = frozenset(base + suffix for base in _COLLECTIVE_BASES
+                         for suffix in ("", "-start", "-done"))
+# an asynchronous collective's two halves, by the name their events carry
+# (the TPU compiler calls a wrapped one ``async-collective-start.<n>``):
+# groups (kind, ``start`` or ``done``, number)
+ASYNC_COLLECTIVE = re.compile(
+    "^(async-collective|" + "|".join(_COLLECTIVE_BASES) +
+    r")-(start|done)((?:\.\d+)*)$")
 _HEAVY = ("dot", "convolution")
 _PALLAS = 'custom_call_target="tpu_custom_call"'
 
@@ -375,6 +385,38 @@ def registered_parts(name: str) -> List[Dict[str, Tuple[str, Any]]]:
     return found
 
 
+# [weak reference to a pipeshard executable, describe(executable)]
+_PIPELINES: List[list] = []
+
+
+def register_pipeline(owner: Any, describe: Callable[[Any], Any]) -> None:
+    """``describe(owner)`` is what a capture needs to lay a pipeshard
+    step's RUN ops over the device events (``telemetry/perf.py``
+    ``joined_from_capture``): ``{"program": the lowered program's hooks,
+    op_meta and dataflow graph, "mesh_chips": {mesh: chip ids},
+    "run_programs": {RUN op's name: its program's name}}``, or None while
+    there is nothing to describe.  Called when the executable lowers its
+    register program; ``owner`` is held weakly."""
+    if not any(entry[0]() is owner for entry in _PIPELINES):
+        _PIPELINES.append([weakref.ref(owner), describe])
+
+
+def registered_pipelines() -> List[Any]:
+    """What every live registered pipeline describes now: asked when a
+    capture stops (by then the benchmark's readers hold no executable),
+    it only gathers references."""
+    found = []
+    for entry in list(_PIPELINES):
+        owner = entry[0]()
+        if owner is None:
+            _PIPELINES.remove(entry)
+            continue
+        described = entry[1](owner)
+        if described is not None:
+            found.append(described)
+    return found
+
+
 def compiled_name(compiled) -> str:
     """The name the profiler gives the runs of a ``jax.stages.Compiled``:
     its HLO module's (``jit_train_step``)."""
@@ -428,7 +470,7 @@ def read_profile(path: str, marker: str):
 
 
 def empty_table(window_ns=None) -> dict:
-    return {"programs": {}, "busy_s": {},
+    return {"programs": {}, "busy_s": {}, "collectives": {},
             "window_us": None if window_ns is None else
             (window_ns[0] / 1e3, window_ns[1] / 1e3)}
 
@@ -452,19 +494,29 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
 
         {"programs": {chip: {program: {
              "runs": n, "run_s": [seconds of each run, in order],
+             "run_us": [(start, end) of each run, in the same order],
              "parts": {part: seconds}, "mixed_s": s, "inherited_s": s,
              "unscoped_s": s}}},
          "busy_s": {chip: seconds},
+         "collectives": {chip: [(instruction, start, end), in order]},
          "window_us": (lo, hi)}
 
     The window is ``window_ns`` (the capture's marker) or, without one,
     from the first device event to the last; ``window_us`` gives it on the
     profiler's clock.  ``busy_s`` is the time of the window some operation
     of the chip covers.  A program's table holds its runs that lie wholly
-    inside the window (a run the window cuts is in ``busy_s`` alone).  An
-    operation belongs to the run that contains it; one that holds others
-    (a ``while``, a ``conditional`` and the instructions of their bodies)
-    keeps its own time only, so that every instant is counted once.
+    inside the window (a run the window cuts is in ``busy_s`` alone), and
+    beside each run's seconds the instants it began and ended at, in
+    microseconds of the profiler's clock (``run_us``: what
+    ``telemetry/perf.py`` ``joined_from_capture`` gives a pipeshard step's
+    RUN ops).  ``collectives`` holds, by chip, every event of those runs
+    whose instruction is a collective (by opcode, or a fusion that holds
+    one: the part ``collective`` less what it inherits, a prefetch's
+    ``copy-done`` whose user is one), on the same clock: the intervals the
+    op line spends in one.  An operation belongs to the run that contains
+    it; one that holds others (a ``while``, a ``conditional`` and the
+    instructions of their bodies) keeps its own time only, so that every
+    instant is counted once.
     ``parts`` holds every second of the program's operations, those that
     found no name (or of a program that registered no text) under
     ``unscoped``, which ``unscoped_s`` repeats; ``mixed_s`` is the part of
@@ -492,20 +544,24 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
         programs = table["programs"][chip] = {}
         # program: the instructions a run held: instruction: ns
         own_ns: Dict[str, Dict[frozenset, Dict[str, float]]] = {}
+        # (program, its instructions): where in ``ops`` each such run lies
+        spans_of: Dict[Tuple[str, frozenset], List[Tuple[int, int]]] = {}
         runs = sorted((s, e, name) for name, s, e in runs
                       if lo <= s and e <= hi)
         ops = sorted((s, -e, name) for name, s, e in ops)
         at = 0
         for run_start, run_end, name in runs:
             entry = programs.setdefault(name, {
-                "runs": 0, "run_s": [], "parts": {}, "mixed_s": 0.0,
-                "inherited_s": 0.0, "unscoped_s": 0.0})
+                "runs": 0, "run_s": [], "run_us": [], "parts": {},
+                "mixed_s": 0.0, "inherited_s": 0.0, "unscoped_s": 0.0})
             entry["runs"] += 1
             entry["run_s"].append((run_end - run_start) / 1e9)
+            entry["run_us"].append((run_start / 1e3, run_end / 1e3))
             own: Dict[str, float] = {}
             open_ops = []           # [end, instruction] of operations open
             while at < len(ops) and ops[at][0] < run_start:
                 at += 1
+            first = at
             while at < len(ops) and ops[at][0] < run_end:
                 s, e, op = ops[at][0], min(-ops[at][1], run_end), ops[at][2]
                 while open_ops and open_ops[-1][0] <= s:
@@ -515,17 +571,26 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
                 own[op] = own.get(op, 0.0) + e - s
                 open_ops.append((e, op))
                 at += 1
-            alike = own_ns.setdefault(name, {}).setdefault(
-                frozenset(own), {})
+            instructions = frozenset(own)
+            spans_of.setdefault((name, instructions), []).append((first, at))
+            alike = own_ns.setdefault(name, {}).setdefault(instructions, {})
             for op, ns in own.items():
                 alike[op] = alike.get(op, 0.0) + ns
+        collectives = []
         for name, by_instructions in own_ns.items():
             registered = parts_of(name)
             entry = programs[name]
-            for own in by_instructions.values():
+            for instructions, own in by_instructions.items():
                 known = max(registered, default={}, key=lambda parts: (
                     sum(ns for op, ns in own.items() if op in parts),
                     -len(parts)))
+                moving = {op for op in own if known.get(op, (None, None))
+                          in ((COLLECTIVE, None), (COLLECTIVE, MIXED))}
+                if moving:
+                    collectives += [
+                        (ops[i][2], ops[i][0] / 1e3, -ops[i][1] / 1e3)
+                        for first, end in spans_of[name, instructions]
+                        for i in range(first, end) if ops[i][2] in moving]
                 for op, ns in own.items():
                     part, how = known.get(op, (UNSCOPED, None))
                     entry["parts"][part] = \
@@ -533,6 +598,8 @@ def reduce_events(chips: Dict[int, tuple], window_ns=None,
                     if how:
                         entry[how + "_s"] += ns / 1e9
             entry["unscoped_s"] = entry["parts"].get(UNSCOPED, 0.0)
+        table["collectives"][chip] = sorted(
+            collectives, key=lambda event: event[1])
     return table
 
 

@@ -36,14 +36,17 @@ from alpa_tpu.analysis.critical_path import (
     CriticalPathReport, TimedOp, measured_critical_path, simulate_dag,
     whatif as _whatif_dag)
 from alpa_tpu.global_env import global_config
+from alpa_tpu.telemetry import device_time as _device_time
 from alpa_tpu.telemetry import metrics as _tmetrics
+from alpa_tpu.telemetry.trace import CAPTURE_MARKER
 
 __all__ = [
     "device_peak_tflops", "peak_flops_info", "stage_flops",
     "compute_mfu", "mfu_from_time",
     "JoinedStep", "MeshBubbles", "TransferBreakdown", "StageMfu",
     "StepPerfReport",
-    "joined_from_recorder", "joined_from_flight", "spans_from_chrome",
+    "joined_from_recorder", "joined_from_flight", "joined_from_capture",
+    "pipeline_time", "IDLE_CAUSES", "spans_from_chrome",
     "build_step_report", "report_from_trace",
     "publish_report", "record_gate_verdict",
 ]
@@ -93,10 +96,19 @@ def mfu_from_time(flops: float, seconds: float, n_devices: int,
 
 def stage_flops(closed_jaxpr) -> float:
     """Analytic FLOPs of one stage invocation (``util.jaxpr_eqn_flops``
-    summed over the stage's closed jaxpr)."""
+    summed over the stage's closed jaxpr).  The backward stage of
+    rematerialised blocks holds its work in ``remat2`` equations (jax's
+    ``checkpoint``), which that function counts as one elementwise
+    operation (it also prices stages for the planner, so it stays as it
+    is): here their bodies count, the recomputation included."""
     from alpa_tpu.util import jaxpr_eqn_flops
-    return float(sum(jaxpr_eqn_flops(eqn)
-                     for eqn in closed_jaxpr.jaxpr.eqns))
+
+    def flops(eqn):
+        if eqn.primitive.name == "remat2":
+            return sum(flops(e) for e in eqn.params["jaxpr"].eqns)
+        return jaxpr_eqn_flops(eqn)
+
+    return float(sum(flops(eqn) for eqn in closed_jaxpr.jaxpr.eqns))
 
 
 ########################################
@@ -106,13 +118,43 @@ def stage_flops(closed_jaxpr) -> float:
 
 @dataclasses.dataclass
 class JoinedStep:
-    """One step's op samples on a common time axis, pre-report."""
+    """One step's op samples on a common time axis, pre-report.
+
+    A RUN is an asynchronous enqueue: under ``"trace"`` and ``"flight"`` an
+    op's interval is the driver's, from when it entered the op to when the
+    enqueue returned, which says what the host did and little of what the
+    chips did.  Under ``"device"`` (:func:`joined_from_capture`) every
+    instant is on the profiler's clock, a RUN op's interval is that of its
+    program's run on its mesh's chips, and the fields below the line hold
+    what the account of idle time needs beside it."""
     ops: List[TimedOp]
     t0_us: float
     envelope_us: float
     pool_spans: List[Dict[str, Any]]     # alpa-overlap-* track spans
-    source: str                          # "trace" | "flight"
+    source: str                          # "trace" | "flight" | "device"
     aligned: bool                        # ops joined 1:1 to program hooks
+    notes: List[str] = dataclasses.field(default_factory=list)
+    # ---- source == "device" alone ----
+    # the driver's instants of the same ops (entered, enqueue returned)
+    host_ops: Optional[List[TimedOp]] = None
+    # when the step's ``pipeshard.place-inputs`` returned
+    inputs_placed_us: float = 0.0
+    # (name, start, end) of every span of the capture that touches the
+    # envelope: what the driver was in while a chip waited for it
+    host_spans: List[Tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)
+    mesh_chips: Dict[str, List[int]] = dataclasses.field(
+        default_factory=dict)            # track -> the mesh's chips
+    # chip -> [(instruction, start, end)] of its collective events
+    collectives: Dict[int, List[tuple]] = dataclasses.field(
+        default_factory=dict)
+
+
+STEP_SPAN = "pipeshard.step"
+PLACE_INPUTS_SPAN = "pipeshard.place-inputs"
+RUN_AHEAD_SPAN = "pipeshard.run-ahead"
+# why a mesh idles, in the order an instant is tried against them
+IDLE_CAUSES = ("boundary", "upstream", "dispatch", "edge")
 
 
 def _kind_from_name(name: str) -> str:
@@ -123,16 +165,25 @@ def _kind_from_name(name: str) -> str:
     return "exec"
 
 
+def last_step_start(spans: Sequence[Dict[str, Any]]) -> Optional[float]:
+    """``ts_us`` of the newest ``pipeshard.step`` span, or None: a capture
+    holds the recorder's last step where the two agree."""
+    return max((s["ts_us"] for s in spans if s["name"] == STEP_SPAN),
+               default=None)
+
+
 def _join_spans(spans: Sequence[Dict[str, Any]],
-                program=None) -> Optional[JoinedStep]:
-    """Window the span list to the last ``pipeshard.step`` envelope and
+                program=None, step=None) -> Optional[JoinedStep]:
+    """Window the span list to the envelope of ``step`` (a
+    ``pipeshard.step`` span; the last one where none is given) and
     align the per-op spans positionally against the program's
     ``op_meta``/``hooks`` (both are emitted in replay order)."""
-    steps = [s for s in spans if s["name"] == "pipeshard.step"]
     w0 = w1 = None
-    if steps:
-        env = max(steps, key=lambda s: s["ts_us"])
-        w0, w1 = env["ts_us"], env["ts_us"] + env["dur_us"]
+    if step is None:
+        steps = [s for s in spans if s["name"] == STEP_SPAN]
+        step = max(steps, key=lambda s: s["ts_us"]) if steps else None
+    if step is not None:
+        w0, w1 = step["ts_us"], step["ts_us"] + step["dur_us"]
 
     def in_window(s):
         return w0 is None or (s["ts_us"] >= w0 - 1.0 and
@@ -212,6 +263,132 @@ def joined_from_flight(events: Sequence[Any],
                       pool_spans=[], source="flight", aligned=aligned)
 
 
+def _is_run(op: TimedOp) -> bool:
+    return op.kind == "exec" and op.name.startswith("RUN ")
+
+
+def joined_from_capture(capture, program, mesh_chips: Dict[int, Sequence[int]],
+                        run_programs: Optional[Dict[str, str]] = None
+                        ) -> List[JoinedStep]:
+    """Join every ``pipeshard.step`` of a
+    :class:`~alpa_tpu.telemetry.trace.Capture` on the device's clock: one
+    :class:`JoinedStep` a traced step, oldest first.
+
+    Each step is joined as :func:`joined_from_recorder` joins the last one,
+    every instant shifted by ``capture.offset_us()``; then each RUN op takes
+    the interval its program ran on its mesh's chips: the k-th ``RUN
+    stage_0_bwd`` on track ``mesh 0`` inside the step is the k-th run of
+    ``jit_stage_0_bwd`` on each of that mesh's chips inside it, from the
+    earliest start to the latest end over them
+    (``Capture.device_time()``'s ``run_us``).  ``mesh_chips`` maps a mesh's
+    index to its chips' ids, ``run_programs`` a RUN op's name to the name
+    the profiler gives its program's runs (``jit_`` and the stage's name
+    where it says nothing).  The ops that run no program keep the driver's
+    instants, and ``host_ops`` keeps them for the RUN ops too.  A step's
+    envelope runs from its ``pipeshard.step`` span's start to the next
+    step's, and the last traced step's to the end of its last run: the span
+    itself ends when the driver returns, before the chips do.
+
+    Where a capture holds no device event (the CPU), the spans do not line
+    up with ``program``, or a chip ran a program another number of times
+    than the step has RUN ops for it, the step is joined on the host's
+    clock (``source == "trace"``) and its ``notes`` say why: a count that
+    does not match gives no account, never a guessed one."""
+    steps = sorted((s for s in capture.spans if s["name"] == STEP_SPAN),
+                   key=lambda s: s["ts_us"])
+    try:
+        table = capture.device_time()
+        shift = capture.offset_us() if table["programs"] else None
+    except (FileNotFoundError, ValueError):     # no trace, or no marker
+        table, shift = None, None
+    joined = []
+    for k, step in enumerate(steps):
+        host = _join_spans(capture.spans, program, step)
+        if host is None:
+            continue
+        if shift is not None:
+            until = (steps[k + 1]["ts_us"] + shift if k + 1 < len(steps)
+                     else None)
+            host = _on_device_clock(host, capture, table, shift, until,
+                                    mesh_chips, run_programs or {})
+        joined.append(host)
+    return joined
+
+
+def _on_device_clock(host: JoinedStep, capture, table, shift: float,
+                     until: Optional[float], mesh_chips, run_programs
+                     ) -> JoinedStep:
+    """``host`` with its RUN ops on the device's clock (see
+    :func:`joined_from_capture`), or ``host`` itself with a note."""
+    if not host.aligned:
+        host.notes.append(
+            "device join: the spans did not align 1:1 with the lowered "
+            "program; the report reads the host's clock")
+        return host
+    t0 = host.t0_us + shift
+    shifted = [dataclasses.replace(o, t0_us=o.t0_us + shift,
+                                   t1_us=o.t1_us + shift)
+               for o in host.ops]
+    # (track, program) -> the RUN ops of it, in the driver's order
+    asked: Dict[Tuple[str, str], List[int]] = {}
+    for o in shifted:
+        if _is_run(o):
+            name = run_programs.get(o.name, "jit_" + o.name[4:])
+            asked.setdefault((o.track, name), []).append(o.idx)
+    tracks = {f"mesh {m}": list(chips) for m, chips in mesh_chips.items()}
+    ops = list(shifted)
+    for (track, name), idxs in asked.items():
+        found = []          # a chip: its runs of the program in the step
+        for chip in tracks.get(track, ()):
+            runs = table["programs"].get(chip, {}).get(name, {}).get(
+                "run_us", ())
+            found.append([r for r in runs if t0 <= r[0] and
+                          (until is None or r[0] < until)])
+            if len(found[-1]) != len(idxs):
+                host.notes.append(
+                    f"device join: chip {chip} ran {name} "
+                    f"{len(found[-1])} times inside the step and {track} "
+                    f"has {len(idxs)} {shifted[idxs[0]].name} ops; the "
+                    "report reads the host's clock")
+                return host
+        if not found:
+            host.notes.append(f"device join: {track} has no chips in "
+                              f"{sorted(tracks)}; the report reads the "
+                              "host's clock")
+            return host
+        for k, i in enumerate(idxs):
+            ops[i] = dataclasses.replace(
+                ops[i], t0_us=min(runs[k][0] for runs in found),
+                t1_us=max(runs[k][1] for runs in found))
+    end = until if until is not None else max(
+        (o.t1_us for o in ops if _is_run(o)), default=t0)
+    placed = [s for s in capture.spans if s["name"] == PLACE_INPUTS_SPAN and
+              host.t0_us <= s["ts_us"] <= host.t0_us + host.envelope_us]
+    return JoinedStep(
+        ops=ops, t0_us=t0, envelope_us=end - t0,
+        pool_spans=[dict(s, ts_us=s["ts_us"] + shift)
+                    for s in host.pool_spans],
+        source="device", aligned=True, notes=host.notes, host_ops=shifted,
+        inputs_placed_us=(placed[0]["ts_us"] + placed[0]["dur_us"] + shift
+                          if placed else t0),
+        host_spans=[(_span_label(s), s["ts_us"] + shift,
+                     s["ts_us"] + s["dur_us"] + shift)
+                    for s in capture.spans
+                    if s["name"] != CAPTURE_MARKER and s["dur_us"] > 0 and
+                    s["ts_us"] + shift < end and
+                    s["ts_us"] + s["dur_us"] + shift > t0],
+        mesh_chips=tracks,
+        collectives={chip: [c for c in events if c[2] > t0 and c[1] < end]
+                     for chip, events in table["collectives"].items()})
+
+
+def _span_label(span: Dict[str, Any]) -> str:
+    """A span's name, a driver's wait for a mesh's queue with the mesh."""
+    if span["name"] == RUN_AHEAD_SPAN and span.get("args"):
+        return f"{RUN_AHEAD_SPAN} (mesh {span['args'].get('mesh')})"
+    return span["name"]
+
+
 def _op_dependencies(program, n_ops: int
                      ) -> Tuple[Dict[int, set], List[set]]:
     """Map dataflow-graph edges into op space.
@@ -271,6 +448,18 @@ class MeshBubbles:
     sched_warmup_ticks: Optional[int] = None
     sched_drain_ticks: Optional[int] = None
     sched_num_clock: Optional[int] = None
+    # ---- on the device's clock alone (a report whose source is "device"):
+    # every idle instant of the envelope by cause (``IDLE_CAUSES``), so
+    # that busy_us + sum(idle_us.values()) == envelope_us
+    idle_us: Optional[Dict[str, float]] = None
+    # the ``dispatch`` part by the shortest span open on the host then
+    dispatch_by_span: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
+    n_chips: int = 0
+    # mean over the mesh's chips: the op line in a collective, and between
+    # an asynchronous collective's ``-start`` and its ``-done``
+    collective_exposed_us: float = 0.0
+    collective_hidden_us: float = 0.0
 
     def fractions(self) -> Dict[str, float]:
         e = self.envelope_us or 1.0
@@ -314,7 +503,9 @@ class StageMfu:
 
 @dataclasses.dataclass
 class StepPerfReport:
-    source: str                   # "trace" | "flight"
+    # the clock the RUN ops were read on: "device" (a capture's device
+    # events) or the host's, around an enqueue ("trace" | "flight")
+    source: str
     mode: Optional[str]
     envelope_us: float
     n_ops: int
@@ -374,7 +565,17 @@ class StepPerfReport:
                     "n_ops": b.n_ops,
                     "stream_wait_us": round(b.stream_wait_us, 3),
                     **{f"{k}_fraction": round(v, 4)
-                       for k, v in b.fractions().items()}}
+                       for k, v in b.fractions().items()},
+                    **({} if b.idle_us is None else {
+                        "idle_us": {c: round(v, 3)
+                                    for c, v in b.idle_us.items()},
+                        "dispatch_by_span": {
+                            n: round(v, 3)
+                            for n, v in b.dispatch_by_span.items()},
+                        "collective_exposed_us":
+                            round(b.collective_exposed_us, 3),
+                        "collective_hidden_us":
+                            round(b.collective_hidden_us, 3)})}
                 for m, b in sorted(self.bubbles.items())
             },
             "transfers": {
@@ -402,8 +603,10 @@ class StepPerfReport:
     # ---- text report (perf_report.txt) ------------------------------
 
     def format_text(self, top: int = 10) -> str:
+        clock = ("the device's clock" if self.source == "device" else
+                 "the host's clock: a RUN's time is its enqueue's")
         lines = [
-            f"step perf report ({self.source}"
+            f"step perf report ({self.source}: {clock}"
             f"{', mode=' + self.mode if self.mode else ''}"
             f"{', graph-joined' if self.aligned else ', track-order only'}"
             f"): {self.n_ops} ops over {self.envelope_us:.1f} us",
@@ -422,6 +625,26 @@ class StepPerfReport:
                 f"  {m:<8} {f['busy']:7.3f} {f['warmup']:7.3f} "
                 f"{f['steady_idle']:7.3f} {f['drain']:7.3f} "
                 f"{b.bubble_fraction:7.3f} {b.n_ops:5d} {sched:>10}")
+        if any(b.idle_us is not None for b in self.bubbles.values()):
+            lines += [
+                "",
+                "idle by cause (fractions of the step envelope), and the "
+                "op line in a collective:",
+                f"  {'mesh':<8} " + " ".join(f"{c:>9}" for c in IDLE_CAUSES)
+                + f" {'exposed':>9} {'hidden':>9}"]
+            for m, b in sorted(self.bubbles.items()):
+                if b.idle_us is None:
+                    continue
+                e = b.envelope_us or 1.0
+                lines.append(
+                    f"  {m:<8} " + " ".join(
+                        f"{b.idle_us[c] / e:9.3f}" for c in IDLE_CAUSES) +
+                    f" {b.collective_exposed_us / e:9.3f}"
+                    f" {b.collective_hidden_us / e:9.3f}")
+                for name, us in sorted(b.dispatch_by_span.items(),
+                                       key=lambda kv: -kv[1])[:4]:
+                    lines.append(f"  {'':<8} dispatch under {name}: "
+                                 f"{us:.1f} us")
         t = self.transfers
         lines += [
             "",
@@ -515,6 +738,167 @@ def _mesh_bubbles(ops: Sequence[TimedOp], t0_us: float,
     return out
 
 
+def _upstream_runs(ops: Sequence[TimedOp], causal: Dict[int, set]
+                   ) -> Dict[int, List[int]]:
+    """For every RUN op, the RUN ops on OTHER meshes it cannot start
+    before: its causal predecessors, followed back through the ops that
+    run no program (the RESHARD that carries a value, its LAUNCH and WAIT,
+    a FREE) and no further than the first RUN on each way."""
+    out = {}
+    for o in ops:
+        if not _is_run(o):
+            continue
+        found, seen, stack = [], set(), list(causal.get(o.idx, ()))
+        while stack:
+            j = stack.pop()
+            if j in seen:
+                continue
+            seen.add(j)
+            if _is_run(ops[j]):
+                if ops[j].track != o.track:
+                    found.append(j)
+            else:
+                stack.extend(causal.get(j, ()))
+        out[o.idx] = found
+    return out
+
+
+def _by_shortest_span(spans: Sequence[Tuple[str, float, float]],
+                      lo: float, hi: float) -> Dict[str, float]:
+    """{name: microseconds} of [lo, hi]: each instant under the shortest
+    of ``spans`` (name, start, end) open then, of two as short the first in
+    the alphabet (the rule of the benchmark's ``idle_gaps``); what no span
+    covers under ``unattributed``."""
+    open_ = [(e - s, n, s, e) for n, s, e in spans if s < hi and e > lo]
+    cuts = sorted({lo, hi, *(t for _, _, s, e in open_ for t in (s, e)
+                             if lo < t < hi)})
+    out: Dict[str, float] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        name = min(((d, n) for d, n, s, e in open_ if s <= a and e >= b),
+                   default=(0.0, "unattributed"))[1]
+        out[name] = out.get(name, 0.0) + b - a
+    return out
+
+
+def _covered_us(intervals, lo: float, hi: float) -> float:
+    """Microseconds of [lo, hi] that some (start, end) of ``intervals``
+    covers."""
+    total, at = 0.0, lo
+    for s, e in sorted(intervals):
+        s, e = max(s, at), min(e, hi)
+        if e > s:
+            total += e - s
+            at = e
+    return total
+
+
+def _collective_time(events: Sequence[tuple], lo: float, hi: float
+                     ) -> Tuple[float, float]:
+    """(exposed, hidden) microseconds of [lo, hi] on one chip, from its
+    collective events (instruction, start, end) in order of start.
+    Exposed: the op line has a collective open, so the core does nothing
+    else (a synchronous ``all-gather``, a ``-done`` that waits).  Hidden:
+    some asynchronous collective is between the end of its ``-start`` and
+    the start of its ``-done``, where the op line runs other work.  A
+    ``-done`` is paired with the oldest open ``-start`` of its name
+    (``async-collective-start.3``, ``async-collective-done.3``), else of
+    its kind."""
+    pending: List[Tuple[str, str, float]] = []  # (kind, number, -start's end)
+    flights = []
+    for name, s, e in events:
+        half = _device_time.ASYNC_COLLECTIVE.match(name)
+        if half is None:
+            continue
+        kind, which, number = half.groups()
+        if which == "start":
+            pending.append((kind, number, e))
+            continue
+        found = next((p for p in pending if p[:2] == (kind, number)),
+                     next((p for p in pending if p[0] == kind), None))
+        if found is not None:
+            pending.remove(found)
+            flights.append((found[2], s))
+    return (_covered_us([(s, e) for _, s, e in events], lo, hi),
+            _covered_us(flights, lo, hi))
+
+
+def _device_bubbles(joined: JoinedStep, causal: Dict[int, set],
+                    schedule=None) -> Dict[str, MeshBubbles]:
+    """Per-mesh account of a step joined on the device's clock: a mesh is
+    busy while one of its RUN ops' programs runs, and every other instant
+    of the envelope goes to one cause.
+
+    The gap before RUN *i* on a mesh (from the end of the mesh's previous
+    run, or the envelope's start, to the run's start) is cut at three
+    instants, and each stretch goes to the first cause that holds:
+
+    ``boundary``  before the step's ``pipeshard.place-inputs`` returned
+                  (the inputs' ``device_put``, the zeroed accumulators,
+                  what the host did between two steps);
+    ``upstream``  a RUN on ANOTHER mesh that RUN *i* depends on
+                  (:func:`_upstream_runs`) has not finished on the device:
+                  the pipeline's own dependency (fill, drain, every
+                  steady-state bubble);
+    ``dispatch``  those have finished and the enqueue of RUN *i* has not
+                  returned: the chip waits for the driver
+                  (``dispatch_by_span`` says what the driver was in);
+    ``edge``      what is left: predecessors done, RUN enqueued, program
+                  not started (the cross-mesh move and the launch).
+
+    After a mesh's last run: ``upstream`` until the step's last run on any
+    mesh has ended, ``boundary`` from there to the envelope's end."""
+    ops, host = joined.ops, joined.host_ops
+    t0, t1 = joined.t0_us, joined.t0_us + joined.envelope_us
+    upstream = _upstream_runs(ops, causal)
+    placed = joined.inputs_placed_us
+    last_end = max((o.t1_us for o in ops if _is_run(o)), default=t0)
+    host_bubbles = _mesh_bubbles(host, t0, joined.envelope_us, schedule)
+    out: Dict[str, MeshBubbles] = {}
+    for track, hb in host_bubbles.items():
+        runs = sorted((o for o in ops if o.track == track and _is_run(o)),
+                      key=lambda o: o.t0_us)
+        idle = dict.fromkeys(IDLE_CAUSES, 0.0)
+        by_span: Dict[str, float] = {}
+        busy, at = 0.0, t0      # ``at``: the envelope is accounted to here
+        for o in runs:
+            start, end = min(max(o.t0_us, t0), t1), min(o.t1_us, t1)
+            if start > at:
+                done = max([placed] + [ops[j].t1_us
+                                       for j in upstream[o.idx]])
+                cuts = [min(max(c, at), start) for c in
+                        (placed, done, max(done, host[o.idx].t1_us))]
+                idle["boundary"] += cuts[0] - at
+                idle["upstream"] += cuts[1] - cuts[0]
+                idle["dispatch"] += cuts[2] - cuts[1]
+                idle["edge"] += start - cuts[2]
+                if cuts[2] > cuts[1]:
+                    for name, us in _by_shortest_span(
+                            joined.host_spans, cuts[1], cuts[2]).items():
+                        by_span[name] = by_span.get(name, 0.0) + us
+                at = start
+            if end > at:
+                busy += end - at
+                at = end
+        first = min(max(runs[0].t0_us, t0), t1) if runs else t1
+        drain_from = at
+        if t1 > at:
+            cut = min(max(last_end, at), t1)
+            idle["upstream"] += cut - at
+            idle["boundary"] += t1 - cut
+        chips = joined.mesh_chips.get(track, [])
+        moving = [_collective_time(joined.collectives.get(c, ()), t0, t1)
+                  for c in chips]
+        exposed, hidden = (sum(part) / len(chips) for part in zip(*moving)) \
+            if moving else (0.0, 0.0)
+        warmup, drain = first - t0, t1 - drain_from
+        out[track] = dataclasses.replace(
+            hb, busy_us=busy, warmup_us=warmup, drain_us=drain,
+            steady_idle_us=max(0.0, t1 - t0 - busy - warmup - drain),
+            idle_us=idle, dispatch_by_span=by_span, n_chips=len(chips),
+            collective_exposed_us=exposed, collective_hidden_us=hidden)
+    return out
+
+
 def _transfer_breakdown(ops: Sequence[TimedOp],
                         pool_spans: Sequence[Dict[str, Any]],
                         run_stats: Optional[Dict[str, Any]] = None
@@ -605,7 +989,7 @@ def build_step_report(joined: JoinedStep, program=None, schedule=None,
     ``schedule`` keys the warmup/drain bubble expectation;
     ``stage_execs`` enable MFU attribution."""
     ops = joined.ops
-    notes: List[str] = []
+    notes: List[str] = list(joined.notes)
     causal: Dict[int, set] = {}
     if joined.aligned and program is not None and \
             program.graph is not None:
@@ -624,8 +1008,11 @@ def build_step_report(joined: JoinedStep, program=None, schedule=None,
             last_on_track[o.track] = i
     cp = measured_critical_path(ops, causal,
                                 envelope_us=joined.envelope_us)
-    bubbles = _mesh_bubbles(ops, joined.t0_us, joined.envelope_us,
-                            schedule)
+    if joined.source == "device":
+        bubbles = _device_bubbles(joined, causal, schedule)
+    else:
+        bubbles = _mesh_bubbles(ops, joined.t0_us, joined.envelope_us,
+                                schedule)
     transfers = _transfer_breakdown(ops, joined.pool_spans, run_stats)
     stages = _stage_mfu(ops, stage_execs, peak_tflops)
     return StepPerfReport(
@@ -636,6 +1023,46 @@ def build_step_report(joined: JoinedStep, program=None, schedule=None,
         sim_durs_us=[o.dur_us for o in ops],
         sim_preds=[tuple(sorted(p)) for p in sim_preds],
         sim_ops=list(ops))
+
+
+########################################
+# the account a capture gives with no executable at hand
+########################################
+
+
+def pipeline_time(capture, pipelines: Sequence[Dict[str, Any]]
+                  ) -> Dict[str, Dict[str, Any]]:
+    """What :meth:`Capture.pipeline_time` returns: by mesh, seconds summed
+    over the traced steps that joined on the device's clock.
+    ``pipelines`` is what the capture kept of each pipeshard executable
+    that had lowered its program when it stopped
+    (``device_time.register_pipeline``): ``{"program", "mesh_chips",
+    "run_programs"}`` as :func:`joined_from_capture` takes them."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for kept in pipelines:
+        program = kept["program"]
+        for joined in joined_from_capture(capture, program,
+                                          kept["mesh_chips"],
+                                          kept["run_programs"]):
+            if joined.source != "device" or program.graph is None:
+                continue
+            causal, _ = _op_dependencies(program, len(joined.ops))
+            for track, b in _device_bubbles(joined, causal).items():
+                acc = out.setdefault(track, {
+                    "chips": b.n_chips, "envelope_s": 0.0, "busy_s": 0.0,
+                    **{f"{c}_s": 0.0 for c in IDLE_CAUSES},
+                    "collective_exposed_s": 0.0,
+                    "collective_hidden_s": 0.0, "dispatch_by_span": {}})
+                acc["envelope_s"] += b.envelope_us / 1e6
+                acc["busy_s"] += b.busy_us / 1e6
+                for c in IDLE_CAUSES:
+                    acc[f"{c}_s"] += b.idle_us[c] / 1e6
+                acc["collective_exposed_s"] += b.collective_exposed_us / 1e6
+                acc["collective_hidden_s"] += b.collective_hidden_us / 1e6
+                for name, us in b.dispatch_by_span.items():
+                    acc["dispatch_by_span"][name] = \
+                        acc["dispatch_by_span"].get(name, 0.0) + us / 1e6
+    return out
 
 
 ########################################
@@ -700,6 +1127,11 @@ _BUBBLE_GAUGE = _PERF_REG.gauge(
     "alpa_step_bubble_fraction",
     "Last analyzed step's per-mesh idle fraction of the step envelope",
     labelnames=("mesh",))
+_IDLE_GAUGE = _PERF_REG.gauge(
+    "alpa_step_idle_seconds",
+    "Last analyzed step's idle seconds per mesh by cause (boundary, "
+    "upstream, dispatch, edge), from a capture's device events",
+    labelnames=("mesh", "cause"))
 _CRITICAL_PATH_GAUGE = _PERF_REG.gauge(
     "alpa_critical_path_us",
     "Last analyzed step's measured critical-path op time")
@@ -715,6 +1147,8 @@ def publish_report(report: StepPerfReport) -> None:
     for track, b in report.bubbles.items():
         label = track.split()[1] if track.startswith("mesh ") else track
         _BUBBLE_GAUGE.labels(label).set(b.bubble_fraction)
+        for cause, us in (b.idle_us or {}).items():
+            _IDLE_GAUGE.labels(label, cause).set(us / 1e6)
     for name, s in report.stages.items():
         _STAGE_MFU_GAUGE.labels(name).set(s.mfu)
 

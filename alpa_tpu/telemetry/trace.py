@@ -379,6 +379,10 @@ class Capture:
     marker_ts_us: float
     _offset_us: Optional[float] = None
     _device_time: Optional[Dict[str, Any]] = None
+    # what the pipeshard executables alive when the capture stopped said of
+    # their programs (``device_time.registered_pipelines``)
+    _pipelines: Any = ()
+    _pipeline_time: Optional[Dict[str, Any]] = None
 
     def xplane_path(self) -> str:
         found = sorted(glob.glob(os.path.join(
@@ -418,6 +422,29 @@ class Capture:
         if self._device_time is None:
             self._read()
         return self._device_time
+
+    def pipeline_time(self) -> Dict[str, Any]:
+        """The traced pipeshard steps' account on the device's clock, by
+        mesh (``"mesh 0"``), in seconds summed over the steps::
+
+            {mesh: {"chips": n, "envelope_s", "busy_s", "boundary_s",
+                    "upstream_s", "dispatch_s", "edge_s",
+                    "collective_exposed_s", "collective_hidden_s",
+                    "dispatch_by_span": {span: seconds}}}
+
+        ``busy_s`` and the four causes of idleness add up to
+        ``envelope_s`` (``telemetry/perf.py`` ``_device_bubbles`` says what
+        each cause is); the two collective times are means over the mesh's
+        chips.  ``{}`` where no pipeshard step was traced or none joined
+        (the CPU: no device events).  Made when first asked for, from what
+        :func:`stop_capture` read and kept; reads nothing again."""
+        if self._pipeline_time is None:
+            self._pipeline_time = {}
+            if self._pipelines and self._device_time is not None:
+                from alpa_tpu.telemetry import perf
+                self._pipeline_time = perf.pipeline_time(self,
+                                                         self._pipelines)
+        return self._pipeline_time
 
 
 # (log_dir, enabled() before, the marker's annotation, its start)
@@ -475,7 +502,8 @@ def stop_capture() -> Capture:
         jax.profiler.stop_trace()
     finally:
         set_enabled(was)
-    capture = Capture(log_dir, _RECORDER.spans(), ts)
+    capture = Capture(log_dir, _RECORDER.spans(), ts,
+                      _pipelines=device_time.registered_pipelines())
     try:
         capture._read()     # pylint: disable=protected-access
     except FileNotFoundError:   # the profiler wrote nothing: asked again,

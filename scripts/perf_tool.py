@@ -24,7 +24,10 @@ the same ``(analysis, code)``-set semantics the acceptance gate uses.
 ``analyze`` prints the full :class:`StepPerfReport` (critical path,
 per-mesh bubble fractions, transfer overlap, stage MFU where RUN spans
 carry stage names) for the last ``pipeshard.step`` envelope in the
-trace; ``critical-path`` prints just the path table; ``whatif``
+trace, and says on its first line which clock it read: a saved Chrome
+trace holds the driver's spans only, so it is the host's, where a RUN's
+time is its enqueue's (``get_perf_report(capture)`` in the process reads
+the device's); ``critical-path`` prints just the path table; ``whatif``
 re-simulates the step with an op class made free ("if this RESHARD were
 free, step −X%"); ``compare`` diffs two analyzed traces metric by
 metric (the interactive sibling of ``benchmark/perf_gate.py``, which

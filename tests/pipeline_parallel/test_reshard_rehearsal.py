@@ -96,3 +96,53 @@ def test_a_step_fetches_nothing_and_matches_the_direct_path(
     direct = _two_steps(tmp_path, "direct_p2p")
     assert fetched_in_replay != []
     assert losses == direct
+
+
+def test_a_traced_toy_step_has_no_account_on_the_cpu(tmp_path,
+                                                     four_devices,
+                                                     monkeypatch):
+    """``--trace 2`` of the toy cell: the capture holds the steps' spans
+    and no device event (a CPU trace has no TPU plane), so the account by
+    mesh is empty, the six readers of it find nothing, and the report is
+    read on the host's clock and says so (ISSUE 49)."""
+    from alpa_tpu.telemetry import trace as ttrace
+    train = run.load_module("drivers", "train")
+    held = []
+    describe = train._describe
+    monkeypatch.setattr(train, "_describe", lambda ctx, executable: (
+        held.append(executable), describe(ctx, executable)))
+    ctx = run.Context(
+        cell={"name": "toy-gpt-pipeshard.train",
+              "config": "toy-gpt-pipeshard", "traffic": "toy-lm",
+              "chips": 4},
+        config=TOY, mix=traffic.load_mix("toy-lm"), seed=2147483659,
+        seconds=0.0, trace=2, rehearsal=True, spans=observe.Spans(),
+        compile_events=observe.CompileEvents(),
+        trace_dir=str(tmp_path / "trace"))
+    try:
+        obs = train.run(ctx)
+        assert obs["failed"] == 0 and obs["checks"]["all_finite"]
+        capture = ttrace.last_capture()
+        steps = [s for s in capture.spans if s["name"] == "pipeshard.step"]
+        assert len(steps) == ctx.mix["trace_steps"]
+        # the executable said what a capture needs of it, and the capture
+        # kept it: only the device events are missing
+        (executable,) = held
+        hooks = executable._last_program().hooks
+        assert any(kept["program"].hooks is hooks
+                   for kept in capture._pipelines)
+        assert capture.device_time()["programs"] == {}
+        assert capture.pipeline_time() == {}
+        for name in ("mesh_idle_max_pct", "pipeline_bubble_pct",
+                     "dispatch_starved_pct", "edge_exposed_pct",
+                     "step_boundary_idle_pct", "collective_exposed_pct"):
+            assert run.metric_reader(name)(obs) is None
+        report = executable.get_perf_report()
+        assert report.source == "trace" and report.aligned, report.notes
+        assert "the host's clock" in report.format_text().splitlines()[0]
+        assert all(b.idle_us is None for b in report.bubbles.values())
+        # given the capture itself: the same step, the same clock
+        assert executable.get_perf_report(capture).source == "trace"
+    finally:
+        ttrace.set_enabled(False)
+        alpa_tpu.shutdown()

@@ -391,7 +391,8 @@ def test_without_a_marker_the_window_is_the_events_and_none_is_empty():
     assert table["window_us"] == (0.1, 0.2)
     assert table["programs"][0]["jit_p"]["runs"] == 1
     assert dt.reduce_events({}, None) == dt.empty_table() == \
-        {"programs": {}, "busy_s": {}, "window_us": None}
+        {"programs": {}, "busy_s": {}, "collectives": {},
+         "window_us": None}
 
 
 # ---- one trace recorded on the chip, with its HLO text ---------------------

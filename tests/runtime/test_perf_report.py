@@ -205,6 +205,25 @@ class TestMfu:
         got = perf.stage_flops(closed)
         assert matmuls <= got <= matmuls * 1.2       # + relu elementwise
 
+    def test_stage_flops_counts_a_rematerialised_blocks_backward(self):
+        import jax
+        import jax.numpy as jnp
+        w = jnp.ones((16, 16), jnp.float32)
+
+        def block(x):
+            return jnp.sum(jnp.tanh(x @ w) @ w)
+
+        x = jnp.ones((8, 16), jnp.float32)
+        plain = perf.stage_flops(jax.make_jaxpr(jax.grad(block))(x))
+        closed = jax.make_jaxpr(jax.grad(jax.checkpoint(block)))(x)
+        assert any(e.primitive.name == "remat2" for e in closed.jaxpr.eqns)
+        # the two products forward, their input gradients, and the
+        # recomputation of the first: not the one elementwise operation a
+        # ``remat2`` equation counts as elsewhere
+        matmul = 2 * 8 * 16 * 16
+        assert plain >= 3 * matmul
+        assert perf.stage_flops(closed) >= plain
+
     def test_knob_overrides_generation_peak(self):
         prev = global_config.device_peak_tflops
         try:
