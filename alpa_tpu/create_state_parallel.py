@@ -58,7 +58,34 @@ class CreateStateParallel(ParallelMethod):
 def _compile_create_state_pipeshard(fun, in_avals, pipeshard_exec):
     """Pipeshard target: every state leaf must materialize on the mesh its
     consuming stage lives on (ref compile_create_state_executable:73 /
-    propagate_mesh_assignment:151)."""
+    propagate_mesh_assignment:151), in the sharding the step reads it
+    with.  One program a mesh, whose outputs are that mesh's leaves with
+    their shardings (the compiler drops what the others need): no leaf is
+    first made whole on one device and re-laid out from there, which held
+    the whole state, and a second copy of every leaf that is sharded, on
+    the first device."""
+    gin = pipeshard_exec.global_invars
+    place = pipeshard_exec.input_place
+    n_out = len(jax.eval_shape(fun, *in_avals))
+    # mesh (None: a leaf the step places nowhere) -> [(leaf, sharding)]
+    by_mesh = {}
+    for i in range(n_out):
+        v = gin[i] if i < len(gin) else None
+        mesh_id, sharding = place[v][0] if v in place else (None, None)
+        by_mesh.setdefault(mesh_id, []).append((i, sharding))
+
+    def program(mesh_id, members):
+        keep = [i for i, _ in members]
+
+        def leaves(*flat_args):
+            outs = fun(*flat_args)
+            return [outs[i] for i in keep]
+
+        if mesh_id is None:
+            return keep, jax.jit(leaves)
+        return keep, jax.jit(leaves, out_shardings=[s for _, s in members])
+
+    programs = [program(m, members) for m, members in by_mesh.items()]
 
     class _CreateStatePipeshardExecutable:
 
@@ -67,19 +94,10 @@ def _compile_create_state_pipeshard(fun, in_avals, pipeshard_exec):
             self.in_avals = in_avals
 
         def launch_on_driver(self, *flat_args):
-            outs_host = jax.jit(fun)(*flat_args)
-            # place each leaf per the pipeshard input placement
-            flat_outs = list(outs_host)
-            placed = []
-            gin = pipeshard_exec.global_invars
-            place = pipeshard_exec.input_place
-            for i, x in enumerate(flat_outs):
-                v = gin[i] if i < len(gin) else None
-                if v is not None and v in place:
-                    mesh_id, sharding = place[v][0]
-                    placed.append(jax.device_put(x, sharding))
-                else:
-                    placed.append(x)
+            placed = [None] * n_out
+            for keep, jitted in programs:
+                for i, x in zip(keep, jitted(*flat_args)):
+                    placed[i] = x
             return placed
 
     return _CreateStatePipeshardExecutable()

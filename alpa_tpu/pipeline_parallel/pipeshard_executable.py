@@ -35,7 +35,8 @@ from alpa_tpu.pipeline_parallel.runtime_emitter import (
     partition_streams)
 from alpa_tpu.pipeline_parallel.schedules import create_pipeline_schedule
 from alpa_tpu.shard_parallel import kernel_choice
-from alpa_tpu.shard_parallel.auto_sharding import MESH_AXIS_NAMES
+from alpa_tpu.shard_parallel.auto_sharding import (MESH_AXIS_NAMES,
+                                                  resolved_zero_stage)
 from alpa_tpu.telemetry import device_time as _device_time
 from alpa_tpu.telemetry import flight as _flight
 from alpa_tpu.telemetry import metrics as _tmetrics
@@ -81,7 +82,7 @@ class StageExecutable:
 
     def __init__(self, name, comp, mesh_id, physical_mesh, as_option,
                  logical_shape, donate_idx, as_overrides=None,
-                 in_paths=None):
+                 in_paths=None, fixed_in=None, state_pairs=()):
         self.name = name
         self.comp = comp
         self.mesh_id = mesh_id
@@ -96,6 +97,14 @@ class StageExecutable:
         # stage-internal values) — lets the per-stage planner classify
         # optimizer-state / param leaves for weight-update sharding
         self._in_paths = list(in_paths) if in_paths is not None else None
+        # invar position -> the sharding the value has on this mesh
+        # whatever this program plans (another program's choice, which
+        # unification hands it): the planner takes it as given
+        self._fixed_in = dict(fixed_in or {})
+        # (invar position, outvar position) of an apply program's donated
+        # state leaves and their new values, which unification pins to the
+        # leaf's input sharding
+        self._state_pairs = list(state_pairs)
         self._fun = None
         self.compiled = None
         self.plan()
@@ -110,6 +119,9 @@ class StageExecutable:
         fun = jaxpr_as_fun(closed)
         avals = [v.aval for v in self.comp.invars]
         as_option = self._as_option
+        # what the planner did with the donated pairs
+        # (``solver.alias_stats``; empty where it was handed none)
+        self.alias_stats: Dict[str, int] = {}
 
         if physical_mesh.num_devices > 1 and as_option.enable_auto_sharding:
             from alpa_tpu.shard_parallel.solver import plan_auto_sharding
@@ -126,8 +138,15 @@ class StageExecutable:
                 setattr(opt, k, v)
             in_paths = (self._in_paths if self._in_paths is not None
                         else [""] * len(avals))
+            # an output written into a donated input's buffer leaves with
+            # that input's sharding (``donated_out_shardings``, and for a
+            # state leaf the pin of its new value): the planner chooses
+            # the sharding with the lock in view
             jax_mesh, in_shardings, cfn, _shape = plan_auto_sharding(
-                fun, avals, in_paths, [], physical_mesh, opt)
+                fun, avals, in_paths, [], physical_mesh, opt,
+                alias_pairs=self.donated_pairs() + self._state_pairs,
+                fixed_in=self._fixed_in, stage=self.name,
+                stats=self.alias_stats)
             if cfn is not None:
                 fun = cfn  # realize the ILP plan inside the stage too
         else:
@@ -136,7 +155,7 @@ class StageExecutable:
                 (physical_mesh.num_devices, 1))
             jax_mesh = lm.get_jax_mesh(MESH_AXIS_NAMES)
             from alpa_tpu.shard_parallel.auto_sharding import (
-                plan_rule_based, resolved_zero_stage)
+                plan_rule_based)
             if (physical_mesh.num_devices > 1 and
                     self._in_paths is not None and
                     resolved_zero_stage(as_option) in (2, 3)):
@@ -155,17 +174,26 @@ class StageExecutable:
         # consumer-pinned output shardings (filled by unification)
         self.pinned_out: Dict[Var, Any] = {}
 
+    def donated_pairs(self) -> List[Tuple[int, int]]:
+        """(invar position, outvar position) of every summed gradient
+        accumulator that is written into its (donated) acc invar's
+        buffer."""
+        donate_var = {self.comp.invars[i]: i for i in self.donate_idx}
+        acc_out_for = getattr(self.comp, "_acc_out_map", {})
+        return [
+            (donate_var[acc_out_for[ov]], k)
+            for k, ov in enumerate(self.comp.outvars)
+            if ov in acc_out_for and acc_out_for[ov] in donate_var
+        ]
+
     def donated_out_shardings(self) -> Dict[Var, Any]:
         """Outvars whose sharding is locked by donation: summed gradient
         accumulators alias their (donated) acc invar's buffer, so their
         output sharding must equal that input sharding.  Single source of
         truth for both unification seeding and compile()."""
-        donate_var = {self.comp.invars[i]: i for i in self.donate_idx}
-        acc_out_for = getattr(self.comp, "_acc_out_map", {})
         return {
-            ov: self.in_shardings[donate_var[acc_out_for[ov]]]
-            for ov in self.comp.outvars
-            if ov in acc_out_for and acc_out_for[ov] in donate_var
+            self.comp.outvars[k]: self.in_shardings[i]
+            for i, k in self.donated_pairs()
         }
 
     def compile(self):
@@ -223,6 +251,9 @@ def _unify_same_mesh_shardings(execs: List["StageExecutable"],
 
     so no runtime relayout (same-mesh device_put) is needed between
     stages.  Call after every stage's plan() and before any compile().
+    Returns the agreed sharding by (mesh, value), which a program planned
+    later takes as given; a second call with those programs added agrees
+    with the first on everything the first decided.
     """
     # (mesh_id, var) -> chosen sharding (first consumer wins).
     # ``var_alias`` canonicalizes distinct Vars naming the same runtime
@@ -251,6 +282,7 @@ def _unify_same_mesh_shardings(execs: List["StageExecutable"],
             s = chosen.get((ex.mesh_id, canon(v)))
             if s is not None and v not in ex.donated_out_shardings():
                 ex.pinned_out[v] = s
+    return chosen
 
 
 class PipeshardDriverExecutable:
@@ -335,24 +367,6 @@ class PipeshardDriverExecutable:
         for comp in apply_comps:
             for v in comp.invars:
                 use_count[v] = use_count.get(v, 0) + 1
-        self.apply_execs: List[Optional[StageExecutable]] = []
-        for m, comp in enumerate(apply_comps):
-            if comp.eqns or comp.outvars:
-                donate = [
-                    i for i, v in enumerate(comp.invars)
-                    if v in donated_global and use_count.get(v) == 1
-                ]
-                self.apply_execs.append(
-                    StageExecutable(comp.name, comp, m, self.mesh_group[m],
-                                    as_option, logical_shapes[m], donate,
-                                    in_paths=stage_paths(comp)))
-            else:
-                self.apply_execs.append(None)
-        # unify shardings of values shared across same-mesh stages, then
-        # compile everything with the agreed layouts
-        all_execs = self.stage_execs + [
-            e for e in self.apply_execs if e is not None
-        ]
         post_to_sum = {
             post: acc_info[pre][1]
             for pre, post in grad_pairs if pre in acc_info
@@ -369,7 +383,51 @@ class PipeshardDriverExecutable:
             if d and isinstance(o, Var) and o is not i and
             o.aval.shape == i.aval.shape and o.aval.dtype == i.aval.dtype
         }
-        _unify_same_mesh_shardings(all_execs, {**new_to_old, **post_to_sum})
+        same_value = {**new_to_old, **post_to_sum}
+        # What the forward and backward stages agree on is given to an
+        # apply program before it is planned: the parameters as their
+        # first reader wants them, the summed gradients as their
+        # accumulators' donation locks them.  Its own choice is the rest
+        # (the optimizer's state), made with those in view and with each
+        # donated leaf's new value held to the leaf's sharding.
+        # That is weight-update sharding chosen by cost, which is what
+        # ``zero_stage="auto"`` asks for: "0" turns it off and "2"/"3" lay
+        # the state out by their own rule, and under those the apply
+        # programs are planned as they were.
+        agreed = (_unify_same_mesh_shardings(self.stage_execs, same_value)
+                  if resolved_zero_stage(as_option) == -1 else {})
+        self.apply_execs: List[Optional[StageExecutable]] = []
+        for m, comp in enumerate(apply_comps):
+            if comp.eqns or comp.outvars:
+                donate = [
+                    i for i, v in enumerate(comp.invars)
+                    if v in donated_global and use_count.get(v) == 1
+                ]
+                fixed_in = {
+                    i: agreed[(m, same_value.get(v, v))]
+                    for i, v in enumerate(comp.invars)
+                    if (m, same_value.get(v, v)) in agreed
+                }
+                position = {v: i for i, v in enumerate(comp.invars)}
+                state_pairs = [
+                    (position[new_to_old[o]], k)
+                    for k, o in enumerate(comp.outvars)
+                    if agreed and position.get(new_to_old.get(o)) in donate
+                ]
+                self.apply_execs.append(
+                    StageExecutable(comp.name, comp, m, self.mesh_group[m],
+                                    as_option, logical_shapes[m], donate,
+                                    in_paths=stage_paths(comp),
+                                    fixed_in=fixed_in,
+                                    state_pairs=state_pairs))
+            else:
+                self.apply_execs.append(None)
+        # unify shardings of values shared across same-mesh stages, then
+        # compile everything with the agreed layouts
+        all_execs = self.stage_execs + [
+            e for e in self.apply_execs if e is not None
+        ]
+        _unify_same_mesh_shardings(all_execs, same_value)
         for e in all_execs:
             e.compile()
         if global_config.print_compilation_time:
@@ -1723,6 +1781,7 @@ class PipeshardDriverExecutable:
             stage_execs=(self.stage_execs +
                          [e for e in self.apply_execs if e is not None]),
             mode=mode, run_stats=stats)
+        report.donated = self._alias_stats_by_program()
         _perf.publish_report(report)
         try:
             # fold the measured step into the calibration store (ISSUE
@@ -1951,7 +2010,9 @@ class PipeshardDriverExecutable:
 
     def get_resharding_report(self) -> str:
         """Planned cross-mesh traffic per step (tile-level accounting from
-        cross_mesh_resharding.plan_resharding)."""
+        cross_mesh_resharding.plan_resharding), and, a line a program,
+        what the planner did with the pairs that share a donated buffer
+        (``solver.alias_stats``)."""
         n = sum(1 for i in self.instructions
                 if i.opcode == PipelineInstType.RESHARD and
                 i.src_mesh != i.dst_mesh)
@@ -1965,7 +2026,17 @@ class PipeshardDriverExecutable:
                 f"; executed {self._executed_resharding_bytes / 1e6:.3f} MB "
                 f"cross-mesh + {self._executed_intra_mesh_bytes / 1e6:.3f} MB "
                 f"intra-mesh ({global_config.resharding_execution})")
+        from alpa_tpu.telemetry.perf import format_alias_stats
+        for name, stats in self._alias_stats_by_program().items():
+            report += "\n" + format_alias_stats(name, stats)
         return report
+
+    def _alias_stats_by_program(self) -> Dict[str, Dict[str, int]]:
+        return {
+            e.name: dict(e.alias_stats)
+            for e in self.stage_execs + self.apply_execs
+            if e is not None and e.alias_stats
+        }
 
     def sync(self):
         self.mesh_group.sync_workers()

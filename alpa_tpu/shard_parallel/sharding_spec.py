@@ -113,6 +113,20 @@ def enumerate_var_specs(aval, mesh_shape: Sequence[int],
     return tuple(uniq)
 
 
+def _resharding_steps(src: Spec, dst: Spec):
+    """(collective, mesh axis) for every axis of ``src`` that ``dst`` does
+    not keep on the same dim: gathered where ``dst`` drops it, moved by an
+    all-to-all where ``dst`` has it on another dim.  Axes new in ``dst``
+    are a local slice: free, no step."""
+    dst_axis_dim = {a: d for d, axes in enumerate(dst) for a in axes}
+    for d, axes in enumerate(src):
+        for a in axes:
+            if a not in dst_axis_dim:
+                yield "all_gather", a
+            elif dst_axis_dim[a] != d:
+                yield "all_to_all", a
+
+
 def resharding_cost(aval, src: Spec, dst: Spec, logical_mesh) -> float:
     """Alpha-beta cost of transforming src-sharded tensor to dst sharding.
 
@@ -121,19 +135,33 @@ def resharding_cost(aval, src: Spec, dst: Spec, logical_mesh) -> float:
     gathering pays all-gather; slicing is free; moving an axis between dims
     pays an all-to-all.
     """
-    if src == dst:
-        return 0.0
-    mesh_shape = logical_mesh.shape
     size_bytes = float(np.prod(aval.shape) if aval.shape else 1) * \
         aval.dtype.itemsize
     cost = 0.0
-    src_axis_dim = {a: d for d, axes in enumerate(src) for a in axes}
-    dst_axis_dim = {a: d for d, axes in enumerate(dst) for a in axes}
-    for a, d in src_axis_dim.items():
-        if a not in dst_axis_dim:
-            # gather this axis; bytes gathered = full size / shards kept
+    for kind, a in _resharding_steps(src, dst):
+        if kind == "all_gather":
+            # bytes gathered = full size / shards kept
             cost += logical_mesh.all_gather_cost(size_bytes, a)
-        elif dst_axis_dim[a] != d:
+        else:
             cost += logical_mesh.all_to_all_cost(size_bytes, a)
-    # axes newly introduced in dst: local slice, free.
     return cost
+
+
+def resharding_bytes(aval, src: Spec, dst: Spec,
+                     mesh_shape: Sequence[int],
+                     dropped: Sequence[int] = ()) -> float:
+    """Bytes each device receives for the same transformation, counted as
+    ``resharding_cost`` counts them: (n-1)/n of the tensor for a gather
+    over an axis of n, (n-1)/n^2 for an all-to-all.  ``dropped``: axes
+    gathered before it (``strategy.map_spec``'s, which an edge charges
+    the same way)."""
+    size_bytes = float(np.prod(aval.shape) if aval.shape else 1) * \
+        aval.dtype.itemsize
+    steps = [("all_gather", a) for a in dropped]
+    steps += _resharding_steps(src, dst)
+    total = 0.0
+    for kind, a in steps:
+        n = mesh_shape[a]
+        total += size_bytes * (n - 1) / (n if kind == "all_gather"
+                                         else n * n)
+    return total

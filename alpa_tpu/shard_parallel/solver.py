@@ -28,8 +28,10 @@ from alpa_tpu.shard_parallel.auto_sharding import (AutoShardingOption,
                                                   MESH_AXIS_NAMES)
 from alpa_tpu.shard_parallel.ilp import (InfeasibleMemoryBudget,
                                          solution_cost, solve_strategy_graph)
-from alpa_tpu.shard_parallel.sharding_spec import spec_to_partition_spec
-from alpa_tpu.shard_parallel.strategy import build_strategy_graph
+from alpa_tpu.shard_parallel.sharding_spec import (is_replicated,
+                                                   resharding_bytes,
+                                                   spec_to_partition_spec)
+from alpa_tpu.shard_parallel.strategy import build_strategy_graph, map_spec
 from alpa_tpu.telemetry import trace as _ttrace
 
 logger = logging.getLogger(__name__)
@@ -87,18 +89,54 @@ def _note_grad_quantized_choices(graph, choice) -> None:
             codec, full, _codec.grad_wire_bytes(shape, itemsize, codec))
 
 
+def alias_stats(graph, choice) -> dict:
+    """What a solution does with the graph's donated pairs: how many there
+    are, how many of their invars it shards, and the bytes a device
+    receives in a run to bring the outputs to their invars' specs (0 where
+    every one is produced in place)."""
+    mesh_shape = graph.logical_mesh.shape
+    sharded, moved = 0, 0.0
+    for src_idx, dimmap, inv_idx in graph.alias_edges:
+        inv = graph.nodes[inv_idx]
+        spec = inv.strategies[choice[inv_idx]].out_spec
+        sharded += not is_replicated(spec)
+        produced, dropped = map_spec(
+            graph.nodes[src_idx].strategies[choice[src_idx]].out_spec,
+            dimmap, len(spec))
+        moved += resharding_bytes(inv.aval, produced, spec, mesh_shape,
+                                  dropped)
+    return {"alias_pairs": len(graph.alias_edges),
+            "alias_sharded": int(sharded),
+            "alias_reshard_bytes": int(moved)}
+
+
 def plan_auto_sharding(fun: Callable,
                        in_avals: Sequence[Any],
                        in_paths: Sequence[str],
                        batch_flat_idx: Sequence[int],
                        physical_mesh: PhysicalDeviceMesh,
                        option: AutoShardingOption,
-                       return_graph: bool = False):
+                       return_graph: bool = False,
+                       alias_pairs: Sequence[Tuple[int, int]] = (),
+                       fixed_in: Optional[dict] = None,
+                       stage: str = "",
+                       stats: Optional[dict] = None):
     """Search logical mesh shapes; returns
     (jax_mesh, flat in_shardings, constraint_fn or None, chosen_shape);
     with ``return_graph`` also (graph, choice) of the winning solve —
-    used by fidelity tests comparing the ILP solution to compiled HLO."""
+    used by fidelity tests comparing the ILP solution to compiled HLO.
+
+    ``alias_pairs``: (flat invar index, flat outvar index) of outputs the
+    caller writes into donated inputs' buffers and so compiles with the
+    invars' shardings (``build_strategy_graph``).  ``fixed_in``: flat
+    invar index -> the ``NamedSharding`` the caller compiles that input
+    with whatever is planned here; one that a candidate shape's axes
+    cannot express is planned as if it were free.  ``stage`` names the
+    program in the solve's span; ``stats``, where given, receives
+    ``alias_stats`` of the chosen solution, as the span does."""
     closed_jaxpr = jax.make_jaxpr(fun)(*in_avals)
+    alias_pairs = tuple((int(i), int(o)) for i, o in alias_pairs)
+    fixed_in = dict(sorted((fixed_in or {}).items()))
 
     # The winning (shape, choice) is a pure function of the jaxpr, the
     # physical mesh extent, and the option — replay it from the compile
@@ -122,17 +160,25 @@ def plan_auto_sharding(fun: Callable,
             # calibration fingerprint (ISSUE 12): absent when
             # replan_mode=off so off-mode keys stay byte-identical;
             # grad-quantize token (ISSUE 19): same contract — absent at
-            # grad_quantize=off
+            # grad_quantize=off; the donated pairs: a graph with their
+            # edges has the nodes of one without, so a cached choice of
+            # the other would replay
         ] + ([cal_tok] if cal_tok else [])
-          + ([gq_tok] if gq_tok else []))
+          + ([gq_tok] if gq_tok else [])
+          + ([f"alias:{alias_pairs}"] if alias_pairs else [])
+          + ([f"fixed:{[(i, str(s.spec)) for i, s in fixed_in.items()]}"]
+             if fixed_in else []))
         entry = cache.get("ilp", key)
         if entry is not None:
             with _ttrace.span("ilp-cache-replay", "compile",
                               {"cache": "hit"} if _ttrace.enabled()
-                              else None):
+                              else None) as replay_span:
                 replayed = _replay_cached_solution(
                     closed_jaxpr, in_avals, in_paths, batch_flat_idx,
-                    physical_mesh, option, entry)
+                    physical_mesh, option, entry, alias_pairs, fixed_in)
+                if replayed is not None:
+                    _note_alias_stats(replayed[2], replayed[3], stage,
+                                      replay_span, stats)
             if replayed is not None:
                 cache.record_saved_seconds(
                     "ilp", entry.get("solve_seconds", 0.0))
@@ -149,12 +195,22 @@ def plan_auto_sharding(fun: Callable,
     best = None
     tic = time.time()
     infeasible = None
-    for shape in candidate_mesh_shapes(physical_mesh.num_devices, option,
-                                       physical_mesh.num_hosts == 1):
+    shapes = candidate_mesh_shapes(physical_mesh.num_devices, option,
+                                   physical_mesh.num_hosts == 1)
+    # a shape that cannot say how a given input arrives would plan it as
+    # free, and win on a cost it does not pay: only the shapes that
+    # express the most of them compete
+    fixed_specs = {s: _fixed_specs(fixed_in, s, in_avals) for s in shapes}
+    most = max(len(f) for f in fixed_specs.values())
+    for shape in shapes:
+        if len(fixed_specs[shape]) < most:
+            continue
         logical_mesh = physical_mesh.get_logical_mesh(shape)
         graph = build_strategy_graph(closed_jaxpr, in_avals, logical_mesh,
                                      batch_flat_idx, option,
-                                     in_paths=in_paths)
+                                     in_paths=in_paths,
+                                     alias_pairs=alias_pairs,
+                                     fixed_in_specs=fixed_specs[shape])
         try:
             with _ttrace.span("ilp-solve-shape", "compile",
                               {"shape": str(shape)} if _ttrace.enabled()
@@ -179,6 +235,7 @@ def plan_auto_sharding(fun: Callable,
         raise infeasible
     cost, shape, logical_mesh, graph, choice = best
     solve_seconds = time.time() - tic
+    _note_alias_stats(graph, choice, stage, solve_span, stats)
     _ttrace.end(solve_span)
     if global_config.print_compilation_time:
         logger.warning("auto-sharding search took %.2f s; picked %s "
@@ -197,8 +254,39 @@ def plan_auto_sharding(fun: Callable,
                           return_graph)
 
 
+def _fixed_specs(fixed_in, shape, in_avals) -> dict:
+    """``plan_auto_sharding``'s ``fixed_in`` as specs over a logical mesh
+    of ``shape``, without those whose mesh axes are not that mesh's."""
+    axis_names = MESH_AXIS_NAMES[:len(shape)]
+    specs = {}
+    for i, sharding in fixed_in.items():
+        dims = [(p,) if isinstance(p, str) else tuple(p or ())
+                for p in sharding.spec]
+        dims += [()] * (len(in_avals[i].shape) - len(dims))
+        names = [n for d in dims for n in d]
+        if all(n in axis_names and sharding.mesh.shape[n] ==
+               shape[axis_names.index(n)] for n in names):
+            specs[i] = tuple(tuple(axis_names.index(n) for n in d)
+                             for d in dims)
+    return specs
+
+
+def _note_alias_stats(graph, choice, stage, span, stats):
+    """``alias_stats`` of the chosen solution into the solve's span (beside
+    the program's name) and into the caller's ``stats``; nothing for a
+    program without donated pairs."""
+    if not graph.alias_edges:
+        return
+    found = alias_stats(graph, choice)
+    if getattr(span, "args", None) is not None:
+        span.args.update(found, stage=stage)
+    if stats is not None:
+        stats.update(found)
+
+
 def _replay_cached_solution(closed_jaxpr, in_avals, in_paths,
-                            batch_flat_idx, physical_mesh, option, entry):
+                            batch_flat_idx, physical_mesh, option, entry,
+                            alias_pairs, fixed_in):
     """Rebuild (shape, logical_mesh, graph, choice) from a cached ILP
     solution, or None if the entry no longer fits the strategy graph
     (e.g. strategy enumeration changed without a format-version bump)."""
@@ -212,7 +300,10 @@ def _replay_cached_solution(closed_jaxpr, in_avals, in_paths,
         logical_mesh = physical_mesh.get_logical_mesh(shape)
         graph = build_strategy_graph(closed_jaxpr, in_avals, logical_mesh,
                                      batch_flat_idx, option,
-                                     in_paths=in_paths)
+                                     in_paths=in_paths,
+                                     alias_pairs=alias_pairs,
+                                     fixed_in_specs=_fixed_specs(
+                                         fixed_in, shape, in_avals))
         if len(choice) != len(graph.nodes):
             return None
         for node, s in zip(graph.nodes, choice):

@@ -150,6 +150,10 @@ class StrategyGraph:
     nodes: List[Node]
     edges: List[Edge]
     logical_mesh: Any
+    # (source node, dim map var<-node, invar node) of every donated pair
+    # (``build_strategy_graph``'s ``alias_pairs``)
+    alias_edges: List[Tuple[int, DimMap, int]] = dataclasses.field(
+        default_factory=list)
 
     def stats(self):
         nvars = sum(len(n.strategies) for n in self.nodes)
@@ -882,7 +886,23 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
                          logical_mesh,
                          batch_flat_idx: Sequence[int],
                          option,
-                         in_paths: Sequence[str] = ()) -> StrategyGraph:
+                         in_paths: Sequence[str] = (),
+                         alias_pairs: Sequence[Tuple[int, int]] = (),
+                         fixed_in_specs: Optional[Dict[int, Spec]] = None
+                         ) -> StrategyGraph:
+    """``fixed_in_specs``: flat invar index -> the spec the value arrives
+    in whatever this graph chooses (the caller compiles the program with
+    it): that invar's node has the one strategy.
+
+    ``alias_pairs``: (flat invar index, flat outvar index) of every
+    output that is written into a donated input's buffer (a stage's
+    gradient accumulators).  The caller compiles such an output with its
+    invar's sharding, so the graph prices that here: an edge from the
+    output's source node to the invar node, whose cost is the resharding
+    of the value (through the follow chain's dim map) to each spec the
+    invar node can choose.  The analog of the reference ILP's alias
+    constraints (ref auto_sharding.py:771-823), as a cost instead of a
+    hard constraint since GSPMD can always close the gap."""
     jaxpr = closed_jaxpr.jaxpr
     mesh_shape = logical_mesh.shape
     nodes: List[Node] = []
@@ -928,6 +948,9 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
             sharded = tuple(s for s in specs if any(bool(d) for d in s))
             if sharded:
                 specs = sharded
+        given = (fixed_in_specs or {}).get(i)
+        if given is not None and spec_valid(aval, given, mesh_shape):
+            specs = (given,)
         if zero_leaf:
             nbytes = (float(np.prod(aval.shape) if aval.shape else 1) *
                       aval.dtype.itemsize)
@@ -1237,7 +1260,24 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
     graph.constvars = list(jaxpr.constvars)
     sub_env = flatten_info.get("env", {})
     graph.outvars = [_subst(v, sub_env) for v in jaxpr.outvars]
+
     graph.captured_consts = flatten_info.get("captured_consts", {})
+
+    # donated pairs: the output leaves in its invar's spec, whatever its
+    # producer chose, and the way there is on the objective
+    for in_idx, out_idx in alias_pairs:
+        ov = graph.outvars[out_idx]
+        inv = var_node[jaxpr.invars[in_idx]]
+        src = None if isinstance(ov, Literal) else var_node.get(ov)
+        if src is None:
+            continue
+        graph.alias_edges.append((src[0], src[1], inv[0]))
+        if src == inv:
+            continue  # the invar itself, or a sum that follows it
+        inv_node = nodes[inv[0]]
+        edges.append(Edge(src[0], inv[0], edge_cost_matrix(
+            nodes[src[0]], src[1], inv_node.aval,
+            [st.out_spec for st in inv_node.strategies])))
     return graph
 
 

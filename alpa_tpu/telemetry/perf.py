@@ -515,6 +515,10 @@ class StepPerfReport:
     transfers: TransferBreakdown
     stages: Dict[str, StageMfu]
     notes: List[str] = dataclasses.field(default_factory=list)
+    # program -> what its planner did with the pairs that share a donated
+    # buffer (``solver.alias_stats``, from the ``ilp-solve`` spans' args)
+    donated: Dict[str, Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
     # re-simulation model (kept for whatif; not part of the text report)
     sim_durs_us: List[float] = dataclasses.field(
         default_factory=list, repr=False)
@@ -662,6 +666,10 @@ class StepPerfReport:
                 lines.append(
                     f"  {name:<24} {s.n_runs:5d} {s.run_time_us:10.1f} "
                     f"{s.tflops_per_chip:12.4f} {s.mfu:8.4f}")
+        if self.donated:
+            lines += ["", "donated pairs, as the planner left them:"] + [
+                "  " + format_alias_stats(name, stats)
+                for name, stats in self.donated.items()]
         if self.notes:
             lines += [""] + [f"note: {n}" for n in self.notes]
         return "\n".join(lines)
@@ -1103,15 +1111,39 @@ def spans_from_chrome(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
     return spans
 
 
+def format_alias_stats(program: str, stats: Dict[str, int]) -> str:
+    """One line of ``solver.alias_stats`` for a report."""
+    return (f"{program}: {stats['alias_pairs']} donated pairs, "
+            f"{stats['alias_sharded']} sharded, "
+            f"{stats['alias_reshard_bytes']} B a run to bring the outputs "
+            "to their inputs' specs")
+
+
+def donated_from_spans(spans: Sequence[Dict[str, Any]]
+                       ) -> Dict[str, Dict[str, int]]:
+    """``StepPerfReport.donated`` from the planner's spans (``ilp-solve``
+    or, for a plan replayed from the cache, ``ilp-cache-replay``)."""
+    keys = ("alias_pairs", "alias_sharded", "alias_reshard_bytes")
+    return {
+        s["args"]["stage"]: {k: int(s["args"][k]) for k in keys}
+        for s in spans
+        if s["name"] in ("ilp-solve", "ilp-cache-replay") and
+        all(k in (s.get("args") or {}) for k in keys + ("stage",))
+    }
+
+
 def report_from_trace(trace: Dict[str, Any],
                       peak_tflops: Optional[float] = None
                       ) -> Optional[StepPerfReport]:
     """Analyze a saved Chrome trace (no program/graph available —
     track-order analysis of the last ``pipeshard.step`` envelope)."""
-    joined = _join_spans(spans_from_chrome(trace), None)
+    spans = spans_from_chrome(trace)
+    joined = _join_spans(spans, None)
     if joined is None:
         return None
-    return build_step_report(joined, peak_tflops=peak_tflops)
+    report = build_step_report(joined, peak_tflops=peak_tflops)
+    report.donated = donated_from_spans(spans)
+    return report
 
 
 ########################################

@@ -4,6 +4,8 @@ Analog of ref ``alpa/testing.py`` (SURVEY.md §4): the core oracle is
 serial-vs-parallel numeric equivalence, plus structural assertions on
 compiled HLO.
 """
+import collections
+import re
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -107,6 +109,103 @@ def get_mlp_train_step(parallel_method=None, use_value_and_grad=False):
     if parallel_method is not None:
         return alpa_tpu.parallelize(train_step, method=parallel_method)
     return jax.jit(train_step)
+
+
+def get_gpt_train_step(config, batch_size, parallel_method=None,
+                       learning_rate=1e-4):
+    """A language-model train step of ``GPTModel(config)`` under Adam, as
+    the benchmark's training driver builds it: ``(train_step,
+    create_state, batch)``.  With a method the step is parallelized and
+    donates its state (compile it from shapes with
+    ``train_step.get_executable(jax.eval_shape(create_state), batch)``);
+    without one it is a plain ``jax.jit``."""
+    from alpa_tpu.model.gpt_model import GPTModel
+    from alpa_tpu.model.model_util import gpt_lm_loss
+    model = GPTModel(config)
+    shape = (batch_size, config.seq_len)
+    tx = optax.adam(learning_rate)
+    k_ids, k_labels = jax.random.split(jax.random.PRNGKey(1))
+    batch = {
+        "input_ids": jax.random.randint(k_ids, shape, 0, config.vocab_size),
+        "labels": jax.random.randint(k_labels, shape, 0, config.vocab_size),
+    }
+
+    def create_state():
+        params = model.init(jax.random.PRNGKey(0),
+                            jnp.ones(shape, jnp.int32))
+        return train_state.TrainState.create(apply_fn=model.apply,
+                                             params=params, tx=tx)
+
+    def train_step(state, batch):
+
+        def loss_fn(params):
+            return gpt_lm_loss(state.apply_fn, params, batch)
+
+        grad_fn = (alpa_tpu.value_and_grad if parallel_method is not None
+                   else jax.value_and_grad)
+        loss, grads = grad_fn(loss_fn)(state.params)
+        return state.apply_gradients(grads=grads), loss
+
+    if parallel_method is not None:
+        train_step = alpa_tpu.parallelize(train_step, method=parallel_method,
+                                          static_argnums=(),
+                                          donate_argnums=(0,))
+    else:
+        train_step = jax.jit(train_step)
+    return train_step, create_state, batch
+
+
+_GATHER = re.compile(r"= (\w+\[[\d,]*\])\S* all-gather(?:-start)?\(")
+
+
+def gathers_by_shape(text):
+    """How many all-gathers of a compiled program's text give each shape."""
+    return collections.Counter(_GATHER.findall(text))
+
+
+def _shape_of(aval):
+    return f"{np.dtype(aval.dtype).name.replace('float', 'f')}" \
+           f"[{','.join(map(str, aval.shape))}]"
+
+
+def donated_accumulator_faults(executable):
+    """What is wrong with a compiled pipeshard executable's donated
+    gradient accumulators, as ``(program, what)``; ``[]`` when every
+    backward stage keeps each accumulator in the sharding of its sum,
+    shards the rank-2 ones (the kernels: a product is sharded over one of
+    its output dimensions) and gathers none of them, and every update
+    program gathers a kernel at most once.  (A rank-1 sum may still be
+    gathered: the planner holds the ``split`` and ``concatenate`` around
+    an attention core replicated, GSPMD does not, and a bias's gradient
+    is then produced sharded where the plan has it whole.)"""
+    wrong = []
+    for stage in executable.stage_execs:
+        pairs = stage.donated_pairs()
+        if not stage.name.endswith("_bwd"):
+            continue
+        assert pairs, stage.name
+        gathered = gathers_by_shape(stage.compiled.as_text())
+        for i, k in pairs:
+            aval = stage.invars[i].aval
+            ndim = len(aval.shape)
+            if not stage.in_shardings[i].is_equivalent_to(
+                    stage.out_shardings[k], ndim):
+                wrong.append((stage.name, f"{aval} in != out"))
+            if ndim == 2:
+                if stage.in_shardings[i].is_fully_replicated:
+                    wrong.append((stage.name, f"{aval} replicated"))
+                if gathered[_shape_of(aval)]:
+                    wrong.append((stage.name, f"{aval} gathered"))
+    for apply in executable.apply_execs:
+        leaves = collections.Counter(
+            _shape_of(apply.invars[i].aval) for i in apply.donate_idx)
+        for shape, n in gathers_by_shape(apply.compiled.as_text()).items():
+            # a kernel, its two moments and its summed gradient are four
+            # arrays of one shape: one gather a kernel, not one an array
+            if n > max(leaves[shape] // 3, 1):
+                wrong.append((apply.name, f"{shape} gathered {n} times "
+                              f"for {leaves[shape]} leaves"))
+    return wrong
 
 
 def data_loader_input_iter_func(start, end, batch_size):

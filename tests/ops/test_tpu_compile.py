@@ -409,3 +409,35 @@ def test_a_trinity_chunk_holds_no_chunk_of_logits_on_v5e(one_chip):
     assert text.startswith("HloModule jit_chunk_prefill")
     assert re.search(r"bf16\[1,%d\]" % vocab, text)
     assert not re.search(r"\[(\d+,)?%d,%d\]" % (chunk, vocab), text)
+
+
+def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
+    """Two pipeline stages of two chips compiled for the described 2x2
+    from shapes (the rehearsal cell's depth and method at widths a
+    product's sharding shows at): no backward stage gathers a summed
+    weight gradient, each update program gathers a kernel once
+    (``tests/pipeline_parallel/test_donated_accumulators.py`` holds the
+    same on four virtual CPU devices)."""
+    import alpa_tpu
+    from alpa_tpu import PipeshardParallel
+    from alpa_tpu.model.gpt_model import GPTConfig
+    from alpa_tpu.pipeline_parallel.layer_construction import (
+        ManualLayerOption)
+    from alpa_tpu.pipeline_parallel.stage_construction import (
+        UniformStageOption)
+    from alpa_tpu.testing import (donated_accumulator_faults,
+                                  get_gpt_train_step)
+    cfg = GPTConfig(vocab_size=1024, hidden_size=256, num_layers=2,
+                    num_heads=4, seq_len=128, dtype=jnp.bfloat16,
+                    remat_blocks=True, pipeline_boundary_every=1)
+    method = PipeshardParallel(
+        num_micro_batches=2, pipeline_schedule="1f1b",
+        layer_option=ManualLayerOption(),
+        stage_option=UniformStageOption(num_stages=2))
+    alpa_tpu.init("local", devices=topo.devices)
+    step, create_state, batch = get_gpt_train_step(cfg, 4, method)
+    executable, _ = step.get_executable(
+        jax.eval_shape(create_state),
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch))
+    assert donated_accumulator_faults(executable) == []
