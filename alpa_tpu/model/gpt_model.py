@@ -20,6 +20,7 @@ Clean-room analog of ref ``alpa/model/gpt_model.py`` (which wraps
 The GPT ladder (125M..76B, ref benchmark/alpa/suite_manual_gpt.py:18-26) is
 reproduced in ``gpt_specs``.
 """
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Callable, Optional, Tuple
@@ -216,6 +217,25 @@ class GPTConfig:
     # latents), which need not be the "latent" layers': heads, ranks, head
     # sizes, rotary base and factors (``LatentWidths``); None: no such layer
     sliding_latent: Optional["LatentWidths"] = None
+    # --- window and full layers of per-head keys and values that differ in
+    # more than the mask (``model_type`` mimo_v2_flash).  Every default is
+    # the block of today.  The key/value heads and the rotary base of the
+    # "sliding" layers where they are not the "full" layers' (None:
+    # ``num_kv_heads``, ``rope_theta``)
+    sliding_kv_heads: Optional[int] = None
+    sliding_rope_theta: Optional[float] = None
+    # channels of a "full" or "sliding" layer's value heads where they are
+    # not the keys' ``head_size`` (``v_head_dim`` above, 0: as the keys).
+    # The leading channels of every q and k head that rotary positions
+    # turn, the others passing as they are; 0: all of them
+    rotary_dim: int = 0
+    # what the heads' weighted values are multiplied by before the output
+    # projection
+    value_scale: float = 1.0
+    # the attention kinds ("full", "sliding") whose softmax has a learned
+    # logit a query head in its denominator (a sink: it takes probability
+    # and carries no value; parameter ``sink``, float32)
+    sink_kinds: Tuple[str, ...] = ()
 
     def mlp_kind(self, layer: int) -> str:
         return self.mlp if isinstance(self.mlp, str) else self.mlp[layer]
@@ -239,6 +259,30 @@ class GPTConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def value_size(self) -> int:
+        """Channels of one value head of a "full" or "sliding" layer."""
+        return self.v_head_dim or self.head_size
+
+    @property
+    def unlike_kinds(self) -> bool:
+        """Whether the "full" and "sliding" layers differ in more than the
+        mask: heads or a rotary base of their own, values narrower than the
+        keys (caches of unlike shapes a kind AND an array), a sink."""
+        return bool(self.sliding_kv_heads or self.sliding_rope_theta or
+                    self.sink_kinds or self.value_size != self.head_size)
+
+    def kv_heads_of(self, kind: str) -> int:
+        """Key/value heads of a "full" or "sliding" layer."""
+        if kind == "sliding" and self.sliding_kv_heads:
+            return self.sliding_kv_heads
+        return self.kv_heads
+
+    def rope_theta_of(self, kind: str) -> float:
+        if kind == "sliding" and self.sliding_rope_theta:
+            return self.sliding_rope_theta
+        return self.rope_theta
 
     def latent_widths(self, kind: str) -> "LatentWidths":
         """The widths of a latent layer of this kind: the configuration's
@@ -378,6 +422,13 @@ _HF_KINDS = {
     "dots3_note": dict(norm="rmsnorm", positions="rotary",
                        rope_interleaved=True, fused_gate_up=True,
                        router_score="sigmoid", router_bias=True),
+    # MiMo-V2-Flash (XiaomiMiMo): wiring read as the families its keys name
+    # where config.json does not fix it (DeepSeek-V3's sigmoid router with
+    # a choice bias, rotate-half rotary pairs on the leading channels, the
+    # sink as a logit a head in the softmax's denominator)
+    "mimo_v2_flash": dict(norm="rmsnorm", positions="rotary",
+                          fused_gate_up=True, router_score="sigmoid",
+                          router_bias=True),
 }
 
 
@@ -663,12 +714,76 @@ def _dots3_note_fields(hf: dict) -> dict:
         route_scale=float(hf["routed_scaling_factor"]))
 
 
+def _mimo_v2_flash_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` mimo_v2_flash says beyond the
+    keys all decoders share: which layers are full and which under the
+    window (``hybrid_layer_pattern``: 0 full, 1 sliding), each kind's
+    key/value heads and rotary base (the ``swa_`` keys), keys of
+    ``head_dim`` channels with values of ``v_head_dim``, rotary positions
+    on the leading ``int(head_dim * partial_rotary_factor)`` channels, the
+    scale on the values, which kinds' softmax has a learned sink, which
+    layers route (``moe_layer_freq``: 1 routed) and the sigmoid router with
+    a choice bias.  Of lists longer than ``num_hidden_layers`` (a file cut
+    in depth may keep the published ones) the leading layers count.
+    ``attention_chunk_size`` changes no equation and is not read; the
+    multi-token-prediction modules have no key in the file and are not
+    built."""
+    layers = hf["num_hidden_layers"]
+    pattern = hf["hybrid_layer_pattern"][:layers]
+    routed = hf["moe_layer_freq"][:layers]
+    if len(pattern) != layers or len(routed) != layers:
+        raise ValueError("hybrid_layer_pattern and moe_layer_freq must name "
+                         "every layer")
+    if set(pattern) - {0, 1} or set(routed) - {0, 1}:
+        raise ValueError("hybrid_layer_pattern and moe_layer_freq hold 0 "
+                         "and 1 only")
+    if hf["topk_method"] != "noaux_tc" or hf["scoring_func"] != "sigmoid" \
+            or hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError("mimo_v2_flash: only sigmoid scores under noaux_tc "
+                         "in one group are supported")
+    if hf.get("n_shared_experts"):
+        raise ValueError("mimo_v2_flash: shared experts are not supported")
+    for swa, full in (("swa_num_attention_heads", "num_attention_heads"),
+                      ("swa_head_dim", "head_dim"),
+                      ("swa_v_head_dim", "v_head_dim"),
+                      ("sliding_window_size", "sliding_window")):
+        if hf[swa] != hf[full]:
+            raise ValueError(f"mimo_v2_flash: {swa} {hf[swa]} differs from "
+                             f"{full} {hf[full]}: the window layers share "
+                             "the full layers' query heads and head widths")
+    rotary = int(hf["head_dim"] * hf["partial_rotary_factor"])
+    if rotary % 2:
+        raise ValueError(f"partial_rotary_factor leaves {rotary} channels "
+                         "to rotate, which are no pairs")
+    return dict(
+        _depth_and_widths(hf), activation=hf["hidden_act"],
+        layer_norm_eps=hf["layernorm_epsilon"],
+        tie_embeddings=hf["tie_word_embeddings"],
+        mlp=tuple("experts" if r else "gated" for r in routed),
+        attention=tuple("sliding" if p else "full" for p in pattern),
+        sliding_window=hf["sliding_window"], head_dim=hf["head_dim"],
+        v_head_dim=0 if hf["v_head_dim"] == hf["head_dim"]
+        else hf["v_head_dim"],
+        rotary_dim=0 if rotary == hf["head_dim"] else rotary,
+        value_scale=float(hf["attention_value_scale"] or 1.0),
+        sink_kinds=tuple(kind for kind, key in (
+            ("full", "add_full_attention_sink_bias"),
+            ("sliding", "add_swa_attention_sink_bias")) if hf[key]),
+        sliding_kv_heads=hf["swa_num_key_value_heads"],
+        sliding_rope_theta=float(hf["swa_rope_theta"]),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        norm_topk_prob=hf["norm_topk_prob"],
+        route_scale=float(hf["routed_scaling_factor"] or 1.0))
+
+
 # what each model type's file says beyond the keys all share
 _HF_FIELDS = {
     "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
     "deepseek_v2": _deepseek_v2_fields, "sdar_moe": _sdar_moe_fields,
     "lfm2_moe": _lfm2_moe_fields, "longcat_flash": _longcat_flash_fields,
     "dots3_note": _dots3_note_fields,
+    "mimo_v2_flash": _mimo_v2_flash_fields,
 }
 
 
@@ -747,10 +862,12 @@ def apply_rotary(x, position_ids, theta: float, interleaved: bool = False,
 
 
 def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
-                        window: int = 0, k_positions=None, block: int = 0):
+                        window: int = 0, k_positions=None, block: int = 0,
+                        sink=None):
     """Plain einsum attention; XLA fuses this well on TPU for short seqs.
 
-    q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D), H a multiple of Hkv: query head
+    q: (B, Sq, H, D); k: (B, Sk, Hkv, D), v: (B, Sk, Hkv, Dv) (the values
+    as wide as the keys or not), H a multiple of Hkv: query head
     i reads key/value head i // (H / Hkv) (grouped-query attention; the
     keys and values are never repeated).  fp32 softmax accumulation.
     ``offset`` shifts query positions for decode-with-cache; a scalar
@@ -770,6 +887,10 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
     the keys at positions ``< (p // block + 1) * block``: every key of its
     own block, the later ones too, and of the blocks before it (generation
     by diffusion over blocks, ``GPTConfig.block_length``).
+
+    ``sink`` ((H,) float32): a logit a query head that joins the softmax's
+    denominator and carries no value: ``p_ij = exp(s_ij) / (exp(sink_h) +
+    sum_j' exp(s_ij'))`` (``GPTConfig.sink_kinds``).
     """
     if block and (not causal or window or k_positions is not None):
         raise ValueError("a block-causal mask goes with a causal mask over "
@@ -817,12 +938,19 @@ def reference_attention(q, k, v, *, causal: bool, offset=0, bias=None,
                 mask &= q_pos - k_pos[None] < window
             mask = mask[:, None]                             # (B,1,Sq,Sk)
         scores = jnp.where(mask, scores, jnp.float32(-1e9))
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    else:
+        sink = sink.astype(jnp.float32)[None, :, None, None]
+        top = jnp.maximum(scores.max(-1, keepdims=True), sink)
+        probs = jnp.exp(scores - top)
+        probs = (probs / (probs.sum(-1, keepdims=True) +
+                          jnp.exp(sink - top))).astype(q.dtype)
     if nkv == nh:
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     out = jnp.einsum("bhgqk,bkhd->bqhgd",
                      probs.reshape(b, nkv, nh // nkv, sq, sk), v)
-    return out.reshape(b, sq, nh, dim)
+    return out.reshape(b, sq, nh, v.shape[-1])
 
 
 def attention(q, k, v, *, causal: bool):
@@ -916,6 +1044,12 @@ CACHE_WRITE_SCOPE = "cache_write"
 # attention (the gather of their latents and the core over them)
 INDEXER_SCOPE = "indexer"
 SELECT_SCOPE = "latent_select"
+# inside it too, in a configuration whose "sliding" and "full" layers
+# differ in more than the mask (``GPTConfig.unlike_kinds``): the core and
+# the cache's write of a layer of either kind, so that a capture tells the
+# window layers' time from the full layers'
+WINDOW_CORE_SCOPE = "window_core"
+FULL_CORE_SCOPE = "full_core"
 
 
 # the lanes of a TPU vector register: the extent the compiler tiles an
@@ -934,7 +1068,8 @@ def _write_rows(cache, new, index):
     The view the rows are written through follows the head width, and
     nothing else.  Heads of ``LANES`` channels or more fill the lanes, the
     compiler keeps the cache as it is named, and the rows are written into
-    it as named.  Narrower heads would be padded to the lanes, so the
+    it as named.  Narrower heads (and heads of no whole number of lane
+    tiles: 192 channels) would be padded to the lanes, so the
     compiler keeps such a cache with its POSITIONS minor-most
     (``{1,3,2,0}``), and the rows are written into the cache seen as it
     lies, ((B H D), S), through ``_write_latent_rows``: the transposes
@@ -952,7 +1087,9 @@ def _write_rows(cache, new, index):
     """
     b, seq_len, heads, dim = cache.shape
     s = new.shape[1]
-    narrow = dim < LANES
+    # (192 channels, a lane tile and a half, lie as 64 do: the compiler
+    # keeps any head width that is no whole tiles positions-minor)
+    narrow = dim % LANES != 0
     # at trace time: which view this cache's rows were written through
     tmetrics.get_registry().gauge(
         "alpa_cache_row_write_view",
@@ -1004,7 +1141,11 @@ def update_kv_cache(kv_cache, k, v):
     last, for narrower ones), so that no caller and no configuration
     chooses.  A row whose ``s`` positions do not all fit in the
     cache is not written at all (``_write_rows``); its index advances all
-    the same.  No caller lets an active row get there (``generate``, the
+    the same.  Caches of three dimensions hold the heads folded into the
+    channels, (B, S, Hkv D) (a layer whose keys are wider than its values:
+    ``kv_cache_shapes``), and are written as a latent layer's are
+    (``_write_latent_rows``).  No caller lets an active row get there
+    (``generate``, the
     speculative rounds and the engine's ``submit`` refuse a request that
     would).  The rows that do are the engine's free rows, decoded along in
     every tick with an index that only grows: nothing reads them, and the
@@ -1013,6 +1154,12 @@ def update_kv_cache(kv_cache, k, v):
     k_cache, v_cache, index = kv_cache
     index = jnp.asarray(index, jnp.int32)
     k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+    write_rows = _write_rows
+    if k_cache.ndim == 3:
+        # caches with the heads folded into the channels
+        # (``kv_cache_shapes``): the new keys and values fold for nothing
+        k, v = (x.reshape(x.shape[0], x.shape[1], -1) for x in (k, v))
+        write_rows = partial(_write_latent_rows, axis=1)
     with jax.named_scope(CACHE_WRITE_SCOPE):
         if index.ndim == 0:
             k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, index,
@@ -1020,16 +1167,18 @@ def update_kv_cache(kv_cache, k, v):
             v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, index,
                                                           axis=1)
         else:
-            k_cache = _write_rows(k_cache, k, index)
-            v_cache = _write_rows(v_cache, v, index)
+            k_cache = write_rows(k_cache, k, index)
+            v_cache = write_rows(v_cache, v, index)
     return k_cache, v_cache, index + k.shape[1]
 
 
-def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0):
+def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0,
+                     sink=None):
     """The attention of a full-attention layer's ``s`` new queries ``q``
     (B, s, H, D) over its written caches (B, S, Hkv, D), as
-    ``update_kv_cache`` returns them; ``offset`` and ``block`` are
-    ``reference_attention``'s.
+    ``update_kv_cache`` returns them; ``offset``, ``block`` and ``sink`` are
+    ``reference_attention``'s.  Caches with the heads folded into the
+    channels, (B, S, Hkv D) and (B, S, Hkv Dv): ``folded_cached_attention``.
 
     One of two cores, by what the call's shapes say and nothing a caller
     or a configuration sets.  Per-row offsets (the engine's decode tick
@@ -1047,22 +1196,35 @@ def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0):
     ``alpa_cached_attention_core`` says at trace time which one a
     program's layers took."""
     from alpa_tpu.ops import cached_attention as kernel
+    if k_cache.ndim == 3:
+        if block:
+            raise ValueError("a block-causal mask goes with caches of "
+                             "per-head keys and values as wide as each "
+                             "other")
+        return folded_cached_attention(q, k_cache, v_cache, offset, sink)
     offset = jnp.asarray(offset, jnp.int32)
-    key_blocks = offset.ndim == 1 and kernel.fits(q, k_cache)
-    tmetrics.get_registry().gauge(
+    key_blocks = offset.ndim == 1 and sink is None and \
+        kernel.fits(q, k_cache)
+    _cached_core_gauge().labels(
+        "key_blocks" if key_blocks else "reference", k_cache.shape[2],
+        k_cache.shape[3], q.shape[1]).inc()
+    if not key_blocks:
+        return reference_attention(q, k_cache, v_cache, causal=True,
+                                   offset=offset, block=block, sink=sink)
+    return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
+
+
+def _cached_core_gauge():
+    return tmetrics.get_registry().gauge(
         "alpa_cached_attention_core",
         "full-attention layers whose attention over the written cache was "
         "traced with each core (key_blocks: where lowered for a TPU, the "
         "kernel that reads each row's cache as far as the row has written; "
+        "key_block_walk: a loop in jax.numpy over the key blocks up to the "
+        "last query's, of a cache with the heads folded into the channels; "
         "reference: every position the cache can hold), by the cache's "
         "heads, head width and the new queries a row",
-        ("core", "heads", "head_dim", "queries")).labels(
-            "key_blocks" if key_blocks else "reference", k_cache.shape[2],
-            k_cache.shape[3], q.shape[1]).inc()
-    if not key_blocks:
-        return reference_attention(q, k_cache, v_cache, causal=True,
-                                   offset=offset, block=block)
-    return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
+        ("core", "heads", "head_dim", "queries"))
 
 
 @partial(jax.jit, static_argnames="block")
@@ -1078,6 +1240,111 @@ def _attention_over_key_blocks(q, k_cache, v_cache, offset, block):
         tpu=partial(kernel.cached_attention, block=block),
         default=lambda q, k, v, offset: reference_attention(
             q, k, v, causal=True, offset=offset, block=block))
+
+
+def folded_cached_attention(q, k_cache, v_cache, offset, sink=None):
+    """``cached_attention`` over caches with the heads folded into the
+    channels: ``q`` (B, s, H, D) over ``k_cache`` (B, S, Hkv D) and
+    ``v_cache`` (B, S, Hkv Dv), as ``update_kv_cache`` returns them (a
+    layer whose keys are wider than its values, ``kv_cache_shapes``);
+    returns (B, s, H, Dv).
+
+    No core here scores against every position the cache can hold (at the
+    context such a layer serves, a chunk's scores over all of it would be
+    gigabytes).  Per-row offsets and shapes the kernel of
+    ``ops/cached_attention.py`` takes (``folded_fits``): a program lowered
+    for a TPU runs that kernel, which reads of every row's cache the key
+    blocks the row's queries can see, as the cache lies, and any other
+    platform ``_attention_over_folded_blocks``, its ``jax.numpy`` twin.
+    Every other call (a scalar offset: a prefill chunk, ``generate``; a
+    sink) is the twin, a loop over key blocks that ends at the last
+    query's.  The gauge ``alpa_cached_attention_core`` says which."""
+    from alpa_tpu.ops import cached_attention as kernel
+    offset = jnp.asarray(offset, jnp.int32)
+    dim = q.shape[-1]
+    key_blocks = offset.ndim == 1 and sink is None and \
+        kernel.folded_fits(q, k_cache, v_cache)
+    _cached_core_gauge().labels(
+        "key_blocks" if key_blocks else "key_block_walk",
+        k_cache.shape[2] // dim, dim, q.shape[1]).inc()
+    if not key_blocks:
+        return _attention_over_folded_blocks(q, k_cache, v_cache, offset,
+                                             sink)
+    return _folded_key_blocks(q, k_cache, v_cache, offset)
+
+
+@jax.jit
+def _folded_key_blocks(q, k_cache, v_cache, offset):
+    """``folded_cached_attention``'s kernel where the program is lowered
+    for a TPU, its twin anywhere else; a ``jit`` of its own as
+    ``_attention_over_key_blocks`` is."""
+    from alpa_tpu.ops import cached_attention as kernel
+    return jax.lax.platform_dependent(
+        q, k_cache, v_cache, offset, tpu=kernel.folded_cached_attention,
+        default=_attention_over_folded_blocks)
+
+
+# positions in a key block of ``_attention_over_folded_blocks`` at least
+_WALK_BLOCK = 128
+
+
+def _attention_over_folded_blocks(q, k_cache, v_cache, offset, sink=None):
+    """``reference_attention(q, k, v, causal=True, offset=offset,
+    sink=sink)`` over folded caches, a key block at a time: the block's
+    keys and values are unfolded to their heads inside the loop, scored,
+    and folded into a running maximum, sum and weighted values (float32),
+    so that one block's scores exist at a time (``H x s x block``, the
+    block ``max(s, _WALK_BLOCK)`` positions), and the loop ends at the
+    block of the last query: positions no row holds yet are not read
+    (``_latent_attention_blocks`` is the same walk over latents)."""
+    b, sq, nh, dim = q.shape
+    sk = k_cache.shape[1]
+    nkv = k_cache.shape[2] // dim
+    dv = v_cache.shape[2] // nkv
+    block = min(max(sq, _WALK_BLOCK), sk)
+    q_pos = _query_positions(offset, b, sq)
+    n_blocks = jnp.minimum(jnp.max(q_pos) // block + 1, -(-sk // block))
+    steps = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block), 2)
+    grouped = q.reshape(b, sq, nkv, nh // nkv, dim)
+    scale = 1 / np.sqrt(dim)
+
+    def one_block(j, carry):
+        top, total, acc = carry
+        # the last block of a cache that is no multiple of it overlaps
+        # the one before: its first positions are then masked
+        start = jnp.minimum(j * block, sk - block)
+        keys = jax.lax.dynamic_slice_in_dim(k_cache, start, block, axis=1)
+        values = jax.lax.dynamic_slice_in_dim(v_cache, start, block, axis=1)
+        scores = scale * _einsum_f32(
+            "bqhgd,bkhd->bhgqk", grouped, keys.reshape(b, block, nkv, dim))
+        k_pos = start + steps
+        seen = ((k_pos <= q_pos[:, :, None]) &
+                (k_pos >= j * block))[:, None, None]       # (.,1,1,Sq,blk)
+        top_new = jnp.maximum(
+            top, jnp.where(seen, scores, -jnp.inf).max(-1))
+        probs = jnp.where(seen, jnp.exp(scores - top_new[..., None]), 0.0)
+        keep = jnp.exp(top - top_new)
+        total = total * keep + probs.sum(-1)
+        acc = acc * keep[..., None] + _einsum_f32(
+            "bhgqk,bkhd->bhgqd", probs.astype(q.dtype),
+            values.reshape(b, block, nkv, dv))
+        return top_new, total, acc
+
+    # a finite floor: a block that a query sees nothing of leaves its
+    # maximum there, and exp(floor - floor) is 1 and not NaN
+    heads = (b, nkv, nh // nkv, sq)
+    init = (jnp.full(heads, -1e30, jnp.float32),
+            jnp.zeros(heads, jnp.float32),
+            jnp.zeros(heads + (dv,), jnp.float32))
+    top, total, acc = jax.lax.fori_loop(0, n_blocks, one_block, init)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(1, nkv, nh // nkv, 1)
+        keep = jnp.exp(top - jnp.maximum(top, sink))
+        total = total * keep + jnp.exp(sink - jnp.maximum(top, sink))
+        acc = acc * keep[..., None]
+    out = acc / jnp.maximum(total, 1e-30)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, nh, dv).astype(
+        q.dtype)
 
 
 def update_ring_cache(kv_cache, k, v, lengths=None):
@@ -1925,6 +2192,14 @@ class ShortConv(nn.Module):
         return out, new_cache
 
 
+def _sink_init(window: int):
+    """Sinks drawn from N(ln(window), 1); N(0, 1) on a layer without a
+    window."""
+    def init(key, shape, dtype=jnp.float32):
+        return np.log(max(window, 1)) + jax.random.normal(key, shape, dtype)
+    return init
+
+
 class SelfAttention(nn.Module):
     """``attention`` is the layer's kind (``GPTConfig.attention``; None:
     the configuration's, which must then be one kind for all layers).
@@ -1940,15 +2215,17 @@ class SelfAttention(nn.Module):
         if padding_bias is not None and kv_cache is not None:
             raise ValueError("a padding bias goes with no cache: a cached "
                              "call masks by the rows' offsets")
-        h, nh, nkv, hd = (cfg.hidden_size, cfg.num_heads, cfg.kv_heads,
-                          cfg.head_size)
         kind = self.attention or cfg.attention_kind(0)
         if kind not in ("full", "sliding"):
             raise ValueError(f"unknown attention kind {kind!r}")
+        # keys (and queries) of ``hd`` channels, values of ``dv``
+        h, nh, nkv, hd, dv = (cfg.hidden_size, cfg.num_heads,
+                              cfg.kv_heads_of(kind), cfg.head_size,
+                              cfg.value_size)
         window = cfg.sliding_window if kind == "sliding" else 0
         dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=cfg.use_bias,
                         param_dtype=cfg.param_dtype)
-        qkv = dense((nh + 2 * nkv) * hd, name="qkv")(x)
+        qkv = dense((nh + nkv) * hd + nkv * dv, name="qkv")(x)
         q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
         b, s = x.shape[0], x.shape[1]
         if cfg.qk_norm is True:
@@ -1956,7 +2233,7 @@ class SelfAttention(nn.Module):
             k = make_norm(cfg, "k_norm")(k).astype(cfg.dtype)
         q = q.reshape(b, s, nh, hd)
         k = k.reshape(b, s, nkv, hd)
-        v = v.reshape(b, s, nkv, hd)
+        v = v.reshape(b, s, nkv, dv)
         if cfg.qk_norm == "head":
             q = make_norm(cfg, "q_norm")(q).astype(cfg.dtype)
             k = make_norm(cfg, "k_norm")(k).astype(cfg.dtype)
@@ -1964,8 +2241,27 @@ class SelfAttention(nn.Module):
             raise ValueError(f"unknown qk_norm {cfg.qk_norm!r}")
         if cfg.positions == "rotary" and (kind == "sliding" or
                                           cfg.rope_on_full_attention):
-            q = apply_rotary(q, position_ids, cfg.rope_theta)
-            k = apply_rotary(k, position_ids, cfg.rope_theta)
+            turned, theta = cfg.rotary_dim, cfg.rope_theta_of(kind)
+
+            def rotate(x):
+                if not turned:
+                    return apply_rotary(x, position_ids, theta)
+                # the leading channels turn, the others pass
+                return jnp.concatenate(
+                    [apply_rotary(x[..., :turned], position_ids, theta),
+                     x[..., turned:]], axis=-1)
+
+            q, k = rotate(q), rotate(k)
+        sink = None
+        if kind in cfg.sink_kinds:
+            # a logit a query head in the softmax's denominator.  A model
+            # made from a seed draws it around ln(window), not around 0: a
+            # sink then takes about as much as the window's keys together
+            # (scores of random weights are unit normal), as a trained
+            # sink does, where one at the scale of a single score takes
+            # 1 / 129 of a head's mass and its absence hides in rounding
+            sink = self.param("sink", _sink_init(window), (nh,),
+                              jnp.float32)
 
         new_cache = None
         # the scope of the attention core (the cache's update, scores,
@@ -1975,39 +2271,47 @@ class SelfAttention(nn.Module):
         if block and window:
             raise ValueError("a block-causal mask (GPTConfig.block_length) "
                              "goes with full attention layers only")
-        with jax.named_scope(ATTENTION_SCOPE):
+        of_kind = jax.named_scope(
+            WINDOW_CORE_SCOPE if window else FULL_CORE_SCOPE) \
+            if cfg.unlike_kinds else contextlib.nullcontext()
+        with jax.named_scope(ATTENTION_SCOPE), of_kind:
             if kv_cache is not None and window:
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 k_use, v_use, k_positions, new_cache = update_ring_cache(
                     kv_cache, k, v, cache_lengths)
                 out = reference_attention(
                     q, k_use, v_use, causal=True, offset=index,
-                    window=window, k_positions=k_positions)
+                    window=window, k_positions=k_positions, sink=sink)
             elif kv_cache is not None:
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 new_cache = update_kv_cache(kv_cache, k, v)
                 # the written caches as they lie: the causal offset alone
                 # hides what a row has not reached (``update_kv_cache``)
                 out = cached_attention(q, *new_cache[:2], index,
-                                       block=block)
-            elif padding_bias is not None or window or nkv != nh or block:
+                                       block=block, sink=sink)
+            elif padding_bias is not None or window or nkv != nh or \
+                    block or dv != hd or sink is not None:
                 # additive padding bias: encoder path only (the ring and
                 # ulysses cores take no bias operand, no window and no
                 # grouped heads)
-                if (window or nkv != nh or block) and \
+                if (window or nkv != nh or block or dv != hd or
+                        sink is not None) and \
                         cfg.attention_impl != "reference":
                     raise ValueError(
                         "sliding-window, grouped-query and block-causal "
-                        "attention need attention_impl 'reference'")
+                        "attention, values narrower than the keys and a "
+                        "sink need attention_impl 'reference'")
                 out = reference_attention(q, k, v, causal=cfg.causal,
                                           bias=padding_bias, window=window,
-                                          block=block)
+                                          block=block, sink=sink)
             else:
                 attn_fn = get_attention_fn(cfg)
                 out = attn_fn(q, k, v, causal=cfg.causal)
-        out = out.reshape(b, s, nh * hd)
+        out = out.reshape(b, s, nh * dv)
+        if cfg.value_scale != 1.0:
+            out = out * jnp.asarray(cfg.value_scale, out.dtype)
         if cfg.attn_gate:
-            out = out * jax.nn.sigmoid(dense(nh * hd, name="gate")(x))
+            out = out * jax.nn.sigmoid(dense(nh * dv, name="gate")(x))
         out = dense(h, name="out")(out)
         return out, new_cache
 
@@ -2269,10 +2573,26 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     ((B, conv_taps - 1, hidden_size), (B, 0)), the second array empty so
     that the entry is a triple as every layer's is.
     Takes any decoder family's configuration: what ``GPTConfig`` alone has
-    reads as its default."""
+    reads as its default.
+
+    A "full" or "sliding" layer whose values are narrower than its keys
+    (``GPTConfig.v_head_dim``) holds a pair too, each kind with its own
+    key/value heads (``GPTConfig.kv_heads_of``).  The ring keeps its
+    heads: ((B, window, Hkv, D), (B, window, Hkv, Dv)).  The full layer's
+    pair has them FOLDED into the channels: ((B, seq_len, Hkv D), (B,
+    seq_len, Hkv Dv)).  Keys of 192 channels a head are a lane tile and a
+    half: kept a head, the TPU compiler lays such a cache out with its
+    positions minor-most (``_write_rows``), where a block of positions is
+    no block of memory; four heads' 768 channels are six whole tiles, the
+    cache lies as it is named, and the kernel that reads it takes it as
+    it lies (``ops/cached_attention.py`` ``folded_cached_attention``)."""
     heads = getattr(config, "num_kv_heads", None) or config.num_heads
     hd = getattr(config, "head_dim", None) or \
         config.hidden_size // config.num_heads
+    # values narrower than the keys, key/value heads a kind (``GPTConfig``
+    # alone)
+    dv = getattr(config, "value_size", hd)
+    heads_of = getattr(config, "kv_heads_of", lambda kind: heads)
     kinds = getattr(config, "attention", "full")
     shapes = []
     for i in range(config.num_layers):
@@ -2302,7 +2622,15 @@ def kv_cache_shapes(config, batch_size: int) -> list:
             continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
-        shapes.append((batch_size, length, heads, hd))
+        kv = heads_of(kind)
+        if dv == hd:
+            shapes.append((batch_size, length, kv, hd))
+        elif kind == "sliding":
+            shapes.append(((batch_size, length, kv, hd),
+                           (batch_size, length, kv, dv)))
+        else:
+            shapes.append(((batch_size, length, kv * hd),
+                           (batch_size, length, kv * dv)))
     return shapes
 
 
@@ -2352,9 +2680,19 @@ def cached_key_block(config, queries: int) -> int:
             of(1, queries, config.num_heads, config.kv_lora_rank),
             of(1, config.seq_len, config.kv_lora_rank))
         return latent_attention.DECODE_BLOCK_K if takes else 0
-    takes = "full" in kinds and cached_attention.fits(
-        of(1, queries, config.num_heads, config.head_size),
-        of(1, config.seq_len, config.kv_heads, config.head_size))
+    if "full" not in kinds:
+        return 0
+    q = of(1, queries, config.num_heads, config.head_size)
+    if config.value_size != config.head_size:
+        # folded caches (``kv_cache_shapes``)
+        caches = (of(1, config.seq_len, config.kv_heads * config.head_size),
+                  of(1, config.seq_len, config.kv_heads * config.value_size))
+        if "full" in config.sink_kinds or \
+                not cached_attention.folded_fits(q, *caches):
+            return 0
+        return cached_attention.folded_block_k(*caches)
+    takes = "full" not in config.sink_kinds and cached_attention.fits(
+        q, of(1, config.seq_len, config.kv_heads, config.head_size))
     return cached_attention.block_k(config.kv_heads, config.head_size) \
         if takes else 0
 
@@ -2421,6 +2759,20 @@ def require_uniform_kv_caches(config, what: str):
             "attention: a latent and a shared rotary key a position, in "
             "two arrays of unlike shapes, no heads): "
             f"{sorted(set(kv_cache_shapes(config, 1)))}")
+    if getattr(config, "value_size", None) not in (
+            None, getattr(config, "head_size", None)):
+        shapes = sorted({shape for entry in kv_cache_shapes(config, 1)
+                         for shape in entry}, key=str)
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape, and "
+            "this configuration's layers hold keys wider than their values "
+            f"(GPTConfig.v_head_dim: {config.head_size} and "
+            f"{config.value_size} channels a head), a \"full\" layer's "
+            "with the heads folded into the channels, a \"sliding\" "
+            f"layer's a ring of {config.kv_heads_of('sliding')} key/value "
+            f"heads where a full layer has {config.kv_heads}: "
+            f"{len(shapes)} shapes in one model, K and V unlike in every "
+            f"layer: {shapes}")
     if not uniform_kv_caches(config):
         raise ValueError(
             f"{what} indexes one cache shape for all layers, and this "

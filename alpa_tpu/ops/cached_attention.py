@@ -37,6 +37,23 @@ before the values' product, a position past a row's reach contributing
 exactly nothing (its probability is an exact 0 whatever finite value lies
 there).
 
+Keys wider than the values, neither in whole lanes a head
+(``folded_cached_attention``: keys of 192 channels, values of 128).  A
+cache kept a head, (B, S, Hkv, 192), the compiler lays out with its
+positions minor-most, as it does narrow heads, beside values of 128 that
+lie as named: two orders in one layer, and 192 x 4 rows of positions a
+block.  Such a layer's caches hold the heads FOLDED into the channels,
+(B, S, Hkv 192) and (B, S, Hkv 128), whole lanes both
+(``gpt_model.kv_cache_shapes``), so both lie as named, and the kernel
+reads them as they lie: a key block is (positions, Hkv 192), each query
+sits in the channels of its head's group with zeros in the others' (as
+with narrow heads above), so one product over all ``Hkv 192`` channels
+scores every head against its own group's keys and a column is a
+position; the values' product gives every group's channels, of which a
+head keeps its own.  The matrix unit loads each key and value element
+once, as it does when the heads' keys are rows, and the softmax works on
+``s H x positions`` scores, not on ``Hkv`` times as many.
+
 The kernel is compiled where the program is lowered for a TPU
 (``gpt_model.cached_attention`` chooses between it and
 ``reference_attention`` with ``lax.platform_dependent``);
@@ -220,3 +237,102 @@ def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0,
         out = jnp.einsum("bshgd,hg->bshd",
                          out.reshape(b, s, nh, nkv, dim), group)
     return out.reshape(b, s, nh, dim)
+
+
+def folded_block_k(k_cache, v_cache) -> int:
+    """Positions a key block of folded caches holds: the largest power of
+    two whose keys stay within ``BLOCK_ELEMENTS``."""
+    per_block = BLOCK_ELEMENTS // k_cache.shape[2]
+    return 1 << (per_block.bit_length() - 1)
+
+
+def folded_fits(q, k_cache, v_cache) -> bool:
+    """Whether ``folded_cached_attention`` takes these shapes: a few new
+    queries a row in whole sublanes, both caches' channels in whole lanes
+    (the key/value heads together), few enough key/value heads, the cache
+    in whole key blocks."""
+    _, s, nh, dim = q.shape
+    seq_len, width = k_cache.shape[1], k_cache.shape[2]
+    if width % dim:
+        return False
+    nkv = width // dim
+    if (s > MAX_QUERIES or nh % nkv or (s * nh) % 16 or
+            nkv > MAX_KV_HEADS or v_cache.shape[2] % nkv or
+            width % LANES or v_cache.shape[2] % LANES or
+            width > BLOCK_ELEMENTS // 16):
+        return False
+    return seq_len % folded_block_k(k_cache, v_cache) == 0
+
+
+def _folded_kernel(blocks_ref, offset_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, scale: float, heads: int):
+    b, kb = pl.program_id(0), pl.program_id(1)
+    pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        # keys (position, key/value head x channel), the queries each in
+        # the channels of its head's group: a column is a position
+        per_block = k_ref.shape[0]
+        row = lax.broadcasted_iota(jnp.int32, (q_ref.shape[0], 1), 0)
+        s = scale * lax.dot_general(
+            q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        k_pos = kb * per_block + lax.broadcasted_iota(
+            jnp.int32, (1, per_block), 1)
+        _fold_in(s, k_pos <= offset_ref[b] + row // heads, v_ref[:], m_ref,
+                 l_ref, acc_ref)
+
+    pl.when(kb == pl.num_programs(1) - 1)(
+        lambda: _finish(o_ref, l_ref, acc_ref))
+
+
+def folded_cached_attention(q, k_cache, v_cache, offset, *,
+                            interpret: bool = False):
+    """``q`` (B, s, H, D) against written caches with the heads folded into
+    the channels, ``k_cache`` (B, S, Hkv D) and ``v_cache`` (B, S, Hkv
+    Dv); row ``b``'s query i sits at ``offset[b] + i`` ((B,) int32) and
+    sees the keys at or before it.  Returns (B, s, H, Dv) in the queries'
+    dtype."""
+    b, s, nh, dim = q.shape
+    seq_len, k_width = k_cache.shape[1], k_cache.shape[2]
+    nkv, v_width = k_width // dim, v_cache.shape[2]
+    dv = v_width // nkv
+    per_block = folded_block_k(k_cache, v_cache)
+    offset = offset.astype(jnp.int32)
+    blocks = blocks_read(offset + s - 1, per_block, seq_len)
+    # which key/value head a head reads
+    group = jax.nn.one_hot(jnp.arange(nh) // (nh // nkv), nkv, dtype=q.dtype)
+    q = (q[:, :, :, None, :] * group[:, :, None]).reshape(b, s * nh, k_width)
+
+    def per_row(b_, kb, blocks_ref, offset_ref):
+        return b_, 0, 0
+
+    def key_block(b_, kb, blocks_ref, offset_ref):
+        return b_, _block_of(b_, kb, blocks_ref), 0
+
+    out = pl.pallas_call(
+        functools.partial(_folded_kernel, scale=float(1 / np.sqrt(dim)),
+                          heads=nh),
+        out_shape=jax.ShapeDtypeStruct((b, s * nh, v_width), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, seq_len // per_block),
+            in_specs=[
+                pl.BlockSpec((None, s * nh, k_width), per_row),
+                pl.BlockSpec((None, per_block, k_width), key_block),
+                pl.BlockSpec((None, per_block, v_width), key_block),
+            ],
+            out_specs=pl.BlockSpec((None, s * nh, v_width), per_row),
+            scratch_shapes=[pltpu.VMEM((s * nh, 1), jnp.float32),
+                            pltpu.VMEM((s * nh, 1), jnp.float32),
+                            pltpu.VMEM((s * nh, v_width), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        # what a device trace calls the kernel's events
+        name="cached_attention_folded_key_blocks",
+    )(blocks, offset, q, k_cache, v_cache)
+    return jnp.einsum("bshgd,hg->bshd", out.reshape(b, s, nh, nkv, dv),
+                      group)

@@ -136,13 +136,40 @@ _KV_CACHE_BYTES = _REG.gauge(
     "layer's normed latent and shared rotary key; with an index key a "
     "position where the layer selects its positions; a ring of the "
     "window's latents where the layer is under the window) or conv (a "
-    "short convolution's state: its last positions, whatever the context)",
+    "short convolution's state: its last positions, whatever the context); "
+    "the arrays' sizes as the device lays them out (held_bytes)",
     labelnames=("kind",))
+_KV_CACHE_ARRAY_BYTES = _REG.gauge(
+    "alpa_serving_kv_cache_array_bytes",
+    "The same bytes by the kind of the layers' cache, the array (keys, "
+    "values: a latent layer's latents and shared keys, a short "
+    "convolution's state and nothing) and the shape one layer's array has, "
+    "summed over the layers that hold it: a configuration whose kinds "
+    "differ in heads and whose keys are wider than its values has four "
+    "series",
+    labelnames=("kind", "array", "shape"))
 
 # every engine span: category "serving", on this track (the queue waits,
 # which overlap each other, on their own)
 _TRACK = "serve-engine"
 _QUEUE_TRACK = "serve-queue"
+
+
+def held_bytes(x) -> int:
+    """The bytes the device holds for the array ``x``: its elements with
+    the two minor-most dimensions of its layout rounded up to the layout's
+    first tile, where the device says how it tiles (a TPU pads a
+    minor-most dimension to whole lanes); ``x.nbytes`` where it does not."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    tiling = getattr(layout, "tiling", None)
+    if not tiling or not tiling[0]:
+        return x.nbytes
+    # the dimensions from the minor-most up, each tile dimension padding one
+    dims = [x.shape[d] for d in reversed(layout.major_to_minor)]
+    for at, tile in enumerate(reversed(tiling[0])):
+        if at < len(dims):
+            dims[at] = -(-dims[at] // tile) * tile
+    return int(np.prod(dims, dtype=np.int64)) * x.dtype.itemsize
 
 
 def _lowered_for_tpu() -> bool:
@@ -499,11 +526,18 @@ class ContinuousBatchingEngine:
         # by the kind of the layer's entry; a short convolution's is its
         # state, as large a row whatever the context
         by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0}
+        by_array = {}
         for kind, (k, v, _i) in zip(kv_cache_kinds(cfgm), self._caches):
             # every latent layer's entry is "latent", whatever it holds
-            by_kind[kind.partition("_")[0]] += k.nbytes + v.nbytes
+            kind = kind.partition("_")[0]
+            for array, x in (("keys", k), ("values", v)):
+                by_kind[kind] += held_bytes(x)
+                series = (kind, array, "x".join(map(str, x.shape)))
+                by_array[series] = by_array.get(series, 0) + held_bytes(x)
         for kind, nbytes in by_kind.items():
             _KV_CACHE_BYTES.labels(kind).set(nbytes)
+        for series, nbytes in by_array.items():
+            _KV_CACHE_ARRAY_BYTES.labels(*series).set(nbytes)
         # what the last decode said of its routed layers ({}: no decode
         # yet, or no such layers): read back with the next tick's tokens
         self._routing = {}

@@ -10,6 +10,7 @@ Supports greedy / temperature / top-k sampling, batched requests, and a
 pluggable parallel method (ShardParallel on one mesh today; the pipeshard
 inference schedule slots in via the same executable interface).
 """
+import collections
 import dataclasses
 import logging
 import threading
@@ -337,6 +338,11 @@ def row_length(n: int):
 ADMISSION_STEP = 0.25
 
 
+# Bytes of cache arrays that chunk steps dispatched and not yet run may
+# hold (``Generator._run_chunked_prefill``)
+CHUNK_CACHES_AHEAD_BYTES = 2**31
+
+
 def default_prompt_buckets(seq_len: int) -> List[int]:
     """Power-of-two prompt-length buckets up to seq_len."""
     buckets, b = [], 32
@@ -547,6 +553,9 @@ class Generator:
             return logits[:, 0, :], caches, {}
 
         self.prefill_chunk = prefill_chunk
+        # the logits of the last chunk step sent, where the steps in
+        # flight are bounded (``_run_chunked_prefill``)
+        self._last_chunk = None
         self._parallel_method = parallel_method
 
         def chunk_prefill(params, ids_chunk, lengths, caches, last):
@@ -679,10 +688,33 @@ class Generator:
             init_last = jnp.zeros((b, self.config.vocab_size),
                                   self.config.dtype)
         last = init_last
+        # the chunk step does not donate its caches (they may be a prefix
+        # handle's), and a dispatch allocates its results at once: a host
+        # that runs two prompts of 22 chunks ahead of the device holds 44
+        # sets of a row's caches (7.5 GB where a set is 171 MB).  Where a
+        # whole context's chunks of such sets would not fit
+        # ``CHUNK_CACHES_AHEAD_BYTES``, the host lets the prompt before
+        # this one finish first, and waits for the chunk it sent ``ahead``
+        # chunks ago before it sends the next: the device is never without
+        # a chunk but between two prompts.  A configuration whose sets are
+        # small is dispatched as far ahead as the host gets, as ever
+        ahead = CHUNK_CACHES_AHEAD_BYTES // max(1, sum(
+            getattr(kc, "nbytes", 0) + getattr(vc, "nbytes", 0)
+            for kc, vc, _i in caches))
+        bounded = ahead < self.config.seq_len // c
+        ahead = max(2, ahead)
+        if bounded and self._last_chunk is not None:
+            jax.block_until_ready(self._last_chunk)
+        sent = collections.deque()
         for ci in range(n_chunks):
             chunk = jnp.asarray(ids[:, ci * c:(ci + 1) * c])
             last, caches = self._chunk_prefill(self.params, chunk,
                                                lengths_j, caches, last)
+            sent.append(last)
+            if bounded and len(sent) > ahead:
+                jax.block_until_ready(sent.popleft())
+        if bounded:
+            self._last_chunk = last
         # per-row decode positions take over from the scalar chunk index
         caches = [(kc, vc, lengths_j) for (kc, vc, _i) in caches]
         return last, caches

@@ -39,6 +39,12 @@ part                         components
                              projections, scores and choice
 ``attention.latent_select``  ``latent_select`` inside it: the selected
                              positions' gather and the core over them
+``attention.window_core``    ``window_core`` inside it: a window layer's
+                             core (its ring, the softmax with its sink)
+                             where the window and the full layers differ
+                             in more than the mask
+``attention.full_core``      ``full_core`` inside it: there, a full
+                             layer's core over its cache
 ``short_conv``               ``model.gpt_model.CONV_SCOPE``, a gated short
                              convolution whole: both products, the gates,
                              the taps, the state's update (and ``conv``,
@@ -135,6 +141,10 @@ _COMPONENTS = {
     # core of a latent layer that selects its positions
     "indexer": "attention.indexer",
     "latent_select": "attention.latent_select",
+    # model/gpt_model.py WINDOW_CORE_SCOPE, FULL_CORE_SCOPE: the cores of
+    # the two kinds of layer where they differ in more than the mask
+    "window_core": "attention.window_core",
+    "full_core": "attention.full_core",
     "short_conv": "short_conv", "conv": "short_conv",
     "mlp": "mlp",
     "moe": "moe",

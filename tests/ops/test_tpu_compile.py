@@ -13,6 +13,7 @@ worker imports every test file.
 import functools
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -441,3 +442,84 @@ def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
         jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch))
     assert donated_accumulator_faults(executable) == []
+
+
+# ---- keys wider than values, the heads folded (PR 51) --------------------
+
+def test_folded_cached_attention_compiles_for_v5e(one_chip):
+    """``ops/cached_attention.py`` ``folded_cached_attention`` at the
+    ``mimo-v2-flash-1chip`` cell's shapes (32 rows of 32,768 positions, 64
+    query heads over 4 key/value heads, keys of 192 channels and values of
+    128, the heads folded into 768 and 512 channels): one Pallas kernel,
+    inside its fast memory, handed each cache as it lies."""
+    from alpa_tpu.ops import cached_attention as ca
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, keys, values = (spec(32, 1, 64, 192), spec(32, 32768, 768),
+                       spec(32, 32768, 512))
+    assert ca.folded_fits(q, keys, values)
+    assert ca.folded_block_k(keys, values) == 512
+    compiled = jax.jit(ca.folded_cached_attention).lower(
+        q, keys, values, spec(32, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the caches as they were named: no padding, no other order
+    assert "bf16[32,32768,768]{2,1,0:T(8,128)(2,1)}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+def test_a_mimo_tick_and_chunk_read_what_the_rows_hold_on_v5e(one_chip):
+    """The decode tick and the chunk step of ``mimo-v2-flash-1chip`` as its
+    cell compiles them (the published widths, the cell's depth, 32 rows,
+    served context 32,768, bfloat16).  The tick: its two full layers run
+    the folded kernel, beside the caches it holds megabytes, and no array
+    of a full cache's size is a copy or a transpose.  The chunk: no array
+    holds a chunk's scores against every position the cache can hold."""
+    import json
+    from alpa_tpu.model.gpt_model import GPTModel, init_kv_caches
+    from alpa_tpu.serve.generation import Generator
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from chipbench import run
+    hf = run.load_json(run.HERE, "configs", "mimo-v2-flash-1chip.json")
+    serve = hf["serve"]
+    rows, chunk, context = (serve["engine_rows"], serve["prefill_chunk"],
+                            serve["served_context"])
+    cfg = run.load_module("drivers", "serve_mla").model_config(
+        hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=context)
+    model = GPTModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)))
+    gen = Generator(model, params, cfg, prefill_chunk=chunk)
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    tick = gen._decode.jitted.lower(
+        params, spec(rows, 1), spec(rows),
+        on_chip([(k, v) for k, v, _ in caches]),
+        [spec(rows) for _ in caches]).compile()
+    text = tick.as_text()
+    assert text.startswith("HloModule jit_decode")
+    assert text.count("cached_attention_folded_key_blocks") >= 2
+    assert tick.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    moved = [line for line in text.splitlines()
+             if re.search(r"= bf16\[%d,%d,(768|512)\]" % (rows, context),
+                          line) and re.search(r" (copy|transpose)\(", line)]
+    assert not moved, moved[:3]
+    step = gen._chunk_prefill.lower(
+        params, spec(1, chunk), spec(1),
+        on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1))),
+        spec(1, cfg.vocab_size, dtype=jnp.bfloat16)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 2**30
+    assert not re.search(r"\[[\d,]*%d,%d\]" % (chunk, context),
+                         step.as_text())

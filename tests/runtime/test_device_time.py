@@ -63,6 +63,14 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
      "attention.latent_select"),
     ("jit(chunk_prefill)/GPTModel/h5/attn/attention/latent_select/"
      "cond/branch_1_fun/pallas_call", "attention.latent_select"),
+    # window and full layers that differ in more than the mask (PR 51)
+    ("jit(decode)/GPTModel/h1/attn/attention/window_core/exp",
+     "attention.window_core"),
+    ("jit(decode)/GPTModel/h6/attn/attention/full_core/"
+     "jit(_folded_key_blocks)/cond/branch_1_fun/pallas_call",
+     "attention.full_core"),
+    ("jit(decode)/GPTModel/h1/attn/attention/window_core/cache_write/"
+     "dynamic_update_slice", "attention.cache_write"),
     ("jit(decode)/GPTModel/h0/mlp/fc_in/dot_general", "mlp"),
     ("jit(decode)/GPTModel/h2/mlp/moe/router/dot_general", "moe"),
     ("jit(decode)/GPTModel/h2/mlp/moe/grouped_matmul/cond/branch_0_fun/"
