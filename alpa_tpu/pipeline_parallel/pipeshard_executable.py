@@ -73,11 +73,15 @@ class _InputLoad:
 class StageExecutable:
     """One compiled stage bound to one mesh.
 
-    Two-phase: ``plan()`` runs the intra-op planner and exposes
-    ``in_shardings``; the driver may then *unify* shardings of values
-    shared across same-mesh stages (see ``_unify_same_mesh_shardings``)
-    before ``compile()`` locks them in — eliminating runtime relayouts
-    between stages on one mesh.
+    Two-phase: ``plan()`` runs the intra-op planner, handed what the
+    programs planned before it on the mesh decided (``fixed_in``: a value
+    one of them produces arrives as produced, one it reads as it reads
+    it), and keeps the plan: ``planned_in``, how each input arrives, and
+    ``planned_out``, how each output leaves (None where the plan does not
+    say).  The driver then reads the plans together
+    (``_unify_same_mesh_shardings``) and ``compile()`` locks in what they
+    agree on, so that no value is re-laid out between two programs of one
+    mesh, at run time or by GSPMD at a program's end.
     """
 
     def __init__(self, name, comp, mesh_id, physical_mesh, as_option,
@@ -119,9 +123,11 @@ class StageExecutable:
         fun = jaxpr_as_fun(closed)
         avals = [v.aval for v in self.comp.invars]
         as_option = self._as_option
-        # what the planner did with the donated pairs
-        # (``solver.alias_stats``; empty where it was handed none)
-        self.alias_stats: Dict[str, int] = {}
+        # what the planner did with the donated pairs and the given
+        # inputs (``solver.alias_stats``, ``solver.given_stats``; empty
+        # where no planner ran)
+        self.plan_stats: Dict[str, int] = {}
+        planned_out: List[Any] = []
 
         if physical_mesh.num_devices > 1 and as_option.enable_auto_sharding:
             from alpa_tpu.shard_parallel.solver import plan_auto_sharding
@@ -146,7 +152,7 @@ class StageExecutable:
                 fun, avals, in_paths, [], physical_mesh, opt,
                 alias_pairs=self.donated_pairs() + self._state_pairs,
                 fixed_in=self._fixed_in, stage=self.name,
-                stats=self.alias_stats)
+                stats=self.plan_stats, out_shardings=planned_out)
             if cfn is not None:
                 fun = cfn  # realize the ILP plan inside the stage too
         else:
@@ -171,8 +177,15 @@ class StageExecutable:
         self._avals = avals
         self.jax_mesh = jax_mesh
         self.in_shardings = list(in_shardings)
-        # consumer-pinned output shardings (filled by unification)
+        # the plan as it was made, which unification reads and
+        # ``in_shardings`` / ``pinned_out`` may leave
+        self.planned_in = list(in_shardings)
+        self.planned_out = planned_out or [None] * len(self.outvars)
+        # output shardings as the programs of the mesh agree on them, and
+        # how many of this program's inputs and outputs that agreement
+        # took from what it planned (both filled by unification)
         self.pinned_out: Dict[Var, Any] = {}
+        self.unify_overrides = 0
 
     def donated_pairs(self) -> List[Tuple[int, int]]:
         """(invar position, outvar position) of every summed gradient
@@ -240,49 +253,75 @@ class StageExecutable:
         return self.compiled(*args)
 
 
+def _same_sharding(a, b, ndim: int) -> bool:
+    return a is b or a.is_equivalent_to(b, ndim)
+
+
+def _decide_shardings(ex: "StageExecutable", chosen: Dict, canon):
+    """Add to ``chosen`` ((mesh, value) -> sharding) what program ``ex``
+    is the first on its mesh to decide: a value it reads, in the sharding
+    it planned to read it in; a value it produces, in the sharding its
+    plan has it leave in (an accumulator's sum in its donation's lock; an
+    output the plan says nothing of is left to its first reader)."""
+    for v, planned in zip(ex.invars, ex.planned_in):
+        chosen.setdefault((ex.mesh_id, canon(v)), planned)
+    locked = ex.donated_out_shardings()
+    for v, planned in zip(ex.outvars, ex.planned_out):
+        s = locked.get(v, planned)
+        if s is not None:
+            chosen.setdefault((ex.mesh_id, canon(v)), s)
+
+
 def _unify_same_mesh_shardings(execs: List["StageExecutable"],
                                var_alias: Optional[Dict[Var, Var]] = None):
-    """Align shardings of values shared between stages on one mesh:
+    """Read the plans of the programs of each mesh together, in the order
+    they were planned (``execs``'s), and lock in one sharding a value:
 
-    * multiple consumers of the same var on a mesh adopt the first
-      consumer's planned sharding,
-    * producers pin their output sharding of a var to its same-mesh
-      consumer's input sharding,
+    * the first program to decide a value decides it for the mesh
+      (``_decide_shardings``),
+    * every later reader's ``in_shardings`` entry and the producer's
+      ``pinned_out`` are that sharding.
 
-    so no runtime relayout (same-mesh device_put) is needed between
-    stages.  Call after every stage's plan() and before any compile().
-    Returns the agreed sharding by (mesh, value), which a program planned
-    later takes as given; a second call with those programs added agrees
-    with the first on everything the first decided.
+    A program planned with the earlier decisions given (``fixed_in``) has
+    already paid in its own objective for whatever else it wanted, and
+    this changes nothing of it.  One planned without them (an update
+    program under a forced or disabled ``zero_stage``) is handed them
+    here, after its plan, and ``unify_overrides`` counts how many of its
+    inputs and outputs that moved from what it planned.
+
+    Call once, after every program's plan() and before any compile().
     """
-    # (mesh_id, var) -> chosen sharding (first consumer wins).
     # ``var_alias`` canonicalizes distinct Vars naming the same runtime
     # value (gradient-marker `post` vars alias the accumulator's summed
-    # outvar), so apply stages adopt the accumulator shardings.
+    # outvar; a donated state leaf's new value is named as the old), so
+    # apply stages adopt the accumulator shardings and write each leaf as
+    # the next step reads it.
     var_alias = var_alias or {}
 
     def canon(v):
         return var_alias.get(v, v)
 
     chosen: Dict[Tuple[int, Var], Any] = {}
-    # accumulator sum outputs are donation-locked to the acc input's
-    # sharding — seed those first so consumers (apply stages) adopt them
     for ex in execs:
-        for ov, s in ex.donated_out_shardings().items():
-            chosen[(ex.mesh_id, canon(ov))] = s
+        _decide_shardings(ex, chosen, canon)
+    read = {(ex.mesh_id, canon(v)) for ex in execs for v in ex.invars}
     for ex in execs:
+        ex.unify_overrides = 0
         for pos, v in enumerate(ex.invars):
+            s = chosen[(ex.mesh_id, canon(v))]
+            ex.in_shardings[pos] = s
+            ex.unify_overrides += not _same_sharding(
+                s, ex.planned_in[pos], len(v.aval.shape))
+        locked = ex.donated_out_shardings()
+        for v, planned in zip(ex.outvars, ex.planned_out):
             key = (ex.mesh_id, canon(v))
-            if key in chosen:
-                ex.in_shardings[pos] = chosen[key]
-            else:
-                chosen[key] = ex.in_shardings[pos]
-    for ex in execs:
-        for v in ex.outvars:
-            s = chosen.get((ex.mesh_id, canon(v)))
-            if s is not None and v not in ex.donated_out_shardings():
-                ex.pinned_out[v] = s
-    return chosen
+            # an output that no program of the mesh reads is the
+            # compiler's to lay out
+            if key in read and v not in locked:
+                ex.pinned_out[v] = chosen[key]
+                ex.unify_overrides += planned is not None and \
+                    not _same_sharding(chosen[key], planned,
+                                       len(v.aval.shape))
 
 
 class PipeshardDriverExecutable:
@@ -334,39 +373,6 @@ class PipeshardDriverExecutable:
                 return None
             return [self.invar_paths.get(v, "") for v in comp.invars]
 
-        self.stage_execs: List[StageExecutable] = []
-        self._stage_of_comp = {}
-        tic = time.time()
-        for s, comp in enumerate(fwd_stages):
-            donate = [
-                i for i, v in enumerate(comp.invars) if v in self.acc_pairs
-            ]
-            self.stage_execs.append(
-                StageExecutable(comp.name, comp, s, self.mesh_group[s],
-                                as_option, logical_shapes[s], donate,
-                                as_dicts[s] if as_dicts else None,
-                                in_paths=stage_paths(comp)))
-        for s, comp in enumerate(bwd_stages):
-            donate = [
-                i for i, v in enumerate(comp.invars) if v in self.acc_pairs
-            ]
-            self.stage_execs.append(
-                StageExecutable(comp.name, comp, s, self.mesh_group[s],
-                                as_option, logical_shapes[s], donate,
-                                as_dicts[s] if as_dicts else None,
-                                in_paths=stage_paths(comp)))
-        self.num_fwd_stages = len(fwd_stages)
-        self.has_bwd = len(bwd_stages) > 0
-        # Donate state inputs (params/opt state) to the apply executables
-        # that consume them exactly once — realizes the caller's
-        # donate_argnums contract so old and new state never coexist.
-        donated_global = {
-            v for v, d in zip(global_invars, donated_invars) if d
-        }
-        use_count: Dict[Var, int] = {}
-        for comp in apply_comps:
-            for v in comp.invars:
-                use_count[v] = use_count.get(v, 0) + 1
         post_to_sum = {
             post: acc_info[pre][1]
             for pre, post in grad_pairs if pre in acc_info
@@ -384,50 +390,100 @@ class PipeshardDriverExecutable:
             o.aval.shape == i.aval.shape and o.aval.dtype == i.aval.dtype
         }
         same_value = {**new_to_old, **post_to_sum}
-        # What the forward and backward stages agree on is given to an
-        # apply program before it is planned: the parameters as their
-        # first reader wants them, the summed gradients as their
-        # accumulators' donation locks them.  Its own choice is the rest
-        # (the optimizer's state), made with those in view and with each
-        # donated leaf's new value held to the leaf's sharding.
+
+        # On a mesh, a program planned after another takes every value the
+        # earlier ones decided as given (a value one of them produces
+        # arrives as its plan has it leave, one it reads as it reads it),
+        # and its own ILP pays for whatever else its products want.  The
+        # forward stages come first and find nothing decided; a backward
+        # stage finds its forward stage's residuals, parameters and pieces
+        # of the batch.
+        self.stage_execs: List[StageExecutable] = []
+        self._stage_of_comp = {}
+        tic = time.time()
+
+        self.apply_execs: List[Optional[StageExecutable]] = []
+        # (mesh, value) -> sharding, as decided by the programs planned so
+        # far (``_decide_shardings``)
+        decided: Dict[Tuple[int, Var], Any] = {}
+
+        def same(v):
+            return same_value.get(v, v)
+
+        def planned(comp, mesh_id, donate, given=True, **kwargs):
+            """The next program of a mesh, planned with what is decided
+            there given (``fixed_in``) unless told not to; what it is the
+            first to decide is decided from here on."""
+            fixed_in = {i: decided[(mesh_id, same(v))]
+                        for i, v in enumerate(comp.invars)
+                        if given and (mesh_id, same(v)) in decided}
+            ex = StageExecutable(comp.name, comp, mesh_id,
+                                 self.mesh_group[mesh_id], as_option,
+                                 logical_shapes[mesh_id], donate,
+                                 in_paths=stage_paths(comp),
+                                 fixed_in=fixed_in, **kwargs)
+            _decide_shardings(ex, decided, same)
+            return ex
+
+        for s, comp in itertools.chain(enumerate(fwd_stages),
+                                       enumerate(bwd_stages)):
+            donate = [
+                i for i, v in enumerate(comp.invars) if v in self.acc_pairs
+            ]
+            self.stage_execs.append(planned(
+                comp, s, donate,
+                as_overrides=as_dicts[s] if as_dicts else None))
+        self.num_fwd_stages = len(fwd_stages)
+        self.has_bwd = len(bwd_stages) > 0
+        # Donate state inputs (params/opt state) to the apply executables
+        # that consume them exactly once — realizes the caller's
+        # donate_argnums contract so old and new state never coexist.
+        donated_global = {
+            v for v, d in zip(global_invars, donated_invars) if d
+        }
+        use_count: Dict[Var, int] = {}
+        for comp in apply_comps:
+            for v in comp.invars:
+                use_count[v] = use_count.get(v, 0) + 1
+        # An apply program is handed the same under ``zero_stage="auto"``:
+        # the parameters as their first reader wants them, the summed
+        # gradients as their accumulators' donation locks them.  Its own
+        # choice is the rest (the optimizer's state), made with those in
+        # view and with each donated leaf's new value held to the leaf's
+        # sharding.
         # That is weight-update sharding chosen by cost, which is what
         # ``zero_stage="auto"`` asks for: "0" turns it off and "2"/"3" lay
         # the state out by their own rule, and under those the apply
-        # programs are planned as they were.
-        agreed = (_unify_same_mesh_shardings(self.stage_execs, same_value)
-                  if resolved_zero_stage(as_option) == -1 else {})
-        self.apply_execs: List[Optional[StageExecutable]] = []
+        # programs are planned as they were, and handed the rest after.
+        by_cost = resolved_zero_stage(as_option) == -1
         for m, comp in enumerate(apply_comps):
             if comp.eqns or comp.outvars:
                 donate = [
                     i for i, v in enumerate(comp.invars)
                     if v in donated_global and use_count.get(v) == 1
                 ]
-                fixed_in = {
-                    i: agreed[(m, same_value.get(v, v))]
-                    for i, v in enumerate(comp.invars)
-                    if (m, same_value.get(v, v)) in agreed
-                }
                 position = {v: i for i, v in enumerate(comp.invars)}
                 state_pairs = [
                     (position[new_to_old[o]], k)
                     for k, o in enumerate(comp.outvars)
-                    if agreed and position.get(new_to_old.get(o)) in donate
+                    if by_cost and position.get(new_to_old.get(o)) in donate
                 ]
-                self.apply_execs.append(
-                    StageExecutable(comp.name, comp, m, self.mesh_group[m],
-                                    as_option, logical_shapes[m], donate,
-                                    in_paths=stage_paths(comp),
-                                    fixed_in=fixed_in,
-                                    state_pairs=state_pairs))
+                self.apply_execs.append(planned(
+                    comp, m, donate, given=by_cost,
+                    state_pairs=state_pairs))
             else:
                 self.apply_execs.append(None)
-        # unify shardings of values shared across same-mesh stages, then
-        # compile everything with the agreed layouts
+        # read the plans together, then compile everything with the
+        # layouts they agree on
         all_execs = self.stage_execs + [
             e for e in self.apply_execs if e is not None
         ]
-        _unify_same_mesh_shardings(all_execs, same_value)
+        with _ttrace.span("unify-shardings", "compile",
+                          {} if _ttrace.enabled() else None) as unify_span:
+            _unify_same_mesh_shardings(all_execs, same_value)
+            if getattr(unify_span, "args", None) is not None:
+                unify_span.args["unify_overrides"] = {
+                    e.name: e.unify_overrides for e in all_execs}
         for e in all_execs:
             e.compile()
         if global_config.print_compilation_time:
@@ -1781,7 +1837,7 @@ class PipeshardDriverExecutable:
             stage_execs=(self.stage_execs +
                          [e for e in self.apply_execs if e is not None]),
             mode=mode, run_stats=stats)
-        report.donated = self._alias_stats_by_program()
+        report.donated = self._plan_stats_by_program()
         _perf.publish_report(report)
         try:
             # fold the measured step into the calibration store (ISSUE
@@ -2010,9 +2066,11 @@ class PipeshardDriverExecutable:
 
     def get_resharding_report(self) -> str:
         """Planned cross-mesh traffic per step (tile-level accounting from
-        cross_mesh_resharding.plan_resharding), and, a line a program,
-        what the planner did with the pairs that share a donated buffer
-        (``solver.alias_stats``)."""
+        cross_mesh_resharding.plan_resharding), and, by program, what the
+        planner did with the pairs that share a donated buffer and with
+        the inputs it was handed as given (``solver.alias_stats``,
+        ``solver.given_stats``), and what unification then took from the
+        plan (``unify_overrides``)."""
         n = sum(1 for i in self.instructions
                 if i.opcode == PipelineInstType.RESHARD and
                 i.src_mesh != i.dst_mesh)
@@ -2026,16 +2084,17 @@ class PipeshardDriverExecutable:
                 f"; executed {self._executed_resharding_bytes / 1e6:.3f} MB "
                 f"cross-mesh + {self._executed_intra_mesh_bytes / 1e6:.3f} MB "
                 f"intra-mesh ({global_config.resharding_execution})")
-        from alpa_tpu.telemetry.perf import format_alias_stats
-        for name, stats in self._alias_stats_by_program().items():
-            report += "\n" + format_alias_stats(name, stats)
+        from alpa_tpu.telemetry.perf import format_plan_stats
+        for name, stats in self._plan_stats_by_program().items():
+            report += "".join("\n" + line
+                              for line in format_plan_stats(name, stats))
         return report
 
-    def _alias_stats_by_program(self) -> Dict[str, Dict[str, int]]:
+    def _plan_stats_by_program(self) -> Dict[str, Dict[str, int]]:
         return {
-            e.name: dict(e.alias_stats)
+            e.name: dict(e.plan_stats, unify_overrides=e.unify_overrides)
             for e in self.stage_execs + self.apply_execs
-            if e is not None and e.alias_stats
+            if e is not None and e.plan_stats
         }
 
     def sync(self):

@@ -110,6 +110,47 @@ def alias_stats(graph, choice) -> dict:
             "alias_reshard_bytes": int(moved)}
 
 
+def given_stats(graph, choice) -> dict:
+    """What a solution does with the invars it was handed as given
+    (``fixed_in``): how many there are, how many of them arrive sharded,
+    and the bytes a device receives in a run to bring each from the layout
+    it arrives in to the one the program reads it in (0 where each is read
+    as it arrives)."""
+    arrive = {i: graph.nodes[i].strategies[0].out_spec
+              for i in graph.relayouts}
+    moved = sum(
+        resharding_bytes(graph.nodes[i].aval, arrive[i],
+                         graph.nodes[b].strategies[choice[b]].out_spec,
+                         graph.logical_mesh.shape)
+        for i, b in graph.relayouts.items())
+    return {"given_in": len(arrive),
+            "given_sharded": sum(not is_replicated(s)
+                                 for s in arrive.values()),
+            "given_reshard_bytes": int(moved)}
+
+
+def planned_out_specs(graph, choice) -> list:
+    """How each output leaves under a solution: one of a donated pair as
+    its invar arrives (the pair's edge has priced the way there), any
+    other in its source node's chosen ``out_spec`` through the follow
+    chain's dim map.  None for a literal and for a value the graph holds
+    as a barrier: GSPMD lays that one out, and the plan says nothing of
+    it."""
+    specs = []
+    for k, (ov, src) in enumerate(zip(graph.outvars, graph.out_sources)):
+        if src is None or graph.nodes[src[0]].barrier:
+            specs.append(None)
+            continue
+        if k in graph.alias_outs:
+            inv = graph.alias_outs[k]
+            specs.append(graph.nodes[inv].strategies[choice[inv]].out_spec)
+            continue
+        chosen = graph.nodes[src[0]].strategies[choice[src[0]]]
+        specs.append(map_spec(chosen.out_spec, src[1],
+                              len(ov.aval.shape))[0])
+    return specs
+
+
 def plan_auto_sharding(fun: Callable,
                        in_avals: Sequence[Any],
                        in_paths: Sequence[str],
@@ -120,7 +161,8 @@ def plan_auto_sharding(fun: Callable,
                        alias_pairs: Sequence[Tuple[int, int]] = (),
                        fixed_in: Optional[dict] = None,
                        stage: str = "",
-                       stats: Optional[dict] = None):
+                       stats: Optional[dict] = None,
+                       out_shardings: Optional[list] = None):
     """Search logical mesh shapes; returns
     (jax_mesh, flat in_shardings, constraint_fn or None, chosen_shape);
     with ``return_graph`` also (graph, choice) of the winning solve —
@@ -133,7 +175,10 @@ def plan_auto_sharding(fun: Callable,
     with whatever is planned here; one that a candidate shape's axes
     cannot express is planned as if it were free.  ``stage`` names the
     program in the solve's span; ``stats``, where given, receives
-    ``alias_stats`` of the chosen solution, as the span does."""
+    ``alias_stats`` and ``given_stats`` of the chosen solution, as the
+    span does; ``out_shardings``, where given, receives the sharding each
+    output leaves in under that solution (``planned_out_specs``: None
+    where the plan does not say)."""
     closed_jaxpr = jax.make_jaxpr(fun)(*in_avals)
     alias_pairs = tuple((int(i), int(o)) for i, o in alias_pairs)
     fixed_in = dict(sorted((fixed_in or {}).items()))
@@ -162,11 +207,13 @@ def plan_auto_sharding(fun: Callable,
             # grad-quantize token (ISSUE 19): same contract — absent at
             # grad_quantize=off; the donated pairs: a graph with their
             # edges has the nodes of one without, so a cached choice of
-            # the other would replay
+            # the other would replay; the given inputs (``given:``, where
+            # PR 50 wrote ``fixed:`` for a one-strategy node: a choice of
+            # that graph means another layout in this one)
         ] + ([cal_tok] if cal_tok else [])
           + ([gq_tok] if gq_tok else [])
           + ([f"alias:{alias_pairs}"] if alias_pairs else [])
-          + ([f"fixed:{[(i, str(s.spec)) for i, s in fixed_in.items()]}"]
+          + ([f"given:{[(i, str(s.spec)) for i, s in fixed_in.items()]}"]
              if fixed_in else []))
         entry = cache.get("ilp", key)
         if entry is not None:
@@ -177,7 +224,7 @@ def plan_auto_sharding(fun: Callable,
                     closed_jaxpr, in_avals, in_paths, batch_flat_idx,
                     physical_mesh, option, entry, alias_pairs, fixed_in)
                 if replayed is not None:
-                    _note_alias_stats(replayed[2], replayed[3], stage,
+                    _note_plan_stats(replayed[2], replayed[3], stage,
                                       replay_span, stats)
             if replayed is not None:
                 cache.record_saved_seconds(
@@ -186,7 +233,7 @@ def plan_auto_sharding(fun: Callable,
                 return _assemble_plan(closed_jaxpr, in_avals, in_paths,
                                       batch_flat_idx, option, shape,
                                       logical_mesh, graph, choice,
-                                      return_graph)
+                                      return_graph, out_shardings)
 
     solve_span = _ttrace.begin(
         "ilp-solve", "compile",
@@ -235,7 +282,7 @@ def plan_auto_sharding(fun: Callable,
         raise infeasible
     cost, shape, logical_mesh, graph, choice = best
     solve_seconds = time.time() - tic
-    _note_alias_stats(graph, choice, stage, solve_span, stats)
+    _note_plan_stats(graph, choice, stage, solve_span, stats)
     _ttrace.end(solve_span)
     if global_config.print_compilation_time:
         logger.warning("auto-sharding search took %.2f s; picked %s "
@@ -251,7 +298,7 @@ def plan_auto_sharding(fun: Callable,
 
     return _assemble_plan(closed_jaxpr, in_avals, in_paths, batch_flat_idx,
                           option, shape, logical_mesh, graph, choice,
-                          return_graph)
+                          return_graph, out_shardings)
 
 
 def _fixed_specs(fixed_in, shape, in_avals) -> dict:
@@ -271,13 +318,19 @@ def _fixed_specs(fixed_in, shape, in_avals) -> dict:
     return specs
 
 
-def _note_alias_stats(graph, choice, stage, span, stats):
-    """``alias_stats`` of the chosen solution into the solve's span (beside
-    the program's name) and into the caller's ``stats``; nothing for a
-    program without donated pairs."""
-    if not graph.alias_edges:
+def _note_plan_stats(graph, choice, stage, span, stats):
+    """``alias_stats`` (of a program with donated pairs) and ``given_stats``
+    (of one its caller names: a program planned among others, which reads
+    ``given_in`` 0 where nothing was decided before it) of the chosen
+    solution into the solve's span, beside the program's name, and into
+    the caller's ``stats``."""
+    found = {}
+    if graph.alias_edges:
+        found.update(alias_stats(graph, choice))
+    if stage or graph.relayouts:
+        found.update(given_stats(graph, choice))
+    if not found:
         return
-    found = alias_stats(graph, choice)
     if getattr(span, "args", None) is not None:
         span.args.update(found, stage=stage)
     if stats is not None:
@@ -317,12 +370,18 @@ def _replay_cached_solution(closed_jaxpr, in_avals, in_paths,
 
 
 def _assemble_plan(closed_jaxpr, in_avals, in_paths, batch_flat_idx, option,
-                   shape, logical_mesh, graph, choice, return_graph):
+                   shape, logical_mesh, graph, choice, return_graph,
+                   out_shardings=None):
     """Turn a solved (graph, choice) into the plan_auto_sharding result
     tuple.  Shared by the fresh-solve path and the cache-replay path."""
     _note_grad_quantized_choices(graph, choice)
     axis_names = MESH_AXIS_NAMES[:len(shape)]
     jax_mesh = logical_mesh.get_jax_mesh(axis_names)
+    if out_shardings is not None:
+        out_shardings[:] = [
+            spec if spec is None else NamedSharding(
+                jax_mesh, spec_to_partition_spec(spec, axis_names))
+            for spec in planned_out_specs(graph, choice)]
 
     # Assemble invar shardings from the solved assignment.
     in_shardings: List[Optional[NamedSharding]] = [None] * len(in_avals)

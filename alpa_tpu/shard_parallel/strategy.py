@@ -135,6 +135,9 @@ class Node:
     invar_idx: Optional[int] = None
     # op nodes: the eqn's primary outvar (for constraint emission)
     outvar: Optional[Var] = None
+    # a value the graph does not model and holds replicated (an opaque
+    # op's output, a constant): GSPMD lays it out, not the plan
+    barrier: bool = False
 
 
 @dataclasses.dataclass
@@ -153,6 +156,16 @@ class StrategyGraph:
     # (source node, dim map var<-node, invar node) of every donated pair
     # (``build_strategy_graph``'s ``alias_pairs``)
     alias_edges: List[Tuple[int, DimMap, int]] = dataclasses.field(
+        default_factory=list)
+    # outvar position -> invar node, of every donated pair: the output
+    # leaves as that invar arrives
+    alias_outs: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # of every invar planned as given (``fixed_in_specs``): its node, which
+    # holds the layout it arrives in -> the node behind it, which holds the
+    # layout the program reads it in
+    relayouts: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # (source node, dim map var<-node) of every outvar, None for a literal
+    out_sources: List[Optional[Tuple[int, DimMap]]] = dataclasses.field(
         default_factory=list)
 
     def stats(self):
@@ -305,12 +318,41 @@ def _dot_semantic_dims(eqn):
             rhs_free)
 
 
-def enumerate_dot_strategies(eqn, logical_mesh) -> List[Strategy]:
+def _reduce_scattered(strategy: Strategy, out_av, out_map, out_bytes,
+                      ar_axes, logical_mesh) -> List[Strategy]:
+    """The variants of a strategy that all-reduces partial results over
+    ``ar_axes`` in which one of those axes reduce-scatters them instead,
+    over an output dimension that ``out_map`` (dim -> axis) leaves whole
+    (``k0@1>1``): half the bytes, and the result leaves sharded."""
+    found = []
+    ndim = len(out_av.shape)
+    for a in ar_axes:
+        for d in range(ndim):
+            spec = make_spec(ndim, {**out_map, d: a})
+            if d in out_map or not spec_valid(out_av, spec,
+                                              logical_mesh.shape):
+                continue
+            found.append(dataclasses.replace(
+                strategy, name=f"{strategy.name}>{d}", out_spec=spec,
+                comm_cost=strategy.comm_cost -
+                logical_mesh.all_reduce_cost(out_bytes, a) +
+                logical_mesh.reduce_scatter_cost(out_bytes, a),
+                comm_kind="reduce_scatter"))
+    return found
+
+
+def enumerate_dot_strategies(eqn, logical_mesh,
+                             scatter: bool = False) -> List[Strategy]:
     """The dot handler (analog of ref ``auto_sharding_dot_handler.cc``).
 
     Enumerates assignments of each non-trivial mesh axis to one semantic
     role: a batch dim (Sb), an lhs free dim (Si), an rhs free dim (Sj), or
     a contracting dim (Sk -> all-reduce of the output on that axis).
+
+    ``scatter``: a contracting axis may also reduce-scatter the output
+    (``_reduce_scattered``): what a weight gradient wants whose operands
+    arrive sharded over the positions it contracts and whose sum lands in
+    a sharded accumulator.
     """
     mesh_shape = logical_mesh.shape
     lhs_av, rhs_av = eqn.invars[0].aval, eqn.invars[1].aval
@@ -381,6 +423,9 @@ def enumerate_dot_strategies(eqn, logical_mesh) -> List[Strategy]:
         seen.add(key)
         strategies.append(Strategy(name, out_spec, cost,
                                    (lhs_spec, rhs_spec)))
+        if scatter:
+            strategies += _reduce_scattered(strategies[-1], out_av, out_map,
+                                            out_bytes, ar_axes, logical_mesh)
     if not strategies:
         strategies.append(Strategy("R", replicated_spec(out_ndim), 0.0,
                                    (replicated_spec(len(lhs_av.shape)),
@@ -655,7 +700,8 @@ SCATTER_PRIMS = frozenset({"scatter", "scatter-add", "scatter-mul",
                            "scatter-min", "scatter-max"})
 
 
-def enumerate_scatter_strategies(eqn, logical_mesh) -> Optional[List[Strategy]]:
+def enumerate_scatter_strategies(eqn, logical_mesh, scatter: bool = False
+                                 ) -> Optional[List[Strategy]]:
     """Scatter handler — the transpose of gather (embedding-gradient
     ``scatter-add`` is the headline case; KV-cache writes that lower to
     scatter take the same roles).  Output has the operand's shape.
@@ -667,7 +713,8 @@ def enumerate_scatter_strategies(eqn, logical_mesh) -> Optional[List[Strategy]]:
                  (GSPMD masks the rest), updates replicated, free;
       ('ub', k): shard the k-th updates batch dim — each shard scatters
                  its slice of updates, the operand-shaped partials
-                 all-reduce (grad-accumulation pattern).
+                 all-reduce (grad-accumulation pattern), or with
+                 ``scatter`` reduce-scatter (``_reduce_scattered``).
     """
     dn = eqn.params["dimension_numbers"]
     if dn.operand_batching_dims or dn.scatter_indices_batching_dims:
@@ -742,6 +789,9 @@ def enumerate_scatter_strategies(eqn, logical_mesh) -> Optional[List[Strategy]]:
         # out spec == operand spec (scatter writes in place)
         strategies.append(Strategy(name, op_spec, cost,
                                    (op_spec, idx_spec, upd_spec)))
+        if scatter:
+            strategies += _reduce_scattered(strategies[-1], out_av, op_map,
+                                            out_bytes, ar_axes, logical_mesh)
     if not strategies:
         strategies.append(Strategy("R", replicated_spec(op_ndim), 0.0,
                                    (replicated_spec(op_ndim),
@@ -909,6 +959,11 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
     edges: List[Edge] = []
     # var -> (node_idx, dimmap var<-node)
     var_node: Dict[Var, Tuple[int, DimMap]] = {}
+    # flat invar index -> its node (a given invar's holds the layout it
+    # arrives in; the program reads the node behind it, ``relayouts``)
+    invar_nodes: List[int] = []
+    given_invars: List[int] = []
+    relayouts: Dict[int, int] = {}
 
     def new_node(kind, aval, strategies, label="", invar_idx=None,
                  outvar=None):
@@ -918,14 +973,17 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
 
     def barrier_node(aval, label):
         nd = len(aval.shape) if hasattr(aval, "shape") else 0
-        return new_node("op", aval,
-                        [Strategy("R", replicated_spec(nd), 0.0)], label)
+        n = new_node("op", aval,
+                     [Strategy("R", replicated_spec(nd), 0.0)], label)
+        n.barrier = True
+        return n
 
     # --- invar nodes ---
     from alpa_tpu.shard_parallel.auto_sharding import (
         is_opt_state_path, is_param_path, resolved_zero_stage)
     zero = resolved_zero_stage(option)
     batch_set = set(batch_flat_idx)
+    paired = {in_idx for in_idx, _ in alias_pairs}
     for i, (v, aval) in enumerate(zip(jaxpr.invars, in_avals)):
         specs = enumerate_var_specs(aval, mesh_shape)
         if i in batch_set and option.force_batch_dim_to_mesh_dim is not None:
@@ -964,8 +1022,12 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
                     # the ring model the traffic terms cancel and the
                     # residual is the collective latency — the memory
                     # term (mem_bytes, 1/dp of the leaf) then decides.
-                    charge = sum(logical_mesh.all_gather_cost(nbytes, a)
-                                 for a in axes)
+                    # (One of a donated pair is charged nothing here: the
+                    # gather of what it is updated into is on the pair's
+                    # edge and on its kernel's.)
+                    charge = 0.0 if i in paired else sum(
+                        logical_mesh.all_gather_cost(nbytes, a)
+                        for a in axes)
                     credit = sum(
                         logical_mesh.all_reduce_cost(nbytes, a) -
                         logical_mesh.reduce_scatter_cost(nbytes, a)
@@ -1034,30 +1096,56 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
             ]
         n = new_node("invar", aval, strategies, f"invar{i}", invar_idx=i)
         var_node[v] = (n.idx, identity_dimmap(len(aval.shape)))
+        invar_nodes.append(n.idx)
+        if specs == (given,):
+            given_invars.append(i)
 
     # constvars: replicated barriers
     for v in jaxpr.constvars:
-        nd = len(v.aval.shape) if hasattr(v.aval, "shape") else 0
-        n = new_node("op", v.aval,
-                     [Strategy("R", replicated_spec(nd), 0.0)], "const")
-        var_node[v] = (n.idx, identity_dimmap(nd))
+        n = barrier_node(v.aval, "const")
+        var_node[v] = (n.idx, identity_dimmap(len(n.strategies[0].out_spec)))
 
-    def edge_cost_matrix(src_node: Node, dimmap: DimMap, aval,
-                         required: List[Spec]) -> np.ndarray:
-        """cost[s_src, s_req] of delivering src's value (viewed through
-        dimmap) as each required operand spec."""
+    def add_edge(src_idx: int, dst_idx: int, dimmap: DimMap, aval,
+                 required: List[Spec]):
+        """The edge src -> dst with cost[s_src, s_req] of delivering src's
+        value (viewed through dimmap) as each spec its reader may
+        require."""
+        src_node = nodes[src_idx]
         ndim = len(aval.shape) if hasattr(aval, "shape") else 0
+        size_bytes = (float(np.prod(aval.shape) if aval.shape else 1) *
+                      aval.dtype.itemsize)
         C = np.zeros((len(src_node.strategies), len(required)))
         for si, st in enumerate(src_node.strategies):
             mapped, dropped = map_spec(st.out_spec, dimmap, ndim)
-            size_bytes = (float(np.prod(aval.shape) if aval.shape else 1) *
-                          aval.dtype.itemsize)
             drop_cost = sum(logical_mesh.all_gather_cost(size_bytes, a)
                             for a in dropped)
             for ri, req in enumerate(required):
                 C[si, ri] = drop_cost + resharding_cost(
                     aval, mapped, req, logical_mesh)
-        return C
+        edges.append(Edge(src_idx, dst_idx, C))
+
+    # A given input arrives as it is given (its node has the one strategy)
+    # and the program reads it one node behind that, in a layout of the
+    # plan's choosing: every reader, as ``make_constrained_fun`` brings the
+    # argument itself there at the program's head.  The way there is paid
+    # once (one gather serves every op that wants the value whole), where
+    # an edge from the input to each reader would pay it once a reader.  At
+    # a tie nothing is moved at the head: what can be gathered at the tail
+    # instead (an update program's new kernel, where the other way is its
+    # sum's gather at the head) leaves the state between them sharded; the
+    # bias outweighs the free inputs' preference for replication.
+    for i in given_invars:
+        inv, given = nodes[invar_nodes[i]], fixed_in_specs[i]
+        specs = enumerate_var_specs(inv.aval, mesh_shape)
+        behind = new_node("op", inv.aval, [
+            Strategy(str(s), s, 0.0, tie_bias=1e-5 if resharding_cost(
+                inv.aval, given, s, logical_mesh) else 0.0)
+            for s in specs], f"relayout{i}")
+        relayouts[inv.idx] = behind.idx
+        add_edge(inv.idx, behind.idx, identity_dimmap(len(given)), inv.aval,
+                 list(specs))
+        var_node[jaxpr.invars[i]] = (behind.idx,
+                                     identity_dimmap(len(given)))
 
     def get_source(v):
         """Node+dimmap for a var, creating a replicated barrier for unknown
@@ -1087,7 +1175,10 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
 
         if prim in ("dot_general", "conv_general_dilated"):
             if prim == "dot_general":
-                strategies = enumerate_dot_strategies(eqn, logical_mesh)
+                # (only in a graph that is handed something: one that is
+                # handed nothing is the graph it was, strategy for strategy)
+                strategies = enumerate_dot_strategies(
+                    eqn, logical_mesh, scatter=bool(given_invars))
             else:
                 strategies = enumerate_conv_strategies(eqn, logical_mesh)
             out_av = eqn.outvars[0].aval
@@ -1100,9 +1191,8 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
                 if src is None:
                     continue
                 src_idx, dimmap = src
-                req = [st.operand_specs[oi] for st in strategies]
-                C = edge_cost_matrix(nodes[src_idx], dimmap, v.aval, req)
-                edges.append(Edge(src_idx, n.idx, C))
+                add_edge(src_idx, n.idx, dimmap, v.aval,
+                         [st.operand_specs[oi] for st in strategies])
             var_node[eqn.outvars[0]] = (n.idx,
                                         identity_dimmap(len(out_av.shape)))
             continue
@@ -1116,9 +1206,8 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
             src = get_source(v)
             if src is not None:
                 src_idx, dimmap = src
-                req = [st.operand_specs[0] for st in strategies]
-                C = edge_cost_matrix(nodes[src_idx], dimmap, v.aval, req)
-                edges.append(Edge(src_idx, n.idx, C))
+                add_edge(src_idx, n.idx, dimmap, v.aval,
+                         [st.operand_specs[0] for st in strategies])
             var_node[eqn.outvars[0]] = (n.idx,
                                         identity_dimmap(len(out_av.shape)))
             continue
@@ -1127,7 +1216,8 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
             if prim == "gather":
                 strategies = enumerate_gather_strategies(eqn, logical_mesh)
             else:
-                strategies = enumerate_scatter_strategies(eqn, logical_mesh)
+                strategies = enumerate_scatter_strategies(
+                    eqn, logical_mesh, scatter=bool(given_invars))
             if strategies is not None:
                 out_av = eqn.outvars[0].aval
                 n = new_node("op", out_av, strategies,
@@ -1141,9 +1231,8 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
                     if src is None:
                         continue
                     src_idx, dimmap = src
-                    req = [st.operand_specs[oi] for st in strategies]
-                    C = edge_cost_matrix(nodes[src_idx], dimmap, v.aval, req)
-                    edges.append(Edge(src_idx, n.idx, C))
+                    add_edge(src_idx, n.idx, dimmap, v.aval,
+                             [st.operand_specs[oi] for st in strategies])
                 var_node[eqn.outvars[0]] = (
                     n.idx, identity_dimmap(len(out_av.shape)))
                 continue
@@ -1249,11 +1338,10 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
                     if src is None:
                         continue
                     src_idx, dimmap = src
-                    req = [replicated_spec(len(v.aval.shape))]
-                    C = edge_cost_matrix(nodes[src_idx], dimmap, v.aval, req)
-                    edges.append(Edge(src_idx, n.idx, C))
+                    add_edge(src_idx, n.idx, dimmap, v.aval,
+                             [replicated_spec(len(v.aval.shape))])
 
-    graph = StrategyGraph(nodes, edges, logical_mesh)
+    graph = StrategyGraph(nodes, edges, logical_mesh, relayouts=relayouts)
     graph.closed_jaxpr = closed_jaxpr
     graph.flat_eqns = flat_eqns
     graph.invars = list(jaxpr.invars)
@@ -1263,21 +1351,22 @@ def build_strategy_graph(closed_jaxpr: ClosedJaxpr,
 
     graph.captured_consts = flatten_info.get("captured_consts", {})
 
+    graph.out_sources = [
+        None if isinstance(ov, Literal) else var_node.get(ov)
+        for ov in graph.outvars]
     # donated pairs: the output leaves in its invar's spec, whatever its
     # producer chose, and the way there is on the objective
     for in_idx, out_idx in alias_pairs:
-        ov = graph.outvars[out_idx]
-        inv = var_node[jaxpr.invars[in_idx]]
-        src = None if isinstance(ov, Literal) else var_node.get(ov)
+        inv_node = nodes[invar_nodes[in_idx]]
+        src = graph.out_sources[out_idx]
         if src is None:
             continue
-        graph.alias_edges.append((src[0], src[1], inv[0]))
-        if src == inv:
+        graph.alias_edges.append((src[0], src[1], inv_node.idx))
+        graph.alias_outs[out_idx] = inv_node.idx
+        if src == (inv_node.idx, identity_dimmap(len(inv_node.aval.shape))):
             continue  # the invar itself, or a sum that follows it
-        inv_node = nodes[inv[0]]
-        edges.append(Edge(src[0], inv[0], edge_cost_matrix(
-            nodes[src[0]], src[1], inv_node.aval,
-            [st.out_spec for st in inv_node.strategies])))
+        add_edge(src[0], inv_node.idx, src[1], inv_node.aval,
+                 [st.out_spec for st in inv_node.strategies])
     return graph
 
 
@@ -1360,7 +1449,17 @@ def make_constrained_fun(graph: StrategyGraph, choice, jax_mesh,
             if isinstance(v, Literal) or _too_small(v.aval):
                 continue
             in_cons[(pos, ii)] = _sharding(op_spec)
-    if not out_cons and not in_cons:
+    # A given input that the program reads in another layout than it
+    # arrives in (``StrategyGraph.relayouts``) is brought there once, at
+    # the program's head: what the plan paid for, and what keeps GSPMD from
+    # carrying the arriving layout into the ops and resharding around each.
+    head_cons = {}  # flat invar index -> NamedSharding
+    for inv_idx, behind_idx in graph.relayouts.items():
+        inv = graph.nodes[inv_idx]
+        spec = graph.nodes[behind_idx].strategies[choice[behind_idx]].out_spec
+        if spec != inv.strategies[0].out_spec and not _too_small(inv.aval):
+            head_cons[inv.invar_idx] = _sharding(spec)
+    if not out_cons and not in_cons and not head_cons:
         return None
 
     root = graph.closed_jaxpr
@@ -1381,6 +1480,15 @@ def make_constrained_fun(graph: StrategyGraph, choice, jax_mesh,
                 outer_in = [read(v) for v in eqn.invars]
                 aligned = _align_call_args(outer_in, sub_jaxpr.invars)
                 if prim in ("remat", "checkpoint", "remat2"):
+                    # ``jax.checkpoint`` binds the block anew as one that
+                    # has not been differentiated, which lowers with no
+                    # barrier; the block of a backward pass keeps the one
+                    # its own lowering would put on its inputs, or the
+                    # scheduler is free to recompute every block first
+                    # and hold them all
+                    if eqn.params.get("differentiated") and \
+                            eqn.params.get("prevent_cse", True):
+                        aligned = _jax.lax.optimization_barrier(aligned)
                     fn = functools.partial(
                         _remat_body, eval_jaxpr, sub_jaxpr, sub_consts,
                         depth)
@@ -1443,6 +1551,8 @@ def make_constrained_fun(graph: StrategyGraph, choice, jax_mesh,
                     _bind(eqn, env, read, depth)
             return [read(v) for v in jaxpr.outvars]
 
+        args = [_jax.lax.with_sharding_constraint(a, head_cons[i])
+                if i in head_cons else a for i, a in enumerate(args)]
         return eval_jaxpr(root.jaxpr, consts, args, 0)
 
     return constrained

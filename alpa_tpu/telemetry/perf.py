@@ -516,7 +516,10 @@ class StepPerfReport:
     stages: Dict[str, StageMfu]
     notes: List[str] = dataclasses.field(default_factory=list)
     # program -> what its planner did with the pairs that share a donated
-    # buffer (``solver.alias_stats``, from the ``ilp-solve`` spans' args)
+    # buffer and with the inputs it was handed as given, and what
+    # unification took from its plan (``solver.alias_stats``,
+    # ``solver.given_stats``, ``unify_overrides``; from the ``ilp-solve``
+    # and ``unify-shardings`` spans' args)
     donated: Dict[str, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
     # re-simulation model (kept for whatif; not part of the text report)
@@ -667,9 +670,10 @@ class StepPerfReport:
                     f"  {name:<24} {s.n_runs:5d} {s.run_time_us:10.1f} "
                     f"{s.tflops_per_chip:12.4f} {s.mfu:8.4f}")
         if self.donated:
-            lines += ["", "donated pairs, as the planner left them:"] + [
-                "  " + format_alias_stats(name, stats)
-                for name, stats in self.donated.items()]
+            lines += ["", "donated pairs and given inputs, as the planner "
+                      "left them:"] + [
+                "  " + line for name, stats in self.donated.items()
+                for line in format_plan_stats(name, stats)]
         if self.notes:
             lines += [""] + [f"note: {n}" for n in self.notes]
         return "\n".join(lines)
@@ -1119,17 +1123,49 @@ def format_alias_stats(program: str, stats: Dict[str, int]) -> str:
             "to their inputs' specs")
 
 
+def format_plan_stats(program: str, stats: Dict[str, int]) -> List[str]:
+    """A program's plan counters as lines of a report: what the planner
+    did with its donated pairs (``solver.alias_stats``), with the inputs
+    it was handed as given (``solver.given_stats``), and how many of its
+    shardings unification then took from the plan, whichever it has."""
+    lines = []
+    if "alias_pairs" in stats:
+        lines.append(format_alias_stats(program, stats))
+    if "given_in" in stats:
+        line = (f"{program}: {stats['given_in']} inputs given, "
+                f"{stats['given_sharded']} sharded, "
+                f"{stats['given_reshard_bytes']} B a run to re-lay them "
+                "out for their readers")
+        if "unify_overrides" in stats:
+            line += (f"; {stats['unify_overrides']} shardings moved from "
+                     "the plan by unification")
+        lines.append(line)
+    return lines
+
+
+_PLAN_STAT_KEYS = ("alias_pairs", "alias_sharded", "alias_reshard_bytes",
+                   "given_in", "given_sharded", "given_reshard_bytes")
+
+
 def donated_from_spans(spans: Sequence[Dict[str, Any]]
                        ) -> Dict[str, Dict[str, int]]:
     """``StepPerfReport.donated`` from the planner's spans (``ilp-solve``
-    or, for a plan replayed from the cache, ``ilp-cache-replay``)."""
-    keys = ("alias_pairs", "alias_sharded", "alias_reshard_bytes")
-    return {
-        s["args"]["stage"]: {k: int(s["args"][k]) for k in keys}
-        for s in spans
-        if s["name"] in ("ilp-solve", "ilp-cache-replay") and
-        all(k in (s.get("args") or {}) for k in keys + ("stage",))
-    }
+    or, for a plan replayed from the cache, ``ilp-cache-replay``) and the
+    driver's ``unify-shardings``."""
+    found: Dict[str, Dict[str, int]] = {}
+    for s in spans:
+        args = s.get("args") or {}
+        if s["name"] in ("ilp-solve", "ilp-cache-replay") and \
+                args.get("stage"):
+            found[args["stage"]] = {k: int(args[k]) for k in _PLAN_STAT_KEYS
+                                    if k in args}
+    for s in spans:
+        if s["name"] == "unify-shardings":
+            for program, n in ((s.get("args") or {})
+                               .get("unify_overrides") or {}).items():
+                if program in found:
+                    found[program]["unify_overrides"] = int(n)
+    return found
 
 
 def report_from_trace(trace: Dict[str, Any],

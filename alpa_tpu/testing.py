@@ -208,6 +208,52 @@ def donated_accumulator_faults(executable):
     return wrong
 
 
+def handed_over_faults(executable):
+    """What is wrong with the values a compiled pipeshard executable's
+    forward stage hands its backward stage on one mesh, as ``(program,
+    what)``; ``[]`` when every value the forward stage writes and the
+    backward stage reads leaves the one as it enters the other, every
+    value both read enters both alike, each of those is compiled as the
+    program's own plan had it (``planned_in``, ``planned_out``), and the
+    forward program's text all-gathers no array of the shape of a
+    handed-over output that leaves whole (a plan that GSPMD had to close
+    the gap to)."""
+    wrong = []
+    n = executable.num_fwd_stages
+    for fwd, bwd in zip(executable.stage_execs[:n],
+                        executable.stage_execs[n:]):
+        assert fwd.mesh_id == bwd.mesh_id, (fwd.name, bwd.name)
+        gathered = gathers_by_shape(fwd.compiled.as_text())
+        for i, v in enumerate(bwd.invars):
+            ndim = len(v.aval.shape)
+            enters = bwd.in_shardings[i]
+            if not enters.is_equivalent_to(bwd.planned_in[i], ndim):
+                wrong.append((bwd.name, f"{v.aval} read not as planned"))
+            if v in fwd.outvars:
+                k = fwd.outvars.index(v)
+                if not fwd.out_shardings[k].is_equivalent_to(enters, ndim):
+                    wrong.append((fwd.name, f"{v.aval} out != in"))
+                planned = fwd.planned_out[k]
+                if planned is not None and not planned.is_equivalent_to(
+                        fwd.out_shardings[k], ndim):
+                    wrong.append((fwd.name,
+                                  f"{v.aval} written not as planned"))
+                # (one that leaves sharded is not what a gather's result,
+                # which is whole on a mesh of two, was made for)
+                if fwd.out_shardings[k].is_fully_replicated and \
+                        gathered[_shape_of(v.aval)]:
+                    wrong.append((fwd.name, f"{v.aval} gathered"))
+            elif v in fwd.invars:
+                j = fwd.invars.index(v)
+                if not fwd.in_shardings[j].is_equivalent_to(enters, ndim):
+                    wrong.append((bwd.name, f"{v.aval} in != in"))
+                if not fwd.in_shardings[j].is_equivalent_to(
+                        fwd.planned_in[j], ndim):
+                    wrong.append((fwd.name,
+                                  f"{v.aval} read not as planned"))
+    return wrong
+
+
 def data_loader_input_iter_func(start, end, batch_size):
     """Deterministic fake-data iterator used by data loader tests."""
     num = (end - start) // batch_size

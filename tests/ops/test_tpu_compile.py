@@ -412,13 +412,16 @@ def test_a_trinity_chunk_holds_no_chunk_of_logits_on_v5e(one_chip):
     assert not re.search(r"\[(\d+,)?%d,%d\]" % (chunk, vocab), text)
 
 
-def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
+_PIPESHARD = {}
+
+
+def _pipeshard_on_v5e(topo, num_layers=2, hidden=256, seq=128):
     """Two pipeline stages of two chips compiled for the described 2x2
-    from shapes (the rehearsal cell's depth and method at widths a
-    product's sharding shows at): no backward stage gathers a summed
-    weight gradient, each update program gathers a kernel once
-    (``tests/pipeline_parallel/test_donated_accumulators.py`` holds the
-    same on four virtual CPU devices)."""
+    from shapes: the rehearsal cell's method at widths a product's
+    sharding shows at, ``num_layers // 2`` rematerialised blocks a stage
+    (the rehearsal cell's depth by default).  Compiled once a shape."""
+    if (num_layers, hidden, seq) in _PIPESHARD:
+        return _PIPESHARD[num_layers, hidden, seq]
     import alpa_tpu
     from alpa_tpu import PipeshardParallel
     from alpa_tpu.model.gpt_model import GPTConfig
@@ -426,11 +429,12 @@ def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
         ManualLayerOption)
     from alpa_tpu.pipeline_parallel.stage_construction import (
         UniformStageOption)
-    from alpa_tpu.testing import (donated_accumulator_faults,
-                                  get_gpt_train_step)
-    cfg = GPTConfig(vocab_size=1024, hidden_size=256, num_layers=2,
-                    num_heads=4, seq_len=128, dtype=jnp.bfloat16,
-                    remat_blocks=True, pipeline_boundary_every=1)
+    from alpa_tpu.testing import get_gpt_train_step
+    cfg = GPTConfig(vocab_size=1024, hidden_size=hidden,
+                    num_layers=num_layers, num_heads=4, seq_len=seq,
+                    dtype=jnp.bfloat16,
+                    remat_blocks=True,
+                    pipeline_boundary_every=num_layers // 2)
     method = PipeshardParallel(
         num_micro_batches=2, pipeline_schedule="1f1b",
         layer_option=ManualLayerOption(),
@@ -441,7 +445,68 @@ def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
         jax.eval_shape(create_state),
         jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch))
+    return _PIPESHARD.setdefault((num_layers, hidden, seq), executable)
+
+
+def test_pipeshard_stages_gather_no_accumulator_on_v5e(topo):
+    """No backward stage gathers a summed weight gradient or holds a
+    kernel's whole on both chips, each update program gathers a kernel
+    once (``tests/pipeline_parallel/test_donated_accumulators.py`` holds
+    the same on four virtual CPU devices)."""
+    from alpa_tpu.testing import donated_accumulator_faults
+    assert donated_accumulator_faults(_pipeshard_on_v5e(topo)) == []
+
+
+def test_pipeshard_forward_stage_gathers_no_logits_on_v5e(topo):
+    """The same two stages (ISSUE 52): what a forward stage hands its
+    backward stage leaves as the forward stage's plan produces it and
+    enters as the backward stage's plan was given it, so ``stage_1_fwd``
+    ends in no all-gather of the float32 logits (the parent's did: its
+    backward stage had chosen "whole" at no price and the output was
+    pinned to that), neither forward stage gathers a block's residual
+    for its backward stage, and unification moves nothing of the four.
+    ``stage_1_bwd`` gathers the logits' bfloat16 gradient no more either
+    (the parent's did, once a run): the table's product contracts over
+    the sequence the gradient is sharded over and reduce-scatters its
+    sum onto the sharded accumulator."""
+    from alpa_tpu.testing import (donated_accumulator_faults,
+                                  gathers_by_shape, handed_over_faults)
+    executable = _pipeshard_on_v5e(topo)
+    stages = {e.name: e for e in executable.stage_execs}
+    gathered = {name: gathers_by_shape(e.compiled.as_text())
+                for name, e in stages.items()}
+    assert gathered["stage_1_fwd"]["f32[2,128,1024]"] == 0
+    assert gathered["stage_1_bwd"]["f32[2,128,1024]"] == 0
+    assert gathered["stage_1_bwd"]["bf16[2,128,1024]"] == 0
+    for name in ("stage_0_fwd", "stage_1_fwd"):
+        assert gathered[name]["bf16[2,128,256]"] == 0
+        assert gathered[name]["f32[2,128,256]"] == 0
+    assert handed_over_faults(executable) == []
     assert donated_accumulator_faults(executable) == []
+    assert [e.unify_overrides for e in executable.stage_execs] == [0] * 4
+    for name in ("stage_0_bwd", "stage_1_bwd"):
+        assert stages[name].plan_stats["given_sharded"] > 0
+    # the logits leave over the sequence, as the head's product makes them
+    logits, = [k for k, v in enumerate(stages["stage_1_fwd"].outvars)
+               if v.aval.shape == (2, 128, 1024)]
+    assert not stages["stage_1_fwd"].out_shardings[
+        logits].is_fully_replicated
+
+
+def test_pipeshard_backward_stage_holds_one_block_at_a_time_on_v5e(topo):
+    """A backward stage of four rematerialised blocks recomputes a block
+    when it comes to it: its temporaries stay under two blocks' attention
+    scores (``memory_analysis()``; 22 MB here, where the scheduler, free
+    to recompute every block first, held 42).  What holds it there is the
+    barrier a differentiated block is lowered with, which
+    ``make_constrained_fun`` keeps when it evaluates the block anew."""
+    micro_batch, heads, seq = 2, 4, 1024
+    executable = _pipeshard_on_v5e(topo, num_layers=8, hidden=512, seq=seq)
+    scores_a_chip = micro_batch * heads * seq * seq * 4 // 2
+    for e in executable.stage_execs:
+        if e.name.endswith("_bwd"):
+            held = e.compiled.memory_analysis().temp_size_in_bytes
+            assert 0 < held < 2 * scores_a_chip, (e.name, held)
 
 
 # ---- keys wider than values, the heads folded (PR 51) --------------------

@@ -207,6 +207,8 @@ def test_unification_pins_an_aliased_output_to_its_readers_sharding():
         def __init__(self, mesh_id, invars, in_shardings, outvars):
             self.mesh_id, self.invars, self.outvars = mesh_id, invars, outvars
             self.in_shardings = list(in_shardings)
+            self.planned_in = list(in_shardings)
+            self.planned_out = [None] * len(outvars)
             self.pinned_out = {}
 
         def donated_out_shardings(self):

@@ -69,6 +69,7 @@ def toy():
     found = {
         "executable": executable,
         "spans": [s for s in spans if s["name"] == "ilp-solve"],
+        "all_spans": spans,
         "loss": (float(loss_s), float(loss_p)),
         "params": (jax.device_get(new_s.params),
                    jax.device_get(new_p.params)),
@@ -96,7 +97,7 @@ def test_solve_span_says_what_became_of_the_pairs(toy, stage):
     assert args["alias_pairs"] == len(exec_.donated_pairs()) > 0
     assert 0 < args["alias_sharded"] <= args["alias_pairs"]
     assert args["alias_reshard_bytes"] == 0
-    assert exec_.alias_stats == {k: args[k] for k in exec_.alias_stats}
+    assert exec_.plan_stats == {k: args[k] for k in exec_.plan_stats}
     assert (f"{stage}: {args['alias_pairs']} donated pairs, "
             f"{args['alias_sharded']} sharded, 0 B") in toy["report"]
 

@@ -228,6 +228,66 @@ class TestConstraintEmission:
         np.testing.assert_allclose(np.asarray(want), np.asarray(got),
                                    rtol=1e-5, atol=1e-5)
 
+    def test_a_differentiated_block_keeps_its_barrier(self):
+        """A rematerialised block of a backward pass is lowered with an
+        optimization barrier on its inputs, which orders its recomputation
+        after the gradient that asks for it.  The constrained function
+        evaluates the block anew through ``jax.checkpoint``, which binds
+        one that has not been differentiated: it puts the barrier there
+        itself, once a block of the backward pass and none for a block of
+        the forward pass."""
+        from alpa_tpu.device_mesh import get_global_cluster
+        from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
+        from alpa_tpu.shard_parallel.solver import plan_auto_sharding
+
+        alpa_tpu.init("local")
+        mesh = get_global_cluster().get_physical_mesh()
+        D = 512
+
+        def fn(w1, w2, x):
+
+            @jax.checkpoint
+            def blk(w, x):
+                return jnp.tanh(x @ w)
+
+            def loss(w1, w2):
+                return blk(w2, blk(w1, x)).sum()
+
+            return jax.grad(loss, argnums=(0, 1))(w1, w2)
+
+        avals = [
+            jax.ShapeDtypeStruct((D, D), jnp.float32),
+            jax.ShapeDtypeStruct((D, D), jnp.float32),
+            jax.ShapeDtypeStruct((8, D), jnp.float32),
+        ]
+        _, _, cfn, _ = plan_auto_sharding(fn, avals, ["w1", "w2", "x"], [2],
+                                          mesh, AutoShardingOption())
+        assert cfn is not None
+
+        def count(jx, name):
+            n = 0
+            for e in jx.eqns:
+                n += e.primitive.name == name
+                for v in e.params.values():
+                    sub = getattr(v, "jaxpr", v)
+                    if hasattr(sub, "eqns"):
+                        n += count(sub, name)
+            return n
+
+        differentiated = sum(
+            bool(e.params.get("differentiated"))
+            for e in jax.make_jaxpr(fn)(*avals).jaxpr.eqns
+            if e.primitive.name in ("remat2", "checkpoint"))
+        assert differentiated == 2
+        assert count(jax.make_jaxpr(cfn)(*avals).jaxpr,
+                     "optimization_barrier") == differentiated
+        rs = np.random.RandomState(0)
+        args = [jnp.asarray(rs.randn(*a.shape).astype(np.float32))
+                for a in avals]
+        for want, got in zip(fn(*args), cfn(*args)):
+            np.testing.assert_allclose(np.asarray(want), np.asarray(got),
+                                       rtol=1e-5, atol=1e-5)
+
     def test_ilp_choice_realized_in_hlo_gpt(self):
         """Fidelity: the all-reduces in compiled HLO equal the comm-bearing
         strategies the ILP chose (planner choice == HLO reality)."""

@@ -222,5 +222,258 @@ class TestDonatedAccumulator:
         assert again[2].spec == with_pairs[2].spec
 
 
+def _head_backward(logits, hidden, table):
+    """The shape of a head's backward: the logits, as the forward stage
+    left them, through an elementwise chain into the table's product."""
+    import jax.numpy as jnp
+    grad = jnp.exp(logits) * 0.5
+    return grad.T @ hidden + table
+
+
+class TestGivenInput:
+    """``fixed_in``: a value another program of the mesh decided arrives
+    as it was decided, and the plan pays for whatever else its products
+    want (ISSUE 52)."""
+
+    SHAPES = ((512, 1024), (512, 256), (1024, 256))
+
+    def _plan(self, fixed_in=None, **kwargs):
+        import jax
+        import jax.numpy as jnp
+        from alpa_tpu.device_mesh import LocalPhysicalDeviceMesh
+        from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
+        from alpa_tpu.shard_parallel.solver import plan_auto_sharding
+        avals = [jax.ShapeDtypeStruct(s, jnp.float32) for s in self.SHAPES]
+        opt = AutoShardingOption(logical_mesh_shape=(1, 2),
+                                 constrain_min_elements=0)
+        mesh = LocalPhysicalDeviceMesh(jax.devices()[:2])
+        return plan_auto_sharding(_head_backward, avals, [""] * 3, [],
+                                  mesh, opt, fixed_in=fixed_in, **kwargs)
+
+    @staticmethod
+    def _only(graph, node, name):
+        """Leave a node the one strategy called ``name``."""
+        keep = [k for k, st in enumerate(node.strategies)
+                if st.name == name]
+        assert len(keep) == 1, [st.name for st in node.strategies]
+        node.strategies = [node.strategies[k] for k in keep]
+        for e in graph.edges:
+            if e.src == node.idx:
+                e.cost = e.cost[keep, :]
+            if e.dst == node.idx:
+                e.cost = e.cost[:, keep]
+
+    def _costs(self, fixed_in):
+        """The objective with the product left to the solver, and with it
+        held to the strategy that wants the chain's value whole."""
+        from alpa_tpu.shard_parallel.ilp import (solution_cost,
+                                                 solve_strategy_graph)
+        *_, (graph, choice) = self._plan(fixed_in, return_graph=True)
+        dot = next(n for n in graph.nodes if n.label.startswith("dot"))
+        free = solution_cost(graph, choice), dot.strategies[
+            choice[dot.idx]].name
+        self._only(graph, dot, "j0@1")
+        return graph, free, solution_cost(graph,
+                                          solve_strategy_graph(graph))
+
+    def test_the_strategy_that_slices_a_given_operand_wins_by_a_price(self):
+        from jax.sharding import NamedSharding, PartitionSpec
+        from alpa_tpu.shard_parallel.solver import given_stats
+        mesh, *_ = self._plan()
+        over_vocab = NamedSharding(mesh, PartitionSpec(None, "mesh1"))
+        # nothing given: the product's two output splits tie at no cost
+        _, (cost, _), held = self._costs(None)
+        assert cost == held == 0.0
+        # the logits given sharded over the vocabulary: ``i0@1`` slices
+        # them as they lie; ``j0@1`` wants the chain whole and pays the
+        # gather, once
+        graph, (cost, name), held = self._costs({0: over_vocab})
+        assert (cost, name) == (0.0, "i0@1")
+        logits = graph.nodes[next(iter(graph.relayouts))]
+        nbytes = 512 * 1024 * 4
+        assert held == graph.logical_mesh.all_gather_cost(nbytes, 1) > 0
+        assert [st.out_spec for st in logits.strategies] == [((), (1,))]
+        *_, (graph, choice) = self._plan({0: over_vocab},
+                                         return_graph=True)
+        assert given_stats(graph, choice) == {
+            "given_in": 1, "given_sharded": 1, "given_reshard_bytes": 0}
+
+    def test_a_second_reader_shares_the_gather(self):
+        """Two products that want a given value whole pay one gather:
+        they read it, as every reader does, one node behind the input."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+        from alpa_tpu.device_mesh import LocalPhysicalDeviceMesh
+        from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
+        from alpa_tpu.shard_parallel.ilp import solution_cost
+        from alpa_tpu.shard_parallel.solver import (given_stats,
+                                                    plan_auto_sharding)
+
+        def two_readers(x, w1, w2):
+            return x @ w1, jnp.tanh(x) @ w2
+
+        avals = [jax.ShapeDtypeStruct(s, jnp.float32)
+                 for s in ((256, 512), (512, 8), (512, 8))]
+        opt = AutoShardingOption(logical_mesh_shape=(1, 2),
+                                 constrain_min_elements=0)
+        mesh = LocalPhysicalDeviceMesh(jax.devices()[:2])
+        jax_mesh, *_ = plan_auto_sharding(two_readers, avals, [""] * 3, [],
+                                          mesh, opt)
+        given = NamedSharding(jax_mesh, PartitionSpec(None, "mesh1"))
+        _, in_sh, _, _, (graph, choice) = plan_auto_sharding(
+            two_readers, avals, [""] * 3, [], mesh, opt,
+            fixed_in={0: given}, return_graph=True)
+        assert in_sh[0].spec == given.spec
+        behind = [n for n in graph.nodes if n.label == "relayout0"]
+        assert len(behind) == 1
+        assert sorted(e.dst for e in graph.edges
+                      if e.src == behind[0].idx) == sorted(
+            n.idx for n in graph.nodes if n.label.startswith("dot"))
+        # what is cheapest here: each product contracts over its slice
+        # and all-reduces a [256, 8]; held whole, the value is gathered
+        # once for both
+        one_gather = graph.logical_mesh.all_gather_cost(256 * 512 * 4, 1)
+        assert 0 < solution_cost(graph, choice) < one_gather
+        from alpa_tpu.shard_parallel.ilp import solve_strategy_graph
+        for dot in [n for n in graph.nodes if n.label.startswith("dot")]:
+            self._only(graph, dot, "j0@1")
+        choice = solve_strategy_graph(graph)
+        assert solution_cost(graph, choice) == one_gather
+        assert given_stats(graph, choice)["given_reshard_bytes"] == \
+            256 * 512 * 4 // 2
+
+    def test_a_product_reduce_scatters_onto_its_accumulator(self):
+        """A weight gradient whose operands arrive sharded over the
+        positions it contracts: the sum lands in a sharded accumulator by
+        a reduce-scatter (half an all-reduce's bytes, and nothing whole on
+        both chips), in the plan and in the compiled program."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+        from alpa_tpu.device_mesh import LocalPhysicalDeviceMesh
+        from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
+        from alpa_tpu.shard_parallel.ilp import solution_cost
+        from alpa_tpu.shard_parallel.solver import (alias_stats,
+                                                    plan_auto_sharding)
+
+        def accumulate(x, dy, acc):
+            return acc + x.T @ dy
+
+        avals = [jax.ShapeDtypeStruct(s, jnp.float32)
+                 for s in ((512, 256), (512, 128), (256, 128))]
+        opt = AutoShardingOption(logical_mesh_shape=(1, 2),
+                                 constrain_min_elements=0)
+        mesh = LocalPhysicalDeviceMesh(jax.devices()[:2])
+
+        def plan(fixed_in):
+            return plan_auto_sharding(
+                accumulate, avals, [""] * 3, [], mesh, opt,
+                alias_pairs=[(2, 0)], fixed_in=fixed_in, return_graph=True)
+
+        jax_mesh, _, _, _, (graph, _) = plan(None)
+        dot = next(n for n in graph.nodes if n.label.startswith("dot"))
+        assert not any(">" in st.name for st in dot.strategies)
+        rows = NamedSharding(jax_mesh, PartitionSpec("mesh1"))
+        _, in_sh, cfn, _, (graph, choice) = plan({0: rows, 1: rows})
+        dot = next(n for n in graph.nodes if n.label.startswith("dot"))
+        chosen = dot.strategies[choice[dot.idx]]
+        assert chosen.name.startswith("k0@1>")
+        assert chosen.comm_kind == "reduce_scatter"
+        assert not in_sh[2].is_fully_replicated
+        lm = graph.logical_mesh
+        nbytes = 256 * 128 * 4
+        assert solution_cost(graph, choice) == \
+            lm.reduce_scatter_cost(nbytes, 1) < lm.all_reduce_cost(nbytes, 1)
+        assert alias_stats(graph, choice) == {
+            "alias_pairs": 1, "alias_sharded": 1, "alias_reshard_bytes": 0}
+        hlo = jax.jit(cfn, in_shardings=in_sh, out_shardings=in_sh[2],
+                      donate_argnums=(2,)).lower(*avals).compile().as_text()
+        assert "all-gather" not in hlo, hlo
+
+    def test_every_reader_reads_a_given_value_behind_its_input(self):
+        """The plan and the program agree on who reads the re-laid value:
+        everybody.  An elementwise chain, a product and an output that
+        follows the input all hang off ``relayout<i>``; only a donated
+        pair's edge ends at the input's own node (the output leaves as the
+        input arrives)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+        from alpa_tpu.device_mesh import LocalPhysicalDeviceMesh
+        from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
+        from alpa_tpu.shard_parallel.solver import (planned_out_specs,
+                                                    plan_auto_sharding)
+
+        def readers(x, w, side):
+            return x * 2.0, (side + x) @ w, x + 1.0
+
+        avals = [jax.ShapeDtypeStruct(s, jnp.float32)
+                 for s in ((256, 512), (512, 8), (256, 512))]
+        opt = AutoShardingOption(logical_mesh_shape=(1, 2),
+                                 constrain_min_elements=0)
+        mesh = LocalPhysicalDeviceMesh(jax.devices()[:2])
+        jax_mesh, *_ = plan_auto_sharding(readers, avals, [""] * 3, [],
+                                          mesh, opt)
+        given = NamedSharding(jax_mesh, PartitionSpec(None, "mesh1"))
+        *_, (graph, choice) = plan_auto_sharding(
+            readers, avals, [""] * 3, [], mesh, opt, alias_pairs=[(0, 2)],
+            fixed_in={0: given}, return_graph=True)
+        (inv, behind), = graph.relayouts.items()
+        assert graph.nodes[behind].label == "relayout0"
+        # out of the input's node: the one edge to the node behind it
+        assert [e.dst for e in graph.edges if e.src == inv] == [behind]
+        # the first output follows the value as the program reads it, the
+        # donated one goes back to how it arrives
+        assert graph.out_sources[0][0] == behind
+        assert graph.out_sources[2][0] == behind
+        assert [(e.src, e.dst) for e in graph.edges if e.dst == inv] == \
+            [(behind, inv)]
+        specs = planned_out_specs(graph, choice)
+        assert specs[0] == \
+            graph.nodes[behind].strategies[choice[behind]].out_spec
+        assert specs[2] == graph.nodes[inv].strategies[0].out_spec
+
+    def test_a_graph_with_nothing_given_is_the_parents(self):
+        """Node for node and edge for edge (a digest taken on the parent
+        commit), and under the cache key the parent made for it."""
+        import hashlib
+        from alpa_tpu.compile_cache import get_compile_cache
+        from alpa_tpu.global_env import global_config
+        *_, (graph, _) = self._plan(return_graph=True)
+        assert graph.relayouts == {}
+        h = hashlib.sha256()
+        for n in graph.nodes:
+            h.update(repr((n.idx, n.kind, n.label, n.invar_idx, [
+                (st.name, st.out_spec, st.comm_cost, st.operand_specs,
+                 st.mem_bytes, st.tie_bias) for st in n.strategies])
+            ).encode())
+        for e in graph.edges:
+            h.update(repr((e.src, e.dst, e.cost.tolist())).encode())
+        assert h.hexdigest() == GRAPH_DIGEST
+        prev = global_config.compile_cache_enabled
+        global_config.compile_cache_enabled = True
+        try:
+            cache = get_compile_cache()
+            made = []
+            make_key = cache.make_key
+            cache.make_key = lambda ns, parts: made.append(
+                make_key(ns, parts)) or made[-1]
+            try:
+                self._plan()
+            finally:
+                del cache.make_key
+        finally:
+            global_config.compile_cache_enabled = prev
+        assert made == [ILP_KEY]
+
+
+# of ``TestGivenInput``'s function with nothing given, on commit 0132b16
+GRAPH_DIGEST = (
+    "0f15f80b331e36af4909b30df68192b869ea0e6eb1cd11337f6e22de5aaf507e")
+ILP_KEY = (
+    "ilp-2cb5a519e46c0032414b3b728231153c98b9359ec59e1e83878cc20bfc67f5af")
+
+
 if __name__ == "__main__":
     pytest.main([__file__, "-x", "-q"])
