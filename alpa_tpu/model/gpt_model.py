@@ -1916,15 +1916,53 @@ def selected_mask_upto(scores, k: int, upto):
         [within(n) for n in lengths], scores)
 
 
+def positions_of(mask, k: int):
+    """(R, k) int32: the positions ``mask`` (R, n) bool holds, ascending,
+    in a row's first ``mask.sum(-1)`` slots (at most ``k`` of them are
+    taken); every later slot names position ``n - 1``.  A stable
+    compaction with no sort, no scatter and no (k, n) array: the positions
+    go in blocks of ``LANES``; a slot's block is the number of blocks that
+    end at or before its count ((R, k, n / LANES) comparisons), and its
+    place inside is read off the block's ``LANES`` running counts, which a
+    product with the block's one-hot fetches (counts up to ``LANES`` and
+    ones are exact in bfloat16, the sums in float32)."""
+    r, n = mask.shape
+    blocks = -(-n // LANES)
+    held = jnp.pad(mask, ((0, 0), (0, blocks * LANES - n))).reshape(
+        r, blocks, LANES).astype(jnp.bfloat16)
+    at = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    upto = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    # how many of its block a position and those before it hold
+    inside = jnp.einsum("rbi,ij->rbj", held, (at <= upto).astype(held.dtype),
+                        preferred_element_type=jnp.float32)
+    total = inside[..., -1]
+    through = jnp.cumsum(total, axis=-1)
+    slot = jax.lax.broadcasted_iota(jnp.float32, (1, k, 1), 1)
+    ended = through[:, None, :] <= slot                       # (R, k, blocks)
+    block = ended.sum(-1, dtype=jnp.int32)
+    skipped = jnp.where(ended, total[:, None, :], 0).sum(-1)
+    one_hot = block[..., None] == jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, blocks), 2)
+    counts = jnp.einsum("rkb,rbj->rkj", one_hot.astype(held.dtype),
+                        inside.astype(held.dtype),
+                        preferred_element_type=jnp.float32)  # (R, k, LANES)
+    place = (counts <= slot - skipped[..., None]).sum(-1, dtype=jnp.int32)
+    return jnp.minimum(block * LANES + place, n - 1)
+
+
 def selected_positions(scores, k: int):
     """((.., k) int32, (..,) int32): the positions of the ``k`` largest of
-    ``scores`` (.., Sk) a row, ties to the lower position
-    (``jax.lax.top_k``), and how many of them are real: the first ``min(k,
-    finite scores)``, the largest first; the rest name positions whose
-    score is ``-inf``, which the caller masks."""
-    _, positions = jax.lax.top_k(scores, k)
-    real = jnp.minimum((scores > -jnp.inf).sum(-1, dtype=jnp.int32), k)
-    return positions.astype(jnp.int32), real
+    ``scores`` (.., Sk) a query, ties to the lower position (the set
+    ``jax.lax.top_k`` names), and how many of them are real: the first
+    ``min(k, finite scores)``, in ascending position; the rest name some
+    position in range, which the caller masks.  No sort: the selection is
+    ``selected_mask``'s (its threshold, its tie rule) with the queries
+    folded into the rows, and the table is that mask compacted
+    (``positions_of``)."""
+    sk = scores.shape[-1]
+    chosen = selected_mask(scores.reshape(-1, sk), k)
+    return (positions_of(chosen, k).reshape(scores.shape[:-1] + (k,)),
+            chosen.sum(-1, dtype=jnp.int32).reshape(scores.shape[:-1]))
 
 
 def _latent_attention_masked(q_nope, q_pe, c, k_pe, w_kv_b, seen, *, scale):
@@ -2064,8 +2102,9 @@ class LatentAttention(nn.Module):
     and the softmax and the values go over the ``index_topk`` best alone.
     Its cache is ``(rows, index keys, index)``
     (``update_latent_index_cache``).  A decode takes the selected
-    positions' rows out of the cache (one gather) and runs the absorbed
-    core over those alone; a chunk, and a call without a cache, the
+    positions' rows out of the cache (``selected_positions``: a table in
+    ascending position, made with no sort; one gather) and runs the
+    absorbed core over those alone; a chunk, and a call without a cache, the
     expanded form under the selection's mask
     (``latent_attention_selected``).  A decode of a FEW queries a row (a
     verify of a tick that drafts: ``few_queries``) scores, chooses and
@@ -2191,9 +2230,9 @@ class LatentAttention(nn.Module):
                         out = latent_attention_gathered(
                             q_nope, q_pe, rows, w_kv_b, scale, *selected)
                     elif s == 1:
-                        # the selected positions' rows, the best first, and
-                        # the core over those alone: the real ones are the
-                        # first ``selected[1]``
+                        # the selected positions' rows, in ascending
+                        # position, and the core over those alone: the real
+                        # ones are the first ``selected[1]``
                         taken = jnp.take_along_axis(
                             rows, selected[0][:, :, None], axis=1)
                         out = latent_attention_absorbed(
@@ -2644,7 +2683,7 @@ class GPTModel(nn.Module):
         layers, tokens, k) int32, every token's experts; and, where layers
         select their positions and the call is one new position a row,
         ``selected`` (selecting layers, B, index_topk) int32, the positions
-        each row's query attended over, the best first, and
+        each row's query attended over, in ascending position, and
         ``selected_real`` (selecting layers, B): how many of them are real
         (the first ones).  Of a few positions a row at per-row indices (a
         verify of a tick that drafts: ``few_queries``) both hold an entry a

@@ -4,6 +4,7 @@ dots3-note's published widths, and hold each kernel to its ``jax.numpy``
 twin there:
 
     chiprun -- python3 scripts/time_dsa_parts.py [--held 8192 32768]
+    chiprun -- python3 scripts/time_dsa_parts.py --selection
 
 One JSON line a part: ``ms`` (the median of ``--repeat`` runs that end in
 ``block_until_ready``) and, where the part has a twin, ``max_diff`` against
@@ -11,8 +12,19 @@ it.  A decode's parts at 16 rows holding ``held`` positions each: the index
 scores, the top-2,048, the gather of the selected rows, the absorbed core
 over them.  A chunk's at 1,024 queries that end at ``held``: the index
 scores, the selection's mask, the expanded core under it.
+
+``--selection``: a decode's selection alone, at dots3-note's shape (16 rows,
+one query each, 32,768 positions) and GLM-5's (16 rows, two queries each,
+24,576), the longest row holding a third of the cache and all of it:
+``jax.lax.top_k`` as PR 53 ran it, the same over the queries folded into the
+rows and the static leading part that holds them alone, ``selected_positions``
+(counts and a compaction, no sort) over the whole and over that leading part,
+and its parts (the counts; the compaction as
+shipped, its block's counts fetched by a one-hot product; the same with a row
+gather in the product's place), each compared with ``top_k`` as sets.
 """
 import argparse
+from functools import partial
 import json
 import os
 import statistics
@@ -38,14 +50,18 @@ def rnd(i, *shape, dtype=jnp.bfloat16):
                              jnp.float32).astype(dtype)
 
 
-def timed(name, fn, *args, repeat, twin=None, **more):
+def timed(name, fn, *args, repeat, twin=None, inner=1, **more):
+    """``inner``: calls dispatched back to back before the one wait, so
+    that a part of well under a millisecond is not the host's dispatch."""
     fn = jax.jit(fn)
     out = jax.block_until_ready(fn(*args))
     times = []
     for _ in range(repeat):
         tic = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - tic)
+        for _ in range(inner):
+            last = fn(*args)
+        jax.block_until_ready(last)
+        times.append((time.perf_counter() - tic) / inner)
     line = {"part": name, "ms": round(1e3 * statistics.median(times), 3),
             **more}
     if twin is not None:
@@ -59,13 +75,104 @@ def timed(name, fn, *args, repeat, twin=None, **more):
     return out
 
 
+def positions_of_by_gather(mask, k):
+    """``gm.positions_of`` with the slot's block fetched by a gather of
+    rows of ``LANES`` counts where the shipped one multiplies by a one-hot:
+    the candidate that lost (PERF.md, PR 54)."""
+    lanes = gm.LANES
+    r, n = mask.shape
+    blocks = -(-n // lanes)
+    held = jnp.pad(mask, ((0, 0), (0, blocks * lanes - n))).reshape(
+        r, blocks, lanes)
+    inside = jnp.cumsum(held, axis=-1, dtype=jnp.int32)
+    total = inside[..., -1]
+    through = jnp.cumsum(total, axis=-1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k, 1), 1)
+    ended = through[:, None, :] <= slot
+    block = ended.sum(-1, dtype=jnp.int32)
+    skipped = jnp.where(ended, total[:, None, :], 0).sum(-1)
+    counts = jnp.take_along_axis(
+        inside, jnp.minimum(block, blocks - 1)[..., None], axis=1)
+    place = (counts <= slot - skipped[..., None]).sum(-1, dtype=jnp.int32)
+    return jnp.minimum(block * lanes + place, n - 1)
+
+
+def selection(repeat):
+    """A decode's selection alone (module docstring)."""
+    # twenty calls a wait: these parts take a few tenths of a millisecond
+    time_part = partial(timed, repeat=repeat, inner=20)
+    for rows, queries, context in ((16, 1, 32768), (16, 2, 24576)):
+        for held in (context // 3, context):
+            # the rows hold from half of ``held`` up to all of it
+            lengths = held // 2 + (held - held // 2) * \
+                jnp.arange(1, rows + 1) // rows
+            q_pos = lengths[:, None] - queries + jnp.arange(queries)[None]
+            scores = jnp.where(
+                jnp.arange(context)[None, None] <= q_pos[..., None],
+                rnd(11, rows, queries, context, dtype=jnp.float32), -jnp.inf)
+            shape = {"shape": [rows, queries, context], "held": held}
+            # the static leading part that holds them all
+            # (``selected_mask_upto``'s lengths)
+            part = next(n for n in (2 * TOPK, 4 * TOPK, 8 * TOPK, context)
+                        if n >= min(held, context))
+            # one query a row went in as (rows, Sk), two as (rows, 2, Sk)
+            _, want = time_part(
+                "decode top_k, as PR 53 ran it",
+                lambda s: jax.lax.top_k(s, TOPK),
+                scores[:, 0] if queries == 1 else scores, **shape)
+            want = want.reshape(scores.shape[:-1] + (TOPK,))
+
+            def same(name, got, real):
+                hit = jnp.zeros(scores.shape, bool)
+                put = jax.vmap(jax.vmap(lambda h, p: h.at[p].set(True)))
+                live = jnp.arange(TOPK) < real[..., None]
+                print(json.dumps({
+                    "part": name + ": the set top_k names", **shape,
+                    "same": bool(((put(hit, got) & (scores > -jnp.inf)) ==
+                                  (put(hit, want) & (scores > -jnp.inf))
+                                  ).all()),
+                    "ascending": bool(((jnp.diff(got, axis=-1) > 0) |
+                                       ~live[..., 1:]).all()),
+                    "real_min_max": [int(real.min()), int(real.max())]}),
+                      flush=True)
+
+            time_part("decode top_k, queries folded, the leading part",
+                      lambda s: jax.lax.top_k(
+                          s.reshape(-1, context)[:, :part], TOPK), scores,
+                      leading=part, **shape)
+            got, real = time_part(
+                "decode selected_positions (no sort)",
+                lambda s: gm.selected_positions(s, TOPK), scores, **shape)
+            same("selected_positions", got, real)
+            time_part("decode selected_positions, the leading part alone",
+                      lambda s: gm.selected_positions(s[..., :part], TOPK),
+                      scores, leading=part, **shape)
+            folded = scores.reshape(-1, context)[:, :part]
+            mask = time_part(
+                "  its counts: selected_mask, the leading part",
+                lambda s: gm.selected_mask(s, TOPK), folded,
+                leading=part, **shape)
+            for name, compact in (
+                    ("  its compaction: a one-hot product (shipped)",
+                     gm.positions_of),
+                    ("  its compaction: a row gather",
+                     positions_of_by_gather)):
+                table = time_part(name, lambda m: compact(m, TOPK), mask,
+                                  leading=part, **shape)
+                same(name.strip(), table.reshape(got.shape), real)
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--held", type=int, nargs="+",
                         default=[8192, 32768])
     parser.add_argument("--repeat", type=int, default=10)
+    parser.add_argument("--selection", action="store_true",
+                        help="a decode's selection alone, both shapes")
     args = parser.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.selection:
+        return selection(args.repeat)
     keys = rnd(0, ROWS, CONTEXT, DI)
     rows = rnd(1, ROWS, CONTEXT, 640)
     w_kv_b = rnd(2, RANK, HEADS, DN + DV) * RANK ** -0.5

@@ -14,6 +14,7 @@ chunks and then decoding through the caches against the reference's full
 forward pass, logits and not tokens, and the selected sets themselves.  The
 benchmark's cell compares the bfloat16 program with the same reference on
 the chip."""
+from functools import partial
 import json
 import os
 import sys
@@ -253,27 +254,86 @@ def test_the_ring_holds_exactly_the_window():
 
 # ---- the selection ----------------------------------------------------
 
-def test_the_selection_is_the_top_k_ties_to_the_lower_position():
-    x = jnp.round(jax.random.normal(jax.random.PRNGKey(3), (6, 64)) * 2) / 2
-    x = x.at[:, 40:].set(-jnp.inf)
-    mask = np.asarray(gpt_model.selected_mask(x, 10))
-    positions, real = gpt_model.selected_positions(x, 10)
-    best, want = jax.lax.top_k(x, 10)
-    assert (mask.sum(-1) == 10).all() and (np.asarray(real) == 10).all()
-    for row, got, named in zip(mask, as_sets(np.asarray(positions),
-                                             np.asarray(real)),
-                               np.asarray(want)):
-        assert set(np.flatnonzero(row).tolist()) == got == \
-            set(named.tolist())
-    # a tie that straddles the tenth place was broken somewhere
-    assert any((x[i] == best[i, -1]).sum() > (best[i] == best[i, -1]).sum()
-               for i in range(6))
-    # fewer positions than the selection: all of them, and no others
-    short = x.at[:, 5:].set(-jnp.inf)
-    assert (np.asarray(gpt_model.selected_mask(short, 10)) ==
-            (np.arange(64) < 5)).all()
-    assert (np.asarray(gpt_model.selected_positions(short, 10)[1]) ==
-            5).all()
+# (Sk, k): 96 is three times a power of two, as GLM-5's 24,576 is; 768
+# holds six of the compaction's blocks of 128 positions
+SELECTIONS = [(64, 10), (96, 8), (768, 100)]
+@partial(jax.jit, static_argnums=1)
+def _selected_every_way(x, k):
+    """One program a shape: the mask, the table and what ``top_k`` names."""
+    return (gpt_model.selected_mask(x, k),
+            *gpt_model.selected_positions(x, k), *jax.lax.top_k(x, k))
+
+
+def _held_lengths(sk, k):
+    """How much of ``sk`` positions the longest row holds: the static
+    leading lengths a chunk's selection counts over
+    (``selected_mask_upto``) and the lengths either side of each."""
+    return sorted({n + d for n in (k, 2 * k, 4 * k, 8 * k, sk)
+                   for d in (-1, 0, 1) if n + d <= sk})
+
+
+def _scores_to_select(kind, queries, sk):
+    """(6, Sk), or (6, queries, Sk) where ``queries``: scores in halves, so
+    that ties abound, a query seeing one position more than the query
+    before it."""
+    shape = (6,) + queries + (sk,)
+    x = np.round(np.random.RandomState(3).randn(*shape) * 2).astype(
+        np.float32) / 2
+    back = np.arange(*queries, 0, -1)[:, None] - 1 if queries else 0
+
+    def holding(held):
+        return np.where(np.arange(sk) < held - back, x, -np.inf)
+
+    if kind == "a-tie-straddles-the-kth":
+        return holding(sk * 5 // 8)
+    if kind == "all-equal":
+        return np.ones(shape, np.float32)
+    if kind == "all-equal-of-those-held":
+        return np.where(holding(sk // 2) > -np.inf, np.float32(0.25),
+                        -np.inf)
+    if kind == "fewer-finite-than-k":
+        return holding(5)
+    if kind == "a-row-with-none":
+        x = holding(sk)
+        x[2] = -np.inf
+        return x
+    return holding(int(kind.split("-")[1]))
+
+
+@pytest.mark.parametrize("queries", [(), (2,)],
+                         ids=["one-query-a-row", "two-queries-a-row"])
+@pytest.mark.parametrize("sk,k,kind", [
+    (sk, k, kind) for sk, k in SELECTIONS for kind in [
+        "a-tie-straddles-the-kth", "all-equal", "all-equal-of-those-held",
+        "fewer-finite-than-k", "a-row-with-none"] + [
+            "held-%d" % n for n in _held_lengths(sk, k)]])
+def test_the_selection_is_the_top_k_ties_to_the_lower_position(sk, k, kind,
+                                                               queries):
+    """``selected_positions`` (no sort: ``selected_mask``'s counts, and a
+    compaction) and ``selected_mask`` name the set ``jax.lax.top_k`` names,
+    the table in ascending position with the real ones first."""
+    x = _scores_to_select(kind, queries, sk)
+    mask, positions, real, best, want = map(
+        np.asarray, _selected_every_way(x, k))
+    assert positions.shape == x.shape[:-1] + (k,) and \
+        positions.dtype == np.int32 and real.shape == x.shape[:-1]
+    flat = x.reshape(-1, sk)
+    mask, positions, real, best, want = (
+        a.reshape((len(flat),) + a.shape[x.ndim - 1:])
+        for a in (mask, positions, real, best, want))
+    assert (real == np.minimum((flat > -np.inf).sum(-1), k)).all()
+    assert (mask.sum(-1) == real).all()
+    assert positions.min() >= 0 and positions.max() < sk
+    for row, table, n, named in zip(mask, positions, real, want):
+        assert (np.diff(table[:n]) > 0).all()
+        assert set(np.flatnonzero(row).tolist()) == \
+            set(table[:n].tolist()) == set(named[:n].tolist())
+    if kind == "a-tie-straddles-the-kth":
+        # a tie that straddles the k-th place was broken somewhere
+        assert ((flat == best[:, -1:]).sum(-1) >
+                (best == best[:, -1:]).sum(-1)).any()
+    if kind == "a-row-with-none":
+        assert (real.reshape((6,) + queries)[2] == 0).all()
 
 
 @pytest.mark.parametrize("upto", [3, 8, 9, 16, 17, 40, 64, 65, 96])

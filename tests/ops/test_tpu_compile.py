@@ -387,15 +387,16 @@ def test_selecting_latent_kernels_compile_for_v5e(one_chip, kernel, shapes):
     assert compiled.as_text().count(KERNEL) == 1
 
 
-def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
-    """``_decode`` of ``dots3-note-prev-1chip`` (its leading layer, which
-    selects, at the published widths, 16 rows, served context 32,768)
-    after the selecting decode learned to take a few queries a row (PR
-    53): one query a row still runs the one-query index kernel and the
-    absorbed kernel over ONE gathered selection a row, and no array of it
-    has a second query.  (At the cell's depth the
-    compiled program is the parent commit's line for line: PERF.md, PR
-    53.)"""
+_SELECTING_DECODES = {}
+
+
+def _selecting_decode(one_chip, name):
+    """The compiled tick of the selecting configuration ``name`` at ONE
+    layer of its published widths, its cell's rows and served context
+    (``_decode``; ``_verify_draft`` where a module drafts, whose block
+    selects too); compiled once a session.  ``(compiled, rows, context)``."""
+    if name in _SELECTING_DECODES:
+        return _SELECTING_DECODES[name]
     import json
     from alpa_tpu.model.gpt_model import GPTModel, init_kv_caches
     from alpa_tpu.serve.generation import Generator
@@ -404,7 +405,7 @@ def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
     sys.path.insert(0, root)
     from chipbench import run
     with open(os.path.join(root, "chipbench", "configs",
-                           "dots3-note-prev-1chip.json")) as f:
+                           name + ".json")) as f:
         hf = dict(json.load(f), num_hidden_layers=1)
     rows, context = hf["serve"]["engine_rows"], hf["serve"]["served_context"]
     cfg = run.load_module("drivers", "serve_mla").model_config(
@@ -416,18 +417,35 @@ def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    def spec(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
                                     jnp.ones((1, 8), jnp.int32)))
     gen = Generator(model, params, cfg, prefill_chunk=1024)
-    assert gen._verify_draft is None
     caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
-    text = gen._decode.jitted.lower(
-        params, spec(rows, 1), spec(rows),
-        on_chip([(k, v) for k, v, _ in caches]),
-        [spec(rows) for _ in caches]).compile().as_text()
+    args = (params, spec(rows, 1), spec(rows),
+            on_chip([(k, v) for k, v, _ in caches]),
+            [spec(rows) for _ in caches])
+    if gen._verify_draft is None:
+        lowered = gen._decode.jitted.lower(*args)
+    else:
+        lowered = gen._verify_draft.jitted.lower(
+            *args, spec(rows), spec(rows), spec(rows, dtype=jnp.bool_))
+    _SELECTING_DECODES[name] = lowered.compile(), rows, context
+    return _SELECTING_DECODES[name]
+
+
+def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
+    """``_decode`` of ``dots3-note-prev-1chip`` (its leading layer, which
+    selects, at the published widths, 16 rows, served context 32,768)
+    after the selecting decode learned to take a few queries a row (PR
+    53): one query a row still runs the one-query index kernel and the
+    absorbed kernel over ONE gathered selection a row, and no array of it
+    has a second query."""
+    compiled, rows, context = _selecting_decode(one_chip,
+                                                "dots3-note-prev-1chip")
+    text = compiled.as_text()
     kernels = [line for line in text.splitlines() if KERNEL in line]
     scores = [k for k in kernels if re.search(
         r"= f32\[%d,1,%d\]" % (rows, context), k)]
@@ -436,6 +454,28 @@ def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
     assert len(scores) == 1 and len(cores) == 1, (len(scores), len(cores))
     assert all("bf16[%d,2048,512]" % rows in k for k in cores)
     assert not re.search(r"\[%d,2,%d\]" % (rows, context), text)
+
+
+# the tick's temporaries at the parent of PR 54, where the selection was a
+# sort (``jax.lax.top_k``): the same compile, one sort a selecting block
+SORTED_DECODE_TEMP_BYTES = {"glm-5-1chip": 177_382_912,
+                            "dots3-note-prev-1chip": 36_941_824}
+
+
+@pytest.mark.parametrize("name", ["glm-5-1chip", "dots3-note-prev-1chip"])
+def test_a_selecting_decode_sorts_nothing_on_v5e(one_chip, name):
+    """The tick of a configuration whose layers select (GLM-5: two
+    queries a row, its one layer here and its module; dots3-note: one
+    query a row) holds no sort: the 2,048 positions a query attends over
+    come from counts and a compaction (``selected_positions``, PR 54);
+    and its temporaries are what they were with the sort, within 0.1
+    GB."""
+    compiled, _, _ = _selecting_decode(one_chip, name)
+    text = compiled.as_text()
+    assert not re.search(r"\bsort\(", text)
+    assert "top_k" not in text.lower()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - SORTED_DECODE_TEMP_BYTES[name]) < 100e6, temp
 
 
 # ---- a prefill chunk's head (PR 48) -------------------------------------
