@@ -61,7 +61,54 @@ class MLPModel(nn.Module):
         return x
 
 
+def highest(fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` at full matmul precision: what a comparison
+    with a float32 reference runs under, so that it reads the mathematics
+    and not the backend's default rounding."""
+    with jax.default_matmul_precision("highest"):
+        return fn(*args, **kwargs)
+
+
+def jitted(fn):
+    """``fn`` as one compiled program in place of one dispatch (and at
+    first use one compilation) an operation: for a whole model's ``apply``
+    or ``jax.grad`` whose result is only compared.  A function of its own
+    every time, so that a trace made before, under another monkeypatch,
+    is never found again."""
+    return jax.jit(lambda *args, **kwargs: fn(*args, **kwargs))
+
+
+def init_params(model, rngkey, *args, **kwargs):
+    """``model.init(rngkey, *args, **kwargs)`` as one compiled program,
+    under the caller's matmul precision.  Outside ``jit`` flax dispatches
+    (and at first use compiles) every initializer and every operation of
+    the forward pass one by one.  The leaves of flax's initializers are
+    the same bit for bit (``tests/util/test_util.py`` holds one toy to
+    that); an initializer that adds a constant to a draw (``gpt_model``'s
+    sinks) may differ in the last bit, the sum being one multiply-add
+    here."""
+    return jitted(partial(model.init, **kwargs))(rngkey, *args)
+
+
+def shake(params, names, seed=0):
+    """``params`` with every leaf whose last key is in ``names`` (norm
+    scales, biases, routers' biases) moved by a draw of spread 0.3, away
+    from the 1 or 0 it is initialised to, so that a weight left out or
+    applied in the wrong place shows."""
+    def moved(path, x):
+        if path[-1].key not in names:
+            return x
+        key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                 hash(jax.tree_util.keystr(path)) % 997)
+        return x + 0.3 * jax.random.normal(key, x.shape, x.dtype)
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
 def create_train_state(rngkey, model, inputs, learning_rate=1e-2):
+    # eager on purpose: the models that come here are a few Dense layers
+    # and many tests make several states (a kill-schedule fuzz 21); the
+    # process compiles their handful of operations once, where a program
+    # a call would be compiled every time (CHANGES.md, PR 55)
     params = model.init(rngkey, *inputs)
     tx = optax.sgd(learning_rate=learning_rate, momentum=0.9)
     return train_state.TrainState.create(apply_fn=model.apply,
@@ -131,8 +178,8 @@ def get_gpt_train_step(config, batch_size, parallel_method=None,
     }
 
     def create_state():
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.ones(shape, jnp.int32))
+        params = init_params(model, jax.random.PRNGKey(0),
+                             jnp.ones(shape, jnp.int32))
         return train_state.TrainState.create(apply_fn=model.apply,
                                              params=params, tx=tx)
 

@@ -12,10 +12,7 @@ latent cache against the reference's full forward pass, logits and not
 tokens.  The benchmark's cell compares the bfloat16 program with the same
 reference on the chip."""
 import dataclasses
-import json
 import math
-import os
-import sys
 import threading
 
 import jax
@@ -33,12 +30,8 @@ from alpa_tpu.serve.disagg import PrefillEngine
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params
+from chipbench import run
 
 TOY = run.load_json(run.HERE, "configs", "toy-deepseek-v2.json")
 CELL = run.load_json(run.HERE, "configs", "deepseek-v2-1chip.json")
@@ -65,7 +58,7 @@ def toy():
     model = GPTModel(toy_config())
     ids = jax.random.randint(jax.random.PRNGKey(0), (3, S), 0,
                              TOY["vocab_size"])
-    params = model.init(jax.random.PRNGKey(2), ids)
+    params = init_params(model, jax.random.PRNGKey(2), ids)
 
     def shake(path, x):
         if path[-1].key != "scale":
@@ -83,11 +76,6 @@ def wanted(reference, toy):
     _model, params, ids = toy
     weights = mod.weights_from_program(params)
     return np.stack([np.asarray(ref.logits(weights, row)) for row in ids])
-
-
-def highest(fn, *args, **kwargs):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args, **kwargs)
 
 
 # ---- the configuration ------------------------------------------------
@@ -534,7 +522,7 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
     # the reference norms its input itself: the program is handed that
     h = mod.rms(x, 1.0, eps)[None]
     layer = moe.DroplessExperts(toy_config(experts_held=None))
-    p = layer.init(jax.random.PRNGKey(6), h)["params"]
+    p = init_params(layer, jax.random.PRNGKey(6), h)["params"]
     shared_w = [p[f"shared{i}"] for i in range(2)]
 
     def ref_part(first, count):
@@ -592,7 +580,7 @@ def test_rows_routed_to_an_absent_expert_take_no_part():
     cfg = toy_config(num_shared_experts=0, experts_held=(12, 4))
     layer = moe.DroplessExperts(cfg)
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(2), h)
+    params = init_params(layer, jax.random.PRNGKey(2), h)
     y, routing = layer.apply(params, h)
     experts = np.asarray(routing["experts"])
     elsewhere = (experts < 12).all(-1)
@@ -604,24 +592,13 @@ def test_rows_routed_to_an_absent_expert_take_no_part():
 
 # ---- the driver -------------------------------------------------------
 
-def _toy_context(tmp_path, steady):
-    """The toy cell's context, its check on the same requests whatever the
-    machine's load (``conftest.checks_the_same_requests``)."""
-    return steady(run.Context(
-        cell={"name": "toy-deepseek-v2.longdoc", "config": "toy-deepseek-v2",
-              "traffic": "toy-longdoc", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-longdoc"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace")))
-
-
 def test_the_routers_balance_leaves_the_mean_input_unscored():
     """``balance_routers``: every router loses one direction, that of the
     mean of its layer's input over the schedule's batches, which then
     scores 0 with every expert; nothing else of the model moves."""
     model = GPTModel(toy_config())
-    params = model.init(jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32))
+    params = init_params(model, jax.random.PRNGKey(3),
+                         jnp.ones((1, 8), jnp.int32))
     key = jax.random.PRNGKey(4)
     moved = DRIVER.balance_routers(model, params, key, TOY["vocab_size"])
     changed = [jax.tree_util.keystr(path) for (path, a), b in zip(
@@ -652,12 +629,13 @@ def test_the_routers_balance_leaves_the_mean_input_unscored():
     assert abs(cosine) > 0.9999
 
 
-def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
+def test_driver_runs_the_toy_cell(toy_context, checks_the_same_requests):
     """``chipbench/drivers/serve_mla.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference."""
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-deepseek-v2.longdoc", "toy-longdoc", 3.0,
+                                 2, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -686,7 +664,7 @@ def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
 
 
 def test_driver_holds_the_latent_cache_to_its_precision(
-        tmp_path, monkeypatch, checks_the_same_requests):
+        toy_context, monkeypatch, checks_the_same_requests):
     """The control the cell's limits are set against, at the toy size: a
     latent cache rounded to float8 (e4m3) on its way in serves plausible
     tokens and is not correct."""
@@ -697,20 +675,17 @@ def test_driver_holds_the_latent_cache_to_its_precision(
                       jax.lax.reduce_precision(k_pe, 4, 3))
 
     monkeypatch.setattr(gpt_model, "update_latent_cache", rounded)
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-deepseek-v2.longdoc", "toy-longdoc", 3.0,
+                                 0, checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
     """Every number of the catalog row's ``config`` is in the cell's file
     under the same key, but the three keys it lists as reduced."""
-    path = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if not os.path.exists(path):
-        pytest.skip("no catalog here")
-    row = next(json.loads(line) for line in open(path)
-               if json.loads(line)["name"] == "DeepSeek-V2")
+    row = catalog_row("DeepSeek-V2")
     differ = {k for k, v in row["config"].items() if CELL.get(k) != v}
     assert differ == set(CELL["reduced"]) == {
         "num_hidden_layers", "n_routed_experts", "vocab_size"}

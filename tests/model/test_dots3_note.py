@@ -15,9 +15,6 @@ forward pass, logits and not tokens, and the selected sets themselves.  The
 benchmark's cell compares the bfloat16 program with the same reference on
 the chip."""
 from functools import partial
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -33,26 +30,16 @@ from alpa_tpu.model.gpt_model import (LATENT_SLIDING, GPTModel,
                                       selected_per_row, uniform_kv_caches)
 from alpa_tpu.ops import latent_attention as kernels
 from alpa_tpu.serve.generation import GenerationConfig, Generator
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import arithmetic_dsa, observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params, shake
+from chipbench import arithmetic_dsa, run
 
 TOY = run.load_json(run.HERE, "configs", "toy-dots3.json")
 CELL = run.load_json(run.HERE, "configs", "dots3-note-prev-1chip.json")
 DRIVER = run.load_module("drivers", "serve_dsa")
 MLA = run.load_module("drivers", "serve_mla")
 REF = run.load_module("references", "dots3_note_decoder")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONTEXT, S = 96, 48
 TOL = 5e-5
-
-
-def highest(f, *args):
-    with jax.default_matmul_precision("highest"):
-        return f(*args)
 
 
 def toy_config(**kwargs):
@@ -60,26 +47,16 @@ def toy_config(**kwargs):
         TOY, **{"dtype": jnp.float32, "seq_len": CONTEXT, **kwargs})
 
 
-def shake(params, seed=0):
-    """Norm weights and the index keys' LayerNorm away from 1 and 0 and
-    router biases away from 0, so that a weight applied in the wrong place
-    shows."""
-    def moved(path, x):
-        name = path[-1].key
-        if name in ("scale", "bias", "router_bias"):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                     hash(jax.tree_util.keystr(path)) % 997)
-            return x + 0.3 * jax.random.normal(key, x.shape, x.dtype)
-        return x
-    return jax.tree_util.tree_map_with_path(moved, params)
+# norm weights, the index keys' LayerNorm and the routers' biases
+SHAKEN = ("scale", "bias", "router_bias")
 
 
 @pytest.fixture(scope="module")
 def toy():
     cfg = toy_config()
     model = GPTModel(cfg)
-    params = shake(model.init(jax.random.PRNGKey(0),
-                              jnp.ones((1, 8), jnp.int32)))
+    params = shake(init_params(model, jax.random.PRNGKey(0),
+                               jnp.ones((1, 8), jnp.int32)), SHAKEN)
     return cfg, model, params
 
 
@@ -106,25 +83,17 @@ def as_sets(positions, real):
 
 # ---- the configuration ------------------------------------------------
 
-def catalog_row():
-    if not os.path.exists(CATALOG):
-        pytest.skip("the catalog of architectures is not on this machine")
-    with open(CATALOG) as f:
-        return next(row for row in map(json.loads, f)
-                    if row["name"] == "dots3-note-prev")
-
-
 def parameters(cfg):
-    shapes = jax.eval_shape(
-        lambda key: GPTModel(cfg).init(key, jnp.ones((1, 8), jnp.int32)),
-        jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(GPTModel(cfg).init, jax.random.PRNGKey(0),
+                            jnp.ones((1, 8), jnp.int32))
     return sum(x.size for x in jax.tree_util.tree_leaves(shapes))
 
 
-def test_config_from_hf_reads_the_catalog_rows_config():
+def test_config_from_hf_reads_the_catalog_rows_config(catalog_row):
     """The catalog row's ``config``, unedited, is the published 46-layer
     model: 279.55 B language-model parameters."""
-    cfg = config_from_hf(catalog_row()["config"])
+    published = catalog_row("dots3-note-prev")["config"]
+    cfg = config_from_hf(published)
     assert cfg.num_layers == 46 and cfg.hidden_size == 5120
     assert cfg.attention.count("latent") == 13
     assert cfg.attention.count(LATENT_SLIDING) == 33
@@ -148,7 +117,7 @@ def test_config_from_hf_reads_the_catalog_rows_config():
     assert cfg.attn_gate == "head" and not cfg.tie_embeddings
     assert parameters(cfg) == 279_551_726_592
     assert arithmetic_dsa.model_parameters(dict(
-        catalog_row()["config"], published={"n_routed_experts": 256})) == \
+        published, published={"n_routed_experts": 256})) == \
         279_551_148_032      # beside the norms and the routers' biases
 
 
@@ -172,8 +141,8 @@ def test_the_cells_file_is_the_share_the_issue_counts():
     assert arithmetic_dsa.ring_bytes_per_row(CELL, 2) == 513 * 1088 * 2
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
-    published = catalog_row()["config"]
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
+    published = catalog_row("dots3-note-prev")["config"]
     for key, value in published.items():
         if key in CELL["reduced"]:
             assert CELL[key] != value and CELL["published"][key] == value
@@ -541,8 +510,8 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
              published={"n_routed_experts": 16}),
         dtype=jnp.float32, seq_len=CONTEXT, experts_held=None)
     model = GPTModel(whole_cfg)
-    params = shake(model.init(jax.random.PRNGKey(5),
-                              jnp.ones((1, 8), jnp.int32)))
+    params = shake(init_params(model, jax.random.PRNGKey(5),
+                               jnp.ones((1, 8), jnp.int32)), SHAKEN)
     layer = REF.weights_from_program(params)["layers"][1]["mlp"]
     x = jax.random.normal(jax.random.PRNGKey(6), (S, 64), jnp.float32)
     args = (1e-5, 3, True, 1.0)
@@ -581,23 +550,14 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
 
 # ---- the driver -------------------------------------------------------
 
-def _toy_context(tmp_path, steady):
-    return steady(run.Context(
-        cell={"name": "toy-dots3.longctx", "config": "toy-dots3",
-              "traffic": "toy-longctx", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-longctx"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace")))
-
-
-def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
+def test_driver_runs_the_toy_cell(toy_context, checks_the_same_requests):
     """``chipbench/drivers/serve_dsa.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference (logits, picks and
     selected sets); and what the cell's readers make of it."""
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-dots3.longctx", "toy-longctx", 3.0, 2,
+                                 checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 16, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -635,14 +595,15 @@ def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
 
 @pytest.mark.parametrize("control", ["cache_in_float8", "recent_positions",
                                      "gates_left_out"])
-def test_driver_fails_a_control(tmp_path, monkeypatch, control,
+def test_driver_fails_a_control(toy_context, monkeypatch, control,
                                 checks_the_same_requests):
     """The controls the cell's limits are set against
     (``chipbench/controls_dots3.py``), planted at the toy size: each
     serves plausible tokens and is not correct."""
     from chipbench import controls_dots3
     controls_dots3.CONTROLS[control](TOY, monkeypatch.setattr)
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-dots3.longctx", "toy-longctx", 3.0, 0,
+                                 checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks

@@ -13,10 +13,6 @@ is the mathematics: the training call, prefill in chunks, the one-token
 decode and the two-position verify through the caches, the module's logits
 and the selected sets themselves.  The benchmark's cell compares the
 bfloat16 program with the same reference on the chip."""
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,26 +25,16 @@ from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf,
                                       require_rollback_by_index,
                                       require_uniform_kv_caches)
 from alpa_tpu.serve.generation import GenerationConfig, Generator
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import arithmetic_glm5, run  # noqa: E402
+from alpa_tpu.testing import highest, init_params, shake
+from chipbench import arithmetic_glm5, run
 
 TOY = run.load_json(run.HERE, "configs", "toy-glm5.json")
 CELL = run.load_json(run.HERE, "configs", "glm-5-1chip.json")
 DRIVER = run.load_module("drivers", "serve_glm5")
 MLA = run.load_module("drivers", "serve_mla")
 REF = run.load_module("references", "glm5_decoder")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONTEXT, S = 96, 32
 TOL = 5e-5
-
-
-def highest(f, *args, **kwargs):
-    with jax.default_matmul_precision("highest"):
-        return f(*args, **kwargs)
 
 
 def toy_config(**kwargs):
@@ -56,26 +42,16 @@ def toy_config(**kwargs):
         TOY, **{"dtype": jnp.float32, "seq_len": CONTEXT, **kwargs})
 
 
-def shake(params, seed=0):
-    """Norm weights and the index keys' LayerNorm away from 1 and 0 and
-    router biases away from 0, so that a weight applied in the wrong place
-    shows."""
-    def moved(path, x):
-        name = path[-1].key
-        if name in ("scale", "bias", "router_bias"):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                     hash(jax.tree_util.keystr(path)) % 997)
-            return x + 0.3 * jax.random.normal(key, x.shape, x.dtype)
-        return x
-    return jax.tree_util.tree_map_with_path(moved, params)
+# norm weights, the index keys' LayerNorm and the routers' biases
+SHAKEN = ("scale", "bias", "router_bias")
 
 
 @pytest.fixture(scope="module")
 def toy():
     cfg = toy_config()
     model = GPTModel(cfg)
-    params = shake(jax.jit(model.init)(jax.random.PRNGKey(0),
-                                       jnp.ones((1, 8), jnp.int32)))
+    params = shake(init_params(model, jax.random.PRNGKey(0),
+                               jnp.ones((1, 8), jnp.int32)), SHAKEN)
     return cfg, model, params
 
 
@@ -108,27 +84,18 @@ def as_sets(positions, real):
 
 # ---- the configuration (a) ---------------------------------------------
 
-def catalog_row():
-    if not os.path.exists(CATALOG):
-        pytest.skip("the catalog of architectures is not on this machine")
-    with open(CATALOG) as f:
-        return next(row for row in map(json.loads, f)
-                    if row["name"] == "GLM-5")
-
-
 def parameters(cfg):
-    shapes = jax.eval_shape(
-        lambda key: GPTModel(cfg).init(key, jnp.ones((1, 8), jnp.int32)),
-        jax.random.PRNGKey(0))["params"]
+    shapes = jax.eval_shape(GPTModel(cfg).init, jax.random.PRNGKey(0),
+                            jnp.ones((1, 8), jnp.int32))["params"]
     count = lambda tree: sum(   # noqa: E731
         x.size for x in jax.tree_util.tree_leaves(tree))
     return count(shapes), count(shapes.get("mtp", {}))
 
 
-def test_config_from_hf_reads_the_catalog_rows_config():
+def test_config_from_hf_reads_the_catalog_rows_config(catalog_row):
     """The catalog row's ``config``, unedited, is the published 78-layer
     model: 743.91 B parameters, 753.86 B with its module."""
-    published = catalog_row()["config"]
+    published = catalog_row("GLM-5")["config"]
     cfg = config_from_hf(published)
     assert cfg.num_layers == 78 and cfg.hidden_size == 6144
     assert cfg.attention == "latent" and \
@@ -193,14 +160,15 @@ def test_the_cells_file_is_the_share_the_issue_counts():
     assert arithmetic_glm5.selecting_blocks(CELL) == 6
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
-    published = catalog_row()["config"]
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
+    row = catalog_row("GLM-5")
+    published = row["config"]
     for key, value in published.items():
         if key in CELL["reduced"]:
             assert CELL[key] != value and CELL["published"][key] == value
         else:
             assert CELL[key] == value, key
-    assert CELL["source"].startswith(catalog_row()["source_url"])
+    assert CELL["source"].startswith(row["source_url"])
 
 
 @pytest.mark.parametrize("key,value", [
@@ -404,8 +372,8 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
     uncut reference's layer."""
     whole = dict(TOY, n_routed_experts=16, share_index=0)
     cfg = MLA.model_config(whole, dtype=jnp.float32, seq_len=CONTEXT)
-    params = shake(jax.jit(GPTModel(cfg).init)(
-        jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32)))
+    params = shake(init_params(GPTModel(cfg), jax.random.PRNGKey(3),
+                               jnp.ones((1, 8), jnp.int32)), SHAKEN)
     weights = REF.weights_from_program(params)
     x = jax.random.normal(jax.random.PRNGKey(4), (S, 64), jnp.float32)
     full = REF.Reference(DRIVER.reference_settings(whole))

@@ -11,10 +11,6 @@ compared is the mathematics: prefill and then decoding through the state
 and the cache against the reference's full forward pass, logits and not
 tokens.  The benchmark's cell compares the bfloat16 program with the same
 reference on the chip."""
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,16 +23,11 @@ from alpa_tpu.model.gpt_model import (GPTModel, ShortConv, TransformerBlock,
                                       update_conv_state)
 from alpa_tpu.model.model_util import routed_lm_loss
 from alpa_tpu.serve.generation import Generator
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import run  # noqa: E402
+from alpa_tpu.testing import highest, init_params, jitted
+from chipbench import run
 
 TOY = run.load_json(run.HERE, "configs", "toy-lfm2.json")
 DRIVER = run.load_module("drivers", "serve_hybrid")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONTEXT, S, H = 64, 48, TOY["hidden_size"]
 TOL = 2e-5      # float32 at full precision, logits of unit spread
 
@@ -59,7 +50,7 @@ def toy():
     model = GPTModel(toy_config())
     ids = jax.random.randint(jax.random.PRNGKey(0), (3, S), 0,
                              TOY["vocab_size"])
-    params = model.init(jax.random.PRNGKey(2), ids)
+    params = init_params(model, jax.random.PRNGKey(2), ids)
 
     def shake(path, x):
         key = jax.random.PRNGKey(len(str(path)))
@@ -82,24 +73,11 @@ def wanted(reference, toy):
     return np.stack([np.asarray(ref.logits(weights, row)) for row in ids])
 
 
-def highest(fn, *args, **kwargs):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args, **kwargs)
-
-
-def catalog_row() -> dict:
-    if not os.path.exists(CATALOG):
-        pytest.skip("the catalog of architectures is not on this machine")
-    with open(CATALOG) as f:
-        return next(row for row in map(json.loads, f)
-                    if row["name"] == "LFM2-8B-A1B")
-
-
-def test_config_from_hf_reads_the_catalog_row():
+def test_config_from_hf_reads_the_catalog_row(catalog_row):
     """The published ``config.json`` as the catalog holds it: it says
     ``norm_eps``, and has no ``hidden_act``, no ``head_dim`` and no
     ``tie_word_embeddings``."""
-    hf = catalog_row()["config"]
+    hf = catalog_row("LFM2-8B-A1B")["config"]
     cfg = config_from_hf(hf, dtype=jnp.bfloat16, seq_len=8192)
     assert cfg.attention.count("conv") == 18 and \
         cfg.attention.count("full") == 6
@@ -367,12 +345,11 @@ def test_loss_and_every_gradient_leaf_match_the_reference(reference, toy):
 
     want = ref.lm_loss(mod.weights_from_program(params), batch["input_ids"],
                        batch["labels"])
-    assert float(program_loss(params)) == pytest.approx(want, rel=2e-6)
-    assert float(reference_loss(mod, ref, params, batch)) == \
-        pytest.approx(want, rel=2e-6)
-    got = jax.grad(program_loss)(params)
-    wanted_grad = jax.grad(lambda p: reference_loss(mod, ref, p, batch))(
-        params)
+    loss, got = jitted(jax.value_and_grad(program_loss))(params)
+    assert float(loss) == pytest.approx(want, rel=2e-6)
+    loss, wanted_grad = jitted(jax.value_and_grad(
+        lambda p: reference_loss(mod, ref, p, batch)))(params)
+    assert float(loss) == pytest.approx(want, rel=2e-6)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree_util.tree_leaves(wanted_grad)
     # 4 conv x 5 + attention 6, dense 3 + 4 x 4 routed, wte, ln_f

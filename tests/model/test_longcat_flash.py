@@ -13,9 +13,6 @@ caches against the reference's full forward pass, logits and not tokens.
 The benchmark's cell compares the bfloat16 program with the same reference
 on the chip."""
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -28,12 +25,8 @@ from alpa_tpu.model.gpt_model import (SHORTCUT_MLP, GPTModel,
                                       kv_cache_kinds, kv_cache_shapes,
                                       latent_kv_caches, uniform_kv_caches)
 from alpa_tpu.serve.generation import Generator
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import arithmetic_longcat, observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params
+from chipbench import arithmetic_longcat, run
 
 TOY = run.load_json(run.HERE, "configs", "toy-longcat.json")
 CELL = run.load_json(run.HERE, "configs", "longcat-flash-1chip.json")
@@ -46,28 +39,9 @@ CONTEXT, S = 96, 48
 TOL = 5e-5
 
 
-def highest(f, *args):
-    with jax.default_matmul_precision("highest"):
-        return f(*args)
-
-
 def toy_config(**kwargs):
     return MLA.model_config(
         TOY, **{"dtype": jnp.float32, "seq_len": CONTEXT, **kwargs})
-
-
-def shake(params, seed=0):
-    """Norm weights away from 1 and router biases away from 0, so that a
-    forgotten one shows."""
-    def one(path, x):
-        key = jax.random.PRNGKey(seed + len(jax.tree_util.keystr(path)))
-        if path[-1].key == "scale":
-            return x * jax.random.uniform(key, x.shape, minval=0.5,
-                                          maxval=1.5)
-        if path[-1].key == "router_bias":
-            return 0.02 * jax.random.normal(key, x.shape)
-        return x
-    return jax.tree_util.tree_map_with_path(one, params)
 
 
 @pytest.fixture(scope="module")
@@ -78,11 +52,23 @@ def reference():
 
 @pytest.fixture(scope="module")
 def toy():
-    """(model, parameters, ids (3, S))."""
+    """(model, parameters, ids (3, S)): norm weights away from 1 and
+    router biases away from 0, so that a forgotten one shows."""
     model = GPTModel(toy_config())
     ids = jax.random.randint(jax.random.PRNGKey(0), (3, S), 0,
                              TOY["vocab_size"])
-    return model, shake(model.init(jax.random.PRNGKey(2), ids)), ids
+    params = init_params(model, jax.random.PRNGKey(2), ids)
+
+    def shake(path, x):
+        key = jax.random.PRNGKey(len(jax.tree_util.keystr(path)))
+        if path[-1].key == "scale":
+            return x * jax.random.uniform(key, x.shape, minval=0.5,
+                                          maxval=1.5)
+        if path[-1].key == "router_bias":
+            return 0.02 * jax.random.normal(key, x.shape)
+        return x
+
+    return model, jax.tree_util.tree_map_with_path(shake, params), ids
 
 
 @pytest.fixture(scope="module")
@@ -149,14 +135,10 @@ def test_config_from_hf_reads_the_catalog_rows_config():
         config_from_hf({**hf, "rope_scaling": {"type": "yarn", "factor": 2}})
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
     """Every number of the catalog row's ``config`` is in the cell's file
     under the same key, but the three keys it lists as reduced."""
-    path = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if not os.path.exists(path):
-        pytest.skip("no catalog here")
-    row = next(json.loads(line) for line in open(path)
-               if json.loads(line)["name"] == "LongCat-Flash-Chat")
+    row = catalog_row("LongCat-Flash-Chat")
     differ = {k for k, v in row["config"].items() if CELL.get(k) != v}
     assert differ == set(CELL["reduced"]) == {
         "num_layers", "n_routed_experts", "vocab_size"}
@@ -332,7 +314,8 @@ def test_experts_that_give_nothing_leave_the_dense_double_layer(toy):
 def test_a_block_held_back_for_no_one_is_refused():
     cfg = toy_config(num_layers=3, mlp=(SHORTCUT_MLP, "gated", SHORTCUT_MLP))
     with pytest.raises(ValueError, match="held back for a next block"):
-        GPTModel(cfg).init(jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+        init_params(GPTModel(cfg), jax.random.PRNGKey(0),
+                    jnp.ones((1, 8), jnp.int32))
 
 
 def _decode_all(gen, params, row, start, caches, wanted_row):
@@ -403,7 +386,7 @@ def test_bucketed_prefill_then_decode_equals_the_reference(toy, wanted):
 
 def _layer_params(cfg, h, bias=None):
     layer = moe.DroplessExperts(cfg)
-    p = layer.init(jax.random.PRNGKey(6), h)["params"]
+    p = init_params(layer, jax.random.PRNGKey(6), h)["params"]
     if bias is not None:
         p = {**p, "router_bias": jnp.asarray(bias, jnp.float32)}
     return layer, p
@@ -529,24 +512,13 @@ def test_rows_behind_the_groups_take_no_part():
 
 # ---- the driver -------------------------------------------------------
 
-def _toy_context(tmp_path, steady):
-    """The toy cell's context, its check on the same requests whatever the
-    machine's load (``conftest.checks_the_same_requests``)."""
-    return steady(run.Context(
-        cell={"name": "toy-longcat.agent", "config": "toy-longcat",
-              "traffic": "toy-agent", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-agent"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace")))
-
-
 def test_the_routers_balance_leaves_the_mean_input_unscored():
     """``balance_routers``: every router loses one direction, that of the
     mean of its layer's input, which then scores 0 with every output, the
     identity experts' too; nothing else of the model moves."""
     model = GPTModel(toy_config())
-    params = model.init(jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32))
+    params = init_params(model, jax.random.PRNGKey(3),
+                         jnp.ones((1, 8), jnp.int32))
     moved = DRIVER.balance_routers(model, params, jax.random.PRNGKey(4),
                                    TOY["vocab_size"])
     changed = [jax.tree_util.keystr(path) for (path, a), b in zip(
@@ -563,13 +535,14 @@ def test_the_routers_balance_leaves_the_mean_input_unscored():
         assert np.abs(u[:, 0] @ w_new).max() < 1e-5  # and none of it left
 
 
-def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
+def test_driver_runs_the_toy_cell(toy_context, checks_the_same_requests):
     """``chipbench/drivers/serve_scmoe.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference; and what the
     cell's readers make of it."""
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-longcat.agent", "toy-agent", 3.0, 2,
+                                 checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -605,14 +578,15 @@ def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
 @pytest.mark.parametrize("control", ["cache_in_float8",
                                      "matrices_in_float8",
                                      "identity_left_out"])
-def test_driver_fails_a_control(tmp_path, monkeypatch, control,
+def test_driver_fails_a_control(toy_context, monkeypatch, control,
                                 checks_the_same_requests):
     """The controls the cell's limits are set against
     (``chipbench/controls_longcat.py``), planted at the toy size: each
     serves plausible tokens and is not correct."""
     from chipbench import controls_longcat
     controls_longcat.CONTROLS[control](TOY, monkeypatch.setattr)
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-longcat.agent", "toy-agent", 3.0, 0,
+                                 checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks

@@ -15,9 +15,6 @@ against the reference's full forward pass, logits and not tokens.  The
 benchmark's cell compares the bfloat16 program with the same reference on
 the chip."""
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,13 +29,8 @@ from alpa_tpu.model.gpt_model import (GPTModel, cached_key_block,
                                       uniform_kv_caches)
 from alpa_tpu.ops import cached_attention as kernels
 from alpa_tpu.serve.generation import GenerationConfig, Generator
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import (arithmetic_mimo, controls_mimo, observe,  # noqa: E402
-                       run, traffic)
+from alpa_tpu.testing import highest, init_params, shake
+from chipbench import arithmetic_mimo, controls_mimo, run, traffic
 
 TOY = run.load_json(run.HERE, "configs", "toy-mimo.json")
 CELL = run.load_json(run.HERE, "configs", "mimo-v2-flash-1chip.json")
@@ -46,14 +38,8 @@ BENCH = run.load_json(run.ROOT, "BENCHMARK.json")
 DRIVER = run.load_module("drivers", "serve_mimo")
 MLA = run.load_module("drivers", "serve_mla")
 REF = run.load_module("references", "mimo_v2_flash_decoder")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONTEXT, S = 128, 48
 TOL = 5e-5
-
-
-def highest(f, *args):
-    with jax.default_matmul_precision("highest"):
-        return f(*args)
 
 
 def toy_config(**kwargs):
@@ -61,23 +47,13 @@ def toy_config(**kwargs):
         TOY, **{"dtype": jnp.float32, "seq_len": CONTEXT, **kwargs})
 
 
-def shake(params, seed=0):
-    """Norm weights away from 1 and router biases away from 0, so that a
-    weight applied in the wrong place shows (the sinks are drawn at the
-    scale of a score as they are)."""
-    def moved(path, x):
-        if path[-1].key in ("scale", "router_bias"):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                     hash(jax.tree_util.keystr(path)) % 997)
-            return x + 0.3 * jax.random.normal(key, x.shape, x.dtype)
-        return x
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
 def made(cfg):
+    """Norm weights and the routers' biases shaken (the sinks are drawn at
+    the scale of a score as they are)."""
     model = GPTModel(cfg)
-    return model, shake(model.init(jax.random.PRNGKey(0),
-                                   jnp.ones((1, 8), jnp.int32)))
+    return model, shake(init_params(model, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)),
+                        ("scale", "router_bias"))
 
 
 @pytest.fixture(scope="module")
@@ -98,16 +74,8 @@ def wanted(toy):
 
 # ---- the configuration ------------------------------------------------
 
-def catalog_row():
-    if not os.path.exists(CATALOG):
-        pytest.skip("the catalog of architectures is not on this machine")
-    with open(CATALOG) as f:
-        return next(row for row in map(json.loads, f)
-                    if row["name"] == "MiMo-V2-Flash")
-
-
-def test_config_from_hf_reads_the_catalog_rows_config():
-    cfg = config_from_hf(catalog_row()["config"])
+def test_config_from_hf_reads_the_catalog_rows_config(catalog_row):
+    cfg = config_from_hf(catalog_row("MiMo-V2-Flash")["config"])
     assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads) == (4096, 48, 64)
     assert (cfg.kv_heads_of("full"), cfg.kv_heads_of("sliding")) == (4, 8)
     assert (cfg.head_size, cfg.value_size, cfg.rotary_dim) == (192, 128, 64)
@@ -129,11 +97,12 @@ def test_config_from_hf_reads_the_catalog_rows_config():
     assert cfg.layer_norm_eps == 1e-5 and cfg.seq_len == 262144
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
-    published = catalog_row()["config"]
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
+    row = catalog_row("MiMo-V2-Flash")
+    published = row["config"]
     entry = next(c for c in BENCH["configs"]
                  if c["name"] == "mimo-v2-flash-1chip")
-    assert entry["source"] == catalog_row()["source_url"]
+    assert entry["source"] == row["source_url"]
     assert CELL["reduced"] == entry["reduced"] == [
         "num_hidden_layers", "hybrid_layer_pattern", "moe_layer_freq",
         "n_routed_experts", "vocab_size"]
@@ -155,9 +124,8 @@ def test_the_cells_file_is_the_share_the_issue_counts():
     parameters: 3,430 M parameters, and the caches' bytes."""
     cfg = MLA.model_config(CELL, dtype=jnp.bfloat16,
                            param_dtype=jnp.bfloat16, seq_len=32768)
-    shapes = jax.eval_shape(
-        lambda key: GPTModel(cfg).init(key, jnp.ones((1, 8), jnp.int32)),
-        jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(GPTModel(cfg).init, jax.random.PRNGKey(0),
+                            jnp.ones((1, 8), jnp.int32))
     count = sum(x.size for x in jax.tree_util.tree_leaves(shapes))
     small = 15 * 4096 + 5 * 64 + 6 * 256        # norms, sinks, biases
     assert count == arithmetic_mimo.model_parameters(CELL) + small == \
@@ -507,23 +475,14 @@ def test_the_head_forgets_the_common_direction_and_nothing_else(toy):
                if path[-2].key != "lm_head")
 
 
-def _toy_context(tmp_path, steady):
-    return steady(run.Context(
-        cell={"name": "toy-mimo.longmix", "config": "toy-mimo",
-              "traffic": "toy-longmix", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-longmix"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace")))
-
-
-def test_driver_runs_the_toy_cell(tmp_path, checks_the_same_requests):
+def test_driver_runs_the_toy_cell(toy_context, checks_the_same_requests):
     """``chipbench/drivers/serve_mimo.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' biases, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the check against the reference; and what the
     cell's readers make of it."""
-    obs = DRIVER.run(_toy_context(tmp_path, checks_the_same_requests))
+    obs = DRIVER.run(toy_context("toy-mimo.longmix", "toy-longmix", 3.0, 2,
+                                 checks_the_same_requests))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 16, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0

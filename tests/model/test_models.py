@@ -13,7 +13,7 @@ from alpa_tpu.model.gpt_model import GPTConfig, GPTModel, init_kv_caches
 from alpa_tpu.model.moe import MoEConfig, MoELMModel
 from alpa_tpu.model.model_util import cross_entropy_loss
 from alpa_tpu.model.wide_resnet import WResNetConfig, WideResNet
-from alpa_tpu.testing import assert_allclose
+from alpa_tpu.testing import init_params, jitted
 
 
 class TestGPT:
@@ -25,14 +25,15 @@ class TestGPT:
         model = GPTModel(cfg)
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (2, 16), 0, 64)
-        params = model.init(rng, ids)
-        full_logits = model.apply(params, ids)
+        params = init_params(model, rng, ids)
+        full_logits = jitted(model.apply)(params, ids)
 
         caches = init_kv_caches(cfg, batch_size=2)
+        decode = jitted(model.apply)
         for t in range(16):
             step_ids = ids[:, t:t + 1]
             pos = jnp.full((2, 1), t, jnp.int32)
-            logits, caches = model.apply(params, step_ids, pos, caches)
+            logits, caches = decode(params, step_ids, pos, caches)
         np.testing.assert_allclose(np.asarray(logits[:, 0]),
                                    np.asarray(full_logits[:, -1]),
                                    rtol=2e-4, atol=2e-4)
@@ -49,7 +50,7 @@ class TestMoE:
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (8, 16), 0, 64)
         labels = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-        params = model.init(rng, ids)
+        params = init_params(model, rng, ids)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
                                               tx=optax.adam(1e-3))
@@ -96,21 +97,22 @@ class TestMoE:
         model = MoELMModel(cfg)
         rng = np.random.RandomState(0)
         ids = rng.randint(0, 64, (2, 10)).astype(np.int32)
-        params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))
-        full, _aux = model.apply(params, jnp.asarray(ids))
+        params = init_params(model, jax.random.PRNGKey(0), jnp.asarray(ids))
+        apply = jitted(model.apply)
+        full, _aux = apply(params, jnp.asarray(ids))
         full = np.asarray(full)
 
         caches = init_moe_kv_caches(cfg, 2)
-        logits_p, caches = model.apply(params, jnp.asarray(ids[:, :6]),
-                                       None, caches)
+        logits_p, caches = apply(params, jnp.asarray(ids[:, :6]), None,
+                                 caches)
         np.testing.assert_allclose(np.asarray(logits_p), full[:, :6],
                                    rtol=5e-4, atol=5e-4)
         for t in range(6, 10):
             # learned position table: absolute positions must be passed
             # for incremental decode (the Generator does this)
             pos = jnp.full((2, 1), t, jnp.int32)
-            step, caches = model.apply(params, jnp.asarray(ids[:, t:t + 1]),
-                                       pos, caches)
+            step, caches = apply(params, jnp.asarray(ids[:, t:t + 1]), pos,
+                                 caches)
             np.testing.assert_allclose(np.asarray(step)[:, 0], full[:, t],
                                        rtol=5e-4, atol=5e-4)
 
@@ -123,8 +125,8 @@ class TestMoE:
                         capacity_factor=4.0, expert_group_size=64,
                         moe_every=2, ep_axis=None)
         model = MoELMModel(cfg)
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.ones((1, 8), jnp.int32))
+        params = init_params(model, jax.random.PRNGKey(0),
+                             jnp.ones((1, 8), jnp.int32))
         gen = Generator(model, params, cfg, batch_size=1,
                         prompt_buckets=[8])
         out = gen.generate(np.array([[1, 2, 3]], np.int32),
@@ -133,7 +135,7 @@ class TestMoE:
         # greedy replay without cache
         replay = np.array([[1, 2, 3]], np.int32)
         for _ in range(5):
-            lg, _aux = model.apply(params, jnp.asarray(replay))
+            lg, _aux = jitted(model.apply)(params, jnp.asarray(replay))
             nxt = np.argmax(np.asarray(lg[:, -1]), -1)
             replay = np.concatenate([replay, nxt[:, None].astype(np.int32)],
                                     axis=1)
@@ -148,12 +150,13 @@ class TestBert:
         model = BertForMaskedLM(cfg)
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (4, 16), 0, 64)
-        params = model.init(rng, ids)
-        logits = model.apply(params, ids)
+        params = init_params(model, rng, ids)
+        apply = jitted(model.apply)
+        logits = apply(params, ids)
         assert logits.shape == (4, 16, 64)
         # bidirectional: perturbing a late token changes early logits
         ids2 = ids.at[:, -1].set((ids[:, -1] + 1) % 64)
-        logits2 = model.apply(params, ids2)
+        logits2 = apply(params, ids2)
         assert not np.allclose(np.asarray(logits[:, 0]),
                                np.asarray(logits2[:, 0]))
 
@@ -165,17 +168,18 @@ class TestBert:
         ids = jax.random.randint(rng, (2, 16), 0, 64)
         mask = jnp.concatenate([jnp.ones((2, 12), jnp.int32),
                                 jnp.zeros((2, 4), jnp.int32)], axis=1)
-        params = model.init(rng, ids, mask)
-        base = model.apply(params, ids, mask)
+        params = init_params(model, rng, ids, mask)
+        apply = jitted(model.apply)
+        base = apply(params, ids, mask)
         # changing tokens under the padding mask must not change valid
         # positions' logits
         ids2 = ids.at[:, -1].set((ids[:, -1] + 7) % 64)
-        out2 = model.apply(params, ids2, mask)
+        out2 = apply(params, ids2, mask)
         np.testing.assert_allclose(np.asarray(base[:, :12]),
                                    np.asarray(out2[:, :12]),
                                    rtol=1e-6, atol=1e-6)
         # without the mask they do change (sanity)
-        out3 = model.apply(params, ids2)
+        out3 = apply(params, ids2)
         assert not np.allclose(np.asarray(base[:, :12]),
                                np.asarray(out3[:, :12]))
 
@@ -187,12 +191,12 @@ class TestBert:
         model = BertForPreTraining(cfg)
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (4, 16), 0, 64)
-        params = model.init(rng, ids)
+        params = init_params(model, rng, ids)
         # tied decoder: no separate (H, V) decoder kernel in the tree
         flat = jax.tree_util.tree_leaves_with_path(params)
         assert not any("decoder/" in jax.tree_util.keystr(p).replace(
             "']['", "/") and l.ndim == 2 for p, l in flat)
-        mlm_logits, nsp_logits = model.apply(params, ids)
+        mlm_logits, nsp_logits = jitted(model.apply)(params, ids)
         assert mlm_logits.shape == (4, 16, 64)
         assert nsp_logits.shape == (4, 2)
 
@@ -208,7 +212,7 @@ class TestBert:
             return bert_pretraining_loss(ml, nl, mlm_labels, mlm_weights,
                                          nsp_labels)
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        loss, grads = jitted(jax.value_and_grad(loss_fn))(params)
         assert np.isfinite(float(loss))
         # the tied embedding table receives gradient from the MLM head
         g_emb = grads["params"]["bert"]["word_embeddings"]["embedding"]
@@ -223,7 +227,7 @@ class TestWideResNet:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (8, 32, 32, 3))
         y = jax.random.randint(jax.random.PRNGKey(1), (8,), 0, 10)
-        params = model.init(rng, x)
+        params = init_params(model, rng, x)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
                                               tx=optax.sgd(1e-2))
@@ -243,10 +247,6 @@ class TestWideResNet:
         assert np.isfinite(float(loss))
 
 
-if __name__ == "__main__":
-    pytest.main([__file__, "-x", "-q"])
-
-
 class TestUNetAndConformer:
 
     def test_unet_forward_and_grad(self):
@@ -258,10 +258,11 @@ class TestUNetAndConformer:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, 16, 16, 3))
         t = jnp.array([1, 5])
-        params = model.init(rng, x, t)
-        out = model.apply(params, x, t)
+        params = init_params(model, rng, x, t)
+        out = jitted(model.apply)(params, x, t)
         assert out.shape == (2, 16, 16, 3)
-        g = jax.grad(lambda p: (model.apply(p, x, t)**2).mean())(params)
+        g = jitted(jax.grad(lambda p: (model.apply(p, x, t)**2).mean()))(
+            params)
         assert np.isfinite(float(
             jax.tree_util.tree_leaves(g)[0].sum()))
 
@@ -279,14 +280,15 @@ class TestUNetAndConformer:
         x = jax.random.normal(rng, (2, 16, 16, 4))
         t = jnp.array([3, 11])
         ctx = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 24))
-        params = model.init(rng, x, t, ctx)
-        out = model.apply(params, x, t, ctx)
+        params = init_params(model, rng, x, t, ctx)
+        apply = jitted(model.apply)
+        out = apply(params, x, t, ctx)
         assert out.shape == (2, 16, 16, 4)
         # conditioning actually conditions: different context, different out
-        out2 = model.apply(params, x, t, ctx + 1.0)
+        out2 = apply(params, x, t, ctx + 1.0)
         assert not np.allclose(np.asarray(out), np.asarray(out2))
-        g = jax.grad(lambda p: (model.apply(p, x, t, ctx)**2).mean())(
-            params)
+        g = jitted(jax.grad(
+            lambda p: (model.apply(p, x, t, ctx)**2).mean()))(params)
         assert np.isfinite(float(jax.tree_util.tree_leaves(g)[0].sum()))
 
     def test_unet_auto_sharding_nontrivial(self):
@@ -299,9 +301,9 @@ class TestUNetAndConformer:
                          time_embed_dim=32)
         model = UNet2D(cfg)
         rng = jax.random.PRNGKey(0)
-        x = jax.random.normal(rng, (16, 16, 16, 3))
-        t = jnp.arange(16)
-        params = model.init(rng, x, t)
+        x = jax.random.normal(rng, (8, 16, 16, 3))
+        t = jnp.arange(8)
+        params = init_params(model, rng, x, t)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
                                               tx=optax.sgd(1e-2))
@@ -332,8 +334,9 @@ class TestUNetAndConformer:
         rng = jax.random.PRNGKey(0)
         feats = jax.random.normal(rng, (4, 64, 20))
         lengths = jnp.array([64, 48, 32, 16])
-        params = model.init(rng, feats, lengths)
-        log_probs, out_lens = model.apply(params, feats, lengths)
+        params = init_params(model, rng, feats, lengths)
+        apply = jitted(model.apply)
+        log_probs, out_lens = apply(params, feats, lengths)
         assert log_probs.shape == (4, 16, 30)     # T subsampled 4x
         assert list(np.asarray(out_lens)) == [16, 12, 8, 4]
         # log-probs normalized
@@ -342,7 +345,7 @@ class TestUNetAndConformer:
         # padding invariance: corrupting frames past a row's length must
         # not change its valid outputs
         feats2 = feats.at[1, 48:].set(99.0)
-        lp2, _ = model.apply(params, feats2, lengths)
+        lp2, _ = apply(params, feats2, lengths)
         np.testing.assert_allclose(np.asarray(log_probs[1, :12]),
                                    np.asarray(lp2[1, :12]), rtol=1e-4,
                                    atol=1e-4)
@@ -350,7 +353,7 @@ class TestUNetAndConformer:
         # width must give the same valid log-probs (no norm reading stats
         # off the time axis)
         solo = jnp.zeros((1, 32, 20)).at[0, :].set(feats[2, :32])
-        lp_solo, _ = model.apply(params, solo, jnp.array([32]))
+        lp_solo, _ = apply(params, solo, jnp.array([32]))
         np.testing.assert_allclose(np.asarray(log_probs[2, :8]),
                                    np.asarray(lp_solo[0, :8]), rtol=1e-4,
                                    atol=1e-4)
@@ -362,8 +365,8 @@ class TestUNetAndConformer:
         model = Conformer(cfg)
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (8, 32, 20))
-        params = model.init(rng, x)
-        out = model.apply(params, x)
+        params = init_params(model, rng, x)
+        out = jitted(model.apply)(params, x)
         assert out.shape == (8, 32, 64)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
@@ -403,7 +406,7 @@ class TestExpertParallelStructure:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (8, 32, 64))
         with jax.set_mesh(mesh):
-            params = m.init(rng, x)
+            params = init_params(m, rng, x)
             f = jax.jit(lambda p, xx: m.apply(p, xx)[0],
                         in_shardings=(None, NamedSharding(mesh, P("ep"))))
             hlo = f.lower(params, x).compile().as_text()
@@ -411,7 +414,8 @@ class TestExpertParallelStructure:
         total, ar, ag, rs, a2a = count_communication_primitives(hlo)
         assert a2a >= 2, (total, ar, ag, rs, a2a)
         assert ag == 0, f"dispatch fell back to all-gathers: {ag}"
-        out_ref = MoEMLP(MoEConfig(ep_axis=None, **kw)).apply(params, x)[0]
+        out_ref = jitted(MoEMLP(MoEConfig(ep_axis=None, **kw)).apply)(
+            params, x)[0]
         np.testing.assert_allclose(np.asarray(out_sharded),
                                    np.asarray(out_ref), rtol=2e-5,
                                    atol=2e-5)
@@ -444,7 +448,7 @@ class TestDynamicScale:
         model = GPTModel(cfg)
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (8, 16), 0, 64)
-        params = model.init(rng, ids)
+        params = init_params(model, rng, ids)
         state = TrainState.create_with_scale(
             apply_fn=model.apply, params=params, tx=optax.sgd(1e-2),
             use_dynamic_scale=True)
@@ -478,3 +482,7 @@ class TestDynamicScale:
         assert all(np.isfinite(l) for l in losses)
         assert losses[-1] < losses[0]
         assert float(state.dynamic_scale.scale) >= 1.0
+
+
+if __name__ == "__main__":
+    pytest.main([__file__, "-x", "-q"])

@@ -5,9 +5,6 @@ at a toy size on the CPU: hidden 64, 8 experts, 2 a token, 2 layers, seeded
 weights.  Float32 activations at full matmul precision, so that what is
 compared is the mathematics; the benchmark's cell compares the bfloat16
 program with the same reference on the chip."""
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,12 +18,8 @@ from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, TransformerBlock,
                                       config_from_hf)
 from alpa_tpu.model.model_util import routed_lm_loss
 from alpa_tpu.ops.grouped_matmul import grouped_matmul
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params, jitted
+from chipbench import run
 
 AUX = 0.01
 TOY = run.load_json(run.HERE, "configs", "toy-olmoe.json")
@@ -56,7 +49,7 @@ def toy():
                              TOY["vocab_size"])
     labels = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                                 TOY["vocab_size"])
-    params = model.init(jax.random.PRNGKey(2), ids)
+    params = init_params(model, jax.random.PRNGKey(2), ids)
     # norm weights away from 1, so that a forgotten one shows
     params = jax.tree_util.tree_map_with_path(
         lambda path, x: x * jax.random.uniform(
@@ -66,8 +59,7 @@ def toy():
 
 
 def program_loss(model, params, batch):
-    with jax.default_matmul_precision("highest"):
-        return routed_lm_loss(model.apply, params, batch, AUX)[0]
+    return highest(routed_lm_loss, model.apply, params, batch, AUX)[0]
 
 
 def reference_loss(mod, ref, params, batch):
@@ -97,8 +89,8 @@ def reference_loss(mod, ref, params, batch):
 def test_logits_match_reference(toy, reference):
     model, params, batch = toy
     mod, ref = reference
-    with jax.default_matmul_precision("highest"):
-        logits, routing = model.apply(params, batch["input_ids"])
+    logits, routing = highest(jitted(model.apply), params,
+                              batch["input_ids"])
     weights = mod.weights_from_program(params)
     for i in range(B):
         want = ref.logits(weights, batch["input_ids"][i])
@@ -116,7 +108,7 @@ def test_logits_match_reference(toy, reference):
 def test_loss_matches_reference(toy, reference):
     model, params, batch = toy
     mod, ref = reference
-    got = float(program_loss(model, params, batch))
+    got = float(jitted(lambda p: program_loss(model, p, batch))(params))
     want = ref.lm_loss(mod.weights_from_program(params), batch["input_ids"],
                        batch["labels"])
     assert got == pytest.approx(want, rel=2e-6)
@@ -124,15 +116,16 @@ def test_loss_matches_reference(toy, reference):
     assert float(reference_loss(mod, ref, params, batch)) == \
         pytest.approx(want, rel=2e-6)
     # the load-balancing term is in it: k at an even routing, more here
-    _, routing = model.apply(params, batch["input_ids"])
+    _, routing = jitted(model.apply)(params, batch["input_ids"])
     assert float(routing["load_balance_loss"]) > 2.0
 
 
 def test_every_gradient_leaf_matches_reference(toy, reference):
     model, params, batch = toy
     mod, ref = reference
-    got = jax.grad(lambda p: program_loss(model, p, batch))(params)
-    want = jax.grad(lambda p: reference_loss(mod, ref, p, batch))(params)
+    got = jitted(jax.grad(lambda p: program_loss(model, p, batch)))(params)
+    want = jitted(jax.grad(
+        lambda p: reference_loss(mod, ref, p, batch)))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree_util.tree_leaves(want)
     assert len(flat_got) == 23     # 10 a layer, wte, ln_f, lm_head
@@ -153,8 +146,8 @@ def test_every_token_to_the_same_experts_still_agrees(toy, reference):
     params = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.zeros_like(x) if "router" in
         jax.tree_util.keystr(path) else x, params)
-    with jax.default_matmul_precision("highest"):
-        logits, routing = model.apply(params, batch["input_ids"])
+    logits, routing = highest(jitted(model.apply), params,
+                              batch["input_ids"])
     assert (np.asarray(routing["expert_counts"]) ==
             [[B * S, B * S, 0, 0, 0, 0, 0, 0]] * 2).all()
     weights = mod.weights_from_program(params)
@@ -162,7 +155,8 @@ def test_every_token_to_the_same_experts_still_agrees(toy, reference):
         np.testing.assert_allclose(
             logits[i], ref.logits(weights, batch["input_ids"][i]),
             atol=2e-5, rtol=0)
-    assert float(program_loss(model, params, batch)) == pytest.approx(
+    got = float(jitted(lambda p: program_loss(model, p, batch))(params))
+    assert got == pytest.approx(
         ref.lm_loss(weights, batch["input_ids"], batch["labels"]), rel=2e-6)
 
 
@@ -283,16 +277,10 @@ def test_the_plan_treats_the_expert_path_as_recorded(toy):
     assert len(choice) == len(graph.nodes)
 
 
-def test_driver_runs_the_toy_cell(tmp_path):
+def test_driver_runs_the_toy_cell(toy_context):
     """``chipbench/drivers/train_lm.py`` end to end on the CPU: plan,
     state, reference, per-position check, warm-up, window, traced part."""
-    ctx = run.Context(
-        cell={"name": "toy-olmoe.train", "config": "toy-olmoe",
-              "traffic": "toy-lm", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-lm"), seed=2147483659,
-        seconds=1.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace"))
+    ctx = toy_context("toy-olmoe.train", "toy-lm", 1.0, 2)
     # the registry is the process's: an engine of routed layers in an
     # earlier test has fed the same counter
     from alpa_tpu.telemetry import metrics as tmetrics
@@ -363,7 +351,7 @@ def test_legacy_capacity_path_reports_its_drops():
                         capacity_factor=0.5, mlp_ratio=2)
     layer = moe.MoEMLP(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 32))
-    params = layer.init(jax.random.PRNGKey(1), x)
+    params = init_params(layer, jax.random.PRNGKey(1), x)
     _, state = layer.apply(params, x, mutable=["intermediates"])
     kept, dropped = moe.legacy_routing(state["intermediates"])
     assert kept.sum() + dropped == 2 * 64 and dropped > 0
@@ -389,7 +377,7 @@ def test_block_takes_its_mlp_kind_from_the_configuration(kind, names):
                     num_experts=4, num_experts_per_tok=2, activation="silu")
     block = TransformerBlock(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 32))
-    params = block.init(jax.random.PRNGKey(1), x)["params"]
+    params = init_params(block, jax.random.PRNGKey(1), x)["params"]
     assert set(params["mlp"]) == names
     out = block.apply({"params": params}, x)
     assert len(out) == (3 if kind == "experts" else 2)
@@ -402,7 +390,8 @@ def test_layers_may_differ_and_the_default_block_is_gpt2s():
                       intermediate_size=48, num_experts=4,
                       num_experts_per_tok=2)
     ids = jnp.zeros((1, 8), jnp.int32)
-    params = GPTModel(mixed).init(jax.random.PRNGKey(0), ids)["params"]
+    params = init_params(GPTModel(mixed), jax.random.PRNGKey(0),
+                         ids)["params"]
     assert set(params["h0"]["mlp"]) == {"fc_in", "fc_out"}
     assert "router" in params["h1"]["mlp"]
     logits, routing = GPTModel(mixed).apply({"params": params}, ids)
@@ -411,7 +400,7 @@ def test_layers_may_differ_and_the_default_block_is_gpt2s():
     # bias, learned positions, a fused biased qkv, a tied head
     plain = GPTModel(GPTConfig(hidden_size=32, num_heads=2, seq_len=8,
                                vocab_size=64, num_layers=1))
-    params = plain.init(jax.random.PRNGKey(0), ids)["params"]
+    params = init_params(plain, jax.random.PRNGKey(0), ids)["params"]
     assert set(params) == {"wte", "wpe", "h0", "ln_f"}
     assert set(params["h0"]["ln1"]) == {"scale", "bias"}
     assert set(params["h0"]["attn"]["qkv"]) == {"kernel", "bias"}

@@ -6,10 +6,6 @@ weights at a small size, in float32 at full matmul precision; the
 published ``config.json`` through ``config_from_hf``; the cell's driver on
 its toy configuration.
 """
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,18 +16,13 @@ from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf,
                                       reference_attention)
 from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
                                        Generator)
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params
+from chipbench import run
 
 TOY = run.load_json(run.HERE, "configs", "toy-sdar.json")
 CELL = run.load_json(run.HERE, "configs", "sdar-30b-a3b-1chip.json")
 DRIVER = run.load_module("drivers", "serve_diffusion")
 REF = run.load_module("references", "sdar_moe_decoder")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 L, MASK, CONTEXT = 4, 250, 96
 TOL = 2e-5      # float32 at full precision, logits of unit spread
 
@@ -40,11 +31,6 @@ def toy_config(**kwargs):
     return DRIVER.model_config(TOY, dtype=jnp.float32,
                                param_dtype=jnp.float32, seq_len=CONTEXT,
                                **kwargs)
-
-
-def highest(fn, *args, **kwargs):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args, **kwargs)
 
 
 @pytest.fixture(scope="module")
@@ -56,7 +42,8 @@ def reference():
 def toy():
     cfg = toy_config()
     model = GPTModel(cfg)
-    params = model.init(jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32))
+    params = init_params(model, jax.random.PRNGKey(3),
+                         jnp.ones((1, 8), jnp.int32))
     return cfg, model, params, REF.weights_from_program(params)
 
 
@@ -103,11 +90,8 @@ def test_the_parameter_tree_counts_what_the_issue_counts(depth, parameters):
         parameters
 
 
-def test_the_cells_json_keeps_the_catalog_rows_numbers():
-    if not os.path.exists(CATALOG):
-        pytest.skip("no catalog here")
-    row = next(json.loads(line) for line in open(CATALOG)
-               if json.loads(line)["name"] == "SDAR-30B-A3B-Chat")
+def test_the_cells_json_keeps_the_catalog_rows_numbers(catalog_row):
+    row = catalog_row("SDAR-30B-A3B-Chat")
     differ = {k for k, v in row["config"].items() if CELL.get(k) != v}
     assert differ == set(CELL["reduced"]) == {"num_hidden_layers"}
     assert CELL["published"]["num_hidden_layers"] == \
@@ -335,22 +319,12 @@ def test_a_generator_is_told_how_its_configuration_generates(toy):
 
 # ---- the cell's driver -------------------------------------------------
 
-def _toy_context(tmp_path):
-    return run.Context(
-        cell={"name": "toy-sdar.reasoning", "config": "toy-sdar",
-              "traffic": "toy-reasoning", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-reasoning"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace"))
-
-
-def test_driver_runs_the_toy_cell(tmp_path):
+def test_driver_runs_the_toy_cell(toy_context):
     """``chipbench/drivers/serve_diffusion.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights, the
     routers' balance, controller, warm-up, a closed-loop window over HTTP,
     the traced seconds, the replay and the check against the reference."""
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(toy_context("toy-sdar.reasoning", "toy-reasoning", 3.0, 2))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -391,13 +365,14 @@ def _causal_inside_a_block(monkeypatch):
         lambda *args, **kwargs: plain(*args, **{**kwargs, "block": 0}))
 
 
-def test_driver_reads_a_causal_block_as_not_correct(tmp_path, monkeypatch):
+def test_driver_reads_a_causal_block_as_not_correct(toy_context,
+                                                     monkeypatch):
     """One of the controls the cell's limits are set against, at the toy
     size: the plain causal mask inside a block (mathematics left out, one
     comparison a score saved) serves every request in full and is not
     correct."""
     _causal_inside_a_block(monkeypatch)
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(toy_context("toy-sdar.reasoning", "toy-reasoning", 3.0, 0))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks

@@ -12,8 +12,6 @@ through the ring and full caches against the reference's full forward
 pass, logits and not tokens.  The benchmark's cell compares the bfloat16
 program with the same reference on the chip."""
 import dataclasses
-import os
-import sys
 import threading
 
 import jax
@@ -29,12 +27,8 @@ from alpa_tpu.serve.disagg import PrefillEngine
 from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-from chipbench import observe, run, traffic  # noqa: E402
+from alpa_tpu.testing import highest, init_params
+from chipbench import run
 
 TOY = run.load_json(run.HERE, "configs", "toy-trinity.json")
 DRIVER = run.load_module("drivers", "serve_lm")
@@ -59,7 +53,7 @@ def toy():
     model = GPTModel(toy_config())
     ids = jax.random.randint(jax.random.PRNGKey(0), (3, S), 0,
                              TOY["vocab_size"])
-    params = model.init(jax.random.PRNGKey(2), ids)
+    params = init_params(model, jax.random.PRNGKey(2), ids)
 
     def shake(path, x):
         key = jax.random.PRNGKey(len(str(path)))
@@ -80,11 +74,6 @@ def wanted(reference, toy):
     _model, params, ids = toy
     weights = mod.weights_from_program(params)
     return np.stack([np.asarray(ref.logits(weights, row)) for row in ids])
-
-
-def highest(fn, *args, **kwargs):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args, **kwargs)
 
 
 def test_config_from_hf_reads_the_afmoe_keys():
@@ -325,22 +314,12 @@ def test_parameters_are_stored_in_the_stated_dtype():
         assert leaf.dtype == want, path
 
 
-def _toy_context(tmp_path):
-    return run.Context(
-        cell={"name": "toy-trinity.mixed", "config": "toy-trinity",
-              "traffic": "toy-mixed", "chips": 1},
-        config=TOY, mix=traffic.load_mix("toy-mixed"), seed=2147483659,
-        seconds=3.0, trace=2, rehearsal=True, spans=observe.Spans(),
-        compile_events=observe.CompileEvents(),
-        trace_dir=str(tmp_path / "trace"))
-
-
-def test_driver_runs_the_toy_cell(tmp_path):
+def test_driver_runs_the_toy_cell(toy_context):
     """``chipbench/drivers/serve_lm.py`` end to end on the CPU
     (``chipbench/rehearsal.json`` is not this PR's to edit): weights,
     controller, warm-up, a closed-loop window over HTTP, the traced
     seconds, the check against the reference."""
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(toy_context("toy-trinity.mixed", "toy-mixed", 3.0, 2))
     checks = obs["checks"]
     assert obs["failed"] == 0 and obs["attempted"] >= 4, checks
     assert checks["checked_requests"] == 4 and checks["over_margin"] == 0
@@ -363,7 +342,7 @@ def test_driver_runs_the_toy_cell(tmp_path):
         for s in spans)
 
 
-def test_driver_holds_the_decode_program_to_the_reference(tmp_path,
+def test_driver_holds_the_decode_program_to_the_reference(toy_context,
                                                           monkeypatch):
     """``correct`` reads the logits of the compiled decode the window
     ran, not those of a program of the check's own: a decode (and only the
@@ -379,7 +358,7 @@ def test_driver_holds_the_decode_program_to_the_reference(tmp_path,
         return donating(decode)
 
     monkeypatch.setattr(generation, "_jit_donating_kv", shifted)
-    obs = DRIVER.run(_toy_context(tmp_path))
+    obs = DRIVER.run(toy_context("toy-trinity.mixed", "toy-mixed", 3.0, 0))
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert checks["worst_logit_deficit"] <= TOY["logit_margin"], checks

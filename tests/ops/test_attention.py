@@ -10,6 +10,7 @@ from alpa_tpu.model.gpt_model import (GPTConfig, SelfAttention,
                                       reference_attention, update_kv_cache)
 from alpa_tpu.ops import flash_attention as fa
 from alpa_tpu.ops.ring_attention import make_ring_attention_fn, ring_attention
+from alpa_tpu.testing import init_params
 
 
 
@@ -140,8 +141,8 @@ def _traced_core(config, seq, padding_bias=False):
     positions = jnp.broadcast_to(jnp.arange(seq), (2, seq))
 
     def apply(x):
-        params = attn.init(jax.random.PRNGKey(0), x, position_ids=positions,
-                           padding_bias=bias)
+        params = init_params(attn, jax.random.PRNGKey(0), x,
+                             position_ids=positions, padding_bias=bias)
         return attn.apply(params, x, position_ids=positions,
                           padding_bias=bias)[0]
 

@@ -34,6 +34,7 @@ from alpa_tpu.pipeline_parallel.layer_construction import ManualLayerOption
 from alpa_tpu.pipeline_parallel.primitive_def import mark_pipeline_boundary
 from alpa_tpu.pipeline_parallel.stage_construction import UniformStageOption
 from alpa_tpu.telemetry.metrics import get_registry
+from alpa_tpu.testing import init_params
 
 MOVED_ARRAYS = "alpa_pipeshard_launch_moved_arrays_total"
 MOVED_BYTES = "alpa_pipeshard_launch_moved_bytes_total"
@@ -64,7 +65,7 @@ def tied_lm_state_and_batch(batch_size=8, seq_len=8, vocab=64, hidden=16,
     labels = jax.random.randint(jax.random.PRNGKey(1),
                                 (batch_size, seq_len), 0, vocab)
     state = train_state.TrainState.create(
-        apply_fn=model.apply, params=model.init(rng, ids),
+        apply_fn=model.apply, params=init_params(model, rng, ids),
         tx=tx or optax.adam(1e-2))
     return state, {"ids": np.asarray(ids), "labels": np.asarray(labels)}
 

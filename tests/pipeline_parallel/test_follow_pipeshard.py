@@ -20,7 +20,7 @@ from alpa_tpu.model.gpt_model import GPTConfig, GPTModel
 from alpa_tpu.model.model_util import cross_entropy_loss
 from alpa_tpu.pipeline_parallel.layer_construction import ManualLayerOption
 from alpa_tpu.pipeline_parallel.stage_construction import UniformStageOption
-from alpa_tpu.testing import assert_allclose
+from alpa_tpu.testing import assert_allclose, init_params
 
 
 def _tied_gpt_setup():
@@ -35,7 +35,7 @@ def _tied_gpt_setup():
         "labels": jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
                                      64),
     }
-    params = model.init(rng, batch["input_ids"])
+    params = init_params(model, rng, batch["input_ids"])
     tx = optax.sgd(0.01)
     state = train_state.TrainState.create(apply_fn=model.apply,
                                           params=params, tx=tx)

@@ -15,7 +15,7 @@ from alpa_tpu.pipeline_parallel.layer_construction import (AutoLayerOption,
 from alpa_tpu.pipeline_parallel.stage_construction import (ManualStageOption,
                                                            UniformStageOption)
 from alpa_tpu.testing import (assert_allclose, create_mlp_train_state_and_batch,
-                              get_mlp_train_step)
+                              get_mlp_train_step, init_params)
 
 
 def _compare_pipeshard(method, n_steps=2, rtol=2e-3, num_layers=4,
@@ -112,9 +112,9 @@ class TestPipeshard:
         tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adam(1e-3))
 
         def mkstate():
-            return train_state.TrainState.create(apply_fn=model.apply,
-                                                 params=model.init(rng, x),
-                                                 tx=tx)
+            return train_state.TrainState.create(
+                apply_fn=model.apply, params=init_params(model, rng, x),
+                tx=tx)
 
         def step(state, batch):
 
@@ -170,7 +170,7 @@ class TestPipeshardGPT:
         labels = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 64)
 
         def make_state():
-            params = model.init(rng, ids)
+            params = init_params(model, rng, ids)
             return train_state.TrainState.create(
                 apply_fn=model.apply, params=params, tx=optax.adam(1e-3))
 
@@ -291,7 +291,7 @@ class TestFourStageGPT:
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (8, 32), 0, 128)
         labels = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 128)
-        params = model.init(rng, ids)
+        params = init_params(model, rng, ids)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
                                               tx=optax.adam(1e-3))
@@ -349,7 +349,7 @@ class TestBertPipeshard:
         model = BertForPreTraining(cfg)
         rng = jax.random.PRNGKey(0)
         ids = jax.random.randint(rng, (8, 16), 0, 64)
-        params = model.init(rng, ids)
+        params = init_params(model, rng, ids)
         state = train_state.TrainState.create(apply_fn=model.apply,
                                               params=params,
                                               tx=optax.sgd(1e-2))

@@ -19,7 +19,7 @@ from alpa_tpu.parallel_plan import (ParallelPlan, executable_to_plan,
 from alpa_tpu.serialization import (checkpoint_wait, restore_checkpoint,
                                     save_checkpoint)
 from alpa_tpu.testing import (assert_allclose, create_mlp_train_state_and_batch,
-                              get_mlp_train_step)
+                              get_mlp_train_step, init_params)
 
 
 class TestCheckpoint:
@@ -193,7 +193,7 @@ class TestCreateStateAndFollow:
 
         def create_state():
             rng = jax.random.PRNGKey(0)
-            params = model.init(rng, jnp.ones((64, 32)))
+            params = init_params(model, rng, jnp.ones((64, 32)))
             return ts.TrainState.create(apply_fn=model.apply, params=params,
                                         tx=optax.sgd(1e-2, momentum=0.9))
 

@@ -28,6 +28,7 @@ from alpa_tpu.serve.generation import Generator
 from alpa_tpu.shard_parallel import strategy
 from alpa_tpu.telemetry import device_time as dt
 from alpa_tpu.telemetry import trace as ttrace
+from alpa_tpu.testing import init_params
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -645,8 +646,8 @@ def _toy_train_step(method, layers=2, boundary_every=0):
     tx = optax.adam(1e-4)
 
     def create_state():
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.ones((8, 64), jnp.int32))
+        params = init_params(model, jax.random.PRNGKey(0),
+                             jnp.ones((8, 64), jnp.int32))
         return train_state.TrainState.create(apply_fn=model.apply,
                                              params=params, tx=tx)
 

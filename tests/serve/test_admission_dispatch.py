@@ -24,6 +24,7 @@ from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
                                        Generator)
 from alpa_tpu.telemetry import trace as ttrace
+from alpa_tpu.testing import init_params
 
 # the toy configurations of the benchmark's chunked cells (data alone)
 TOYS = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
@@ -192,7 +193,8 @@ def _toy_generator(kind):
     cfg = config_from_hf(toy, dtype=jnp.float32, param_dtype=jnp.float32,
                          seq_len=TOY_CONTEXT, **blocks)
     model = GPTModel(cfg)
-    params = model.init(jax.random.PRNGKey(3), jnp.ones((1, 8), jnp.int32))
+    params = init_params(model, jax.random.PRNGKey(3),
+                         jnp.ones((1, 8), jnp.int32))
     diffusion = BlockDiffusion(toy["serve"]["mask_token_id"]) \
         if blocks else None
     return Generator(model, params, cfg, prefill_chunk=TOY_CHUNK,

@@ -20,6 +20,7 @@ from alpa_tpu.serve.generation import (BlockDiffusion, GenerationConfig,
                                        sample_positions, unmask_quota)
 from alpa_tpu.telemetry import metrics as tmetrics
 from alpa_tpu.telemetry import trace as ttrace
+from alpa_tpu.testing import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -46,8 +47,8 @@ def config(**kwargs):
 def toy():
     cfg = config()
     model = GPTModel(cfg)
-    return cfg, model, model.init(jax.random.PRNGKey(0),
-                                  jnp.ones((1, 8), jnp.int32))
+    return cfg, model, init_params(model, jax.random.PRNGKey(0),
+                                   jnp.ones((1, 8), jnp.int32))
 
 
 def generator(toy, steps=2, remasking="low_confidence_static"):

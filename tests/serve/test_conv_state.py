@@ -22,6 +22,7 @@ from alpa_tpu.serve.engine import ContinuousBatchingEngine
 from alpa_tpu.serve.generation import GenerationConfig, Generator
 from alpa_tpu.serve.kv_cache import KVBlockPool
 from alpa_tpu.telemetry import metrics as tmetrics
+from alpa_tpu.testing import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -46,7 +47,7 @@ def toy():
     model = GPTModel(toy_config())
     ids = jax.random.randint(jax.random.PRNGKey(0), (3, 48), 0,
                              TOY["vocab_size"])
-    params = model.init(jax.random.PRNGKey(2), ids)
+    params = init_params(model, jax.random.PRNGKey(2), ids)
     params = jax.tree_util.tree_map_with_path(
         lambda path, x: 0.05 * jax.random.normal(
             jax.random.PRNGKey(len(str(path))), x.shape)

@@ -11,6 +11,7 @@ import pytest
 from alpa_tpu.model.gpt_model import GPTConfig, GPTModel, init_gpt_real
 from alpa_tpu.serve import (Controller, GenerationConfig, Generator,
                             get_model, run_controller)
+from alpa_tpu.testing import init_params, jitted
 
 
 def _tiny_generator(batch_size=1):
@@ -32,7 +33,7 @@ class TestGeneration:
         # replay without cache
         ids = prompt
         for _ in range(6):
-            logits = gen.model.apply(gen.params, jnp.asarray(ids))
+            logits = jitted(gen.model.apply)(gen.params, jnp.asarray(ids))
             nxt = np.argmax(np.asarray(logits[:, -1]), axis=-1)
             ids = np.concatenate([ids, nxt[:, None].astype(np.int32)],
                                  axis=1)
@@ -683,8 +684,8 @@ class TestPipelinedGeneration:
                           seq_len=32, vocab_size=64,
                           pipeline_boundary_every=1)
         model = BloomModel(cfg)
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.ones((1, 8), jnp.int32))
+        params = init_params(model, jax.random.PRNGKey(0),
+                             jnp.ones((1, 8), jnp.int32))
         plain = Generator(model, params, cfg)
         piped = Generator(
             model, params, cfg,

@@ -11,7 +11,7 @@ import pytest
 import alpa_tpu
 from alpa_tpu import AutoShardingOption, ShardParallel
 from alpa_tpu.testing import (assert_allclose, create_mlp_train_state_and_batch,
-                              get_mlp_train_step)
+                              get_mlp_train_step, init_params)
 from alpa_tpu.util import count_communication_primitives
 
 
@@ -303,7 +303,7 @@ class TestConstraintEmission:
         block = TransformerBlock(cfg)
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (4, 64, 512))
-        params = block.init(rng, x)
+        params = init_params(block, rng, x)
         flat, tree = jax.tree_util.tree_flatten((params, x))
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in flat]
 
@@ -356,7 +356,7 @@ class TestConstraintEmission:
         model = Tower()
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (2, 16, 16, 256))
-        params = model.init(rng, x)
+        params = init_params(model, rng, x)
         flat, tree = jax.tree_util.tree_flatten((params, x))
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in flat]
 
@@ -413,7 +413,7 @@ class TestConstraintEmission:
         model = SpatialNet()
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (1, 64, 64, 3))
-        params = model.init(rng, x)
+        params = init_params(model, rng, x)
         flat, tree = jax.tree_util.tree_flatten((params, x))
         avals = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in flat]
 
@@ -477,9 +477,9 @@ class TestConstraintEmission:
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (16, 32, 32, 3))
         y = jax.random.randint(jax.random.PRNGKey(1), (16,), 0, 10)
-        state = train_state.TrainState.create(apply_fn=model.apply,
-                                              params=model.init(rng, x),
-                                              tx=optax.sgd(1e-2))
+        state = train_state.TrainState.create(
+            apply_fn=model.apply, params=init_params(model, rng, x),
+            tx=optax.sgd(1e-2))
 
         def step_fn(state, batch):
 
@@ -495,9 +495,9 @@ class TestConstraintEmission:
         serial = jax.jit(step_fn)
         _, lp = pstep(state, {"x": x, "y": y})
 
-        state2 = train_state.TrainState.create(apply_fn=model.apply,
-                                               params=model.init(rng, x),
-                                               tx=optax.sgd(1e-2))
+        state2 = train_state.TrainState.create(
+            apply_fn=model.apply, params=init_params(model, rng, x),
+            tx=optax.sgd(1e-2))
         _, ls = serial(state2, {"x": x, "y": y})
         assert_allclose(float(lp), float(ls), 1e-3, 1e-3)
         ex = pstep.get_last_executable()

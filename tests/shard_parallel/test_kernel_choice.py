@@ -14,6 +14,7 @@ from alpa_tpu.shard_parallel.auto_sharding import AutoShardingOption
 from alpa_tpu.shard_parallel.ilp import solution_cost
 from alpa_tpu.shard_parallel.solver import plan_auto_sharding
 from alpa_tpu.telemetry import metrics as tmetrics
+from alpa_tpu.testing import init_params
 
 
 BATCH, SEQ, HEADS, DIM = 4, 512, 4, 64
@@ -194,7 +195,7 @@ def test_pipeshard_stages_on_two_devices_run_the_reference_core():
     model = GPTModel(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(1), (BATCH, SEQ), 0, 128)
     batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
-    params = model.init(jax.random.PRNGKey(0), ids)
+    params = init_params(model, jax.random.PRNGKey(0), ids)
     state = train_state.TrainState.create(apply_fn=model.apply,
                                           params=params, tx=optax.sgd(0.1))
     gauge = tmetrics.get_registry().gauge(

@@ -246,3 +246,26 @@ def test_imports_of_repo_modules_resolve():
                     missing.update((rel, f"{mod}.{n}") for n in names
                                    if not has_name(mod, n))
     assert not missing, sorted(missing)
+
+
+def test_model_tests_go_through_the_harness():
+    """A configuration's test file under ``tests/model/`` holds its
+    configuration, its reference and its assertions; what every such file
+    needs is in one place (``highest``, ``init_params``, ``jitted`` and
+    ``shake`` in ``alpa_tpu/testing.py``, ``toy_context`` and
+    ``catalog_row`` in ``tests/model/conftest.py``).  None defines one of
+    its own, writes a ``run.Context(`` of its own, or calls a module's
+    ``.init(`` (parameters are built under ``jit`` by ``init_params``;
+    ``jax.eval_shape(model.init, ...)`` names the method and calls
+    nothing)."""
+    own = re.compile(
+        r"^def (highest|init_params|jitted|shake|_?toy_context|catalog_row)\b"
+        r"|run\.Context\(|\.init\(", re.M)
+    found = []
+    for path in sorted(glob.glob(os.path.join(ROOT, "tests", "model",
+                                              "test_*.py"))):
+        rel = os.path.relpath(path, ROOT)
+        text = _read(rel)
+        found += [(rel, text.count("\n", 0, m.start()) + 1, m.group())
+                  for m in own.finditer(text)]
+    assert not found, found

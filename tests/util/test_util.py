@@ -85,5 +85,28 @@ class TestListHelpers:
         assert divide_evenly(10, 3) == [4, 3, 3]
 
 
+def test_init_params_builds_the_eager_parameters_bit_for_bit():
+    """Why ``alpa_tpu.testing.init_params`` may stand wherever a test said
+    ``model.init``: the OLMoE toy of ``tests/model/test_olmoe.py`` (norms,
+    attention with q/k norms, a router and its experts, an untied head)
+    built both ways is the same tree, leaf by leaf, dtypes too."""
+    from alpa_tpu.model.gpt_model import GPTModel, config_from_hf
+    from alpa_tpu.testing import init_params
+    from chipbench import run
+    toy = run.load_json(run.HERE, "configs", "toy-olmoe.json")
+    model = GPTModel(config_from_hf(toy, dtype=jnp.float32))
+    key = jax.random.PRNGKey(2)
+    ids = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0,
+                             toy["vocab_size"])
+    eager = jax.tree_util.tree_leaves_with_path(model.init(key, ids))
+    compiled = jax.tree_util.tree_leaves_with_path(
+        init_params(model, key, ids))
+    assert [path for path, _ in eager] == [path for path, _ in compiled]
+    assert len(eager) == 23
+    for (path, a), (_, b) in zip(eager, compiled):
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
 if __name__ == "__main__":
     pytest.main([__file__, "-x", "-q"])
