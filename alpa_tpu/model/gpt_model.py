@@ -1263,20 +1263,25 @@ def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0,
     ``reference_attention``'s.  Caches with the heads folded into the
     channels, (B, S, Hkv D) and (B, S, Hkv Dv): ``folded_cached_attention``.
 
-    One of two cores, by what the call's shapes say and nothing a caller
-    or a configuration sets.  Per-row offsets (the engine's decode tick
-    and block step) and shapes the kernel of ``ops/cached_attention.py``
-    takes (a few new queries a row, the cache in whole key blocks: its
-    ``fits``): a program lowered for a TPU runs
-    that kernel, which reads of every row's cache the key blocks the
-    row's queries can see, through the view the rows were written in
-    (``_write_rows``: heads of whole lanes as named, narrower ones with
-    the positions in the lanes), and any other platform
-    ``reference_attention``.  Every other call (a scalar offset: a prefill
-    chunk, ``generate``, a verify step) is ``reference_attention`` over
-    every position the cache can hold, the
-    causal offset alone hiding what a row has not reached.  The gauge
-    ``alpa_cached_attention_core`` says at trace time which one a
+    One of three cores, by what the call's shapes say and nothing a caller
+    or a configuration sets; the first two are Pallas kernels of
+    ``ops/cached_attention.py`` where the program is lowered for a TPU and
+    ``reference_attention`` on any other platform.  A FEW new queries a
+    row at per-row offsets (the engine's decode tick and block step) in
+    shapes its ``fits`` takes: the kernel over key blocks, which reads of
+    every row's cache the key blocks the row's queries can see, through
+    the view the rows were written in (``_write_rows``: heads of whole
+    lanes as named, narrower ones with the positions in the lanes).  MANY
+    new queries a row at a scalar or per-row offset (a prefill chunk, from
+    a cached prefix too) in shapes its ``chunk_fits`` takes (heads of
+    whole lanes, whole query and key blocks): the kernel over query blocks
+    and key blocks, in which a block's scores stay in fast memory and a
+    query block reads the key blocks up to its last query's reach.  Every
+    other call (a sink; heads narrower than a lane at more than a few
+    queries; ``generate`` and a verify step, a few queries at a scalar
+    offset) is ``reference_attention`` over every position the cache can
+    hold, the causal offset alone hiding what a row has not reached.  The
+    gauge ``alpa_cached_attention_core`` says at trace time which one a
     program's layers took."""
     from alpa_tpu.ops import cached_attention as kernel
     if k_cache.ndim == 3:
@@ -1286,15 +1291,20 @@ def cached_attention(q, k_cache, v_cache, offset, *, block: int = 0,
                              "other")
         return folded_cached_attention(q, k_cache, v_cache, offset, sink)
     offset = jnp.asarray(offset, jnp.int32)
-    key_blocks = offset.ndim == 1 and sink is None and \
-        kernel.fits(q, k_cache)
-    _cached_core_gauge().labels(
-        "key_blocks" if key_blocks else "reference", k_cache.shape[2],
-        k_cache.shape[3], q.shape[1]).inc()
-    if not key_blocks:
-        return reference_attention(q, k_cache, v_cache, causal=True,
-                                   offset=offset, block=block, sink=sink)
-    return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
+    core = "reference"
+    if sink is None and offset.ndim == 1 and kernel.fits(q, k_cache):
+        core = "key_blocks"
+    elif sink is None and kernel.chunk_fits(q, k_cache, v_cache):
+        core = "query_key_blocks"
+    _cached_core_gauge().labels(core, k_cache.shape[2], k_cache.shape[3],
+                                q.shape[1]).inc()
+    if core == "key_blocks":
+        return _attention_over_key_blocks(q, k_cache, v_cache, offset, block)
+    if core == "query_key_blocks":
+        return _attention_over_query_blocks(q, k_cache, v_cache, offset,
+                                            block)
+    return reference_attention(q, k_cache, v_cache, causal=True,
+                               offset=offset, block=block, sink=sink)
 
 
 def _cached_core_gauge():
@@ -1303,6 +1313,10 @@ def _cached_core_gauge():
         "full-attention layers whose attention over the written cache was "
         "traced with each core (key_blocks: where lowered for a TPU, the "
         "kernel that reads each row's cache as far as the row has written; "
+        "query_key_blocks: where lowered for a TPU, the kernel over query "
+        "blocks and key blocks of a prefill chunk, whose scores stay in "
+        "fast memory and whose query blocks read the cache as far as "
+        "their last query sees; "
         "key_block_walk: a loop in jax.numpy over the key blocks up to the "
         "last query's, of a cache with the heads folded into the channels; "
         "reference: every position the cache can hold), by the cache's "
@@ -1325,6 +1339,26 @@ def _attention_over_key_blocks(q, k_cache, v_cache, offset, block):
             q, k, v, causal=True, offset=offset, block=block))
 
 
+@partial(jax.jit, static_argnames="block")
+def _attention_over_query_blocks(q, k_cache, v_cache, offset, block=0):
+    """A chunk's core over query blocks and key blocks, for either layout
+    of the caches: the kernel where the program is lowered for a TPU; on
+    any other platform the core such a call took before there was one,
+    ``reference_attention`` over per-head caches and the walk over key
+    blocks over folded ones.  A ``jit`` of its own as
+    ``_attention_over_key_blocks`` is."""
+    from alpa_tpu.ops import cached_attention as kernel
+    if k_cache.ndim == 3:
+        twin = _attention_over_folded_blocks
+    else:
+        def twin(q, k, v, offset):
+            return reference_attention(q, k, v, causal=True, offset=offset,
+                                       block=block)
+    return jax.lax.platform_dependent(
+        q, k_cache, v_cache, offset,
+        tpu=partial(kernel.chunk_attention, block=block), default=twin)
+
+
 def folded_cached_attention(q, k_cache, v_cache, offset, sink=None):
     """``cached_attention`` over caches with the heads folded into the
     channels: ``q`` (B, s, H, D) over ``k_cache`` (B, S, Hkv D) and
@@ -1334,26 +1368,36 @@ def folded_cached_attention(q, k_cache, v_cache, offset, sink=None):
 
     No core here scores against every position the cache can hold (at the
     context such a layer serves, a chunk's scores over all of it would be
-    gigabytes).  Per-row offsets and shapes the kernel of
-    ``ops/cached_attention.py`` takes (``folded_fits``): a program lowered
-    for a TPU runs that kernel, which reads of every row's cache the key
-    blocks the row's queries can see, as the cache lies, and any other
-    platform ``_attention_over_folded_blocks``, its ``jax.numpy`` twin.
-    Every other call (a scalar offset: a prefill chunk, ``generate``; a
-    sink) is the twin, a loop over key blocks that ends at the last
-    query's.  The gauge ``alpa_cached_attention_core`` says which."""
+    gigabytes).  A few new queries a row at per-row offsets in shapes
+    ``ops/cached_attention.py`` ``folded_fits`` takes (a decode tick): a
+    program lowered for a TPU runs the kernel over key blocks, which reads
+    of every row's cache the key blocks the row's queries can see, as the
+    cache lies.  Many new queries a row in shapes its ``chunk_fits`` takes
+    (a prefill chunk, at a scalar or per-row offset): a program lowered
+    for a TPU runs the kernel over query blocks and key blocks, which
+    fetches of a key block the whole lane tiles its head's keys lie in and
+    keeps the scores in fast memory.  On any
+    other platform both are ``_attention_over_folded_blocks``, their
+    ``jax.numpy`` twin, a loop over key blocks that ends at the last
+    query's; so is every other call (a sink; ``generate``, a few queries
+    at a scalar offset).  The gauge ``alpa_cached_attention_core`` says
+    which."""
     from alpa_tpu.ops import cached_attention as kernel
     offset = jnp.asarray(offset, jnp.int32)
     dim = q.shape[-1]
-    key_blocks = offset.ndim == 1 and sink is None and \
-        kernel.folded_fits(q, k_cache, v_cache)
-    _cached_core_gauge().labels(
-        "key_blocks" if key_blocks else "key_block_walk",
-        k_cache.shape[2] // dim, dim, q.shape[1]).inc()
-    if not key_blocks:
-        return _attention_over_folded_blocks(q, k_cache, v_cache, offset,
-                                             sink)
-    return _folded_key_blocks(q, k_cache, v_cache, offset)
+    core = "key_block_walk"
+    if sink is None and offset.ndim == 1 and \
+            kernel.folded_fits(q, k_cache, v_cache):
+        core = "key_blocks"
+    elif sink is None and kernel.chunk_fits(q, k_cache, v_cache):
+        core = "query_key_blocks"
+    _cached_core_gauge().labels(core, k_cache.shape[2] // dim, dim,
+                                q.shape[1]).inc()
+    if core == "key_blocks":
+        return _folded_key_blocks(q, k_cache, v_cache, offset)
+    if core == "query_key_blocks":
+        return _attention_over_query_blocks(q, k_cache, v_cache, offset)
+    return _attention_over_folded_blocks(q, k_cache, v_cache, offset, sink)
 
 
 @jax.jit

@@ -1,6 +1,8 @@
-"""Attention of a few new queries a row over the row's written cache of
-per-head keys and values (grouped-query or multi-head): one Pallas kernel
-over key blocks that reads of each row's cache what the row holds.
+"""Attention of new queries over the row's written cache of per-head keys
+and values (grouped-query or multi-head), in Pallas kernels that read of
+each row's cache what the row holds and keep the scores in fast memory: one
+over key blocks for a FEW new queries a row, one over query blocks and key
+blocks for MANY (a prefill's chunk; at the end of this text).
 
 What a decode tick (one new position a row) and a block step of
 generation by diffusion (``block_length`` new positions a row) need of
@@ -54,10 +56,25 @@ head keeps its own.  The matrix unit loads each key and value element
 once, as it does when the heads' keys are rows, and the softmax works on
 ``s H x positions`` scores, not on ``Hkv`` times as many.
 
-The kernel is compiled where the program is lowered for a TPU
-(``gpt_model.cached_attention`` chooses between it and
-``reference_attention`` with ``lax.platform_dependent``);
-``interpret=True`` runs it anywhere, for the tests.
+Many new queries a row (``chunk_attention``: a prefill's chunk of 1,024
+positions, from a cached prefix too).  The ``Hkv`` times of either way
+above, products a tick hides under its fetch, a chunk does not hide: its
+products ARE its time.  Its kernel's grid is ``(rows, key/value heads,
+query blocks, key blocks)``, a program one key/value head's queries of one
+query block against that head's own keys (of folded caches the whole
+lane tiles they lie in, of per-head caches every ``Hkv``-th row of the
+block); a query block reads the key blocks up to its last query's reach
+and no further.  In its place ``gpt_model`` ran ``jax.numpy``: over
+folded caches a loop over key blocks whose (H, s, block) float32 scores
+went through the chip's memory three times a block (1.2 ms a block of
+1,024 keys at MiMo-V2-Flash's 64 heads, 18 % of the matrix unit's peak),
+over per-head caches ``reference_attention`` against every position the
+cache can hold (PERF.md, PR 56).
+
+The kernels are compiled where the program is lowered for a TPU
+(``gpt_model.cached_attention`` chooses between each and its ``jax.numpy``
+twin with ``lax.platform_dependent``); ``interpret=True`` runs them
+anywhere, for the tests.
 """
 import functools
 
@@ -78,8 +95,9 @@ LANES = 128
 # of keys and 1 MB of values in bfloat16, so that a step's fixed cost
 # stays a small part of its fetch
 BLOCK_ELEMENTS = 4096 * 128
-# new positions a row: a decode tick's one, a diffusion block's few; a
-# prefill's chunk wants a kernel over query blocks too
+# new positions a row of the kernels over key blocks: a decode tick's one,
+# a diffusion block's few.  More (a prefill's chunk) go to the kernel over
+# query blocks too, ``chunk_attention``
 MAX_QUERIES = 16
 # key/value heads of a cache that lies as named: every query is scored
 # against the keys of all of them
@@ -336,3 +354,234 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
     )(blocks, offset, q, k_cache, v_cache)
     return jnp.einsum("bshgd,hg->bshd", out.reshape(b, s, nh, nkv, dv),
                       group)
+
+
+# ---- many new queries a row (a prefill's chunk) ----
+
+# rows of a step's two products: the new positions of a query block times
+# the query heads that share a key/value head.  4,096 are a tenth faster at
+# Trinity's shape and no faster at MiMo's, and take the compiler 16 s where
+# these take 8 (PERF.md, PR 56)
+QUERY_ROWS = 2048
+# positions of a key block of the chunk's kernel.  What a step does a ROW
+# (the running maximum and sum, the rescaling of the weighted values) it
+# does in vectors of one useful lane, as dear a row as 128 scores: blocks
+# of 1,024 keys halve that against 512 (16.0 -> 9.5 ms at MiMo's shape, a
+# chunk at 27,648; 2,048 gain no more and spend the fast memory).  With
+# ``QUERY_ROWS`` rows a step's float32 scores are 8 MB
+CHUNK_BLOCK_K = 1024
+
+
+def _chunk_shapes(q, k_cache, v_cache):
+    """(key/value heads, a head's value channels, positions a query block)
+    of a chunk-shaped call, or None where the caches' channels do not
+    divide into the queries' heads."""
+    _, s, nh, dim = q.shape
+    if k_cache.ndim == 3:
+        nkv, left = divmod(k_cache.shape[2], dim)
+        if left or not nkv or v_cache.shape[2] % nkv:
+            return None
+        dv = v_cache.shape[2] // nkv
+    else:
+        nkv, dv = k_cache.shape[2], v_cache.shape[3]
+    if nh % nkv or QUERY_ROWS % (nh // nkv):
+        return None
+    return nkv, dv, min(s, QUERY_ROWS // (nh // nkv))
+
+
+def _lane_tiles(dim: int, kv_heads: int) -> int:
+    """Whole lane tiles that hold any one head's ``dim`` channels of keys
+    folded into the channels: head ``g``'s start ``dim g`` channels in,
+    so within a tile at ``dim g % LANES``."""
+    return max(-(-(dim * g % LANES + dim) // LANES) for g in range(kv_heads))
+
+
+def chunk_fits(q, k_cache, v_cache) -> bool:
+    """Whether ``chunk_attention`` takes these shapes: more new queries a
+    row than the kernels over key blocks take, in whole query blocks, the
+    cache in whole key blocks, and the heads in whole lanes as the cache
+    lies: folded caches (B, S, Hkv D) whose values are whole lanes a head
+    and whose every head's keys lie within the same number of whole lane
+    tiles of the cache, or per-head caches (B, S, Hkv, D) of whole lanes
+    (in 16 bits with an even number of key/value heads: a pair of heads
+    shares a 32-bit sublane).  Narrower per-head caches lie with their
+    positions in the lanes, in key blocks of many thousand positions: they
+    keep ``reference_attention``."""
+    _, s, nh, dim = q.shape
+    shapes = _chunk_shapes(q, k_cache, v_cache)
+    if shapes is None or s <= MAX_QUERIES:
+        return False
+    nkv, dv, block_q = shapes
+    if k_cache.ndim == 3:
+        width = k_cache.shape[2]
+        lanes = (width % LANES == 0 and dv % LANES == 0 and
+                 (dim * (nkv - 1) // LANES + _lane_tiles(dim, nkv)) * LANES
+                 <= width)
+    else:
+        lanes = (dim % LANES == 0 and dv == dim and
+                 nkv % (4 // k_cache.dtype.itemsize) == 0)
+    return (lanes and s % block_q == 0 and (block_q * nh // nkv) % 16 == 0
+            and k_cache.shape[1] % CHUNK_BLOCK_K == 0)
+
+
+def _chunk_kernel(offset_ref, q_ref, *refs, scale: float, group: int,
+                  kv_heads: int, block: int, folded: bool, needed):
+    k_refs = refs[:-5]
+    v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-5:]
+    b, g, qb, kb = (pl.program_id(i) for i in range(4))
+    rows = q_ref.shape[0]
+    block_q = rows // group
+    block_k = v_ref.shape[0] if folded else v_ref.shape[1] // kv_heads
+    first = offset_ref[b] + qb * block_q
+    pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+
+    def of_head(ref, head):
+        """Key/value head ``head``'s (positions, channels) of a block of
+        per-head caches, whose rows are (position, head): every
+        ``kv_heads``-th row.  Two rows of 16 bits share a 32-bit sublane:
+        there the pair's rows are every ``kv_heads / 2``-th of the block
+        seen as 32 bits, and a head is a half of each."""
+        if ref.dtype.itemsize == 4:
+            return ref[0, pl.ds(head, block_k, stride=kv_heads), :]
+        pairs = ref.bitcast(jnp.uint32)
+        pair = pairs[0, pl.ds(head // 2, block_k, stride=kv_heads // 2), :]
+        half = pair << 16 if head % 2 == 0 else pair & jnp.uint32(0xffff0000)
+        return pltpu.bitcast(half, jnp.float32).astype(ref.dtype)
+
+    def fold_in(masked):
+        if folded:
+            # the whole lane tiles its head's keys lie in, side by side
+            keys = jnp.concatenate([ref[:] for ref in k_refs], axis=1)
+            values = v_ref[:]
+        else:
+            # the head is static where its rows are picked out of a block
+            keys, values = lax.switch(
+                g, [lambda i=i: (of_head(k_refs[0], i), of_head(v_ref, i))
+                    for i in range(kv_heads)])
+        s = scale * lax.dot_general(q_ref[:], keys, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        seen = None
+        if masked:
+            # a query row is (new position, head of the group)
+            row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            k_pos = kb * block_k + lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1)
+            seen = k_pos <= _last_seen(first + row // group, block)
+        _fold_in(s, seen, values, m_ref, l_ref, acc_ref)
+
+    # the key blocks wholly before the query block's first query need no
+    # mask; those past its last query's reach compute nothing
+    whole = (kb + 1) * block_k - 1 <= _last_seen(first, block)
+    wanted = kb < needed(offset_ref[b], qb)
+    pl.when(wanted & whole)(lambda: fold_in(False))
+    pl.when(wanted & ~whole)(lambda: fold_in(True))
+    pl.when(kb == pl.num_programs(3) - 1)(
+        lambda: _finish(o_ref, l_ref, acc_ref))
+
+
+def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
+                    interpret: bool = False):
+    """MANY new queries a row (a prefill's chunk): ``q`` (B, s, H, D)
+    against the written caches, per head (B, S, Hkv, D) or with the heads
+    folded into the channels, (B, S, Hkv D) and (B, S, Hkv Dv); row ``b``'s
+    query i sits at ``offset[b] + i`` (a scalar, or (B,) int32) and sees
+    what ``reference_attention``'s causal mask shows it (``block`` > 0: up
+    to the end of its block).  Returns (B, s, H, Dv) in the queries' dtype.
+
+    Grid ``(rows, key/value heads, query blocks, key blocks)``, the key
+    blocks innermost and sequential with the online softmax's state in
+    float32 scratch: a block's scores never leave fast memory.  A program
+    holds one key/value head's queries of one query block, ``block_q``
+    positions x the group's heads, as the ROWS of both products, so the
+    matrix unit loads a key or value element once a group.  How far a
+    query block reads is data: the key blocks up to its last query's
+    reach (``offset`` is a prefetched scalar); later steps compute nothing
+    and, their block index clamped, fetch nothing; earlier ones wholly
+    before its first query skip the mask.
+
+    The caches are read as they lie.  Folded: a head's ``D`` channels
+    start ``D g`` channels in, for MiMo's 192 at lane 0 or 64 of a tile,
+    and a ``BlockSpec`` cuts whole tiles.  A step fetches the whole lane
+    tiles its head's keys lie in (two of the six), and the head's queries
+    are zero-padded into the same tiles, so the channels of a neighbour
+    that come along meet zeros: the scores' product contracts 256
+    channels for 192, (256 + 128) / (192 + 128) = 1.2 times the heads' own
+    products, with no slice or shift inside the kernel.  (Padding a query
+    into a PAIR of heads' 384 channels costs 1.6 times, into all heads'
+    channels, as the decode's kernel does under its fetch, ``Hkv``
+    times.)  The values come a head a block.  Per head: a step fetches the
+    block's (positions x Hkv, D) rows and takes its head's, every
+    ``Hkv``-th, by a strided read of 32-bit pairs."""
+    b, s, nh, dim = q.shape
+    seq_len = k_cache.shape[1]
+    nkv, dv, block_q = _chunk_shapes(q, k_cache, v_cache)
+    group, block_k = nh // nkv, CHUNK_BLOCK_K
+    rows = block_q * group
+    offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (b,))
+
+    def needed(first, qb):
+        """Key blocks query block ``qb`` of a row that starts at ``first``
+        reads: up to its last query's reach."""
+        return blocks_read(
+            _last_seen(first + (qb + 1) * block_q - 1, block), block_k,
+            seq_len)
+
+    def queries(b_, g, qb, kb, offset_ref):
+        return b_, g, qb, 0
+
+    def key_block(channels=lambda g: 0):
+        """The key block a step fetches, and of its channels the block
+        ``channels(g)``: its own while the query block needs it, the last
+        needed one after (the same again: no fetch)."""
+        def index(b_, g, qb, kb, offset_ref):
+            at = jnp.minimum(kb, needed(offset_ref[b_], qb) - 1)
+            return b_, at, channels(g)
+        return index
+
+    # a key/value head's queries, a row (new position, head of the group)
+    q = q.reshape(b, s, nkv, group, dim).transpose(0, 2, 1, 3, 4).reshape(
+        b, nkv, s * group, dim)
+    folded = k_cache.ndim == 3
+    if folded:
+        tiles = _lane_tiles(dim, nkv)
+        # each head's queries in the whole lane tiles its keys lie in
+        padded = jnp.zeros(q.shape[:3] + (tiles * LANES,), q.dtype)
+        for g in range(nkv):
+            at = dim * g % LANES
+            padded = padded.at[:, g, :, at:at + dim].set(q[:, g])
+        q = padded
+        keys = [pl.BlockSpec((None, block_k, LANES),
+                             key_block(lambda g, t=t: dim * g // LANES + t))
+                for t in range(tiles)]
+        values = pl.BlockSpec((None, block_k, dv), key_block(lambda g: g))
+    else:
+        # the cache as it lies: (B, S Hkv, D); a block keeps its rank for
+        # the view of 32 bits
+        k_cache, v_cache = (x.reshape(b, seq_len * nkv, dim)
+                            for x in (k_cache, v_cache))
+        keys = [pl.BlockSpec((1, block_k * nkv, dim), key_block())]
+        values = keys[0]
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, scale=float(1 / np.sqrt(dim)),
+                          group=group, kv_heads=nkv, block=block,
+                          folded=folded, needed=needed),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, s * group, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, nkv, s // block_q, seq_len // block_k),
+            in_specs=[pl.BlockSpec((None, None, rows, q.shape[-1]), queries),
+                      *keys, values],
+            out_specs=pl.BlockSpec((None, None, rows, dv), queries),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, dv), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        # what a device trace calls the kernel's events
+        name="cached_attention_query_key_blocks",
+    )(offset, q, *[k_cache] * len(keys), v_cache)
+    return out.reshape(b, nkv, s, group, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, s, nh, dv)

@@ -106,13 +106,18 @@ def _start(m_ref, l_ref, acc_ref):
 
 def _fold_in(s, seen, values, m_ref, l_ref, acc_ref, keys_last=False):
     """One block of the online softmax: scores ``s`` (queries, keys)
-    float32 of which ``seen`` count, and the keys' ``values`` (keys, d),
-    or (d, keys) with ``keys_last``, into the running maximum, sum and
-    weighted values."""
+    float32 of which ``seen`` count (None: all), and the keys' ``values``
+    (keys, d), or (d, keys) with ``keys_last``, into the running maximum,
+    sum and weighted values."""
     m_prev = m_ref[:]
-    m_new = jnp.maximum(
-        m_prev, jnp.max(jnp.where(seen, s, _FLOOR), axis=1, keepdims=True))
-    p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+    if seen is None:
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+    else:
+        m_new = jnp.maximum(
+            m_prev,
+            jnp.max(jnp.where(seen, s, _FLOOR), axis=1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
     keep = jnp.exp(m_prev - m_new)
     m_ref[:] = m_new
     l_ref[:] = l_ref[:] * keep + jnp.sum(p, axis=1, keepdims=True)

@@ -420,3 +420,120 @@ def test_cached_attention_fits(what, q_shape, cache_shape, takes):
     from alpa_tpu.ops import cached_attention as ca
     assert ca.fits(jax.ShapeDtypeStruct(q_shape, jnp.bfloat16),
                    jax.ShapeDtypeStruct(cache_shape, jnp.bfloat16)) is takes
+
+
+# ---- many new queries a row over the row's written cache (ISSUE 56) ----
+
+CHUNK_SEQ, CHUNK_S, CHUNK_BLOCK_K = 256, 64, 64
+# finite, and far above anything written: a sum that took it in would show
+JUNK = 1e4
+# (id, query heads, key/value heads, Dk, Dv, heads folded into the
+# channels, block of the block-causal mask, dtype): MiMo's full layers (16
+# heads a group, keys of one and a half lane tiles), Trinity's (8 heads a
+# group), SDAR's mask, and Trinity's again in the cache's own 16 bits, where
+# two key/value heads share a 32-bit sublane
+CHUNK_LAYOUTS = [
+    ("folded-4x192-4x128", 64, 4, 192, 128, True, 0, jnp.float32),
+    ("per-head-4x128", 32, 4, 128, 128, False, 0, jnp.float32),
+    ("block-causal-4", 32, 4, 128, 128, False, 4, jnp.float32),
+    ("per-head-4x128-bfloat16", 32, 4, 128, 128, False, 0, jnp.bfloat16),
+]
+# a row's first new position: a scalar (the chunk step's) or one a row
+CHUNK_OFFSETS = [
+    ("from-the-start", 0),
+    ("from-inside-a-key-block", CHUNK_BLOCK_K + 36),
+    ("to-the-caches-end", CHUNK_SEQ - CHUNK_S),
+    ("per-row", [0, CHUNK_BLOCK_K + 36, CHUNK_SEQ - CHUNK_S]),
+]
+
+
+@pytest.mark.parametrize("offset", [c[1] for c in CHUNK_OFFSETS],
+                         ids=[c[0] for c in CHUNK_OFFSETS])
+@pytest.mark.parametrize("heads,kv_heads,dk,dv,folded,block,dtype",
+                         [c[1:] for c in CHUNK_LAYOUTS],
+                         ids=[c[0] for c in CHUNK_LAYOUTS])
+def test_chunk_attention_reads_what_its_queries_see(monkeypatch, heads,
+                                                    kv_heads, dk, dv, folded,
+                                                    block, dtype, offset):
+    """``ops/cached_attention.py`` ``chunk_attention`` (interpreted, four
+    query blocks over four key blocks) against ``reference_attention`` in
+    both layouts it takes, at a scalar and at per-row offsets.  What lies
+    past every query's reach is large and finite, and reaches no output:
+    bit for bit the output over zeros there."""
+    from alpa_tpu.ops import cached_attention as ca
+    group = heads // kv_heads
+    monkeypatch.setattr(ca, "QUERY_ROWS", CHUNK_S // 4 * group)
+    monkeypatch.setattr(ca, "CHUNK_BLOCK_K", CHUNK_BLOCK_K)
+    offset = jnp.asarray(offset, jnp.int32)
+    rows = offset.size
+    rng = np.random.default_rng(heads + dk + block + rows)
+    # the last position any of a row's queries sees
+    last = np.asarray(offset).reshape(-1) + CHUNK_S - 1
+    if block:
+        last = (last // block + 1) * block - 1
+    within = (np.arange(CHUNK_SEQ)[None, :] <= last[:, None])[:, :, None,
+                                                              None]
+    q = jnp.asarray(rng.normal(size=(rows, CHUNK_S, heads, dk)), dtype)
+    written = [rng.normal(size=(rows, CHUNK_SEQ, kv_heads, d))
+               for d in (dk, dv)]
+    signs = [rng.choice([-JUNK, JUNK], size=x.shape) for x in written]
+
+    def caches(beyond):
+        return [jnp.asarray(np.where(within, x, junk if beyond else 0.0),
+                            dtype) for x, junk in zip(written, signs)]
+
+    def kernel(k, v):
+        if folded:
+            k, v = (x.reshape(rows, CHUNK_SEQ, -1) for x in (k, v))
+        assert ca.chunk_fits(q, k, v)
+        return np.asarray(ca.chunk_attention(q, k, v, offset, block=block,
+                                             interpret=True), np.float32)
+
+    dirty, clean = kernel(*caches(True)), kernel(*caches(False))
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+    want = np.asarray(reference_attention(
+        q, *caches(False), causal=True, offset=offset, block=block),
+        np.float32)
+    # (in 16 bits the reference rounds its scores before the softmax)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(clean, want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("what,q_shape,k_shape,v_shape,takes", [
+    ("mimo-chunk", (1, 1024, 64, 192), (1, 32768, 768), (1, 32768, 512),
+     True),
+    ("trinity-chunk", (1, 1024, 32, 128), (1, 16384, 4, 128),
+     (1, 16384, 4, 128), True),
+    ("sdar-chunk", (1, 1024, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128),
+     True),
+    ("a-short-chunk-of-one-query-block", (2, 128, 32, 128),
+     (2, 2048, 4, 128), (2, 2048, 4, 128), True),
+    ("lfm2-chunk-heads-of-64", (1, 1024, 32, 64), (1, 8192, 8, 64),
+     (1, 8192, 8, 64), False),
+    ("opt-prefill-heads-of-64", (1, 1024, 32, 64), (1, 2048, 32, 64),
+     (1, 2048, 32, 64), False),
+    ("sixteen-queries", (32, 16, 32, 128), (32, 8192, 4, 128),
+     (32, 8192, 4, 128), False),
+    ("a-chunk-in-no-whole-query-blocks", (1, 1000, 32, 128),
+     (1, 8192, 4, 128), (1, 8192, 4, 128), False),
+    ("a-cache-in-no-whole-key-blocks", (1, 1024, 32, 128),
+     (1, 8000, 4, 128), (1, 8000, 4, 128), False),
+    ("an-odd-number-of-heads-in-16-bits", (1, 1024, 24, 128),
+     (1, 8192, 3, 128), (1, 8192, 3, 128), False),
+    ("folded-values-of-half-a-lane-tile", (1, 1024, 64, 192),
+     (1, 32768, 768), (1, 32768, 256), False)])
+def test_chunk_attention_fits(what, q_shape, k_shape, v_shape, takes):
+    """Which shapes the kernel over query blocks and key blocks takes:
+    more than ``MAX_QUERIES`` new queries a row in whole query blocks,
+    heads of whole lanes as the cache lies; heads of 64, a tick's or a
+    block step's few queries and ragged chunks or caches it leaves to the
+    cores they had (a sink is the caller's to leave:
+    ``tests/serve/test_cached_attention_core.py``)."""
+    from alpa_tpu.ops import cached_attention as ca
+    q, k, v = (jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+               for shape in (q_shape, k_shape, v_shape))
+    assert ca.chunk_fits(q, k, v) is takes
+    # the two kernels never take the same call
+    assert not (takes and (ca.folded_fits(q, k, v) if len(k_shape) == 3
+                           else ca.fits(q, k)))

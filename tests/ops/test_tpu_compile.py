@@ -11,6 +11,7 @@ import: only one process may hold the TPU library, and every pytest
 worker imports every test file.
 """
 import functools
+import math
 import os
 import re
 import sys
@@ -517,6 +518,10 @@ def test_a_trinity_chunk_holds_no_chunk_of_logits_on_v5e(one_chip):
     assert text.startswith("HloModule jit_chunk_prefill")
     assert re.search(r"bf16\[1,%d\]" % vocab, text)
     assert not re.search(r"\[(\d+,)?%d,%d\]" % (chunk, vocab), text)
+    # its full layer runs the kernel over query blocks and key blocks (PR
+    # 56): no array holds a chunk's scores against the whole cache
+    assert "cached_attention_query_key_blocks" in text
+    assert not re.search(r"\[[\d,]*%d,%d\]" % (chunk, cfg.seq_len), text)
 
 
 _PIPESHARD = {}
@@ -642,6 +647,48 @@ def test_folded_cached_attention_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
+# ---- a prefill chunk's full layers (PR 56) -------------------------------
+
+# (id, queries (B, s, H, D), keys, values, block of the block-causal mask):
+# the chunk step's full layers of the three cells that reach the kernel
+CHUNK_CASES = [
+    ("mimo", (1, 1024, 64, 192), (1, 32768, 768), (1, 32768, 512), 0),
+    ("trinity", (1, 1024, 32, 128), (1, 16384, 4, 128), (1, 16384, 4, 128),
+     0),
+    ("sdar", (1, 1024, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128), 4),
+]
+
+
+@pytest.mark.parametrize("q_shape,k_shape,v_shape,block",
+                         [c[1:] for c in CHUNK_CASES],
+                         ids=[c[0] for c in CHUNK_CASES])
+def test_chunk_attention_compiles_for_v5e(one_chip, q_shape, k_shape,
+                                          v_shape, block):
+    """``ops/cached_attention.py`` ``chunk_attention`` at the cells' chunk
+    shapes, in both layouts of a cache: one Pallas kernel and no loop
+    around it, handed each cache as it lies (no array of a cache's size
+    beside the caches), and no float32 array as large as one head's
+    scores of one key block (they stay in the kernel's fast memory)."""
+    from alpa_tpu.ops import cached_attention as ca
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, keys, values = spec(*q_shape), spec(*k_shape), spec(*v_shape)
+    assert ca.chunk_fits(q, keys, values)
+    compiled = jax.jit(functools.partial(
+        ca.chunk_attention, block=block)).lower(
+            q, keys, values, spec(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    assert "cached_attention_query_key_blocks" in text
+    assert " while(" not in text
+    held = [math.prod(int(d) for d in dims.split(","))
+            for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(held, default=0) < q_shape[1] * ca.CHUNK_BLOCK_K
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 def test_a_mimo_tick_and_chunk_read_what_the_rows_hold_on_v5e(one_chip):
     """The decode tick and the chunk step of ``mimo-v2-flash-1chip`` as its
     cell compiles them (the published widths, the cell's depth, 32 rows,
@@ -695,3 +742,8 @@ def test_a_mimo_tick_and_chunk_read_what_the_rows_hold_on_v5e(one_chip):
     assert step.memory_analysis().temp_size_in_bytes < 2**30
     assert not re.search(r"\[[\d,]*%d,%d\]" % (chunk, context),
                          step.as_text())
+    # its two full layers run the kernel over query blocks and key blocks
+    # (one program lowered for both), and no loop walks the key blocks
+    assert "cached_attention_query_key_blocks" in step.as_text()
+    assert not [line for line in step.as_text().splitlines()
+                if " while(" in line and "/attention/" in line]
