@@ -87,7 +87,8 @@ class GPTConfig:
     # model/moe.py DroplessExperts) | "gated+shortcut" (a gated MLP that
     # joins the stream as "gated" does, and beside it routed experts on
     # the same normed input whose sum is HELD BACK: the next block adds it
-    # with its own MLP's output, ``TransformerBlock``)
+    # with its own MLP's output, ``TransformerBlock``) | "none" (no MLP and
+    # no second norm: the layer is its mixer alone, ``x + mixer(ln1(x))``)
     mlp: Any = "dense"
     # width of the MLP, of one expert where routed; None: mlp_ratio * h
     intermediate_size: Optional[int] = None
@@ -110,7 +111,11 @@ class GPTConfig:
     # the widths of ``sliding_latent``; its cache is a ring of latents)
     # | "conv" (no attention: a gated short convolution, ``ShortConv``; its
     # state is the last ``conv_taps - 1`` positions of a product, whatever
-    # the row's length)
+    # the row's length) | "ssm" (no attention: a Mamba-2 mixer, ``Mamba2``;
+    # its state is a matrix a head and the last ``conv_taps - 1`` positions
+    # of what its convolution runs over, whatever the row's length) | "none"
+    # (no mixer and no first norm: the layer is its MLP alone, ``x +
+    # mlp(ln2(x))``, and its cache entry holds nothing)
     attention: Any = "full"
     sliding_window: int = 0
     # False: rotary positions turn the q and k of "sliding" layers only,
@@ -251,6 +256,26 @@ class GPTConfig:
     # with it inside the engine's tick (``serve/generation.py``
     # ``_verify_draft``).
     num_nextn_predict_layers: int = 0
+    # --- layers that are a mixer or a feed-forward part alone, Mamba-2
+    # mixers and ungated experts (``model_type`` nemotron_h).  Every
+    # default is the block of today.  An ``attention`` kind "ssm"
+    # (``Mamba2``): ``ssm_heads`` heads of ``ssm_head_dim`` channels (the
+    # mixer's inner width is their product, whatever the hidden size), a
+    # state of ``ssm_head_dim x ssm_state_size`` float32 a head, ``B`` and
+    # ``C`` in ``ssm_groups`` groups of consecutive heads, a causal
+    # depthwise convolution of ``conv_taps`` taps with a bias over ``[x | B
+    # | C]``, and the scan over ``s`` new positions in sub-chunks of
+    # ``ssm_chunk``
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # False: a routed expert (and a shared one) is ``down(act(up(x)))``, two
+    # matrices and no gate
+    expert_gated: bool = True
+    # width of a shared expert where it is not the routed experts'
+    shared_expert_width: Optional[int] = None
 
     def mlp_kind(self, layer: int) -> str:
         """The MLP kind of a layer; a multi-token-prediction module's
@@ -275,6 +300,17 @@ class GPTConfig:
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.mlp_width
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of an "ssm" layer's heads together."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels an "ssm" layer's convolution runs over: ``[x | B |
+        C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @property
     def kv_heads(self) -> int:
@@ -460,6 +496,14 @@ _HF_KINDS = {
     "glm_moe_dsa": dict(norm="rmsnorm", positions="rotary",
                         attention="latent", fused_gate_up=True,
                         router_score="sigmoid", router_bias=True),
+    # Nemotron-H (nvidia): wiring read from the family's report and its
+    # published modelling code where config.json does not fix it (one
+    # RMSNorm and one sub-layer a layer, no positions of any kind in the
+    # attention layers, DeepSeek-V3's sigmoid router with a choice bias,
+    # ungated experts)
+    "nemotron_h": dict(norm="rmsnorm", positions="rotary",
+                       rope_on_full_attention=False, router_score="sigmoid",
+                       router_bias=True, expert_gated=False),
 }
 
 
@@ -850,6 +894,68 @@ def _mimo_v2_flash_fields(hf: dict) -> dict:
         route_scale=float(hf["routed_scaling_factor"] or 1.0))
 
 
+# ``hybrid_override_pattern``'s letters: (attention kind, mlp kind)
+_NEMOTRON_H_LAYERS = {"M": ("ssm", "none"), "*": ("full", "none"),
+                      "E": ("none", "experts")}
+
+
+def _nemotron_h_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` nemotron_h says beyond the
+    keys all decoders share: what every layer is (``hybrid_override_pattern``,
+    a letter a layer: ``M`` a Mamba-2 mixer, ``*`` attention, ``E`` routed
+    experts; a layer is ONE of them; the family's ``-``, a dense MLP, is in
+    no published pattern this reads and is refused), the Mamba-2
+    mixer's sizes (``mamba_num_heads`` x ``mamba_head_dim`` is its inner
+    width: ``expand`` sizes nothing), the routed experts, the one shared
+    expert of a width of its own, and the sigmoid router with a choice
+    bias in one group (``n_group`` is the router's, ``n_groups`` the
+    mixer's).  Of a pattern longer than ``num_hidden_layers`` (a file cut
+    in depth may keep the published one) the leading layers count.  The
+    attention layers apply no positions (``_HF_KINDS``), so the file's
+    ``rope_theta`` and ``partial_rotary_factor`` are read by nothing;
+    ``time_step_min``, ``time_step_max`` and ``time_step_floor`` shape the
+    initial ``dt_bias`` (``Mamba2``) and no equation."""
+    layers = hf["num_hidden_layers"]
+    pattern = hf["hybrid_override_pattern"][:layers]
+    if len(pattern) != layers:
+        raise ValueError("hybrid_override_pattern must name every layer")
+    unknown = set(pattern) - set(_NEMOTRON_H_LAYERS)
+    if unknown:
+        raise ValueError(f"unknown layers {sorted(unknown)} in "
+                         "hybrid_override_pattern (known: "
+                         f"{sorted(_NEMOTRON_H_LAYERS)})")
+    if (hf["n_group"], hf["topk_group"]) != (1, 1):
+        raise ValueError("nemotron_h: only a router of one group is "
+                         "supported")
+    for key in ("use_bias", "mlp_bias", "mamba_proj_bias"):
+        if hf[key]:
+            raise ValueError(f"nemotron_h: {key} true is not supported")
+    if not hf["use_conv_bias"] or hf["mamba_hidden_act"] != "silu":
+        raise ValueError("nemotron_h: only a convolution with a bias under "
+                         "silu is supported")
+    if hf["n_shared_experts"] not in (0, 1):
+        raise ValueError("nemotron_h: one shared expert at most, whose "
+                         "width is moe_shared_expert_intermediate_size")
+    kinds = [_NEMOTRON_H_LAYERS[letter] for letter in pattern]
+    return dict(
+        _depth_and_widths(hf),
+        attention=tuple(a for a, _ in kinds),
+        mlp=tuple(m for _, m in kinds),
+        activation=hf["mlp_hidden_act"],
+        layer_norm_eps=hf["layer_norm_epsilon"],
+        tie_embeddings=hf["tie_word_embeddings"],
+        head_dim=hf["head_dim"],
+        ssm_heads=hf["mamba_num_heads"], ssm_head_dim=hf["mamba_head_dim"],
+        ssm_state_size=hf["ssm_state_size"], ssm_groups=hf["n_groups"],
+        ssm_chunk=hf["chunk_size"], conv_taps=hf["conv_kernel"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        num_shared_experts=hf["n_shared_experts"],
+        shared_expert_width=hf["moe_shared_expert_intermediate_size"],
+        norm_topk_prob=hf["norm_topk_prob"],
+        route_scale=float(hf["routed_scaling_factor"]))
+
+
 # what each model type's file says beyond the keys all share
 _HF_FIELDS = {
     "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
@@ -858,6 +964,7 @@ _HF_FIELDS = {
     "dots3_note": _dots3_note_fields,
     "mimo_v2_flash": _mimo_v2_flash_fields,
     "glm_moe_dsa": _glm_moe_dsa_fields,
+    "nemotron_h": _nemotron_h_fields,
 }
 
 
@@ -2350,6 +2457,9 @@ def update_conv_state(kv_cache, g, lengths=None):
     mask hides a wrong state afterwards, as one hides a cache's padding:
     every later token of the row reads it.  None: all ``s`` are real.
 
+    A Mamba-2 mixer's convolution runs over the same kind of state, wider
+    (``Mamba2``, which puts its ssm state where ``empty`` is).
+
     Nothing of ``update_kv_cache``'s rule for unwritten positions holds
     here: a fresh row's state has to BE zeros (position 0 sees two zero
     positions before it), which ``fresh_kv_caches`` gives; a free row's
@@ -2417,6 +2527,160 @@ class ShortConv(nn.Module):
                        full[:, j:j + s].astype(jnp.float32)
                        for j in range(taps)).astype(cfg.dtype)
             out = dense(h, name="out_proj")(c_gate * conv)
+        return out, new_cache
+
+
+# the scope a Mamba-2 mixer is traced under, the whole mixer: both
+# projections, the convolution, the recurrence, the gated norm, both
+# states' updates (a capture reads it: telemetry/device_time.py)
+SSM_SCOPE = "ssm_mixer"
+
+
+def _dt_bias_init(low: float = 1e-3, high: float = 0.1, floor: float = 1e-4):
+    """``dt_bias`` as the family initialises it: the inverse softplus of a
+    step drawn log-uniformly in [``low``, ``high``] and floored (the
+    file's ``time_step_min``, ``time_step_max``, ``time_step_floor``), so
+    that ``softplus(0 + dt_bias)`` is that step."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) *
+                     (np.log(high) - np.log(low)) + np.log(low))
+        dt = jnp.maximum(dt, floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+def real_steps(dt, index, lengths):
+    """The steps ``dt`` (B, s, H) of ``s`` new positions from ``index`` on
+    (a scalar, or (B,) a row), 0 at the positions past the rows' whole
+    ``lengths`` (B,): right-padding, which is not real and has to pass the
+    state on as it found it (a step of 0 neither decays nor adds)."""
+    first = jnp.asarray(index, jnp.int32)
+    first = first[:, None] if first.ndim else first[None, None]
+    at = first + jax.lax.broadcasted_iota(jnp.int32, (1, dt.shape[1]), 1)
+    return jnp.where((at < lengths[:, None])[..., None], dt, 0.0)
+
+
+def gated_group_norm(y, z, weight, groups: int, eps: float):
+    """``rmsnorm_groups(y * silu(z)) * weight`` in float32: the gate FIRST,
+    then an RMSNorm over each of the ``groups`` groups of consecutive
+    channels, one weight vector for all of them (Mamba-2's gated norm as
+    Nemotron-H configures it)."""
+    y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    grouped = y.reshape(y.shape[:-1] + (groups, -1))
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.square(grouped).mean(-1, keepdims=True) + eps)
+    return grouped.reshape(y.shape) * weight.astype(jnp.float32)
+
+
+class Mamba2(nn.Module):
+    """The Mamba-2 mixer of Nemotron-H (Dao & Gu 2024; the ``attention``
+    kind "ssm" of ``GPTConfig``), no bias but the convolution's.  ``H``
+    heads of ``P`` channels (``ssm_heads``, ``ssm_head_dim``; inner width
+    ``H P``), a state of ``N`` (``ssm_state_size``), ``G`` groups
+    (``ssm_groups``) of ``H / G`` consecutive heads:
+
+    ``[z | xBC | dt] = in_proj(u)`` of widths ``H P``, ``H P + 2 G N``, ``H``;
+    ``xBC = silu(conv(xBC) + bias)``, a depthwise causal convolution of
+    ``conv_taps`` taps behind zeros; ``[x | B | C] = xBC``, ``x`` as (H, P),
+    ``B`` and ``C`` as (G, N); ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` a head, float32; for head h of group g ``S_t = exp(dt_t
+    A) S_{t-1} + dt_t x_t B_t^T`` and ``y_t = S_t C_t + D_h x_t``
+    (``ops/ssm_scan.py``); ``y = rmsnorm_groups(y * silu(z)) * w``, the gate
+    first, then an RMSNorm over each group's ``H P / G`` channels;
+    ``out_proj(y)``.
+
+    Without a cache (the training call) the sequence starts from zeros.
+    With one, the layer's entry is ``(conv state (B, taps - 1, H P + 2 G
+    N), ssm state (B, H, P, N) float32, index)``: one new position a row
+    reads both states, steps and writes them (``ssm_step``); ``s`` new
+    positions run the taps over the conv state with ``xBC`` behind it
+    (``update_conv_state``) and the scan in sub-chunks of ``ssm_chunk``
+    FROM the row's ssm state (``ssm_chunk_scan``), and leave, a row, both
+    states of its last REAL position (``cache_lengths``: a padded position
+    has ``dt`` 0, which neither decays the state nor adds to it; no mask
+    hides a wrong state afterwards).
+
+    A model made from a seed draws ``A_log = log(1 .. H)``, ``dt_bias``
+    the inverse softplus of a log-uniform step in [0.001, 0.1], ``D`` ones,
+    as the family does, so that its state neither dies in a position nor
+    never forgets; the three are float32 whatever ``param_dtype``."""
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, deterministic=True,
+                 position_ids=None, cache_lengths=None):
+        from alpa_tpu.ops.ssm_scan import ssm_chunk_scan, ssm_step
+        cfg = self.config
+        if not cfg.causal or cfg.block_length:
+            raise ValueError("a Mamba-2 mixer is causal over one sequence "
+                             "a row and takes no block-causal mask")
+        heads, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim,
+                          cfg.ssm_state_size, cfg.ssm_groups)
+        taps, inner, width = cfg.conv_taps, cfg.ssm_inner, cfg.ssm_conv_width
+        if min(heads, p, n) < 1 or heads % g or inner % g or taps < 2:
+            raise ValueError(
+                "an \"ssm\" layer needs GPTConfig.ssm_heads (a multiple "
+                "of ssm_groups), ssm_head_dim, ssm_state_size and "
+                f"conv_taps >= 2, got {(heads, g, p, n, taps)}")
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=False,
+                        param_dtype=cfg.param_dtype)
+        b, s = x.shape[:2]
+        new_cache = None
+        with jax.named_scope(SSM_SCOPE):
+            z, xbc, dt = jnp.split(
+                dense(inner + width + heads, name="in_proj")(x),
+                [inner, inner + width], axis=-1)
+            # lecun_normal over the taps: a tap's fan-in is ``taps``
+            kernel = self.param("conv_kernel",
+                                nn.initializers.lecun_normal(),
+                                (taps, width), cfg.param_dtype)
+            # (as torch's Conv1d draws it: a zero bias would hide its loss)
+            bias = self.param(
+                "conv_bias", lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -0.5, 0.5), (width,), cfg.param_dtype)
+            dt_bias = self.param("dt_bias", _dt_bias_init(), (heads,),
+                                 jnp.float32)
+            a_log = self.param(
+                "A_log", lambda key, shape, dtype: jnp.log(
+                    jnp.arange(1, shape[0] + 1, dtype=dtype)),
+                (heads,), jnp.float32)
+            skip = self.param("D", nn.initializers.ones, (heads,),
+                              jnp.float32)
+            if kv_cache is None:
+                full = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+                state = jnp.zeros((b, heads, p, n), jnp.float32)
+            else:
+                conv_state, state, index = kv_cache
+                full, (conv_state, _, index) = update_conv_state(
+                    (conv_state, None, index), xbc, cache_lengths)
+            # the shifted copies, summed in float32
+            conv = sum(kernel[j].astype(jnp.float32) *
+                       full[:, j:j + s].astype(jnp.float32)
+                       for j in range(taps)) + bias.astype(jnp.float32)
+            xs, bs, cs = jnp.split(nn.silu(conv).astype(cfg.dtype),
+                                   [inner, inner + g * n], axis=-1)
+            xs = xs.reshape(b, s, heads, p)
+            bs, cs = bs.reshape(b, s, g, n), cs.reshape(b, s, g, n)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            if kv_cache is not None and cache_lengths is not None:
+                dt = real_steps(dt, kv_cache[2], cache_lengths)
+            a = -jnp.exp(a_log)
+            if s == 1 and kv_cache is not None:
+                y, state = ssm_step(state, xs[:, 0], dt[:, 0], a, bs[:, 0],
+                                    cs[:, 0])
+                y = y[:, None]
+            else:
+                y, state = ssm_chunk_scan(state, xs, dt, a, bs, cs,
+                                          cfg.ssm_chunk, cfg.dtype)
+            y = y + skip[:, None] * xs.astype(jnp.float32)
+            if kv_cache is not None:
+                new_cache = (conv_state, state, index)
+            weight = self.param("norm", nn.initializers.ones, (inner,),
+                                cfg.param_dtype)
+            y = gated_group_norm(y.reshape(b, s, inner), z, weight, g,
+                                 cfg.layer_norm_eps)
+            out = dense(cfg.hidden_size, name="out_proj")(
+                y.astype(cfg.dtype))
         return out, new_cache
 
 
@@ -2549,6 +2813,9 @@ def activation_fn(name: str) -> Callable:
         return nn.relu
     if name == "silu":
         return nn.silu
+    if name == "relu2":
+        # squared ReLU (Nemotron-H's ``mlp_hidden_act``)
+        return lambda x: jnp.square(nn.relu(x))
     return partial(nn.gelu, approximate=True)
 
 
@@ -2597,7 +2864,12 @@ class TransformerBlock(nn.Module):
 
     ``return_selected`` (a latent layer that selects its positions, one
     new position a row over a cache): what the layer selected comes third,
-    before the routing (``LatentAttention``)."""
+    before the routing (``LatentAttention``).
+
+    A block whose ``attention`` or ``mlp`` is "none" (Nemotron-H: a layer
+    is a mixer or a feed-forward part ALONE) has the other half only: one
+    norm (``ln1`` before a mixer, ``ln2`` before an MLP), one sub-layer,
+    one residual sum."""
     config: GPTConfig
     mlp: Optional[str] = None
     attention: Optional[str] = None
@@ -2608,23 +2880,42 @@ class TransformerBlock(nn.Module):
                  shortcut=None, return_selected=False):
         cfg = self.config
         kind = self.mlp or cfg.mlp_kind(0)
-        ln1 = make_norm(cfg, "ln1")(x)
         mixer = self.attention or cfg.attention_kind(0)
-        if mixer in ("latent", LATENT_SLIDING):
-            attn = LatentAttention(cfg, attention=mixer, name="attn")
-            if return_selected:
-                attn = partial(attn, return_selected=True)
-        elif mixer == "conv":
-            attn = ShortConv(cfg, name="conv")
+        if mixer == "none" and kind == "none":
+            raise ValueError("a layer of neither a mixer nor an MLP")
+        selection = ()
+        if mixer == "none":
+            # the layer is its MLP alone: its cache entry holds nothing and
+            # goes back as it came, at the index the others are at
+            new_cache = kv_cache if kv_cache is None else (
+                kv_cache[0], kv_cache[1],
+                jnp.asarray(kv_cache[2], jnp.int32) + x.shape[1])
         else:
-            attn = partial(
-                SelfAttention(cfg, attention=self.attention, name="attn"),
-                padding_bias=padding_bias)
-        attn_out, new_cache, *selection = attn(
-            ln1, kv_cache, deterministic, position_ids, cache_lengths)
-        if cfg.post_norms:
-            attn_out = make_norm(cfg, "ln1_post")(attn_out)
-        x = x + attn_out.astype(x.dtype)
+            ln1 = make_norm(cfg, "ln1")(x)
+            if mixer in ("latent", LATENT_SLIDING):
+                attn = LatentAttention(cfg, attention=mixer, name="attn")
+                if return_selected:
+                    attn = partial(attn, return_selected=True)
+            elif mixer == "conv":
+                attn = ShortConv(cfg, name="conv")
+            elif mixer == "ssm":
+                attn = Mamba2(cfg, name="ssm")
+            else:
+                attn = partial(
+                    SelfAttention(cfg, attention=self.attention,
+                                  name="attn"),
+                    padding_bias=padding_bias)
+            attn_out, new_cache, *selection = attn(
+                ln1, kv_cache, deterministic, position_ids, cache_lengths)
+            if cfg.post_norms:
+                attn_out = make_norm(cfg, "ln1_post")(attn_out)
+            x = x + attn_out.astype(x.dtype)
+        if kind == "none":
+            # the layer is its mixer alone
+            if shortcut is not None:
+                raise ValueError("a layer without an MLP joins no held-back "
+                                 "experts to the stream")
+            return (x, new_cache) + tuple(selection)
         ln2 = make_norm(cfg, "ln2")(x)
         routing = ()
         if routed_mlp(kind):
@@ -2911,7 +3202,11 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     ((B, window, rank), (B, rope dim, window)).  A
     "conv" layer holds no positions but a state: its entry is the pair
     ((B, conv_taps - 1, hidden_size), (B, 0)), the second array empty so
-    that the entry is a triple as every layer's is.
+    that the entry is a triple as every layer's is.  An "ssm" layer holds
+    two states: ((B, conv_taps - 1, ``ssm_conv_width``), (B, ssm_heads,
+    ssm_head_dim, ssm_state_size)), the second float32 whatever the
+    caches' dtype (``init_kv_caches``).  A "none" layer (an MLP alone)
+    holds nothing: ((B, 0), (B, 0)).
     Takes any decoder family's configuration: what ``GPTConfig`` alone has
     reads as its default.
 
@@ -2963,6 +3258,15 @@ def kv_cache_shapes(config, batch_size: int) -> list:
                 (batch_size, config.conv_taps - 1, config.hidden_size),
                 (batch_size, 0)))
             continue
+        if kind == "ssm":
+            shapes.append((
+                (batch_size, config.conv_taps - 1, config.ssm_conv_width),
+                (batch_size, config.ssm_heads, config.ssm_head_dim,
+                 config.ssm_state_size)))
+            continue
+        if kind == "none":
+            shapes.append(((batch_size, 0), (batch_size, 0)))
+            continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
         kv = heads_of(kind)
@@ -2983,7 +3287,9 @@ def kv_cache_kinds(config) -> list:
     positions of K and V), "window" (a "sliding" layer's ring), "latent",
     "latent_index" (a latent layer that selects its positions: a row and
     an index key a position), "latent_window" (a "latent_sliding" layer's
-    ring of latents), "conv" (a state and no positions)."""
+    ring of latents), "conv" (a state and no positions), "ssm" (a Mamba-2
+    mixer's two states and no positions), "none" (a layer that is its MLP
+    alone: an empty entry)."""
     kinds = getattr(config, "attention", "full")
 
     def label(kind):
@@ -3059,14 +3365,23 @@ def conv_states(config) -> bool:
     return "conv" in kv_cache_kinds(config)
 
 
+def ssm_states(config) -> bool:
+    """Whether any layer is a Mamba-2 mixer, whose cache entry is two
+    states of fixed size (the convolution's last positions, a matrix a
+    head) and no cache of positions."""
+    return "ssm" in kv_cache_kinds(config)
+
+
+
 def uniform_kv_caches(config) -> bool:
     """Whether every layer's cache holds positions and has one shape: what
     the block pool, the speculative verify step, beam search and the
     disaggregated prefill count on (one block table, one length and one
     index for all layers).  A short convolution's state holds no positions, so a
     configuration with one is not uniform whatever its shapes; its cached
-    calls are handed the rows' lengths as a ring's are."""
-    return not conv_states(config) and \
+    calls are handed the rows' lengths as a ring's are; so is one with a
+    Mamba-2 mixer's states, or with a layer that holds nothing."""
+    return not conv_states(config) and not ssm_states(config) and \
         len(set(kv_cache_shapes(config, 1))) == 1
 
 
@@ -3086,6 +3401,16 @@ def require_uniform_kv_caches(config, what: str):
             "whose entry is a state of the last conv_taps - 1 positions "
             "that every step overwrites: no index brings an earlier state "
             "back, and there are no positions to page or reorder: "
+            f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
+    if ssm_states(config):
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape and "
+            "rolls a row back by its index, and this configuration has "
+            "Mamba-2 mixers (GPTConfig.attention \"ssm\"), whose entry is "
+            "two states (the convolution's last conv_taps - 1 positions "
+            "and a matrix a head) that every step overwrites: no index "
+            "brings an earlier state back, and there are no positions to "
+            "page or reorder: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     kinds = set(kv_cache_kinds(config))
     if kinds & {"latent_index", "latent_window"}:
@@ -3136,7 +3461,8 @@ def require_rollback_by_index(config, what: str):
     position it may not keep and takes it back by not advancing the index
     over it, so that the next step overwrites it.  A ring that the
     rejected position was written into has lost the position it replaced,
-    a short convolution's state has moved on, and a configuration that
+    a short convolution's state and a Mamba-2 mixer's have moved on, and a
+    configuration that
     generates by diffusion over blocks has no one token to verify: each
     is refused by name."""
     kinds = set(kv_cache_kinds(config))
@@ -3146,7 +3472,9 @@ def require_rollback_by_index(config, what: str):
             ("latent_window", "a ring of the window's latents "
              "(GPTConfig.attention \"latent_sliding\")"),
             ("conv", "a short convolution's state (GPTConfig.attention "
-             "\"conv\")")):
+             "\"conv\")"),
+            ("ssm", "a Mamba-2 mixer's states (GPTConfig.attention "
+             "\"ssm\")")):
         if kind in kinds:
             raise ValueError(
                 f"{what} rolls a rejected position back by the row's index "
@@ -3185,16 +3513,20 @@ def init_kv_caches(config: GPTConfig, batch_size: int,
     """KV caches as explicit arrays (ref opt_model.py:605 init_cache_aval):
     ``[(k, v, index)]`` a layer, each layer's of its own shape
     (``kv_cache_shapes``); a "latent" layer's ``(c, k_pe, index)``, a
-    "conv" layer's ``(state, empty, index)``.  All zeros, which a "conv"
-    layer's state has to be for a row that starts
-    (``update_conv_state``)."""
+    "conv" layer's ``(state, empty, index)``, an "ssm" layer's ``(conv
+    state, ssm state, index)`` with the ssm state float32 whatever
+    ``dtype`` (a state kept in bfloat16 would round once a position for
+    thousands of positions).  All zeros, which a "conv" or "ssm" layer's
+    states have to be for a row that starts (``update_conv_state``)."""
     dtype = dtype or config.dtype
     caches = []
-    for shape in kv_cache_shapes(config, batch_size):
+    for kind, shape in zip(kv_cache_kinds(config),
+                           kv_cache_shapes(config, batch_size)):
         k_shape, v_shape = shape if isinstance(shape[0], tuple) else \
             (shape, shape)
-        caches.append((jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype),
-                       jnp.int32(0)))
+        caches.append((jnp.zeros(k_shape, dtype),
+                       jnp.zeros(v_shape, jnp.float32 if kind == "ssm"
+                                 else dtype), jnp.int32(0)))
     return caches
 
 

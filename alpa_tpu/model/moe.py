@@ -158,16 +158,19 @@ _permute_rows.defvjp(lambda x, perm, inverse: (x[perm], inverse),
 
 
 class DroplessExperts(nn.Module):
-    """Top-k routed, SiLU-gated experts without capacity (module
-    docstring).  ``config`` is a ``GPTConfig``: ``num_experts``,
-    ``num_experts_per_tok``, ``expert_width`` (one expert's),
-    ``activation``, the router's settings (``router_score``,
+    """Top-k routed, gated experts without capacity (module docstring):
+    ``down(act(gate(x)) * up(x))``, or with ``expert_gated`` False
+    ``down(act(up(x)))``, two matrices and no gate (Nemotron-H, whose
+    ``act`` is the squared ReLU).  ``config`` is a ``GPTConfig``:
+    ``num_experts``, ``num_experts_per_tok``, ``expert_width`` (one
+    expert's), ``activation``, the router's settings (``router_score``,
     ``router_bias``, ``norm_topk_prob``, ``route_scale``:
     ``topk_routing``), ``num_shared_experts``, ``dtype``.  The router and
     its scores are float32 at full matmul precision (a near-tie between
     two experts then flips only on the activations' own rounding); the
     experts multiply in ``dtype`` with float32 accumulation.  The shared
-    experts are one gated MLP each, applied to every token and added to
+    experts are one MLP each of the routed experts' form, ``expert_width``
+    wide or ``shared_expert_width``, applied to every token and added to
     the routed sum.
 
     ``experts_held`` (first, count): the layer's experts are divided over
@@ -214,7 +217,20 @@ class DroplessExperts(nn.Module):
         first, mine = (0, e) if held is None else held
         h = x.shape[-1]
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        if cfg.fused_gate_up:
+        # False: ``down(act(up(x)))``, two matrices an expert and no gate
+        gated = getattr(cfg, "expert_gated", True)
+        if not gated:
+            # stored (E, width, h), as ``w_down`` with the hidden size
+            # minor-most: a width that is no whole number of lane tiles
+            # (1,856) as a parameter's minor-most dimension makes the TPU
+            # lay the array out in another order, and the kernel's operand
+            # is then copied into the named one every call (3 ms a tick
+            # of Nemotron-3-Nano's 23 layers, PERF.md, PR 58)
+            w_up = self.param(
+                "w_up", nn.initializers.lecun_normal(
+                    in_axis=-1, out_axis=-2, batch_axis=(0,)),
+                (mine, width, h), cfg.param_dtype)
+        elif cfg.fused_gate_up:
             # [gate | up] of every expert, side by side as the grouped
             # matmul takes them
             w_gate_up = self.param("w_gate_up", init, (mine, h, 2 * width),
@@ -259,13 +275,18 @@ class DroplessExperts(nn.Module):
                 counts[first:first + mine]
             rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
                                     inverse, k)
-            # gate and up in one pass over the rows
-            if not cfg.fused_gate_up:
-                w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1)
-            gate_up = grouped_matmul(rows, w_gate_up, group_sizes)
             act = activation_fn(cfg.activation)
-            hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
-                      gate_up[:, width:].astype(jnp.float32))
+            if not gated:
+                hidden = act(grouped_matmul(
+                    rows, w_up, group_sizes, transposed=True).astype(
+                        jnp.float32))
+            else:
+                # gate and up in one pass over the rows
+                if not cfg.fused_gate_up:
+                    w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1)
+                gate_up = grouped_matmul(rows, w_gate_up, group_sizes)
+                hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
+                          gate_up[:, width:].astype(jnp.float32))
             out_rows = grouped_matmul(hidden.astype(cfg.dtype), w_down,
                                       group_sizes)
             if partial:
@@ -281,9 +302,10 @@ class DroplessExperts(nn.Module):
                 y = y + jnp.where(identity, weights, 0.0).sum(
                     -1, keepdims=True) * tokens.astype(jnp.float32)
             for i in range(cfg.num_shared_experts):
-                y = y + MLPBlock(cfg, gated=True, width=width,
-                                 name=f"shared{i}")(tokens).astype(
-                                     jnp.float32)
+                y = y + MLPBlock(
+                    cfg, gated=gated, name=f"shared{i}", width=getattr(
+                        cfg, "shared_expert_width", None) or width)(
+                            tokens).astype(jnp.float32)
         routing = {"counts": counts, "prob_sums": probs.sum(0),
                    "experts": experts}
         if zeros:

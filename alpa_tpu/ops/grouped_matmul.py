@@ -63,6 +63,8 @@ TILING = (512, 1024, 1024)
 # no row tile under the matrix unit's 128 rows: at 64 rows a group, 64 was
 # no faster than 128 on the chip (PERF.md, PR 31)
 MIN_ROW_TILE = 128
+# channels in a lane tile
+LANES = 128
 # of the kernel's 16 MiB, what its blocks may take: the rest is the
 # compiler's (the store's mask and select over a float32 tile)
 VMEM_BUDGET = 12 * 2**20
@@ -75,10 +77,20 @@ def _gmm_vmem(tm, tk, tn):
     return 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
 
 
+def _lane_divisors(size, cap):
+    """The whole numbers of lane tiles that divide ``size``, at most
+    ``cap``, the largest first."""
+    return [t for t in range(cap - cap % LANES, 0, -LANES) if size % t == 0]
+
+
 def _divisor(size, tile):
-    tile = min(tile, size)
+    cap = tile = min(tile, size)
     while size % tile:
         tile //= 2
+    if tile == LANES:
+        # halving came down to ONE lane tile (2,688 = 21 x 128): the most
+        # whole lane tiles under the cap that divide the dimension (896)
+        tile = _lane_divisors(size, cap)[0]
     return tile
 
 
@@ -89,11 +101,23 @@ def tiling(m, k, n, groups):
     and ``TILING``'s; the other two are ``TILING``'s, then, where the rows
     are more than one tile, the contracted dimension whole and the produced
     one whole, each if the blocks still fit ``VMEM_BUDGET``.  Every tile is
-    cut down to a divisor of its dimension."""
+    cut down to a divisor of its dimension by halving (to the most whole
+    lane tiles that divide it where halving leaves one); a dimension that
+    so comes under a lane tile (1,856 = 29 x 64) is walked whole, and the
+    other's tile then shrinks by whole lane tiles until the blocks fit."""
     rows = -(-m // groups)
     tm = _divisor(m, min(TILING[0],
                          max(MIN_ROW_TILE, 1 << (rows - 1).bit_length())))
     tk, tn = _divisor(k, TILING[1]), _divisor(n, TILING[2])
+    # a block's last dimension is whole lane tiles or the array's own
+    tk, tn = (k if tk < LANES else tk), (n if tn < LANES else tn)
+    # (one of the two walked whole and the blocks too large: the other's)
+    while _gmm_vmem(tm, tk, tn) > VMEM_BUDGET and (tk == k) != (tn == n):
+        smaller = _lane_divisors(k, tk - 1) if tn == n else \
+            _lane_divisors(n, tn - 1)
+        if not smaller:
+            break
+        tk, tn = (smaller[0], tn) if tn == n else (tk, smaller[0])
     if m > tm:
         if _gmm_vmem(tm, k, tn) <= VMEM_BUDGET:
             tk = k
@@ -114,9 +138,10 @@ def _run(kernel, *args, **static):
         default=functools.partial(kernel, interpret=True, **static))
 
 
-@jax.custom_vjp
-def _grouped_matmul(lhs, rhs, group_sizes):
-    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(lhs, rhs, group_sizes, transposed):
+    (m, k), groups = lhs.shape, rhs.shape[0]
+    n = rhs.shape[1 if transposed else 2]
     tiles = tiling(m, k, n, groups)
     # at trace time: what the shape made of the row tile
     tmetrics.get_registry().gauge(
@@ -126,38 +151,49 @@ def _grouped_matmul(lhs, rhs, group_sizes):
             padded_work_ratio(m, groups, tiles[0]))
     with jax.named_scope(SCOPE):
         return _run(gmm, lhs, rhs, group_sizes,
-                    preferred_element_type=lhs.dtype, tiling=tiles)
+                    preferred_element_type=lhs.dtype, tiling=tiles,
+                    transpose_rhs=transposed)
 
 
-def _forward(lhs, rhs, group_sizes):
-    return _grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def _forward(lhs, rhs, group_sizes, transposed):
+    return (_grouped_matmul(lhs, rhs, group_sizes, transposed),
+            (lhs, rhs, group_sizes))
 
 
-def _backward(residuals, g):
+def _backward(transposed, residuals, g):
     lhs, rhs, group_sizes = residuals
-    (m, k), (groups, _, n) = lhs.shape, rhs.shape
+    (m, k), groups = lhs.shape, rhs.shape[0]
+    n = rhs.shape[1 if transposed else 2]
     # tgmm produces (tk, tn) tiles with a float32 accumulator each: they
     # stay TILING's, which is what fits (PERF.md, PR 26), under the rule's
     # row tile
     tm = tiling(m, k, n, groups)[0]
+    tk, tn = _divisor(k, TILING[1]), _divisor(n, TILING[2])
     with jax.named_scope(SCOPE):
+        # the rows' gradient meets the weights the other way round
         d_lhs = _run(gmm, g, rhs, group_sizes,
                      preferred_element_type=lhs.dtype,
-                     tiling=tiling(m, n, k, groups), transpose_rhs=True)
-        d_rhs = _run(tgmm, lhs.swapaxes(0, 1), g, group_sizes,
-                     preferred_element_type=rhs.dtype,
-                     tiling=(tm, _divisor(k, TILING[1]),
-                             _divisor(n, TILING[2])),
-                     num_actual_groups=groups)
+                     tiling=tiling(m, n, k, groups),
+                     transpose_rhs=not transposed)
+        if transposed:
+            d_rhs = _run(tgmm, g.swapaxes(0, 1), lhs, group_sizes,
+                         preferred_element_type=rhs.dtype,
+                         tiling=(tm, tn, tk), num_actual_groups=groups)
+        else:
+            d_rhs = _run(tgmm, lhs.swapaxes(0, 1), g, group_sizes,
+                         preferred_element_type=rhs.dtype,
+                         tiling=(tm, tk, tn), num_actual_groups=groups)
     return d_lhs, d_rhs, None
 
 
 _grouped_matmul.defvjp(_forward, _backward)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(lhs, rhs, group_sizes, transposed: bool = False):
     """(M, K) x (G, K, N) -> (M, N) in ``lhs.dtype``: group g's rows
     against ``rhs[g]``.  ``rhs`` is cast to ``lhs.dtype`` first (float32
-    parameters under bfloat16 activations)."""
+    parameters under bfloat16 activations).  ``transposed``: ``rhs`` is
+    (G, N, K) and group g's rows meet ``rhs[g]^T`` (the kernel contracts
+    both operands' last dimension; nothing is transposed in memory)."""
     return _grouped_matmul(lhs, rhs.astype(lhs.dtype),
-                           group_sizes.astype(jnp.int32))
+                           group_sizes.astype(jnp.int32), transposed)

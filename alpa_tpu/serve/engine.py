@@ -151,15 +151,18 @@ _KV_CACHE_BYTES = _REG.gauge(
     "full (the served context), latent (the served context of a latent "
     "layer's normed latent and shared rotary key; with an index key a "
     "position where the layer selects its positions; a ring of the "
-    "window's latents where the layer is under the window) or conv (a "
-    "short convolution's state: its last positions, whatever the context); "
+    "window's latents where the layer is under the window), conv (a "
+    "short convolution's state: its last positions, whatever the context) "
+    "or ssm (a Mamba-2 mixer's two states: its convolution's last "
+    "positions and a matrix a head, whatever the context); "
     "the arrays' sizes as the device lays them out (held_bytes)",
     labelnames=("kind",))
 _KV_CACHE_ARRAY_BYTES = _REG.gauge(
     "alpa_serving_kv_cache_array_bytes",
     "The same bytes by the kind of the layers' cache, the array (keys, "
     "values: a latent layer's latents and shared keys, a short "
-    "convolution's state and nothing) and the shape one layer's array has, "
+    "convolution's state and nothing, a Mamba-2 mixer's conv state and "
+    "ssm state) and the shape one layer's array has, "
     "summed over the layers that hold it: a configuration whose kinds "
     "differ in heads and whose keys are wider than its values has four "
     "series",
@@ -581,9 +584,12 @@ class ContinuousBatchingEngine:
                         for (k, v, _i) in init_kv_caches(cfgm, self.B)]
         # by the kind of the layer's entry; a short convolution's is its
         # state, as large a row whatever the context
-        by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0}
+        by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0, "ssm": 0}
         by_array = {}
         for kind, (k, v, _i) in zip(kv_cache_kinds(cfgm), self._caches):
+            if kind == "none":
+                # a layer that is its MLP alone holds nothing
+                continue
             # every latent layer's entry is "latent", whatever it holds
             kind = kind.partition("_")[0]
             for array, x in (("keys", k), ("values", v)):

@@ -421,6 +421,18 @@ class Generator:
     (``update_conv_state``).  What rolls a row back by its index or
     indexes positions refuses it, by the same call.
 
+    A Mamba-2 layer (``GPTConfig.attention`` "ssm") holds two states and no
+    positions: its entry is ``(conv state, ssm state, index)``, the
+    convolution's last ``conv_taps - 1`` positions in the caches' dtype and
+    a float32 matrix a head, and rides in the list as a "conv" layer's
+    does: both arrays donated by the decode and updated in place (64 rows'
+    states of 23 layers are 3.1 GB that a tick reads once and writes once),
+    scattered whole by ``_scatter_row``, the chunk step's scan started FROM
+    the row's state and left at its last real position (a padded
+    position's step is 0: ``gpt_model.real_steps``).  A layer that is its
+    MLP alone (``attention`` "none") holds an empty entry, which every step
+    hands back at the others' index.  The same refusals hold.
+
     ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
     every routed-expert layer, and ``{}`` (no output of the compiled

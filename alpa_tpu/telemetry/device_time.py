@@ -49,6 +49,11 @@ part                         components
                              convolution whole: both products, the gates,
                              the taps, the state's update (and ``conv``,
                              its module: a weight the compiler copies)
+``ssm_mixer``                ``model.gpt_model.SSM_SCOPE``, a Mamba-2
+                             mixer whole: both projections, the
+                             convolution, the recurrence, the gated norm,
+                             both states' updates (and ``ssm``, its
+                             module: a weight the compiler copies)
 ``mlp``                      ``mlp`` (``MLPBlock``; a routed layer's
                              shared expert)
 ``moe``                      ``model.moe.SCOPE``: router, top-k, sort,
@@ -134,7 +139,8 @@ COLLECTIVE = "collective"
 MIXED, INHERITED = "mixed", "inherited"
 
 # path component -> part.  The scope names are those of model/gpt_model.py
-# (ATTENTION_SCOPE, CACHE_WRITE_SCOPE, CONV_SCOPE), model/moe.py (SCOPE),
+# (ATTENTION_SCOPE, CACHE_WRITE_SCOPE, CONV_SCOPE, SSM_SCOPE), model/moe.py
+# (SCOPE),
 # ops/grouped_matmul.py (SCOPE) and model/model_util.py (LOSS_SCOPE):
 # telemetry imports no model.
 _COMPONENTS = {
@@ -152,6 +158,8 @@ _COMPONENTS = {
     "window_core": "attention.window_core",
     "full_core": "attention.full_core",
     "short_conv": "short_conv", "conv": "short_conv",
+    # model/gpt_model.py SSM_SCOPE, and ``ssm``, the module's name
+    "ssm_mixer": "ssm_mixer", "ssm": "ssm_mixer",
     "mlp": "mlp",
     "moe": "moe",
     "grouped_matmul": "moe.grouped_matmul",

@@ -1,0 +1,66 @@
+"""Hashes of the lowered programs of cells a change should leave as they
+are: ``python3 scripts/lowered_program_hashes.py <root of a checkout>``
+prints one JSON line, a name -> the first 16 hex digits of the SHA-256 of
+the program's StableHLO text, lowered from abstract arguments on the CPU
+(nothing is compiled or run): OPT-1.3B's decode over 4 rows and its two
+dense prefills, the gradient of two GPT-1.3B blocks under remat, of one
+OLMoE layer with its routed experts, and the served decode and chunk step
+of LFM2 and Trinity at a depth of four and five layers.  Two checkouts whose lines
+agree build the same programs for those cells (PR 58 compared its tree
+with its parent so)."""
+import hashlib, json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+root = os.path.abspath(sys.argv[1])
+sys.path.insert(0, root)
+import jax, jax.numpy as jnp
+from alpa_tpu.model.gpt_model import (GPTModel, config_from_hf, config_from_opt_spec, config_from_spec, init_kv_caches)
+from alpa_tpu.serve.generation import Generator
+
+def digest(lowered):
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+
+def abstract(tree):
+    return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+out = {}
+# OPT-1.3B served: decode over 4 rows, dense prefill at 512 and 2048, the chunk step
+cfg = config_from_opt_spec("1.3b", dtype=jnp.bfloat16)
+model = GPTModel(cfg)
+params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+gen = Generator(model, params, cfg, prefill_chunk=None)
+caches = jax.eval_shape(lambda: init_kv_caches(cfg, 4))
+S = jax.ShapeDtypeStruct
+out["opt.decode"] = digest(gen._decode.jitted.lower(params, S((4, 1), jnp.int32), S((4,), jnp.int32), [(k, v) for k, v, _ in caches], [S((4,), jnp.int32) for _ in caches]))
+for bucket in (512, 2048):
+    out[f"opt.prefill{bucket}"] = digest(gen._prefill.lower(params, S((1, bucket), jnp.int32), None, S((1,), jnp.int32)))
+# GPT 1.3B training: loss and gradients of the block stack
+import dataclasses
+cfg = dataclasses.replace(config_from_spec("1.3B", dtype=jnp.bfloat16, remat_blocks=True), num_layers=2)
+model = GPTModel(cfg)
+params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+def loss(p, ids):
+    return model.apply(p, ids).astype(jnp.float32).mean()
+out["gpt.grad"] = digest(jax.jit(jax.grad(loss)).lower(params, S((8, 1024), jnp.int32)))
+# OLMoE: routed experts forward and backward
+hf = dict(json.load(open(os.path.join(root, "chipbench/configs/olmoe-1b-7b-1chip.json"))), num_hidden_layers=1)
+cfg = config_from_hf(hf, dtype=jnp.bfloat16)
+model = GPTModel(cfg)
+params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+def loss(p, ids):
+    logits, routing = model.apply(p, ids)
+    return logits.astype(jnp.float32).mean() + routing["load_balance_loss"]
+out["olmoe.grad"] = digest(jax.jit(jax.grad(loss)).lower(params, S((2, 4096), jnp.int32)))
+# LFM2 and Trinity served: the decode over the cell's rows and the chunk step
+for name, layers in (("lfm2-8b-a1b-1chip", 5), ("trinity-mini-1chip", 4)):
+    hf = json.load(open(os.path.join(root, f"chipbench/configs/{name}.json")))
+    hf = dict(hf, num_hidden_layers=layers, layer_types=hf["layer_types"][:layers])
+    serve = hf["serve"]
+    cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=serve["served_context"])
+    model = GPTModel(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+    gen = Generator(model, params, cfg, prefill_chunk=serve["prefill_chunk"])
+    rows = serve["engine_rows"]
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    out[name + ".decode"] = digest(gen._decode.jitted.lower(params, S((rows, 1), jnp.int32), S((rows,), jnp.int32), [(k, v) for k, v, _ in caches], [S((rows,), jnp.int32) for _ in caches]))
+    out[name + ".chunk"] = digest(gen._chunk_prefill.lower(params, S((1, serve["prefill_chunk"]), jnp.int32), S((1,), jnp.int32), jax.eval_shape(lambda: init_kv_caches(cfg, 1)), S((1, cfg.vocab_size), jnp.bfloat16)))
+print(json.dumps(out))

@@ -234,6 +234,8 @@ GROUPED_CASES = [
      True, 3),
     ("trinity-chunk-down-grad", (8192, 1024, 2048), 128, jnp.bfloat16, True,
      3),
+    ("nemotron-decode-down", (384, 1856, 2688), 8, jnp.bfloat16, False, 1),
+    ("nemotron-chunk-down", (6144, 1856, 2688), 8, jnp.bfloat16, False, 1),
 ]
 
 
@@ -289,6 +291,13 @@ TILING_CASES = [
     ("trinity-chunk-gate-up", (8192, 2048, 2048, 128), (128, 2048, 1024)),
     ("trinity-chunk-down", (8192, 1024, 2048, 128), (128, 1024, 2048)),
     ("toy-rows-no-multiple-of-the-tile", (192, 48, 40, 4), (64, 48, 40)),
+    # a width of 29 x 64 halves to under a lane tile and is walked whole;
+    # 2,688 = 21 lane tiles halves to one and takes seven, or three where
+    # the row tile leaves no room for seven
+    ("nemotron-decode-up", (384, 2688, 1856, 8), (128, 896, 1856)),
+    ("nemotron-decode-down", (384, 1856, 2688, 8), (128, 1856, 896)),
+    ("nemotron-chunk-up", (6144, 2688, 1856, 8), (512, 384, 1856)),
+    ("nemotron-chunk-down", (6144, 1856, 2688, 8), (512, 1856, 384)),
 ]
 
 
@@ -747,3 +756,66 @@ def test_a_mimo_tick_and_chunk_read_what_the_rows_hold_on_v5e(one_chip):
     assert "cached_attention_query_key_blocks" in step.as_text()
     assert not [line for line in step.as_text().splitlines()
                 if " while(" in line and "/attention/" in line]
+
+
+def test_a_nemotron_tick_updates_every_state_in_place_on_v5e(one_chip):
+    """The decode tick of ``nemotron-3-nano-30b-a3b-1chip`` at its
+    published widths, its cell's 64 rows and served context, and ONE
+    period of its depth (``MEMEM*EME``: four Mamba-2 mixers, an attention,
+    four expert layers of this chip's 8 experts 1,856 wide, which the
+    grouped matmul walks whole).  Both states of every ``M`` layer and the
+    attention layer's caches are aliased to the results that replace them,
+    and the tick's temporaries hold no array of an ssm state's shape: 3.1
+    GB of states at full depth move once in and once out, in place."""
+    from alpa_tpu.model.gpt_model import GPTModel, init_kv_caches
+    from alpa_tpu.serve.generation import Generator
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from chipbench import run
+    hf = dict(run.load_json(run.HERE, "configs",
+                            "nemotron-3-nano-30b-a3b-1chip.json"),
+              num_hidden_layers=9)
+    rows, context = hf["serve"]["engine_rows"], hf["serve"]["served_context"]
+    cfg = run.load_module("drivers", "serve_mla").model_config(
+        hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=context)
+    assert cfg.attention.count("ssm") == 4 and cfg.experts_held == (0, 8)
+    model = GPTModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)))
+    gen = Generator(model, params, cfg, prefill_chunk=1024)
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    tick = gen._decode.jitted.lower(
+        params, spec(rows, 1), spec(rows),
+        on_chip([(k, v) for k, v, _ in caches]),
+        [spec(rows) for _ in caches]).compile()
+    memory = tick.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for k, v, _ in caches
+               for x in (k, v))
+    assert held == 4 * rows * (64 * 64 * 128 * 4 + 3 * 6144 * 2) + \
+        rows * context * 2 * 2 * 128 * 2
+    assert memory.alias_size_in_bytes == held
+    assert memory.temp_size_in_bytes < 64 * 2**20
+    text = tick.as_text()
+    head = text[:text.index("\n")]
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         head)
+    # (both arrays of all nine entries, an expert layer's empty ones too)
+    assert len(aliased) == 2 * 9
+    state = r"f32\[%d,64,64,128\]" % rows
+    # an ssm state is a parameter, or comes out of the one fusion that
+    # reads it and writes it; nothing copies, transposes or converts one
+    moved = [line for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose|convert|bitcast-convert)"
+                          r"\(" % state, line)]
+    assert not moved, moved[:3]
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * 4

@@ -189,7 +189,8 @@ def test_what_the_tick_builds_on_is_asked_for_by_name(generators):
 
 @pytest.mark.parametrize("field,value,named", [
     ("attention", ("full", "sliding"), "ring of the sliding window"),
-    ("attention", ("full", "conv"), "short convolution's state")])
+    ("attention", ("full", "conv"), "short convolution's state"),
+    ("attention", ("full", "ssm"), "Mamba-2 mixer's states")])
 def test_a_generator_refuses_to_draft_over_what_no_index_rolls_back(
         field, value, named):
     cfg = GPTConfig(num_layers=2, hidden_size=64, num_heads=4,
