@@ -1930,9 +1930,11 @@ def latent_row_width(rank: int, rope_dim: int) -> int:
     its positions minor-most for the per-row writes and move it whole into
     row order for the gather, two copies of the cache a layer a tick (512
     + 64 channels: 604 MB each at 16 rows of 32,768 positions); rounded
-    up, the writes and the gather share one order
+    up, the writes, the gather and the kernel that reads the rows under a
+    selection's mask share one order
     (``tests/serve/test_decode_in_place.py``).  The channels past the key
-    are never read."""
+    are written as zeros and read only against zeros (that kernel's
+    queries are ``[q_lat | q_pe | 0]``)."""
     return -(-(rank + rope_dim) // LANES) * LANES
 
 
@@ -1941,8 +1943,10 @@ def update_latent_index_cache(kv_cache, c, k_pe, k_index):
     positions: ``kv_cache`` is ``(rows (B, S, latent_row_width), keys (B,
     S, di), index)``.  A position's normed latent and rotated shared key
     lie side by side in one row of ``rows`` (``[c | k_pe | unused]``), so
-    that a decode fetches a selected position with one gather of one row;
-    its index key is a row of ``keys``.  The ``s`` new positions ``c`` (B,
+    that a decode fetches a selected position with one gather of one row,
+    or scores a block of positions with one product against the rows as
+    they lie (``latent_attention_over_selection``); its index key is a row
+    of ``keys``.  The ``s`` new positions ``c`` (B,
     s, r), ``k_pe`` (B, s, dr), ``k_index`` (B, s, di) are written at
     ``index`` (a row whose write does not fit stays as it was); returns
     the entry with ``index + s``."""
@@ -2116,6 +2120,28 @@ def selected_positions(scores, k: int):
             chosen.sum(-1, dtype=jnp.int32).reshape(scores.shape[:-1]))
 
 
+def mask_of(positions, real, n: int):
+    """(R, n) int8: ``positions_of`` inverted.  The positions the first
+    ``real`` (R,) slots of the table ``positions`` (R, k) name are 1, every
+    other of the ``n`` is 0, whatever the later slots name.  No scatter:
+    a slot's two one-hots, its block of ``LANES`` positions ((R, k, n /
+    LANES); a slot at or past ``real`` has none) and its place inside ((R,
+    k, LANES)), and one product of them over the slots, the size of
+    ``positions_of``'s own."""
+    r, k = positions.shape
+    blocks = -(-n // LANES)
+    live = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1) < real[:, None]
+    block = jnp.where(live, positions // LANES, blocks)
+    in_block = block[..., None] == jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, blocks), 2)
+    place = (positions % LANES)[..., None] == jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, LANES), 2)
+    held = jnp.einsum("rkb,rkj->rbj", in_block.astype(jnp.bfloat16),
+                      place.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return (held > 0).reshape(r, blocks * LANES)[:, :n].astype(jnp.int8)
+
+
 def _latent_attention_masked(q_nope, q_pe, c, k_pe, w_kv_b, seen, *, scale):
     """Latent attention in its published form over the keys ``seen`` ((1
     or B, Sq, Sk) bool) says, all keys at once: ``c`` (B, Sk, r) expanded
@@ -2178,11 +2204,12 @@ def latent_attention_selected(q_nope, q_pe, rows, w_kv_b, scale, offset,
 
 def few_queries(config, queries: int, index) -> bool:
     """Whether a cached call of ``queries`` new positions a row at the
-    cache index ``index`` is a DECODE to a selecting layer, which gathers
-    each query's selection: one query a row, or, at per-row indices, up to
-    one more a multi-token-prediction module (the verify of a tick that
-    drafts).  Anything else is a prefill's chunk, which walks the cache
-    under the selection's mask."""
+    cache index ``index`` is a DECODE to a selecting layer, which makes
+    each query a table of its selection and attends over that
+    (``latent_attention_over_selection``): one query a row, or, at per-row
+    indices, up to one more a multi-token-prediction module (the verify of
+    a tick that drafts).  Anything else is a prefill's chunk, which walks
+    the cache under the selection's mask with its many queries a block."""
     return queries == 1 or (
         queries <= 1 + config.num_nextn_predict_layers and
         jnp.ndim(index) == 1)
@@ -2207,6 +2234,93 @@ def latent_attention_gathered(q_nope, q_pe, rows, w_kv_b, scale, positions,
         taken[..., rank:rank + dr].swapaxes(1, 2), w_kv_b, scale,
         real.reshape(b * s) - 1)
     return out.reshape((b, s) + out.shape[2:])
+
+
+# What the gather of one (row, query)'s selected rows is worth, in key
+# blocks of ``ops/latent_attention.py``'s ``DECODE_BLOCK_K`` positions read
+# under the selection's mask instead.  ``scripts/time_dsa_parts.py --cores``
+# on a v5e (PERF.md, PR 59), 16 rows all holding 2,048 positions up to the
+# whole cache: under the mask 0.298 ms + 2.02 us a key block held at
+# dots3-note's shape (one query of 128 heads, 32,768 positions) and 0.249
+# ms + 2.01 us at GLM-5's (two queries of 64 heads, 24,576), the fixed part
+# the grid's steps that skip (0.36 us each) and the table's mask; gathered
+# 0.865 ms and 1.624 ms whatever the rows hold, 54 and 51 us a (row,
+# query).  That is 17.6 and 21.4 key blocks a (row, query): the lower.
+GATHER_WORTH_KEY_BLOCKS = 18
+
+
+def latent_attention_over_selection(q_nope, q_pe, rows, w_kv_b, scale, index,
+                                    positions, real, interpret=False):
+    """A DECODE's queries (``few_queries``: (B, s, H, .), the first of a
+    row at ``index`` (B,)) of a selecting layer, each over the selection
+    ``selected_positions`` made for it: ``positions`` (B, s, k) into the
+    cached ``rows`` (B, Sk, ``latent_row_width``), the first ``real`` (B,
+    s) of each real; (B, s, H, dv).
+
+    Two cores, one result but for the order of a sum.  ``gathered``
+    (``latent_attention_gathered``) copies each query's selected rows out
+    of the cache and runs the absorbed core over the copy: a fixed price a
+    (row, query), whatever the row holds.  ``under_mask`` turns the table
+    back into the mask it was compacted from (``mask_of``) and runs
+    ``ops/latent_attention.py`` ``absorbed_under_mask`` over the cache as it
+    lies: no copy, a row's key blocks read once for all its queries, as
+    far as the row has written; its price is the key blocks the rows hold.
+    A (row, query)'s gather is worth ``GATHER_WORTH_KEY_BLOCKS`` of them,
+    so in a program lowered for a TPU, over shapes the kernel takes: a
+    cache of no more than that many blocks a query goes ``under_mask``
+    whatever it holds, and the program has no gather; a longer one
+    ``by_held``, a ``lax.cond`` on the blocks this call's rows hold, summed,
+    against that price for all rows and queries.  Anywhere else:
+    ``gathered``.  The gauge ``alpa_selecting_decode_core`` says at trace
+    time which.  ``interpret``: the kernel interpreted, whatever the
+    platform (the tests)."""
+    from alpa_tpu.ops import latent_attention as kernel
+    b, s, nh, dn = q_nope.shape
+    sk, rank = rows.shape[1], w_kv_b.shape[0]
+    index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
+
+    def gathered(q_nope, q_pe, rows, w_kv_b, index, positions, real):
+        return latent_attention_gathered(q_nope, q_pe, rows, w_kv_b, scale,
+                                         positions, real)
+
+    def under_mask(q_nope, q_pe, rows, w_kv_b, index, positions, real):
+        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kv_b[..., :dn])
+        chosen = mask_of(positions.reshape(b * s, -1), real.reshape(b * s),
+                         sk).reshape(b, s, sk)
+        o_lat = kernel.absorbed_under_mask(q_lat, q_pe, rows, chosen, index,
+                                           scale=scale, interpret=interpret)
+        return jnp.einsum("bqhr,rhd->bqhd", o_lat, w_kv_b[..., dn:])
+
+    def by_held(q_nope, q_pe, rows, w_kv_b, index, positions, real):
+        return jax.lax.cond(
+            kernel.decode_blocks(index, s, sk).sum() <=
+            b * s * GATHER_WORTH_KEY_BLOCKS, under_mask, gathered,
+            q_nope, q_pe, rows, w_kv_b, index, positions, real)
+
+    if not kernel.under_mask_fits(q_pe, rows, rank):
+        core = gathered
+    elif sk // kernel.DECODE_BLOCK_K <= s * GATHER_WORTH_KEY_BLOCKS:
+        core = under_mask
+    else:
+        core = by_held
+    _selecting_core_gauge().labels(core.__name__, nh, s, sk).inc()
+    args = (q_nope, q_pe, rows, w_kv_b, index, positions, real)
+    if core is gathered or interpret:
+        return core(*args)
+    return jax.lax.platform_dependent(*args, tpu=core, default=gathered)
+
+
+def _selecting_core_gauge():
+    return tmetrics.get_registry().gauge(
+        "alpa_selecting_decode_core",
+        "selecting latent layers whose decode was traced with each core "
+        "(under_mask: where lowered for a TPU, the kernel that reads each "
+        "row's cache as it lies under the selection's mask, no gather in "
+        "the program; by_held: where lowered for a TPU, that kernel or the "
+        "gather by the key blocks the call's rows hold; gathered: a copy "
+        "of the selected rows and the absorbed core over it), by the "
+        "heads, the queries a row and the cache's positions",
+        ("core", "heads", "queries", "positions"))
 
 
 def head_gates(logits):
@@ -2252,16 +2366,18 @@ class LatentAttention(nn.Module):
     ``x``) scores every earlier position for every query (``index_scores``),
     and the softmax and the values go over the ``index_topk`` best alone.
     Its cache is ``(rows, index keys, index)``
-    (``update_latent_index_cache``).  A decode takes the selected
-    positions' rows out of the cache (``selected_positions``: a table in
-    ascending position, made with no sort; one gather) and runs the
-    absorbed core over those alone; a chunk, and a call without a cache, the
-    expanded form under the selection's mask
+    (``update_latent_index_cache``).  A decode names the selected
+    positions (``selected_positions``: a table in ascending position, made
+    with no sort) and runs the absorbed core over those alone
+    (``latent_attention_over_selection``: over a copy of their rows, one
+    gather, or over the cache as it lies under the mask the table came
+    from, by what the cache can hold and the rows do hold); a chunk, and a
+    call without a cache, the expanded form under the selection's mask
     (``latent_attention_selected``).  A decode of a FEW queries a row (a
-    verify of a tick that drafts: ``few_queries``) scores, chooses and
-    gathers for each query by itself and runs the absorbed core a query
-    over its own rows (``latent_attention_gathered``); one query a row is
-    the program it was.  With ``return_selected`` the third result is what
+    verify of a tick that drafts: ``few_queries``) scores and chooses for
+    each query by itself and takes the same function, a row's queries
+    together; one query a row is that at one.  With ``return_selected``
+    the third result is what
     a decode selected: ``(positions (B, index_topk), how many of them are
     real (B,))``, of a few queries ``((B, s, index_topk), (B, s))``.  The
     indexer's rotary pairs are rotate-half, or interleaved where
@@ -2377,19 +2493,15 @@ class LatentAttention(nn.Module):
                         chosen = selected_mask_upto(
                             scores, cfg.index_topk, jnp.max(index) + s)
                 with jax.named_scope(SELECT_SCOPE):
-                    if few:
-                        out = latent_attention_gathered(
-                            q_nope, q_pe, rows, w_kv_b, scale, *selected)
-                    elif s == 1:
-                        # the selected positions' rows, in ascending
-                        # position, and the core over those alone: the real
-                        # ones are the first ``selected[1]``
-                        taken = jnp.take_along_axis(
-                            rows, selected[0][:, :, None], axis=1)
-                        out = latent_attention_absorbed(
-                            q_nope, q_pe, taken[..., :rank],
-                            taken[..., rank:rank + dr].swapaxes(1, 2),
-                            w_kv_b, scale, selected[1] - 1)
+                    if few or s == 1:
+                        # a decode: over each query's table of positions,
+                        # in ascending position, the first ``real`` real
+                        positions, real = selected
+                        if s == 1:
+                            positions, real = positions[:, None], real[:, None]
+                        out = latent_attention_over_selection(
+                            q_nope, q_pe, rows, w_kv_b, scale, index,
+                            positions, real)
                     else:
                         out = latent_attention_selected(
                             q_nope, q_pe, rows, w_kv_b, scale, index,

@@ -59,9 +59,16 @@ queries with a block of the row's keys, reduced over the heads in the
 kernel (a few queries a row, a verify of a tick that drafts: the same
 grid, the block fetched once and scored a query in turn).  Key blocks past a row's last query are filled with ``-inf`` and
 not fetched.  A chunk then runs ``expanded`` with the selection as one more
-operand, a block of (queries, keys) int8 a step; a decode gathers the
-selected rows and runs ``absorbed`` over them as over a cache of 2,048
-positions, the real ones first.
+operand, a block of (queries, keys) int8 a step.  A decode (one query a
+row, or a verify's few) has two cores (``gpt_model.
+latent_attention_over_selection`` says which a call takes): it gathers each
+query's selected rows and runs ``absorbed`` over the copy as over a cache
+of 2,048 positions, the real ones first, at a fixed price a (row, query)
+(84 MB of copies a GLM-5 block at a tenth of the chip's bandwidth, PERF.md,
+PR 54); or it runs ``absorbed_under_mask`` over the cache as it lies, the
+selection one more operand, a block of (queries, keys) int8 a step: no
+copy, a row's key blocks read once for all its queries and heads and only
+as far as the row has written, a price by what the rows hold.
 
 The kernels are compiled where the program is lowered for a TPU
 (``gpt_model`` chooses between each and its ``jax.numpy`` twin with
@@ -86,6 +93,8 @@ DECODE_BLOCK_K = 1024
 # queries, the scores and their exponentials, two blocks in flight
 VMEM_LIMIT = 48 * 2**20
 _FLOOR = -1e30
+# what a device trace calls the decode's kernel under a selection's mask
+UNDER_MASK_NAME = "latent_decode_under_mask"
 
 
 def fits(q_nope, c, w_kv_b) -> bool:
@@ -307,6 +316,110 @@ def absorbed(q_lat, q_pe, c, k_pe, index, *, scale: float,
         interpret=interpret,
     )(blocks, index, q_lat[:, 0], q_pe[:, 0], c, k_pe)
     return out[:, None]
+
+
+# --- a decode over a selection, the cache read as it lies ---
+
+def under_mask_fits(q_pe, rows, rank: int) -> bool:
+    """Whether ``absorbed_under_mask`` takes these shapes (``q_pe`` (B, s,
+    H, dr), the cache's ``rows`` (B, Sk, width), a latent of ``rank``
+    channels): a few queries a row, the latent and the cache's rows in
+    whole lanes, the latent and the key inside a row, the heads in whole
+    sublanes, the cache in whole key blocks."""
+    queries, heads, dr = q_pe.shape[1:]
+    sk, width = rows.shape[1:]
+    return (queries <= INDEX_FEW_Q and rank % 128 == 0 and
+            heads % 16 == 0 and width % 128 == 0 and rank + dr <= width and
+            sk % DECODE_BLOCK_K == 0)
+
+
+def decode_blocks(index, queries: int, sk: int):
+    """(B,) int32: the key blocks of ``DECODE_BLOCK_K`` that the last of a
+    row's ``queries`` new positions, the first at ``index``, reaches into."""
+    return jnp.clip((index.astype(jnp.int32) + queries - 1) // DECODE_BLOCK_K
+                    + 1, 1, sk // DECODE_BLOCK_K)
+
+
+def _under_mask_kernel(blocks_ref, q_ref, rows_ref, sel_ref, o_ref, m_ref,
+                       l_ref, acc_ref, *, scale: float, rank: int):
+    b, kb = pl.program_id(0), pl.program_id(1)
+    queries, block_k = sel_ref.shape
+    heads = q_ref.shape[0] // queries
+    pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+
+    @pl.when(kb < blocks_ref[b])
+    def _block():
+        held = rows_ref[:]
+        # [q_lat | q_pe | 0] against [c | k_pe | spare]: one product
+        s = scale * lax.dot_general(q_ref[:], held, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        chosen = sel_ref[:].astype(jnp.int32) != 0
+        seen = jnp.concatenate(
+            [jnp.broadcast_to(chosen[i:i + 1], (heads, block_k))
+             for i in range(queries)], axis=0)
+        _fold_in(s, seen, held[:, :rank], m_ref, l_ref, acc_ref)
+
+    pl.when(kb == pl.num_programs(1) - 1)(
+        lambda: _finish(o_ref, l_ref, acc_ref))
+
+
+def absorbed_under_mask(q_lat, q_pe, rows, selected, index, *, scale: float,
+                        interpret: bool = False):
+    """A few queries a row, each over a selection of its own, with no copy
+    of what it selected: ``q_lat`` (B, s, H, r) and ``q_pe`` (B, s, H, dr)
+    against a selecting layer's cache ``rows`` (B, Sk, width), a row ``[c
+    | k_pe | spare]`` as ``gpt_model.update_latent_index_cache`` writes it,
+    under ``selected`` ((B, s, Sk) int8: of the positions at or before
+    each query, those it attends over); the first of row ``b``'s queries
+    sits at ``index[b]``.  Returns the probabilities' weighted latents (B,
+    s, H, r), for ``W_uv`` to expand.
+
+    Grid ``(rows, key blocks)`` as ``absorbed``'s: a program holds ALL of
+    its row's queries, ``(s H, width)`` as ``[q_lat | q_pe | zeros]``, so a
+    step's scores are one product against the block of the cache as it
+    lies, fetched once for every query and head; the block's mask goes
+    beside it, ``(s, block_k)``, and the values are the block's first
+    ``r`` channels.  Steps past the row's newest position fetch and
+    compute nothing."""
+    b, queries, nh, rank = q_lat.shape
+    sk, width = rows.shape[1:]
+    nk = sk // DECODE_BLOCK_K
+    q = jnp.concatenate(
+        [q_lat, q_pe, jnp.zeros(
+            q_lat.shape[:3] + (width - rank - q_pe.shape[3],), q_lat.dtype)],
+        axis=-1).reshape(b, queries * nh, width)
+
+    def per_row(b_, kb, blocks_ref):
+        return b_, 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_under_mask_kernel, scale=scale, rank=rank),
+        out_shape=jax.ShapeDtypeStruct((b, queries * nh, rank), q_lat.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, nk),
+            in_specs=[
+                pl.BlockSpec((None, queries * nh, width), per_row),
+                pl.BlockSpec(
+                    (None, DECODE_BLOCK_K, width),
+                    lambda b_, kb, blocks_ref:
+                    (b_, _block_of(b_, kb, blocks_ref), 0)),
+                pl.BlockSpec(
+                    (None, queries, DECODE_BLOCK_K),
+                    lambda b_, kb, blocks_ref:
+                    (b_, 0, _block_of(b_, kb, blocks_ref))),
+            ],
+            out_specs=pl.BlockSpec((None, queries * nh, rank), per_row),
+            scratch_shapes=[pltpu.VMEM((queries * nh, 1), jnp.float32),
+                            pltpu.VMEM((queries * nh, 1), jnp.float32),
+                            pltpu.VMEM((queries * nh, rank), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=UNDER_MASK_NAME,
+    )(decode_blocks(index, queries, sk), q, rows, selected)
+    return out.reshape(b, queries, nh, rank)
 
 
 # --- the indexer's scores (a layer that selects its positions) ---

@@ -5,6 +5,7 @@ twin there:
 
     chiprun -- python3 scripts/time_dsa_parts.py [--held 8192 32768]
     chiprun -- python3 scripts/time_dsa_parts.py --selection
+    chiprun -- python3 scripts/time_dsa_parts.py --cores [--held 2048 8192]
 
 One JSON line a part: ``ms`` (the median of ``--repeat`` runs that end in
 ``block_until_ready``) and, where the part has a twin, ``max_diff`` against
@@ -22,6 +23,20 @@ rows and the static leading part that holds them alone, ``selected_positions``
 and its parts (the counts; the compaction as
 shipped, its block's counts fetched by a one-hot product; the same with a row
 gather in the product's place), each compared with ``top_k`` as sets.
+
+``--cores``: the two cores of a selecting layer's DECODE
+(``gpt_model.latent_attention_over_selection``) at both selecting cells'
+shapes (dots3-note: 16 rows, one query of 128 heads over 32,768 positions;
+GLM-5: 16 rows, two queries of 64 heads over 24,576), every row holding
+``held`` positions, ``--held`` from 2,048 to the whole cache: the gather of
+each query's selected rows with the absorbed core over the copy, whose price
+does not depend on what the rows hold, beside the table turned back into its
+mask (``mask_of``) and the kernel that reads the cache as it lies under it
+(``absorbed_under_mask``), whose price is the key blocks the rows hold; each
+against the other (``max_diff``).  The last line of a shape is the fit that
+``gpt_model.GATHER_WORTH_KEY_BLOCKS`` quotes: a (row, query)'s gather in key
+blocks under the mask.  ``--tiny`` rehearses the same on a CPU at toy widths,
+the kernel interpreted.
 """
 import argparse
 from functools import partial
@@ -30,6 +45,7 @@ import os
 import statistics
 import sys
 import time
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +66,10 @@ def rnd(i, *shape, dtype=jnp.bfloat16):
                              jnp.float32).astype(dtype)
 
 
-def timed(name, fn, *args, repeat, twin=None, inner=1, **more):
+def timed(name, fn, *args, repeat, twin=None, inner=1, lines=None, **more):
     """``inner``: calls dispatched back to back before the one wait, so
-    that a part of well under a millisecond is not the host's dispatch."""
+    that a part of well under a millisecond is not the host's dispatch.
+    ``lines``: a list the printed line is appended to."""
     fn = jax.jit(fn)
     out = jax.block_until_ready(fn(*args))
     times = []
@@ -71,6 +88,8 @@ def timed(name, fn, *args, repeat, twin=None, inner=1, **more):
             seen, out.astype(jnp.float32) - want.astype(jnp.float32),
             0.0)).max())
         line["same_unseen"] = bool((jnp.isfinite(out) == seen).all())
+    if lines is not None:
+        lines.append(line)
     print(json.dumps(line), flush=True)
     return out
 
@@ -162,6 +181,81 @@ def selection(repeat):
                 same(name.strip(), table.reshape(got.shape), real)
 
 
+def cores(repeat, helds, tiny):
+    """A decode's two cores over one selection (module docstring)."""
+    time_part = partial(timed, repeat=repeat, inner=1 if tiny else 10)
+    block_k = la.DECODE_BLOCK_K
+    shapes = [(16, 1, 128, 32768), (16, 2, 64, 24576)]
+    rank, dn, dv, topk = RANK, DN, DV, TOPK
+    if tiny:
+        shapes, rank, dn, dv, topk = [(2, 2, 16, 4096)], 128, 128, 128, 256
+        helds = [1024, 4096]
+    width = gm.latent_row_width(rank, DR)
+    for rows, queries, heads, context in shapes:
+        cache = rnd(1, rows, context, width)
+        w_kv_b = rnd(2, rank, heads, dn + dv) * rank ** -0.5
+        q_nope = rnd(5, rows, queries, heads, dn)
+        q_pe = rnd(6, rows, queries, heads, DR)
+        shape = {"shape": [rows, queries, heads, context]}
+        points = []
+        for held in [h for h in helds if topk <= h < context] + [context]:
+            index = jnp.full((rows,), held - queries, jnp.int32)
+            q_pos = index[:, None] + jnp.arange(queries)[None]
+            scores = jnp.where(
+                jnp.arange(context)[None, None] <= q_pos[..., None],
+                rnd(11, rows, queries, context, dtype=jnp.float32), -jnp.inf)
+            positions, real = jax.jit(
+                lambda s: gm.selected_positions(s, topk))(scores)
+            more = dict(shape, held=held,
+                        key_blocks=rows * -(-held // block_k))
+            chosen = time_part(
+                "decode mask_of (the table back to its mask)",
+                lambda p, r: gm.mask_of(p.reshape(rows * queries, topk),
+                                        r.reshape(rows * queries), context),
+                positions, real, **more)
+            chosen = chosen.reshape(rows, queries, context)
+            q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kv_b[..., :dn])
+            time_part("decode absorbed_under_mask, the kernel alone",
+                      partial(la.absorbed_under_mask, scale=SCALE,
+                              interpret=tiny),
+                      q_lat, q_pe, cache, chosen, index, **more)
+            args = (q_nope, q_pe, cache, w_kv_b, index, positions, real)
+
+            def under_mask(qn, qp, c, w, i, p, r):
+                # the core a cache of few blocks takes whatever it holds
+                with mock.patch.object(gm, "GATHER_WORTH_KEY_BLOCKS",
+                                       context):
+                    return gm.latent_attention_over_selection(
+                        qn, qp, c, w, SCALE, i, p, r, interpret=tiny)
+
+            def gathered(qn, qp, c, w, i, p, r):
+                return gm.latent_attention_gathered(qn, qp, c, w, SCALE, p, r)
+
+            whole = []
+            time_part("decode under the mask, whole", under_mask, *args,
+                      twin=gathered, lines=whole, **more)
+            time_part("decode gather + absorbed, whole", gathered, *args,
+                      lines=whole, **more)
+            points.append((more["key_blocks"], whole[0]["ms"],
+                           whole[1]["ms"]))
+        # under the mask: ms = fixed + a_block * key blocks (least squares)
+        n = len(points)
+        mean_x = sum(p[0] for p in points) / n
+        mean_y = sum(p[1] for p in points) / n
+        a_block = sum((p[0] - mean_x) * (p[1] - mean_y) for p in points) / \
+            max(sum((p[0] - mean_x) ** 2 for p in points), 1e-9)
+        fixed = mean_y - a_block * mean_x
+        gather = statistics.median(p[2] for p in points)
+        print(json.dumps(dict(
+            shape, part="a (row, query)'s gather in key blocks under the mask",
+            under_mask_fixed_ms=round(fixed, 4),
+            under_mask_us_a_key_block=round(1e3 * a_block, 3),
+            gathered_ms=gather,
+            worth_key_blocks=round(
+                (gather - fixed) / max(a_block, 1e-9) / (rows * queries),
+                1))), flush=True)
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--held", type=int, nargs="+",
@@ -169,10 +263,18 @@ def main():
     parser.add_argument("--repeat", type=int, default=10)
     parser.add_argument("--selection", action="store_true",
                         help="a decode's selection alone, both shapes")
+    parser.add_argument("--cores", action="store_true",
+                        help="a decode's two cores over one selection, both "
+                        "shapes")
+    parser.add_argument("--tiny", action="store_true",
+                        help="with --cores: toy widths, the kernel "
+                        "interpreted (a CPU rehearsal)")
     args = parser.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     if args.selection:
         return selection(args.repeat)
+    if args.cores:
+        return cores(args.repeat, args.held, args.tiny)
     keys = rnd(0, ROWS, CONTEXT, DI)
     rows = rnd(1, ROWS, CONTEXT, 640)
     w_kv_b = rnd(2, RANK, HEADS, DN + DV) * RANK ** -0.5

@@ -49,3 +49,57 @@ def catalog_row():
             return next(row for row in map(json.loads, f)
                         if row["name"] == name)
     return row
+
+
+@pytest.fixture
+def selecting_decode():
+    """``case(queries, heads, dn, dv, starts, seed)``: one selecting
+    layer's DECODE at widths ``ops/latent_attention.py``
+    ``absorbed_under_mask`` takes (a latent of 128 and a rotary key of 64
+    in rows of 256 channels, a cache of 4,096 positions, 64 selected), in
+    float32, the rows' first new positions ``starts``: the arguments of
+    ``gpt_model.latent_attention_over_selection`` after its ``scale``, and
+    what ``latent_attention_gathered`` makes of the same cache and
+    table."""
+    import jax
+    import jax.numpy as jnp
+    from alpa_tpu.model import gpt_model
+
+    def case(queries, heads, dn, dv, starts, seed=0):
+        rank, dr, sk, topk = 128, 64, 4096, 64
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 5))
+
+        def rnd(*shape):
+            return jax.random.normal(next(keys), shape, jnp.float32)
+
+        b = len(starts)
+        index = jnp.asarray(starts, jnp.int32)
+        q_pos = index[:, None] + jnp.arange(queries)[None]
+        scores = jnp.where(jnp.arange(sk)[None, None] <= q_pos[..., None],
+                           rnd(b, queries, sk), -jnp.inf)
+        positions, real = gpt_model.selected_positions(scores, topk)
+        q_nope, q_pe = rnd(b, queries, heads, dn), rnd(b, queries, heads, dr)
+        rows = rnd(b, sk, gpt_model.latent_row_width(rank, dr))
+        w_kv_b = rnd(rank, heads, dn + dv) * rank ** -0.5
+        scale = (dn + dr) ** -0.5
+        want = gpt_model.latent_attention_gathered(
+            q_nope, q_pe, rows, w_kv_b, scale, positions, real)
+        return (q_nope, q_pe, rows, w_kv_b, scale, index, positions,
+                real), want
+    return case
+
+
+@pytest.fixture
+def selecting_cores_traced():
+    """``traced(queries)``: {core: count} of the gauge
+    ``alpa_selecting_decode_core`` over ``selecting_decode``'s cache, for
+    a decode of ``queries`` a row."""
+    from alpa_tpu.telemetry import metrics as tmetrics
+
+    def traced(queries):
+        return {key.split('core="')[1].split('"')[0]: value
+                for key, value in tmetrics.get_registry().snapshot().items()
+                if key.startswith("alpa_selecting_decode_core") and
+                'queries="%d"' % queries in key and
+                'positions="4096"' in key}
+    return traced

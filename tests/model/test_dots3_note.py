@@ -607,3 +607,44 @@ def test_driver_fails_a_control(toy_context, monkeypatch, control,
     checks = obs["checks"]
     assert obs["failed"] == 0 and checks["checked_requests"] == 4
     assert not obs["correct"], checks
+
+
+# ---- the decode under its selection's mask (ISSUE 59) ----------------------
+
+def test_the_decode_under_the_mask_is_the_gathered_decode(
+        selecting_decode, selecting_cores_traced):
+    """What the ``selects`` branch calls for a decode
+    (``latent_attention_over_selection``) at widths the kernel takes
+    (dots3-note's: one query a row, keys of 128 + 64 and values of 128),
+    the kernel interpreted: the cache of four key blocks goes
+    under the mask whatever its rows hold, and gives what
+    ``latent_attention_gathered`` gives of the same cache and table."""
+    args, want = selecting_decode(1, 32, 128, 128, [37, 1023, 2500])
+    before = selecting_cores_traced(1).get("under_mask", 0)
+    got = gpt_model.latent_attention_over_selection(*args, interpret=True)
+    assert selecting_cores_traced(1)["under_mask"] == before + 1
+    assert got.shape == want.shape == (3, 1, 32, 128)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("starts,under_mask", [([100, 900], True),
+                                               ([100, 3500], False)],
+                         ids=["rows-that-hold-little", "rows-that-hold-much"])
+def test_the_rule_by_what_the_rows_hold_has_one_answer(
+        selecting_decode, selecting_cores_traced, monkeypatch, starts,
+        under_mask):
+    """A cache longer than a query's gather is worth (here one key block
+    of 1,024 positions a (row, query): four blocks are too many to take
+    statically) is read ``by_held``: under the mask while the call's rows
+    hold no more key blocks than their gathers are worth, gathered beyond;
+    one result on both sides."""
+    from alpa_tpu.ops import latent_attention as kernels
+    monkeypatch.setattr(gpt_model, "GATHER_WORTH_KEY_BLOCKS", 1)
+    args, want = selecting_decode(1, 32, 128, 128, starts, seed=1)
+    held = int(kernels.decode_blocks(args[5], 1, 4096).sum())
+    assert (held <= len(starts) * 1) == under_mask
+    before = selecting_cores_traced(1).get("by_held", 0)
+    got = jax.jit(lambda *a: gpt_model.latent_attention_over_selection(
+        *a[:4], args[4], *a[4:], interpret=True))(*args[:4], *args[5:])
+    assert selecting_cores_traced(1)["by_held"] == before + 1
+    np.testing.assert_allclose(got, want, atol=1e-5)

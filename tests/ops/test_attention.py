@@ -537,3 +537,113 @@ def test_chunk_attention_fits(what, q_shape, k_shape, v_shape, takes):
     # the two kernels never take the same call
     assert not (takes and (ca.folded_fits(q, k, v) if len(k_shape) == 3
                            else ca.fits(q, k)))
+
+
+# ---- a selecting decode under its selection's mask (ISSUE 59) -------------
+
+SELECT_BLOCK_K, SELECT_SEQ, SELECT_TOPK = 128, 1024, 48
+# (id, queries a row, the rows' first new positions, what else): the kernel's
+# key blocks are of 128 positions here, the cache of eight
+UNDER_MASK_CASES = [
+    ("rows-of-unlike-lengths", 1, [40, 300, 777], None),
+    ("rows-of-unlike-lengths-two-queries", 2, [40, 300, 777], None),
+    ("a-row-holds-fewer-than-topk", 1, [10, 500], None),
+    ("a-row-holds-fewer-than-topk-two-queries", 2, [10, 500], None),
+    # the last query at a block's last position, and at the next one's first
+    ("a-length-ends-on-a-blocks-edge", 1, [255, 256, 1023], None),
+    ("a-length-ends-on-a-blocks-edge-two-queries", 2, [254, 255, 1022], None),
+    ("a-block-with-no-selected-position", 1, [600, 900], "a-block-unselected"),
+    ("a-block-with-no-selected-position-two-queries", 2, [600, 900],
+     "a-block-unselected"),
+    # ``chipbench/controls_glm5.py`` ``second_query_reuses_first``
+    ("a-table-shared-by-both-queries", 2, [40, 300, 777], "shared"),
+]
+
+
+@pytest.mark.parametrize("queries,starts,what",
+                         [c[1:] for c in UNDER_MASK_CASES],
+                         ids=[c[0] for c in UNDER_MASK_CASES])
+def test_a_decode_under_the_mask_is_the_gather(monkeypatch, queries, starts,
+                                               what):
+    """``ops/latent_attention.py`` ``absorbed_under_mask`` (interpreted)
+    over the mask ``mask_of`` makes of ``selected_positions``'s table,
+    against the copy it replaces: ``latent_attention_gathered`` at two
+    queries a row, the one-query gather as ``LatentAttention`` wrote it
+    out until ISSUE 59 at one.  What the cache holds past a row's newest
+    position is large and finite and reaches no output."""
+    from alpa_tpu.model import gpt_model as gm
+    from alpa_tpu.ops import latent_attention as la
+    monkeypatch.setattr(la, "DECODE_BLOCK_K", SELECT_BLOCK_K)
+    heads, rank, dr, dn, dv = 16, 128, 64, 64, 32
+    b, sk, k = len(starts), SELECT_SEQ, SELECT_TOPK
+    rng = np.random.default_rng(queries + sum(starts))
+    index = jnp.asarray(starts, jnp.int32)
+    q_pos = index[:, None] + jnp.arange(queries)[None]
+    scores = rng.normal(size=(b, queries, sk)).astype(np.float32)
+    if what == "a-block-unselected":
+        scores[:, :, 2 * SELECT_BLOCK_K:3 * SELECT_BLOCK_K] -= 100.0
+    scores = jnp.where(jnp.arange(sk)[None, None] <= q_pos[..., None],
+                       scores, -jnp.inf)
+    positions, real = gm.selected_positions(scores, k)
+    if what == "shared":
+        positions = jnp.broadcast_to(positions[:, :1], positions.shape)
+        real = jnp.broadcast_to(real[:, :1], real.shape)
+    chosen = gm.mask_of(positions.reshape(b * queries, k),
+                        real.reshape(b * queries), sk).reshape(b, queries, sk)
+    if what == "a-block-unselected":
+        assert not np.asarray(chosen)[
+            :, :, 2 * SELECT_BLOCK_K:3 * SELECT_BLOCK_K].any()
+    if what is None:
+        assert (np.asarray(chosen) != 0).tolist() == np.asarray(
+            gm.selected_mask(scores, k)).tolist()
+    written = np.arange(sk)[None, :, None] < (
+        np.asarray(index) + queries)[:, None, None]
+    cache = jnp.asarray(np.where(
+        written, rng.normal(size=(b, sk, gm.latent_row_width(rank, dr))),
+        rng.choice([-3e4, 3e4], size=(b, sk, 1))), jnp.float32)
+    q_nope = jnp.asarray(rng.normal(size=(b, queries, heads, dn)),
+                         jnp.float32)
+    q_pe = jnp.asarray(rng.normal(size=(b, queries, heads, dr)), jnp.float32)
+    w_kv_b = jnp.asarray(rng.normal(size=(rank, heads, dn + dv)) * 0.1,
+                         jnp.float32)
+    q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_kv_b[..., :dn])
+    assert la.under_mask_fits(q_pe, cache, rank)
+    got = jnp.einsum("bqhr,rhd->bqhd", la.absorbed_under_mask(
+        q_lat, q_pe, cache, chosen, index, scale=0.09, interpret=True),
+        w_kv_b[..., dn:])
+    if queries == 1:
+        taken = jnp.take_along_axis(cache, positions[:, 0, :, None], axis=1)
+        want = gm.latent_attention_absorbed(
+            q_nope, q_pe, taken[..., :rank],
+            taken[..., rank:rank + dr].swapaxes(1, 2), w_kv_b, 0.09,
+            real[:, 0] - 1)
+    else:
+        want = gm.latent_attention_gathered(q_nope, q_pe, cache, w_kv_b,
+                                            0.09, positions, real)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("n,k,held", [
+    (1024, 48, [0, 1, 47, 48]), (1024, 48, [48, 48, 48]),
+    (4096, 300, [5, 299, 300, 300]), (200, 16, [0, 7, 16])],
+    ids=["real-below-k", "every-slot-real", "blocks-of-many-lanes",
+         "positions-in-no-whole-lanes"])
+def test_the_mask_of_a_table_is_the_mask_it_was_compacted_from(n, k, held):
+    """``gpt_model.mask_of`` inverts ``positions_of``: mask -> table ->
+    mask and table -> mask -> table, a row holding fewer than ``k`` (its
+    later slots name a position in range, which the mask must not hold)
+    included."""
+    from alpa_tpu.model import gpt_model as gm
+    rng = np.random.default_rng(n + k)
+    mask = np.zeros((len(held), n), bool)
+    for row, count in zip(mask, held):
+        row[rng.choice(n, size=count, replace=False)] = True
+    real = jnp.asarray(held, jnp.int32)
+    table = gm.positions_of(jnp.asarray(mask), k)
+    back = gm.mask_of(table, real, n)
+    assert back.dtype == jnp.int8 and back.shape == mask.shape
+    assert ((np.asarray(back) != 0) == mask).all()
+    live = np.arange(k)[None] < np.asarray(held)[:, None]
+    assert (np.asarray(gm.positions_of(back != 0, k))[live] ==
+            np.asarray(table)[live]).all()

@@ -375,6 +375,18 @@ SELECTING_CASES = [
       ((1, 24576, 640), jnp.bfloat16), ((1, 64, 24576), jnp.bfloat16),
       ((512, 64, 512), jnp.bfloat16), ((1,), jnp.int32),
       ((1, 1024, 24576), jnp.int8)]),
+    # the decode of both selecting cells under its selection's mask (ISSUE
+    # 59): GLM-5's two queries a row of 64 heads over 24,576 positions and
+    # dots3-note's one of 128 heads over 32,768, the cache's rows of 640
+    # channels as they lie
+    ("under-the-mask-verify", "absorbed_under_mask",
+     [((16, 2, 64, 512), jnp.bfloat16), ((16, 2, 64, 64), jnp.bfloat16),
+      ((16, 24576, 640), jnp.bfloat16), ((16, 2, 24576), jnp.int8),
+      ((16,), jnp.int32)]),
+    ("under-the-mask-decode", "absorbed_under_mask",
+     [((16, 1, 128, 512), jnp.bfloat16), ((16, 1, 128, 64), jnp.bfloat16),
+      ((16, 32768, 640), jnp.bfloat16), ((16, 1, 32768), jnp.int8),
+      ((16,), jnp.int32)]),
 ]
 
 
@@ -388,13 +400,21 @@ def test_selecting_latent_kernels_compile_for_v5e(one_chip, kernel, shapes):
             return la.index_scores(*args)
         if kernel == "absorbed":
             return la.absorbed(*args, scale=192 ** -0.5)
+        if kernel == "absorbed_under_mask":
+            return la.absorbed_under_mask(*args, scale=192 ** -0.5)
         *rest, selected = args
         return la.expanded(*rest, scale=192 ** -0.5, selected=selected)
 
     compiled = jax.jit(call).lower(*[
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes]).compile()
-    assert compiled.as_text().count(KERNEL) == 1
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    if kernel == "absorbed_under_mask":
+        # by its name in a device trace, and the cache handed in as it
+        # lies: no array of its size beside it
+        assert "%" + la.UNDER_MASK_NAME in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
 _SELECTING_DECODES = {}
@@ -446,24 +466,53 @@ def _selecting_decode(one_chip, name):
     return _SELECTING_DECODES[name]
 
 
-def test_a_dots3_decode_is_one_query_a_row_as_it_was_on_v5e(one_chip):
+def _moved_whole(text, rows, context):
+    """The instructions of a compiled tick that copy or transpose a
+    selecting layer's whole cache (rows of 640 channels, index keys of
+    128)."""
+    whole = (r"= \S*\[(%d,%d|%d),(640|128)\]\S* "
+             r"(copy|copy-start|transpose)\(") % (rows, context,
+                                                 rows * context)
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(whole, line)]
+
+
+def test_a_dots3_decode_is_one_query_a_row_on_v5e(one_chip):
     """``_decode`` of ``dots3-note-prev-1chip`` (its leading layer, which
-    selects, at the published widths, 16 rows, served context 32,768)
-    after the selecting decode learned to take a few queries a row (PR
-    53): one query a row still runs the one-query index kernel and the
-    absorbed kernel over ONE gathered selection a row, and no array of it
-    has a second query."""
+    selects, at the published widths, 16 rows, served context 32,768) in
+    the form the tick has since ISSUE 59: one query a row runs the
+    one-query index kernel, and ONE core a selecting layer, chosen in the
+    tick by what its rows hold (the cache's 32 key blocks are more than a
+    query's gather is worth): a conditional whose one side is the kernel
+    under the selection's mask over the cache as it lies and whose other
+    is the absorbed kernel over ONE gathered selection a row.  No array of
+    it has a second query, and no whole cache is copied for either
+    side."""
+    from alpa_tpu.model import gpt_model
+    from alpa_tpu.ops import latent_attention as la
     compiled, rows, context = _selecting_decode(one_chip,
                                                 "dots3-note-prev-1chip")
+    assert context // la.DECODE_BLOCK_K > gpt_model.GATHER_WORTH_KEY_BLOCKS
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if KERNEL in line]
     scores = [k for k in kernels if re.search(
         r"= f32\[%d,1,%d\]" % (rows, context), k)]
     cores = [k for k in kernels if re.search(
         r"= bf16\[%d,128,512\]" % rows, k)]
-    assert len(scores) == 1 and len(cores) == 1, (len(scores), len(cores))
-    assert all("bf16[%d,2048,512]" % rows in k for k in cores)
+    assert len(scores) == 1 and len(cores) == 2, (len(scores), len(cores))
+    under_mask = [k for k in cores if "%" + la.UNDER_MASK_NAME in k]
+    gathered = [k for k in cores if k not in under_mask]
+    assert len(under_mask) == 1 and len(gathered) == 1
+    assert "bf16[%d,%d,640]" % (rows, context) in under_mask[0]
+    assert "bf16[%d,2048,512]" % rows in gathered[0]
+    # both under the one conditional of the layer's ``latent_select``
+    assert all("/latent_select/cond/branch_0_fun/cond/branch_" in k
+               for k in cores)
+    assert len(re.findall(r" conditional\(.*/latent_select/", text)) == 1
+    assert len(re.findall(r"= \S*\[%d,2048,640\]\S* gather\(" % rows,
+                          text)) == 1
     assert not re.search(r"\[%d,2,%d\]" % (rows, context), text)
+    assert _moved_whole(text, rows, context) == []
 
 
 # the tick's temporaries at the parent of PR 54, where the selection was a
@@ -478,14 +527,42 @@ def test_a_selecting_decode_sorts_nothing_on_v5e(one_chip, name):
     queries a row, its one layer here and its module; dots3-note: one
     query a row) holds no sort: the 2,048 positions a query attends over
     come from counts and a compaction (``selected_positions``, PR 54);
-    and its temporaries are what they were with the sort, within 0.1
-    GB."""
+    and its temporaries are no more than they were with the sort, within
+    0.1 GB.  GLM-5's fell by more than one block's gathered rows (``bf16[
+    65536,640]``, 84 MB) when its decode stopped gathering (ISSUE 59)."""
     compiled, _, _ = _selecting_decode(one_chip, name)
     text = compiled.as_text()
     assert not re.search(r"\bsort\(", text)
     assert "top_k" not in text.lower()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert abs(temp - SORTED_DECODE_TEMP_BYTES[name]) < 100e6, temp
+    assert temp < SORTED_DECODE_TEMP_BYTES[name] + 100e6, temp
+    if name == "glm-5-1chip":
+        assert temp < SORTED_DECODE_TEMP_BYTES[name] - 65536 * 640 * 2, temp
+
+
+def test_a_glm5_tick_gathers_no_selected_rows_on_v5e(one_chip):
+    """The tick of ``glm-5-1chip`` that verifies and drafts (one layer and
+    the module's block, both selecting, at the published widths, 16 rows,
+    served context 24,576: 24 key blocks, which two queries' gathers are
+    worth whatever the rows hold): each block's decode is the kernel under
+    the selection's mask, named, in no conditional; no gather fetches
+    2,048 rows a query (``bf16[65536,640]``, or by row and query), no
+    array holds them, and no whole cache is copied for the kernel."""
+    from alpa_tpu.model import gpt_model
+    from alpa_tpu.ops import latent_attention as la
+    compiled, rows, context = _selecting_decode(one_chip, "glm-5-1chip")
+    assert context // la.DECODE_BLOCK_K <= \
+        2 * gpt_model.GATHER_WORTH_KEY_BLOCKS
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if KERNEL in line]
+    cores = [k for k in kernels if "%" + la.UNDER_MASK_NAME in k]
+    assert len(cores) == 2 and len(kernels) == 4, (len(cores), len(kernels))
+    assert all("/latent_select/" in k for k in cores)
+    assert not re.search(r" conditional\(.*/latent_select/", text)
+    assert not re.search(r"\[(%d|%d,2048|%d,4096),640\]" % (
+        rows * 2 * 2048, rows * 2, rows), text)
+    assert not re.search(r"= \S*,640\]\S* gather\(", text)
+    assert _moved_whole(text, rows, context) == []
 
 
 # ---- a prefill chunk's head (PR 48) -------------------------------------

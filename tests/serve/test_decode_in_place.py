@@ -336,9 +336,12 @@ def test_selecting_decode_moves_no_cache(one_chip):
     kept the cache with its positions minor-most for the writes and moved
     it whole into row order for the gather, two copies of the cache a
     layer a tick, 2.07 GB of temporaries at 16 rows of 32,768 positions
-    (compile for the described v5e, PR 47).  The decode holds no copy of
-    a cache, gives every cache array to the output that replaces it and
-    fetches the selected rows with one gather a layer."""
+    (compile for the described v5e, PR 47).  Since ISSUE 59 a cache of so
+    few key blocks (four of 1,024 positions) is attended over as it lies,
+    under the selection's mask, and nothing is gathered at all; the kernel
+    reads the rows in the order they are written in.  The decode holds no
+    copy of a cache, gives every cache array to the output that replaces
+    it, and fetches no selected row."""
     import json
     from alpa_tpu.model.gpt_model import config_from_hf
     here = os.path.dirname(os.path.abspath(__file__))
@@ -363,13 +366,56 @@ def test_selecting_decode_moves_no_cache(one_chip):
         ROWS, SELECT_CONTEXT, ROWS * SELECT_CONTEXT))
     assert [result for _name, result, op, _operand in found
             if op in ("copy", "copy-start") and whole.search(result)] == []
-    assert len(re.findall(r"= \S*\[%d,2048,640\]\S* gather\(" % ROWS,
-                          hlo)) == 1
-    # the index scores, and the absorbed core over the gathered rows
+    assert not re.search(r"= \S*,640\]\S* gather\(", hlo)
+    # the index scores, and the core under the selection's mask
     kernels = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
     assert sum("/indexer/" in name for name in kernels) == 1
+    assert [name for name in kernels if "/latent_select/" in name] == [
+        name for name in kernels if "latent_decode_under_mask" in name]
     assert sum("/latent_select/" in name for name in kernels) == 1
+
+
+def test_selecting_verify_moves_no_cache(one_chip):
+    """The same of GLM-5's tick that verifies a draft and drafts the next
+    (``Generator._verify_draft``: one layer and the module's block at the
+    published widths, two queries a row, both selecting): every cache
+    array is given to the output that replaces it, no whole cache is
+    copied for the per-row writes of two positions or for the kernel that
+    reads it under the mask, and nothing gathers a selected row."""
+    import json
+    from chipbench import run
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "..", "chipbench", "configs",
+                           "glm-5-1chip.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=1, vocab_size=1024)
+    cfg = run.load_module("drivers", "serve_mla").model_config(
+        hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        seq_len=SELECT_CONTEXT)
+    model = GPTModel(cfg)
+    params, caches = _abstract_state(model, cfg, ROWS, one_chip)
+    assert [(k.shape, v.shape) for k, v, _ in caches] == 2 * [
+        ((ROWS, SELECT_CONTEXT, 640), (ROWS, SELECT_CONTEXT, 128))]
+    gen = Generator(model, params, cfg, prefill_chunk=1024)
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = gen._verify_draft.jitted.lower(
+        params, spec(ROWS, 1), spec(ROWS), [(k, v) for k, v, _ in caches],
+        [i for _, _, i in caches], spec(ROWS), spec(ROWS),
+        spec(ROWS, dtype=jnp.bool_)).compile(
+            compiler_options=NO_MSA).as_text()
+    assert len(_aliases(hlo)) == 4
+    found, _types = _entry(hlo)
+    whole = re.compile(r"\[(%d,%d|%d),(640|128)\]" % (
+        ROWS, SELECT_CONTEXT, ROWS * SELECT_CONTEXT))
+    assert [result for _name, result, op, _operand in found
+            if op in ("copy", "copy-start") and whole.search(result)] == []
+    assert not re.search(r"= \S*,640\]\S* gather\(", hlo)
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert sum("latent_decode_under_mask" in name for name in kernels) == 2
 
 
 def test_latent_chunk_step_expands_a_block_at_a_time(one_chip):
