@@ -3099,6 +3099,18 @@ def keep_positions(x, logits_at):
                                mode="clip")
 
 
+def _routers_said(routings) -> dict:
+    """``return_routing``'s account of routed layers, from each one's
+    ``routing`` (``moe.DroplessExperts``)."""
+    if not routings:
+        return {}
+    said = {"experts": jnp.stack([r["experts"] for r in routings])}
+    passes = [r["window_passes"] for r in routings if "window_passes" in r]
+    if passes:
+        said["window_passes"] = jnp.stack(passes)
+    return said
+
+
 class GPTModel(nn.Module):
     """Decoder-only LM.  Returns logits (and new kv caches if given).  A
     configuration with routed-expert layers returns ``(logits, routing)``
@@ -3127,7 +3139,10 @@ class GPTModel(nn.Module):
         of a row's last real position (``update_conv_state``).
         ``return_routing`` (with ``kv_caches``, routed layers): a third
         result, what the routed layers' routers did: ``experts`` (expert
-        layers, tokens, k) int32, every token's experts; and, where layers
+        layers, tokens, k) int32, every token's experts; where the expert
+        layers walk the call's rows in windows (``moe.expert_window``),
+        ``window_passes`` (expert layers,) int32, the passes each took; and,
+        where layers
         select their positions and the call is one new position a row,
         ``selected`` (selecting layers, B, index_topk) int32, the positions
         each row's query attended over, in ascending position, and
@@ -3205,8 +3220,7 @@ class GPTModel(nn.Module):
             if not return_routing:
                 return logits, new_caches
             selection = said.pop(0) if asked else None
-            said = {"experts": jnp.stack([r["experts"] for r in said])} \
-                if said else {}
+            said = _routers_said(said)
             if asked:
                 said.update(selected=selection[0][None],
                             selected_real=selection[1][None])
@@ -3287,8 +3301,7 @@ class GPTModel(nn.Module):
             # a module's entry comes back as it came
             new_caches += list(kv_caches[layers:])
             if return_routing:
-                said = {"experts": jnp.stack(
-                    [r["experts"] for r in routings])} if routings else {}
+                said = _routers_said(routings)
                 if selections:
                     said.update(
                         selected=jnp.stack([p for p, _ in selections]),

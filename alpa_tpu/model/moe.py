@@ -7,7 +7,10 @@ Dropless: every one of the k x tokens pairs is computed.  The rows are
 sorted by expert, each expert's consecutive rows meet its weights in one
 grouped matmul (``ops/grouped_matmul.py``), and the results are put back
 in token order and summed with the routing weights.  Nothing depends on an
-expert's load, so there is no capacity and no padding to steal it.
+expert's load, so there is no capacity and no padding to steal it.  A layer
+that holds a share of its experts (expert parallelism's share of the
+weights) multiplies the rows that land on those, a window of the sorted
+rows at a time where nearly all rows leave (``windowed_expert_sum``).
 
 GShard top-2 (the rest of this file):
 
@@ -26,6 +29,7 @@ from typing import Any, Optional
 
 import functools
 import logging
+import math
 
 import flax.linen as nn
 import jax
@@ -157,6 +161,120 @@ _permute_rows.defvjp(lambda x, perm, inverse: (x[perm], inverse),
                      lambda inverse, g: (g[inverse], None, None))
 
 
+# A call of a layer that holds a share of its experts (``experts_held``)
+# gathers, multiplies and combines a WINDOW of its sorted rows at a time, of
+# this many times the rows the held experts can expect of it (tokens x k x
+# their share of the router's width), up to a whole number of the grouped
+# matmul's smallest row tiles.  Set by ``scripts/time_expert_chunk.py`` on a
+# v5e (PERF.md section 5, PR 60)
+WINDOW_OVER_EXPECTED = 2
+# ... where such a window is at most one in this many of the call's rows:
+# a prefill chunk's 1,024 positions at a share of an eighth or less.  A
+# decode tick's, a verify's and a block step's rows are so few that the
+# smallest window is most of them: they go over all rows, as a layer that
+# holds every expert does
+WINDOW_WORTH_ROWS = 4
+
+
+def expert_window(config, tokens: int) -> Optional[int]:
+    """The rows of the window ``DroplessExperts`` walks a call of ``tokens``
+    tokens in (above), from the shape and the held share alone; None where
+    the call goes over all its rows at once."""
+    from alpa_tpu.ops.grouped_matmul import MIN_ROW_TILE
+    held = getattr(config, "experts_held", None)
+    if held is None:
+        return None
+    rows = tokens * config.num_experts_per_tok
+    width = config.num_experts + getattr(config, "num_zero_experts", 0)
+    tile = MIN_ROW_TILE
+    window = tile * math.ceil(
+        WINDOW_OVER_EXPECTED * rows * held[1] / (width * tile))
+    return window if window * WINDOW_WORTH_ROWS <= rows else None
+
+
+def _expert_mlps(cfg, rows, w_in, w_down, group_sizes):
+    """Rows sorted by expert through their experts, ``group_sizes`` rows
+    each: ``down(act(gate(x)) * up(x))`` with ``w_in`` [gate | up] side by
+    side, or, ungated, ``down(act(up(x)))`` with ``w_in`` (E, width, h)."""
+    from alpa_tpu.model.gpt_model import activation_fn
+    from alpa_tpu.ops.grouped_matmul import grouped_matmul
+    act, width = activation_fn(cfg.activation), w_down.shape[1]
+    if not getattr(cfg, "expert_gated", True):
+        hidden = act(grouped_matmul(
+            rows, w_in, group_sizes, transposed=True).astype(jnp.float32))
+    else:
+        # gate and up in one pass over the rows
+        gate_up = grouped_matmul(rows, w_in, group_sizes)
+        hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
+                  gate_up[:, width:].astype(jnp.float32))
+    return grouped_matmul(hidden.astype(cfg.dtype), w_down, group_sizes)
+
+
+def _sum_by_token(rows, token, tokens: int):
+    """(W, h) float32 ``rows`` summed into (``tokens``, h): row r into
+    token ``token[r]``.  A product with the one-hot (tokens, W) matrix at
+    the precision that keeps float32 (a scatter-add walks its rows one
+    after the other on a TPU)."""
+    onto = (jnp.arange(tokens, dtype=jnp.int32)[:, None] ==
+            token[None, :]).astype(jnp.float32)
+    return jnp.dot(onto, rows, precision=jax.lax.Precision.HIGHEST)
+
+
+def windowed_expert_sum(cfg, tokens, weights, order, group_sizes, w_in,
+                        w_down, window: int):
+    """The held experts' part of the routed sum of a call that sends most
+    of its rows elsewhere, a window of ``window`` sorted rows at a time:
+    ``(y (T, h) float32, passes)``.
+
+    ``order`` sorts the call's T x k rows with the held experts' first, by
+    expert (``group_sizes`` rows each, ``local`` in all).  Pass i gathers
+    the tokens of the sorted rows ``i x window`` onward, runs
+    ``_expert_mlps`` with each group's size clipped to the window, and adds
+    ``weight x row`` of the local rows among them into ``y`` by token: no
+    array of T x k rows is made, and the rows bound elsewhere are never
+    gathered.  ``passes`` (int32) is ``ceil(local / window)``: one where
+    the window sufficed, none where no row is local, more where the
+    routing sent more here, so every local row is multiplied whatever the
+    routing.  (A last window that would pass the end of the rows starts
+    earlier and leaves out the rows the pass before it added.)  ``y`` is
+    what the path over all rows sums, in another order of a float32 sum of
+    at most k terms.
+
+    The passes are a ``lax.fori_loop`` of a traced trip count, which JAX
+    does not differentiate in reverse mode: a jitted gradient through a
+    call that takes this path raises JAX's own error ("Reverse-mode
+    differentiation does not work for lax.while_loop or lax.fori_loop with
+    dynamic start/stop values").  Nothing differentiates a held share at
+    such a shape; training holds every expert and goes over all rows."""
+    (n_tokens, h), k = tokens.shape, weights.shape[1]
+    n = n_tokens * k
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    local = ends[-1]
+    flat_weights = weights.reshape(-1)
+
+    def one_pass(i, y):
+        done = i * window
+        lo = jnp.minimum(done, n - window)
+        taken = jax.lax.dynamic_slice(order, (lo,), (window,))
+        token = taken // k
+        sizes = jnp.clip(ends, lo, lo + window) - \
+            jnp.clip(starts, lo, lo + window)
+        out_rows = _expert_mlps(cfg, tokens[token], w_in, w_down, sizes)
+        at = lo + jnp.arange(window, dtype=jnp.int32)
+        # (what lies behind the local rows was not multiplied, and is
+        # whatever the kernel's output buffer held)
+        mine = (at >= done) & (at < local)
+        weighted = jnp.where(
+            mine[:, None],
+            out_rows.astype(jnp.float32) * flat_weights[taken][:, None], 0)
+        return y + _sum_by_token(weighted, token, n_tokens)
+
+    passes = (local + window - 1) // window
+    return jax.lax.fori_loop(
+        0, passes, one_pass, jnp.zeros((n_tokens, h), jnp.float32)), passes
+
+
 class DroplessExperts(nn.Module):
     """Top-k routed, gated experts without capacity (module docstring):
     ``down(act(gate(x)) * up(x))``, or with ``expert_gated`` False
@@ -181,7 +299,12 @@ class DroplessExperts(nn.Module):
     expert are sorted behind the held groups, which the grouped matmul
     does not walk, and take no part: ``y`` is the held experts' part of
     the routed sum (and the shared experts, which every chip computes
-    alike for its own tokens).
+    alike for its own tokens).  Where the call's shape says that at least
+    three quarters of its rows are bound elsewhere (``expert_window``: a
+    prefill chunk at a share of an eighth or less), those rows are not
+    gathered, multiplied in tiles sized for or combined either: the layer
+    walks the head of the sorted rows a window at a time
+    (``windowed_expert_sum``).
 
     ``num_zero_experts`` Z (LongCat-Flash's zero-computation experts): the
     router (and its bias) is ``num_experts + Z`` wide, and a pick of an
@@ -194,19 +317,20 @@ class DroplessExperts(nn.Module):
     ``weight x input`` in float32, computed here for this program's own
     tokens whatever ``experts_held`` says, as the shared experts are).
     How many rows the experts multiply is so decided by the data; the
-    grouped matmul's row count stays the static tokens x k.
+    grouped matmul's row count stays static: tokens x k, or the window.
 
     Returns ``(y, routing)``: ``routing`` holds ``counts`` (E,) int32 and
     ``prob_sums`` (E,) float32 over the ``num_experts`` with matrices,
     whichever are held, ``experts`` (T, k) int32, every pick as the router
-    numbers it (0 .. E + Z - 1), and, where there are identity experts,
-    ``zero_picks`` (a scalar): how many of the picks were of one."""
+    numbers it (0 .. E + Z - 1), where there are identity experts
+    ``zero_picks`` (a scalar): how many of the picks were of one, and
+    where the call walked windows ``window_passes`` (a scalar): how many
+    (``record_window_passes``)."""
     config: Any
 
     @nn.compact
     def __call__(self, x):
-        from alpa_tpu.model.gpt_model import MLPBlock, activation_fn
-        from alpa_tpu.ops.grouped_matmul import grouped_matmul
+        from alpa_tpu.model.gpt_model import MLPBlock
         cfg = self.config
         e, k, width = (cfg.num_experts, cfg.num_experts_per_tok,
                        cfg.expert_width)
@@ -268,35 +392,42 @@ class DroplessExperts(nn.Module):
                 slot = jnp.where(here, flat - first, mine)
             # stable: an expert's rows stay in token order
             order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
+            # rows of the window the call is walked in (``expert_window``)
+            window = expert_window(cfg, tokens.shape[0])
+            if window is None:
+                inverse = jnp.argsort(order).astype(jnp.int32)
             counts = (flat[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
                 0, dtype=jnp.int32)
             group_sizes = counts if held is None else \
                 counts[first:first + mine]
-            rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
-                                    inverse, k)
-            act = activation_fn(cfg.activation)
-            if not gated:
-                hidden = act(grouped_matmul(
-                    rows, w_up, group_sizes, transposed=True).astype(
-                        jnp.float32))
+
+            def w_in():
+                """What ``_expert_mlps`` takes for the first product."""
+                if not gated:
+                    return w_up
+                if cfg.fused_gate_up:
+                    return w_gate_up
+                return jnp.concatenate([w_gate, w_up], axis=-1)
+
+            if window is not None:
+                y, window_passes = windowed_expert_sum(
+                    cfg, tokens.astype(cfg.dtype), weights, order,
+                    group_sizes, w_in(), w_down, window)
             else:
-                # gate and up in one pass over the rows
-                if not cfg.fused_gate_up:
-                    w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1)
-                gate_up = grouped_matmul(rows, w_gate_up, group_sizes)
-                hidden = (act(gate_up[:, :width].astype(jnp.float32)) *
-                          gate_up[:, width:].astype(jnp.float32))
-            out_rows = grouped_matmul(hidden.astype(cfg.dtype), w_down,
-                                      group_sizes)
-            if partial:
-                # what lies behind the groups was not multiplied, and is
-                # whatever the kernel's output buffer held
-                walked = jnp.arange(out_rows.shape[0]) < group_sizes.sum()
-                out_rows = jnp.where(walked[:, None], out_rows, 0)
-            by_token = _permute_rows(out_rows, inverse, order).reshape(
-                tokens.shape[0], k, h)
-            y = (by_token.astype(jnp.float32) * weights[..., None]).sum(1)
+                rows = _rows_to_experts(tokens.astype(cfg.dtype), order,
+                                        inverse, k)
+                out_rows = _expert_mlps(cfg, rows, w_in(), w_down,
+                                        group_sizes)
+                if partial:
+                    # what lies behind the groups was not multiplied, and
+                    # is whatever the kernel's output buffer held
+                    walked = jnp.arange(out_rows.shape[0]) < \
+                        group_sizes.sum()
+                    out_rows = jnp.where(walked[:, None], out_rows, 0)
+                by_token = _permute_rows(out_rows, inverse, order).reshape(
+                    tokens.shape[0], k, h)
+                y = (by_token.astype(jnp.float32) *
+                     weights[..., None]).sum(1)
             if zeros:
                 identity = experts >= e
                 y = y + jnp.where(identity, weights, 0.0).sum(
@@ -311,6 +442,8 @@ class DroplessExperts(nn.Module):
         if zeros:
             routing.update(prob_sums=probs[:, :e].sum(0),
                            zero_picks=identity.sum(dtype=jnp.int32))
+        if window is not None:
+            routing["window_passes"] = window_passes
         return y.astype(cfg.dtype).reshape(x.shape), routing
 
 
@@ -337,6 +470,27 @@ def record_routing(expert_counts, dropped_rows: int = 0):
     registry.gauge("alpa_moe_expert_load_max_over_mean",
                    "rows of the busiest expert over the mean, largest over "
                    "the layers").set(float(load))
+
+
+def record_window_passes(passes):
+    """Feed the metrics registry from what a step said of the windows its
+    expert layers walked their rows in (``return_routing``'s
+    ``window_passes``, read back): ``alpa_moe_window_calls_total`` counts
+    the layers' calls and ``alpa_moe_window_passes_total`` their passes.
+    Passes over calls is 1 where a window always held the local rows, under
+    1 where calls had none, over 1 where the routing sent a call more than
+    its window (``WINDOW_OVER_EXPECTED``)."""
+    from alpa_tpu.telemetry import metrics as tmetrics
+    passes = np.asarray(passes)
+    registry = tmetrics.get_registry()
+    registry.counter("alpa_moe_window_calls_total",
+                     "calls of an expert layer that walked their rows in "
+                     "windows").inc(float(passes.size))
+    registry.counter("alpa_moe_window_passes_total",
+                     "windows those calls gathered, multiplied and combined "
+                     "(over alpa_moe_window_calls_total: 1 where one window "
+                     "always held the rows that landed on the held experts)"
+                     ).inc(float(passes.sum()))
 
 
 ########################################

@@ -994,7 +994,10 @@ class ContinuousBatchingEngine:
                     self._recover_resident_locked(e)
 
     def _count_routing(self, routing):
-        """What a step said of its routed layers, into the counters."""
+        """What a step said of its routed layers, into the counters, and
+        with it what the admissions' chunks before it said of theirs: the
+        host has just read tokens sampled behind them."""
+        self.gen.count_window_passes()
         held = getattr(self.gen.config, "experts_held", None)
         zeros = getattr(self.gen.config, "num_zero_experts", 0)
         for layer in routing.get("experts", ()):

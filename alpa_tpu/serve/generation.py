@@ -26,6 +26,7 @@ from alpa_tpu.model.gpt_model import (GPTConfig, GPTModel, init_kv_caches,
                                       require_rollback_by_index,
                                       require_uniform_kv_caches, routed_mlp,
                                       uniform_kv_caches)
+from alpa_tpu.model.moe import expert_window, record_window_passes
 from alpa_tpu.telemetry import device_time
 
 logger = logging.getLogger(__name__)
@@ -620,12 +621,20 @@ class Generator:
         # the logits of the last chunk step sent, where the steps in
         # flight are bounded (``_run_chunked_prefill``)
         self._last_chunk = None
+        # what the chunk steps sent since ``count_window_passes`` said of
+        # their expert layers' windows (bounded: a caller that prefills
+        # and never reads tokens here, a ``PrefillEngine``, counts none)
+        self._window_passes = collections.deque(maxlen=4096)
         self._parallel_method = parallel_method
 
         def chunk_prefill(params, ids_chunk, lengths, caches, last,
                           after=None):
             """One fixed-shape chunk through the cached path: ``(last,
-            caches)``.  The chunk's absolute start position rides the
+            caches, window_passes)``, the third what the model's expert
+            layers said of the windows they walked their rows in ((expert
+            layers,) int32, ``moe.windowed_expert_sum``'s passes; None
+            where the shape walks none: ``moe.expert_window``).
+            The chunk's absolute start position rides the
             caches' scalar write index.  The final norm and the head run
             over one position a row, the one at ``lengths - 1`` where the
             chunk holds it, and ``last`` (B, V) takes that row's logits
@@ -638,10 +647,14 @@ class Generator:
             start = caches[0][2]                     # scalar chunk start
             pos = start + jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
             off = lengths - 1 - start                # (B,)
-            logits, caches = model.apply(
+            # (static) whether the expert layers have passes to tell of
+            windows = expert_window(config, b * c) is not None
+            logits, caches, *said = model.apply(
                 params, ids_chunk, pos, caches,
                 logits_at=jnp.clip(off, 0, c - 1)[:, None],
-                with_hidden=bool(drafts), **lengths_kw(lengths))
+                with_hidden=bool(drafts), return_routing=windows,
+                **lengths_kw(lengths))
+            passes = said[0]["window_passes"] if windows else None
             hit = (off >= 0) & (off < c)
             if drafts:
                 # the module's block over the chunk, into its own entry:
@@ -659,10 +672,14 @@ class Generator:
                 following = jnp.where(at == off[:, None],
                                       first.astype(jnp.int32)[:, None],
                                       following)
+                # (nothing is asked of the module's routing: a chunk keeps
+                # its block's cache entry alone, so its experts are dead
+                # code here, and an account of their windows would revive
+                # them: 6.5 ms of GLM-5's 53 ms chunk, PERF.md, PR 60)
                 _, caches = model.apply(params, following, pos, caches,
                                         draft_from=hidden, logits_at=at[:, :1])
             last = jnp.where(hit[:, None], logits[:, 0], last)
-            return last, caches
+            return last, caches, passes
 
         def verify_draft(params, tokens, index, caches, draft, left,
                          do_sample):
@@ -840,9 +857,13 @@ class Generator:
             # chunk's last position's is the next chunk's first
             after = (jnp.asarray(ids[:, (ci + 1) * c:(ci + 1) * c + 1]),) \
                 if self._verify_draft is not None else ()
-            last, caches = self._chunk_prefill(self.params, chunk,
-                                               lengths_j, caches, last,
-                                               *after)
+            last, caches, passes = self._chunk_prefill(
+                self.params, chunk, lengths_j, caches, last, *after)
+            if passes is not None:
+                # on its way to the host behind the chunk: whoever next
+                # reads this prompt's tokens finds it there
+                passes.copy_to_host_async()
+                self._window_passes.append(passes)
             sent.append(last)
             if bounded and len(sent) > ahead:
                 jax.block_until_ready(sent.popleft())
@@ -851,6 +872,14 @@ class Generator:
         # per-row decode positions take over from the scalar chunk index
         caches = [(kc, vc, lengths_j) for (kc, vc, _i) in caches]
         return last, caches
+
+    def count_window_passes(self):
+        """Into the counters, what the chunk steps sent so far said of the
+        windows their expert layers walked (``moe.record_window_passes``).
+        The caller has just read tokens that came after those chunks: the
+        passes are on the host already, and nothing is waited for."""
+        while self._window_passes:
+            record_window_passes(self._window_passes.popleft())
 
     def cache_prefix(self, prefix_ids) -> "PrefixHandle":
         """Precompute KV for a shared prefix (system prompt caching).
@@ -1047,6 +1076,7 @@ class Generator:
                 break
         gen = np.stack([np.asarray(g) for g in generated], axis=1) \
             if generated else np.zeros((b, 0), np.int32)
+        self.count_window_passes()
         if len(set(lengths.tolist())) == 1:
             # uniform prompts: 2-D (B, S + T) result, finished rows padded
             # with eos (classic HF-style batch output)

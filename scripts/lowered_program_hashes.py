@@ -5,9 +5,13 @@ the program's StableHLO text, lowered from abstract arguments on the CPU
 (nothing is compiled or run): OPT-1.3B's decode over 4 rows and its two
 dense prefills, the gradient of two GPT-1.3B blocks under remat, of one
 OLMoE layer with its routed experts, and the served decode and chunk step
-of LFM2 and Trinity at a depth of four and five layers.  Two checkouts whose lines
+of LFM2 and Trinity at a depth of four and five layers, and the decode tick
+(the verify of a tick that drafts, GLM-5's) of the six served cells that hold
+a share of their experts, at a depth of two layers, or as many as hold one
+of every kind.  Two checkouts whose lines
 agree build the same programs for those cells (PR 58 compared its tree
-with its parent so)."""
+with its parent so; PR 60 added the six ticks, which its windows over a
+chunk's expert rows leave as they were)."""
 import hashlib, json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 root = os.path.abspath(sys.argv[1])
@@ -50,17 +54,34 @@ def loss(p, ids):
     logits, routing = model.apply(p, ids)
     return logits.astype(jnp.float32).mean() + routing["load_balance_loss"]
 out["olmoe.grad"] = digest(jax.jit(jax.grad(loss)).lower(params, S((2, 4096), jnp.int32)))
+def served(cfg, serve):
+    """(generator, abstract parameters, the cell's rows, the decode tick's abstract arguments) of a served configuration."""
+    model = GPTModel(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
+    gen = Generator(model, params, cfg, prefill_chunk=serve["prefill_chunk"])
+    rows = serve["engine_rows"]
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    return gen, params, rows, (params, S((rows, 1), jnp.int32), S((rows,), jnp.int32), [(k, v) for k, v, _ in caches], [S((rows,), jnp.int32) for _ in caches])
+
 # LFM2 and Trinity served: the decode over the cell's rows and the chunk step
 for name, layers in (("lfm2-8b-a1b-1chip", 5), ("trinity-mini-1chip", 4)):
     hf = json.load(open(os.path.join(root, f"chipbench/configs/{name}.json")))
     hf = dict(hf, num_hidden_layers=layers, layer_types=hf["layer_types"][:layers])
     serve = hf["serve"]
     cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=serve["served_context"])
-    model = GPTModel(cfg)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))
-    gen = Generator(model, params, cfg, prefill_chunk=serve["prefill_chunk"])
-    rows = serve["engine_rows"]
-    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
-    out[name + ".decode"] = digest(gen._decode.jitted.lower(params, S((rows, 1), jnp.int32), S((rows,), jnp.int32), [(k, v) for k, v, _ in caches], [S((rows,), jnp.int32) for _ in caches]))
+    gen, params, rows, tick = served(cfg, serve)
+    out[name + ".decode"] = digest(gen._decode.jitted.lower(*tick))
     out[name + ".chunk"] = digest(gen._chunk_prefill.lower(params, S((1, serve["prefill_chunk"]), jnp.int32), S((1,), jnp.int32), jax.eval_shape(lambda: init_kv_caches(cfg, 1)), S((1, cfg.vocab_size), jnp.bfloat16)))
+# the six cells that hold a share of their experts: the decode tick over the cell's rows
+from chipbench import run
+serve_mla = run.load_module("drivers", "serve_mla")
+for name, layers in (("deepseek-v2-1chip", 2), ("longcat-flash-1chip", 2), ("dots3-note-prev-1chip", 2), ("mimo-v2-flash-1chip", 2), ("glm-5-1chip", 2), ("nemotron-3-nano-30b-a3b-1chip", 9)):
+    hf = dict(json.load(open(os.path.join(root, f"chipbench/configs/{name}.json"))), num_hidden_layers=layers)
+    serve = hf["serve"]
+    cfg = serve_mla.model_config(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=serve["served_context"])
+    gen, params, rows, tick = served(cfg, serve)
+    if gen._verify_draft is None:
+        out[name + ".decode"] = digest(gen._decode.jitted.lower(*tick))
+    else:
+        out[name + ".decode"] = digest(gen._verify_draft.jitted.lower(*tick, S((rows,), jnp.int32), S((rows,), jnp.int32), S((rows,), jnp.bool_)))
 print(json.dumps(out))

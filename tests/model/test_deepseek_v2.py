@@ -511,11 +511,19 @@ def test_a_latent_cache_is_refused_by_name(toy, what):
 
 # ---- the share --------------------------------------------------------
 
-def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
+@pytest.mark.parametrize("window", [None, 16], ids=["all_rows", "windows"])
+def test_the_shares_of_a_layer_add_up_to_the_whole(reference, window,
+                                                   monkeypatch):
     """One expert layer of 16 experts over four chips: what the four
     shares give (the program's ``DroplessExperts`` told which 4 experts it
     holds, and the reference given the same 4), the shared experts
     counted once, is what the uncut reference gives for the whole layer."""
+    if window:
+        # the shares walk their rows in windows of 16 (ISSUE 60); the
+        # layer that holds every expert never does
+        monkeypatch.setattr(
+            moe, "expert_window",
+            lambda cfg, tokens: window if cfg.experts_held else None)
     mod, _ = reference
     eps = TOY["rms_norm_eps"]
     x = jax.random.normal(jax.random.PRNGKey(5), (40, 64), jnp.float32)
@@ -549,6 +557,7 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
         y, routing = highest(
             moe.DroplessExperts(toy_config(experts_held=held)).apply,
             {"params": params}, h)
+        assert ("window_passes" in routing) == bool(window and held)
         return np.asarray(y[0]), np.asarray(routing["experts"])
 
     whole, chosen = ref_part(0, 16)
@@ -574,9 +583,16 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
     np.testing.assert_allclose(from_reference, whole, atol=TOL)
 
 
-def test_rows_routed_to_an_absent_expert_take_no_part():
+@pytest.mark.parametrize("window", [None, 16], ids=["all_rows", "windows"])
+def test_rows_routed_to_an_absent_expert_take_no_part(window, monkeypatch):
     """All of a token's experts elsewhere: its routed part is exactly 0,
     whatever lies in the rows behind the held groups."""
+    if window:
+        # the shares walk their rows in windows of 16 (ISSUE 60); the
+        # layer that holds every expert never does
+        monkeypatch.setattr(
+            moe, "expert_window",
+            lambda cfg, tokens: window if cfg.experts_held else None)
     cfg = toy_config(num_shared_experts=0, experts_held=(12, 4))
     layer = moe.DroplessExperts(cfg)
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64), jnp.float32)

@@ -426,13 +426,21 @@ def test_identity_picks_add_their_weight_times_the_input():
     np.testing.assert_allclose(y[0], want, atol=TOL)
 
 
-def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
+@pytest.mark.parametrize("window", [None, 16], ids=["all_rows", "windows"])
+def test_the_shares_of_a_layer_add_up_to_the_whole(reference, window,
+                                                   monkeypatch):
     """The share test: one expert branch of 8 experts and 4 identity
     experts over four chips.  What the four shares give (the program's
     ``DroplessExperts`` told which 2 experts it holds, and the reference
     given the same 2), the identity part, which every share computes alike
     for its own tokens, counted once, is what the uncut reference gives
     for the whole layer."""
+    if window:
+        # the shares walk their rows in windows of 16 (ISSUE 60); the
+        # layer that holds every expert never does
+        monkeypatch.setattr(
+            moe, "expert_window",
+            lambda cfg, tokens: window if cfg.experts_held else None)
     mod, _ = reference
     cfg = dataclasses.replace(toy_config(experts_held=None), num_experts=8,
                               num_zero_experts=4)
@@ -488,10 +496,17 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(reference):
     np.testing.assert_allclose(from_reference, whole, atol=TOL)
 
 
-def test_rows_behind_the_groups_take_no_part():
+@pytest.mark.parametrize("window", [None, 16], ids=["all_rows", "windows"])
+def test_rows_behind_the_groups_take_no_part(window, monkeypatch):
     """All of a token's picks on absent or identity experts: its routed
     part is exactly the identity part, whatever lies in the rows behind
     the held groups."""
+    if window:
+        # the shares walk their rows in windows of 16 (ISSUE 60); the
+        # layer that holds every expert never does
+        monkeypatch.setattr(
+            moe, "expert_window",
+            lambda cfg, tokens: window if cfg.experts_held else None)
     cfg = toy_config(experts_held=(12, 4))
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64), jnp.float32)
     layer, p = _layer_params(cfg, h)

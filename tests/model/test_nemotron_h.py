@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from alpa_tpu.model import gpt_model
+from alpa_tpu.model import gpt_model, moe
 from alpa_tpu.model.gpt_model import (GPTModel, MLPBlock, config_from_hf,
                                       init_kv_caches, kv_cache_kinds,
                                       kv_cache_shapes, ssm_states,
@@ -324,12 +324,20 @@ def test_a_wrong_wiring_moves_the_logits(toy, wanted, monkeypatch, wiring):
     assert np.abs(got - wanted).max() > 1000 * TOL
 
 
-def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
+@pytest.mark.parametrize("window", [None, 16], ids=["all_rows", "windows"])
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy, window,
+                                                           monkeypatch):
     """The guide's test of the cut: an expert layer's result over every
     share of its experts (two chips of four here; the router at its whole
     width in each, the picks of absent experts adding nothing), with the
     shared expert, which every chip computes alike, counted once, is what
     the layer gives with all its experts."""
+    if window:
+        # the shares walk their rows in windows of 16 (ISSUE 60); the layer
+        # that holds every expert never does
+        monkeypatch.setattr(
+            moe, "expert_window",
+            lambda cfg, tokens: window if cfg.experts_held else None)
     _model, params, _ids = toy
     layer = params["params"]["h1"]["mlp"]
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 12, H))

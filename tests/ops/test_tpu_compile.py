@@ -896,3 +896,50 @@ def test_a_nemotron_tick_updates_every_state_in_place_on_v5e(one_chip):
                           r"\(" % state, line)]
     assert not moved, moved[:3]
     assert text.count('custom_call_target="tpu_custom_call"') >= 2 * 4
+
+
+@pytest.mark.parametrize("name,rows,window", [
+    ("longcat-flash-1chip", 12288, 512),
+    ("nemotron-3-nano-30b-a3b-1chip", 6144, 768)])
+def test_a_chunks_expert_layer_walks_a_window_on_v5e(one_chip, monkeypatch,
+                                                     name, rows, window):
+    """One expert layer of a cell that holds a share of its experts, at the
+    published widths, over a prefill chunk's 1,024 positions (LongCat: 16 of
+    768 router outputs held, 12 picks a token; Nemotron: 8 of 128, 6 picks,
+    ungated): the compiled layer holds no array of all ``tokens x k`` rows
+    of the hidden size, in any type, and both grouped matmuls run over the
+    rule's window with a row tile of 128 (ISSUE 60)."""
+    from alpa_tpu.model import moe
+    from alpa_tpu.ops import grouped_matmul as gm
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from chipbench import run
+    cfg = run.load_module("drivers", "serve_mla").model_config(
+        run.load_json(run.HERE, "configs", name + ".json"),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    assert 1024 * cfg.num_experts_per_tok == rows
+    assert moe.expert_window(cfg, 1024) == window
+    seen = []
+
+    def recording(*args, tiling, **static):
+        seen.append((args[0].shape[0], tiling[0]))
+        return gmm(*args, tiling=tiling, **static)
+
+    gmm = gm.gmm
+    monkeypatch.setattr(gm, "gmm", recording)
+    layer = moe.DroplessExperts(cfg)
+    x = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    seen.clear()
+    text = jax.jit(layer.apply).lower(params, x).compile().as_text()
+    # (each call traced for the TPU and for the platforms that interpret)
+    assert set(seen) == {(window, 128)} and len(seen) == 4, seen
+    assert text.count(KERNEL) == 2
+    assert not re.search(r"\[%d,%d\]" % (rows, cfg.hidden_size), text)
+    assert not re.search(r"\[1024,%d,%d\]" % (cfg.num_experts_per_tok,
+                                               cfg.hidden_size), text)
+    assert re.search(r" while\(", text)

@@ -218,7 +218,7 @@ def _with_the_parents_chunk_step(gen):
         off = lengths - 1 - start
         hit = (off >= 0) & (off < c)
         sel = logits[jnp.arange(b), jnp.clip(off, 0, c - 1)]
-        return jnp.where(hit[:, None], sel, last), caches
+        return jnp.where(hit[:, None], sel, last), caches, None
 
     gen._chunk_prefill = step
 

@@ -147,7 +147,7 @@ def test_identity_picks_are_counted_apart_where_all_experts_are_held():
                            experts_held=None)
     registry = tmetrics.get_registry()
     gen = Generator.__new__(Generator)
-    gen.config = cfg
+    gen.config, gen._window_passes = cfg, ()
     engine = ContinuousBatchingEngine.__new__(ContinuousBatchingEngine)
     engine.gen = gen
     before = registry.snapshot()
