@@ -113,7 +113,10 @@ class GPTConfig:
     # state is the last ``conv_taps - 1`` positions of a product, whatever
     # the row's length) | "ssm" (no attention: a Mamba-2 mixer, ``Mamba2``;
     # its state is a matrix a head and the last ``conv_taps - 1`` positions
-    # of what its convolution runs over, whatever the row's length) | "none"
+    # of what its convolution runs over, whatever the row's length) | "s6"
+    # (no attention: a Mamba-1 mixer, ``Mamba1``; its state is
+    # ``ssm_state_size`` values a channel and the last ``conv_taps - 1``
+    # positions of its ``s6_inner`` channels) | "none"
     # (no mixer and no first norm: the layer is its MLP alone, ``x +
     # mlp(ln2(x))``, and its cache entry holds nothing)
     attention: Any = "full"
@@ -276,6 +279,14 @@ class GPTConfig:
     expert_gated: bool = True
     # width of a shared expert where it is not the routed experts'
     shared_expert_width: Optional[int] = None
+    # --- Mamba-1 mixers (``model_type`` jamba).  An ``attention`` kind
+    # "s6" (``Mamba1``): ``s6_inner`` channels, each with
+    # ``ssm_state_size`` state values float32 and a decay a channel AND a
+    # state value, the step ``dt`` through a projection of rank
+    # ``s6_dt_rank``, a causal depthwise convolution of ``conv_taps`` taps
+    # with a bias over the channels
+    s6_inner: int = 0
+    s6_dt_rank: int = 0
 
     def mlp_kind(self, layer: int) -> str:
         """The MLP kind of a layer; a multi-token-prediction module's
@@ -324,6 +335,16 @@ class GPTConfig:
     def value_size(self) -> int:
         """Channels of one value head of a "full" or "sliding" layer."""
         return self.v_head_dim or self.head_size
+
+    @property
+    def folds_full_caches(self) -> bool:
+        """Whether a "full" layer's caches hold the key/value heads folded
+        into the channels, (B, S, Hkv D) and (B, S, Hkv Dv): where the
+        values are narrower than the keys, and where there is ONE
+        key/value head (a second-minor dimension of 1 is a tile of 16 on
+        the chip: kept a head, the cache would lie at sixteen times its
+        bytes) (``kv_cache_shapes``)."""
+        return self.value_size != self.head_size or self.kv_heads == 1
 
     @property
     def unlike_kinds(self) -> bool:
@@ -504,6 +525,13 @@ _HF_KINDS = {
     "nemotron_h": dict(norm="rmsnorm", positions="rotary",
                        rope_on_full_attention=False, router_score="sigmoid",
                        router_bias=True, expert_gated=False),
+    # Jamba (ai21labs): wiring read from the family's report and its
+    # published modelling code where config.json does not fix it (two
+    # RMSNorms a layer, a gated MLP behind every mixer, no positions of
+    # any kind in the attention layers, three RMSNorms inside the Mamba-1
+    # mixer, no bias but the convolution's and ``dt_proj``'s)
+    "jamba": dict(norm="rmsnorm", positions="rotary",
+                  rope_on_full_attention=False, mlp="gated"),
 }
 
 
@@ -956,6 +984,43 @@ def _nemotron_h_fields(hf: dict) -> dict:
         route_scale=float(hf["routed_scaling_factor"]))
 
 
+def _jamba_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` jamba says beyond the keys all
+    decoders share: which layers are attention (layer ``i`` where ``i %
+    attn_layer_period == attn_layer_offset``; every other is a Mamba-1
+    mixer) and the mixer's sizes (``mamba_expand`` times the hidden size
+    its inner width, ``mamba_d_state`` state values a channel,
+    ``mamba_dt_rank`` the rank ``dt`` comes through, ``mamba_d_conv`` taps).
+    Every layer's MLP is the gated one: a file whose ``num_experts`` is
+    over 1 routes every ``expert_layer_period``-th, which this does not
+    read and refuses; with one expert ``expert_layer_period``,
+    ``expert_layer_offset`` and ``num_experts_per_tok`` choose nothing.
+    The attention layers apply no positions (``_HF_KINDS``), and the file
+    has no rotary key at all."""
+    if hf["num_experts"] != 1:
+        raise ValueError("jamba: only num_experts 1 (a gated MLP in every "
+                         f"layer) is supported, got {hf['num_experts']}")
+    if not hf["mamba_conv_bias"] or hf["mamba_proj_bias"]:
+        raise ValueError("jamba: only a convolution with a bias and "
+                         "projections without one are supported")
+    if hf.get("sliding_window"):
+        raise ValueError("jamba: sliding_window is not supported")
+    layers = hf["num_hidden_layers"]
+    period, offset = hf["attn_layer_period"], hf["attn_layer_offset"]
+    fields = dict(
+        num_layers=layers, intermediate_size=hf["intermediate_size"],
+        attention=tuple("full" if i % period == offset else "s6"
+                        for i in range(layers)),
+        activation=hf["hidden_act"], layer_norm_eps=hf["rms_norm_eps"],
+        tie_embeddings=hf["tie_word_embeddings"],
+        s6_inner=hf["mamba_expand"] * hf["hidden_size"],
+        s6_dt_rank=hf["mamba_dt_rank"],
+        ssm_state_size=hf["mamba_d_state"], conv_taps=hf["mamba_d_conv"])
+    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        fields["num_kv_heads"] = hf["num_key_value_heads"]
+    return fields
+
+
 # what each model type's file says beyond the keys all share
 _HF_FIELDS = {
     "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
@@ -965,6 +1030,7 @@ _HF_FIELDS = {
     "mimo_v2_flash": _mimo_v2_flash_fields,
     "glm_moe_dsa": _glm_moe_dsa_fields,
     "nemotron_h": _nemotron_h_fields,
+    "jamba": _jamba_fields,
 }
 
 
@@ -979,7 +1045,8 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     routed experts; ``block_length``: the blocks a diffusion decoder
     generates in).  Of ``rope_scaling`` only deepseek_v2's ``yarn`` is
     known; a file that keeps its rotary base under ``rope_parameters``
-    (glm_moe_dsa) must name the ``default`` type there."""
+    (glm_moe_dsa) must name the ``default`` type there; a file of a family
+    without positions (jamba) names no base, and none is set."""
     kinds = _HF_KINDS.get(hf["model_type"])
     if kinds is None:
         raise ValueError(f"no decoder kinds for model_type "
@@ -995,8 +1062,9 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
         vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
         num_heads=hf["num_attention_heads"],
         seq_len=hf["max_position_embeddings"],
-        rope_theta=float(rope["rope_theta"]),
         use_bias=hf.get("attention_bias", False), **kinds)
+    if rope.get("rope_theta") is not None:
+        fields["rope_theta"] = float(rope["rope_theta"])
     fields.update(_HF_FIELDS[hf["model_type"]](hf))
     fields.update(kwargs)
     return GPTConfig(**fields)
@@ -2796,6 +2864,138 @@ class Mamba2(nn.Module):
         return out, new_cache
 
 
+# the scope of a Mamba-1 mixer's recurrence ALONE, inside ``SSM_SCOPE`` (a
+# capture reads it: telemetry/device_time.py)
+S6_SCAN_SCOPE = "selective_scan"
+
+
+def s6_rates(a_log):
+    """``A = -exp(A_log)``, (N, D): a channel's AND a state value's own."""
+    return -jnp.exp(a_log)
+
+
+def s6_dt(low, kernel, bias):
+    """``softplus(dt_proj(low))`` in float32, ``dt_proj`` WITH its bias and
+    no clamp: ``low`` (B, s, R) in the kernel's dtype, ``kernel`` (R, D),
+    ``bias`` (D,) float32."""
+    return jax.nn.softplus(jnp.dot(
+        low, kernel, preferred_element_type=jnp.float32) + bias)
+
+
+def s6_gate(y, z):
+    """``y * silu(z)`` in float32: the gate alone, no norm behind it."""
+    return y * nn.silu(z.astype(jnp.float32))
+
+
+class Mamba1(nn.Module):
+    """The Mamba-1 mixer of Jamba (Gu & Dao 2023; the ``attention`` kind
+    "s6" of ``GPTConfig``), no bias but the convolution's and
+    ``dt_proj``'s.  ``D`` channels (``s6_inner``), ``N`` state values a
+    channel (``ssm_state_size``), a step through rank ``R``
+    (``s6_dt_rank``):
+
+    ``[x | z] = in_proj(u)``, each ``D`` wide, ``x`` FIRST; ``x =
+    silu(conv(x) + bias)``, a depthwise causal convolution of ``conv_taps``
+    taps behind zeros; ``[dt | B | C] = x_proj(x)`` of widths ``R``, ``N``,
+    ``N``, each through an RMSNorm of its own (the family's addition to
+    Mamba); ``dt = softplus(dt_proj(dt))`` (``s6_dt``) and ``A =
+    -exp(A_log)`` (N, D), float32; for channel d and state value n ``h_t =
+    exp(dt_t[d] A[n, d]) h_{t-1} + dt_t[d] B_t[n] x_t[d]`` and ``y_t[d] =
+    sum_n h_t[n, d] C_t[n] + D[d] x_t[d]`` (``ops/selective_scan.py``);
+    ``y = y * silu(z)`` (``s6_gate``), no norm behind the gate;
+    ``out_proj(y)``.
+
+    Without a cache (the training call) the sequence starts from zeros.
+    With one, the layer's entry is ``(conv state (B, taps - 1, D), ssm
+    state (B, N, D) float32, index)``, the CHANNELS minor-most (16 state
+    values in the chip's 128 lanes would lie at eight times their bytes):
+    one new position a row reads both states, steps and writes them
+    (``s6_step``); ``s`` new positions run the taps over the conv state
+    with ``x`` behind it (``update_conv_state``) and the scan FROM the
+    row's ssm state (``s6_chunk_scan``), and leave, a row, both states of
+    its last REAL position (``cache_lengths``: a padded position has ``dt``
+    0, ``real_steps``).
+
+    A model made from a seed draws ``A_log[:, d] = log(1 .. N)``,
+    ``dt_proj``'s bias the inverse softplus of a log-uniform step in
+    [0.001, 0.1], ``D`` ones, as the Mamba reference does; the three are
+    float32 whatever ``param_dtype``."""
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, kv_cache=None, deterministic=True,
+                 position_ids=None, cache_lengths=None):
+        from alpa_tpu.ops import selective_scan
+        cfg = self.config
+        if not cfg.causal or cfg.block_length:
+            raise ValueError("a Mamba-1 mixer is causal over one sequence "
+                             "a row and takes no block-causal mask")
+        inner, n, rank, taps = (cfg.s6_inner, cfg.ssm_state_size,
+                                cfg.s6_dt_rank, cfg.conv_taps)
+        if min(inner, n, rank) < 1 or taps < 2:
+            raise ValueError(
+                "an \"s6\" layer needs GPTConfig.s6_inner, ssm_state_size, "
+                f"s6_dt_rank and conv_taps >= 2, got {(inner, n, rank, taps)}")
+        dense = partial(nn.Dense, dtype=cfg.dtype, use_bias=False,
+                        param_dtype=cfg.param_dtype)
+        b, s = x.shape[:2]
+        new_cache = None
+        with jax.named_scope(SSM_SCOPE):
+            xs, z = jnp.split(dense(2 * inner, name="in_proj")(x), 2,
+                              axis=-1)
+            # lecun_normal over the taps: a tap's fan-in is ``taps``
+            kernel = self.param("conv_kernel",
+                                nn.initializers.lecun_normal(),
+                                (taps, inner), cfg.param_dtype)
+            # (as torch's Conv1d draws it: a zero bias would hide its loss)
+            bias = self.param(
+                "conv_bias", lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -0.5, 0.5), (inner,), cfg.param_dtype)
+            if kv_cache is None:
+                full = jnp.pad(xs, ((0, 0), (taps - 1, 0), (0, 0)))
+                state = jnp.zeros((b, n, inner), jnp.float32)
+            else:
+                conv_state, state, index = kv_cache
+                full, (conv_state, _, index) = update_conv_state(
+                    (conv_state, None, index), xs, cache_lengths)
+            # the shifted copies, summed in float32
+            conv = sum(kernel[j].astype(jnp.float32) *
+                       full[:, j:j + s].astype(jnp.float32)
+                       for j in range(taps)) + bias.astype(jnp.float32)
+            xs = nn.silu(conv).astype(cfg.dtype)
+            low, bs, cs = jnp.split(dense(rank + 2 * n, name="x_proj")(xs),
+                                    [rank, rank + n], axis=-1)
+            low, bs, cs = (
+                make_norm(cfg, name)(v).astype(cfg.dtype) for name, v in
+                (("dt_norm", low), ("b_norm", bs), ("c_norm", cs)))
+            dt = s6_dt(low, self.param(
+                "dt_proj", nn.initializers.lecun_normal(), (rank, inner),
+                cfg.param_dtype), self.param(
+                    "dt_bias", _dt_bias_init(), (inner,), jnp.float32))
+            if kv_cache is not None and cache_lengths is not None:
+                dt = real_steps(dt, kv_cache[2], cache_lengths)
+            a = s6_rates(self.param(
+                "A_log", lambda key, shape, dtype: jnp.broadcast_to(jnp.log(
+                    jnp.arange(1, shape[0] + 1, dtype=dtype))[:, None],
+                    shape), (n, inner), jnp.float32))
+            skip = self.param("D", nn.initializers.ones, (inner,),
+                              jnp.float32)
+            with jax.named_scope(S6_SCAN_SCOPE):
+                if s == 1 and kv_cache is not None:
+                    y, state = selective_scan.s6_step(
+                        state, xs[:, 0], dt[:, 0], a, bs[:, 0], cs[:, 0])
+                    y = y[:, None]
+                else:
+                    y, state = selective_scan.s6_chunk_scan(
+                        state, xs, dt, a, bs, cs)
+            if kv_cache is not None:
+                new_cache = (conv_state, state, index)
+            y = s6_gate(y + skip * xs.astype(jnp.float32), z)
+            out = dense(cfg.hidden_size, name="out_proj")(
+                y.astype(cfg.dtype))
+        return out, new_cache
+
+
 def _sink_init(window: int):
     """Sinks drawn from N(ln(window), 1); N(0, 1) on a layer without a
     window."""
@@ -3012,6 +3212,8 @@ class TransformerBlock(nn.Module):
                 attn = ShortConv(cfg, name="conv")
             elif mixer == "ssm":
                 attn = Mamba2(cfg, name="ssm")
+            elif mixer == "s6":
+                attn = Mamba1(cfg, name="ssm")
             else:
                 attn = partial(
                     SelfAttention(cfg, attention=self.attention,
@@ -3330,8 +3532,10 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     that the entry is a triple as every layer's is.  An "ssm" layer holds
     two states: ((B, conv_taps - 1, ``ssm_conv_width``), (B, ssm_heads,
     ssm_head_dim, ssm_state_size)), the second float32 whatever the
-    caches' dtype (``init_kv_caches``).  A "none" layer (an MLP alone)
-    holds nothing: ((B, 0), (B, 0)).
+    caches' dtype (``init_kv_caches``); an "s6" layer likewise: ((B,
+    conv_taps - 1, ``s6_inner``), (B, ssm_state_size, ``s6_inner``)), the
+    channels minor-most.  A "none" layer (an MLP alone) holds nothing:
+    ((B, 0), (B, 0)).
     Takes any decoder family's configuration: what ``GPTConfig`` alone has
     reads as its default.
 
@@ -3345,7 +3549,9 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     positions minor-most (``_write_rows``), where a block of positions is
     no block of memory; four heads' 768 channels are six whole tiles, the
     cache lies as it is named, and the kernel that reads it takes it as
-    it lies (``ops/cached_attention.py`` ``folded_cached_attention``)."""
+    it lies (``ops/cached_attention.py`` ``folded_cached_attention``).  A
+    full layer of ONE key/value head lies folded too, (B, seq_len, D) each
+    (``GPTConfig.folds_full_caches``)."""
     heads = getattr(config, "num_kv_heads", None) or config.num_heads
     hd = getattr(config, "head_dim", None) or \
         config.hidden_size // config.num_heads
@@ -3389,13 +3595,22 @@ def kv_cache_shapes(config, batch_size: int) -> list:
                 (batch_size, config.ssm_heads, config.ssm_head_dim,
                  config.ssm_state_size)))
             continue
+        if kind == "s6":
+            shapes.append((
+                (batch_size, config.conv_taps - 1, config.s6_inner),
+                (batch_size, config.ssm_state_size, config.s6_inner)))
+            continue
         if kind == "none":
             shapes.append(((batch_size, 0), (batch_size, 0)))
             continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
         kv = heads_of(kind)
-        if dv == hd:
+        if kind != "sliding" and dv == hd and \
+                getattr(config, "folds_full_caches", False):
+            # ONE key/value head, folded as the wider keys below are
+            shapes.append((batch_size, length, kv * hd))
+        elif dv == hd:
             shapes.append((batch_size, length, kv, hd))
         elif kind == "sliding":
             shapes.append(((batch_size, length, kv, hd),
@@ -3413,15 +3628,15 @@ def kv_cache_kinds(config) -> list:
     "latent_index" (a latent layer that selects its positions: a row and
     an index key a position), "latent_window" (a "latent_sliding" layer's
     ring of latents), "conv" (a state and no positions), "ssm" (a Mamba-2
-    mixer's two states and no positions), "none" (a layer that is its MLP
-    alone: an empty entry)."""
+    or a Mamba-1 mixer's two states and no positions), "none" (a layer
+    that is its MLP alone: an empty entry)."""
     kinds = getattr(config, "attention", "full")
 
     def label(kind):
         if kind == "latent" and getattr(config, "index_topk", 0):
             return "latent_index"
-        return {"sliding": "window", LATENT_SLIDING: "latent_window"}.get(
-            kind, kind)
+        return {"sliding": "window", LATENT_SLIDING: "latent_window",
+                "s6": "ssm"}.get(kind, kind)
 
     entries = getattr(config, "cache_entries", config.num_layers)
     return [label(kinds if isinstance(kinds, str) else
@@ -3458,7 +3673,7 @@ def cached_key_block(config, queries: int) -> int:
     if "full" not in kinds:
         return 0
     q = of(1, queries, config.num_heads, config.head_size)
-    if config.value_size != config.head_size:
+    if config.folds_full_caches:
         # folded caches (``kv_cache_shapes``)
         caches = (of(1, config.seq_len, config.kv_heads * config.head_size),
                   of(1, config.seq_len, config.kv_heads * config.value_size))
@@ -3491,11 +3706,21 @@ def conv_states(config) -> bool:
 
 
 def ssm_states(config) -> bool:
-    """Whether any layer is a Mamba-2 mixer, whose cache entry is two
-    states of fixed size (the convolution's last positions, a matrix a
-    head) and no cache of positions."""
+    """Whether any layer is a Mamba-2 or a Mamba-1 mixer
+    (``GPTConfig.attention`` "ssm", "s6"), whose cache entry is two states
+    of fixed size (the convolution's last positions; a matrix a head, or
+    ``ssm_state_size`` values a channel) and no cache of positions."""
     return "ssm" in kv_cache_kinds(config)
 
+
+
+def _mamba_kind(config) -> tuple:
+    """(name, ``GPTConfig.attention`` kind) of the mixers whose entry is
+    labelled "ssm", for the refusals."""
+    kinds = getattr(config, "attention", "full")
+    if "s6" in ((kinds,) if isinstance(kinds, str) else kinds):
+        return "Mamba-1", "s6"
+    return "Mamba-2", "ssm"
 
 
 def uniform_kv_caches(config) -> bool:
@@ -3528,12 +3753,14 @@ def require_uniform_kv_caches(config, what: str):
             "back, and there are no positions to page or reorder: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     if ssm_states(config):
+        name, kind = _mamba_kind(config)
         raise ValueError(
             f"{what} indexes per-head K and V caches of one shape and "
             "rolls a row back by its index, and this configuration has "
-            "Mamba-2 mixers (GPTConfig.attention \"ssm\"), whose entry is "
-            "two states (the convolution's last conv_taps - 1 positions "
-            "and a matrix a head) that every step overwrites: no index "
+            f"{name} mixers (GPTConfig.attention \"{kind}\"), whose "
+            "entry is two states (the convolution's last conv_taps - 1 "
+            "positions, and a matrix a head or ssm_state_size values a "
+            "channel) that every step overwrites: no index "
             "brings an earlier state back, and there are no positions to "
             "page or reorder: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
@@ -3571,6 +3798,13 @@ def require_uniform_kv_caches(config, what: str):
             f"heads where a full layer has {config.kv_heads}: "
             f"{len(shapes)} shapes in one model, K and V unlike in every "
             f"layer: {shapes}")
+    if getattr(config, "folds_full_caches", False):
+        raise ValueError(
+            f"{what} indexes per-head K and V caches (B, positions, heads, "
+            "channels), and this configuration's \"full\" layers have ONE "
+            "key/value head, whose caches lie with the head folded into "
+            "the channels (GPTConfig.folds_full_caches): "
+            f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     if not uniform_kv_caches(config):
         raise ValueError(
             f"{what} indexes one cache shape for all layers, and this "
@@ -3591,6 +3825,7 @@ def require_rollback_by_index(config, what: str):
     generates by diffusion over blocks has no one token to verify: each
     is refused by name."""
     kinds = set(kv_cache_kinds(config))
+    mamba, mamba_kind = _mamba_kind(config)
     for kind, name in (
             ("window", "a ring of the sliding window's positions "
              "(GPTConfig.attention \"sliding\")"),
@@ -3598,8 +3833,8 @@ def require_rollback_by_index(config, what: str):
              "(GPTConfig.attention \"latent_sliding\")"),
             ("conv", "a short convolution's state (GPTConfig.attention "
              "\"conv\")"),
-            ("ssm", "a Mamba-2 mixer's states (GPTConfig.attention "
-             "\"ssm\")")):
+            ("ssm", f"a {mamba} mixer's states (GPTConfig.attention "
+             f"\"{mamba_kind}\")")):
         if kind in kinds:
             raise ValueError(
                 f"{what} rolls a rejected position back by the row's index "

@@ -266,15 +266,15 @@ def folded_block_k(k_cache, v_cache) -> int:
 
 def folded_fits(q, k_cache, v_cache) -> bool:
     """Whether ``folded_cached_attention`` takes these shapes: a few new
-    queries a row in whole sublanes, both caches' channels in whole lanes
-    (the key/value heads together), few enough key/value heads, the cache
-    in whole key blocks."""
+    queries a row (padded to whole sublanes there), both caches' channels
+    in whole lanes (the key/value heads together), few enough key/value
+    heads, the cache in whole key blocks."""
     _, s, nh, dim = q.shape
     seq_len, width = k_cache.shape[1], k_cache.shape[2]
     if width % dim:
         return False
     nkv = width // dim
-    if (s > MAX_QUERIES or nh % nkv or (s * nh) % 16 or
+    if (s > MAX_QUERIES or nh % nkv or
             nkv > MAX_KV_HEADS or v_cache.shape[2] % nkv or
             width % LANES or v_cache.shape[2] % LANES or
             width > BLOCK_ELEMENTS // 16):
@@ -322,6 +322,11 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
     # which key/value head a head reads
     group = jax.nn.one_hot(jnp.arange(nh) // (nh // nkv), nkv, dtype=q.dtype)
     q = (q[:, :, :, None, :] * group[:, :, None]).reshape(b, s * nh, k_width)
+    # a row's queries in whole sublanes: rows of zeros behind them (20
+    # query heads on one key/value head are 32 rows), which score 0 against
+    # every key they are shown and are dropped from the result
+    rows = -(-s * nh // 16) * 16
+    q = jnp.pad(q, ((0, 0), (0, rows - s * nh), (0, 0)))
 
     def per_row(b_, kb, blocks_ref, offset_ref):
         return b_, 0, 0
@@ -332,19 +337,19 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
     out = pl.pallas_call(
         functools.partial(_folded_kernel, scale=float(1 / np.sqrt(dim)),
                           heads=nh),
-        out_shape=jax.ShapeDtypeStruct((b, s * nh, v_width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, v_width), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, seq_len // per_block),
             in_specs=[
-                pl.BlockSpec((None, s * nh, k_width), per_row),
+                pl.BlockSpec((None, rows, k_width), per_row),
                 pl.BlockSpec((None, per_block, k_width), key_block),
                 pl.BlockSpec((None, per_block, v_width), key_block),
             ],
-            out_specs=pl.BlockSpec((None, s * nh, v_width), per_row),
-            scratch_shapes=[pltpu.VMEM((s * nh, 1), jnp.float32),
-                            pltpu.VMEM((s * nh, 1), jnp.float32),
-                            pltpu.VMEM((s * nh, v_width), jnp.float32)]),
+            out_specs=pl.BlockSpec((None, rows, v_width), per_row),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, v_width), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -352,8 +357,8 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
         # what a device trace calls the kernel's events
         name="cached_attention_folded_key_blocks",
     )(blocks, offset, q, k_cache, v_cache)
-    return jnp.einsum("bshgd,hg->bshd", out.reshape(b, s, nh, nkv, dv),
-                      group)
+    return jnp.einsum("bshgd,hg->bshd",
+                      out[:, :s * nh].reshape(b, s, nh, nkv, dv), group)
 
 
 # ---- many new queries a row (a prefill's chunk) ----
@@ -384,9 +389,13 @@ def _chunk_shapes(q, k_cache, v_cache):
         dv = v_cache.shape[2] // nkv
     else:
         nkv, dv = k_cache.shape[2], v_cache.shape[3]
-    if nh % nkv or QUERY_ROWS % (nh // nkv):
+    if nh % nkv or nh // nkv > QUERY_ROWS:
         return None
-    return nkv, dv, min(s, QUERY_ROWS // (nh // nkv))
+    # the most positions, a power of two, whose rows stay within
+    # ``QUERY_ROWS`` (20 heads on one key/value head: 64 positions, 1,280
+    # rows)
+    block_q = QUERY_ROWS // (nh // nkv)
+    return nkv, dv, min(s, 1 << (block_q.bit_length() - 1))
 
 
 def _lane_tiles(dim: int, kv_heads: int) -> int:

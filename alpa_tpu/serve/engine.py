@@ -153,8 +153,9 @@ _KV_CACHE_BYTES = _REG.gauge(
     "position where the layer selects its positions; a ring of the "
     "window's latents where the layer is under the window), conv (a "
     "short convolution's state: its last positions, whatever the context) "
-    "or ssm (a Mamba-2 mixer's two states: its convolution's last "
-    "positions and a matrix a head, whatever the context); "
+    "or ssm (a Mamba-2 or Mamba-1 mixer's two states: its convolution's "
+    "last positions and a matrix a head or a few values a channel, "
+    "whatever the context); "
     "the arrays' sizes as the device lays them out (held_bytes)",
     labelnames=("kind",))
 _KV_CACHE_ARRAY_BYTES = _REG.gauge(

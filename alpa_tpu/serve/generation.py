@@ -432,7 +432,14 @@ class Generator:
     the row's state and left at its last real position (a padded
     position's step is 0: ``gpt_model.real_steps``).  A layer that is its
     MLP alone (``attention`` "none") holds an empty entry, which every step
-    hands back at the others' index.  The same refusals hold.
+    hands back at the others' index.  The same refusals hold.  A Mamba-1
+    layer (``attention`` "s6") rides the same way: ``(conv state, ssm state
+    (B, N, D) float32 with the channels minor-most, index)``, the chunk
+    step's scan a kernel over positions from the row's state
+    (``ops/selective_scan.py``).  Beside it the caches of an attention
+    layer of ONE key/value head lie folded, (B, positions, D)
+    (``GPTConfig.folds_full_caches``), and are written and attended over
+    as the folded caches of wider keys are.
 
     ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in

@@ -49,11 +49,14 @@ part                         components
                              convolution whole: both products, the gates,
                              the taps, the state's update (and ``conv``,
                              its module: a weight the compiler copies)
-``ssm_mixer``                ``model.gpt_model.SSM_SCOPE``, a Mamba-2
-                             mixer whole: both projections, the
-                             convolution, the recurrence, the gated norm,
-                             both states' updates (and ``ssm``, its
+``ssm_mixer``                ``model.gpt_model.SSM_SCOPE``, a Mamba-2 or a
+                             Mamba-1 mixer whole: the projections, the
+                             convolution, the recurrence, the gate (and
+                             norm), both states' updates (and ``ssm``, its
                              module: a weight the compiler copies)
+``ssm_mixer.scan``           ``model.gpt_model.S6_SCAN_SCOPE``, a Mamba-1
+                             mixer's recurrence ALONE (the step of a tick,
+                             the walk over a chunk's positions)
 ``mlp``                      ``mlp`` (``MLPBlock``; a routed layer's
                              shared expert)
 ``moe``                      ``model.moe.SCOPE``: router, top-k, sort,
@@ -160,6 +163,9 @@ _COMPONENTS = {
     "short_conv": "short_conv", "conv": "short_conv",
     # model/gpt_model.py SSM_SCOPE, and ``ssm``, the module's name
     "ssm_mixer": "ssm_mixer", "ssm": "ssm_mixer",
+    # model/gpt_model.py S6_SCAN_SCOPE: a Mamba-1 mixer's recurrence alone,
+    # inside SSM_SCOPE
+    "selective_scan": "ssm_mixer.scan",
     "mlp": "mlp",
     "moe": "moe",
     "grouped_matmul": "moe.grouped_matmul",

@@ -97,9 +97,15 @@ def selecting_cores_traced():
     from alpa_tpu.telemetry import metrics as tmetrics
 
     def traced(queries):
-        return {key.split('core="')[1].split('"')[0]: value
-                for key, value in tmetrics.get_registry().snapshot().items()
-                if key.startswith("alpa_selecting_decode_core") and
-                'queries="%d"' % queries in key and
-                'positions="4096"' in key}
+        counts = {}
+        # (summed over the series' other label, the heads: another file's
+        # decode of as many queries over as long a cache, traced earlier
+        # in this process, is a series of its own)
+        for key, value in tmetrics.get_registry().snapshot().items():
+            if key.startswith("alpa_selecting_decode_core") and \
+                    'queries="%d"' % queries in key and \
+                    'positions="4096"' in key:
+                core = key.split('core="')[1].split('"')[0]
+                counts[core] = counts.get(core, 0) + value
+        return counts
     return traced

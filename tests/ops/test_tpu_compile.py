@@ -943,3 +943,84 @@ def test_a_chunks_expert_layer_walks_a_window_on_v5e(one_chip, monkeypatch,
     assert not re.search(r"\[1024,%d,%d\]" % (cfg.num_experts_per_tok,
                                                cfg.hidden_size), text)
     assert re.search(r" while\(", text)
+
+
+def test_a_jamba_tick_and_chunk_keep_their_named_bytes_on_v5e(one_chip):
+    """The decode tick and the chunk step of ``jamba2-3b-1chip`` at its
+    published widths, its cell's 16 rows and served context of 65,536, and
+    ONE Mamba-1 and ONE attention layer.  The tick: both states of the
+    mixer and both caches of the attention's one key/value head are
+    aliased to the results that replace them, at their NAMED bytes (an ssm
+    state 16 x 5,120 float32 = 327,680 B a row, a cache 128 x 2 B a
+    position a tensor: no 16 state values padded to 128 lanes, no one head
+    padded to a tile of 16), nothing copies a state, and its attention is
+    the folded kernel over key blocks (20 query rows padded to 32).  The
+    chunk: its attention is the kernel over query blocks and key blocks
+    (64 positions x 20 heads a block), its recurrence the kernel over
+    positions, and no loop walks either."""
+    from alpa_tpu.model.gpt_model import GPTModel, init_kv_caches
+    from alpa_tpu.serve.generation import Generator
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from chipbench import run
+    hf = dict(run.load_json(run.HERE, "configs", "jamba2-3b-1chip.json"),
+              num_hidden_layers=2, attn_layer_period=2, attn_layer_offset=1)
+    serve = hf["serve"]
+    rows, chunk, context = (serve["engine_rows"], serve["prefill_chunk"],
+                            serve["served_context"])
+    cfg = run.load_module("drivers", "serve_s6").model_config(
+        hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=context)
+    assert cfg.attention == ("s6", "full") and (rows, context) == (16, 65536)
+    model = GPTModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)))
+    gen = Generator(model, params, cfg, prefill_chunk=chunk)
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    tick = gen._decode.jitted.lower(
+        params, spec(rows, 1), spec(rows),
+        on_chip([(k, v) for k, v, _ in caches]),
+        [spec(rows) for _ in caches]).compile()
+    memory = tick.memory_analysis()
+    held = rows * (16 * 5120 * 4 + 3 * 5120 * 2) + \
+        rows * context * 2 * 128 * 2
+    assert held == sum(x.size * x.dtype.itemsize for k, v, _ in caches
+                       for x in (k, v))
+    assert memory.alias_size_in_bytes == held
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    # (the arguments: the weights, the caches, the ids and the indices)
+    assert memory.argument_size_in_bytes < weights + held + 2**20
+    assert memory.temp_size_in_bytes < 16 * 2**20
+    text = tick.as_text()
+    head = text[:text.index("\n")]
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         head)
+    assert len(aliased) == 2 * 2
+    assert "f32[%d,16,5120]{2,1,0:T(8,128)}" % rows in head
+    assert "bf16[%d,%d,128]{2,1,0:T(8,128)(2,1)}" % (rows, context) in head
+    moved = [line for line in text.splitlines()
+             if re.search(r"= (f32\[%d,16,5120\]|bf16\[%d,%d,128\])\S* "
+                          r"(copy|transpose|convert)\(" % (rows, rows,
+                                                           context), line)]
+    assert not moved, moved[:3]
+    assert "cached_attention_folded_key_blocks" in text
+    step = gen._chunk_prefill.lower(
+        params, spec(1, chunk), spec(1),
+        on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1))),
+        spec(1, cfg.vocab_size, dtype=jnp.bfloat16)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 2**28
+    text = step.as_text()
+    assert "cached_attention_query_key_blocks" in text
+    assert "selective_scan_positions" in text
+    assert not [line for line in text.splitlines() if " while(" in line]
+    assert not re.search(r"\[[\d,]*%d,%d\]" % (chunk, context), text)
