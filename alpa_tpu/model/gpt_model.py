@@ -2259,7 +2259,7 @@ def latent_attention_selected(q_nope, q_pe, rows, w_kv_b, scale, offset,
             [w_kv_b[..., :dn], jnp.zeros(w_kv_b.shape[:2] + (spare,),
                                          w_kv_b.dtype), w_kv_b[..., dn:]],
             -1)
-    if not kernel.fits(wide_q, rows, wide_w):
+    if not kernel.fits(wide_q, rows, wide_w, masked=True):
         return masked(q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected)
     return jax.lax.platform_dependent(
         q_nope, q_pe, rows, k_pe, w_kv_b, offset, selected, wide_q, wide_w,

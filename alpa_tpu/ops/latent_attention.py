@@ -59,8 +59,18 @@ queries with a block of the row's keys, reduced over the heads in the
 kernel (a few queries a row, a verify of a tick that drafts: the same
 grid, the block fetched once and scored a query in turn).  Key blocks past a row's last query are filled with ``-inf`` and
 not fetched.  A chunk then runs ``expanded`` with the selection as one more
-operand, a block of (queries, keys) int8 a step.  A decode (one query a
-row, or a verify's few) has two cores (``gpt_model.
+operand, a block of (queries, keys) int8 a head a step beside the block's
+latents and shared keys, in key blocks of 1,024 (``SELECTED_BLOCK_K``).
+What a step costs beside its products is what it does a ROW of the scores,
+above all the reductions across lanes (at 1,024 queries of 512 keys 2.2 of
+a step's 5.3 us, the exponentials and the mask nothing; PERF.md, PR 62):
+key blocks of 1,024 halve those a key.  With them out of the way the mask's
+passes over the scores count, so a hidden key is hidden by ONE ``where`` on
+the scores (``_HIDDEN``) and the block folded in as if all were seen.  A
+step of 1,024 keys takes its queries in four parts, one after another
+(``SELECTED_PARTS``), so that its unrolled code is no longer than a step's
+of 512.  A decode (one query a row, or a verify's few) has two cores
+(``gpt_model.
 latent_attention_over_selection`` says which a call takes): it gathers each
 query's selected rows and runs ``absorbed`` over the copy as over a cache
 of 2,048 positions, the real ones first, at a fixed price a (row, query)
@@ -86,6 +96,17 @@ from jax.experimental.pallas import tpu as pltpu
 # keys a step of the expanded kernel: with 1,024 queries a step's float32
 # scores are 2 MB
 BLOCK_K = 512
+# and of the kernel under a selection: what a step does a ROW of its scores
+# (the reductions across lanes of the running maximum and sum, the
+# rescaling of what it holds) it does half as often a key; at dots3-note's
+# widths that is a third of the kernel (PERF.md, PR 62)
+SELECTED_BLOCK_K = 1024
+# and the parts a step under a selection takes its queries in: a step's
+# code is unrolled over its scores, and a program's code lies in the chip's
+# memory, which GLM-5's cell fills to the last MB; in parts of a quarter a
+# step of 1,024 keys is less code than one of 512 was, at a twelfth more
+# time.  ``scripts/time_dsa_parts.py --chunk-core --sweep`` times both
+SELECTED_PARTS = 4
 # and of the absorbed one, whose step is a microsecond of work: 1 MB of
 # latents, so that a step's fixed cost stays a small part of it
 DECODE_BLOCK_K = 1024
@@ -93,18 +114,26 @@ DECODE_BLOCK_K = 1024
 # queries, the scores and their exponentials, two blocks in flight
 VMEM_LIMIT = 48 * 2**20
 _FLOOR = -1e30
-# what a device trace calls the decode's kernel under a selection's mask
+# the score of a key that a query of the chunk's kernel under a selection
+# does not see: below any running maximum, so it never is one
+_HIDDEN = 2 * _FLOOR
+# what a device trace calls the decode's kernel under a selection's mask,
+# and the chunk's
 UNDER_MASK_NAME = "latent_decode_under_mask"
+CHUNK_UNDER_MASK_NAME = "latent_chunk_under_mask"
 
 
-def fits(q_nope, c, w_kv_b) -> bool:
+def fits(q_nope, c, w_kv_b, masked: bool = False) -> bool:
     """Whether the kernel takes these shapes: the heads' channels and the
-    latent's in whole lanes of 128, the queries in whole sublanes, the
-    cache in whole key blocks."""
+    latent's in whole lanes of 128, the queries (``masked``, under a
+    selection: each part of them a step takes) in whole sublanes, the cache
+    in whole key blocks."""
     sq, dn = q_nope.shape[1], q_nope.shape[3]
     rank, dv = w_kv_b.shape[0], w_kv_b.shape[2] - dn
+    block_k, parts = (SELECTED_BLOCK_K, SELECTED_PARTS) if masked else \
+        (BLOCK_K, 1)
     return (dn % 128 == 0 and dv % 128 == 0 and rank % 128 == 0 and
-            sq % 16 == 0 and c.shape[1] % BLOCK_K == 0)
+            sq % (16 * parts) == 0 and c.shape[1] % block_k == 0)
 
 
 def _start(m_ref, l_ref, acc_ref):
@@ -113,12 +142,13 @@ def _start(m_ref, l_ref, acc_ref):
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
-def _fold_in(s, seen, values, m_ref, l_ref, acc_ref, keys_last=False):
+def _fold_in(s, seen, values, m_ref, l_ref, acc_ref, keys_last=False,
+             at=slice(None)):
     """One block of the online softmax: scores ``s`` (queries, keys)
     float32 of which ``seen`` count (None: all), and the keys' ``values``
     (keys, d), or (d, keys) with ``keys_last``, into the running maximum,
-    sum and weighted values."""
-    m_prev = m_ref[:]
+    sum and weighted values (the rows ``at`` of each: all)."""
+    m_prev = m_ref[at]
     if seen is None:
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -128,9 +158,9 @@ def _fold_in(s, seen, values, m_ref, l_ref, acc_ref, keys_last=False):
             jnp.max(jnp.where(seen, s, _FLOOR), axis=1, keepdims=True))
         p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
     keep = jnp.exp(m_prev - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * keep + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * keep + lax.dot_general(
+    m_ref[at] = m_new
+    l_ref[at] = l_ref[at] * keep + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[at] = acc_ref[at] * keep + lax.dot_general(
         p.astype(values.dtype), values,
         (((1,), (1 if keys_last else 0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -148,13 +178,15 @@ def _block_of(b, kb, blocks_ref):
 
 
 def _kernel(blocks_ref, offset_ref, qn_ref, qp_ref, c_ref, kpe_ref, w_ref,
-            *rest, scale: float, dn: int, masked: bool = False):
+            *rest, scale: float, dn: int, masked: bool = False,
+            parts: int = 1):
     # with ``masked`` one more operand before the output: the block's
-    # (queries, keys) int8 of the keys each query may see at all
+    # (queries, keys) int8 of the keys each query may see at all; a step
+    # takes its queries in ``parts``, one after another
     sel_ref = rest[0] if masked else None
     o_ref, m_ref, l_ref, acc_ref = rest[masked:]
     b, kb = pl.program_id(0), pl.program_id(2)
-    sq, block_k = qn_ref.shape[0], c_ref.shape[0]
+    block_k, tq = c_ref.shape[0], qn_ref.shape[0] // parts
     pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
 
     @pl.when(kb < blocks_ref[b])
@@ -162,19 +194,39 @@ def _kernel(blocks_ref, offset_ref, qn_ref, qp_ref, c_ref, kpe_ref, w_ref,
         # this head's keys and values of the block, from its latents
         kv = jnp.dot(c_ref[:], w_ref[:],
                      preferred_element_type=jnp.float32).astype(c_ref.dtype)
-        s = scale * (
-            lax.dot_general(qn_ref[:], kv[:, :dn], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) +
-            jnp.dot(qp_ref[:], kpe_ref[:],
-                    preferred_element_type=jnp.float32))
-        q_pos = offset_ref[b] + lax.broadcasted_iota(
-            jnp.int32, (sq, block_k), 0)
-        k_pos = kb * block_k + lax.broadcasted_iota(
-            jnp.int32, (sq, block_k), 1)
-        seen = k_pos <= q_pos
-        if masked:
-            seen &= sel_ref[:].astype(jnp.int32) != 0
-        _fold_in(s, seen, kv[:, dn:], m_ref, l_ref, acc_ref)
+
+        def part(mine, start=None):
+            """The queries ``mine``, the first the chunk's ``start``-th
+            (None: its first)."""
+            s = scale * (
+                lax.dot_general(qn_ref[mine], kv[:, :dn],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) +
+                jnp.dot(qp_ref[mine], kpe_ref[:],
+                        preferred_element_type=jnp.float32))
+            first = offset_ref[b] if start is None else offset_ref[b] + start
+            q_pos = first + lax.broadcasted_iota(jnp.int32, (tq, block_k), 0)
+            k_pos = kb * block_k + lax.broadcasted_iota(
+                jnp.int32, (tq, block_k), 1)
+            seen = k_pos <= q_pos
+            if masked:
+                # one pass over the scores for the mask, where ``_fold_in``
+                # makes two: a hidden key's exponential is exactly 0
+                # against any running maximum, which starts at the floor
+                s = jnp.where(seen & (sel_ref[mine].astype(jnp.int32) != 0),
+                              s, _HIDDEN)
+                seen = None
+            _fold_in(s, seen, kv[:, dn:], m_ref, l_ref, acc_ref, at=mine)
+
+        if parts == 1:
+            part(slice(None))
+        else:
+            def in_turn(i, _):
+                start = pl.multiple_of(i * tq, tq)
+                part(pl.ds(start, tq), start)
+                return _
+
+            lax.fori_loop(0, parts, in_turn, None)
 
     pl.when(kb == pl.num_programs(2) - 1)(
         lambda: _finish(o_ref, l_ref, acc_ref))
@@ -189,26 +241,28 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
     dtype.  ``c`` may be wider than ``r`` (a selecting layer's rows): its
     first ``r`` channels are the latent.  ``selected`` ((B, Sq, Sk) int8,
     None: all): of the keys at or before a query, those it sees; the
-    kernel walks the same key blocks and fetches a block of the mask a
-    head beside them."""
+    kernel walks the same keys in blocks of ``SELECTED_BLOCK_K`` and fetches
+    a block of the mask a head beside them."""
     b, sq, nh, dn = q_nope.shape
     dr, sk = k_pe.shape[1], c.shape[1]
     rank, dv = w_kv_b.shape[0], w_kv_b.shape[2] - dn
-    nk = sk // BLOCK_K
+    masked = selected is not None
+    block_k = SELECTED_BLOCK_K if masked else BLOCK_K
+    nk = sk // block_k
     offset = offset.astype(jnp.int32)
     # the key blocks a row's last query reaches into
-    blocks = jnp.clip((offset + sq - 1) // BLOCK_K + 1, 1, nk)
+    blocks = jnp.clip((offset + sq - 1) // block_k + 1, 1, nk)
 
     def per_head(b_, h, kb, blocks_ref, offset_ref):
         return b_, h, 0, 0
 
-    masked = selected is not None
     mask_spec = [pl.BlockSpec(
-        (None, sq, BLOCK_K),
+        (None, sq, block_k),
         lambda b_, h, kb, blocks_ref, offset_ref:
         (b_, 0, _block_of(b_, kb, blocks_ref)))] if masked else []
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, dn=dn, masked=True)
+        functools.partial(_kernel, scale=scale, dn=dn, masked=True,
+                          parts=SELECTED_PARTS)
         if masked else functools.partial(_kernel, scale=scale, dn=dn),
         out_shape=jax.ShapeDtypeStruct((b, nh, sq, dv), q_nope.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -218,11 +272,11 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
                 pl.BlockSpec((None, None, sq, dn), per_head),
                 pl.BlockSpec((None, None, sq, dr), per_head),
                 pl.BlockSpec(
-                    (None, BLOCK_K, rank),
+                    (None, block_k, rank),
                     lambda b_, h, kb, blocks_ref, offset_ref:
                     (b_, _block_of(b_, kb, blocks_ref), 0)),
                 pl.BlockSpec(
-                    (None, dr, BLOCK_K),
+                    (None, dr, block_k),
                     lambda b_, h, kb, blocks_ref, offset_ref:
                     (b_, 0, _block_of(b_, kb, blocks_ref))),
                 pl.BlockSpec(
@@ -237,6 +291,7 @@ def expanded(q_nope, q_pe, c, k_pe, w_kv_b, offset, *, scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name=CHUNK_UNDER_MASK_NAME if masked else None,
     )(blocks, offset, q_nope.transpose(0, 2, 1, 3),
       q_pe.transpose(0, 2, 1, 3), c, k_pe, w_kv_b.transpose(1, 0, 2),
       *([selected] if masked else []))

@@ -8,10 +8,15 @@ OLMoE layer with its routed experts, and the served decode and chunk step
 of LFM2 and Trinity at a depth of four and five layers, and the decode tick
 (the verify of a tick that drafts, GLM-5's) of the six served cells that hold
 a share of their experts, at a depth of two layers, or as many as hold one
-of every kind.  Two checkouts whose lines
-agree build the same programs for those cells (PR 58 compared its tree
-with its parent so; PR 60 added the six ticks, which its windows over a
-chunk's expert rows leave as they were)."""
+of every kind; and of those six the chunk step and the tick once more,
+lowered FOR A TPU (``<name>.chunk.tpu``, ``<name>.decode.tpu``: the Pallas
+kernels that ``lax.platform_dependent`` chooses there are in the text, body
+and all, where a lowering for the CPU holds their ``jax.numpy`` twins).  Two
+checkouts whose lines agree build the same programs for those cells (PR 58
+compared its tree with its parent so; PR 60 added the six ticks, which its
+windows over a chunk's expert rows leave as they were; PR 62 the lowerings
+for a TPU, which its kernel under a selection changes in the chunk steps of
+dots3-note and GLM-5 alone)."""
 import hashlib, json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 root = os.path.abspath(sys.argv[1])
@@ -22,6 +27,18 @@ from alpa_tpu.serve.generation import Generator
 
 def digest(lowered):
     return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+
+def for_tpu(jitted, *args):
+    """The program lowered for a TPU; a kernel's body, which the text holds as bytecode with the checkout's paths and line numbers in it, as its text without them."""
+    import base64, re
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    def body(match):
+        with mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            return ir.Module.parse(base64.b64decode(match.group(1))).operation.get_asm(enable_debug_info=False)
+    text = jitted.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    return hashlib.sha256(re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text).encode()).hexdigest()[:16]
 
 def abstract(tree):
     return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
@@ -81,7 +98,10 @@ for name, layers in (("deepseek-v2-1chip", 2), ("longcat-flash-1chip", 2), ("dot
     cfg = serve_mla.model_config(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=serve["served_context"])
     gen, params, rows, tick = served(cfg, serve)
     if gen._verify_draft is None:
-        out[name + ".decode"] = digest(gen._decode.jitted.lower(*tick))
+        decode = gen._decode.jitted
     else:
-        out[name + ".decode"] = digest(gen._verify_draft.jitted.lower(*tick, S((rows,), jnp.int32), S((rows,), jnp.int32), S((rows,), jnp.bool_)))
+        decode, tick = gen._verify_draft.jitted, tick + (S((rows,), jnp.int32), S((rows,), jnp.int32), S((rows,), jnp.bool_))
+    out[name + ".decode"] = digest(decode.lower(*tick))
+    out[name + ".decode.tpu"] = for_tpu(decode, *tick)
+    out[name + ".chunk.tpu"] = for_tpu(gen._chunk_prefill, params, S((1, serve["prefill_chunk"]), jnp.int32), S((1,), jnp.int32), jax.eval_shape(lambda: init_kv_caches(cfg, 1)), S((1, cfg.vocab_size), jnp.bfloat16))
 print(json.dumps(out))

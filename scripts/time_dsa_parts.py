@@ -6,6 +6,7 @@ twin there:
     chiprun -- python3 scripts/time_dsa_parts.py [--held 8192 32768]
     chiprun -- python3 scripts/time_dsa_parts.py --selection
     chiprun -- python3 scripts/time_dsa_parts.py --cores [--held 2048 8192]
+    chiprun -- python3 scripts/time_dsa_parts.py --chunk-core [--sweep]
 
 One JSON line a part: ``ms`` (the median of ``--repeat`` runs that end in
 ``block_until_ready``) and, where the part has a twin, ``max_diff`` against
@@ -37,6 +38,19 @@ against the other (``max_diff``).  The last line of a shape is the fit that
 ``gpt_model.GATHER_WORTH_KEY_BLOCKS`` quotes: a (row, query)'s gather in key
 blocks under the mask.  ``--tiny`` rehearses the same on a CPU at toy widths,
 the kernel interpreted.
+
+``--chunk-core``: the core of a selecting layer's CHUNK alone
+(``la.expanded``) at both selecting cells' shapes (dots3-note: 1,024 queries
+of 128 heads, keys and values of 128 channels, a cache of 32,768; GLM-5: 64
+heads, keys of 192 channels handed in as 256 and values of 256, a cache of
+24,576), the chunk ending at ``held`` (``--held``, and the whole cache):
+under the selection's mask beside the same call without one, which walks the
+same keys in blocks of 512 (ROADMAP A14(a)(1)'s comparison at one shape);
+with a short cache, each against ``gm._latent_attention_masked``
+(``max_diff``).  ``--sweep`` adds the masked core at key blocks of 512 and
+1,024, and at 1,024 a step's queries in one, two and four parts: the readings
+that ``la.SELECTED_BLOCK_K`` and ``la.SELECTED_PARTS`` rest on.  ``--tiny``
+rehearses it on a CPU.
 """
 import argparse
 from functools import partial
@@ -256,6 +270,73 @@ def cores(repeat, helds, tiny):
                 1))), flush=True)
 
 
+def chunk_core(repeat, helds, tiny, sweep):
+    """A chunk's expanded core with and without its mask (module
+    docstring)."""
+    sq, rank, topk = 1024, RANK, TOPK
+    # heads, a key's channels as the kernel takes them, a value's, the cache
+    shapes = {"dots3-note": (128, 128, 128, 32768),
+              "glm-5": (64, 256, 256, 24576)}
+    if tiny:
+        sq, rank, topk = 64, 128, 128
+        shapes, helds = {"toy": (4, 128, 128, 2048)}, [1024]
+    width = gm.latent_row_width(rank, DR)
+
+    def core(start, masked):
+        def run(qn, qp, r, kp, wk, m):
+            return la.expanded(
+                qn, qp, r, kp, wk, jnp.asarray([start], jnp.int32),
+                scale=SCALE, interpret=tiny,
+                selected=m.astype(jnp.int8) if masked else None)
+        return run
+
+    def twin(start, masked):
+        def run(qn, qp, r, kp, wk, m):
+            seen = jnp.arange(r.shape[1])[None, None] <= \
+                start + jnp.arange(qn.shape[1])[None, :, None]
+            return gm._latent_attention_masked(
+                qn, qp, r[..., :rank], kp, wk, seen & m if masked else seen,
+                scale=SCALE)
+        return run
+
+    for name, (heads, dn, dv, context) in shapes.items():
+        rows = rnd(1, 1, context, width)
+        k_pe = rows[:, :, rank:rank + DR].swapaxes(1, 2)
+        w_kv_b = rnd(2, rank, heads, dn + dv) * rank ** -0.5
+        q_nope, q_pe = rnd(9, 1, sq, heads, dn), rnd(10, 1, sq, heads, DR)
+        for held in [h for h in helds if sq <= h < context] + [context]:
+            start = held - sq
+            q_pos = start + jnp.arange(sq, dtype=jnp.int32)[None]
+            scores = jnp.where(
+                jnp.arange(context)[None, None] <= q_pos[..., None],
+                rnd(11, 1, sq, context, dtype=jnp.float32), -jnp.inf)
+            mask = jax.jit(lambda s: gm.selected_mask_upto(
+                s, topk, jnp.int32(held)))(scores)
+            more = {"shape": name, "heads": heads, "held": held}
+            args = (q_nope, q_pe, rows, k_pe, w_kv_b, mask)
+            for masked, part in ((True, "chunk expanded under the mask"),
+                                 (False, "chunk expanded, no mask")):
+                timed(part, core(start, masked), *args, repeat=repeat,
+                      **more)
+                if held <= 8192:
+                    # the twin holds every head's scores: a quarter of the
+                    # queries over a cache that ends with the chunk
+                    few = sq // 4
+                    timed(part + ", short cache", core(start, masked),
+                          q_nope[:, :few], q_pe[:, :few], rows[:, :held],
+                          k_pe[:, :, :held], w_kv_b, mask[:, :few, :held],
+                          repeat=3, twin=twin(start, masked), **more)
+            if not sweep:
+                continue
+            shipped = la.SELECTED_BLOCK_K, la.SELECTED_PARTS
+            for block_k, parts in ((512, 1), (1024, 1), (1024, 2), (1024, 4)):
+                la.SELECTED_BLOCK_K, la.SELECTED_PARTS = block_k, parts
+                timed("chunk expanded under the mask, swept",
+                      core(start, True), *args, repeat=repeat,
+                      block_k=block_k, parts=parts, **more)
+            la.SELECTED_BLOCK_K, la.SELECTED_PARTS = shipped
+
+
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--held", type=int, nargs="+",
@@ -266,15 +347,23 @@ def main():
     parser.add_argument("--cores", action="store_true",
                         help="a decode's two cores over one selection, both "
                         "shapes")
+    parser.add_argument("--chunk-core", action="store_true",
+                        help="a chunk's expanded core with and without its "
+                        "mask, both shapes")
+    parser.add_argument("--sweep", action="store_true",
+                        help="with --chunk-core: the masked core by key "
+                        "block and by the parts of a step's queries")
     parser.add_argument("--tiny", action="store_true",
-                        help="with --cores: toy widths, the kernel "
-                        "interpreted (a CPU rehearsal)")
+                        help="with --cores or --chunk-core: toy widths, the "
+                        "kernel interpreted (a CPU rehearsal)")
     args = parser.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     if args.selection:
         return selection(args.repeat)
     if args.cores:
         return cores(args.repeat, args.held, args.tiny)
+    if args.chunk_core:
+        return chunk_core(args.repeat, args.held, args.tiny, args.sweep)
     keys = rnd(0, ROWS, CONTEXT, DI)
     rows = rnd(1, ROWS, CONTEXT, 640)
     w_kv_b = rnd(2, RANK, HEADS, DN + DV) * RANK ** -0.5

@@ -345,25 +345,82 @@ def test_the_index_kernels_are_their_twin(rows, queries, starts):
                                np.asarray(want)[seen], atol=1e-4)
 
 
-def test_the_masked_kernel_is_the_masked_twin():
-    sq, heads, dn, dr, dv, rank, keys = 32, 2, 128, 64, 128, 128, 1024
-    qn, qp = rnd(6, 1, sq, heads, dn), rnd(7, 1, sq, heads, dr)
-    rows, w = rnd(8, 1, keys, rank + dr), rnd(9, rank, heads, dn + dv) * 0.1
-    q_pos = 600 + jnp.arange(sq)[None]
+# name: (rows' offsets, heads, a key's channels before its padding to a
+# whole lane, a value's channels); 64 queries a row, which a step takes in
+# four parts of 16, over a cache of two key blocks of 1,024
+MASKED_CASES = {
+    "one-row": ((600,), 2, 128, 128),
+    # row 0's queries end in the first of the cache's two key blocks
+    "two-rows-one-ends-early": ((100, 1500), 2, 128, 128),
+    "three-heads": ((1500,), 3, 128, 128),
+    # the second key block begins among the queries of a step's first part
+    # (row 0) and of its second (row 1)
+    "a-key-block-begins-inside-a-part": ((1010, 1000), 2, 128, 128),
+    # GLM-5's: keys padded to a whole lane, values of two lanes
+    "keys-padded-values-two-lanes": ((1500,), 4, 96, 256),
+    # a whole key block in which some queries selected nothing before the
+    # block that holds what they did, and one query that selected nothing
+    # at all
+    "first-key-block-holds-nothing": ((1100, 1500), 4, 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", MASKED_CASES)
+def test_the_masked_kernel_is_the_masked_twin(case):
+    offsets, heads, dn, dv = MASKED_CASES[case]
+    b, sq, dr, rank, keys = len(offsets), 64, 64, 128, 2048
+    qn, qp = rnd(6, b, sq, heads, dn), rnd(7, b, sq, heads, dr)
+    rows, w = rnd(8, b, keys, rank + dr), rnd(9, rank, heads, dn + dv) * 0.1
+    offset = jnp.asarray(offsets)
+    q_pos = offset[:, None] + jnp.arange(sq)[None]
     scores = jnp.where(jnp.arange(keys)[None, None] <= q_pos[:, :, None],
-                       rnd(10, 1, sq, keys), -jnp.inf)
+                       rnd(10, b, sq, keys), -jnp.inf)
     chosen = gpt_model.selected_mask(scores, 100)
+    answered = np.ones((b, sq), bool)
+    if case == "first-key-block-holds-nothing":
+        chosen = chosen.at[0, :8, :1024].set(False)
+        chosen = chosen.at[1, 4:12, :1024].set(False).at[1, 20].set(False)
+        answered[1, 20] = False
+        assert chosen[0, :8].any(-1).all() and chosen[1, 4:12].any(-1).all()
     k_pe = rows[..., rank:].swapaxes(1, 2)
-    got = kernels.expanded(qn, qp, rows, k_pe, w, jnp.asarray([600]),
+    # as ``latent_attention_selected`` hands a key of 96 channels in
+    spare = -dn % 128
+    wide_q = jnp.pad(qn, ((0, 0),) * 3 + ((0, spare),))
+    wide_w = jnp.concatenate(
+        [w[..., :dn], jnp.zeros(w.shape[:2] + (spare,)), w[..., dn:]], -1)
+    assert kernels.fits(wide_q, rows, wide_w, masked=True)
+    got = kernels.expanded(wide_q, qp, rows, k_pe, wide_w, offset,
                            scale=0.07, selected=chosen.astype(jnp.int8),
                            interpret=True)
     want = gpt_model._latent_attention_masked(
         qn, qp, rows[..., :rank], k_pe, w, chosen, scale=0.07)
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    everything = kernels.expanded(qn, qp, rows, k_pe, w,
-                                  jnp.asarray([600]), scale=0.07,
-                                  interpret=True)
-    assert float(jnp.abs(everything - want).max()) > 1e-2
+    np.testing.assert_allclose(np.asarray(got)[answered],
+                               np.asarray(want)[answered], atol=2e-5)
+    # a query that selected nothing answers 0
+    assert (np.asarray(got)[~answered] == 0).all()
+    if case == "one-row":
+        everything = kernels.expanded(wide_q, qp, rows, k_pe, wide_w, offset,
+                                      scale=0.07, interpret=True)
+        assert float(jnp.abs(everything - want).max()) > 1e-2
+
+
+@pytest.mark.parametrize("queries,keys,unmasked,masked", [
+    (1024, 32768, True, True),      # dots3-note's chunk over its cache
+    (1024, 24576, True, True),      # GLM-5's
+    (64, 2048, True, True),
+    (64, 1536, True, False),        # whole key blocks of 512, not of 1,024
+    (32, 2048, True, False),        # a part of the queries half a sublane tile
+    (24, 2048, False, False)])
+def test_the_masked_kernel_takes_whole_blocks_of_its_own(queries, keys,
+                                                         unmasked, masked):
+    """Under a selection the kernel walks key blocks of ``SELECTED_BLOCK_K``
+    and takes a step's queries in parts: a cache or a chunk that is not
+    whole ones goes to the ``jax.numpy`` twin (``gpt_model.
+    latent_attention_selected`` asks ``fits``)."""
+    shapes = (jnp.zeros((1, queries, 2, 128)), jnp.zeros((1, keys, 192)),
+              jnp.zeros((128, 2, 256)))
+    assert kernels.fits(*shapes) == unmasked
+    assert kernels.fits(*shapes, masked=True) == masked
 
 
 # ---- against the reference --------------------------------------------

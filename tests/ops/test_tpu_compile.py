@@ -415,6 +415,15 @@ def test_selecting_latent_kernels_compile_for_v5e(one_chip, kernel, shapes):
         # lies: no array of its size beside it
         assert "%" + la.UNDER_MASK_NAME in text
         assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    if kernel == "expanded":
+        # by its name in a device trace, and the selection handed in as it
+        # lies: the program makes no array of the mask's shape beside it
+        assert "%" + la.CHUNK_UNDER_MASK_NAME in text
+        queries, keys = shapes[-1][0][1:]
+        made = [line.strip()[:120] for line in text.splitlines()
+                if re.search(r"= \S*\[(1,)?%d,%d\]" % (queries, keys), line)
+                and " parameter(" not in line]
+        assert not made, made
 
 
 _SELECTING_DECODES = {}
