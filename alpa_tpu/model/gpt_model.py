@@ -287,6 +287,31 @@ class GPTConfig:
     # with a bias over the channels
     s6_inner: int = 0
     s6_dt_rank: int = 0
+    # --- keys of two kinds under one softmax (``model_type`` evabyte; EVA,
+    # Zheng et al. 2023, in its deterministic form).  Every default is the
+    # model of today.  An ``attention`` kind "eva": positions lie in
+    # aligned windows of ``eva_window`` and chunks of ``eva_chunk``; a
+    # query sees the exact keys of its OWN window up to itself and, in the
+    # same softmax, one pooled key and value for every full chunk of every
+    # window before it, pooled by two learned vectors a head (``mu`` the
+    # keys, ``phi`` the values; ``eva_pool``).  Its cache holds both kinds
+    # of row in one pair of arrays, the summaries' slots first
+    # (``kv_cache_shapes``, ``update_eva_cache``)
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # the residual stream float32 whatever ``dtype``: every sub-block's
+    # output is added into it in float32 and each norm reads it
+    fp32_residual: bool = False
+    # an RMSNorm's gain is ``1 + scale`` (the stored weight starts at 0)
+    norm_unit_offset: bool = False
+    # the head's logits float32: products of ``dtype``, summed in float32
+    # and never rounded to ``dtype``
+    fp32_logits: bool = False
+    # prediction heads of ONE untied matrix, hidden -> ``num_pred_heads x
+    # vocab_size``: the logits are (..., num_pred_heads x vocab_size), head
+    # ``j``'s ``vocab_size`` columns (the ``j``-th group) predict the token
+    # ``j + 1`` positions ahead off the same final hidden state
+    num_pred_heads: int = 1
 
     def mlp_kind(self, layer: int) -> str:
         """The MLP kind of a layer; a multi-token-prediction module's
@@ -532,6 +557,12 @@ _HF_KINDS = {
     # mixer, no bias but the convolution's and ``dt_proj``'s)
     "jamba": dict(norm="rmsnorm", positions="rotary",
                   rope_on_full_attention=False, mlp="gated"),
+    # EvaByte: wiring read from EVA's paper (Zheng et al., ICLR 2023) and
+    # the family's description where config.json does not fix it (a
+    # pre-norm block of attention and a gated MLP, rotate-half rotary pairs
+    # over every channel at the absolute position, the pooling's form)
+    "evabyte": dict(norm="rmsnorm", positions="rotary", attention="eva",
+                    mlp="gated"),
 }
 
 
@@ -1021,6 +1052,39 @@ def _jamba_fields(hf: dict) -> dict:
     return fields
 
 
+def _evabyte_fields(hf: dict) -> dict:
+    """What ``config.json`` of ``model_type`` evabyte says beyond the keys
+    all decoders share: the window and the chunk of its attention
+    (``window_size``, ``chunk_size``; ``attention_class`` must be "eva",
+    with one key/value head a query head), the float32 residual stream
+    (``fp32_skip_add``), the norms' unit offset (``norm_add_unit_offset``),
+    float32 logits (``fp32_logits``) and the prediction heads of its one
+    head matrix (``num_pred_heads``).  ``mixedp_attn`` is what every
+    attention core here does (a float32 softmax over products of
+    ``dtype``); ``num_chunks``, ``init_fn``, ``init_std``,
+    ``init_cutoff_factor``, ``lazy_init``, ``fp32_ln`` (the norm reads the
+    float32 stream whatever it says) and ``max_seq_length`` are read by
+    nothing."""
+    if hf["attention_class"] != "eva":
+        raise ValueError("evabyte: only attention_class \"eva\" is "
+                         f"supported, got {hf['attention_class']!r}")
+    if hf["num_key_value_heads"] != hf["num_attention_heads"]:
+        raise ValueError("evabyte: a summary is pooled a key/value head by "
+                         "that head's own vectors: only one key/value head "
+                         "a query head is supported")
+    if hf["window_size"] % hf["chunk_size"]:
+        raise ValueError("evabyte: window_size is no multiple of chunk_size")
+    return dict(
+        num_layers=hf["num_hidden_layers"],
+        intermediate_size=hf["intermediate_size"],
+        activation=hf["hidden_act"], layer_norm_eps=hf["rms_norm_eps"],
+        tie_embeddings=hf["tie_word_embeddings"],
+        eva_window=hf["window_size"], eva_chunk=hf["chunk_size"],
+        fp32_residual=hf["fp32_skip_add"],
+        norm_unit_offset=hf["norm_add_unit_offset"],
+        fp32_logits=hf["fp32_logits"], num_pred_heads=hf["num_pred_heads"])
+
+
 # what each model type's file says beyond the keys all share
 _HF_FIELDS = {
     "olmoe": _olmoe_fields, "afmoe": _afmoe_fields,
@@ -1031,6 +1095,7 @@ _HF_FIELDS = {
     "glm_moe_dsa": _glm_moe_dsa_fields,
     "nemotron_h": _nemotron_h_fields,
     "jamba": _jamba_fields,
+    "evabyte": _evabyte_fields,
 }
 
 
@@ -1070,8 +1135,33 @@ def config_from_hf(hf: dict, **kwargs) -> GPTConfig:
     return GPTConfig(**fields)
 
 
+def norm_gain(scale):
+    """What a norm with a unit offset multiplies by
+    (``GPTConfig.norm_unit_offset``): one more than its stored weight."""
+    return 1.0 + scale
+
+
+class UnitOffsetRMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + epsilon) * (1 + scale)`` in float32, the
+    stored ``scale`` starting at 0 (``GPTConfig.norm_unit_offset``)."""
+    epsilon: float
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           self.param_dtype)
+        x = x.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.epsilon)
+        return y * norm_gain(scale.astype(jnp.float32))
+
+
 def make_norm(config: GPTConfig, name: str) -> nn.Module:
     """The normalisation the configuration names, computed in float32."""
+    if config.norm == "rmsnorm" and config.norm_unit_offset:
+        return UnitOffsetRMSNorm(config.layer_norm_eps, config.param_dtype,
+                                 name=name)
     if config.norm == "rmsnorm":
         # scale * x / sqrt(mean(x^2) + eps)
         return nn.RMSNorm(epsilon=config.layer_norm_eps, dtype=jnp.float32,
@@ -2996,6 +3086,300 @@ class Mamba1(nn.Module):
         return out, new_cache
 
 
+# the scope an "eva" layer's summaries are traced under, inside
+# ``ATTENTION_SCOPE``: the pooling of the chunks' keys and values and the
+# write of the pooled rows (a capture reads it: telemetry/device_time.py)
+EVA_SCOPE = "eva_summaries"
+
+
+def eva_pool(k, v, mu, phi, scale: float):
+    """The summaries of chunks of positions (``GPTConfig.attention``
+    "eva"): ``k``, ``v`` (..., C, H, D), the ``C`` positions of a chunk,
+    the keys rotated; ``mu``, ``phi`` (H, D) float32, a head's two learned
+    vectors.  ``a_j = softmax_j(scale k_j . mu)``, ``k~ = sum_j a_j k_j``;
+    ``b_j = softmax_j(scale k_j . phi)``, ``v~ = sum_j b_j v_j``: both
+    softmaxes over the chunk's positions and off the KEYS, in float32.
+    Returns ``(k~, v~)`` (..., H, D) float32."""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def weights(vector):
+        return jax.nn.softmax(scale * jnp.einsum(
+            "...chd,hd->...ch", k32, vector.astype(jnp.float32)), axis=-2)
+
+    return (jnp.einsum("...ch,...chd->...hd", weights(mu), k32),
+            jnp.einsum("...ch,...chd->...hd", weights(phi), v32))
+
+
+def eva_seen(position, window: int, chunk: int, queries: int):
+    """How many summaries a query at ``position`` sees: those of every
+    chunk of every window before its own (the chunks ``0 ..`` under it);
+    ``queries`` new positions a row in the step."""
+    return position // window * (window // chunk)
+
+
+def eva_exact_from(position, window: int):
+    """The first position whose exact key a query at ``position`` sees:
+    the start of its own aligned window."""
+    return position // window * window
+
+
+def eva_reach(position, window: int, queries: int):
+    """The window's slot that holds the key AT ``position``, the last one
+    its query sees (``queries`` new positions a row in the step)."""
+    return position % window
+
+
+def eva_keys_to_pool(unrotated, rotated):
+    """The keys an "eva" layer's summaries are pooled from, of a step's new
+    ones: the rotated ones, as the cache holds them."""
+    return rotated
+
+
+def eva_attention(q, k, v, mu, phi, window: int, chunk: int,
+                  pooled_from=None):
+    """The attention of an "eva" layer over whole sequences, without a
+    cache (a forward pass, ``init``): ``q``, ``k``, ``v`` (B, S, H, D), the
+    queries and keys rotated.  The query at ``t`` sees the keys ``j`` with
+    ``eva_exact_from(t) <= j <= t`` and the summaries (``eva_pool``, cast
+    to the keys' dtype as a cache holds them) of the full chunks ``c <
+    eva_seen(t)``, under ONE float32 softmax; a sequence's last, unfinished
+    chunk has no summary (and lies in the window of every query that could
+    see it)."""
+    b, s, nh, dim = q.shape
+    scale = float(1 / np.sqrt(dim))
+    chunks = s // chunk
+    with jax.named_scope(EVA_SCOPE):
+        k_pool = k if pooled_from is None else pooled_from
+        k_sum, v_sum = eva_pool(
+            k_pool[:, :chunks * chunk].reshape(b, chunks, chunk, nh, dim),
+            v[:, :chunks * chunk].reshape(b, chunks, chunk, nh, dim),
+            mu, phi, scale)
+        k_sum, v_sum = k_sum.astype(k.dtype), v_sum.astype(v.dtype)
+    t = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
+    exact = (at <= t) & (at >= eva_exact_from(t, window))
+    pooled = jax.lax.broadcasted_iota(jnp.int32, (1, chunks), 1) < \
+        eva_seen(t, window, chunk, s)
+    scores = scale * jnp.concatenate(
+        [_einsum_f32("bqhd,bkhd->bhqk", q, k),
+         _einsum_f32("bqhd,bkhd->bhqk", q, k_sum)], axis=-1)
+    scores = jnp.where(jnp.concatenate([exact, pooled], axis=-1)[None, None],
+                       scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs[..., :s], v) + \
+        jnp.einsum("bhqk,bkhd->bqhd", probs[..., s:], v_sum)
+
+
+def eva_chunks_taken(first, lengths):
+    """Which of a step's new full chunks (the positions of their first
+    keys, ``first`` (B, n)) have their summaries written; ``lengths`` (B,)
+    the rows' whole lengths or None.  None: all of them, the chunks of a
+    padded prompt's padding too: a tick overwrites every summary of a
+    window before the window moves on (``update_eva_cache``)."""
+    return None
+
+
+def eva_tick_writes(index, chunk: int, held_now):
+    """Whether a tick at the positions ``index`` (B,) writes the summary of
+    the chunk it is in over what the slot holds (``held_now()``: (B, H D),
+    the pooled keys there); None: always (``update_eva_cache``)."""
+    return None
+
+
+def update_eva_cache(kv_cache, k, v, mu, phi, window: int, chunk: int,
+                     lengths=None, pooled_from=None):
+    """``update_kv_cache`` for an "eva" layer, whose cache is written
+    TWICE.  ``kv_cache`` is (k_cache, v_cache, index), each array (B, N +
+    W, H D) with the heads folded into the channels: the first ``N =
+    seq_len / C`` slots hold the summaries, slot ``c`` the pooled key (or
+    value) of chunk ``c`` of the row's positions (``eva_pool``), the last
+    ``W`` the window's rows, position ``p`` at slot ``N + p % W``.  No
+    ring: a window slot past ``index % W`` holds the window before's row
+    (or padding), which the causal offset hides as ``update_kv_cache``'s
+    does what a row has not reached.  ``index`` is the position of the
+    first of the ``s`` new tokens (rotated keys ``k``, values ``v``, (B, s,
+    H, D)).
+
+    Several new positions (a prefill chunk, at a scalar index): ``s``
+    divides the window and ``C`` divides ``s``, and the caller keeps
+    ``index`` a multiple of ``s``, so the step never straddles a window and
+    holds ``s / C`` whole chunks: their rows go to the window's slots and
+    their summaries to the slots ``index / C ..``.  A right-padded prompt
+    pools its padding too: into the summary of the chunk the prompt ends
+    in and of those behind it.  None of these is ever read as it is: a
+    query sees the summaries of the windows BEFORE its own, and before a
+    row's window moves on the ticks have rewritten them.
+
+    One new position (a tick, per-row indices): the row is written at slot
+    ``N + index % W``, then the summary of the chunk it lies in is pooled
+    once more from that chunk's ``C`` window slots and written at slot
+    ``index / C``.  At the chunk's last position all ``C`` slots hold the
+    row's own keys, whatever part of the chunk a prefill wrote; before it
+    the later slots hold junk (finite: caches start as zeros and hold the
+    model's own rows), and so does the summary, which nothing reads yet.
+    A row whose positions ran out of the cache (an engine's free row)
+    writes no summary.
+
+    ``pooled_from``: the keys a prefill chunk's summaries are pooled from
+    where they are not ``k`` (``eva_keys_to_pool``).  Returns ``(k_cache,
+    v_cache, index + s)``."""
+    k_cache, v_cache, index = kv_cache
+    index = jnp.asarray(index, jnp.int32)
+    b, s, nh, dim = k.shape
+    held = k_cache.shape[1] - window              # the summaries' slots
+    scale = float(1 / np.sqrt(dim))
+
+    def folded(x, like):
+        return x.reshape(x.shape[0], x.shape[1], nh * dim).astype(like.dtype)
+
+    if s > 1:
+        if index.ndim or window % s or s % chunk:
+            raise ValueError(
+                f"an \"eva\" layer's cached step of {s} positions must "
+                f"start at one index for all rows, divide the window "
+                f"({window}) and hold whole chunks of {chunk}: prefill in "
+                "chunks (Generator(prefill_chunk=...))")
+        with jax.named_scope(CACHE_WRITE_SCOPE):
+            at = held + index % window
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, folded(k, k_cache), at, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, folded(v, v_cache), at, axis=1)
+        with jax.named_scope(EVA_SCOPE):
+            n = s // chunk
+            k_pool = k if pooled_from is None else pooled_from
+            k_sum, v_sum = eva_pool(k_pool.reshape(b, n, chunk, nh, dim),
+                                    v.reshape(b, n, chunk, nh, dim),
+                                    mu, phi, scale)
+            k_sum, v_sum = folded(k_sum, k_cache), folded(v_sum, v_cache)
+            slot = index // chunk
+            taken = eva_chunks_taken(
+                index + chunk * jax.lax.broadcasted_iota(
+                    jnp.int32, (b, n), 1), lengths)
+            if taken is not None:
+                k_sum, v_sum = (
+                    jnp.where(taken[:, :, None], new,
+                              jax.lax.dynamic_slice_in_dim(old, slot, n, 1))
+                    for new, old in ((k_sum, k_cache), (v_sum, v_cache)))
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_sum,
+                                                          slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_sum,
+                                                          slot, axis=1)
+        return k_cache, v_cache, index + s
+
+    index_b = jnp.broadcast_to(index, (b,))
+    in_window = index_b % window
+    with jax.named_scope(CACHE_WRITE_SCOPE):
+        k_cache = _write_latent_rows(k_cache, folded(k, k_cache),
+                                     held + in_window, 1)
+        v_cache = _write_latent_rows(v_cache, folded(v, v_cache),
+                                     held + in_window, 1)
+    with jax.named_scope(EVA_SCOPE):
+        # the window's slots of the chunk each row's new position lies in
+        first = held + in_window // chunk * chunk
+
+        def rows_of(cache, start, n):
+            """(B, n, H D): ``n`` slots of every row from ``start[r]``, of
+            the cache seen as two dimensions (``_write_latent_rows``)."""
+            slots, width = cache.shape[1:]
+            flat = cache.reshape(b * slots, width)
+            return jnp.stack([jax.lax.dynamic_slice(
+                flat, (r * slots + start[r], 0), (n, width))
+                for r in range(b)])
+
+        k_sum, v_sum = eva_pool(
+            rows_of(k_cache, first, chunk).reshape(b, 1, chunk, nh, dim),
+            rows_of(v_cache, first, chunk).reshape(b, 1, chunk, nh, dim),
+            mu, phi, scale)
+        slot = index_b // chunk
+        writes = slot < held
+
+        def held_now():
+            """(B, H D): the pooled key each row's slot holds."""
+            return rows_of(k_cache, jnp.minimum(slot, held - 1), 1)[:, 0]
+
+        asked = eva_tick_writes(index_b, chunk, held_now)
+        if asked is not None:
+            writes &= asked
+        # (a row that writes none: an index that does not fit)
+        slot = jnp.where(writes, slot, -1)
+        k_cache = _write_latent_rows(k_cache, folded(k_sum, k_cache), slot, 1)
+        v_cache = _write_latent_rows(v_cache, folded(v_sum, v_cache), slot, 1)
+    return k_cache, v_cache, index + 1
+
+
+def eva_cached_attention(q, k_cache, v_cache, index, window: int, chunk: int):
+    """The attention of an "eva" layer's ``s`` new queries ``q`` (B, s, H,
+    D), the first at ``index`` (a scalar, or (B,) a row), over its written
+    cache (``update_eva_cache``): slot ``j`` under ``eva_seen(index)`` (a
+    summary of a window before the queries') is seen by every query, slot
+    ``N + j`` (a row of the queries' own window) by the queries at or past
+    it, ``j <= eva_reach(index) + i``, and the slots between by none.
+
+    One of three cores, by what the call's shapes say: where the program
+    is lowered for a TPU the Pallas kernels of ``ops/cached_attention.py``
+    that ``cached_attention`` takes over folded caches, each told how many
+    leading slots every query sees and where the causal part begins: the
+    one over key blocks for a few new queries a row at per-row indices (a
+    tick), the one over query blocks and key blocks for many (a prefill
+    chunk); a skipped slot is not fetched.  On any other platform, and for
+    shapes neither takes, one product over every slot under the same mask.
+    The gauge ``alpa_cached_attention_core`` says which."""
+    from alpa_tpu.ops import cached_attention as kernel
+    index = jnp.asarray(index, jnp.int32)
+    b, s, nh, dim = q.shape
+    held = k_cache.shape[1] - window
+    seen = jnp.broadcast_to(eva_seen(index, window, chunk, s), (b,))
+    offset = jnp.broadcast_to(eva_reach(index, window, s), (b,))
+    core = "reference"
+    if index.ndim == 1 and kernel.eva_fits(q, k_cache, held):
+        core = "key_blocks"
+    elif kernel.chunk_fits(q, k_cache, v_cache) and \
+            held % kernel.CHUNK_BLOCK_K == 0:
+        core = "query_key_blocks"
+    _cached_core_gauge().labels(core, nh, dim, s).inc()
+    return _eva_core(q, k_cache, v_cache, offset, seen, held=held, core=core)
+
+
+@partial(jax.jit, static_argnames=("held", "core"))
+def _eva_core(q, k_cache, v_cache, offset, seen, held, core):
+    """``eva_cached_attention``'s core: the kernel where the program is
+    lowered for a TPU, the product over every slot anywhere else; a ``jit``
+    of its own as ``_attention_over_key_blocks`` is."""
+    from alpa_tpu.ops import cached_attention as kernel
+    twin = partial(_eva_attention_over_slots, held=held)
+    if core == "reference":
+        return twin(q, k_cache, v_cache, offset, seen)
+    take = kernel.folded_cached_attention if core == "key_blocks" \
+        else kernel.chunk_attention
+
+    def on_tpu(q, k_cache, v_cache, offset, seen):
+        return take(q, k_cache, v_cache, offset, seen=seen, exact_from=held)
+
+    return jax.lax.platform_dependent(q, k_cache, v_cache, offset, seen,
+                                      tpu=on_tpu, default=twin)
+
+
+def _eva_attention_over_slots(q, k_cache, v_cache, offset, seen, held: int):
+    """``q`` (B, s, H, D) over every slot of folded caches (B, S, H D)
+    under ``eva_cached_attention``'s mask (``offset``, ``seen`` (B,)): a
+    float32 softmax over the products, the probabilities cast to the
+    queries' dtype before the values' product."""
+    b, s, nh, dim = q.shape
+    slots = k_cache.shape[1]
+    scores = _einsum_f32("bqhd,bkhd->bhqk", q,
+                         k_cache.reshape(b, slots, nh, dim)) / np.sqrt(dim)
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, 1, slots), 2)
+    reach = offset[:, None, None] + jax.lax.broadcasted_iota(
+        jnp.int32, (1, s, 1), 1)
+    visible = (at < seen[:, None, None]) | \
+        ((at >= held) & (at - held <= reach))
+    scores = jnp.where(visible[:, None], scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                      v_cache.reshape(b, slots, nh, dim))
+
+
 def _sink_init(window: int):
     """Sinks drawn from N(ln(window), 1); N(0, 1) on a layer without a
     window."""
@@ -3020,7 +3404,7 @@ class SelfAttention(nn.Module):
             raise ValueError("a padding bias goes with no cache: a cached "
                              "call masks by the rows' offsets")
         kind = self.attention or cfg.attention_kind(0)
-        if kind not in ("full", "sliding"):
+        if kind not in ("full", "sliding", "eva"):
             raise ValueError(f"unknown attention kind {kind!r}")
         # keys (and queries) of ``hd`` channels, values of ``dv``
         h, nh, nkv, hd, dv = (cfg.hidden_size, cfg.num_heads,
@@ -3055,6 +3439,7 @@ class SelfAttention(nn.Module):
                     [apply_rotary(x[..., :turned], position_ids, theta),
                      x[..., turned:]], axis=-1)
 
+            unrotated = k
             q, k = rotate(q), rotate(k)
         sink = None
         if kind in cfg.sink_kinds:
@@ -3066,6 +3451,22 @@ class SelfAttention(nn.Module):
             # 1 / 129 of a head's mass and its absence hides in rounding
             sink = self.param("sink", _sink_init(window), (nh,),
                               jnp.float32)
+
+        eva = ()
+        if kind == "eva":
+            if nkv != nh or dv != hd or cfg.positions != "rotary" or \
+                    not cfg.rope_on_full_attention or not cfg.causal or \
+                    not cfg.eva_chunk or cfg.eva_window % cfg.eva_chunk:
+                raise ValueError(
+                    "an \"eva\" layer is causal, has one key/value head a "
+                    "query head, values as wide as the keys, rotary "
+                    "positions and a window (GPTConfig.eva_window) of whole "
+                    "chunks (eva_chunk)")
+            # a head's two pooling vectors (``eva_pool``), float32
+            eva = tuple(self.param(name, nn.initializers.normal(1.0),
+                                   (nh, hd), jnp.float32)
+                        for name in ("mu", "phi")) + \
+                (cfg.eva_window, cfg.eva_chunk)
 
         new_cache = None
         # the scope of the attention core (the cache's update, scores,
@@ -3079,7 +3480,17 @@ class SelfAttention(nn.Module):
             WINDOW_CORE_SCOPE if window else FULL_CORE_SCOPE) \
             if cfg.unlike_kinds else contextlib.nullcontext()
         with jax.named_scope(ATTENTION_SCOPE), of_kind:
-            if kv_cache is not None and window:
+            if eva and kv_cache is not None:
+                index = jnp.asarray(kv_cache[2], jnp.int32)
+                new_cache = update_eva_cache(
+                    kv_cache, k, v, *eva, lengths=cache_lengths,
+                    pooled_from=eva_keys_to_pool(unrotated, k))
+                out = eva_cached_attention(q, *new_cache[:2], index,
+                                           *eva[2:])
+            elif eva:
+                out = eva_attention(
+                    q, k, v, *eva, pooled_from=eva_keys_to_pool(unrotated, k))
+            elif kv_cache is not None and window:
                 index = jnp.asarray(kv_cache[2], jnp.int32)
                 k_use, v_use, k_positions, new_cache = update_ring_cache(
                     kv_cache, k, v, cache_lengths)
@@ -3313,6 +3724,12 @@ def _routers_said(routings) -> dict:
     return said
 
 
+def stream_dtype(config: GPTConfig):
+    """What a float32 residual stream is kept in
+    (``GPTConfig.fp32_residual``)."""
+    return jnp.float32
+
+
 class GPTModel(nn.Module):
     """Decoder-only LM.  Returns logits (and new kv caches if given).  A
     configuration with routed-expert layers returns ``(logits, routing)``
@@ -3375,9 +3792,18 @@ class GPTModel(nn.Module):
         tok_emb = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                            name="wte")
+        if cfg.tie_embeddings and (cfg.num_pred_heads > 1 or
+                                   cfg.fp32_logits):
+            raise ValueError("several prediction heads and float32 logits "
+                             "go with an untied head")
+        # float32 logits: products of ``dtype`` summed in float32
+        exact = {"dot_general": partial(
+            jax.lax.dot_general, preferred_element_type=jnp.float32)} \
+            if cfg.fp32_logits else {}
         lm_head = None if cfg.tie_embeddings else nn.Dense(
-            cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
-            param_dtype=cfg.param_dtype, name="lm_head")
+            cfg.vocab_size * cfg.num_pred_heads, dtype=cfg.dtype,
+            use_bias=False, param_dtype=cfg.param_dtype, name="lm_head",
+            **exact)
 
         def head(x):
             if cfg.tie_embeddings:
@@ -3428,6 +3854,10 @@ class GPTModel(nn.Module):
                             selected_real=selection[1][None])
             return logits, new_caches, said
         x = tok_emb(input_ids)
+        if cfg.fp32_residual:
+            # every block adds into the stream in its dtype and each norm
+            # reads it (``TransformerBlock``)
+            x = x.astype(stream_dtype(cfg))
         if cfg.scale_embedding:
             x = x * jnp.asarray(np.sqrt(cfg.hidden_size), x.dtype)
         if cfg.positions == "learned":
@@ -3535,7 +3965,10 @@ def kv_cache_shapes(config, batch_size: int) -> list:
     caches' dtype (``init_kv_caches``); an "s6" layer likewise: ((B,
     conv_taps - 1, ``s6_inner``), (B, ssm_state_size, ``s6_inner``)), the
     channels minor-most.  A "none" layer (an MLP alone) holds nothing:
-    ((B, 0), (B, 0)).
+    ((B, 0), (B, 0)).  An "eva" layer holds rows of two kinds in one pair
+    of arrays of one shape, (B, ``sum(eva_slots)``, H D), the heads folded
+    into the channels: a pooled key (value) for every chunk of the context
+    first, the rows of one window behind them (``update_eva_cache``).
     Takes any decoder family's configuration: what ``GPTConfig`` alone has
     reads as its default.
 
@@ -3603,6 +4036,9 @@ def kv_cache_shapes(config, batch_size: int) -> list:
         if kind == "none":
             shapes.append(((batch_size, 0), (batch_size, 0)))
             continue
+        if kind == "eva":
+            shapes.append((batch_size, sum(eva_slots(config)), heads * hd))
+            continue
         length = min(config.sliding_window, config.seq_len) \
             if kind == "sliding" else config.seq_len
         kv = heads_of(kind)
@@ -3619,6 +4055,15 @@ def kv_cache_shapes(config, batch_size: int) -> list:
             shapes.append(((batch_size, length, kv * hd),
                            (batch_size, length, kv * dv)))
     return shapes
+
+
+def eva_slots(config) -> tuple:
+    """(summaries, window rows): the slots of an "eva" layer's cache, the
+    summaries' first: one for every ``eva_chunk`` positions of the served
+    context (the last window's are written and never read: that many keep
+    the window's rows at a whole number of the kernels' key blocks), and
+    the window's ``eva_window`` (``update_eva_cache``)."""
+    return -(-config.seq_len // config.eva_chunk), config.eva_window
 
 
 def kv_cache_kinds(config) -> list:
@@ -3714,6 +4159,25 @@ def ssm_states(config) -> bool:
 
 
 
+def eva_caches(config) -> bool:
+    """Whether any layer's cache holds rows of two kinds, summaries of
+    chunks beside one window's own rows (``GPTConfig.attention`` "eva"): no
+    array of the context's positions, and an index that rolls a row back
+    only inside its current window."""
+    return "eva" in kv_cache_kinds(config)
+
+
+_EVA_ENTRY = (
+    "layers whose softmax runs over the exact keys of the query's own "
+    "aligned window beside pooled summaries of the chunks before it "
+    "(GPTConfig.attention \"eva\"): a cache entry holds the summaries "
+    "and ONE window's rows, a row at slot position % eva_window, so that "
+    "no array holds the context's positions, a step that straddles a "
+    "window's edge has overwritten the window before, and an index rolls "
+    "a row back only inside its current window and only while the summary "
+    "of its chunk is pooled again")
+
+
 def _mamba_kind(config) -> tuple:
     """(name, ``GPTConfig.attention`` kind) of the mixers whose entry is
     labelled "ssm", for the refusals."""
@@ -3732,6 +4196,7 @@ def uniform_kv_caches(config) -> bool:
     calls are handed the rows' lengths as a ring's are; so is one with a
     Mamba-2 mixer's states, or with a layer that holds nothing."""
     return not conv_states(config) and not ssm_states(config) and \
+        not eva_caches(config) and \
         len(set(kv_cache_shapes(config, 1))) == 1
 
 
@@ -3763,6 +4228,12 @@ def require_uniform_kv_caches(config, what: str):
             "channel) that every step overwrites: no index "
             "brings an earlier state back, and there are no positions to "
             "page or reorder: "
+            f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
+    if eva_caches(config):
+        raise ValueError(
+            f"{what} indexes per-head K and V caches of one shape that hold "
+            f"the context's positions, and this configuration has "
+            f"{_EVA_ENTRY}: "
             f"{sorted(set(kv_cache_shapes(config, 1)), key=str)}")
     kinds = set(kv_cache_kinds(config))
     if kinds & {"latent_index", "latent_window"}:
@@ -3834,7 +4305,11 @@ def require_rollback_by_index(config, what: str):
             ("conv", "a short convolution's state (GPTConfig.attention "
              "\"conv\")"),
             ("ssm", f"a {mamba} mixer's states (GPTConfig.attention "
-             f"\"{mamba_kind}\")")):
+             f"\"{mamba_kind}\")"),
+            ("eva", "summaries of chunks beside ONE window's rows "
+             "(GPTConfig.attention \"eva\": a rejected position at a "
+             "window's first slot has overwritten the window before's row, "
+             "and the summary of its chunk was pooled with it)")):
         if kind in kinds:
             raise ValueError(
                 f"{what} rolls a rejected position back by the row's index "
@@ -3858,6 +4333,13 @@ def require_one_token_steps(config, what: str):
     tick that verifies a draft (``_verify_draft``) refuses such a
     configuration itself, with the rings and the states
     (``require_rollback_by_index``)."""
+    if eva_caches(config):
+        raise ValueError(
+            f"{what} resumes a row at any position of a cache that holds "
+            f"the context's positions, and this configuration has "
+            f"{_EVA_ENTRY}: serve it through Generator(prefill_chunk=...) "
+            "and a ContinuousBatchingEngine with chunked_admission, whose "
+            "chunks divide the window, and nothing else")
     block = getattr(config, "block_length", 0)
     if block:
         raise ValueError(

@@ -282,12 +282,58 @@ def folded_fits(q, k_cache, v_cache) -> bool:
     return seq_len % folded_block_k(k_cache, v_cache) == 0
 
 
-def _folded_kernel(blocks_ref, offset_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale: float, heads: int):
+def eva_fits(q, k_cache, held: int) -> bool:
+    """Whether ``folded_cached_attention`` takes these shapes with the
+    cache in two parts (``seen``): a few new queries a row (padded to
+    whole sublanes there), heads of whole lanes folded into the channels,
+    one key/value
+    head a query head (any number of them: a key block of such a cache is
+    ``BLOCK_ELEMENTS`` whatever the heads, and a step's scores are ``H x
+    positions``, not ``H`` times as many), and both parts in whole key
+    blocks, the first ``held`` slots and the rest."""
+    _, s, nh, dim = q.shape
+    slots, width = k_cache.shape[1], k_cache.shape[2]
+    if (k_cache.ndim != 3 or s > MAX_QUERIES or width != nh * dim or
+            dim % LANES or width > BLOCK_ELEMENTS // 16):
+        return False
+    per_block = folded_block_k(k_cache, k_cache)
+    return held % per_block == 0 and slots % per_block == 0
+
+
+def _two_parts(kb, per_block: int, seen, exact_from: int, blocks):
+    """Of a cache in two parts (every slot under ``seen`` visible, the
+    slots from ``exact_from`` on causal, ``blocks`` key blocks of them
+    needed, the slots between visible to none), whether step ``kb`` has a
+    key block to fold in, and the block it fetches: its own where it has,
+    and where it has not the last one fetched before it (the same again:
+    no fetch), or the causal part's first while nothing was."""
+    under = -(-seen // per_block)
+    first = exact_from // per_block
+    wanted = (kb < under) | ((kb >= first) & (kb < first + blocks))
+    at = jnp.where(
+        kb < under, kb, jnp.where(
+            kb < first, jnp.where(under > 0, under - 1, first),
+            jnp.minimum(kb, first + blocks - 1)))
+    return wanted, at
+
+
+def _folded_kernel(blocks_ref, offset_ref, *refs, scale: float, heads: int,
+                   exact_from=None):
+    # with ``exact_from`` one more prefetched scalar a row before the
+    # operands: the leading slots every query of the row sees
+    seen_ref = None
+    if exact_from is not None:
+        seen_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     b, kb = pl.program_id(0), pl.program_id(1)
     pl.when(kb == 0)(lambda: _start(m_ref, l_ref, acc_ref))
+    if seen_ref is None:
+        wanted = kb < blocks_ref[b]
+    else:
+        wanted, _ = _two_parts(kb, k_ref.shape[0], seen_ref[b], exact_from,
+                               blocks_ref[b])
 
-    @pl.when(kb < blocks_ref[b])
+    @pl.when(wanted)
     def _block():
         # keys (position, key/value head x channel), the queries each in
         # the channels of its head's group: a column is a position
@@ -298,27 +344,42 @@ def _folded_kernel(blocks_ref, offset_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32)
         k_pos = kb * per_block + lax.broadcasted_iota(
             jnp.int32, (1, per_block), 1)
-        _fold_in(s, k_pos <= offset_ref[b] + row // heads, v_ref[:], m_ref,
-                 l_ref, acc_ref)
+        reach = offset_ref[b] + row // heads
+        if seen_ref is None:
+            seen = k_pos <= reach
+        else:
+            seen = (k_pos < seen_ref[b]) | (
+                (k_pos >= exact_from) & (k_pos - exact_from <= reach))
+        _fold_in(s, seen, v_ref[:], m_ref, l_ref, acc_ref)
 
     pl.when(kb == pl.num_programs(1) - 1)(
         lambda: _finish(o_ref, l_ref, acc_ref))
 
 
-def folded_cached_attention(q, k_cache, v_cache, offset, *,
-                            interpret: bool = False):
+def folded_cached_attention(q, k_cache, v_cache, offset, *, seen=None,
+                            exact_from: int = 0, interpret: bool = False):
     """``q`` (B, s, H, D) against written caches with the heads folded into
     the channels, ``k_cache`` (B, S, Hkv D) and ``v_cache`` (B, S, Hkv
     Dv); row ``b``'s query i sits at ``offset[b] + i`` ((B,) int32) and
     sees the keys at or before it.  Returns (B, s, H, Dv) in the queries'
-    dtype."""
+    dtype.
+
+    ``seen`` ((B,) int32): the cache is in two parts (an "eva" layer's:
+    ``gpt_model.update_eva_cache``).  Every query of row ``b`` sees the
+    slots under ``seen[b]``; the slots from ``exact_from`` on are the
+    causal part, slot ``exact_from + j`` seen by the queries with ``j <=
+    offset[b] + i``; the slots between are seen by none, and their key
+    blocks are not fetched (``_two_parts``).  One more prefetched scalar a
+    row and one more term in the mask: the walk over key blocks and the
+    online softmax are the one kernel's."""
     b, s, nh, dim = q.shape
     seq_len, k_width = k_cache.shape[1], k_cache.shape[2]
     nkv, v_width = k_width // dim, v_cache.shape[2]
     dv = v_width // nkv
     per_block = folded_block_k(k_cache, v_cache)
     offset = offset.astype(jnp.int32)
-    blocks = blocks_read(offset + s - 1, per_block, seq_len)
+    blocks = blocks_read(offset + s - 1, per_block,
+                         seq_len - (exact_from if seen is not None else 0))
     # which key/value head a head reads
     group = jax.nn.one_hot(jnp.arange(nh) // (nh // nkv), nkv, dtype=q.dtype)
     q = (q[:, :, :, None, :] * group[:, :, None]).reshape(b, s * nh, k_width)
@@ -328,18 +389,28 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
     rows = -(-s * nh // 16) * 16
     q = jnp.pad(q, ((0, 0), (0, rows - s * nh), (0, 0)))
 
-    def per_row(b_, kb, blocks_ref, offset_ref):
+    def per_row(b_, kb, *scalars):
         return b_, 0, 0
 
-    def key_block(b_, kb, blocks_ref, offset_ref):
-        return b_, _block_of(b_, kb, blocks_ref), 0
+    if seen is None:
+        scalars, two_parts = (blocks, offset), {}
+
+        def key_block(b_, kb, blocks_ref, offset_ref):
+            return b_, _block_of(b_, kb, blocks_ref), 0
+    else:
+        scalars = (blocks, offset, seen.astype(jnp.int32))
+        two_parts = {"exact_from": exact_from}
+
+        def key_block(b_, kb, blocks_ref, offset_ref, seen_ref):
+            return b_, _two_parts(kb, per_block, seen_ref[b_], exact_from,
+                                  blocks_ref[b_])[1], 0
 
     out = pl.pallas_call(
         functools.partial(_folded_kernel, scale=float(1 / np.sqrt(dim)),
-                          heads=nh),
+                          heads=nh, **two_parts),
         out_shape=jax.ShapeDtypeStruct((b, rows, v_width), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b, seq_len // per_block),
             in_specs=[
                 pl.BlockSpec((None, rows, k_width), per_row),
@@ -356,7 +427,7 @@ def folded_cached_attention(q, k_cache, v_cache, offset, *,
         interpret=interpret,
         # what a device trace calls the kernel's events
         name="cached_attention_folded_key_blocks",
-    )(blocks, offset, q, k_cache, v_cache)
+    )(*scalars, q, k_cache, v_cache)
     return jnp.einsum("bshgd,hg->bshd",
                       out[:, :s * nh].reshape(b, s, nh, nkv, dv), group)
 
@@ -433,8 +504,15 @@ def chunk_fits(q, k_cache, v_cache) -> bool:
             and k_cache.shape[1] % CHUNK_BLOCK_K == 0)
 
 
-def _chunk_kernel(offset_ref, q_ref, *refs, scale: float, group: int,
-                  kv_heads: int, block: int, folded: bool, needed):
+def _chunk_kernel(offset_ref, *refs, scale: float, group: int,
+                  kv_heads: int, block: int, folded: bool, needed,
+                  exact_from=None):
+    # with ``exact_from`` one more prefetched scalar a row before the
+    # operands: the leading slots every query of the row sees
+    seen_ref = None
+    if exact_from is not None:
+        seen_ref, refs = refs[0], refs[1:]
+    q_ref, refs = refs[0], refs[1:]
     k_refs = refs[:-5]
     v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-5:]
     b, g, qb, kb = (pl.program_id(i) for i in range(4))
@@ -475,13 +553,27 @@ def _chunk_kernel(offset_ref, q_ref, *refs, scale: float, group: int,
             row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
             k_pos = kb * block_k + lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1)
-            seen = k_pos <= _last_seen(first + row // group, block)
+            if seen_ref is None:
+                seen = k_pos <= _last_seen(first + row // group, block)
+            else:
+                seen = (k_pos < seen_ref[b]) | (
+                    (k_pos >= exact_from) &
+                    (k_pos - exact_from <= first + row // group))
         _fold_in(s, seen, values, m_ref, l_ref, acc_ref)
 
     # the key blocks wholly before the query block's first query need no
     # mask; those past its last query's reach compute nothing
-    whole = (kb + 1) * block_k - 1 <= _last_seen(first, block)
-    wanted = kb < needed(offset_ref[b], qb)
+    if seen_ref is None:
+        whole = (kb + 1) * block_k - 1 <= _last_seen(first, block)
+        wanted = kb < needed(offset_ref[b], qb)
+    else:
+        # (wholly under what every query sees, or wholly in the causal
+        # part at or before the first query)
+        whole = ((kb + 1) * block_k <= seen_ref[b]) | (
+            (kb * block_k >= exact_from) &
+            ((kb + 1) * block_k - 1 - exact_from <= first))
+        wanted, _ = _two_parts(kb, block_k, seen_ref[b], exact_from,
+                               needed(offset_ref[b], qb))
     pl.when(wanted & whole)(lambda: fold_in(False))
     pl.when(wanted & ~whole)(lambda: fold_in(True))
     pl.when(kb == pl.num_programs(3) - 1)(
@@ -489,6 +581,7 @@ def _chunk_kernel(offset_ref, q_ref, *refs, scale: float, group: int,
 
 
 def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
+                    seen=None, exact_from: int = 0,
                     interpret: bool = False):
     """MANY new queries a row (a prefill's chunk): ``q`` (B, s, H, D)
     against the written caches, per head (B, S, Hkv, D) or with the heads
@@ -520,7 +613,13 @@ def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
     channels, as the decode's kernel does under its fetch, ``Hkv``
     times.)  The values come a head a block.  Per head: a step fetches the
     block's (positions x Hkv, D) rows and takes its head's, every
-    ``Hkv``-th, by a strided read of 32-bit pairs."""
+    ``Hkv``-th, by a strided read of 32-bit pairs.
+
+    ``seen`` ((B,) int32, no ``block``): the cache is in two parts, as
+    ``folded_cached_attention``'s: every query of row ``b`` sees the slots
+    under ``seen[b]``, slot ``exact_from + j`` is seen by the queries with
+    ``j <= offset[b] + i``, the slots between by none and their key blocks
+    are not fetched; ``exact_from`` is a whole number of key blocks."""
     b, s, nh, dim = q.shape
     seq_len = k_cache.shape[1]
     nkv, dv, block_q = _chunk_shapes(q, k_cache, v_cache)
@@ -528,22 +627,37 @@ def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
     rows = block_q * group
     offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (b,))
 
+    if seen is None:
+        scalars, two_parts, causal = (offset,), {}, seq_len
+    else:
+        if block or exact_from % block_k:
+            raise ValueError("a cache in two parts goes with a causal mask "
+                             "and a first part of whole key blocks")
+        scalars = (offset, jnp.broadcast_to(
+            jnp.asarray(seen, jnp.int32), (b,)))
+        two_parts, causal = {"exact_from": exact_from}, seq_len - exact_from
+
     def needed(first, qb):
         """Key blocks query block ``qb`` of a row that starts at ``first``
         reads: up to its last query's reach."""
         return blocks_read(
             _last_seen(first + (qb + 1) * block_q - 1, block), block_k,
-            seq_len)
+            causal)
 
-    def queries(b_, g, qb, kb, offset_ref):
+    def queries(b_, g, qb, kb, *scalar_refs):
         return b_, g, qb, 0
 
     def key_block(channels=lambda g: 0):
         """The key block a step fetches, and of its channels the block
         ``channels(g)``: its own while the query block needs it, the last
         needed one after (the same again: no fetch)."""
-        def index(b_, g, qb, kb, offset_ref):
-            at = jnp.minimum(kb, needed(offset_ref[b_], qb) - 1)
+        def index(b_, g, qb, kb, offset_ref, *seen_ref):
+            blocks = needed(offset_ref[b_], qb)
+            if seen_ref:
+                at = _two_parts(kb, block_k, seen_ref[0][b_], exact_from,
+                                blocks)[1]
+            else:
+                at = jnp.minimum(kb, blocks - 1)
             return b_, at, channels(g)
         return index
 
@@ -573,10 +687,10 @@ def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, scale=float(1 / np.sqrt(dim)),
                           group=group, kv_heads=nkv, block=block,
-                          folded=folded, needed=needed),
+                          folded=folded, needed=needed, **two_parts),
         out_shape=jax.ShapeDtypeStruct((b, nkv, s * group, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(b, nkv, s // block_q, seq_len // block_k),
             in_specs=[pl.BlockSpec((None, None, rows, q.shape[-1]), queries),
                       *keys, values],
@@ -591,6 +705,6 @@ def chunk_attention(q, k_cache, v_cache, offset, *, block: int = 0,
         interpret=interpret,
         # what a device trace calls the kernel's events
         name="cached_attention_query_key_blocks",
-    )(offset, q, *[k_cache] * len(keys), v_cache)
+    )(*scalars, q, *[k_cache] * len(keys), v_cache)
     return out.reshape(b, nkv, s, group, dv).transpose(0, 2, 1, 3, 4).reshape(
         b, s, nh, dv)

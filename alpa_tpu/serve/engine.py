@@ -25,8 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from alpa_tpu import fault
-from alpa_tpu.model.gpt_model import (cached_key_block, init_kv_caches,
-                                      kv_cache_kinds, require_one_token_steps,
+from alpa_tpu.model.gpt_model import (cached_key_block, eva_slots,
+                                      init_kv_caches, kv_cache_kinds,
+                                      require_one_token_steps,
                                       selected_per_row)
 from alpa_tpu.serve.generation import (GenerationConfig, Generator,
                                        fresh_kv_caches, read_block,
@@ -108,6 +109,25 @@ _DECODE_POSITIONS_READ = _REG.counter(
     "select their positions (GPTConfig.index_topk), what such a layer's "
     "core fetches a row: the selection, or all the row holds while that "
     "is less")
+_EVA_KEYS = _REG.counter(
+    "alpa_serving_eva_keys_total",
+    "Keys a layer the decode ticks' active rows' queries saw, where the "
+    "configuration's attention runs over keys of two kinds "
+    "(GPTConfig.attention \"eva\"), summed over the rows and the ticks, "
+    "from the rows' positions: exact (a query at position t sees t % "
+    "eva_window + 1 rows of its own window) and summary (one pooled key "
+    "for every eva_chunk positions of the windows before, eva_window / "
+    "eva_chunk x (t // eva_window)); not fed by any other configuration",
+    labelnames=("kind",))
+_EVA_CHUNK_PAIRS = _REG.counter(
+    "alpa_serving_eva_chunk_pairs_total",
+    "Pairs of a query and a key it sees a layer, over the positions the "
+    "chunk steps of chunked admissions ran over (padding included: the "
+    "step computes them alike), from the chunks' offsets: exact (the "
+    "window's rows at or before the query) and summary (the pooled keys "
+    "of the windows before); the counterpart of "
+    "alpa_serving_eva_keys_total for the prefill",
+    labelnames=("kind",))
 _SELECT_POSITIONS = _REG.counter(
     "alpa_serving_select_positions_total",
     "Cache positions on the decode ticks' layers that select their "
@@ -156,6 +176,10 @@ _KV_CACHE_BYTES = _REG.gauge(
     "or ssm (a Mamba-2 or Mamba-1 mixer's two states: its convolution's "
     "last positions and a matrix a head or a few values a channel, "
     "whatever the context); "
+    "or, where a layer's keys are of two kinds (GPTConfig.attention "
+    "\"eva\"), window (the rows of one aligned window) and summary (a "
+    "pooled key and value for every chunk of the served context), the two "
+    "parts of one pair of arrays by their slots; "
     "the arrays' sizes as the device lays them out (held_bytes)",
     labelnames=("kind",))
 _KV_CACHE_ARRAY_BYTES = _REG.gauge(
@@ -412,6 +436,22 @@ class ContinuousBatchingEngine:
         # selects), and how many layers select
         self._selected = selected_per_row(cfgm)
         self._select_layers = kv_cache_kinds(cfgm).count("latent_index")
+        # (window, summaries a window) where layers' keys are of two
+        # kinds, which the ticks and the chunked admissions then count
+        # (``_EVA_KEYS``); None: nothing is counted
+        self._eva = (cfgm.eva_window, cfgm.eva_window // cfgm.eva_chunk) \
+            if "eva" in kv_cache_kinds(cfgm) else None
+        if self._eva is not None:
+            for what, given in (("kv_pool (KVBlockPool)", kv_pool),
+                                ("a static prefix", prefix)):
+                if given:
+                    require_one_token_steps(cfgm, what)
+            if not chunked_admission:
+                raise ValueError(
+                    "an engine over a configuration whose layers' caches "
+                    "hold ONE window's rows beside summaries "
+                    "(GPTConfig.attention \"eva\") admits in chunks that "
+                    "divide the window: chunked_admission=True")
         self._prefix = prefix
         # what a dense admission pads to; empty where none can happen
         self._ladder = [] if chunked_admission or prefix is not None \
@@ -586,10 +626,24 @@ class ContinuousBatchingEngine:
         # by the kind of the layer's entry; a short convolution's is its
         # state, as large a row whatever the context
         by_kind = {"window": 0, "full": 0, "latent": 0, "conv": 0, "ssm": 0}
+        if self._eva is not None:
+            by_kind["summary"] = 0
         by_array = {}
         for kind, (k, v, _i) in zip(kv_cache_kinds(cfgm), self._caches):
             if kind == "none":
                 # a layer that is its MLP alone holds nothing
+                continue
+            if kind == "eva":
+                # one pair of arrays holds rows of two kinds: the
+                # summaries' slots first, one window's rows behind them
+                summaries, rows = eva_slots(cfgm)
+                for array, x in (("keys", k), ("values", v)):
+                    held = held_bytes(x)
+                    by_kind["summary"] += held * summaries // (
+                        summaries + rows)
+                    by_kind["window"] += held * rows // (summaries + rows)
+                    series = (kind, array, "x".join(map(str, x.shape)))
+                    by_array[series] = by_array.get(series, 0) + held
                 continue
             # every latent layer's entry is "latent", whatever it holds
             kind = kind.partition("_")[0]
@@ -881,6 +935,8 @@ class ContinuousBatchingEngine:
                         padded = self._chunk_padded(asked)
                         logits1, caches1 = self.gen._run_chunked_prefill(
                             [p], row_length(len(p)), 1)
+                        if self._eva is not None:
+                            self._count_eva_chunks(padded)
                     else:
                         path, asked = "dense", len(p)
                         logits1, caches1, padded = self.gen.prefill_row(
@@ -1191,6 +1247,26 @@ class ContinuousBatchingEngine:
             _SELECT_POSITIONS.labels("selected").inc(
                 read * self._select_layers)
 
+    def _count_eva_keys(self, position: int):
+        """What the query a tick enqueued for a row at ``position`` sees a
+        layer, into the counters: the rows of its own window up to itself,
+        and a summary for every chunk of the windows before."""
+        window, summaries = self._eva
+        _EVA_KEYS.labels("exact").inc(position % window + 1)
+        _EVA_KEYS.labels("summary").inc(summaries * (position // window))
+
+    def _count_eva_chunks(self, padded: int):
+        """What the queries of a chunked admission's ``padded`` positions
+        (whole chunks from position 0) see a layer, into the counters:
+        query ``t`` sees ``t % window + 1`` exact keys and the summaries of
+        the windows before."""
+        window, summaries = self._eva
+        whole, rest = divmod(padded, window)
+        _EVA_CHUNK_PAIRS.labels("exact").inc(
+            whole * window * (window + 1) // 2 + rest * (rest + 1) // 2)
+        _EVA_CHUNK_PAIRS.labels("summary").inc(summaries * (
+            window * whole * (whole - 1) // 2 + rest * whole))
+
     def _positions_read(self, held: int) -> int:
         """Of a row that holds ``held`` positions, those the tick's
         attention core fetches: whole key blocks up to the row's newest
@@ -1272,6 +1348,8 @@ class ContinuousBatchingEngine:
                     held = len(item["prompt"]) + len(item["tokens"])
                     positions += held
                     read += self._positions_read(held)
+                    if self._eva is not None:
+                        self._count_eva_keys(held - 1)
                     if over or item.get("cancelled"):
                         self._finish_row(r, item)
                 self._count_positions(positions, read)

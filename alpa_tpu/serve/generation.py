@@ -441,6 +441,18 @@ class Generator:
     (``GPTConfig.folds_full_caches``), and are written and attended over
     as the folded caches of wider keys are.
 
+    A layer whose keys are of two kinds (``attention`` "eva") rides as
+    ``(keys, values, index)`` too, each array (B, summaries + window, H D):
+    a pooled summary for every chunk of the context and the rows of ONE
+    aligned window (``gpt_model.update_eva_cache``).  ``prefill_chunk``
+    must divide the window and hold whole chunks, so a step never
+    straddles a window; the chunk step then DONATES its caches (no prefix
+    handle can be: such a configuration refuses one) and writes the row's
+    set in place.  Of a configuration with several prediction heads
+    (``num_pred_heads``) the steps hand the engine the FIRST head's logits,
+    the next token's, and ``_decode``'s third result holds all of them,
+    ``{"pred_logits": (B, heads, V)}``, which stay on the device.
+
     ``_decode`` returns ``(logits, caches, routing)``: ``routing`` is
     ``{"experts": (expert layers, rows, k) int32}``, every row's experts in
     every routed-expert layer, and ``{}`` (no output of the compiled
@@ -572,6 +584,30 @@ class Generator:
                      ([kinds] if isinstance(kinds, str) else kinds)) or \
             getattr(config, "index_topk", 0) > 0
         rings = not uniform_kv_caches(config)
+        # prediction heads of one head matrix (``GPTConfig.num_pred_heads``):
+        # the steps give the engine the FIRST head's logits, the next
+        # token's, and a tick all of them beside its routing
+        pred_heads = getattr(config, "num_pred_heads", 1)
+        window = getattr(config, "eva_window", 0)
+        if window and prefill_chunk and (
+                window % prefill_chunk or
+                prefill_chunk % config.eva_chunk):
+            raise ValueError(
+                f"prefill_chunk {prefill_chunk} must divide the window "
+                f"({window}) of this configuration's \"eva\" layers and "
+                f"hold whole chunks of {config.eva_chunk}: a step that "
+                "straddles a window's edge overwrites rows its first "
+                "queries still see")
+
+        def first_head(logits, like=None):
+            """(..., V): the first prediction head's logits (in ``like``'s
+            dtype, where float32 logits go into a buffer of the model's)."""
+            if pred_heads > 1:
+                logits = logits[..., :config.vocab_size]
+            if like is not None and logits.dtype != like.dtype:
+                logits = logits.astype(like.dtype)
+            return logits
+
         # multi-token-prediction modules to draft with (class docstring)
         drafts = getattr(config, "num_nextn_predict_layers", 0)
         if drafts:
@@ -609,7 +645,7 @@ class Generator:
             pos = jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
             logits, caches = model.apply(params, input_ids, pos, caches,
                                          **lengths_kw(lengths))
-            last = logits[jnp.arange(b), lengths - 1]
+            last = first_head(logits[jnp.arange(b), lengths - 1])
             # per-row cache indices: each row continues at its own length
             caches = [(kc, vc, lengths) for (kc, vc, _i) in caches]
             return last, caches
@@ -622,6 +658,12 @@ class Generator:
                     params, token, pos, caches, return_routing=True)
                 return logits[:, 0, :], caches, routing
             logits, caches = model.apply(params, token, pos, caches)
+            if pred_heads > 1:
+                # every head's logits, (B, heads, V), stay on the device
+                # beside what a routed tick says
+                return first_head(logits[:, 0, :]), caches, {
+                    "pred_logits": logits[:, 0, :].reshape(
+                        logits.shape[0], pred_heads, config.vocab_size)}
             return logits[:, 0, :], caches, {}
 
         self.prefill_chunk = prefill_chunk
@@ -685,7 +727,8 @@ class Generator:
                 # them: 6.5 ms of GLM-5's 53 ms chunk, PERF.md, PR 60)
                 _, caches = model.apply(params, following, pos, caches,
                                         draft_from=hidden, logits_at=at[:, :1])
-            last = jnp.where(hit[:, None], logits[:, 0], last)
+            last = jnp.where(hit[:, None], first_head(logits[:, 0], last),
+                             last)
             return last, caches, passes
 
         def verify_draft(params, tokens, index, caches, draft, left,
@@ -784,7 +827,16 @@ class Generator:
         else:
             self._prefill = _jit_registered(prefill)
             self._decode = _jit_donating_kv(decode)
-            self._chunk_prefill = _jit_registered(chunk_prefill)
+            # a chunk step does not donate its caches (they may be a
+            # prefix handle's), and so returns a copy of a row's whole set
+            # a chunk.  Where no handle can be (an "eva" configuration
+            # refuses a prefix: ``require_one_token_steps``) the set is
+            # the prefill's own, a cache of the WHOLE context's summaries
+            # beside one window is 0.54 GB a row at EvaByte's widths, and
+            # the step writes it in place
+            self._chunk_prefill = _jit_registered(
+                chunk_prefill, **({"donate_argnums": (3,)} if window
+                                  else {}))
         # beam-search KV-cache gather, compiled once (per cache shapes)
         self._reorder = jax.jit(
             lambda caches, idx: jax.tree_util.tree_map(

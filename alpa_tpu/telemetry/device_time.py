@@ -57,6 +57,10 @@ part                         components
 ``ssm_mixer.scan``           ``model.gpt_model.S6_SCAN_SCOPE``, a Mamba-1
                              mixer's recurrence ALONE (the step of a tick,
                              the walk over a chunk's positions)
+``attention.summaries``      ``model.gpt_model.EVA_SCOPE``, inside the
+                             attention core of an "eva" layer: the pooling
+                             of chunks of keys and values into summaries
+                             and the write of the pooled rows
 ``mlp``                      ``mlp`` (``MLPBlock``; a routed layer's
                              shared expert)
 ``moe``                      ``model.moe.SCOPE``: router, top-k, sort,
@@ -166,6 +170,10 @@ _COMPONENTS = {
     # model/gpt_model.py S6_SCAN_SCOPE: a Mamba-1 mixer's recurrence alone,
     # inside SSM_SCOPE
     "selective_scan": "ssm_mixer.scan",
+    # model/gpt_model.py EVA_SCOPE: the pooling of an "eva" layer's chunks
+    # into summaries and the write of the pooled rows, inside
+    # ATTENTION_SCOPE
+    "eva_summaries": "attention.summaries",
     "mlp": "mlp",
     "moe": "moe",
     "grouped_matmul": "moe.grouped_matmul",

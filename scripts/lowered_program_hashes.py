@@ -16,7 +16,8 @@ checkouts whose lines agree build the same programs for those cells (PR 58
 compared its tree with its parent so; PR 60 added the six ticks, which its
 windows over a chunk's expert rows leave as they were; PR 62 the lowerings
 for a TPU, which its kernel under a selection changes in the chunk steps of
-dots3-note and GLM-5 alone)."""
+dots3-note and GLM-5 alone; PR 63 Jamba's tick and chunk step for a TPU, a
+Mamba-1 and an attention layer: twenty-nine in all)."""
 import hashlib, json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 root = os.path.abspath(sys.argv[1])
@@ -104,4 +105,11 @@ for name, layers in (("deepseek-v2-1chip", 2), ("longcat-flash-1chip", 2), ("dot
     out[name + ".decode"] = digest(decode.lower(*tick))
     out[name + ".decode.tpu"] = for_tpu(decode, *tick)
     out[name + ".chunk.tpu"] = for_tpu(gen._chunk_prefill, params, S((1, serve["prefill_chunk"]), jnp.int32), S((1,), jnp.int32), jax.eval_shape(lambda: init_kv_caches(cfg, 1)), S((1, cfg.vocab_size), jnp.bfloat16))
+# Jamba (PR 63 added it: its two attention layers of one key/value head lower through the folded kernels that a cache in two parts shares): a Mamba-1 and an attention layer, the tick and the chunk step for a TPU
+hf = dict(json.load(open(os.path.join(root, "chipbench/configs/jamba2-3b-1chip.json"))), num_hidden_layers=2, attn_layer_period=2, attn_layer_offset=1)
+serve = hf["serve"]
+cfg = config_from_hf(hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=serve["served_context"])
+gen, params, rows, tick = served(cfg, serve)
+out["jamba2-3b-1chip.decode.tpu"] = for_tpu(gen._decode.jitted, *tick)
+out["jamba2-3b-1chip.chunk.tpu"] = for_tpu(gen._chunk_prefill, params, S((1, serve["prefill_chunk"]), jnp.int32), S((1,), jnp.int32), jax.eval_shape(lambda: init_kv_caches(cfg, 1)), S((1, cfg.vocab_size), jnp.bfloat16))
 print(json.dumps(out))

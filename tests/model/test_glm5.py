@@ -198,6 +198,7 @@ def test_the_modules_block_has_a_cache_entry_of_its_own(toy):
     ("attention", ("latent", "conv"), "short convolution's state"),
     ("attention", ("latent", "ssm"), "Mamba-2 mixer's states"),
     ("attention", ("latent", "s6"), "Mamba-1 mixer's states"),
+    ("attention", ("latent", "eva"), "ONE window's rows"),
     ("block_length", 4, "diffusion over blocks")])
 def test_the_tick_refuses_what_no_index_rolls_back(field, value, named):
     cfg = gpt_model.GPTConfig(

@@ -1033,3 +1033,90 @@ def test_a_jamba_tick_and_chunk_keep_their_named_bytes_on_v5e(one_chip):
     assert "selective_scan_positions" in text
     assert not [line for line in text.splitlines() if " while(" in line]
     assert not re.search(r"\[[\d,]*%d,%d\]" % (chunk, context), text)
+
+
+def test_an_evabyte_tick_and_chunk_keep_their_named_bytes_on_v5e(one_chip):
+    """The decode tick and the chunk step of ``evabyte-1chip`` at its
+    published widths, its cell's 16 rows and served context of 32,768, and
+    ONE layer.  The tick: the layer's one pair of arrays (2,048 summaries
+    and 2,048 window rows of 32 heads x 128 channels, folded) is aliased
+    to the results that replace it at its NAMED bytes, 67,108,864 B a row,
+    nothing pads or copies it, and its attention is the folded kernel over
+    key blocks with the cache in two parts (32 key/value heads of whole
+    lanes, one query head each).  The chunk: its attention is the kernel
+    over query blocks and key blocks, and no loop walks the cache.  The
+    gauge names a kernel for both."""
+    from alpa_tpu.model.gpt_model import GPTModel, init_kv_caches
+    from alpa_tpu.serve.generation import Generator
+    from alpa_tpu.telemetry import metrics as tmetrics
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from chipbench import run
+    hf = dict(run.load_json(run.HERE, "configs", "evabyte-1chip.json"),
+              num_hidden_layers=1)
+    serve = hf["serve"]
+    rows, chunk, context = (serve["engine_rows"], serve["prefill_chunk"],
+                            serve["served_context"])
+    cfg = run.load_module("drivers", "serve_eva").model_config(
+        hf, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, seq_len=context)
+    assert cfg.attention == "eva" and (rows, context) == (16, 32768)
+    model = GPTModel(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def cores():
+        return {key.split('core="')[1].split('"')[0] + "/" +
+                key.split('queries="')[1].split('"')[0]: value
+                for key, value in tmetrics.get_registry().snapshot().items()
+                if key.startswith("alpa_cached_attention_core") and
+                'heads="32"' in key and 'head_dim="128"' in key}
+
+    before = cores()
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                    jnp.ones((1, 8), jnp.int32)))
+    gen = Generator(model, params, cfg, prefill_chunk=chunk)
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, rows))
+    tick = gen._decode.jitted.lower(
+        params, spec(rows, 1), spec(rows),
+        on_chip([(k, v) for k, v, _ in caches]),
+        [spec(rows) for _ in caches]).compile()
+    memory = tick.memory_analysis()
+    held = rows * 67_108_864
+    assert held == sum(x.size * x.dtype.itemsize for k, v, _ in caches
+                       for x in (k, v))
+    assert memory.alias_size_in_bytes == held
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    # (the arguments: the weights, the caches, the ids and the indices)
+    assert memory.argument_size_in_bytes < weights + held + 2**20
+    assert memory.temp_size_in_bytes < 16 * 2**20
+    text = tick.as_text()
+    head = text[:text.index("\n")]
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                          head)) == 2
+    named = r"bf16\[%d,4096,4096\]" % rows
+    assert "bf16[%d,4096,4096]{2,1,0:T(8,128)(2,1)}" % rows in head
+    moved = [line for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|transpose|convert)\(" % named,
+                          line)]
+    assert not moved, moved[:3]
+    assert "cached_attention_folded_key_blocks" in text
+    step = gen._chunk_prefill.lower(
+        params, spec(1, chunk), spec(1),
+        on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1))),
+        spec(1, cfg.vocab_size, dtype=jnp.bfloat16)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 2**27
+    text = step.as_text()
+    assert "cached_attention_query_key_blocks" in text
+    assert not [line for line in text.splitlines() if " while(" in line]
+    after = cores()
+    assert {name: after[name] - before.get(name, 0) for name in after
+            if after[name] != before.get(name, 0)} == {
+                "key_blocks/1": 1.0, "query_key_blocks/%d" % chunk: 1.0}
